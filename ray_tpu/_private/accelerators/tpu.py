@@ -6,14 +6,22 @@ Reference analog: ``python/ray/_private/accelerators/tpu.py`` —
 (:475-588), and the extra ``TPU-{pod}-head`` resource on worker 0 (:634)
 that lets the scheduler reserve an ICI-connected slice atomically.
 
-Detection here is env-first (TPU VM images export TPU_* vars), with the GCE
-metadata server as fallback; both layers are injectable for tests (the
-reference mocks the same seams in ``tests/accelerators/test_tpu.py``).
+Chips are counted from what this machine exposes to the TPU runtime — the
+device files libtpu opens — before anything that merely describes the host
+(``TPU_*`` env vars, GCE metadata): a one-chip VM carved out of a four-chip
+host exports the host's ``TPU_CHIPS_PER_HOST_BOUNDS=2,2,1`` but only one
+``/dev/vfio/<n>``. Detection never touches JAX (the process that detects is
+the driver, which must stay off the chip) and never needs the network: the
+metadata server is the last resort, skipped under ``TPU_SKIP_MDS_QUERY`` and
+given up on after its first failed lookup. Every layer is injectable for
+tests (the reference mocks the same seams in
+``tests/accelerators/test_tpu.py``).
 """
 from __future__ import annotations
 
 import glob
 import logging
+import math
 import os
 import re
 from typing import Dict, List, Optional
@@ -35,14 +43,21 @@ _CHIPS_PER_HOST = {"v2": 4, "v3": 4, "v4": 4, "v5p": 4, "v5litepod": 8,
 
 
 _metadata_cache: Dict[str, Optional[str]] = {}
+_metadata_unreachable = False
 
 
 def _fetch_metadata(key: str, timeout: float = 1.0) -> Optional[str]:
-    """GCE metadata attribute (None off-GCE), cached per process — the
-    detection paths re-query the same keys and off-GCE lookups can block on
-    DNS. Patched in tests (patched versions bypass the cache)."""
+    """GCE metadata attribute (None off-GCE), cached per process. One
+    failed lookup marks the server unreachable for the life of the process,
+    so a sealed machine pays at most one timeout, and none when the image
+    says ``TPU_SKIP_MDS_QUERY``. Patched in tests (patched versions bypass
+    the cache)."""
+    global _metadata_unreachable
     if key in _metadata_cache:
         return _metadata_cache[key]
+    if _metadata_unreachable or os.environ.get("TPU_SKIP_MDS_QUERY"):
+        return None
+    import urllib.error
     import urllib.request
 
     try:
@@ -51,10 +66,48 @@ def _fetch_metadata(key: str, timeout: float = 1.0) -> Optional[str]:
         )
         with urllib.request.urlopen(req, timeout=timeout) as r:
             value = r.read().decode()
-    except Exception:
-        value = None
+    except urllib.error.HTTPError:
+        value = None  # server answered: this attribute is not set
+    except OSError:
+        _metadata_unreachable = True
+        return None
     _metadata_cache[key] = value
     return value
+
+
+def _chip_device_files() -> List[str]:
+    """Device files the TPU runtime opens, one per chip: ``/dev/accel<n>``
+    (v4 and older VM images) or ``/dev/vfio/<n>`` (v5e/v6e;
+    ``/dev/vfio/vfio`` is the container control node, not a chip)."""
+    return glob.glob("/dev/accel*") or [
+        p for p in glob.glob("/dev/vfio/*") if not p.endswith("/vfio")
+    ]
+
+
+def local_device_info() -> Dict[str, object]:
+    """Platform, kind and count of the JAX devices this process computes
+    on, for callers to report. Raises :class:`AcceleratorMismatchError`
+    when the node was granted ``TPU`` and JAX came up on anything else —
+    computing on the CPU there would only look like success."""
+    import jax
+
+    from ray_tpu._private import worker as worker_mod
+    from ray_tpu.exceptions import AcceleratorMismatchError
+
+    devices = jax.devices()
+    info = {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+    }
+    w = worker_mod.global_worker
+    granted = w.node_resources.get("TPU", 0) if w is not None else 0
+    if granted > 0 and info["platform"] != "tpu":
+        raise AcceleratorMismatchError(
+            f"node {w.node_id[:8]} was granted TPU={granted:g} but JAX runs "
+            f"on {info['platform']!r} ({info['device_kind']})"
+        )
+    return info
 
 
 @register_accelerator_manager
@@ -76,24 +129,15 @@ class TPUAcceleratorManager(AcceleratorManager):
 
     @staticmethod
     def get_current_node_num_accelerators() -> int:
-        # explicit override first (also the test seam)
+        n = len(_chip_device_files())
+        if n:
+            return n
         v = os.environ.get("TPU_CHIPS_PER_HOST_BOUNDS")
         if v:  # "2,2,1" style bounds
             try:
-                dims = [int(x) for x in v.split(",")]
-                n = 1
-                for d in dims:
-                    n *= d
-                return n
+                return math.prod(int(x) for x in v.split(","))
             except ValueError:
                 pass
-        # device files exposed on TPU VMs (/dev/vfio/vfio is the container
-        # control node, not a chip)
-        n = len(glob.glob("/dev/accel*")) or len(
-            [p for p in glob.glob("/dev/vfio/*") if not p.endswith("/vfio")]
-        )
-        if n:
-            return n
         acc = TPUAcceleratorManager._accelerator_type()
         if acc:
             gen = acc.split("-")[0]

@@ -320,5 +320,9 @@ class LocalCluster:
                 poll.sleep()
             if n.alive():
                 n.kill()
+                # Reap before returning: a node that held the chip keeps
+                # the TPU runtime's lock until the process is gone, and the
+                # caller's next init() starts a node that needs it.
+                n.proc.wait()
         self.nodes.clear()
         self.warm.clear()

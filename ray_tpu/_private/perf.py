@@ -186,7 +186,9 @@ def bench_put_get_device(total_gb: float = 0.5) -> float:
     put()→get() into ANOTHER process (the pull_device_shards DCN leg —
     the same-process path is a table hit and measures nothing). Recorded
     as ``put_get_device_gb_per_s`` next to ``single_client_put_gb_per_s``
-    so the device plane's trajectory rides the same bench JSON."""
+    so the device plane's trajectory rides the same bench JSON. The
+    producer is the calling driver, which initialises JAX here: callers
+    skip this leg on a cluster whose node holds a TPU."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
@@ -595,14 +597,22 @@ def run_core_benchmarks(quick: bool = False) -> Dict[str, float]:
     out["single_client_put_gb_per_s"] = bench_put_gigabytes(
         0.5 if quick else 2.0
     )
-    try:
-        _progress("put_get_device")
-        out["put_get_device_gb_per_s"] = bench_put_get_device(
-            0.125 if quick else 0.5
+    if ray_tpu.cluster_resources().get("TPU", 0) > 0:
+        # The leg's producer is this driver: creating its array would take
+        # the chip from the node that holds it (one process per chip).
+        out["put_get_device_gb_per_s"] = None
+        out["put_get_device_note"] = (
+            "not measured: a node holds the TPU and the driver stays off JAX"
         )
-    except Exception as e:
-        # jax-less / device-less hosts record the miss, never sink the run
-        out["put_get_device_error"] = f"{type(e).__name__}: {e}"
+    else:
+        try:
+            _progress("put_get_device")
+            out["put_get_device_gb_per_s"] = bench_put_get_device(
+                0.125 if quick else 0.5
+            )
+        except Exception as e:
+            # jax-less hosts record the miss, never sink the run
+            out["put_get_device_error"] = f"{type(e).__name__}: {e}"
     _progress("get_calls")
     out["single_client_get_calls_per_s"] = bench_get_calls(
         int(2000 * scale)

@@ -4552,6 +4552,19 @@ class CoreWorker:
                 return None
             return self._pop_pending(lease_set)
 
+    def _flush_settles(self, settles: list):
+        """Hand the replies a shard pusher has collected to the driver
+        loop and empty the list. Called before the pusher blocks on a
+        reply that has not arrived: a chunk-mate may be waiting, on its
+        node, for one of these results (``z = f(y)`` pushed in one chunk
+        with ``y``), and held back until the whole chunk had replied they
+        deadlocked it."""
+        if settles:
+            self.loop.call_soon_threadsafe(
+                self._chunk_settle_on_loop, settles[:]
+            )
+            settles.clear()
+
     def _chunk_settle_on_loop(self, items):
         """Settle one pushed chunk's replies on the driver loop: shard
         pushers collect ``(header, reply_header, reply_frames, fut)``
@@ -4596,8 +4609,10 @@ class CoreWorker:
         pacing, and reply awaiting all stay here; driver-loop state —
         lease bookkeeping, dispatch futures, TCP connections, reply
         settling — marshals through the ``*_on_loop`` helpers. A chunk's
-        settles flush in ONE cross-loop hop (the per-iteration finally),
-        so the driver loop pays O(chunks), not O(tasks)."""
+        settles flush in ONE cross-loop hop (the per-iteration finally)
+        when its replies arrive together, and before any wait for a reply
+        that has not arrived; the driver loop pays O(chunks), not
+        O(tasks), in the common case."""
         my_loop = asyncio.get_running_loop()
         on_shard = my_loop is not self.loop
         shard_idx = (self._pusher_loops.index(my_loop)
@@ -4741,6 +4756,7 @@ class CoreWorker:
                         # the ring ride TCP. Futures must never be dropped.
                         for i, (header, frames, fut) in enumerate(chunk):
                             try:
+                                self._flush_settles(settles)
                                 h, rframes = await self._await_push_reply(
                                     self._call_with_tcp_fallback(
                                         conn, slot.addr, "push_task",
@@ -4817,6 +4833,7 @@ class CoreWorker:
                                 # intern-miss re-push).
                                 h, rframes = rf.result()
                             else:
+                                self._flush_settles(settles)
                                 h, rframes = await self._await_push_reply(
                                     rf, conn, slot.addr, header, frames
                                 )
@@ -4915,14 +4932,11 @@ class CoreWorker:
                     if held:
                         self._win_release(slot, win, held)
                         held = 0
-                    if settles:
-                        # ONE cross-loop hop settles the whole chunk
-                        # (shard mode only appends here). Ordering vs a
-                        # node-lost marshal above is FIFO on the driver
-                        # loop, and the two cover disjoint futures.
-                        self.loop.call_soon_threadsafe(
-                            self._chunk_settle_on_loop, settles
-                        )
+                    # ONE cross-loop hop settles what the chunk still
+                    # holds (shard mode only appends). Ordering vs a
+                    # node-lost marshal above is FIFO on the driver
+                    # loop, and the two cover disjoint futures.
+                    self._flush_settles(settles)
         finally:
             if on_shard:
                 self.loop.call_soon_threadsafe(

@@ -69,6 +69,11 @@ class RuntimeEnvSetupError(RayTpuError):
     """Failed to materialize the runtime environment for a task/actor."""
 
 
+class AcceleratorMismatchError(RayTpuError):
+    """A process whose node was granted ``TPU`` found JAX running on
+    another platform."""
+
+
 class PlacementGroupUnavailableError(RayTpuError):
     """Placement group cannot be scheduled (e.g. infeasible slice topology)."""
 

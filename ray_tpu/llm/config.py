@@ -41,8 +41,7 @@ class LLMConfig:
     tensor_parallel_size: int = 1  # reserved: mesh "tensor" axis size
     # Prompt-lookup speculative decoding (vLLM spec-decode "[ngram]"
     # parity, TPU-first rationale: each verify step amortizes one program
-    # dispatch over up to k tokens — dispatch latency dominates small-batch
-    # decode through a tunneled/jitted path). OPT-IN; greedy requests only
+    # dispatch over up to k tokens). OPT-IN; greedy requests only
     # (temperature 0 — rejection-sampling equivalence for stochastic
     # requests is out of scope and those requests fall back to 1-token
     # ticks). 0 disables; k = max draft tokens proposed per step.
@@ -58,7 +57,9 @@ class LLMConfig:
     max_new_tokens_default: int = 64
     tokenizer: str = "byte"  # "byte" | local HF tokenizer dir
 
-    accelerator_type: Optional[str] = None
+    # serve.deployment(...) keyword arguments for the replica. A replica
+    # that must hold a chip asks for it here:
+    # {"ray_actor_options": {"num_tpus": 1}}.
     deployment_config: Dict[str, Any] = field(default_factory=dict)
 
     def model_config(self):

@@ -112,7 +112,10 @@ class DecodeEngine:
         model = module_for(self.model_config)
         self.tokenizer = load_tokenizer(config)
         if params is None:
-            params = model.init_params(
+            # One program: op by op, each parameter's RNG call compiles on
+            # its own, and a cold replica of GPT-2-small spent 48 s there
+            # on a v5e — more than serve.run() waits for a deployment.
+            params = jax.jit(model.init_params, static_argnums=0)(
                 self.model_config, jax.random.PRNGKey(seed)
             )
         self.params = params

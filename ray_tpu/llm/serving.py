@@ -78,7 +78,11 @@ class LLMServer:
     """Serve deployment target wrapping one engine replica."""
 
     def __init__(self, config_dict: dict, params=None):
+        from ray_tpu._private.accelerators.tpu import local_device_info
+
         self.config = LLMConfig.from_dict(config_dict)
+        # a replica granted a chip refuses to serve from another platform
+        self._device_info = local_device_info()
         self.engine = DecodeEngine(self.config, params=params)
 
     # serve ingress entry: HTTP payloads from the proxy, or direct dicts
@@ -209,6 +213,11 @@ class LLMServer:
 
     def health_check(self) -> bool:
         return True
+
+    def replica_info(self) -> dict:
+        """Where this replica computes (platform, device_kind,
+        device_count) and its engine counters."""
+        return {**self._device_info, "engine_stats": dict(self.engine.stats)}
 
 
 def build_openai_app(config: LLMConfig, *, num_replicas: int = 1,

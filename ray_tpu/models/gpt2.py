@@ -199,7 +199,7 @@ def _attention_dispatch(config: GPT2Config, q, k, v, mesh: Optional[Mesh]):
         from ray_tpu.parallel.ring_attention import ulysses_attention
 
         return ulysses_attention(q, k, v, mesh=mesh, axis=config.seq_axis, causal=True)
-    return attention(q, k, v, causal=True, impl=impl)
+    return attention(q, k, v, causal=True, impl=impl, mesh=mesh)
 
 
 def _qkv(layer, h):

@@ -212,7 +212,7 @@ def _attention_dispatch(config: LlamaConfig, q, k, v, mesh: Optional[Mesh]):
 
         return ulysses_attention(q, k, v, mesh=mesh, axis=config.seq_axis,
                                  causal=True)
-    return attention(q, k, v, causal=True, impl=impl)
+    return attention(q, k, v, causal=True, impl=impl, mesh=mesh)
 
 
 def _ffn(config: LlamaConfig, layer, x, rng=None):

@@ -2,12 +2,17 @@
 
 The shared library is built from ``src/`` on first import (g++ is part of the
 toolchain; there is no server process to deploy — the arena lives in shm and
-every process coordinates through its header). If the toolchain is missing or
-the build fails, importers fall back to the portable Python implementations.
+every process coordinates through its header). A library is stale when the
+content hash of its sources (and the Makefile) differs from the one recorded
+beside it at build time, so a tree that was copied, checked out or unpacked
+builds from the sources it holds whatever the files' mtimes say. If the
+toolchain is missing or the build fails, importers fall back to the portable
+Python implementations; ``load_report()`` says which happened.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import shutil
@@ -48,13 +53,22 @@ def build_failure(target: str = None):
     )
 
 
+def _source_hash(srcs) -> str:
+    h = hashlib.sha256()
+    for path in sorted([*srcs, os.path.join(_DIR, "Makefile")]):
+        h.update(os.path.basename(path).encode())
+        with open(path, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
 def _lib_needs_build(lib_path: str, srcs) -> bool:
-    if not os.path.exists(lib_path):
+    try:
+        with open(lib_path + ".srchash") as f:
+            recorded = f.read().strip()
+    except OSError:
         return True
-    lib_mtime = os.path.getmtime(lib_path)
-    return any(
-        os.path.exists(s) and os.path.getmtime(s) > lib_mtime for s in srcs
-    )
+    return not os.path.exists(lib_path) or recorded != _source_hash(srcs)
 
 
 def build_lib(make_target: str, lib_path: str, srcs) -> bool:
@@ -69,12 +83,16 @@ def build_lib(make_target: str, lib_path: str, srcs) -> bool:
             fcntl.flock(lockf, fcntl.LOCK_EX)
             if not _lib_needs_build(lib_path, srcs):
                 return True  # another process built while we waited
+            # -B: make's own staleness rule is the mtime one this replaces
             res = subprocess.run(
-                ["make", "-C", _DIR, make_target],
+                ["make", "-B", "-C", _DIR, make_target],
                 capture_output=True,
                 text=True,
                 timeout=120,
             )
+            if res.returncode == 0:
+                with open(lib_path + ".srchash", "w") as f:
+                    f.write(_source_hash(srcs))
     except (OSError, subprocess.TimeoutExpired) as e:
         logger.warning("native build (%s) unavailable: %s", make_target, e)
         return False
@@ -140,6 +158,20 @@ def load_library():
             return None
         _lib = lib
         return _lib
+
+
+def load_report() -> dict:
+    """``{library: loaded}`` for the four native planes, building whatever
+    is stale first — how a caller learns that a plane degraded to its
+    Python fallback."""
+    from ray_tpu.native import ring, sched, xfer
+
+    return {
+        "librt_native.so": load_library() is not None,
+        "librt_sched.so": sched._load_library() is not None,
+        "librt_xfer.so": xfer._load_library() is not None,
+        "librt_ring.so": ring._load_library() is not None,
+    }
 
 
 def _bind_symbols(lib) -> None:

@@ -19,6 +19,7 @@ import ctypes
 import logging
 import os
 import struct
+import threading
 import time
 import weakref
 from typing import List, Optional
@@ -125,6 +126,14 @@ class NativeArenaStore:
         # Insertion-ordered (dict): creation order doubles as the
         # spill-eviction order (oldest first).
         self._created: dict = {}
+        # Zero-copy windows handed out by get_frames that are still alive.
+        # The mapping (and every tmpfs page it has faulted) is released the
+        # moment the store is closed AND this reaches zero — a process that
+        # runs many init()/shutdown() cycles must not keep each session's
+        # prefaulted arena resident until it exits.
+        self._views_lock = threading.RLock()  # finalizers may re-enter
+        self._live_views = 0
+        self._closed = False
 
     # -- store interface ----------------------------------------------------
 
@@ -187,9 +196,9 @@ class NativeArenaStore:
         # block must not be reused while any of them is alive. (Reference:
         # plasma client buffers release on destruction.) atexit=False: at
         # interpreter exit the arena is torn down wholesale anyway.
-        fin = weakref.finalize(
-            arr, self._lib.rt_obj_release, self._h, object_hex.encode()
-        )
+        with self._views_lock:
+            self._live_views += 1
+        fin = weakref.finalize(arr, self._release_view, object_hex.encode())
         fin.atexit = False
         buf = memoryview(arr).cast("B")
         nframes = _HDR_COUNT.unpack_from(buf, 0)[0]
@@ -226,6 +235,25 @@ class NativeArenaStore:
             self.free(hex_)
         if self.created_arena:
             self._lib.rt_arena_unlink(self.name.encode())
+        self._closed = True
+        self._detach_if_idle()
+
+    def _release_view(self, enc: bytes):
+        self._lib.rt_obj_release(self._h, enc)
+        with self._views_lock:
+            self._live_views -= 1
+        self._detach_if_idle()
+
+    def _detach_if_idle(self):
+        """Unmap this process's view once the store is closed and its last
+        zero-copy window is gone. The handle slot may be reused by a later
+        session, so the stale handle is dropped: further calls fail with
+        EBADF."""
+        with self._views_lock:
+            if not self._closed or self._live_views or self._h < 0:
+                return
+            h, self._h, self._base = self._h, -1, None
+        self._lib.rt_arena_detach(h)
 
     # -- helpers ------------------------------------------------------------
 

@@ -120,21 +120,3 @@ class TpuSliceSpec:
         (semantics of the reference's TPU-{pod}-head resource,
         ``tpu.py:634``)."""
         return f"TPU-{self.name}-head"
-
-
-def detect_local_tpu() -> Optional[TpuSliceSpec]:
-    """Best-effort description of locally attached TPU chips."""
-    try:
-        tpus = [d for d in jax.devices() if d.platform == "tpu"]
-    except Exception:
-        return None
-    if not tpus:
-        return None
-    n = len(tpus)
-    kind = getattr(tpus[0], "device_kind", "tpu")
-    gen = "v5e"
-    for tag in ("v6e", "v5p", "v5e", "v5", "v4", "v3", "v2"):
-        if tag in str(kind).lower().replace(" ", ""):
-            gen = tag
-            break
-    return TpuSliceSpec(generation=gen, topology=(n,), hosts=1, chips_per_host=n)

@@ -36,7 +36,21 @@ class ScalingConfig:
         res = dict(self.resources_per_worker or {})
         res.setdefault("CPU", 1.0)
         if self.use_tpu and "TPU" not in res:
-            res["TPU"] = 4.0  # chips per host, the v5e/v6e default
+            # One worker per TPU host: it takes every chip its host
+            # advertises (1 on a one-chip VM, 4 or 8 on a full host).
+            import ray_tpu
+
+            per_host = max(
+                (n["resources"].get("TPU", 0) for n in ray_tpu.nodes()
+                 if n["alive"]),
+                default=0,
+            )
+            if per_host <= 0:
+                raise ValueError(
+                    "ScalingConfig(use_tpu=True) but no alive node "
+                    "advertises a TPU resource"
+                )
+            res["TPU"] = float(per_host)
         return res
 
     @property

@@ -61,6 +61,17 @@ def param_shardings(mesh: Mesh, config, rules=None):
     )
 
 
+def opt_state_shardings(opt, opt_state, p_shard, mesh: Mesh):
+    """Shardings for an optimizer state: its parameter-shaped trees (adam's
+    mu/nu) take the params' shardings, its counters are replicated.
+    ``opt_state`` gives the structure only (arrays, tracers or shapes)."""
+    replicated = NamedSharding(mesh, P())
+    return optax.tree_utils.tree_map_params(
+        opt, lambda _, sharding: sharding, opt_state, p_shard,
+        transform_non_params=lambda _: replicated,
+    )
+
+
 def create_train_state(
     config,
     opt: optax.GradientTransformation,
@@ -83,12 +94,13 @@ def create_train_state(
 
     params = jax.jit(init_fn, out_shardings=p_shard)(key)
 
-    # opt state shardings inferred by jit from the param shardings
-    def opt_init(params):
-        return opt.init(params)
-
-    opt_state = jax.jit(opt_init)(params)
-    step = jnp.zeros((), jnp.int32)
+    # Left to jit the optimizer state lands whole on device 0 — zeros depend
+    # on no sharded input — and that device's memory then bounds the model.
+    opt_shard = opt_state_shardings(
+        opt, jax.eval_shape(opt.init, params), p_shard, mesh
+    )
+    opt_state = jax.jit(opt.init, out_shardings=opt_shard)(params)
+    step = jax.device_put(jnp.zeros((), jnp.int32), NamedSharding(mesh, P()))
     return {"params": params, "opt_state": opt_state, "step": step}
 
 
@@ -113,9 +125,7 @@ def make_train_step(
     moe = getattr(config, "moe", None)
     needs_rng = moe is not None and moe.router_jitter > 0
     p_shard = (
-        param_shardings(mesh, config, rules)
-        if (mesh is not None and rules is not None)
-        else None
+        param_shardings(mesh, config, rules) if mesh is not None else None
     )
 
     def loss(params, batch, rng):
@@ -126,7 +136,7 @@ def make_train_step(
 
     def step_fn(state, batch):
         params = state["params"]
-        if p_shard is not None:
+        if p_shard is not None and rules is not None:
             params = jax.lax.with_sharding_constraint(params, p_shard)
         rng = (
             jax.random.fold_in(jax.random.PRNGKey(seed), state["step"])
@@ -138,6 +148,15 @@ def make_train_step(
             grads, state["opt_state"], state["params"]
         )
         new_params = optax.apply_updates(state["params"], updates)
+        if p_shard is not None:
+            # Pin the new state to the layout create_train_state gave it:
+            # left free, GSPMD re-shards some leaves (layer-norm biases
+            # over fsdp), the state comes back changed and the second step
+            # compiles again.
+            new_params = jax.lax.with_sharding_constraint(new_params, p_shard)
+            new_opt = jax.lax.with_sharding_constraint(
+                new_opt, opt_state_shardings(opt, new_opt, p_shard, mesh)
+            )
         metrics = {
             "loss": loss_val,
             "grad_norm": optax.global_norm(grads),

@@ -86,8 +86,11 @@ def default_jax_train_loop(config: Dict[str, Any]):
     config keys: ``model`` (GPT2Config kwargs), ``mesh`` (MeshConfig kwargs),
     ``optimizer`` (OptimizerConfig kwargs), ``num_steps``, ``batch_size``,
     ``seq_len``, ``checkpoint_every`` (0 = only at end), ``data_seed``.
-    Reports ``{loss, step, tokens_per_sec}`` each step; saves orbax
-    checkpoints; resumes from ``get_checkpoint()`` after failures.
+    Reports ``{loss, step, tokens_per_sec, platform, device_kind,
+    device_count}`` each step (a worker whose node was granted ``TPU`` fails
+    with ``AcceleratorMismatchError`` rather than train on another
+    platform); saves orbax checkpoints; resumes from ``get_checkpoint()``
+    after failures.
     """
     import os
     import tempfile
@@ -97,6 +100,7 @@ def default_jax_train_loop(config: Dict[str, Any]):
     import jax.numpy as jnp
     import numpy as np
 
+    from ray_tpu._private.accelerators.tpu import local_device_info
     from ray_tpu.models import get_preset
     from ray_tpu.parallel.mesh import MeshConfig
     from ray_tpu.train import checkpoint as ckpt_mod
@@ -108,6 +112,7 @@ def default_jax_train_loop(config: Dict[str, Any]):
     )
 
     ctx = get_context()
+    device_info = local_device_info()
     model = config.get("model", {})
     if isinstance(model, str):  # zoo preset, e.g. "gpt2-small" / "llama-1b"
         model_cfg = get_preset(model)
@@ -177,6 +182,7 @@ def default_jax_train_loop(config: Dict[str, Any]):
             "loss": loss,
             "step": step + 1,
             "tokens_per_sec": batch_size * seq_len / dt,
+            **device_info,
         }
         is_ckpt_step = ckpt_every and (step + 1) % ckpt_every == 0
         if is_ckpt_step or step + 1 == num_steps:

@@ -122,7 +122,7 @@ class XlaBackendGroup(XlaCollectiveGroup):
             self.stats["host_fallbacks"] += 1
             return super().allreduce(tensor, opts)
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         values = self._exchange(_to_host(tensor))
@@ -145,7 +145,7 @@ class XlaBackendGroup(XlaCollectiveGroup):
 
             return jax.jit(shard_map(
                 f, mesh=self._mesh, in_specs=P(_AXIS), out_specs=P(),
-                check_rep=False,
+                check_vma=False,
             ))
 
         key = ("allreduce", op, np.asarray(values[0]).shape,
@@ -159,7 +159,7 @@ class XlaBackendGroup(XlaCollectiveGroup):
             self.stats["host_fallbacks"] += 1
             return super().allgather(tensor, opts)
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         values = self._exchange(_to_host(tensor))
@@ -170,7 +170,7 @@ class XlaBackendGroup(XlaCollectiveGroup):
 
             return jax.jit(shard_map(
                 f, mesh=self._mesh, in_specs=P(_AXIS), out_specs=P(),
-                check_rep=False,
+                check_vma=False,
             ))
 
         key = ("allgather", np.asarray(values[0]).shape,
@@ -194,7 +194,7 @@ class XlaBackendGroup(XlaCollectiveGroup):
             self.stats["host_fallbacks"] += 1
             return super().reducescatter(tensor, opts)
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         values = self._exchange(host)
@@ -207,7 +207,7 @@ class XlaBackendGroup(XlaCollectiveGroup):
 
             return jax.jit(shard_map(
                 f, mesh=self._mesh, in_specs=P(_AXIS), out_specs=P(_AXIS),
-                check_rep=False,
+                check_vma=False,
             ))
 
         key = ("reducescatter", host.shape, str(host.dtype))
@@ -222,7 +222,7 @@ class XlaBackendGroup(XlaCollectiveGroup):
             self.stats["host_fallbacks"] += 1
             return super().broadcast(tensor, opts)
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         root = opts.root_rank
@@ -243,7 +243,7 @@ class XlaBackendGroup(XlaCollectiveGroup):
 
             return jax.jit(shard_map(
                 f, mesh=self._mesh, in_specs=P(_AXIS), out_specs=P(),
-                check_rep=False,
+                check_vma=False,
             ))
 
         key = ("broadcast", root, np.asarray(values[root]).shape,

@@ -129,3 +129,54 @@ def test_flash_bwd_kernel_gqa_and_ragged(causal):
     g2 = jax.grad(loss_xla, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4, rtol=2e-4)
+
+
+def test_auto_is_decided_by_platform_and_never_falls_back(monkeypatch):
+    """``auto`` is flash on tpu and xla elsewhere, by platform alone. The
+    platform is decided here: told it is on a TPU, the dispatcher lowers the
+    Mosaic kernel — which this CPU backend refuses — and the refusal must
+    surface, not turn into XLA attention."""
+    from ray_tpu.ops.attention import attention
+
+    q, k, v = _make_qkv(B=1, T=128, H=1, D=128)
+    ref = attention_xla(q, k, v, causal=True)
+    out = attention(q, k, v, causal=True, impl="auto")  # cpu -> xla
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(Exception) as err:
+        jax.block_until_ready(attention(q, k, v, causal=True, impl="auto"))
+    assert not isinstance(err.value, AssertionError)
+
+
+@pytest.mark.parametrize("axes", [
+    {"data": 2, "fsdp": 2, "tensor": 2},
+    {"data": -1},
+])
+def test_flash_under_mesh_runs_per_shard(axes):
+    """On a mesh of several devices the kernel runs under shard_map over
+    batch and heads (GSPMD cannot partition a Mosaic call): same values and
+    grads as dense attention, inside a sharded jit."""
+    from ray_tpu.ops.attention import attention
+
+    mesh = MeshConfig(**axes).build()
+    q, k, v = _make_qkv(B=8, T=64, H=4, D=32)
+    spec = NamedSharding(mesh, P(("data", "fsdp"), None, "tensor", None))
+    q, k, v = (jax.device_put(x, spec) for x in (q, k, v))
+
+    def loss(impl, q, k, v):
+        out = attention(q, k, v, causal=True, impl=impl, mesh=mesh,
+                        block_q=32, block_k=32)
+        return (out * out).sum(), out
+
+    grad = lambda impl: jax.jit(  # noqa: E731
+        jax.value_and_grad(lambda *a: loss(impl, *a), argnums=(0, 1, 2),
+                           has_aux=True)
+    )
+    ((_, out), g_flash) = grad("flash_interpret")(q, k, v)
+    ((_, ref), g_xla) = grad("xla")(q, k, v)
+    assert out.sharding.is_equivalent_to(spec, out.ndim)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+    for a, b in zip(g_flash, g_xla):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-4, rtol=5e-4)

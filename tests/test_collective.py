@@ -131,7 +131,7 @@ def test_ici_product_allreduce_with_negatives():
     """PRODUCT must be exact for negative/zero inputs (no log/exp trick)."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from ray_tpu.util.collective.types import ReduceOp
@@ -142,7 +142,7 @@ def test_ici_product_allreduce_with_negatives():
     f = shard_map(
         lambda xs: col.ici_allreduce(xs, "x", op=ReduceOp.PRODUCT),
         mesh=mesh, in_specs=P("x", None), out_specs=P("x", None),
-        check_rep=False,
+        check_vma=False,
     )
     out = jax.jit(f)(x)
     np.testing.assert_allclose(np.asarray(out), np.full((4, 1), 3.0))
@@ -152,7 +152,7 @@ def test_ici_collectives_in_jit():
     """In-jit collectives under shard_map on the 8-device CPU mesh."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.array(jax.devices()[:4]), ("x",))
@@ -168,7 +168,7 @@ def test_ici_collectives_in_jit():
     f = shard_map(
         body, mesh=mesh, in_specs=P("x", None),
         out_specs=(P("x", None), P(None, None), P("x", None), P("x", None)),
-        check_rep=False,
+        check_vma=False,
     )
     s, g, rs, b = jax.jit(f)(x)
     np.testing.assert_allclose(

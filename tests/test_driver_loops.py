@@ -380,6 +380,37 @@ def test_pusher_shards_slot_affinity(monkeypatch):
         ray_tpu.shutdown()
 
 
+def test_dependent_tasks_pushed_in_one_chunk_do_not_deadlock(monkeypatch):
+    """``z = f(y)`` submitted right behind ``y`` rides the same pushed chunk.
+    A shard pusher used to hold ``y``'s reply until the WHOLE chunk had
+    replied, while ``z`` sat on its node waiting for ``y`` from its owner:
+    on any host with enough cores for the planes to switch on, every task
+    that depended on a pending one hung forever (and with it the tier-1
+    run, from tests/test_core_api.py on). Replies now reach the driver
+    loop before the pusher waits for one that has not arrived."""
+    monkeypatch.setenv("RT_PUSHER_LOOP_SHARDS", "2")
+    monkeypatch.setenv("RT_SUBMIT_PACK_THREAD", "1")
+    ray_tpu.init(num_cpus=4)
+    try:
+        w = worker_mod.global_worker
+        assert len(w._pusher_loops) == 2 and w._pack_plane is not None
+
+        @ray_tpu.remote
+        def add(a, b):
+            return a + b
+
+        x = ray_tpu.put(10)
+        y = add.remote(x, 5)
+        z = add.remote(y, y)
+        chain = z
+        for _ in range(20):
+            chain = add.remote(chain, 1)
+        assert ray_tpu.get([z, chain], timeout=60) == [30, 50]
+        assert w._stats["pusher_shard_affinity_breaks"] == 0
+    finally:
+        ray_tpu.shutdown()
+
+
 def test_submit_pack_faultpoint_degrades_inline(rt_start):
     """driver.submit.pack error/drop = THAT submission packs inline on
     the caller thread; every task still completes and none is lost."""

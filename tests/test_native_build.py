@@ -62,3 +62,31 @@ def test_checked_in_libs_not_stale():
     lib = rt_native.load_library()
     assert rt_native.build_failure() is None, rt_native.build_failure()
     assert lib is not None
+
+
+@pytest.mark.skipif(
+    not rt_native.toolchain_available(), reason="no g++/make toolchain"
+)
+def test_staleness_is_a_content_hash_not_an_mtime(tmp_path, monkeypatch):
+    """A tree that was copied or unpacked carries arbitrary mtimes (and, on
+    the chip machine, ``.so`` files built from other sources): a library is
+    rebuilt exactly when the recorded hash of its sources differs."""
+    build = tmp_path / "native"
+    shutil.copytree(_NATIVE_DIR, build, ignore=shutil.ignore_patterns(
+        "*.so", "*.srchash", "__pycache__", ".build.lock"))
+    monkeypatch.setattr(rt_native, "_DIR", str(build))
+    lib = str(build / "librt_ring.so")
+    srcs = [str(build / "src" / "ring.cc")]
+
+    (build / "librt_ring.so").write_bytes(b"a stale binary, newer than src")
+    assert rt_native._lib_needs_build(lib, srcs)  # newer mtime, no hash
+    assert rt_native.build_lib("librt_ring.so", lib, srcs)
+    assert not rt_native._lib_needs_build(lib, srcs)
+    assert rt_native.build_and_load("librt_ring.so", lib, srcs) is not None
+
+    os.utime(srcs[0])  # touched, same bytes: still fresh
+    assert not rt_native._lib_needs_build(lib, srcs)
+    with open(srcs[0], "a") as f:
+        f.write("\n// edited\n")
+    os.utime(srcs[0], (0, 0))  # edited, but OLDER than the .so: stale
+    assert rt_native._lib_needs_build(lib, srcs)

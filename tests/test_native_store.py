@@ -432,3 +432,36 @@ def test_parallel_copy_covers_tail(extra):
         assert bytes(got) == expect
     finally:
         store.close_all()
+
+
+def _mapped(name: str) -> bool:
+    with open("/proc/self/maps") as f:
+        return any(name.strip("/") in line for line in f)
+
+
+def test_closed_arena_is_unmapped_when_its_last_view_dies():
+    """A process that runs many init()/shutdown() cycles (a test worker)
+    must not keep every session's prefaulted arena mapped until it exits —
+    six such workers once pinned 116 GB of tmpfs and the kernel killed two.
+    A view that outlives close_all() stays readable; the mapping goes with
+    the last one."""
+    import gc
+
+    name = f"/rt_test_{os.getpid()}_{secrets.token_hex(4)}"
+    store = NativeArenaStore(name, capacity=1 << 24)
+    oid = _hex()
+    store.put_frames(oid, [b"z" * 8192])
+    view = store.get_frames(oid, {})[0]
+    assert _mapped(name)
+    store.close_all()
+    assert _mapped(name) and bytes(view[:4]) == b"zzzz"  # still readable
+    assert store.put_frames(_hex(), [b"late"]) is not None  # and writable
+    del view
+    gc.collect()
+    assert not _mapped(name)
+    with pytest.raises(RuntimeError, match="errno 9"):  # EBADF, not a crash
+        store.put_frames(_hex(), [b"after detach"])
+
+    idle = NativeArenaStore(name + "b", capacity=1 << 24)
+    idle.close_all()  # no view outstanding: unmapped at once
+    assert not _mapped(name + "b")

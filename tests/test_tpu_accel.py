@@ -17,12 +17,17 @@ from ray_tpu.util.tpu import (
     slice_placement_group,
 )
 
+_real_chip_device_files = tpu_mod._chip_device_files
+_real_fetch_metadata = tpu_mod._fetch_metadata
+
 
 @pytest.fixture(autouse=True)
 def _no_gce(monkeypatch):
     monkeypatch.setattr(tpu_mod, "_fetch_metadata", lambda *a, **k: None)
+    monkeypatch.setattr(tpu_mod, "_chip_device_files", lambda: [])
     for var in ("TPU_ACCELERATOR_TYPE", "ACCELERATOR_TYPE", "TPU_WORKER_ID",
-                "TPU_CHIPS_PER_HOST_BOUNDS", "TPU_NAME", "TPU_TOPOLOGY"):
+                "TPU_CHIPS_PER_HOST_BOUNDS", "TPU_NAME", "TPU_TOPOLOGY",
+                "TPU_SKIP_MDS_QUERY"):
         monkeypatch.delenv(var, raising=False)
 
 
@@ -30,6 +35,46 @@ def test_no_tpu_detected():
     assert TPUAcceleratorManager.get_current_node_num_accelerators() == 0
     assert detect_node_accelerators() == {}
     assert detect_node_labels() == {}
+
+
+def test_device_files_win_over_the_hosts_description(monkeypatch):
+    """What the v5e chip machine exposes: ONE vfio group (plus the control
+    node) on a VM carved out of a four-chip host whose env still describes
+    the whole host. The chips this machine can open are the device files."""
+    monkeypatch.setattr(tpu_mod, "_chip_device_files", _real_chip_device_files)
+    monkeypatch.setattr(tpu_mod.glob, "glob", lambda pat: {
+        "/dev/accel*": [],
+        "/dev/vfio/*": ["/dev/vfio/3", "/dev/vfio/vfio"],
+    }[pat])
+    monkeypatch.setenv("TPU_CHIPS_PER_HOST_BOUNDS", "2,2,1")
+    monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    monkeypatch.setenv("TPU_WORKER_ID", "0")
+    assert tpu_mod._chip_device_files() == ["/dev/vfio/3"]
+    assert TPUAcceleratorManager.get_current_node_num_accelerators() == 1
+    assert detect_node_accelerators()["TPU"] == 1.0
+
+
+def test_metadata_lookup_cannot_stall_a_sealed_machine(monkeypatch):
+    """No network: ``TPU_SKIP_MDS_QUERY`` means no lookup at all, and
+    without it the first failed lookup is the last one attempted."""
+    import urllib.request
+
+    calls = []
+
+    def no_route(req, timeout=None):
+        calls.append(req.full_url)
+        raise OSError("network unreachable")
+
+    monkeypatch.setattr(urllib.request, "urlopen", no_route)
+    monkeypatch.setattr(tpu_mod, "_fetch_metadata", _real_fetch_metadata)
+    monkeypatch.setattr(tpu_mod, "_metadata_cache", {})
+    monkeypatch.setattr(tpu_mod, "_metadata_unreachable", False)
+    monkeypatch.setenv("TPU_SKIP_MDS_QUERY", "true")
+    assert detect_node_accelerators() == {} and detect_node_labels() == {}
+    assert calls == []
+    monkeypatch.delenv("TPU_SKIP_MDS_QUERY")
+    assert detect_node_accelerators() == {} and detect_node_labels() == {}
+    assert len(calls) == 1
 
 
 def test_detection_from_env(monkeypatch):
@@ -130,10 +175,15 @@ def test_slice_placement_group_never_split():
 
 
 def test_slice_placement_group_unsatisfiable():
+    from ray_tpu.train import ScalingConfig
+
     ray_tpu.init(num_cpus=2)
     try:
         spg = slice_placement_group("v5e-16", timeout=2)
         assert not spg.ready(timeout=2)
+        # a lease nothing can grant is refused up front, not left hanging
+        with pytest.raises(ValueError, match="advertises a TPU"):
+            ScalingConfig(use_tpu=True).worker_resources()
     finally:
         ray_tpu.shutdown()
 
@@ -171,5 +221,29 @@ def test_init_autodetects_tpu_resources(monkeypatch):
         total = ray_tpu.cluster_resources()
         assert total.get("TPU") == 8.0
         assert total.get("TPU-v5e-8-head") == 1.0
+    finally:
+        ray_tpu.shutdown()
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_use_tpu_takes_the_chips_a_host_advertises(monkeypatch, chips):
+    """``ScalingConfig(use_tpu=True)`` asks per worker for what one host of
+    the cluster has — 1 on a one-chip machine, where the old literal 4
+    could never be leased."""
+    from ray_tpu.train import ScalingConfig
+
+    monkeypatch.setattr(
+        tpu_mod, "_chip_device_files",
+        lambda: [f"/dev/vfio/{i}" for i in range(chips)],
+    )
+    ray_tpu.init(num_cpus=2, num_nodes=2)
+    try:
+        assert ray_tpu.cluster_resources()["TPU"] == float(chips)  # node 0
+        res = ScalingConfig(use_tpu=True).worker_resources()
+        assert res == {"CPU": 1.0, "TPU": float(chips)}
+        explicit = ScalingConfig(
+            use_tpu=True, resources_per_worker={"TPU": 2}
+        ).worker_resources()
+        assert explicit["TPU"] == 2
     finally:
         ray_tpu.shutdown()

@@ -1,0 +1,92 @@
+"""The main path's kernels compile for the real chip — without the chip.
+
+The TPU compiler is installed here and compiles for a device that is
+described (``v5e:2x2``) and not attached, so a Mosaic kernel the chip would
+refuse (misaligned slice, too much VMEM, unpartitionable call) fails here at
+no chip time. Nothing runs: this says nothing about results or speed.
+
+The topology is described inside a module-scoped fixture of THIS file only
+(never at import, never autouse, never in conftest): describing it loads the
+TPU library, which one process at a time may hold, and xdist workers import
+every test file. Keep every such test in this one file.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import (
+    Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding,
+)
+
+from ray_tpu.ops.attention import attention, flash_attention
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no libtpu / lock held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described device is written to the persistent cache
+    # but cannot be read back without a chip; keep these out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _flash(q, k, v):
+    return flash_attention(q, k, v, True, 512, 512, False)
+
+
+def _flash_grads(q, k, v):
+    return jax.grad(
+        lambda q, k, v: _flash(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2),
+    )(q, k, v)
+
+
+# GPT-2-small at the smoke/bench batch, GPT-2-medium, and head-dim 128.
+WIDTHS = [(32, 1024, 12, 64), (16, 1024, 16, 64), (4, 2048, 16, 128)]
+
+
+@pytest.mark.parametrize("shape", WIDTHS, ids=str)
+@pytest.mark.parametrize("fn,kernels", [(_flash, 1), (_flash_grads, 3)],
+                         ids=["fwd", "fwd_bwd"])
+def test_flash_attention_compiles_for_v5e(one_chip, shape, fn, kernels):
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(fn).lower(x, x, x).compile()
+    assert compiled.as_text().count("tpu_custom_call") == kernels
+
+
+def test_flash_attention_compiles_inside_a_sharded_jit(topo):
+    """The ``--chips 4`` path: on an fsdp=2 x tensor=2 mesh the dispatcher
+    wraps the kernel in shard_map (GSPMD cannot partition a Mosaic call),
+    each chip taking its shard of batch and heads."""
+    import numpy as np
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("fsdp", "tensor"))
+    spec = NamedSharding(mesh, P("fsdp", None, "tensor", None))
+    x = jax.ShapeDtypeStruct((32, 1024, 12, 64), jnp.bfloat16, sharding=spec)
+    fn = functools.partial(attention, causal=True, impl="flash", mesh=mesh)
+    compiled = jax.jit(fn).lower(x, x, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.output_shardings.is_equivalent_to(spec, 4)
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        jax.jit(_flash).lower(x, x, x).compile()
