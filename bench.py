@@ -96,6 +96,20 @@ def require_tpu():
     return dev
 
 
+def _release_device_memory() -> int:
+    """Drop what a finished leg left on the chip — its compiled programs
+    and any array only a cycle keeps alive — and return the bytes still in
+    use. The legs share one process (one process per chip), so the next
+    one needs the HBM the last one held."""
+    import gc
+
+    import jax
+
+    gc.collect()
+    jax.clear_caches()
+    return (jax.devices()[0].memory_stats() or {}).get("bytes_in_use", 0)
+
+
 def _time_train_steps(config, B: int, T: int, steps: int):
     """(tokens/s, final loss) of ``steps`` train steps of ``config`` on one
     repeated batch, after a compile+warm-up step."""
@@ -155,9 +169,11 @@ def bench_train_tokens_per_sec(quick: bool = False):
         "train_backend": dev.platform,
         "train_device_kind": dev.device_kind,
     }
+    _release_device_memory()
     roof = measure_achievable_tflops()
     out["tpu_matmul_tflops_measured"] = roof / 1e12
     out["gpt2_train_mfu_vs_achievable"] = flops / roof
+    _release_device_memory()
     ref = bench_reference_jax_step(quick=quick)
     out.update(ref)
     if ref:
@@ -165,6 +181,7 @@ def bench_train_tokens_per_sec(quick: bool = False):
             tokens_per_sec / ref["gpt2_reference_impl_tokens_per_sec"]
         )
     if not quick:
+        out["hbm_bytes_in_use_before_medium"] = _release_device_memory()
         out.update(bench_train_medium())
     return out
 
@@ -706,7 +723,13 @@ def main():
     extra = {}
     if not args.no_train:
         # A train leg that raises (no chip, a refused compile, an OOM) ends
-        # the run with a traceback and a non-zero exit code.
+        # the run with a traceback and a non-zero exit code; what the core
+        # legs measured is on stderr by then, stdout keeps its ONE line.
+        if not args.train_only:
+            import sys
+
+            print("[bench] core legs:", json.dumps(core), file=sys.stderr,
+                  flush=True)
         extra = bench_train_tokens_per_sec(quick=args.quick)
 
     value = core["single_client_tasks_async_per_s"]

@@ -10,12 +10,13 @@ Chips are counted from what this machine exposes to the TPU runtime — the
 device files libtpu opens — before anything that merely describes the host
 (``TPU_*`` env vars, GCE metadata): a one-chip VM carved out of a four-chip
 host exports the host's ``TPU_CHIPS_PER_HOST_BOUNDS=2,2,1`` but only one
-``/dev/vfio/<n>``. Detection never touches JAX (the process that detects is
-the driver, which must stay off the chip) and never needs the network: the
-metadata server is the last resort, skipped under ``TPU_SKIP_MDS_QUERY`` and
-given up on after its first failed lookup. Every layer is injectable for
-tests (the reference mocks the same seams in
-``tests/accelerators/test_tpu.py``).
+``/dev/vfio/<n>``, so that variable cannot lower or raise a count the device
+files give. A vfio group that sysfs attributes to another vendor (a GPU or
+NIC passed through) is not a chip. Detection never touches JAX (the process
+that detects is the driver, which must stay off the chip) and never needs
+the network: the metadata server is the last resort and is given up on
+after its first failed lookup. Every layer is injectable for tests (the
+reference mocks the same seams in ``tests/accelerators/test_tpu.py``).
 """
 from __future__ import annotations
 
@@ -49,13 +50,12 @@ _metadata_unreachable = False
 def _fetch_metadata(key: str, timeout: float = 1.0) -> Optional[str]:
     """GCE metadata attribute (None off-GCE), cached per process. One
     failed lookup marks the server unreachable for the life of the process,
-    so a sealed machine pays at most one timeout, and none when the image
-    says ``TPU_SKIP_MDS_QUERY``. Patched in tests (patched versions bypass
-    the cache)."""
+    so a sealed machine pays at most one timeout. Patched in tests (patched
+    versions bypass the cache)."""
     global _metadata_unreachable
     if key in _metadata_cache:
         return _metadata_cache[key]
-    if _metadata_unreachable or os.environ.get("TPU_SKIP_MDS_QUERY"):
+    if _metadata_unreachable:
         return None
     import urllib.error
     import urllib.request
@@ -75,13 +75,36 @@ def _fetch_metadata(key: str, timeout: float = 1.0) -> Optional[str]:
     return value
 
 
+_GOOGLE_PCI_VENDOR = "0x1ae0"
+
+
+def _vfio_group_vendors(group: str) -> List[str]:
+    """PCI vendor ids of the devices in vfio/iommu group ``group``, as
+    sysfs gives them; empty where sysfs does not say."""
+    vendors = []
+    pattern = f"/sys/kernel/iommu_groups/{group}/devices/*/vendor"
+    for path in glob.glob(pattern):
+        with open(path) as f:
+            vendors.append(f.read().strip().lower())
+    return vendors
+
+
 def _chip_device_files() -> List[str]:
     """Device files the TPU runtime opens, one per chip: ``/dev/accel<n>``
     (v4 and older VM images) or ``/dev/vfio/<n>`` (v5e/v6e;
-    ``/dev/vfio/vfio`` is the container control node, not a chip)."""
-    return glob.glob("/dev/accel*") or [
-        p for p in glob.glob("/dev/vfio/*") if not p.endswith("/vfio")
-    ]
+    ``/dev/vfio/vfio`` is the container control node, not a chip). A vfio
+    group counts unless sysfs names its devices and none is Google's."""
+    chips = glob.glob("/dev/accel*")
+    if chips:
+        return chips
+    for path in glob.glob("/dev/vfio/*"):
+        group = os.path.basename(path)
+        if group == "vfio":
+            continue
+        vendors = _vfio_group_vendors(group)
+        if not vendors or _GOOGLE_PCI_VENDOR in vendors:
+            chips.append(path)
+    return chips
 
 
 def local_device_info() -> Dict[str, object]:

@@ -3002,8 +3002,11 @@ class CoreWorker:
             if ev is not None:
                 ev.clear()
         # Borrows were already released when the first execution replied; a
-        # second release would corrupt the counts.
-        header = dict(rec["header"], borrows=[])
+        # second release would corrupt the counts. A fresh correlation id:
+        # under the first push's (the task id) the receiver's dedup would
+        # replay the recorded outcome — the lost object's meta — and never
+        # run the task again.
+        header = dict(rec["header"], borrows=[], corr=os.urandom(8).hex())
         self._enqueue_dispatch(
             self._dispatch_task_fast,
             (header, rec["frames"], rec["resources"], rec["strategy"], 2),
@@ -7462,6 +7465,10 @@ class CoreWorker:
                     await self.gcs.close()
                 if self.server is not None:
                     await self.server.close()
+                if self.head is not None:
+                    # The in-process head ends with this loop; what it has
+                    # buffered (export events) is persisted by its close().
+                    await asyncio.wait_for(self.head.close(), timeout=2.0)
             except Exception:
                 pass
             if self._shm is not None:

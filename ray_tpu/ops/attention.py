@@ -466,11 +466,19 @@ def attention(
     if mesh is not None and mesh.size > 1:
         from jax.sharding import PartitionSpec as P
 
-        batch = tuple(a for a in ("data", "fsdp") if a in mesh.axis_names)
-        heads = "tensor" if "tensor" in mesh.axis_names else None
+        # Inside someone else's shard_map (pipeline_apply is manual over
+        # ``stage``) the mesh comes from the context and only the axes that
+        # are still automatic can be taken; taking all of them leaves no axis
+        # for GSPMD to partition the call over.
+        ctx = jax.sharding.get_abstract_mesh()
+        held = set(ctx.manual_axes)
+        free = set(mesh.axis_names) - held
+        batch = tuple(a for a in ("data", "fsdp") if a in free)
+        heads = "tensor" if "tensor" in free else None
         spec = P(batch or None, None, heads, None)
-        kernel = jax.shard_map(
-            kernel, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_vma=False,
-        )
+        if free:
+            kernel = jax.shard_map(
+                kernel, mesh=None if held else mesh, axis_names=free,
+                in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
+            )
     return kernel(q, k, v)

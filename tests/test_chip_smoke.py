@@ -71,11 +71,14 @@ def test_sharded_phase_at_toy_size(records):
     device = chip_smoke.sharded_phase(
         TOY, expected_platform="cpu", attention_impl="flash_interpret",
         mesh={"data": 2, "fsdp": 2, "tensor": 2}, batch_size=8, num_steps=4,
-        seed=0, emit=records.append,
+        parity_shape=(4, 256, 2, 64), seed=0, emit=records.append,
     )
     assert device["count"] == 8
     run = next(r for r in records if "sharded_losses" in r)
     assert run["max_loss_diff"] <= chip_smoke.SHARDED_LOSS_TOL
+    assert run["attention_parity"]["mesh"] == {"data": 2, "fsdp": 2,
+                                               "tensor": 2}
+    assert run["attention_parity"]["devices_holding_out"] == 8
     assert records[-1]["ok"] is True
 
 

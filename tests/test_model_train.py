@@ -1,4 +1,6 @@
 """GPT-2 model + SPMD train step tests on the 8-device CPU mesh."""
+import math
+
 import numpy as np
 import pytest
 
@@ -120,6 +122,45 @@ def test_pipeline_forward_matches_sequential():
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), atol=2e-4, rtol=2e-4
     )
+
+
+@pytest.mark.parametrize("axes", [
+    {"data": 2, "stage": 2},
+    {"data": 1, "stage": 4},
+    {"data": 2, "stage": 2, "tensor": 2},
+], ids=lambda a: "x".join(f"{k}{v}" for k, v in a.items()))
+def test_pipeline_with_the_flash_kernel(axes):
+    """What ``auto`` resolves to on a TPU: the dispatcher's shard_map nested
+    inside ``pipeline_apply``'s, which already holds ``stage``. Forward and
+    the whole pipelined train step against XLA attention."""
+    n = math.prod(axes.values())
+    mesh = MeshConfig(**axes).build(jax.devices()[:n])
+    kw = dict(
+        vocab_size=512, max_seq_len=128, num_layers=4, num_heads=2,
+        embed_dim=64, dtype=jnp.float32, remat=False,
+    )
+    flash = gpt2.GPT2Config(attention_impl="flash_interpret", **kw)
+    dense = gpt2.GPT2Config(attention_impl="xla", **kw)
+    params = gpt2.init_params(flash, jax.random.PRNGKey(2))
+    batch = _batch(B=8, T=128, vocab=512)
+    tokens = batch["tokens"][:, :-1]
+    ref, _ = gpt2.forward(params, tokens, dense)
+    out, _ = jax.jit(
+        lambda p, t: gpt2.forward_pipelined(p, t, flash, mesh,
+                                            num_microbatches=4)
+    )(params, tokens)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(ref), atol=2e-4, rtol=2e-4
+    )
+    opt = OptimizerConfig().build()
+    losses = {}
+    for cfg in (flash, dense):
+        state = create_train_state(cfg, opt, jax.random.PRNGKey(0), mesh)
+        step = make_train_step(cfg, opt, mesh, pipeline_microbatches=4)
+        for _ in range(2):  # the second loss has been through the backward
+            state, m = step(state, batch)
+        losses[cfg.attention_impl] = float(m["loss"])
+    assert abs(losses["flash_interpret"] - losses["xla"]) < 1e-4, losses
 
 
 def test_moe_layer_routing():

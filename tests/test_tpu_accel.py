@@ -26,8 +26,7 @@ def _no_gce(monkeypatch):
     monkeypatch.setattr(tpu_mod, "_fetch_metadata", lambda *a, **k: None)
     monkeypatch.setattr(tpu_mod, "_chip_device_files", lambda: [])
     for var in ("TPU_ACCELERATOR_TYPE", "ACCELERATOR_TYPE", "TPU_WORKER_ID",
-                "TPU_CHIPS_PER_HOST_BOUNDS", "TPU_NAME", "TPU_TOPOLOGY",
-                "TPU_SKIP_MDS_QUERY"):
+                "TPU_CHIPS_PER_HOST_BOUNDS", "TPU_NAME", "TPU_TOPOLOGY"):
         monkeypatch.delenv(var, raising=False)
 
 
@@ -45,7 +44,7 @@ def test_device_files_win_over_the_hosts_description(monkeypatch):
     monkeypatch.setattr(tpu_mod.glob, "glob", lambda pat: {
         "/dev/accel*": [],
         "/dev/vfio/*": ["/dev/vfio/3", "/dev/vfio/vfio"],
-    }[pat])
+    }.get(pat, []))
     monkeypatch.setenv("TPU_CHIPS_PER_HOST_BOUNDS", "2,2,1")
     monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
     monkeypatch.setenv("TPU_WORKER_ID", "0")
@@ -54,9 +53,25 @@ def test_device_files_win_over_the_hosts_description(monkeypatch):
     assert detect_node_accelerators()["TPU"] == 1.0
 
 
+def test_a_vfio_group_of_another_vendor_is_not_a_chip(monkeypatch):
+    """A GPU or NIC passed through with vfio has a ``/dev/vfio/<n>`` too.
+    Where sysfs names a group's devices, only Google's count; a group sysfs
+    says nothing about (group 5) still counts."""
+    monkeypatch.setattr(tpu_mod, "_chip_device_files", _real_chip_device_files)
+    monkeypatch.setattr(tpu_mod.glob, "glob", lambda pat: {
+        "/dev/accel*": [],
+        "/dev/vfio/*": ["/dev/vfio/3", "/dev/vfio/4", "/dev/vfio/5",
+                        "/dev/vfio/vfio"],
+    }.get(pat, []))
+    monkeypatch.setattr(tpu_mod, "_vfio_group_vendors", lambda group: {
+        "3": ["0x1ae0"], "4": ["0x10de"], "5": [],
+    }[group])
+    assert tpu_mod._chip_device_files() == ["/dev/vfio/3", "/dev/vfio/5"]
+    assert TPUAcceleratorManager.get_current_node_num_accelerators() == 2
+
+
 def test_metadata_lookup_cannot_stall_a_sealed_machine(monkeypatch):
-    """No network: ``TPU_SKIP_MDS_QUERY`` means no lookup at all, and
-    without it the first failed lookup is the last one attempted."""
+    """No network: the first failed lookup is the last one attempted."""
     import urllib.request
 
     calls = []
@@ -69,10 +84,7 @@ def test_metadata_lookup_cannot_stall_a_sealed_machine(monkeypatch):
     monkeypatch.setattr(tpu_mod, "_fetch_metadata", _real_fetch_metadata)
     monkeypatch.setattr(tpu_mod, "_metadata_cache", {})
     monkeypatch.setattr(tpu_mod, "_metadata_unreachable", False)
-    monkeypatch.setenv("TPU_SKIP_MDS_QUERY", "true")
     assert detect_node_accelerators() == {} and detect_node_labels() == {}
-    assert calls == []
-    monkeypatch.delenv("TPU_SKIP_MDS_QUERY")
     assert detect_node_accelerators() == {} and detect_node_labels() == {}
     assert len(calls) == 1
 

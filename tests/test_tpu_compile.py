@@ -90,3 +90,22 @@ def test_flash_attention_compiles_inside_a_sharded_jit(topo):
     assert compiled.output_shardings.is_equivalent_to(spec, 4)
     with pytest.raises(NotImplementedError, match="shard_map"):
         jax.jit(_flash).lower(x, x, x).compile()
+
+
+def test_flash_attention_compiles_inside_a_pipeline_stage(topo):
+    """``pipeline_apply`` is a shard_map that holds ``stage``; the
+    dispatcher's own shard_map nests inside it on the axes still free
+    (here ``data``), so a pipelined step keeps the Mosaic kernel."""
+    import numpy as np
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("stage", "data"))
+    spec = NamedSharding(mesh, P("data", None, None, None))
+    x = jax.ShapeDtypeStruct((32, 1024, 12, 64), jnp.bfloat16, sharding=spec)
+    stage = jax.shard_map(
+        functools.partial(attention, causal=True, impl="flash", mesh=mesh),
+        mesh=mesh, axis_names={"stage"}, in_specs=(P(), P(), P()),
+        out_specs=P(), check_vma=False,
+    )
+    compiled = jax.jit(stage).lower(x, x, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.output_shardings.is_equivalent_to(spec, 4)
