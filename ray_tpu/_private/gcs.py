@@ -865,9 +865,12 @@ class HeadService:
             for k in ("action", "top", "duration_s", "hz", "logdir")
             if k in h
         }
+        # a profiler capture is written out after its duration_s, which on
+        # a loaded chip host takes far longer than the capture itself
+        slack = 300 if method == "xla_profile" else 30
         hh, _ = await asyncio.wait_for(
             node.conn.call(method, fwd),
-            timeout=max(float(h.get("duration_s") or 0) + 30, 30),
+            timeout=float(h.get("duration_s") or 0) + slack,
         )
         # strip the forwarded reply's RPC envelope fields
         return {k: v for k, v in hh.items() if k not in ("i", "r")}, []

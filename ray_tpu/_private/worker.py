@@ -1213,7 +1213,6 @@ class CoreWorker:
             or h.get("argrefs")
             or h.get("borrows")
             or h.get("renv")
-            or h.get("trace")
         ):
             return False
         fn = self.fn_cache.get(h["fkey"])
@@ -1279,7 +1278,6 @@ class CoreWorker:
                 or eh.get("argrefs")
                 or eh.get("borrows")
                 or eh.get("renv")
-                or eh.get("trace")
             ):
                 leftovers.append((h, frames))
                 continue
@@ -1411,7 +1409,6 @@ class CoreWorker:
             h.get("nret", 1) != 1
             or h.get("argrefs")
             or h.get("borrows")
-            or h.get("trace")
             or h.get("cg")
             or h.get("method") == "__rt_apply__"
         )
@@ -3646,10 +3643,6 @@ class CoreWorker:
             }
         if an:
             header["an"] = an
-        from ray_tpu.util.tracing import tracing_helper
-
-        if tracing_helper.enabled():
-            header["trace"] = tracing_helper.inject_context()
         if streaming:
             # A re-executed generator would re-emit items: no retries.
             max_retries = 0
@@ -6464,8 +6457,6 @@ class CoreWorker:
         loop = asyncio.get_running_loop()
 
         def run():
-            from ray_tpu.util.tracing import tracing_helper
-
             renv = h.get("renv") or {}
             tid = TaskID.from_hex(h["tid"])
             self.current_task_id.value = tid
@@ -6479,15 +6470,11 @@ class CoreWorker:
                 # inside the venv/conda/container child — the parent
                 # process must stay unpolluted.
                 try:
-                    with tracing_helper.span(
-                        f"task::{h.get('name', 'task')}", h.get("trace"),
-                        {"task_id": h["tid"], "node_id": self.node_id},
-                    ):
-                        return self._run_in_env(
-                            renv, fn, args, kwargs,
-                            owner=tuple(h.get("owner") or ()),
-                            retriable=h.get("retries", 0) > 0,
-                        )
+                    return self._run_in_env(
+                        renv, fn, args, kwargs,
+                        owner=tuple(h.get("owner") or ()),
+                        retriable=h.get("retries", 0) > 0,
+                    )
                 except Exception as e:
                     return False, (e, traceback.format_exc())
             try:
@@ -6495,11 +6482,7 @@ class CoreWorker:
             except Exception as e:
                 return False, (e, traceback.format_exc())
             try:
-                with tracing_helper.span(
-                    f"task::{h.get('name', 'task')}", h.get("trace"),
-                    {"task_id": h["tid"], "node_id": self.node_id},
-                ):
-                    return True, fn(*args, **kwargs)
+                return True, fn(*args, **kwargs)
             except Exception as e:
                 return False, (e, traceback.format_exc())
             finally:

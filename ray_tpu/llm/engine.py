@@ -12,9 +12,18 @@ split, KV cache management. TPU-first redesign instead of a port:
   and leave the running batch without recompiling (the "continuous" part).
 - Sampling happens host-side on the [B, V] logits of the tick (greedy /
   temperature / top-k), which keeps the compiled program sampling-agnostic.
+
+What the loop thread does is in the profiler's own trace, on the device's
+timeline: ``engine.tick`` (children ``.pack``, ``.dispatch``, ``.fetch``,
+``.sample``), ``engine.admit`` (children ``engine.prefill.dispatch``,
+``.fetch``, ``.sample``, ``engine.insert``), ``engine.finish`` and
+``engine.idle`` are ``jax.profiler.TraceAnnotation`` spans, inert unless a
+capture runs (``rt profile --xla``). Their arguments are the counters of
+that boundary, and ``stats`` sums the same quantities with no capture.
 """
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
 import time
@@ -26,6 +35,7 @@ import numpy as np
 
 from ray_tpu._private.backoff import Backoff
 from ray_tpu.llm.config import LLMConfig, load_tokenizer
+from ray_tpu.util.debug import compile_count
 
 
 @dataclass
@@ -51,9 +61,27 @@ class GenerationResult(list):
     """Generated token ids; quacks as the plain list older callers expect,
     with per-token logprob entries riding along when requested."""
 
-    def __init__(self, token_ids, logprobs=None):
+    def __init__(self, token_ids, logprobs=None, finish_reason=""):
         super().__init__(token_ids)
         self.logprobs = logprobs or []
+        # how the answer ended: length | eos | stop | context
+        self.finish_reason = finish_reason
+
+
+class TokenStream:
+    """What ``submit_stream`` returns: an iterator over the generated token
+    ids that knows, once exhausted, how the answer ended."""
+
+    finish_reason = ""
+
+    def __init__(self, tokens):
+        self._tokens = tokens(self)
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        return next(self._tokens)
 
 
 @dataclass
@@ -70,6 +98,21 @@ class _Slot:
     logprobs: List[dict] = field(default_factory=list)
     rng: Optional[Any] = None  # per-request RandomState when seed given
     stream_q: Optional[Any] = None  # queue.Queue for token streaming
+    rid: str = ""  # the id the spans of this request share
+    submitted: float = 0.0  # time.monotonic() at submit
+    first_token_ms: float = 0.0  # submit to the first token, at admission
+
+
+@dataclass
+class _Pending:
+    """A submitted request waiting for a slot."""
+
+    kind: str  # "prompt" | "prefilled"
+    payload: Any  # prompt ids | the transferred prefill state
+    params: SamplingParams
+    future: Future
+    rid: str
+    submitted: float
 
 
 class DecodeEngine:
@@ -177,11 +220,21 @@ class DecodeEngine:
         from collections import OrderedDict
 
         self._prefix_cache: "OrderedDict[tuple, dict]" = OrderedDict()
+        # all numeric: replica_info() hands them out and a reader takes
+        # the difference of every key between two moments
         self.stats = {
             "requests": 0, "tokens_generated": 0, "ticks": 0,
             "prefix_hits": 0, "prefix_partial_hits": 0,
             "spec_proposed": 0, "spec_accepted": 0,
+            # sums at the boundaries the spans mark: submit to admission,
+            # the admissions themselves, active slots over ticks
+            "queue_wait_s": 0.0, "admit_s": 0.0, "slot_ticks": 0,
+            "finished_length": 0, "finished_eos": 0, "finished_stop": 0,
+            "finished_context": 0,
+            "compiles": compile_count(),  # of the process, not the engine
         }
+        self._span = jax.profiler.TraceAnnotation
+        self._rid_seq = itertools.count(1)
 
     # ------------------------------------------------------------- sampling
 
@@ -315,12 +368,14 @@ class DecodeEngine:
             self._prefix_cache.popitem(last=False)
 
     def _prefill_locked(self, prompt_ids, params, rng=None):
-        """(slot_cache jax pytree, first_token, first_logprob). Caller
-        holds the lock.
+        """(slot_cache jax pytree, first_token, first_logprob, how). Caller
+        holds the lock. ``how`` is the admission span's ``bucket`` (0: no
+        program ran) and ``prefix`` (none | partial | exact).
         Consults the prefix cache: an exact hit skips the model entirely; a
         strict-prefix hit prefills only the tail from the cached KV state."""
         import jax.numpy as jnp
 
+        span = self._span
         n = len(prompt_ids)
         self._bucket(n)  # uniform length limit: acceptance must not depend
         # on transient prefix-cache residency
@@ -331,10 +386,11 @@ class DecodeEngine:
         )
         if entry is not None and matched == n:
             self.stats["prefix_hits"] += 1
-            first, lp = self._sample(
-                entry["logits_row"], params, prompt_ids, (), rng
-            )
-            return entry["cache"], first, lp
+            with span("engine.prefill.sample"):
+                first, lp = self._sample(
+                    entry["logits_row"], params, prompt_ids, (), rng
+                )
+            return entry["cache"], first, lp, {"bucket": 0, "prefix": "exact"}
         if entry is not None and (
             matched + self._bucket(n - matched) > self.config.max_seq_len
         ):
@@ -343,114 +399,140 @@ class DecodeEngine:
             entry, matched = None, 0
         if entry is not None:
             self.stats["prefix_partial_hits"] += 1
-            base = matched
-            rem = prompt_ids[matched:]
-            Tpad = self._bucket(len(rem))
+        # base > 0 = continuation: only the prompt's tail runs
+        base, rem = matched, prompt_ids[matched:]
+        Tpad = self._bucket(len(rem))
+        with span("engine.prefill.dispatch"):
             toks = np.zeros((1, Tpad), np.int32)
             toks[0, : len(rem)] = rem
             logits, cache1 = self._prefill(
-                self.params, jnp.asarray(toks), entry["cache"],
-                jnp.full((1,), matched, jnp.int32),
+                self.params, jnp.asarray(toks),
+                entry["cache"] if entry is not None
+                else self._empty_slot_cache(),
+                jnp.full((1,), base, jnp.int32),
             )
+        with span("engine.prefill.fetch"):
+            # the wait for the program and its [1, bucket, V] logits' way
+            # to the host
             logits_np = np.asarray(logits)[0]
-            row = logits_np[len(rem) - 1]
-        else:
-            base = 0
-            Tpad = self._bucket(n)
-            toks = np.zeros((1, Tpad), np.int32)
-            toks[0, :n] = prompt_ids
-            logits, cache1 = self._prefill(
-                self.params, jnp.asarray(toks), self._empty_slot_cache(),
-                jnp.zeros((1,), jnp.int32),
-            )
-            logits_np = np.asarray(logits)[0]
-            row = logits_np[n - 1]
         self._prefix_store_locked(prompt_ids, cache1, logits_np, base)
-        first, lp = self._sample(row, params, prompt_ids, (), rng)
-        return cache1, first, lp
+        with span("engine.prefill.sample"):
+            first, lp = self._sample(
+                logits_np[len(rem) - 1], params, prompt_ids, (), rng
+            )
+        return cache1, first, lp, {
+            "bucket": Tpad, "prefix": "partial" if base else "none"}
 
-    def _activate_slot_locked(self, b, cache1, first, prompt_len, params,
-                              fut, prompt_ids=(), first_lp=None, rng=None):
-        self._cache = self._insert(self._cache, cache1, b)
+    def _activate_slot_locked(self, b, cache1, first, req: _Pending,
+                              prompt_len, prompt_ids=(), first_lp=None,
+                              rng=None):
+        with self._span("engine.insert"):
+            self._cache = self._insert(self._cache, cache1, b)
         slot = self._slots[b]
         slot.active = True
         slot.token_ids = [first]
         slot.prompt_len = prompt_len
-        slot.params = params
+        slot.params = req.params
         slot.produced = 1
-        slot.future = fut
+        slot.future = req.future
         slot.last_token = first
         slot.length = prompt_len
         slot.prompt_ids = list(prompt_ids)
         slot.logprobs = [first_lp] if first_lp is not None else []
         slot.rng = rng
-        slot.stream_q = getattr(fut, "_rt_stream_q", None)
+        slot.stream_q = getattr(req.future, "_rt_stream_q", None)
+        slot.rid, slot.submitted = req.rid, req.submitted
         if slot.stream_q is not None:
             slot.stream_q.put(first)
+        slot.first_token_ms = (time.monotonic() - req.submitted) * 1e3
         self.stats["requests"] += 1
         self._finish_if_done_locked(b)
 
     def _admit_locked(self):
-        import jax.numpy as jnp
-
         free = [i for i, s in enumerate(self._slots) if not s.active]
         while free and not self._pending.empty():
             try:
-                item = self._pending.get_nowait()
+                req = self._pending.get_nowait()
             except queue.Empty:
                 break
             b = free.pop(0)
+            t0 = time.monotonic()
+            queued = t0 - req.submitted
             try:
-                rng = None
-                if item[0] == "prefilled":
-                    # PD disaggregation: the prompt's KV was computed by a
-                    # prefill server; insert its transferred cache directly.
-                    _, prefilled, params, fut = item
-                    cache1 = {
-                        k: jnp.asarray(v)
-                        for k, v in prefilled["cache"].items()
-                    }
-                    first = int(prefilled["first_token"])
-                    prompt_len = int(prefilled["prompt_len"])
-                    prompt_ids = tuple(prefilled.get("prompt_ids", ()))
-                    first_lp = prefilled.get("first_logprob")
-                    if params.seed is not None:
-                        rng = self._rng_for(params)
-                        if params.temperature > 0:
-                            # the prefill server consumed one draw from
-                            # this seed sampling the first token; skip it
-                            # or token 2 reuses token 1's random value
-                            rng.random_sample()
-                else:
-                    _, prompt_ids, params, fut = item
-                    if params.seed is not None:
-                        rng = self._rng_for(params)
-                    cache1, first, first_lp = self._prefill_locked(
-                        prompt_ids, params, rng
-                    )
-                    prompt_len = len(prompt_ids)
-                if prompt_len <= 0:
-                    raise ValueError("prompt must be non-empty")
-                self._activate_slot_locked(
-                    b, cache1, first, prompt_len, params, fut,
-                    prompt_ids=prompt_ids, first_lp=first_lp, rng=rng,
-                )
+                with self._span(
+                    "engine.admit", rid=req.rid,
+                    queued_ms=round(queued * 1e3, 3),
+                    prompt_tokens=self._prompt_len(req), slot=b,
+                ) as admit:
+                    admit.set_metadata(**self._admit_one_locked(req, b))
             except Exception as e:
                 # Admission failure (bad bucket, mismatched transferred
                 # cache shapes, ...) surfaces on the caller's future, never
                 # on other slots or the scheduler loop.
-                fut.set_exception(e)
+                req.future.set_exception(e)
                 free.insert(0, b)
-                continue
+            # an admission holds every decoding slot for its whole length
+            self.stats["queue_wait_s"] += queued
+            self.stats["admit_s"] += time.monotonic() - t0
+        self.stats["compiles"] = compile_count()
+
+    @staticmethod
+    def _prompt_len(req: _Pending) -> int:
+        return (len(req.payload) if req.kind == "prompt"
+                else int(req.payload["prompt_len"]))
+
+    def _admit_one_locked(self, req: _Pending, b: int) -> dict:
+        """Prefill (or take the transferred cache of) one request into slot
+        ``b``; returns the admission span's ``bucket`` and ``prefix``."""
+        import jax.numpy as jnp
+
+        params, rng = req.params, None
+        if req.kind == "prefilled":
+            # PD disaggregation: the prompt's KV was computed by a
+            # prefill server; insert its transferred cache directly.
+            prefilled = req.payload
+            cache1 = {
+                k: jnp.asarray(v) for k, v in prefilled["cache"].items()
+            }
+            first = int(prefilled["first_token"])
+            prompt_ids = tuple(prefilled.get("prompt_ids", ()))
+            first_lp = prefilled.get("first_logprob")
+            how = {"bucket": 0, "prefix": "none"}
+            if params.seed is not None:
+                rng = self._rng_for(params)
+                if params.temperature > 0:
+                    # the prefill server consumed one draw from
+                    # this seed sampling the first token; skip it
+                    # or token 2 reuses token 1's random value
+                    rng.random_sample()
+        else:
+            prompt_ids = req.payload
+            if params.seed is not None:
+                rng = self._rng_for(params)
+            cache1, first, first_lp, how = self._prefill_locked(
+                prompt_ids, params, rng
+            )
+        prompt_len = self._prompt_len(req)
+        if prompt_len <= 0:
+            raise ValueError("prompt must be non-empty")
+        self._activate_slot_locked(
+            b, cache1, first, req, prompt_len,
+            prompt_ids=prompt_ids, first_lp=first_lp, rng=rng,
+        )
+        return how
 
     def _finish_if_done_locked(self, b: int):
         slot = self._slots[b]
-        stop = set(slot.params.stop_token_ids) | {self.tokenizer.eos_id}
+        eos = self.tokenizer.eos_id
+        stop = set(slot.params.stop_token_ids) | {eos}
         out = None
-        done = (
-            slot.produced >= slot.params.max_new_tokens
-            or slot.last_token in stop
-            or slot.length + 1 >= self.config.max_seq_len
+        # the first that holds names how the answer ended
+        reason = (
+            "eos" if slot.last_token == eos
+            else "stop" if slot.last_token in stop
+            else "length" if slot.produced >= slot.params.max_new_tokens
+            else "context" if slot.length + 1 >= self.config.max_seq_len
+            else None
         )
         if slot.params.stop:
             # Runs even when another criterion already fired: the final
@@ -470,21 +552,28 @@ class DecodeEngine:
                     ) > idx:
                         keep -= 1
                     out = slot.token_ids[:keep]
-                    done = True
+                    reason = "stop"
                     break
-        if done:
+        if reason is None:
+            return
+        with self._span(
+            "engine.finish", rid=slot.rid, produced=slot.produced,
+            reason=reason, first_token_ms=round(slot.first_token_ms, 3),
+            total_ms=round((time.monotonic() - slot.submitted) * 1e3, 3),
+        ):
             if out is None:
                 out = slot.token_ids
                 if out and out[-1] in stop:
                     out = out[:-1]
             if slot.stream_q is not None:
-                slot.stream_q.put(("__done__", len(out)))
+                slot.stream_q.put(("__done__", len(out), reason))
             if slot.future is not None:
                 slot.future.set_result(GenerationResult(
-                    out, slot.logprobs[: len(out)]
+                    out, slot.logprobs[: len(out)], reason
                 ))
             slot.active = False
             slot.future = None
+            self.stats["finished_" + reason] += 1
 
     def _tick_locked(self) -> bool:
         if self._spec_k:
@@ -522,8 +611,6 @@ class DecodeEngine:
         attending and masks keys beyond each query position, and later
         writes overwrite rejected-draft positions — stale KV can never
         be attended."""
-        import jax.numpy as jnp
-
         active = [i for i, s in enumerate(self._slots) if s.active]
         if not active:
             return False
@@ -546,36 +633,60 @@ class DecodeEngine:
             # nothing to verify: the (1+K)-wide dispatch would pay ~K x
             # attention/logits cost for zero benefit
             return self._tick_plain_locked()
-        B = len(self._slots)
-        toks = np.zeros((B, 1 + K), np.int32)
-        lens = np.zeros((B,), np.int32)
-        for i in active:
-            slot = self._slots[i]
-            toks[i, :] = slot.last_token
-            lens[i] = slot.length
-            if i in drafts:
-                d = drafts[i]
-                toks[i, 1:1 + len(d)] = d
-        logits, self._cache = self._decode_spec(
-            self.params, jnp.asarray(toks), self._cache, jnp.asarray(lens)
-        )
-        logits = np.asarray(logits)
-        for i in active:
-            slot = self._slots[i]
-            draft = drafts.get(i, [])
-            for j in range(len(draft) + 1):
-                nxt, lp = self._sample(
-                    logits[i, j], slot.params, slot.prompt_ids,
-                    slot.token_ids, slot.rng,
-                )
-                self._emit_token_locked(i, nxt, lp)
-                if not slot.active:
-                    break  # finished mid-run (stop/max/length)
-                if j < len(draft):
-                    if nxt != draft[j]:
-                        break  # mismatch: later logits had wrong context
-                    self.stats["spec_accepted"] += 1
-        self.stats["ticks"] += 1
+
+        def sample(logits):
+            for i in active:
+                slot = self._slots[i]
+                draft = drafts.get(i, [])
+                for j in range(len(draft) + 1):
+                    nxt, lp = self._sample(
+                        logits[i, j], slot.params, slot.prompt_ids,
+                        slot.token_ids, slot.rng,
+                    )
+                    self._emit_token_locked(i, nxt, lp)
+                    if not slot.active:
+                        break  # finished mid-run (stop/max/length)
+                    if j < len(draft):
+                        if nxt != draft[j]:
+                            break  # mismatch: later logits had wrong context
+                        self.stats["spec_accepted"] += 1
+
+        return self._run_tick_locked(
+            active, self._decode_spec, 1 + K, drafts, sample)
+
+    def _run_tick_locked(self, active, program, width, drafts, sample) -> bool:
+        """One decode program over every slot, between its spans: pack the
+        ``[B, width]`` tokens (each active slot's last token, then its
+        draft), dispatch, fetch the logits, ``sample(logits)``."""
+        import jax.numpy as jnp
+
+        span = self._span
+        compiles = self.stats["compiles"]
+        with span("engine.tick", tick=self.stats["ticks"],
+                  active=len(active)) as tick:
+            with span("engine.tick.pack"):
+                toks = np.zeros((len(self._slots), width), np.int32)
+                lens = np.zeros((len(self._slots),), np.int32)
+                for i in active:
+                    slot = self._slots[i]
+                    toks[i, :] = slot.last_token
+                    lens[i] = slot.length
+                    d = drafts.get(i, ())
+                    toks[i, 1:1 + len(d)] = d
+                toks, lens = jnp.asarray(toks), jnp.asarray(lens)
+            with span("engine.tick.dispatch"):
+                logits, self._cache = program(
+                    self.params, toks, self._cache, lens)
+            with span("engine.tick.fetch"):
+                # the wait for the device, then the logits' way to the host
+                logits = np.asarray(logits)
+            with span("engine.tick.sample"):
+                sample(logits)
+            self.stats["ticks"] += 1
+            self.stats["slot_ticks"] += len(active)
+            self.stats["compiles"] = compile_count()
+            tick.set_metadata(
+                compiled=int(self.stats["compiles"] > compiles))
         return True
 
     def _emit_token_locked(self, i: int, nxt: int, lp) -> None:
@@ -593,43 +704,42 @@ class DecodeEngine:
         self._finish_if_done_locked(i)
 
     def _tick_plain_locked(self) -> bool:
-        import jax.numpy as jnp
-
         active = [i for i, s in enumerate(self._slots) if s.active]
         if not active:
             return False
-        B = len(self._slots)
-        toks = np.zeros((B, 1), np.int32)
-        lens = np.zeros((B,), np.int32)
-        for i in active:
-            toks[i, 0] = self._slots[i].last_token
-            lens[i] = self._slots[i].length
-        logits, self._cache = self._decode(
-            self.params, jnp.asarray(toks), self._cache, jnp.asarray(lens)
-        )
-        logits = np.asarray(logits)
-        for i in active:
-            slot = self._slots[i]
-            nxt, lp = self._sample(
-                logits[i], slot.params, slot.prompt_ids, slot.token_ids,
-                slot.rng,
-            )
-            self._emit_token_locked(i, nxt, lp)
-        self.stats["ticks"] += 1
-        return True
+
+        def sample(logits):
+            for i in active:
+                slot = self._slots[i]
+                nxt, lp = self._sample(
+                    logits[i], slot.params, slot.prompt_ids, slot.token_ids,
+                    slot.rng,
+                )
+                self._emit_token_locked(i, nxt, lp)
+
+        return self._run_tick_locked(active, self._decode, 1, {}, sample)
 
     # ------------------------------------------------------------- public
 
+    def _enqueue(self, kind: str, payload, params, fut: Future, rid) -> None:
+        if rid is None:
+            # submitted to the engine directly: an engine-local number
+            rid = f"engine-{next(self._rid_seq)}"
+        self._pending.put(_Pending(
+            kind, payload, params or SamplingParams(), fut, rid,
+            time.monotonic()))
+        self._ensure_loop()
+
     def submit(self, prompt_ids: List[int],
-               params: Optional[SamplingParams] = None) -> Future:
-        """Continuous-batching entry: returns a Future of generated ids."""
+               params: Optional[SamplingParams] = None,
+               rid: Optional[str] = None) -> Future:
+        """Continuous-batching entry: returns a Future of generated ids.
+        ``rid`` is the id the request's spans carry (the serving layer's
+        ``cmpl-...``)."""
         if not prompt_ids:
             raise ValueError("prompt must be non-empty")
         fut: Future = Future()
-        self._pending.put(
-            ("prompt", list(prompt_ids), params or SamplingParams(), fut)
-        )
-        self._ensure_loop()
+        self._enqueue("prompt", list(prompt_ids), params, fut, rid)
         return fut
 
     def prefill_only(self, prompt_ids: List[int],
@@ -642,7 +752,7 @@ class DecodeEngine:
             raise ValueError("prompt must be non-empty")
         params = params or SamplingParams()
         with self._lock:
-            cache1, first, lp = self._prefill_locked(
+            cache1, first, lp, _ = self._prefill_locked(
                 list(prompt_ids), params, self._rng_for(params)
             )
             return {
@@ -659,14 +769,12 @@ class DecodeEngine:
         """Decode-server half of PD disaggregation: continue generation from
         a transferred prefill state."""
         fut: Future = Future()
-        self._pending.put(
-            ("prefilled", prefilled, params or SamplingParams(), fut)
-        )
-        self._ensure_loop()
+        self._enqueue("prefilled", prefilled, params, fut, None)
         return fut
 
     def submit_stream(self, prompt_ids: List[int],
-                      params: Optional[SamplingParams] = None):
+                      params: Optional[SamplingParams] = None,
+                      rid: Optional[str] = None) -> TokenStream:
         """Token-level streaming (reference: vLLM streaming generation /
         OpenAI stream=true). Yields generated token ids as the decode loop
         produces them; raises the request's error if admission fails.
@@ -684,12 +792,9 @@ class DecodeEngine:
         fut: Future = Future()
         q: "_q.Queue" = _q.Queue()
         fut._rt_stream_q = q
-        self._pending.put(
-            ("prompt", list(prompt_ids), params or SamplingParams(), fut)
-        )
-        self._ensure_loop()
+        self._enqueue("prompt", list(prompt_ids), params, fut, rid)
 
-        def gen():
+        def gen(stream: TokenStream):
             while True:
                 if fut.done() and fut.exception() is not None:
                     raise fut.exception()
@@ -698,6 +803,7 @@ class DecodeEngine:
                 except _q.Empty:
                     continue
                 if isinstance(item, tuple) and item[0] == "__done__":
+                    stream.finish_reason = item[2]
                     return
                 # a stop TOKEN ends the request without being part of the
                 # output; the done marker's kept-length already excludes
@@ -708,7 +814,7 @@ class DecodeEngine:
                     continue  # await the done marker
                 yield item
 
-        return gen()
+        return TokenStream(gen)
 
     def generate(self, prompt_ids: List[int],
                  params: Optional[SamplingParams] = None) -> List[int]:
@@ -765,7 +871,9 @@ class DecodeEngine:
                         self._loop_thread = None
                         return
                 idle_since = None
-            tick.sleep()
+            # the chip waits for a request here, not for the host
+            with self._span("engine.idle"):
+                tick.sleep()
 
     def shutdown(self):
         self._stopped = True

@@ -53,14 +53,28 @@ def _logprobs_block(completion_ids) -> dict:
     }
 
 
+def finish_reason(engine_reason: str) -> str:
+    """OpenAI's two words for the engine's four: ``length`` where
+    ``max_tokens`` or the context ended the answer, ``stop`` where EOS or a
+    stop did."""
+    return "length" if engine_reason in ("length", "context") else "stop"
+
+
+def new_request_id(chat: bool = False) -> str:
+    """The id an answer carries and the spans of its request share."""
+    return f"{'chatcmpl' if chat else 'cmpl'}-{uuid.uuid4().hex[:24]}"
+
+
 def completion_response(config: LLMConfig, prompt_tokens: int,
-                        completion_ids, text: str, **extra) -> dict:
+                        completion_ids, text: str, *,
+                        rid: Optional[str] = None, **extra) -> dict:
     """OpenAI text_completion envelope (shared by every ingress)."""
-    choice = {"index": 0, "text": text, "finish_reason": "stop"}
+    choice = {"index": 0, "text": text, "finish_reason": finish_reason(
+        getattr(completion_ids, "finish_reason", ""))}
     if getattr(completion_ids, "logprobs", None):
         choice["logprobs"] = _logprobs_block(completion_ids)
     return {
-        "id": f"cmpl-{uuid.uuid4().hex[:24]}",
+        "id": rid or new_request_id(),
         "object": "text_completion",
         "created": int(time.time()),
         "model": config.model_id,
@@ -119,27 +133,47 @@ class LLMServer:
     def _sampling(self, payload: dict) -> SamplingParams:
         return extract_sampling(payload, self.config)
 
+    def _submit(self, prompt: str, payload: dict, rid: str, stream: bool):
+        """(prompt ids, the engine's future or token stream): tokenizing and
+        the hand-over to the engine, under the request's ``llm.request``
+        span on the serving thread."""
+        from jax.profiler import TraceAnnotation
+
+        params = self._sampling(payload)
+        with TraceAnnotation(
+            "llm.request", rid=rid, max_tokens=params.max_new_tokens,
+            stream=int(stream),
+        ) as request:
+            ids = self.engine.tokenizer.encode(prompt)
+            request.set_metadata(prompt_tokens=len(ids))
+            send = (self.engine.submit_stream if stream
+                    else self.engine.submit)
+            return ids, send(ids, params, rid=rid)
+
     def completions(self, payload: dict) -> dict:
-        prompt = payload.get("prompt", "")
-        ids = self.engine.tokenizer.encode(prompt)
-        out = self.engine.submit(ids, self._sampling(payload)).result(600)
+        rid = new_request_id()
+        ids, fut = self._submit(payload.get("prompt", ""), payload, rid,
+                                False)
+        out = fut.result(600)
         text = self.engine.tokenizer.decode(out)
-        return completion_response(self.config, len(ids), out, text)
+        return completion_response(self.config, len(ids), out, text, rid=rid)
 
     def chat_completions(self, payload: dict) -> dict:
-        prompt = self._chat_prompt(payload.get("messages", []))
-        ids = self.engine.tokenizer.encode(prompt)
-        out = self.engine.submit(ids, self._sampling(payload)).result(600)
+        rid = new_request_id(chat=True)
+        ids, fut = self._submit(
+            self._chat_prompt(payload.get("messages", [])), payload, rid,
+            False)
+        out = fut.result(600)
         text = self.engine.tokenizer.decode(out)
         choice = {
             "index": 0,
             "message": {"role": "assistant", "content": text},
-            "finish_reason": "stop",
+            "finish_reason": finish_reason(out.finish_reason),
         }
         if getattr(out, "logprobs", None):
             choice["logprobs"] = _logprobs_block(out)
         return {
-            "id": f"chatcmpl-{uuid.uuid4().hex[:24]}",
+            "id": rid,
             "object": "chat.completion",
             "created": int(time.time()),
             "model": self.config.model_id,
@@ -165,14 +199,13 @@ class LLMServer:
             prompt = self._chat_prompt(payload.get("messages", []))
         else:
             prompt = payload.get("prompt", "")
-        ids = self.engine.tokenizer.encode(prompt)
-        rid = (f"chatcmpl-{uuid.uuid4().hex[:24]}" if chat
-               else f"cmpl-{uuid.uuid4().hex[:24]}")
+        rid = new_request_id(chat)
+        _, tokens = self._submit(prompt, payload, rid, True)
         created = int(time.time())
         obj = "chat.completion.chunk" if chat else "text_completion"
         produced: List[int] = []
         prev_text = ""
-        for tok in self.engine.submit_stream(ids, self._sampling(payload)):
+        for tok in tokens:
             produced.append(tok)
             text = self.engine.tokenizer.decode(produced)
             # Hold back trailing replacement chars: a partial multi-byte
@@ -202,9 +235,10 @@ class LLMServer:
                 "id": rid, "object": obj, "created": created,
                 "model": self.config.model_id, "choices": [tc],
             }) + "\n\n"
-        final = ({"index": 0, "delta": {}, "finish_reason": "stop"}
+        ended = finish_reason(tokens.finish_reason)
+        final = ({"index": 0, "delta": {}, "finish_reason": ended}
                  if chat else
-                 {"index": 0, "text": "", "finish_reason": "stop"})
+                 {"index": 0, "text": "", "finish_reason": ended})
         yield "data: " + json.dumps({
             "id": rid, "object": obj, "created": created,
             "model": self.config.model_id, "choices": [final],

@@ -86,11 +86,17 @@ def default_jax_train_loop(config: Dict[str, Any]):
     config keys: ``model`` (GPT2Config kwargs), ``mesh`` (MeshConfig kwargs),
     ``optimizer`` (OptimizerConfig kwargs), ``num_steps``, ``batch_size``,
     ``seq_len``, ``checkpoint_every`` (0 = only at end), ``data_seed``.
-    Reports ``{loss, step, tokens_per_sec, platform, device_kind,
+    Reports ``{loss, step, tokens_per_sec, compiles, platform, device_kind,
     device_count}`` each step (a worker whose node was granted ``TPU`` fails
     with ``AcceleratorMismatchError`` rather than train on another
     platform); saves orbax checkpoints; resumes from ``get_checkpoint()``
     after failures.
+
+    Each step is a ``train.step`` in the profiler's own trace (a
+    ``StepTraceAnnotation``, so xprof draws step boundaries) with what the
+    host does as its children: ``train.next_batch``, ``train.dispatch``,
+    ``train.loss_fetch`` (the wait for the step), ``train.report`` or
+    ``train.checkpoint``. All inert unless a capture runs.
     """
     import os
     import tempfile
@@ -99,6 +105,7 @@ def default_jax_train_loop(config: Dict[str, Any]):
     import jax
     import jax.numpy as jnp
     import numpy as np
+    from jax.profiler import StepTraceAnnotation, TraceAnnotation as span
 
     from ray_tpu._private.accelerators.tpu import local_device_info
     from ray_tpu.models import get_preset
@@ -110,6 +117,7 @@ def default_jax_train_loop(config: Dict[str, Any]):
         create_train_state,
         make_train_step,
     )
+    from ray_tpu.util.debug import compile_count
 
     ctx = get_context()
     device_info = local_device_info()
@@ -172,23 +180,32 @@ def default_jax_train_loop(config: Dict[str, Any]):
 
     t0 = time.monotonic()
     for step in range(start_step, num_steps):
-        state, metrics = step_fn(state, next_batch(step))
-        if ctx.should_stop():
-            break
-        loss = float(metrics["loss"])
-        dt = max(time.monotonic() - t0, 1e-9)
-        t0 = time.monotonic()
-        m = {
-            "loss": loss,
-            "step": step + 1,
-            "tokens_per_sec": batch_size * seq_len / dt,
-            **device_info,
-        }
-        is_ckpt_step = ckpt_every and (step + 1) % ckpt_every == 0
-        if is_ckpt_step or step + 1 == num_steps:
-            save(state, step + 1, m)
-        else:
-            report(m)
+        with StepTraceAnnotation("train.step", step_num=step):
+            with span("train.next_batch"):
+                batch = next_batch(step)
+            with span("train.dispatch"):
+                state, metrics = step_fn(state, batch)
+            if ctx.should_stop():
+                break
+            with span("train.loss_fetch"):
+                loss = float(metrics["loss"])
+            dt = max(time.monotonic() - t0, 1e-9)
+            t0 = time.monotonic()
+            m = {
+                "loss": loss,
+                "step": step + 1,
+                "tokens_per_sec": batch_size * seq_len / dt,
+                # a rise between two steps: a program was built in between
+                "compiles": compile_count(),
+                **device_info,
+            }
+            is_ckpt_step = ckpt_every and (step + 1) % ckpt_every == 0
+            if is_ckpt_step or step + 1 == num_steps:
+                with span("train.checkpoint"):
+                    save(state, step + 1, m)
+            else:
+                with span("train.report"):
+                    report(m)
     return {"final_step": int(state["step"])}
 
 

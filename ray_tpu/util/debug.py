@@ -101,7 +101,14 @@ def xla_profile_capture(duration_s: float = 3.0,
     ``jax.profiler.start_trace/stop_trace``, producing a TensorBoard-/
     xprof-readable trace dir with device timelines, HLO op costs and HBM
     usage. Runs in the TPU-owning process — call through the node RPC for
-    workers."""
+    workers.
+
+    The host plane carries the program's own spans (``engine.tick``,
+    ``engine.admit``, ``train.step``, ... — ``jax.profiler.TraceAnnotation``
+    in ``llm/engine.py``, ``llm/serving.py`` and ``train/trainer.py``) on
+    the device's timeline. The Python tracer is off: it stamps every call
+    of every thread, slows exactly the host work the spans weigh, and made
+    a 4 s capture of a loaded replica outlive its relay."""
     import time as _time
 
     try:
@@ -113,7 +120,9 @@ def xla_profile_capture(duration_s: float = 3.0,
 
         logdir = tempfile.mkdtemp(prefix="rt_xla_trace_")
     try:
-        jax.profiler.start_trace(logdir)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(logdir, profiler_options=options)
         _time.sleep(max(duration_s, 0.1))
         jax.profiler.stop_trace()
     except Exception as e:
@@ -123,7 +132,34 @@ def xla_profile_capture(duration_s: float = 3.0,
             pass
         return {"ok": False, "error": f"{type(e).__name__}: {e}"}
     return {"ok": True, "logdir": logdir,
-            "hint": "tensorboard --logdir <logdir>  (profile plugin)"}
+            "hint": "xprof / tensorboard --logdir <logdir>, or Perfetto"}
+
+
+_compiles = 0
+_compiles_listening = False
+_compiles_lock = threading.Lock()
+
+
+def compile_count() -> int:
+    """Executables this process has built since the first call of this
+    function (compiled, or loaded from the persistent cache): one
+    ``jax.monitoring`` listener, registered then. The engine keeps the
+    count in ``stats["compiles"]`` and the train loop reports it, so a
+    program built inside a measured window shows as a rise."""
+    global _compiles_listening
+    with _compiles_lock:
+        if not _compiles_listening:
+            from jax import monitoring
+            from jax._src.dispatch import BACKEND_COMPILE_EVENT
+
+            def on_duration(event, duration, **kwargs):
+                global _compiles
+                if event == BACKEND_COMPILE_EVENT:
+                    _compiles += 1
+
+            monitoring.register_event_duration_secs_listener(on_duration)
+            _compiles_listening = True
+    return _compiles
 
 
 # ----------------------------------------------------------- cluster-facing
@@ -173,7 +209,9 @@ def node_xla_profile(
         {"node_id": node_id, "method": "xla_profile",
          "duration_s": duration_s, "logdir": logdir},
         address,
-        timeout=duration_s + 60,  # the capture itself takes duration_s
+        # the capture takes duration_s, writing it out on a loaded node
+        # much longer; the head's relay waits duration_s + 300
+        timeout=duration_s + 330,
     )
 
 

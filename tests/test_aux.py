@@ -1,4 +1,4 @@
-"""Aux subsystems: metrics, tracing, runtime envs, chaos killers.
+"""Aux subsystems: metrics, runtime envs, chaos killers.
 
 Reference analogs: ``python/ray/tests/test_metrics_agent.py``,
 ``test_tracing.py``, ``test_runtime_env*``, chaos suites under
@@ -94,60 +94,6 @@ def test_metrics_flow_to_head_and_scrape():
         assert "rt_user_metric_total" in text
     finally:
         ray_tpu.shutdown()
-
-
-# --------------------------------------------------------------- tracing
-
-
-def test_tracing_spans_propagate():
-    from ray_tpu.util.tracing import setup_tracing, teardown_tracing
-
-    exporter = setup_tracing(in_memory=True)
-    if exporter is None:
-        pytest.skip("opentelemetry SDK unavailable")
-    try:
-        ray_tpu.init(num_cpus=2)
-        try:
-            @ray_tpu.remote
-            def traced(x):
-                return x + 1
-
-            assert ray_tpu.get(traced.remote(1)) == 2
-            # The submit-side context was injected into the task header;
-            # driver-side spans appear in this process's exporter.
-            from ray_tpu.util.tracing import span
-
-            with span("driver::section"):
-                pass
-            names = [s.name for s in exporter.get_finished_spans()]
-            assert "driver::section" in names
-        finally:
-            ray_tpu.shutdown()
-    finally:
-        teardown_tracing()
-
-
-def test_task_header_carries_trace_context():
-    from ray_tpu.util.tracing import (
-        enabled,
-        inject_context,
-        setup_tracing,
-        teardown_tracing,
-    )
-
-    assert not enabled()
-    assert inject_context() is None  # disabled -> zero-cost path
-    exporter = setup_tracing(in_memory=True)
-    if exporter is None:
-        pytest.skip("opentelemetry SDK unavailable")
-    try:
-        from ray_tpu.util.tracing import span
-
-        with span("parent"):
-            carrier = inject_context()
-        assert carrier and "traceparent" in carrier
-    finally:
-        teardown_tracing()
 
 
 # ------------------------------------------------------------ runtime env

@@ -152,7 +152,8 @@ def test_openai_http_endpoint(llm_cluster):
         )
         with urllib.request.urlopen(req, timeout=120) as r:
             out = json.loads(r.read())
-        assert out["choices"][0]["finish_reason"] == "stop"
+        # three tokens asked, three made: cut by max_tokens
+        assert out["choices"][0]["finish_reason"] == "length"
     finally:
         serve.shutdown()
 
@@ -481,6 +482,44 @@ def test_serving_returns_logprobs(rt_serve_cluster=None):
     assert len(lp["tokens"]) == resp["usage"]["completion_tokens"]
     assert all(v <= 0 for v in lp["token_logprobs"])
     assert all(len(d) == 2 for d in lp["top_logprobs"])
+
+
+@pytest.mark.parametrize("ended_on", ["eos", "length"])
+@pytest.mark.parametrize("stream", [False, True])
+def test_serving_finish_reason(ended_on, stream):
+    """An answer says how it ended: ``length`` where ``max_tokens`` cut it,
+    ``stop`` where the model made EOS — unary and in the last streamed
+    chunk."""
+    import json
+
+    from ray_tpu.llm.serving import LLMServer
+
+    srv = LLMServer.__new__(LLMServer)
+    srv.config = LLMConfig(**_SMALL)
+    srv.engine = _engine()
+    greedy = list(srv.engine.generate(
+        srv.engine.tokenizer.encode("hi"), SamplingParams(max_new_tokens=6)))
+    if ended_on == "eos":
+        # force it: the third token the model makes greedily is now EOS
+        srv.engine.tokenizer.eos_id = greedy[2]
+        kept = greedy.index(greedy[2])
+    else:
+        kept = 6
+    payload = {"prompt": "hi", "max_tokens": 6, "logprobs": 1}
+    want = "stop" if ended_on == "eos" else "length"
+    if not stream:
+        resp = srv.completions(payload)
+        assert resp["usage"]["completion_tokens"] == kept
+        assert resp["choices"][0]["finish_reason"] == want
+        return
+    lines = list(srv.completions_stream(payload))
+    assert lines[-1] == "data: [DONE]\n\n"
+    chunks = [json.loads(l[len("data: "):]) for l in lines[:-1]]
+    assert [c["choices"][0]["finish_reason"] for c in chunks[:-1]] == (
+        [None] * (len(chunks) - 1))
+    assert chunks[-1]["choices"][0]["finish_reason"] == want
+    # one id for the whole answer, the one its spans carry
+    assert len({c["id"] for c in chunks}) == 1
 
 
 # -------------------------------------------------------------- streaming
