@@ -115,6 +115,47 @@ class _Pending:
     submitted: float
 
 
+def engine_programs(cfg):
+    """The engine's four XLA programs for model config ``cfg``: (prefill,
+    insert, decode, decode_all), jitted and not yet compiled. The cache is
+    the model module's pytree with the slot on axis 1; ``insert`` and both
+    decodes take it donated and give it back in the same buffer."""
+    import jax
+
+    from ray_tpu.models import module_for
+
+    model = module_for(cfg)
+
+    def prefill(params, tokens, cache1, start):
+        # start > 0 = continuation from a cached prefix: only the
+        # prompt's tail runs through the model
+        return model.forward_cached(params, tokens, cache1, start, cfg)
+
+    def insert(batch_cache, slot_cache, b):
+        return jax.tree.map(
+            lambda c, s1: jax.lax.dynamic_update_slice(
+                c, s1.astype(c.dtype), (0, b, 0, 0, 0)
+            ),
+            batch_cache, slot_cache,
+        )
+
+    def decode(params, tokens, cache, lens):
+        logits, cache = model.forward_cached(params, tokens, cache, lens, cfg)
+        return logits[:, -1], cache
+
+    def decode_all(params, tokens, cache, lens):
+        # speculation verify: logits at EVERY position (position j's
+        # row predicts the token after input j)
+        return model.forward_cached(params, tokens, cache, lens, cfg)
+
+    return (
+        jax.jit(prefill),
+        jax.jit(insert, donate_argnums=(0,)),
+        jax.jit(decode, donate_argnums=(2,)),
+        jax.jit(decode_all, donate_argnums=(2,)),
+    )
+
+
 class DecodeEngine:
     def __init__(self, config: LLMConfig, params=None, seed: int = 0):
         import jax
@@ -167,45 +208,12 @@ class DecodeEngine:
         self._rng = np.random.RandomState(seed)
 
         cfg = self.model_config
-
-        def prefill(params, tokens, cache1, start):
-            # start > 0 = continuation from a cached prefix: only the
-            # prompt's tail runs through the model
-            logits, cache1 = model.forward_cached(
-                params, tokens, cache1, start, cfg
-            )
-            return logits, cache1
-
-        def insert(batch_cache, slot_cache, b):
-            return jax.tree.map(
-                lambda c, s1: jax.lax.dynamic_update_slice(
-                    c, s1.astype(c.dtype), (0, b, 0, 0, 0)
-                ),
-                batch_cache, slot_cache,
-            )
-
-        def decode(params, tokens, cache, lens):
-            logits, cache = model.forward_cached(params, tokens, cache, lens, cfg)
-            return logits[:, -1], cache
-
-        def decode_all(params, tokens, cache, lens):
-            # speculation verify: logits at EVERY position (position j's
-            # row predicts the token after input j)
-            logits, cache = model.forward_cached(
-                params, tokens, cache, lens, cfg
-            )
-            return logits, cache
-
-        self._prefill = jax.jit(prefill)
-        self._insert = jax.jit(insert, donate_argnums=(0,))
-        self._decode = jax.jit(decode, donate_argnums=(2,))
         self._spec_k = max(
             0, int(getattr(config, "speculative_ngram_k", 0) or 0)
         )  # negatives = disabled, never a half-armed dispatch path
-        self._decode_spec = (
-            jax.jit(decode_all, donate_argnums=(2,))
-            if self._spec_k > 0 else None
-        )
+        self._prefill, self._insert, self._decode, decode_all = (
+            engine_programs(cfg))
+        self._decode_spec = decode_all if self._spec_k > 0 else None
         self._empty_slot_cache = lambda: model.init_kv_cache(cfg, 1, S)
 
         self._slots = [_Slot() for _ in range(B)]
@@ -394,8 +402,8 @@ class DecodeEngine:
         if entry is not None and (
             matched + self._bucket(n - matched) > self.config.max_seq_len
         ):
-            # the padded tail write would clamp inside dynamic_update_slice
-            # and corrupt valid prefix KV — full prefill instead
+            # the padded tail would reach past the end of the cache — full
+            # prefill instead
             entry, matched = None, 0
         if entry is not None:
             self.stats["prefix_partial_hits"] += 1
@@ -617,9 +625,8 @@ class DecodeEngine:
         K = self._spec_k
         S = self.config.max_seq_len
         if any(self._slots[i].length + 1 + K > S for i in active):
-            # near the sequence end the [B, 1+K] write would CLAMP inside
-            # dynamic_update_slice and overwrite valid KV — plain ticks
-            # finish the tail
+            # near the sequence end the [B, 1+K] write would reach past the
+            # end of the cache — plain ticks finish the tail
             return self._tick_plain_locked()
         drafts: Dict[int, list] = {}
         for i in active:

@@ -5,6 +5,9 @@ Each model module exposes: a frozen ``*Config`` dataclass, ``PRESETS``,
 ``init_kv_cache``, ``loss_fn``, ``count_params``, ``flops_per_token`` (and
 optionally ``forward_pipelined``). Train/LLM layers dispatch on the config
 type via :func:`module_for` — adding a family means adding a module here.
+The KV cache is ``{"k", "v"}``, each ``[L, B, KV, D, S]`` (``kv_cache.py``):
+callers outside a model module rely on the slot being axis 1 and on nothing
+else.
 """
 from __future__ import annotations
 

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
+from ray_tpu.models import kv_cache
 from ray_tpu.ops.attention import attention
 from ray_tpu.parallel.moe import (
     MoEConfig,
@@ -297,14 +298,15 @@ def forward(
 
 def init_kv_cache(config: GPT2Config, batch: int, max_len: int,
                   dtype=None) -> Dict[str, jax.Array]:
-    """Static-shape KV cache for incremental decoding: [L, B, S, H, D].
+    """Static-shape KV cache for incremental decoding: ``{"k", "v"}``, each
+    [L, B, H, D, S], position minor (``models/kv_cache.py`` says why).
     (Reference capability analog: the vLLM engine Ray LLM delegates to —
     ``llm/_internal/serve/engines/vllm``; here the cache is a jax pytree so
     the whole decode step stays one XLA program.)"""
-    dtype = dtype or config.dtype
-    L, H, D = config.num_layers, config.num_heads, config.head_dim
-    shape = (L, batch, max_len, H, D)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    return kv_cache.init_kv_cache(
+        config.num_layers, batch, config.num_heads, config.head_dim,
+        max_len, dtype or config.dtype,
+    )
 
 
 def forward_cached(
@@ -319,11 +321,13 @@ def forward_cached(
     tokens [B, T] — a prompt chunk (prefill, start=0) or one decode step
     (T=1, start=seq_len). start [B] int32: absolute position of tokens[:, 0]
     per sequence. Returns (logits [B, T, V] f32, updated cache). All shapes
-    static; per-sequence offsets go through vmapped dynamic_update_slice so
-    slot-based continuous batching is one compiled program.
+    static and every slot at its own offset, so slot-based continuous
+    batching is one compiled program. The whole cache rides the layer scan
+    as its carry and only the new tokens' columns change: a caller that
+    donates the cache gets it back in the same buffer.
     """
     B, T = tokens.shape
-    S = cache["k"].shape[2]
+    S = cache["k"].shape[-1]
     pos = start[:, None] + jnp.arange(T)[None, :]          # [B, T] absolute
     x = params["wte"][tokens].astype(config.dtype)
     x = x + params["wpe"][pos].astype(config.dtype)
@@ -331,33 +335,30 @@ def forward_cached(
     key_pos = jnp.arange(S)[None, None, :]                  # [1, 1, S]
     # causal vs cache: key visible iff key_pos <= query absolute position
     mask = key_pos <= pos[:, :, None]                       # [B, T, S]
+    hit = kv_cache.write_positions(start, T, S)
 
-    def block(carry, layer_and_cache):
-        x = carry
-        layer, ck, cv = layer_and_cache
+    def block(carry, layer):
+        x, i, cache = carry
         h = _layer_norm(x, layer["ln1_g"], layer["ln1_b"])
         q, k_new, v_new = _qkv(layer, h)
-        upd = jax.vmap(
-            lambda c, n, s: jax.lax.dynamic_update_slice(c, n, (s, 0, 0))
-        )
-        ck = upd(ck, k_new.astype(ck.dtype), start)         # [B, S, H, D]
-        cv = upd(cv, v_new.astype(cv.dtype), start)
+        ck, cv = kv_cache.read_layer(cache, i, k_new, v_new, hit)
         # attention core differs from _block: queries attend the cache
-        scores = jnp.einsum("bthd,bshd->bhts", q, ck).astype(jnp.float32)
+        scores = jnp.einsum("bthd,bhds->bhts", q, ck).astype(jnp.float32)
         scores = scores / jnp.sqrt(jnp.float32(q.shape[-1]))
         scores = jnp.where(mask[:, None, :, :], scores, -1e30)
         probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        attn = jnp.einsum("bhts,bshd->bthd", probs, cv)
+        attn = jnp.einsum("bhts,bhds->bthd", probs, cv)
+        cache, attn = kv_cache.write_layer(cache, i, ck, cv, attn)
         x = _attn_residual(layer, x, attn)
         x, _ = _mlp_residual(config, layer, x)
-        return x, (ck, cv)
+        return (x, i + 1, cache), None
 
-    x, (new_k, new_v) = jax.lax.scan(
-        block, x, (params["blocks"], cache["k"], cache["v"])
+    (x, _, cache), _ = jax.lax.scan(
+        block, (x, jnp.int32(0), cache), params["blocks"]
     )
     x = _layer_norm(x, params["ln_f_g"], params["ln_f_b"])
     logits = jnp.einsum("bte,ve->btv", x, params["wte"].astype(x.dtype))
-    return logits.astype(jnp.float32), {"k": new_k, "v": new_v}
+    return logits.astype(jnp.float32), cache
 
 
 def loss_fn(

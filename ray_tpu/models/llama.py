@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
+from ray_tpu.models import kv_cache
 from ray_tpu.ops.attention import attention
 from ray_tpu.parallel.moe import (
     MoEConfig,
@@ -303,12 +304,13 @@ def forward(
 
 def init_kv_cache(config: LlamaConfig, batch: int, max_len: int,
                   dtype=None) -> Dict[str, jax.Array]:
-    """Static-shape GQA cache: [L, B, S, KV, D] — kv heads only, an
-    H/KV-fold HBM saving over caching query-expanded heads."""
-    dtype = dtype or config.dtype
-    L, KV, D = config.num_layers, config.num_kv_heads, config.head_dim
-    shape = (L, batch, max_len, KV, D)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    """Static-shape GQA cache, [L, B, KV, D, S] as ``models/kv_cache.py``
+    has it — kv heads only, an H/KV-fold HBM saving over caching
+    query-expanded heads."""
+    return kv_cache.init_kv_cache(
+        config.num_layers, batch, config.num_kv_heads, config.head_dim,
+        max_len, dtype or config.dtype,
+    )
 
 
 def forward_cached(
@@ -319,63 +321,48 @@ def forward_cached(
     config: LlamaConfig,
 ) -> tuple:
     """Incremental forward with RoPE at absolute positions; same contract as
-    :func:`ray_tpu.models.gpt2.forward_cached` (static shapes; per-sequence
-    offsets via vmapped dynamic_update_slice). MoE configs route each
-    decoded token through its top-k experts (aux loss is a training-only
-    concern and is discarded here)."""
+    :func:`ray_tpu.models.gpt2.forward_cached` (static shapes, every slot at
+    its own offset, the cache carried through the layer scan and written in
+    place). MoE configs route each decoded token through its top-k experts
+    (aux loss is a training-only concern and is discarded here)."""
     B, T = tokens.shape
-    S = cache["k"].shape[2]
+    S = cache["k"].shape[-1]
     pos = start[:, None] + jnp.arange(T)[None, :]            # [B, T]
     x = params["wte"][tokens].astype(config.dtype)
 
     key_pos = jnp.arange(S)[None, None, :]
     mask = key_pos <= pos[:, :, None]                        # [B, T, S]
+    hit = kv_cache.write_positions(start, T, S)
 
-    def block(carry, layer_and_cache):
-        x = carry
-        layer, ck, cv = layer_and_cache
+    def block(carry, layer):
+        x, i, cache = carry
         h = _rms_norm(x, layer["attn_norm"], config.rms_eps)
         q = jnp.einsum("bte,ehd->bthd", h, layer["wq"].astype(h.dtype))
         k_new = jnp.einsum("bte,ehd->bthd", h, layer["wk"].astype(h.dtype))
         v_new = jnp.einsum("bte,ehd->bthd", h, layer["wv"].astype(h.dtype))
         q = _rope(q, pos, config.rope_theta)
         k_new = _rope(k_new, pos, config.rope_theta)
-        upd = jax.vmap(
-            lambda c, n, s: jax.lax.dynamic_update_slice(c, n, (s, 0, 0))
-        )
-        ck = upd(ck, k_new.astype(ck.dtype), start)          # [B, S, KV, D]
-        cv = upd(cv, v_new.astype(cv.dtype), start)
+        ck, cv = kv_cache.read_layer(cache, i, k_new, v_new, hit)
         # GQA attention over the cache: group query heads per kv head.
         g = config.q_per_kv
         qg = q.reshape(B, T, config.num_kv_heads, g, config.head_dim)
-        scores = jnp.einsum("btkgd,bskd->bkgts", qg, ck).astype(jnp.float32)
+        scores = jnp.einsum("btkgd,bkds->bkgts", qg, ck).astype(jnp.float32)
         scores = scores / jnp.sqrt(jnp.float32(config.head_dim))
         scores = jnp.where(mask[:, None, None, :, :], scores, -1e30)
         probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        attn = jnp.einsum("bkgts,bskd->btkgd", probs, cv)
+        attn = jnp.einsum("bkgts,bkds->btkgd", probs, cv)
+        cache, attn = kv_cache.write_layer(cache, i, ck, cv, attn)
         attn = attn.reshape(B, T, config.num_heads, config.head_dim)
         x = x + jnp.einsum("bthd,hde->bte", attn, layer["wo"].astype(x.dtype))
-        h = _rms_norm(x, layer["mlp_norm"], config.rms_eps)
-        if config.moe is not None:
-            routed, _aux = moe_layer(layer["moe"], h, config.moe)
-            x = x + routed
-        else:
-            gate = jnp.einsum(
-                "bte,em->btm", h, layer["w_gate"].astype(h.dtype)
-            )
-            up = jnp.einsum("bte,em->btm", h, layer["w_up"].astype(h.dtype))
-            h = jax.nn.silu(gate) * up
-            x = x + jnp.einsum(
-                "btm,me->bte", h, layer["w_down"].astype(h.dtype)
-            )
-        return x, (ck, cv)
+        x, _ = _ffn(config, layer, x)
+        return (x, i + 1, cache), None
 
-    x, (new_k, new_v) = jax.lax.scan(
-        block, x, (params["blocks"], cache["k"], cache["v"])
+    (x, _, cache), _ = jax.lax.scan(
+        block, (x, jnp.int32(0), cache), params["blocks"]
     )
     x = _rms_norm(x, params["norm_f"], config.rms_eps)
     logits = jnp.einsum("bte,ve->btv", x, params["lm_head"].astype(x.dtype))
-    return logits.astype(jnp.float32), {"k": new_k, "v": new_v}
+    return logits.astype(jnp.float32), cache
 
 
 def loss_fn(

@@ -1,0 +1,181 @@
+"""The KV cache contract (``models/kv_cache.py``) through the engine's own
+programs: cached decoding equals the full forward, for every way the engine
+calls ``forward_cached``, and a tick changes only the positions it writes.
+
+float32 throughout, so "equals" is 1e-4 and a write that lost precision
+would show; the untouched rows are compared bit for bit.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.llm.engine import engine_programs
+from ray_tpu.models import module_for
+from ray_tpu.models.gpt2 import GPT2Config
+from ray_tpu.models.llama import LlamaConfig
+
+S, SLOTS, BUCKET, VOCAB = 32, 3, 8, 128
+
+CONFIGS = {
+    "gpt2": GPT2Config(
+        vocab_size=VOCAB, max_seq_len=S, num_layers=2, num_heads=2,
+        embed_dim=32, dtype=jnp.float32, remat=False,
+    ),
+    # grouped queries: 4 heads share 2 kv heads
+    "llama_gqa": LlamaConfig(
+        vocab_size=VOCAB, max_seq_len=S, num_layers=2, num_heads=4,
+        num_kv_heads=2, embed_dim=64, dtype=jnp.float32, remat=False,
+    ),
+    # num_kv_heads == num_heads must behave as plain MHA (g = 1)
+    "llama_mha": LlamaConfig(
+        vocab_size=VOCAB, max_seq_len=S, num_layers=1, num_heads=4,
+        num_kv_heads=4, embed_dim=32, dtype=jnp.float32, remat=False,
+    ),
+}
+# slot -> (prompt length, tokens in all): three slots at three lengths,
+# the first prompt in a non-zero slot
+SEQS = {2: (5, 14), 0: (3, 12), 1: (7, 16)}
+
+
+class _Model:
+    def __init__(self, family):
+        self.cfg = CONFIGS[family]
+        self.mod = module_for(self.cfg)
+        self.params = self.mod.init_params(self.cfg, jax.random.PRNGKey(1))
+        (self.prefill, self.insert, self.decode,
+         self.decode_all) = engine_programs(self.cfg)
+        rng = np.random.RandomState(1)
+        self.tokens = {
+            b: rng.randint(0, VOCAB, n).astype(np.int32)
+            for b, (_, n) in SEQS.items()
+        }
+        self.full = {
+            b: np.asarray(self.mod.forward(
+                self.params, jnp.asarray(t[None]), self.cfg)[0][0])
+            for b, t in self.tokens.items()
+        }
+
+    def prefill_slot(self, b, upto, cache1=None, start=0):
+        """Tokens [start, upto) of slot b's sequence, padded to the bucket,
+        into ``cache1`` (or an empty slot cache); checks the logits."""
+        toks = np.zeros((1, BUCKET), np.int32)
+        toks[0, : upto - start] = self.tokens[b][start:upto]
+        if cache1 is None:
+            cache1 = self.mod.init_kv_cache(self.cfg, 1, S)
+        logits, cache1 = self.prefill(
+            self.params, jnp.asarray(toks), cache1,
+            jnp.full((1,), start, jnp.int32),
+        )
+        np.testing.assert_allclose(
+            np.asarray(logits)[0, : upto - start], self.full[b][start:upto],
+            rtol=1e-4, atol=1e-4,
+        )
+        return cache1
+
+    def batch_after_prefill(self):
+        """Every slot prefilled with its prompt and inserted."""
+        cache = self.mod.init_kv_cache(self.cfg, SLOTS, S)
+        for b, (plen, _) in SEQS.items():
+            cache = self.insert(cache, self.prefill_slot(b, plen), b)
+        return cache, np.array(
+            [SEQS[b][0] for b in range(SLOTS)], np.int32)
+
+    def step_tokens(self, lens, width):
+        return jnp.asarray(np.stack([
+            self.tokens[b][lens[b]: lens[b] + width] for b in range(SLOTS)
+        ]))
+
+
+@pytest.fixture(scope="module", params=list(CONFIGS))
+def model(request):
+    return _Model(request.param)
+
+
+def test_prefill_insert_then_ticks_at_different_lengths(model):
+    cache, lens = model.batch_after_prefill()
+    for _ in range(5):
+        logits, cache = model.decode(
+            model.params, model.step_tokens(lens, 1), cache,
+            jnp.asarray(lens))
+        for b in range(SLOTS):
+            np.testing.assert_allclose(
+                np.asarray(logits)[b], model.full[b][lens[b]],
+                rtol=1e-4, atol=1e-4,
+            )
+        lens = lens + 1
+
+
+def test_prefix_continuation(model):
+    """start > 0 at B = 1: the prompt's tail on top of a cached prefix, whose
+    own padded prefill left garbage behind position 4."""
+    b = 1
+    cache1 = model.prefill_slot(b, 4)
+    cache1 = model.prefill_slot(b, 11, cache1, start=4)
+    # and a tick on top of both, in the batch cache's last slot
+    cache = model.insert(model.mod.init_kv_cache(model.cfg, SLOTS, S),
+                         cache1, SLOTS - 1)
+    toks = np.zeros((SLOTS, 1), np.int32)
+    toks[-1, 0] = model.tokens[b][11]
+    lens = np.array([0] * (SLOTS - 1) + [11], np.int32)
+    logits, _ = model.decode(
+        model.params, jnp.asarray(toks), cache, jnp.asarray(lens))
+    np.testing.assert_allclose(
+        np.asarray(logits)[-1], model.full[b][11], rtol=1e-4, atol=1e-4)
+
+
+def test_decode_all_verifies_a_draft(model):
+    """T = 1 + K: every position's logits, each slot from its own length."""
+    cache, lens = model.batch_after_prefill()
+    K = 2
+    logits, cache = model.decode_all(
+        model.params, model.step_tokens(lens, 1 + K), cache,
+        jnp.asarray(lens))
+    for b in range(SLOTS):
+        np.testing.assert_allclose(
+            np.asarray(logits)[b], model.full[b][lens[b]: lens[b] + 1 + K],
+            rtol=1e-4, atol=1e-4,
+        )
+    # a rejected draft's positions are written over by the next tick
+    lens = lens + 1
+    logits, _ = model.decode(
+        model.params, model.step_tokens(lens, 1), cache, jnp.asarray(lens))
+    for b in range(SLOTS):
+        np.testing.assert_allclose(
+            np.asarray(logits)[b], model.full[b][lens[b]],
+            rtol=1e-4, atol=1e-4,
+        )
+
+
+@pytest.mark.parametrize("width", [1, 3])
+def test_a_tick_changes_only_the_positions_it_writes(model, width):
+    cache, lens = model.batch_after_prefill()
+    before = {k: np.asarray(v).copy() for k, v in cache.items()}
+    program = model.decode if width == 1 else model.decode_all
+    _, cache = program(
+        model.params, model.step_tokens(lens, width), cache,
+        jnp.asarray(lens))
+    written = np.zeros((SLOTS, S), bool)
+    for b in range(SLOTS):
+        written[b, lens[b]: lens[b] + width] = True
+    for name, old in before.items():
+        new = np.asarray(cache[name])           # [L, B, KV, D, S]
+        same = (new == old).all(axis=(0, 2, 3))  # [B, S]
+        assert same[~written].all(), name
+        assert not same[written].any(), name
+
+
+def test_a_write_past_the_end_is_dropped(model):
+    """The last position is written, what would lie behind it goes nowhere:
+    no earlier row moves (a ``dynamic_update_slice`` would shift the whole
+    write back over rows that hold valid K/V)."""
+    cache, _ = model.batch_after_prefill()
+    before = {k: np.asarray(v).copy() for k, v in cache.items()}
+    lens = np.full((SLOTS,), S - 1, np.int32)
+    _, cache = model.decode_all(
+        model.params, jnp.ones((SLOTS, 3), jnp.int32), cache,
+        jnp.asarray(lens))
+    for name, old in before.items():
+        new = np.asarray(cache[name])
+        assert (new[..., : S - 1] == old[..., : S - 1]).all(), name
+        assert (new[..., S - 1] != old[..., S - 1]).any(), name

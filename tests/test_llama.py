@@ -55,51 +55,8 @@ def test_causality(tiny_params):
     assert not np.allclose(l1[0, -1], l2[0, -1], atol=1e-3)
 
 
-def test_cached_matches_uncached(tiny_params):
-    """Prefill + per-token decode must reproduce the full forward logits
-    (RoPE at absolute positions, GQA cache) — float32 for tight tolerance."""
-    config = LlamaConfig(
-        vocab_size=512, max_seq_len=64, num_layers=2, num_heads=4,
-        num_kv_heads=2, embed_dim=64, dtype=jnp.float32, remat=False,
-    )
-    params = llama.init_params(config, jax.random.PRNGKey(1))
-    rng = np.random.RandomState(1)
-    T = 10
-    tokens = jnp.asarray(rng.randint(0, 512, (1, T)), jnp.int32)
-
-    full, _ = llama.forward(params, tokens, config)
-
-    cache = llama.init_kv_cache(config, 1, 32, dtype=jnp.float32)
-    # prefill the first 4 tokens at once, then decode one at a time
-    logits_p, cache = llama.forward_cached(
-        params, tokens[:, :4], cache, jnp.zeros((1,), jnp.int32), config
-    )
-    np.testing.assert_allclose(logits_p, full[:, :4], rtol=1e-4, atol=1e-4)
-    for t in range(4, T):
-        step_logits, cache = llama.forward_cached(
-            params, tokens[:, t : t + 1], cache,
-            jnp.full((1,), t, jnp.int32), config,
-        )
-        np.testing.assert_allclose(
-            step_logits[:, 0], full[:, t], rtol=1e-4, atol=1e-4
-        )
-
-
-def test_gqa_equals_mha_when_groups_1():
-    """num_kv_heads == num_heads must behave as plain MHA: the grouped
-    einsum path in forward_cached equals forward for g=1 too."""
-    config = LlamaConfig(
-        vocab_size=128, max_seq_len=32, num_layers=1, num_heads=4,
-        num_kv_heads=4, embed_dim=32, dtype=jnp.float32, remat=False,
-    )
-    params = llama.init_params(config, jax.random.PRNGKey(2))
-    tokens = jnp.asarray([[5, 9, 2, 77, 31]], jnp.int32)
-    full, _ = llama.forward(params, tokens, config)
-    cache = llama.init_kv_cache(config, 1, 16, dtype=jnp.float32)
-    cached, _ = llama.forward_cached(
-        params, tokens, cache, jnp.zeros((1,), jnp.int32), config
-    )
-    np.testing.assert_allclose(cached, full, rtol=1e-4, atol=1e-4)
+# Cached decoding against the full forward (GQA, and g = 1 as plain MHA):
+# cases "llama_gqa" and "llama_mha" of tests/test_kv_cache.py.
 
 
 def test_train_step_loss_decreases():

@@ -109,3 +109,89 @@ def test_flash_attention_compiles_inside_a_pipeline_stage(topo):
     compiled = jax.jit(stage).lower(x, x, x).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.output_shardings.is_equivalent_to(spec, 4)
+
+
+# ---------------------------------------------------------------- the engine
+# The serving cell's size: GPT-2 XL, 10 slots, 1024 positions.
+
+
+def _engine_program_args(one_chip, slots, width):
+    from ray_tpu.models import gpt2
+
+    cfg = gpt2.GPT2_XL
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: gpt2.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(
+        lambda: gpt2.init_kv_cache(cfg, slots, cfg.max_seq_len)))
+    tokens = jax.ShapeDtypeStruct((slots, width), jnp.int32,
+                                  sharding=one_chip)
+    start = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    return cfg, (params, tokens, cache, start)
+
+
+def _cache_sized_copies(hlo_text, cache):
+    """(computation, op, shape) of every ``copy``/``transpose`` in the
+    optimized HLO whose dimensions are a layer's slice of the cache or the
+    whole of it, in any order."""
+    import re
+
+    L, B, KV, D, S = cache["k"].shape
+    want = {tuple(sorted(d for d in dims if d > 1))
+            for dims in ((B, KV, D, S), (L, B, KV, D, S))}
+    found, computation = [], None
+    for line in hlo_text.splitlines():
+        if line.startswith(("%", "ENTRY")):
+            computation = line.split(" ", 1)[0]  # "ENTRY" or its %name
+        m = re.match(
+            r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* (copy|transpose)\(",
+            line)
+        if m:
+            dims = tuple(sorted(
+                int(d) for d in m.group(1).split(",") if d and int(d) > 1))
+            if dims in want:
+                found.append((computation, m.group(2), m.group(1)))
+    return found
+
+
+def test_decode_program_updates_the_cache_in_place(one_chip):
+    """``jit_decode`` as the engine builds it: the donated cache is the
+    result's buffer, no second cache among the temporaries (what is left
+    there is the weights in bf16), and no copy of a layer's slice or of the
+    whole cache anywhere in the program."""
+    from ray_tpu.llm.engine import engine_programs
+
+    cfg, args = _engine_program_args(one_chip, slots=10, width=1)
+    decode = engine_programs(cfg)[2]
+    compiled = decode.lower(*args).compile()
+    cache = args[2]
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in cache.values())
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    header = text.split("\n", 1)[0]  # HloModule jit_decode, ..._alias={...}
+    assert "jit_decode" in header
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert header.count("-alias)") == 2
+    assert mem.temp_size_in_bytes < 4e9
+    assert _cache_sized_copies(text, cache) == []
+
+
+def test_prefill_program_copies_no_layer_of_the_cache(one_chip):
+    """``jit_prefill`` at bucket 256, B = 1. Its cache is not donated (a
+    prefix-cache entry is shared), so the entry computation copies it once;
+    inside the layer loop nothing cache-sized is copied or transposed."""
+    from ray_tpu.llm.engine import engine_programs
+
+    cfg, args = _engine_program_args(one_chip, slots=1, width=256)
+    prefill = engine_programs(cfg)[0]
+    compiled = prefill.lower(*args).compile()
+    text = compiled.as_text()
+    assert "jit_prefill" in text.split("\n", 1)[0]
+    copies = _cache_sized_copies(text, args[2])
+    assert [c for c in copies if c[0] != "ENTRY"] == []
+    assert len(copies) <= 2
