@@ -1,9 +1,10 @@
 """Model FLOP/s utilization: the run's tokens per second and chip, times the
-FLOPs one trained token requires (``costs.gpt2_train_flops_per_token``),
+FLOPs one trained token requires (``train_flops_per_token`` of the
+``benchmarks/costs/<name>.py`` that the configuration's ``costs`` names),
 over the chip's published bf16 peak. Host clock of the traced run and a
 count from shapes; no peak, no number.
 
-The count is 6 x (block matrices + tied head) + 6.L.E.T: CAUSAL ATTENTION IS
+The count of ``costs/gpt2.py`` is 6 x (block matrices + tied head) + 6.L.E.T: CAUSAL ATTENTION IS
 COUNTED ONCE, at the half of the score and value products a causal model
 needs, not at the 12.L.E.T of full attention that the usual convention (and
 ISSUE 23) writes; recomputation is never counted. Against the usual

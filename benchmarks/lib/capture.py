@@ -19,8 +19,11 @@ def capture(duration_s: float, logdir: str) -> dict:
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     jax.profiler.start_trace(logdir, profiler_options=options)
+    started = time.monotonic()  # one clock for every process of a machine
     try:
         time.sleep(duration_s)
     finally:
+        stopped = time.monotonic()
         jax.profiler.stop_trace()
-    return {"ok": True, "logdir": logdir}
+    return {"ok": True, "logdir": logdir,
+            "started": started, "stopped": stopped}

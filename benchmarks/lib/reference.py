@@ -1,81 +1,42 @@
-"""Plain float32 reference of the GPT-2 architecture (Radford et al. 2019,
-"Language Models are Unsupervised Multitask Learners"; layout of OpenAI's
-``gpt-2/src/model.py``): learned token and position embeddings, pre-LN
-blocks (LN -> causal multi-head attention -> residual, LN -> 4x GELU MLP ->
-residual), a final LN and the LM head tied to the token embedding.
+"""What the comparison with a plain reference needs, whatever the
+architecture, written once over any ``logits(params, tokens) -> [B, T, V]``.
 
-Straightforward ``jax.numpy``, no kernels, no cache, no remat, no mixed
-precision (the layers are a ``lax.scan`` over the stacked block weights
-only so that 48 of them compile as one): every matrix product runs in float32 at
-``jax.default_matmul_precision("highest")`` (on a TPU a float32 product is
-otherwise computed in bf16 passes). Departures from the published model,
-shared with the program under test: the vocabulary is padded from 50257 to
-50304 rows, and GELU is the tanh approximation OpenAI's code uses (so does
-``jax.nn.gelu`` by default).
+The architecture's own arithmetic is one file, ``references/<name>.py``,
+which the configuration file names (``"reference"``; ``lib/named.py``):
+straightforward float32 ``jax.numpy`` that shares nothing with the program.
+Every matrix product runs at ``jax.default_matmul_precision("highest")``
+(``in_blocks``; on a TPU a float32 product is otherwise computed in bf16
+passes).
 
 The weights are DATA here: the comparison needs the very weights the
-program initialised, so callers pass the program's parameter pytree
-(``ray_tpu.models.gpt2.init_params`` under the same key). The arithmetic
-below shares nothing with the program.
+program initialised, so ``program_initial_weights`` builds the program's own
+configuration from the configuration file and calls its family's
+``init_params`` under the program's key.
 """
 from __future__ import annotations
 
-from typing import Dict
+from typing import Callable, Dict
 
 import jax
 import jax.numpy as jnp
 
-
-def _layer_norm(x, g, b, eps=1e-5):
-    mean = x.mean(-1, keepdims=True)
-    var = ((x - mean) ** 2).mean(-1, keepdims=True)
-    return (x - mean) / jnp.sqrt(var + eps) * g + b
+from benchmarks.lib import named, program
 
 
-def _gelu(x):
-    return 0.5 * x * (1.0 + jnp.tanh(
-        jnp.sqrt(2.0 / jnp.pi) * (x + 0.044715 * x ** 3)))
+def logits_of(config: dict) -> Callable:
+    """The ``logits`` of the reference the configuration file names."""
+    return named.load(config["files"]["reference"]).logits
 
 
-def logits(params: Dict, tokens: jax.Array) -> jax.Array:
-    """tokens [B, T] -> logits [B, T, V], float32. ``params`` is the
-    program's pytree: wte [V,E], wpe [S,E], blocks.* stacked over layers
-    (qkv_w [L,E,3,H,D], proj_w [L,H,D,E], fc_w [L,E,M], out_w [L,M,E])."""
-    f32 = lambda a: jnp.asarray(a, jnp.float32)  # noqa: E731
-    B, T = tokens.shape
-    wte, blocks = f32(params["wte"]), params["blocks"]
-    x = wte[tokens] + f32(params["wpe"])[:T][None]
-    H, D = blocks["qkv_w"].shape[3], blocks["qkv_w"].shape[4]
-    causal = jnp.tril(jnp.ones((T, T), bool))
-
-    def block(x, p):
-        h = _layer_norm(x, p["ln1_g"], p["ln1_b"])
-        qkv = h @ p["qkv_w"].reshape(-1, 3 * H * D) + p["qkv_b"].reshape(-1)
-        q, k, v = (
-            qkv[..., i * H * D:(i + 1) * H * D].reshape(B, T, H, D)
-            for i in range(3)
-        )
-        att = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(jnp.float32(D))
-        att = jnp.where(causal[None, None], att, -jnp.inf)
-        att = jax.nn.softmax(att, axis=-1)
-        a = jnp.einsum("bhqk,bkhd->bqhd", att, v).reshape(B, T, H * D)
-        x = x + a @ p["proj_w"].reshape(H * D, -1) + p["proj_b"]
-        h = _layer_norm(x, p["ln2_g"], p["ln2_b"])
-        h = _gelu(h @ p["fc_w"] + p["fc_b"])
-        return x + h @ p["out_w"] + p["out_b"], None
-
-    x, _ = jax.lax.scan(block, x, jax.tree.map(f32, blocks))
-    x = _layer_norm(x, f32(params["ln_f_g"]), f32(params["ln_f_b"]))
-    return x @ wte.T
-
-
-def token_logprobs(params: Dict, tokens: jax.Array) -> jax.Array:
+def token_logprobs(logits: Callable, params: Dict,
+                   tokens: jax.Array) -> jax.Array:
     """log p(tokens[:, t+1] | tokens[:, :t+1]) for every t: [B, T-1]."""
     lp = jax.nn.log_softmax(logits(params, tokens[:, :-1]), axis=-1)
     return jnp.take_along_axis(lp, tokens[:, 1:, None], axis=-1)[..., 0]
 
 
-def greedy_gaps(params: Dict, tokens: jax.Array) -> jax.Array:
+def greedy_gaps(logits: Callable, params: Dict,
+                tokens: jax.Array) -> jax.Array:
     """How far tokens[:, t+1] lies under the reference's own greedy choice
     after tokens[:, :t+1], in log-probability, for every t: [B, T-1]; 0
     where it IS that choice."""
@@ -84,20 +45,20 @@ def greedy_gaps(params: Dict, tokens: jax.Array) -> jax.Array:
     return lp.max(axis=-1) - chosen
 
 
-def loss(params: Dict, tokens: jax.Array) -> jax.Array:
+def loss(logits: Callable, params: Dict, tokens: jax.Array) -> jax.Array:
     """Mean next-token cross entropy of tokens [B, T+1]."""
-    return -token_logprobs(params, tokens).mean()
+    return -token_logprobs(logits, params, tokens).mean()
 
 
-def program_initial_weights(model: dict) -> Dict:
+def program_initial_weights(config: dict) -> Dict:
     """The weights the program starts from (trainer and engine both
     initialise from ``PRNGKey(0)``), made by the program's own ``init_params``
     in one jitted call on this process's default device."""
-    from ray_tpu.models.gpt2 import GPT2Config, init_params
+    from ray_tpu.models import module_for
 
-    cfg = GPT2Config(**{k: model[k] for k in (
-        "vocab_size", "max_seq_len", "num_layers", "num_heads", "embed_dim")})
-    return jax.jit(init_params, static_argnums=0)(cfg, jax.random.PRNGKey(0))
+    cfg = program.model_config(config)
+    return jax.jit(module_for(cfg).init_params, static_argnums=0)(
+        cfg, jax.random.PRNGKey(0))
 
 
 def in_blocks(fn, params: Dict, tokens, block: int):

@@ -8,8 +8,10 @@ metric is a file found by the name ``BENCHMARK.json`` gives it:
 - ``benchmarks/workloads/<cell>.json``: the runner (``train_job`` or
   ``serve_open_loop``, a module of ``benchmarks/runners``) and the job's or
   the traffic mix's parameters;
-- the configuration's ``file``: the model's sizes and how it is trained
-  and served;
+- the configuration's ``file``: the model's ``family`` and sizes, how it is
+  trained and served, and the names of its plain reference
+  (``benchmarks/references/<name>.py``) and of its FLOP count
+  (``benchmarks/costs/<name>.py``);
 - ``benchmarks/layer_metrics/<metric>.py``: ``read(trace, facts)`` returns
   the number, or ``None`` when there is nothing to read.
 
@@ -25,7 +27,6 @@ STARTED = time.monotonic()  # set-up is counted from here
 
 import argparse  # noqa: E402
 import importlib  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
@@ -40,7 +41,11 @@ def load_cell(name: str, root: str = CHECKOUT) -> tuple:
     """(cell entry, cell file, configuration file, per-layer and end-to-end
     metric entries of this cell) from ``<root>/BENCHMARK.json`` and the
     files it names. ``root`` is the checkout; the rehearsal points it at a
-    toy benchmark under ``benchmarks/tests/data``."""
+    toy benchmark under ``benchmarks/tests/data``. The configuration states
+    its ``family`` and names its ``reference`` and ``costs``: ``files`` holds
+    where each was found."""
+    from benchmarks.lib import named
+
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = {w["name"]: w for w in bench["workloads"]}
@@ -54,6 +59,12 @@ def load_cell(name: str, root: str = CHECKOUT) -> tuple:
         cell = dict(json.load(f), name=name)
     with open(os.path.join(root, config_entry["file"])) as f:
         config = dict(json.load(f), name=config_entry["name"])
+    named.need(config, "family", config_entry["file"])
+    config["files"] = {
+        key: named.find(key, named.need(config, key, config_entry["file"]),
+                        os.path.join(root, bench["paths"][0]),
+                        config_entry["file"])
+        for key in named.KINDS}
     per_layer = [m for m in bench["per_layer"]
                  if name in m.get("workloads", [name])]
     end_to_end = [m for m in bench["end_to_end"]
@@ -62,12 +73,10 @@ def load_cell(name: str, root: str = CHECKOUT) -> tuple:
 
 
 def read_layer_metric(name: str, trace, facts: dict):
-    path = os.path.join(BENCH_DIR, "layer_metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "layer_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read(trace, facts)
+    from benchmarks.lib import named
+
+    return named.load(os.path.join(
+        BENCH_DIR, "layer_metrics", name + ".py")).read(trace, facts)
 
 
 def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import asyncio
 import contextlib
+import functools
 import os
 import shutil
 import statistics
@@ -22,26 +23,7 @@ from typing import List
 
 import numpy as np
 
-from benchmarks.lib import cluster, loadgen, traffic
-
-
-def _engine_config(config: dict, platform: str):
-    from ray_tpu.llm import LLMConfig
-
-    serve = config["serve"]
-    return LLMConfig(
-        model_id=config["name"], model_family="gpt2",
-        dtype=serve["dtype"], max_batch_slots=int(serve["max_batch_slots"]),
-        prefill_buckets=tuple(serve["prefill_buckets"]),
-        prefix_cache_size=int(serve["prefix_cache_size"]),
-        tokenizer=serve["tokenizer"],
-        deployment_config=(
-            {"ray_actor_options": {"num_tpus": 1}} if platform == "tpu"
-            else {}),
-        **{k: config["model"][k] for k in (
-            "vocab_size", "max_seq_len", "num_layers", "num_heads",
-            "embed_dim")},
-    )
+from benchmarks.lib import cluster, loadgen, program, traffic
 
 
 @contextlib.contextmanager
@@ -55,7 +37,11 @@ def deployed(config: dict, platform: str, chips: int):
     cluster.start("serve")
     try:
         handle = serve.run(
-            build_openai_app(_engine_config(config, platform)), name="llm",
+            build_openai_app(program.llm_config(
+                config, deployment_config=(
+                    {"ray_actor_options": {"num_tpus": 1}}
+                    if platform == "tpu" else {}))),
+            name="llm",
             route_prefix="/v1", _blocking_timeout=600.0)
         port = serve.start_http_proxy()
         info = handle.replica_info.remote().result(timeout=600)
@@ -86,25 +72,26 @@ def _prompt(rng, n: int) -> str:
     return "".join(chr(c) for c in rng.integers(97, 123, n))
 
 
-def _reference(model: dict, jobs):
-    """Float32 rows of the plain reference under the engine's own initial
-    weights (the engine initialises from PRNGKey(0)), on this process's
-    first device, after the cluster released the chip. ``jobs`` are
-    (function of ``benchmarks.lib.reference``, sequences, length to pad
-    to); one list of rows a job."""
+def _reference(config: dict, jobs):
+    """Float32 rows of the plain reference the configuration names, under
+    the engine's own initial weights (the engine initialises from
+    PRNGKey(0)), on this process's first device, after the cluster released
+    the chip. ``jobs`` are (function of ``benchmarks.lib.reference``,
+    sequences, length to pad to); one list of rows a job."""
     import jax
 
     from benchmarks.lib import reference
 
-    out = []
+    out, logits = [], reference.logits_of(config)
     with jax.default_device(jax.devices()[0]):
-        params = reference.program_initial_weights(model)
+        params = reference.program_initial_weights(config)
         for fn, sequences, pad_to in jobs:
             toks = np.zeros((len(sequences), pad_to), np.int32)
             for i, s in enumerate(sequences):
                 toks[i, :len(s)] = s  # causal: padding cannot reach a token
             out.append(reference.in_blocks(
-                getattr(reference, fn), params, toks, 1))
+                functools.partial(getattr(reference, fn), logits), params,
+                toks, 1))
     return out
 
 
@@ -195,7 +182,11 @@ async def _drive(cell, config, port, handle, *, seed, seconds, trace,
         cpu_open = loop.run_in_executor(None, cluster.node_cpu_seconds)
         profile = None
         if trace:
-            await asyncio.sleep(float(mix["trace_after_seconds"]))
+            t_capture = t_open + capture_start_s(
+                [r.due_s - ramp for r in window],
+                float(mix["trace_after_seconds"]),
+                float(mix["trace_seconds"]), seconds)
+            await asyncio.sleep(max(0.0, t_capture - time.monotonic()))
             profile = loop.run_in_executor(
                 None, cluster.capture_on_node,
                 float(mix["trace_seconds"]), trace_dir)
@@ -217,6 +208,12 @@ async def _drive(cell, config, port, handle, *, seed, seconds, trace,
         if profile is not None:
             res = await profile
             cluster.require(res.get("ok"), f"profiler capture failed: {res}")
+            captured = [res["started"] - t_open, res["stopped"] - t_open]
+            cluster.log({
+                "cell": cell["name"], "capture_s_after_open": captured,
+                "arrivals_due_in_capture": sum(
+                    1 for r in window
+                    if captured[0] <= r.due_s - ramp <= captured[1])})
 
         def delta(a, b):
             return {k: b["engine_stats"][k] - a["engine_stats"][k]
@@ -246,6 +243,27 @@ async def _drive(cell, config, port, handle, *, seed, seconds, trace,
             "engine_window": delta(await stats_open, await stats_close),
             "node_cpu_s": await cpu_close - await cpu_open,
             "sample": sample, "setup_s": t_open - started}
+
+
+def capture_start_s(due, after_s: float, span_s: float,
+                    window_s: float) -> float:
+    """Seconds after the window opens at which the profiler's capture is
+    asked for: ``after_s``, or as much later as it takes for a request of
+    ``due`` (seconds after the opening) to arrive inside the capture, a
+    quarter of its ``span_s`` or more from either end.
+
+    The readers of ``engine.admit`` need an admission in every seed's
+    trace, and an admission follows an arrival. A capture at a fixed place
+    has none in one seed of fourteen at 0.6 requests/s over 4 s (the
+    stratified gaps reach 6.9 s), and the driver's check of PR 26 was
+    refused for a traced line without ``engine.admit_stall_ms``. Two seeds
+    of three are captured where they were, the others up to 5 s later."""
+    edge = span_s / 4
+    for t in sorted(due):
+        start = max(after_s, t - (span_s - edge))
+        if t >= start + edge and start + span_s <= window_s:
+            return start
+    return after_s  # no arrival to wait for
 
 
 def _tokens_made(counters: dict) -> int:
@@ -291,7 +309,7 @@ def tokens_per_request(asked, in_schedule, recount, in_recount):
 
 def run(cell: dict, config: dict, *, seed: int, seconds: float, trace: bool,
         platform: str, chips: int, started: float) -> dict:
-    mix, model = cell["traffic"], config["model"]
+    mix = cell["traffic"]
     trace_dir = os.path.join(cluster.WORK_DIR, "trace", cell["name"])
     shutil.rmtree(trace_dir, ignore_errors=True)
     with deployed(config, platform, chips) as (handle, port, info):
@@ -390,7 +408,7 @@ def run(cell: dict, config: dict, *, seed: int, seconds: float, trace: bool,
     jobs = [("token_logprobs", sequences, int(mix["check_pad_to"]))]
     if ended:
         jobs.append(("greedy_gaps", ended, int(mix["context_limit"])))
-    rows = _reference(model, jobs)
+    rows = _reference(config, jobs)
     worst = 0.0
     for (n_prompt, got_lp), row in zip(served, rows[0]):
         want = row[n_prompt - 1:n_prompt - 1 + len(got_lp)]

@@ -13,6 +13,7 @@ and make the window's length drift with every optimisation.
 """
 from __future__ import annotations
 
+import functools
 import math
 import os
 import shutil
@@ -21,7 +22,7 @@ import time
 
 import numpy as np
 
-from benchmarks.lib import cluster, costs, peaks
+from benchmarks.lib import cluster, named, peaks, program
 
 
 def timed_train_loop(config: dict):
@@ -71,17 +72,19 @@ def first_batch(seed: int, vocab_size: int, batch: int, seq_len: int):
         0, vocab_size, (batch, seq_len + 1), dtype=np.int32)
 
 
-def reference_first_loss(model: dict, tokens, block: int) -> float:
+def reference_first_loss(config: dict, tokens, block: int) -> float:
     """Plain float32 loss of the first batch under the program's own
-    initial weights (PRNGKey(0)), on this process's first device — called
-    only after the cluster has released the chip."""
+    initial weights (PRNGKey(0)), by the reference the configuration names,
+    on this process's first device — called only after the cluster has
+    released the chip."""
     import jax
 
     from benchmarks.lib import reference
 
+    loss = functools.partial(reference.loss, reference.logits_of(config))
     with jax.default_device(jax.devices()[0]):
-        params = reference.program_initial_weights(model)
-        per_block = reference.in_blocks(reference.loss, params, tokens, block)
+        params = reference.program_initial_weights(config)
+        per_block = reference.in_blocks(loss, params, tokens, block)
     return float(np.mean(per_block))
 
 
@@ -98,7 +101,7 @@ def run(cell: dict, config: dict, *, seed: int, seconds: float, trace: bool,
     storage = os.path.join(cluster.WORK_DIR, "train_storage")
     shutil.rmtree(storage, ignore_errors=True)
     loop_config = {
-        "model": {**model, **config["train"]},
+        "model": program.trainer_model(config),
         "mesh": job["mesh"],
         "optimizer": job.get("optimizer", {}),
         "num_steps": 10 ** 9,  # the stop_event ends the job, not a count
@@ -161,14 +164,15 @@ def run(cell: dict, config: dict, *, seed: int, seconds: float, trace: bool,
     losses = [m["loss"] for m in history]
     finite = all(math.isfinite(x) for x in losses)
     tokens = first_batch(seed, model["vocab_size"], batch, seq_len)
-    ref = reference_first_loss(model, tokens, int(job["reference_block"]))
+    ref = reference_first_loss(config, tokens, int(job["reference_block"]))
     tol = float(job["first_loss_tolerance"])
     close = abs(losses[0] - ref) <= tol
     cluster.log({"cell": cell["name"], "first_loss": losses[0],
                  "reference_first_loss": ref, "tolerance": tol,
                  "all_losses_finite": finite})
 
-    flops = costs.gpt2_train_flops_per_token(model, seq_len)
+    flops = named.load(config["files"]["costs"]).train_flops_per_token(
+        model, seq_len)
     peak = peaks.peaks_for(device["kind"])["bf16_flops_per_s"] \
         if platform == "tpu" else None
     return {

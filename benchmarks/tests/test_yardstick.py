@@ -1,11 +1,21 @@
 """The peaks table, the FLOP and byte functions and the traffic generator,
 each against a count made by hand."""
+import json
 import math
+import os
 
 import pytest
 
-from benchmarks.lib import costs, peaks, traffic
+from benchmarks.lib import costs, named, peaks, traffic
 from benchmarks.lib.loadgen import percentile
+
+TOY = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
+                   "tiny", "benchmarks")
+
+
+def _named(key, name, bench_dir=named.BENCH_DIR):
+    return named.load(named.find(key, name, bench_dir, "the yardstick's test"))
+
 
 MEDIUM = {"vocab_size": 50304, "max_seq_len": 1024, "num_layers": 24,
           "num_heads": 16, "embed_dim": 1024}
@@ -27,13 +37,14 @@ def test_v5e_peaks_and_unknown_device_is_an_error():
 def test_param_count_by_hand():
     # medium: a block holds 12 E^2 of matrices (4 E^2 attention, 8 E^2 MLP)
     # and 13 E of vectors (qkv 3E, proj E, fc 4E, out E, two LNs 4E)
-    n = costs.gpt2_param_count(MEDIUM)
+    gpt2 = _named("costs", "gpt2")
+    n = gpt2.param_count(MEDIUM)
     assert n["block_matrices"] == 24 * 12 * 1024 * 1024 == 301_989_888
     assert n["embedding"] == 50304 * 1024 == 51_511_296
     assert n["total"] == (301_989_888 + 24 * 13 * 1024 + 51_511_296
                           + 1024 * 1024 + 2 * 1024) == 354_871_296
     # xl: published as "1.5B"; with the padded vocabulary 1,557,686,400
-    assert costs.gpt2_param_count(XL)["total"] == (
+    assert gpt2.param_count(XL)["total"] == (
         48 * (12 * 1600 * 1600 + 13 * 1600) + 50304 * 1600 + 1024 * 1600
         + 3200) == 1_557_686_400
 
@@ -41,10 +52,32 @@ def test_param_count_by_hand():
 def test_train_flops_per_token_by_hand():
     # medium, T=1024: 6 x (301,989,888 + 51,511,296) + 6 x 24 x 1024 x 1024
     want = 6 * 353_501_184 + 6 * 24 * 1024 * 1024
-    assert costs.gpt2_train_flops_per_token(MEDIUM, 1024) == want
+    assert _named("costs", "gpt2").train_flops_per_token(MEDIUM, 1024) == want
     assert want == 2_272_002_048  # 2.27 GFLOP a token
     # at 36.5k tokens/s (PR 21's bare loop) that is 42% of 197 TFLOP/s
     assert math.isclose(36_500 * want / 197e12, 0.421, abs_tol=1e-3)
+
+
+def test_the_second_family_costs_by_hand():
+    """The toy's ``costs/llama.py``, found under the toy's own directory:
+    LLAMA_TINY is 2 layers, 64 wide, 4 query heads over 2 key/value heads of
+    16, a gated MLP of 256 (8/3 x 64 = 170, rounded up to 128s), vocabulary
+    512, head untied."""
+    llama = _named("costs", "llama", TOY)
+    tiny = {"vocab_size": 512, "max_seq_len": 128, "num_layers": 2,
+            "num_heads": 4, "num_kv_heads": 2, "embed_dim": 64}
+    n = llama.param_count(tiny)
+    # a layer: wq, wo 64 x 64 each; wk, wv 64 x 32 each; three of 64 x 256
+    assert n["block_matrices"] == 2 * (2 * 4096 + 2 * 2048 + 3 * 16384) \
+        == 122_880
+    assert n["embedding"] == n["head"] == 512 * 64 == 32_768
+    assert n["total"] == 122_880 + 2 * 32_768 + (2 * 2 * 64 + 64) == 188_736
+    assert llama.param_count({**tiny, "mlp_dim": 192})["block_matrices"] \
+        == 2 * (2 * 4096 + 2 * 2048 + 3 * 64 * 192)
+    # T=64: 6 x (122,880 + 32,768) + 6 x 2 x 64 x 64
+    assert llama.train_flops_per_token(tiny, 64) == 933_888 + 49_152
+    with pytest.raises(FileNotFoundError, match="'costs' names 'llama'"):
+        _named("costs", "llama")  # the benchmark itself has no such family
 
 
 def test_flash_cost_by_hand():
@@ -157,6 +190,41 @@ def test_tokens_per_request(name, asked, made, recount, remade, want):
     assert got == want, name
 
 
+@pytest.mark.parametrize("name,due,want", [
+    ("an arrival well inside: captured where it always was",
+     [0.5, 5.0, 20.0], 3.0),
+    ("arrivals only at the capture's edges: moved to the first",
+     [3.2, 6.9, 20.0], 3.9),
+    ("the schedule's longest gap over the old place", [1.0, 9.0], 6.0),
+    ("no arrival it could hold before the window closes", [1.0, 49.5], 3.0),
+])
+def test_capture_start_by_hand(name, due, want):
+    from benchmarks.runners.serve_open_loop import capture_start_s
+
+    assert capture_start_s(due, 3.0, 4.0, 50.0) == pytest.approx(want), name
+
+
+@pytest.mark.parametrize("seed", [
+    7, 3000000019, 2147483671, 2147483683, 2147483685])
+def test_capture_holds_an_arrival_in_the_serving_cell(seed):
+    """The last three seeds have no arrival within 2.5-7.5 s of the
+    opening, where the capture used to lie whatever the schedule: their
+    traces held no ``engine.admit`` for ``engine.admit_stall_ms`` to read."""
+    from benchmarks.runners.serve_open_loop import capture_start_s
+
+    with open(os.path.join(named.BENCH_DIR, "workloads",
+                           "gpt2-xl.serve-chat.json")) as f:
+        mix = json.load(f)["traffic"]
+    span, window = float(mix["trace_seconds"]), 50.0
+    due = [r.due_s for r in traffic.schedule(mix, seed, window)]
+    start = capture_start_s(
+        due, float(mix["trace_after_seconds"]), span, window)
+    assert start >= float(mix["trace_after_seconds"])
+    assert start + span <= window
+    assert any(start + span / 4 - 1e-9 <= t <= start + 3 * span / 4 + 1e-9
+               for t in due)
+
+
 def test_recount_reads_the_tokens_of_a_unary_answer(monkeypatch):
     """The recount sends each request again, unary with ``logprobs: 1``,
     and returns the tokens of its answer: None where it failed or holds
@@ -199,16 +267,19 @@ def test_greedy_gaps_by_hand():
     from benchmarks.lib import reference
     from ray_tpu.models.gpt2 import GPT2Config, init_params
 
+    logits = _named("reference", "gpt2").logits
+
     cfg = GPT2Config(vocab_size=5, max_seq_len=8, num_layers=1, num_heads=1,
                      embed_dim=8)
     params = init_params(cfg, jax.random.PRNGKey(3))
     tokens = np.array([[2, 4, 1, 3, 0, 2]], np.int32)
     logp = np.asarray(jax.nn.log_softmax(
-        reference.logits(params, tokens[:, :-1]), axis=-1))[0]
-    gaps = np.asarray(reference.greedy_gaps(params, tokens))[0]
+        logits(params, tokens[:, :-1]), axis=-1))[0]
+    gaps = np.asarray(reference.greedy_gaps(logits, params, tokens))[0]
     for t in range(5):
         want = logp[t].max() - logp[t, tokens[0, t + 1]]
         assert gaps[t] == pytest.approx(want, abs=1e-6) and gaps[t] >= 0
     greedy = tokens.copy()
     greedy[0, 3] = logp[2].argmax()  # position 3 now holds the greedy choice
-    assert np.asarray(reference.greedy_gaps(params, greedy))[0, 2] == 0
+    assert np.asarray(
+        reference.greedy_gaps(logits, params, greedy))[0, 2] == 0
