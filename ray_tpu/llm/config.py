@@ -4,9 +4,9 @@ Reference analog: ``python/ray/llm/_internal/common/models.py`` /
 ``serve/engines/vllm/vllm_models.py`` — ``LLMConfig`` carrying model id,
 engine kwargs (tensor_parallel_size etc.), and serving knobs. The reference
 delegates the engine to vLLM; here the engine is in-framework
-(``ray_tpu/llm/engine.py`` — jitted JAX prefill/decode on the flagship
-model), so engine kwargs map onto GPT2Config + mesh axes instead of vLLM
-arguments.
+(``ray_tpu/llm/engine.py`` — jitted JAX prefill/decode on a model of
+``ray_tpu/models``), so engine kwargs map onto that family's config
+(``models.config_for``) + mesh axes instead of vLLM arguments.
 """
 from __future__ import annotations
 
@@ -29,11 +29,28 @@ class LLMConfig:
     num_kv_heads: Optional[int] = None  # llama GQA; None = num_heads (MHA)
     embed_dim: int = 256
     dtype: str = "bfloat16"
-    # Mixture-of-Experts (Mixtral-style when model_family="llama"): number
-    # of routed experts; 0 = dense. Decode routes each token through its
-    # top-k experts (parallel/moe.py).
+    # Architecture numbers only some families take; None = not stated, the
+    # family's own default. One that is stated goes to the family's config
+    # under its own name, and a family that does not take it refuses it by
+    # that name (gpt2 has no ``rope_theta``).
+    mlp_dim: Optional[int] = None        # llama: MLP / expert width
+    rope_theta: Optional[float] = None   # llama: rotary base
+    rms_eps: Optional[float] = None      # llama: RMSNorm epsilon
+    qk_norm: Optional[str] = None        # llama: "none" | "full" (OLMoE)
+    param_dtype: Optional[str] = None    # dtype the weights are held in
+    # Routed experts in place of the MLP (``parallel/moe.py``): their number
+    # (0 = dense) and how many a token reaches. GELU experts under gpt2,
+    # SwiGLU under llama (Mixtral: 8 / 2; OLMoE: 64 / 8 and
+    # ``moe_norm_topk_prob=False``, the k gates as the softmax gives them).
+    # Served models route dropless: every token reaches its top-k experts.
     moe_num_experts: int = 0
     moe_top_k: int = 2
+    moe_norm_topk_prob: bool = True
+    # Scale of the router's initial weights where the weights are fresh
+    # (no ``model_source``); None = ``MoEConfig``'s, where training starts.
+    # Random weights served in place of a trained model's state a larger
+    # one (``MoEConfig.router_init_std`` says why).
+    moe_router_init_std: Optional[float] = None
 
     # Engine knobs (reference: engine_kwargs tensor_parallel_size etc.)
     max_batch_slots: int = 8
@@ -63,43 +80,41 @@ class LLMConfig:
     deployment_config: Dict[str, Any] = field(default_factory=dict)
 
     def model_config(self):
-        import jax.numpy as jnp
+        from ray_tpu.models import config_for
 
-        dtype = jnp.bfloat16 if self.dtype == "bfloat16" else jnp.float32
-        moe = None
-        if self.moe_num_experts:
-            from ray_tpu.parallel.moe import MoEConfig
-
-            moe = MoEConfig(
-                num_experts=self.moe_num_experts,
-                top_k=self.moe_top_k,
-                activation=(
-                    "swiglu" if self.model_family == "llama" else "gelu"
-                ),
-            )
-        common = dict(
+        kwargs: Dict[str, Any] = dict(
             vocab_size=self.vocab_size,
             max_seq_len=self.max_seq_len,
             num_layers=self.num_layers,
             num_heads=self.num_heads,
             embed_dim=self.embed_dim,
-            dtype=dtype,
+            dtype=self.dtype,
             attention_impl="xla",
-            moe=moe,
         )
         if self.model_family == "llama":
-            from ray_tpu.models.llama import LlamaConfig
-
-            return LlamaConfig(
-                num_kv_heads=self.num_kv_heads or self.num_heads, **common
+            kwargs["num_kv_heads"] = self.num_kv_heads or self.num_heads
+        elif self.num_kv_heads is not None:
+            kwargs["num_kv_heads"] = self.num_kv_heads
+        for name in ("mlp_dim", "rope_theta", "rms_eps", "qk_norm",
+                     "param_dtype"):
+            if getattr(self, name) is not None:
+                kwargs[name] = getattr(self, name)
+        if self.moe_num_experts:
+            kwargs["moe"] = dict(
+                num_experts=self.moe_num_experts,
+                top_k=self.moe_top_k,
+                norm_topk_prob=self.moe_norm_topk_prob,
+                # Inference routes dropless: capacity-queue drops depend on
+                # the rest of the batch, so prefill and per-step decode
+                # would disagree (and with the full forward).
+                dropless=True,
+                activation=(
+                    "swiglu" if self.model_family == "llama" else "gelu"
+                ),
             )
-        if self.model_family == "gpt2":
-            from ray_tpu.models.gpt2 import GPT2Config
-
-            return GPT2Config(**common)
-        raise ValueError(
-            f"unknown model_family {self.model_family!r} (gpt2 | llama)"
-        )
+            if self.moe_router_init_std is not None:
+                kwargs["moe"]["router_init_std"] = self.moe_router_init_std
+        return config_for(self.model_family, **kwargs)
 
     def to_dict(self) -> dict:
         d = dict(self.__dict__)

@@ -20,6 +20,15 @@ timeline: ``engine.tick`` (children ``.pack``, ``.dispatch``, ``.fetch``,
 ``engine.idle`` are ``jax.profiler.TraceAnnotation`` spans, inert unless a
 capture runs (``rt profile --xla``). Their arguments are the counters of
 that boundary, and ``stats`` sums the same quantities with no capture.
+
+A model with routed experts (``parallel/moe.py``) is told which rows of a
+program carry a token (the slots that decode, a prompt's own positions in
+its prefill bucket), so that nothing else is routed, and its programs hand
+back, beside the logits and in the same fetch, how many distinct experts
+received a row in each layer: ``experts_touched`` and ``moe_rows`` of
+``engine.tick`` (with ``moe_layers``, to divide by) and ``engine.admit``,
+``moe_experts_touched`` and ``moe_rows`` of ``stats``. A dense model's
+programs are as they were.
 """
 from __future__ import annotations
 
@@ -119,17 +128,20 @@ def engine_programs(cfg):
     """The engine's four XLA programs for model config ``cfg``: (prefill,
     insert, decode, decode_all), jitted and not yet compiled. The cache is
     the model module's pytree with the slot on axis 1; ``insert`` and both
-    decodes take it donated and give it back in the same buffer."""
+    decodes take it donated and give it back in the same buffer. For a
+    model with routed experts the three model programs take one more
+    argument, ``real`` [B] (how many of a row's tokens are tokens), and
+    give one more result after the cache, the experts touched a layer [L]."""
     import jax
 
     from ray_tpu.models import module_for
 
     model = module_for(cfg)
 
-    def prefill(params, tokens, cache1, start):
+    def prefill(params, tokens, cache1, start, *real):
         # start > 0 = continuation from a cached prefix: only the
         # prompt's tail runs through the model
-        return model.forward_cached(params, tokens, cache1, start, cfg)
+        return model.forward_cached(params, tokens, cache1, start, cfg, *real)
 
     def insert(batch_cache, slot_cache, b):
         return jax.tree.map(
@@ -139,14 +151,15 @@ def engine_programs(cfg):
             batch_cache, slot_cache,
         )
 
-    def decode(params, tokens, cache, lens):
-        logits, cache = model.forward_cached(params, tokens, cache, lens, cfg)
-        return logits[:, -1], cache
+    def decode(params, tokens, cache, lens, *real):
+        logits, *rest = model.forward_cached(
+            params, tokens, cache, lens, cfg, *real)
+        return (logits[:, -1], *rest)
 
-    def decode_all(params, tokens, cache, lens):
+    def decode_all(params, tokens, cache, lens, *real):
         # speculation verify: logits at EVERY position (position j's
         # row predicts the token after input j)
-        return model.forward_cached(params, tokens, cache, lens, cfg)
+        return model.forward_cached(params, tokens, cache, lens, cfg, *real)
 
     return (
         jax.jit(prefill),
@@ -161,7 +174,7 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.models import module_for
+        from ray_tpu.models import config_for, module_for
 
         self.config = config
         self.model_config = config.model_config()
@@ -174,25 +187,23 @@ class DecodeEngine:
             if "config" in bundle:
                 # checkpoint architecture wins over LLMConfig defaults — a
                 # mismatch would allocate a KV cache with the wrong layout
-                family = bundle.get("family", self.config.model_family)
-                if family == "llama":
-                    from ray_tpu.models.llama import LlamaConfig
-
-                    self.model_config = LlamaConfig(**bundle["config"])
-                else:
-                    from ray_tpu.models.gpt2 import GPT2Config
-
-                    self.model_config = GPT2Config(**bundle["config"])
+                self.model_config = config_for(
+                    bundle.get("family", self.config.model_family),
+                    **bundle["config"])
+        # routed experts: the layers whose rows and touched experts the
+        # programs report (0 = a dense model, whose programs report nothing)
+        self._moe_layers = self._moe_top_k = 0
         if getattr(self.model_config, "moe", None) is not None:
-            # Inference must route dropless: capacity-queue drops depend on
-            # the rest of the batch, so prefill and per-step decode would
-            # disagree (and with the full forward) on dropped tokens.
+            # a bundle's configuration may be a training one (capacity
+            # queues); inference routes dropless (``LLMConfig.model_config``)
             import dataclasses
 
             self.model_config = dataclasses.replace(
                 self.model_config,
                 moe=dataclasses.replace(self.model_config.moe, dropless=True),
             )
+            self._moe_layers = self.model_config.num_layers
+            self._moe_top_k = self.model_config.moe.top_k
         model = module_for(self.model_config)
         self.tokenizer = load_tokenizer(config)
         if params is None:
@@ -239,6 +250,10 @@ class DecodeEngine:
             "queue_wait_s": 0.0, "admit_s": 0.0, "slot_ticks": 0,
             "finished_length": 0, "finished_eos": 0, "finished_stop": 0,
             "finished_context": 0,
+            # routed (token, expert, layer) rows (real rows x k x layers) and
+            # distinct experts that received one, summed over layers and
+            # programs; both 0 for a dense model
+            "moe_rows": 0, "moe_experts_touched": 0,
             "compiles": compile_count(),  # of the process, not the engine
         }
         self._span = jax.profiler.TraceAnnotation
@@ -375,10 +390,33 @@ class DecodeEngine:
         while len(self._prefix_cache) > cap:
             self._prefix_cache.popitem(last=False)
 
+    def _real(self, counts) -> tuple:
+        """The model programs' last argument: how many of each row's tokens
+        are tokens. A dense model's programs take none."""
+        import jax.numpy as jnp
+
+        return (jnp.asarray(counts, jnp.int32),) if self._moe_layers else ()
+
+    def _fetch(self, logits, touched, real_rows: int):
+        """One fetch for a program's logits and, from a model with routed
+        experts, its experts touched a layer -> (logits on the host, the
+        span's ``moe_rows`` and ``experts_touched``), summed into ``stats``."""
+        import jax
+
+        logits, *touched = jax.device_get((logits, *touched))
+        moe = {
+            "moe_rows": real_rows * self._moe_top_k * self._moe_layers,
+            "experts_touched": int(touched[0].sum()) if touched else 0,
+        }
+        self.stats["moe_rows"] += moe["moe_rows"]
+        self.stats["moe_experts_touched"] += moe["experts_touched"]
+        return logits, moe
+
     def _prefill_locked(self, prompt_ids, params, rng=None):
         """(slot_cache jax pytree, first_token, first_logprob, how). Caller
         holds the lock. ``how`` is the admission span's ``bucket`` (0: no
-        program ran) and ``prefix`` (none | partial | exact).
+        program ran), ``prefix`` (none | partial | exact), ``moe_rows`` and
+        ``experts_touched`` (what its program routed).
         Consults the prefix cache: an exact hit skips the model entirely; a
         strict-prefix hit prefills only the tail from the cached KV state."""
         import jax.numpy as jnp
@@ -398,7 +436,9 @@ class DecodeEngine:
                 first, lp = self._sample(
                     entry["logits_row"], params, prompt_ids, (), rng
                 )
-            return entry["cache"], first, lp, {"bucket": 0, "prefix": "exact"}
+            return entry["cache"], first, lp, {
+                "bucket": 0, "prefix": "exact", "moe_rows": 0,
+                "experts_touched": 0}
         if entry is not None and (
             matched + self._bucket(n - matched) > self.config.max_seq_len
         ):
@@ -413,23 +453,24 @@ class DecodeEngine:
         with span("engine.prefill.dispatch"):
             toks = np.zeros((1, Tpad), np.int32)
             toks[0, : len(rem)] = rem
-            logits, cache1 = self._prefill(
+            logits, cache1, *touched = self._prefill(
                 self.params, jnp.asarray(toks),
                 entry["cache"] if entry is not None
                 else self._empty_slot_cache(),
-                jnp.full((1,), base, jnp.int32),
+                jnp.full((1,), base, jnp.int32), *self._real([len(rem)]),
             )
         with span("engine.prefill.fetch"):
             # the wait for the program and its [1, bucket, V] logits' way
             # to the host
-            logits_np = np.asarray(logits)[0]
+            logits_np, moe = self._fetch(logits, touched, len(rem))
+            logits_np = logits_np[0]
         self._prefix_store_locked(prompt_ids, cache1, logits_np, base)
         with span("engine.prefill.sample"):
             first, lp = self._sample(
                 logits_np[len(rem) - 1], params, prompt_ids, (), rng
             )
         return cache1, first, lp, {
-            "bucket": Tpad, "prefix": "partial" if base else "none"}
+            "bucket": Tpad, "prefix": "partial" if base else "none", **moe}
 
     def _activate_slot_locked(self, b, cache1, first, req: _Pending,
                               prompt_len, prompt_ids=(), first_lp=None,
@@ -505,7 +546,8 @@ class DecodeEngine:
             first = int(prefilled["first_token"])
             prompt_ids = tuple(prefilled.get("prompt_ids", ()))
             first_lp = prefilled.get("first_logprob")
-            how = {"bucket": 0, "prefix": "none"}
+            how = {"bucket": 0, "prefix": "none", "moe_rows": 0,
+                   "experts_touched": 0}
             if params.seed is not None:
                 rng = self._rng_for(params)
                 if params.temperature > 0:
@@ -670,30 +712,32 @@ class DecodeEngine:
         span = self._span
         compiles = self.stats["compiles"]
         with span("engine.tick", tick=self.stats["ticks"],
-                  active=len(active)) as tick:
+                  active=len(active), moe_layers=self._moe_layers) as tick:
             with span("engine.tick.pack"):
                 toks = np.zeros((len(self._slots), width), np.int32)
                 lens = np.zeros((len(self._slots),), np.int32)
+                real = np.zeros((len(self._slots),), np.int32)
                 for i in active:
                     slot = self._slots[i]
                     toks[i, :] = slot.last_token
                     lens[i] = slot.length
                     d = drafts.get(i, ())
                     toks[i, 1:1 + len(d)] = d
+                    real[i] = 1 + len(d)
                 toks, lens = jnp.asarray(toks), jnp.asarray(lens)
             with span("engine.tick.dispatch"):
-                logits, self._cache = program(
-                    self.params, toks, self._cache, lens)
+                logits, self._cache, *touched = program(
+                    self.params, toks, self._cache, lens, *self._real(real))
             with span("engine.tick.fetch"):
                 # the wait for the device, then the logits' way to the host
-                logits = np.asarray(logits)
+                logits, moe = self._fetch(logits, touched, int(real.sum()))
             with span("engine.tick.sample"):
                 sample(logits)
             self.stats["ticks"] += 1
             self.stats["slot_ticks"] += len(active)
             self.stats["compiles"] = compile_count()
             tick.set_metadata(
-                compiled=int(self.stats["compiles"] > compiles))
+                compiled=int(self.stats["compiles"] > compiles), **moe)
         return True
 
     def _emit_token_locked(self, i: int, nxt: int, lp) -> None:

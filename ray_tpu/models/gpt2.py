@@ -30,8 +30,9 @@ from ray_tpu.ops.attention import attention
 from ray_tpu.parallel.moe import (
     MoEConfig,
     init_moe_params,
-    moe_layer,
+    moe_layer_counted,
     moe_param_axes,
+    stacked_for,
 )
 
 
@@ -123,7 +124,7 @@ def init_params(config: GPT2Config, key: jax.Array) -> Dict[str, Any]:
     }
     if config.moe is not None:
         params["blocks"]["moe"] = init_moe_params(
-            k[6], E, M, config.moe, pd, num_layers=L
+            k[6], E, M, config.moe, pd, num_layers=L, out_std=res_std
         )
     return params
 
@@ -216,16 +217,24 @@ def _attn_residual(layer, x, attn):
     return x + attn + layer["proj_b"].astype(x.dtype)
 
 
-def _mlp_residual(config: GPT2Config, layer, x, rng=None):
-    """ln2 + MLP (or MoE) + residual. Returns (x, aux_loss)."""
+def _mlp_residual(config: GPT2Config, layer, x, rng=None, row_mask=None,
+                  stacked=None):
+    """ln2 + MLP (or MoE) + residual. Returns (x, aux_loss, experts that
+    received a row: 0 for the dense MLP). ``row_mask`` [B, T] marks the rows
+    that carry a token; only the router asks. ``stacked`` is (every layer's
+    expert weights, this layer's index) where the caller kept them out of
+    its layer scan (``forward_cached``)."""
     h = _layer_norm(x, layer["ln2_g"], layer["ln2_b"])
     if config.moe is not None:
-        h, aux = moe_layer(layer["moe"], h, config.moe, rng=rng)
-        return x + h, aux
+        moe, index = (layer["moe"], None) if stacked is None else stacked
+        h, aux, touched = moe_layer_counted(
+            moe, h, config.moe, rng=rng, row_mask=row_mask, layer=index)
+        return x + h, aux, touched
     h = jnp.einsum("bte,em->btm", h, layer["fc_w"].astype(h.dtype))
     h = jax.nn.gelu(h + layer["fc_b"].astype(h.dtype))
     h = jnp.einsum("btm,me->bte", h, layer["out_w"].astype(h.dtype))
-    return x + h + layer["out_b"].astype(h.dtype), jnp.float32(0.0)
+    return (x + h + layer["out_b"].astype(h.dtype), jnp.float32(0.0),
+            jnp.int32(0))
 
 
 def _block(config: GPT2Config, mesh: Optional[Mesh], x, layer, rng=None):
@@ -235,7 +244,7 @@ def _block(config: GPT2Config, mesh: Optional[Mesh], x, layer, rng=None):
     q, k, v = _qkv(layer, h)
     attn = _attention_dispatch(config, q, k, v, mesh)
     x = _attn_residual(layer, x, attn)
-    return _mlp_residual(config, layer, x, rng=rng)
+    return _mlp_residual(config, layer, x, rng=rng)[:2]
 
 
 def forward_features(
@@ -315,6 +324,7 @@ def forward_cached(
     cache: Dict[str, jax.Array],
     start: jax.Array,
     config: GPT2Config,
+    real: Optional[jax.Array] = None,
 ) -> tuple:
     """Incremental forward: attend over the KV cache, append new K/V.
 
@@ -324,7 +334,10 @@ def forward_cached(
     static and every slot at its own offset, so slot-based continuous
     batching is one compiled program. The whole cache rides the layer scan
     as its carry and only the new tokens' columns change: a caller that
-    donates the cache gets it back in the same buffer.
+    donates the cache gets it back in the same buffer. ``real`` [B] (routed
+    experts only) is how many of a row's T tokens are tokens, as
+    :func:`ray_tpu.models.llama.forward_cached` has it; with it a third
+    result counts the experts that received a row in each layer, [L].
     """
     B, T = tokens.shape
     S = cache["k"].shape[-1]
@@ -336,6 +349,11 @@ def forward_cached(
     # causal vs cache: key visible iff key_pos <= query absolute position
     mask = key_pos <= pos[:, :, None]                       # [B, T, S]
     hit = kv_cache.write_positions(start, T, S)
+    rows = None if real is None else jnp.arange(T)[None, :] < real[:, None]
+    # as ``llama.forward_cached``: dropless experts stay out of the scan
+    blocks = dict(params["blocks"])
+    dropless = config.moe is not None and config.moe.dropless
+    moe = stacked_for(blocks.pop("moe"), config.dtype) if dropless else None
 
     def block(carry, layer):
         x, i, cache = carry
@@ -350,15 +368,18 @@ def forward_cached(
         attn = jnp.einsum("bhts,bhds->bthd", probs, cv)
         cache, attn = kv_cache.write_layer(cache, i, ck, cv, attn)
         x = _attn_residual(layer, x, attn)
-        x, _ = _mlp_residual(config, layer, x)
-        return (x, i + 1, cache), None
+        x, _, touched = _mlp_residual(
+            config, layer, x, row_mask=rows,
+            stacked=(moe, i) if dropless else None)
+        return (x, i + 1, cache), None if real is None else touched
 
-    (x, _, cache), _ = jax.lax.scan(
-        block, (x, jnp.int32(0), cache), params["blocks"]
+    (x, _, cache), touched = jax.lax.scan(
+        block, (x, jnp.int32(0), cache), blocks
     )
     x = _layer_norm(x, params["ln_f_g"], params["ln_f_b"])
     logits = jnp.einsum("bte,ve->btv", x, params["wte"].astype(x.dtype))
-    return logits.astype(jnp.float32), cache
+    logits = logits.astype(jnp.float32)
+    return (logits, cache) if real is None else (logits, cache, touched)
 
 
 def loss_fn(

@@ -4,12 +4,20 @@ Covers the architecture family the reference serves through its LLM layer
 (vLLM engine passthrough, ``python/ray/llm/_internal/serve/engines/vllm/``;
 the reference ships no model code of its own): RMSNorm, rotary position
 embeddings (RoPE), SwiGLU MLP, grouped-query attention (GQA), untied LM
-head. Same TPU-first skeleton as :mod:`ray_tpu.models.gpt2`:
+head. Its flags cover the published shapes built from that block: routed
+SwiGLU experts in place of the MLP (Mixtral: 8 experts, 2 a token, gates
+renormalised; OLMoE: 64 experts, 8 a token, gates as the softmax gives them),
+an RMSNorm over the whole projected q and k (OLMoE's ``qk_norm="full"``),
+weights held in bfloat16 (``param_dtype``). Same TPU-first skeleton as
+:mod:`ray_tpu.models.gpt2`:
 
 - plain-pytree params with a parallel logical-axis tree for pjit sharding
 - one scanned super-layer (``lax.scan`` over depth), remat on the body
 - pluggable attention (xla | flash pallas | ring | ulysses)
-- bfloat16 activations over f32 params
+- bfloat16 activations over f32 params (or bf16 params as they are: a cast
+  to the dtype an array already has is no operation); logits are the head's
+  float32 sums, and the cached forward (serving) sums its residual stream in
+  float32 too
 - static-shape KV cache (GQA-sized: kv heads, not query heads) for the
   slot-based continuous-batching decode engine
 """
@@ -28,8 +36,9 @@ from ray_tpu.ops.attention import attention
 from ray_tpu.parallel.moe import (
     MoEConfig,
     init_moe_params,
-    moe_layer,
+    moe_layer_counted,
     moe_param_axes,
+    stacked_for,
 )
 
 
@@ -52,9 +61,19 @@ class LlamaConfig:
     # block boundaries (max memory savings, ~1 extra forward of FLOPs).
     remat_policy: str = "dots"
     seq_axis: str = "seq"
-    # Mixtral-style MoE: replaces the SwiGLU MLP with routed experts (use
-    # MoEConfig(activation="swiglu") for the Mixtral shape).
+    # Routed experts in place of the SwiGLU MLP, each ``mlp_dim`` wide (use
+    # MoEConfig(activation="swiglu"); Mixtral: 8 experts, top_k 2; OLMoE: 64,
+    # top_k 8, norm_topk_prob False).
     moe: Optional[MoEConfig] = None
+    # "none" | "full": RMSNorm over the WHOLE projected q and k (all heads
+    # together), before the split into heads and before RoPE (OLMoE).
+    qk_norm: str = "none"
+
+    def __post_init__(self):
+        if self.qk_norm not in ("none", "full"):
+            raise ValueError(
+                f"LlamaConfig.qk_norm must be 'none' or 'full', got "
+                f"{self.qk_norm!r}")
 
     @property
     def head_dim(self) -> int:
@@ -70,6 +89,20 @@ class LlamaConfig:
     @property
     def q_per_kv(self) -> int:
         return self.num_heads // self.num_kv_heads
+
+    # the router's numbers under the flat names ``LLMConfig`` and a
+    # configuration file give them
+    @property
+    def moe_num_experts(self) -> int:
+        return self.moe.num_experts if self.moe is not None else 0
+
+    @property
+    def moe_top_k(self) -> Optional[int]:
+        return self.moe.top_k if self.moe is not None else None
+
+    @property
+    def moe_norm_topk_prob(self) -> Optional[bool]:
+        return self.moe.norm_topk_prob if self.moe is not None else None
 
 
 LLAMA_TINY = LlamaConfig(  # test size
@@ -120,10 +153,13 @@ def init_params(config: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         "wo": normal(k[4], (L, H, D, E), res_std),
         "mlp_norm": jnp.ones((L, E), pd),
     }
+    if config.qk_norm == "full":
+        blocks["q_norm"] = jnp.ones((L, H * D), pd)
+        blocks["k_norm"] = jnp.ones((L, KV * D), pd)
     if config.moe is not None:
         # routed experts replace the dense FFN (never materialize both)
         blocks["moe"] = init_moe_params(
-            k[5], E, M, config.moe, pd, num_layers=L
+            k[5], E, M, config.moe, pd, num_layers=L, out_std=res_std
         )
     else:
         blocks["w_gate"] = normal(k[5], (L, E, M))
@@ -157,6 +193,9 @@ def param_axes(config: LlamaConfig) -> Dict[str, Any]:
         "norm_f": ("norm",),
         "lm_head": ("vocab", "embed"),
     }
+    if config.qk_norm == "full":
+        axes["blocks"]["q_norm"] = ("stage", "norm")
+        axes["blocks"]["k_norm"] = ("stage", "norm")
     if config.moe is not None:
         for name in ("w_gate", "w_up", "w_down"):
             del axes["blocks"][name]
@@ -174,10 +213,11 @@ def _remat_policy(config):
     return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
 
 
-def _rms_norm(x, g, eps):
+def _rms_norm(x, g, eps, dtype=None):
+    """``dtype``: what the result is held in (default: as ``x`` is)."""
     x32 = x.astype(jnp.float32)
     scale = jax.lax.rsqrt((x32 * x32).mean(-1, keepdims=True) + eps)
-    return (x32 * scale * g).astype(x.dtype)
+    return (x32 * scale * g).astype(dtype or x.dtype)
 
 
 def _rope(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
@@ -216,33 +256,64 @@ def _attention_dispatch(config: LlamaConfig, q, k, v, mesh: Optional[Mesh]):
     return attention(q, k, v, causal=True, impl=impl, mesh=mesh)
 
 
-def _ffn(config: LlamaConfig, layer, x, rng=None):
-    """mlp_norm + SwiGLU MLP (or routed MoE) + residual → (x, aux_loss)."""
-    h = _rms_norm(x, layer["mlp_norm"], config.rms_eps)
+def _head(params, x):
+    """Final features [B, T, E] -> logits [B, T, V] float32, straight from
+    the product's float32 sums: a bf16 result made float32 afterwards had
+    lost 0.016 of a logit of 4 (a chosen token's log-probability was off by
+    that much before any layer's error), for nothing: the bytes written
+    are the same."""
+    return jnp.einsum("bte,ve->btv", x, params["lm_head"].astype(x.dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def _qkv(config: LlamaConfig, layer, h, pos):
+    """The attention preamble, once for the full forward and the cached one:
+    h [B, T, E] normed, pos [B, T] absolute -> q [B, T, H, D] and k
+    [B, T, KV, D], both rotated, and v [B, T, KV, D]."""
+    q = jnp.einsum("bte,ehd->bthd", h, layer["wq"].astype(h.dtype))
+    k = jnp.einsum("bte,ehd->bthd", h, layer["wk"].astype(h.dtype))
+    v = jnp.einsum("bte,ehd->bthd", h, layer["wv"].astype(h.dtype))
+    if config.qk_norm == "full":
+        B, T = h.shape[:2]
+        q = _rms_norm(q.reshape(B, T, -1), layer["q_norm"],
+                      config.rms_eps).reshape(q.shape)
+        k = _rms_norm(k.reshape(B, T, -1), layer["k_norm"],
+                      config.rms_eps).reshape(k.shape)
+    return (_rope(q, pos, config.rope_theta),
+            _rope(k, pos, config.rope_theta), v)
+
+
+def _ffn(config: LlamaConfig, layer, x, rng=None, row_mask=None,
+         stacked=None):
+    """mlp_norm + SwiGLU MLP (or routed experts) + residual -> (x, aux_loss,
+    experts that received a row: 0 for the dense MLP). ``row_mask`` [B, T]
+    marks the rows that carry a token; only the router asks. ``stacked`` is
+    (every layer's expert weights, this layer's index) where the caller kept
+    them out of its layer scan (``forward_cached``)."""
+    h = _rms_norm(x, layer["mlp_norm"], config.rms_eps, config.dtype)
     if config.moe is not None:
-        h, aux = moe_layer(layer["moe"], h, config.moe, rng=rng)
-        return x + h, aux
+        moe, index = (layer["moe"], None) if stacked is None else stacked
+        h, aux, touched = moe_layer_counted(
+            moe, h, config.moe, rng=rng, row_mask=row_mask, layer=index)
+        return x + h, aux, touched
     gate = jnp.einsum("bte,em->btm", h, layer["w_gate"].astype(h.dtype))
     up = jnp.einsum("bte,em->btm", h, layer["w_up"].astype(h.dtype))
     h = jax.nn.silu(gate) * up
     h = jnp.einsum("btm,me->bte", h, layer["w_down"].astype(h.dtype))
-    return x + h, jnp.float32(0.0)
+    return x + h, jnp.float32(0.0), jnp.int32(0)
 
 
 def _block(config: LlamaConfig, mesh: Optional[Mesh], x, layer,
            pos: jax.Array, rng=None):
     """One decoder block → (x, aux). x: [B, T, E], pos: [B, T] absolute."""
-    h = _rms_norm(x, layer["attn_norm"], config.rms_eps)
-    q = jnp.einsum("bte,ehd->bthd", h, layer["wq"].astype(h.dtype))
-    k = jnp.einsum("bte,ehd->bthd", h, layer["wk"].astype(h.dtype))
-    v = jnp.einsum("bte,ehd->bthd", h, layer["wv"].astype(h.dtype))
-    q = _rope(q, pos, config.rope_theta)
-    k = _rope(k, pos, config.rope_theta)
+    h = _rms_norm(x, layer["attn_norm"], config.rms_eps, config.dtype)
+    q, k, v = _qkv(config, layer, h, pos)
     k = _repeat_kv(k, config.q_per_kv)
     v = _repeat_kv(v, config.q_per_kv)
     attn = _attention_dispatch(config, q, k, v, mesh)
-    x = x + jnp.einsum("bthd,hde->bte", attn, layer["wo"].astype(x.dtype))
-    return _ffn(config, layer, x, rng=rng)
+    x = x + jnp.einsum("bthd,hde->bte", attn, layer["wo"].astype(attn.dtype))
+    x, aux, _ = _ffn(config, layer, x, rng=rng)
+    return x, aux
 
 
 def forward_features(
@@ -298,8 +369,7 @@ def forward(
 ) -> Tuple[jax.Array, jax.Array]:
     """tokens [B, T] int32 -> (logits [B, T, V] f32, moe aux loss)."""
     x, aux = forward_features(params, tokens, config, mesh, rng=rng)
-    logits = jnp.einsum("bte,ve->btv", x, params["lm_head"].astype(x.dtype))
-    return logits.astype(jnp.float32), aux
+    return _head(params, x), aux
 
 
 def init_kv_cache(config: LlamaConfig, batch: int, max_len: int,
@@ -319,29 +389,43 @@ def forward_cached(
     cache: Dict[str, jax.Array],
     start: jax.Array,
     config: LlamaConfig,
+    real: Optional[jax.Array] = None,
 ) -> tuple:
     """Incremental forward with RoPE at absolute positions; same contract as
     :func:`ray_tpu.models.gpt2.forward_cached` (static shapes, every slot at
     its own offset, the cache carried through the layer scan and written in
-    place). MoE configs route each decoded token through its top-k experts
-    (aux loss is a training-only concern and is discarded here)."""
+    place) -> (logits, cache): two results for a caller that gives no
+    ``real``, three for one that does, the one place where the arity follows
+    an argument (a third result, even of zeros, would change the compiled
+    programs of every dense model served). With routed experts every token
+    reaches its top-k experts (aux loss is a training-only concern and is
+    discarded here), and ``real`` [B] says how many of a row's T tokens are
+    tokens: 0 for an idle decode slot, the prompt's length in a prefill
+    bucket. The rest is routed to no expert. Given ``real``, a third result
+    counts the distinct experts that received a row in each layer, [L]
+    int32."""
     B, T = tokens.shape
     S = cache["k"].shape[-1]
     pos = start[:, None] + jnp.arange(T)[None, :]            # [B, T]
-    x = params["wte"][tokens].astype(config.dtype)
+    # The residual stream is summed in float32 here (the sublayers compute
+    # in ``config.dtype``): two roundings a layer of the whole stream were
+    # the larger part of a served token's distance from a float32 forward.
+    x = params["wte"][tokens].astype(jnp.float32)
 
     key_pos = jnp.arange(S)[None, None, :]
     mask = key_pos <= pos[:, :, None]                        # [B, T, S]
     hit = kv_cache.write_positions(start, T, S)
+    rows = None if real is None else jnp.arange(T)[None, :] < real[:, None]
+    # The experts' weights stay out of the scan: it would hand each layer
+    # its slice, and a slice that feeds a kernel is a copy (``moe._experts``)
+    blocks = dict(params["blocks"])
+    dropless = config.moe is not None and config.moe.dropless
+    moe = stacked_for(blocks.pop("moe"), config.dtype) if dropless else None
 
     def block(carry, layer):
         x, i, cache = carry
-        h = _rms_norm(x, layer["attn_norm"], config.rms_eps)
-        q = jnp.einsum("bte,ehd->bthd", h, layer["wq"].astype(h.dtype))
-        k_new = jnp.einsum("bte,ehd->bthd", h, layer["wk"].astype(h.dtype))
-        v_new = jnp.einsum("bte,ehd->bthd", h, layer["wv"].astype(h.dtype))
-        q = _rope(q, pos, config.rope_theta)
-        k_new = _rope(k_new, pos, config.rope_theta)
+        h = _rms_norm(x, layer["attn_norm"], config.rms_eps, config.dtype)
+        q, k_new, v_new = _qkv(config, layer, h, pos)
         ck, cv = kv_cache.read_layer(cache, i, k_new, v_new, hit)
         # GQA attention over the cache: group query heads per kv head.
         g = config.q_per_kv
@@ -353,16 +437,18 @@ def forward_cached(
         attn = jnp.einsum("bkgts,bkds->btkgd", probs, cv)
         cache, attn = kv_cache.write_layer(cache, i, ck, cv, attn)
         attn = attn.reshape(B, T, config.num_heads, config.head_dim)
-        x = x + jnp.einsum("bthd,hde->bte", attn, layer["wo"].astype(x.dtype))
-        x, _ = _ffn(config, layer, x)
-        return (x, i + 1, cache), None
+        x = x + jnp.einsum("bthd,hde->bte", attn,
+                           layer["wo"].astype(attn.dtype))
+        x, _, touched = _ffn(config, layer, x, row_mask=rows,
+                             stacked=(moe, i) if dropless else None)
+        return (x, i + 1, cache), None if real is None else touched
 
-    (x, _, cache), _ = jax.lax.scan(
-        block, (x, jnp.int32(0), cache), params["blocks"]
+    (x, _, cache), touched = jax.lax.scan(
+        block, (x, jnp.int32(0), cache), blocks
     )
-    x = _rms_norm(x, params["norm_f"], config.rms_eps)
-    logits = jnp.einsum("bte,ve->btv", x, params["lm_head"].astype(x.dtype))
-    return logits.astype(jnp.float32), cache
+    x = _rms_norm(x, params["norm_f"], config.rms_eps, config.dtype)
+    logits = _head(params, x)
+    return (logits, cache) if real is None else (logits, cache, touched)
 
 
 def loss_fn(
@@ -444,8 +530,7 @@ def forward_pipelined(
     )
     x, aux = res if collect_aux else (res, jnp.float32(0.0))
     x = _rms_norm(x, params["norm_f"], config.rms_eps)
-    logits = jnp.einsum("bte,ve->btv", x, params["lm_head"].astype(x.dtype))
-    return logits.astype(jnp.float32), aux
+    return _head(params, x), aux
 
 
 def count_params(params) -> int:

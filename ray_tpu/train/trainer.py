@@ -103,12 +103,11 @@ def default_jax_train_loop(config: Dict[str, Any]):
     import time
 
     import jax
-    import jax.numpy as jnp
     import numpy as np
     from jax.profiler import StepTraceAnnotation, TraceAnnotation as span
 
     from ray_tpu._private.accelerators.tpu import local_device_info
-    from ray_tpu.models import get_preset
+    from ray_tpu.models import config_for, get_preset
     from ray_tpu.parallel.mesh import MeshConfig
     from ray_tpu.train import checkpoint as ckpt_mod
     from ray_tpu.train.context import get_checkpoint, get_context, report
@@ -126,22 +125,7 @@ def default_jax_train_loop(config: Dict[str, Any]):
         model_cfg = get_preset(model)
     else:
         model = dict(model)
-        family = model.pop("family", "gpt2")
-        for k in ("dtype", "param_dtype"):
-            if isinstance(model.get(k), str):
-                model[k] = jnp.dtype(model[k]).type
-        if isinstance(model.get("moe"), dict):
-            from ray_tpu.parallel.moe import MoEConfig
-
-            model["moe"] = MoEConfig(**model["moe"])
-        if family == "llama":
-            from ray_tpu.models.llama import LlamaConfig
-
-            model_cfg = LlamaConfig(**model)
-        else:
-            from ray_tpu.models.gpt2 import GPT2Config
-
-            model_cfg = GPT2Config(**model)
+        model_cfg = config_for(model.pop("family", "gpt2"), **model)
     mesh = MeshConfig(**config.get("mesh", {"data": -1})).build()
     opt_cfg = OptimizerConfig(**config.get("optimizer", {}))
     opt = opt_cfg.build()

@@ -115,10 +115,14 @@ def test_flash_attention_compiles_inside_a_pipeline_stage(topo):
 # The serving cell's size: GPT-2 XL, 10 slots, 1024 positions.
 
 
-def _engine_program_args(one_chip, slots, width):
-    from ray_tpu.models import gpt2
+def _engine_program_args(one_chip, slots, width, cfg=None):
+    """Shapes on the described chip of what an engine program of ``cfg``
+    (default: GPT-2 XL) takes: params, tokens, cache, start, and for a model
+    with routed experts the rows that carry a token."""
+    from ray_tpu.models import gpt2, module_for
 
-    cfg = gpt2.GPT2_XL
+    cfg = cfg or gpt2.GPT2_XL
+    model = module_for(cfg)
 
     def on_chip(tree):
         return jax.tree.map(
@@ -126,13 +130,14 @@ def _engine_program_args(one_chip, slots, width):
                                            sharding=one_chip), tree)
 
     params = on_chip(jax.eval_shape(
-        lambda: gpt2.init_params(cfg, jax.random.PRNGKey(0))))
+        lambda: model.init_params(cfg, jax.random.PRNGKey(0))))
     cache = on_chip(jax.eval_shape(
-        lambda: gpt2.init_kv_cache(cfg, slots, cfg.max_seq_len)))
+        lambda: model.init_kv_cache(cfg, slots, cfg.max_seq_len)))
     tokens = jax.ShapeDtypeStruct((slots, width), jnp.int32,
                                   sharding=one_chip)
     start = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
-    return cfg, (params, tokens, cache, start)
+    real = (start,) if getattr(cfg, "moe", None) is not None else ()
+    return cfg, (params, tokens, cache, start, *real)
 
 
 def _cache_sized_copies(hlo_text, cache):
@@ -195,3 +200,65 @@ def test_prefill_program_copies_no_layer_of_the_cache(one_chip):
     copies = _cache_sized_copies(text, args[2])
     assert [c for c in copies if c[0] != "ENTRY"] == []
     assert len(copies) <= 2
+
+
+# ------------------------------------------------------- OLMoE in the engine
+# The second serving cell's size: OLMoE-1B-7B at depth 8, bf16 weights, 16
+# slots of 4096 positions (``benchmarks/configs/olmoe-1b-7b.json``).
+
+
+def _olmoe_config():
+    """The program's configuration of ``olmoe-1b-7b``, as the benchmark
+    builds it from the configuration file."""
+    from benchmarks import run
+    from benchmarks.lib import program
+
+    return program.model_config(
+        run.load_cell("olmoe-1b-7b.serve-assist")[2])
+
+
+@pytest.mark.parametrize("program, slots, width", [
+    ("decode", 16, 1), ("prefill", 1, 2048)])
+def test_olmoe_programs_read_the_experts_where_they_lie(
+        one_chip, program, slots, width):
+    """``jit_decode`` at 16 slots and ``jit_prefill`` at the largest bucket,
+    at the published widths: they fit the chip beside each other's
+    arguments; the three grouped products are the compiler's ragged-dot
+    kernels over ALL layers' experts as one operand ([8 x 64, K, N], a
+    bitcast of the parameter), so no layer's 805 MB of experts is cut out
+    and copied for them (19.6 of 48 ms a tick when it was; my chip run, PR
+    27); no weight is converted (they are bf16 and stay so); and the cache
+    is updated in place as GPT-2 XL's is."""
+    import re
+
+    from ray_tpu.llm.engine import engine_programs
+
+    cfg, args = _engine_program_args(one_chip, slots, width, _olmoe_config())
+    assert cfg.param_dtype == jnp.bfloat16 and cfg.moe.dropless
+    fn = engine_programs(cfg)[0 if program == "prefill" else 2]
+    compiled = fn.lower(*args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    param_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(args[0]))
+    assert 7.0e9 < param_bytes < 7.2e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13e9
+    kernels = re.findall(
+        r"%ragged-dot-none[.\d]* = (\w+)\[(\d+),(\d+)\].* custom-call\((.*)",
+        text)
+    assert len(kernels) == 3
+    rows = slots * width * cfg.moe.top_k
+    for _, m, _, operands in kernels:
+        assert int(m) == rows
+        assert re.search(r"bf16\[512,(2048,1024|1024,2048)\]", operands)
+    # nothing as large as a layer's expert matrix is sliced, copied or
+    # converted anywhere in the program
+    big = re.compile(
+        r"= \w+\[(64|512),(2048,1024|1024,2048)\]\S* "
+        r"(copy|convert|dynamic-slice|fusion)\(")
+    assert [line[:160] for line in text.splitlines() if big.search(line)
+            ] == []
+    if program == "decode":
+        cache_bytes = sum(a.size * a.dtype.itemsize
+                          for a in args[2].values())
+        assert mem.alias_size_in_bytes == cache_bytes
+        assert _cache_sized_copies(text, args[2]) == []
