@@ -19,7 +19,9 @@ timeline: ``engine.tick`` (children ``.pack``, ``.dispatch``, ``.fetch``,
 ``.fetch``, ``.sample``, ``engine.insert``), ``engine.finish`` and
 ``engine.idle`` are ``jax.profiler.TraceAnnotation`` spans, inert unless a
 capture runs (``rt profile --xla``). Their arguments are the counters of
-that boundary, and ``stats`` sums the same quantities with no capture.
+that boundary, and ``stats`` sums the same quantities with no capture:
+``cache_positions`` of ``engine.tick`` is how much of the KV cache the tick
+needed (the active slots' lengths and the columns it writes).
 
 A model with routed experts (``parallel/moe.py``) is told which rows of a
 program carry a token (the slots that decode, a prompt's own positions in
@@ -248,6 +250,9 @@ class DecodeEngine:
             # sums at the boundaries the spans mark: submit to admission,
             # the admissions themselves, active slots over ticks
             "queue_wait_s": 0.0, "admit_s": 0.0, "slot_ticks": 0,
+            # positions of the cache the ticks needed: the active slots'
+            # lengths, each with the columns its tick writes
+            "cache_positions": 0,
             "finished_length": 0, "finished_eos": 0, "finished_stop": 0,
             "finished_context": 0,
             # routed (token, expert, layer) rows (real rows x k x layers) and
@@ -724,6 +729,7 @@ class DecodeEngine:
                     d = drafts.get(i, ())
                     toks[i, 1:1 + len(d)] = d
                     real[i] = 1 + len(d)
+                cache_positions = int(lens.sum() + real.sum())
                 toks, lens = jnp.asarray(toks), jnp.asarray(lens)
             with span("engine.tick.dispatch"):
                 logits, self._cache, *touched = program(
@@ -735,9 +741,11 @@ class DecodeEngine:
                 sample(logits)
             self.stats["ticks"] += 1
             self.stats["slot_ticks"] += len(active)
+            self.stats["cache_positions"] += cache_positions
             self.stats["compiles"] = compile_count()
             tick.set_metadata(
-                compiled=int(self.stats["compiles"] > compiles), **moe)
+                compiled=int(self.stats["compiles"] > compiles),
+                cache_positions=cache_positions, **moe)
         return True
 
     def _emit_token_locked(self, i: int, nxt: int, lp) -> None:

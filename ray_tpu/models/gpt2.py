@@ -345,10 +345,7 @@ def forward_cached(
     x = params["wte"][tokens].astype(config.dtype)
     x = x + params["wpe"][pos].astype(config.dtype)
 
-    key_pos = jnp.arange(S)[None, None, :]                  # [1, 1, S]
-    # causal vs cache: key visible iff key_pos <= query absolute position
-    mask = key_pos <= pos[:, :, None]                       # [B, T, S]
-    hit = kv_cache.write_positions(start, T, S)
+    at = kv_cache.step(start, T, S)
     rows = None if real is None else jnp.arange(T)[None, :] < real[:, None]
     # as ``llama.forward_cached``: dropless experts stay out of the scan
     blocks = dict(params["blocks"])
@@ -359,14 +356,8 @@ def forward_cached(
         x, i, cache = carry
         h = _layer_norm(x, layer["ln1_g"], layer["ln1_b"])
         q, k_new, v_new = _qkv(layer, h)
-        ck, cv = kv_cache.read_layer(cache, i, k_new, v_new, hit)
         # attention core differs from _block: queries attend the cache
-        scores = jnp.einsum("bthd,bhds->bhts", q, ck).astype(jnp.float32)
-        scores = scores / jnp.sqrt(jnp.float32(q.shape[-1]))
-        scores = jnp.where(mask[:, None, :, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        attn = jnp.einsum("bhts,bhds->bthd", probs, cv)
-        cache, attn = kv_cache.write_layer(cache, i, ck, cv, attn)
+        cache, attn = kv_cache.attend(cache, i, q, k_new, v_new, at)
         x = _attn_residual(layer, x, attn)
         x, _, touched = _mlp_residual(
             config, layer, x, row_mask=rows,

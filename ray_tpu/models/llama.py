@@ -412,9 +412,7 @@ def forward_cached(
     # the larger part of a served token's distance from a float32 forward.
     x = params["wte"][tokens].astype(jnp.float32)
 
-    key_pos = jnp.arange(S)[None, None, :]
-    mask = key_pos <= pos[:, :, None]                        # [B, T, S]
-    hit = kv_cache.write_positions(start, T, S)
+    at = kv_cache.step(start, T, S)
     rows = None if real is None else jnp.arange(T)[None, :] < real[:, None]
     # The experts' weights stay out of the scan: it would hand each layer
     # its slice, and a slice that feeds a kernel is a copy (``moe._experts``)
@@ -426,16 +424,10 @@ def forward_cached(
         x, i, cache = carry
         h = _rms_norm(x, layer["attn_norm"], config.rms_eps, config.dtype)
         q, k_new, v_new = _qkv(config, layer, h, pos)
-        ck, cv = kv_cache.read_layer(cache, i, k_new, v_new, hit)
         # GQA attention over the cache: group query heads per kv head.
-        g = config.q_per_kv
-        qg = q.reshape(B, T, config.num_kv_heads, g, config.head_dim)
-        scores = jnp.einsum("btkgd,bkds->bkgts", qg, ck).astype(jnp.float32)
-        scores = scores / jnp.sqrt(jnp.float32(config.head_dim))
-        scores = jnp.where(mask[:, None, None, :, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        attn = jnp.einsum("bkgts,bkds->btkgd", probs, cv)
-        cache, attn = kv_cache.write_layer(cache, i, ck, cv, attn)
+        qg = q.reshape(B, T, config.num_kv_heads, config.q_per_kv,
+                       config.head_dim)
+        cache, attn = kv_cache.attend(cache, i, qg, k_new, v_new, at)
         attn = attn.reshape(B, T, config.num_heads, config.head_dim)
         x = x + jnp.einsum("bthd,hde->bte", attn,
                            layer["wo"].astype(attn.dtype))

@@ -1,0 +1,229 @@
+"""One decode step's cache access as one pallas TPU kernel.
+
+A decode step (one new token a slot) needs, for each slot, the K and V
+columns that are filled and one new column written. ``decode_attention``
+does both over the WHOLE cache ``[L, B, KV, D, S]`` (``models/kv_cache.py``),
+aliased to its results: an operand of a custom call is a whole array, so a
+layer's slice cut by the scan would be copied for it. The cache stays in
+HBM; the layer index and ``lens`` ride as scalar-prefetch arguments and
+steer the kernel's own DMAs:
+
+- read: slot by slot (and group of kv heads), chunk by chunk of positions,
+  only the chunks that hold positions ``< lens[b]`` (one at least: it
+  holds the tile to write). Two buffers: a chunk is in flight while the one
+  before is computed on, across the slots' edges too. Online softmax in
+  float32, products accumulated in float32. The new column never comes from
+  the cache: its score and value open the running softmax.
+- write: the one 128-position tile that holds position ``lens[b]``: the old
+  tile out of the chunk already in VMEM, the new column selected in, sent
+  back. A position past the end selects nothing.
+
+Chunk sizes follow the shapes given (``_blocks``), nothing else; S is a
+multiple of the 128 lanes (the DMAs move whole tiles). What is
+small (the queries, the new columns, the result) sits in VMEM whole.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+NEG_INF = -1e30
+TILE = 128              # the TPU's lane width: the finest write there is
+BLOCK_BYTES = 1 << 19   # of one K (or V) chunk in VMEM; two of each are held
+
+
+def _blocks(KV: int, D: int, S: int, itemsize: int):
+    """(kv heads a chunk, positions a chunk) for a cache ``[.., KV, D, S]``:
+    chunks as long as all heads fit ``BLOCK_BYTES``, then as many heads as
+    do."""
+    bs = TILE
+    while S % (2 * bs) == 0 and KV * D * 2 * bs * itemsize <= BLOCK_BYTES:
+        bs *= 2
+    hb = max(h for h in range(1, KV + 1)
+             if KV % h == 0 and (h == 1 or h * D * bs * itemsize <= BLOCK_BYTES))
+    return hb, bs
+
+
+def _kernel(layer_ref, lens_ref, q_ref, kn_row_ref, vn_row_ref, kn_col_ref,
+            vn_col_ref, k_hbm, v_hbm, o_ref, ko_hbm, vo_hbm, kbuf, vbuf,
+            ktile, vtile, read_sem, write_sem, m_sc, l_sc, acc_sc, *,
+            hb: int, bs: int, scale: float):
+    B, KV = q_ref.shape[:2]
+    S = k_hbm.shape[-1]
+    groups = KV // hb
+    visits = B * groups               # a visit: one slot, one group of heads
+    layer = layer_ref[0]
+
+    def span(i, size):
+        return pl.ds(pl.multiple_of(i * size, size), size)
+
+    def read(v, c, buf):
+        """The DMAs of chunk ``c`` of visit ``v`` into buffer ``buf``."""
+        b, heads = v // groups, pl.ds((v % groups) * hb, hb)
+        at = span(c, bs)
+        return [pltpu.make_async_copy(
+            hbm.at[layer, b, heads, :, at], dst.at[buf], read_sem.at[i, buf])
+            for i, (hbm, dst) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf)))]
+
+    def write(v, buf):
+        """The DMAs of visit ``v``'s tile out of buffer ``buf``."""
+        b, heads = v // groups, pl.ds((v % groups) * hb, hb)
+        at = span(jnp.minimum(lens_ref[b], S - 1) // TILE, TILE)
+        return [pltpu.make_async_copy(
+            src.at[buf], hbm.at[layer, b, heads, :, at], write_sem.at[i, buf])
+            for i, (src, hbm) in enumerate(((ktile, ko_hbm), (vtile, vo_hbm)))]
+
+    def visit(v, buf):
+        """Visit ``v``, whose first chunk is on its way into ``buf`` -> the
+        buffer the next visit's first chunk is on its way into."""
+        b, g = v // groups, v % groups
+        heads = pl.ds(g * hb, hb)
+        n = lens_ref[b]                   # the new column's position
+        kept = n < S                      # past the end it is dropped
+        count = jnp.minimum(n, S - 1) // bs + 1   # chunks this visit reads
+        q = q_ref[b, heads]               # [hb, G, D]
+        # the running softmax opens on the new column alone (p = 1)
+        s_new = jnp.sum(
+            q.astype(jnp.float32) * kn_row_ref[b, heads].astype(jnp.float32),
+            axis=-1, keepdims=True) * scale                  # [hb, G, 1]
+        m_sc[...] = jnp.where(kept, s_new, NEG_INF)
+        l_sc[...] = jnp.where(kept, jnp.ones_like(s_new), 0.0)
+        acc_sc[...] = jnp.where(kept, jnp.broadcast_to(
+            vn_row_ref[b, heads].astype(jnp.float32), acc_sc.shape), 0.0)
+
+        def chunk(c, buf):
+            # the next chunk sets out before this one is computed on: this
+            # visit's next or, behind its last, the next visit's first
+            @pl.when(c + 1 < count)
+            def _():
+                for dma in read(v, c + 1, 1 - buf):
+                    dma.start()
+
+            @pl.when((c + 1 == count) & (v + 1 < visits))
+            def _():
+                for dma in read(v + 1, 0, 1 - buf):
+                    dma.start()
+
+            for dma in read(v, c, buf):
+                dma.wait()
+
+            @pl.when(c * bs < n)          # a filled position among them:
+            def _():                      # an idle slot's chunk has none
+                attend(c, kbuf[buf], vbuf[buf])
+            return 1 - buf
+
+        def attend(c, k, v_):             # [hb, D, bs]
+            kt = jnp.promote_types(q.dtype, k.dtype)
+            sc = jnp.einsum("hgd,hds->hgs", q.astype(kt), k.astype(kt),
+                            preferred_element_type=jnp.float32) * scale
+            pos = c * bs + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 2)
+            old = pos < n                 # the filled positions
+            sc = jnp.where(old, sc, NEG_INF)
+            m_prev = m_sc[...]
+            m_next = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_next)
+            p = jnp.where(old, jnp.exp(sc - m_next), 0.0)
+            l_sc[...] = alpha * l_sc[...] + jnp.sum(p, axis=-1, keepdims=True)
+            vt = jnp.promote_types(q.dtype, v_.dtype)
+            acc_sc[...] = alpha * acc_sc[...] + jnp.einsum(
+                "hgs,hds->hgd", p.astype(q.dtype).astype(vt), v_.astype(vt),
+                preferred_element_type=jnp.float32)
+            m_sc[...] = m_next
+
+        after = jax.lax.fori_loop(0, count, chunk, buf)
+        o_ref[b, heads] = (acc_sc[...] / l_sc[...]).astype(o_ref.dtype)
+
+        # the tile that holds position n: out of the last chunk, the new
+        # column selected in. n == S matches no lane: the tile as it was.
+        held, out = 1 - after, v % 2      # the last chunk's buffer; the tile's
+
+        @pl.when(v >= 2)
+        def _():                          # the tile sent two visits ago
+            for dma in write(v - 2, out):
+                dma.wait()
+
+        at = jnp.minimum(n, S - 1)
+        within = span((at % bs) // TILE, TILE)
+        lane = (at // TILE) * TILE + jax.lax.broadcasted_iota(
+            jnp.int32, (1, TILE), 1)
+        for new_ref, chunk_ref, tile_ref in ((kn_col_ref, kbuf, ktile),
+                                             (vn_col_ref, vbuf, vtile)):
+            new = new_ref[b, g]                              # [D, hb]
+            for h in range(hb):
+                tile_ref[out, h] = jnp.where(
+                    lane == n, new[:, h:h + 1],
+                    chunk_ref[held, h, :, within])
+        for dma in write(v, out):
+            dma.start()
+        return after
+
+    for dma in read(0, 0, 0):
+        dma.start()
+    jax.lax.fori_loop(0, visits, visit, 0)
+    for v in range(max(visits - 2, 0), visits):
+        for dma in write(v, v % 2):
+            dma.wait()
+
+
+def decode_attention(q, k_new, v_new, k_cache, v_cache, layer, lens, *,
+                     interpret: bool = False):
+    """q [B, KV, G, D] attends layer ``layer``'s filled positions
+    (``< lens[b]``) of ``k_cache`` / ``v_cache`` [L, B, KV, D, S] and the
+    new column ``k_new`` / ``v_new`` [B, KV, D], which is written to position
+    ``lens[b]`` (dropped where that is S) -> (out [B, KV, G, D], k_cache,
+    v_cache): the caches are the operands' own buffers."""
+    B, KV, G, D = q.shape
+    S = k_cache.shape[-1]
+    cdt = k_cache.dtype
+    if S % TILE:
+        raise ValueError(
+            f"the kernel moves whole tiles of {TILE} positions: a cache of "
+            f"{S} is the XLA path's (models/kv_cache.py:attend)")
+    hb, bs = _blocks(KV, D, S, cdt.itemsize)
+    k_new, v_new = k_new.astype(cdt), v_new.astype(cdt)
+    out_dtype = jnp.promote_types(q.dtype, cdt)
+
+    def columns(new):                     # [B, KV, D] -> [B, KV // hb, D, hb]
+        return new.reshape(B, KV // hb, hb, D).swapaxes(2, 3)
+
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    cache_shape = jax.ShapeDtypeStruct(k_cache.shape, cdt)
+    chunk_bytes = hb * D * bs * cdt.itemsize
+    o, k_cache, v_cache = pl.pallas_call(
+        functools.partial(_kernel, hb=hb, bs=bs, scale=D ** -0.5),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[vmem, vmem, vmem, vmem, vmem, hbm, hbm],
+            out_specs=[vmem, hbm, hbm],
+            scratch_shapes=[
+                pltpu.VMEM((2, hb, D, bs), cdt),
+                pltpu.VMEM((2, hb, D, bs), cdt),
+                pltpu.VMEM((2, hb, D, TILE), cdt),
+                pltpu.VMEM((2, hb, D, TILE), cdt),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((hb, G, 1), jnp.float32),
+                pltpu.VMEM((hb, G, 1), jnp.float32),
+                pltpu.VMEM((hb, G, D), jnp.float32),
+            ],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((B, KV, G, D), out_dtype),
+                   cache_shape, cache_shape],
+        # operands count from the scalar-prefetch arguments on
+        input_output_aliases={7: 1, 8: 2},
+        # four chunks and four tiles held, the chunk's temporaries, and the
+        # small operands, whose rows pad to whole tiles
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=8 * chunk_bytes + (32 << 20)),
+        name="decode_attention",
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), lens.astype(jnp.int32), q,
+      k_new[:, :, None, :], v_new[:, :, None, :], columns(k_new),
+      columns(v_new), k_cache, v_cache)
+    return o, k_cache, v_cache

@@ -292,6 +292,11 @@ def test_counters_equal_the_sums_of_the_spans_arguments(recording):
     assert stats["ticks"] == len(ticks)
     assert stats["slot_ticks"] == sum(t.args["active"] for t in ticks)
     assert stats["slot_ticks"] == stats["tokens_generated"]
+    # what the ticks needed of the cache: every active slot's length and
+    # the column it writes, so more than a position a token
+    assert stats["cache_positions"] == sum(
+        t.args["cache_positions"] for t in ticks)
+    assert stats["cache_positions"] > stats["slot_ticks"]
     assert stats["queue_wait_s"] == pytest.approx(
         sum(a.args["queued_ms"] for a in admits) / 1e3, abs=1e-5)
     # the host's clock is read just outside the span: never less, and more

@@ -4,6 +4,10 @@ calls ``forward_cached``, and a tick changes only the positions it writes.
 
 float32 throughout, so "equals" is 1e-4 and a write that lost precision
 would show; the untouched rows are compared bit for bit.
+
+On the CPU a decode step takes the XLA path; the last tests hold the TPU's
+kernel (``ops/decode_attention.py``, ``interpret=True``) to it, alone and
+through ``forward_cached``.
 """
 import jax
 import jax.numpy as jnp
@@ -11,9 +15,10 @@ import numpy as np
 import pytest
 
 from ray_tpu.llm.engine import engine_programs
-from ray_tpu.models import module_for
+from ray_tpu.models import kv_cache, module_for
 from ray_tpu.models.gpt2 import GPT2Config
 from ray_tpu.models.llama import LlamaConfig
+from ray_tpu.ops import decode_attention as kernel
 
 S, SLOTS, BUCKET, VOCAB = 32, 3, 8, 128
 
@@ -179,3 +184,139 @@ def test_a_write_past_the_end_is_dropped(model):
         new = np.asarray(cache[name])
         assert (new[..., : S - 1] == old[..., : S - 1]).all(), name
         assert (new[..., S - 1] != old[..., S - 1]).any(), name
+
+
+# ------------------------------------------------- the decode step's kernel
+
+
+@pytest.fixture
+def small_blocks(monkeypatch):
+    """Blocks of 128 positions at test sizes (the cells' are 512 of 1,024
+    and of 4,096), so that a slot runs some blocks and skips the rest; and
+    the kernel, interpreted, where the platform would choose XLA."""
+    monkeypatch.setattr(kernel, "BLOCK_BYTES", 2 * 64 * 128 * 4)
+    monkeypatch.setattr(kv_cache, "_decode_impl", lambda: "pallas_interpret")
+    traced = []  # the options of each kernel call that was traced
+    monkeypatch.setattr(
+        kv_cache, "decode_attention",
+        lambda *a, **kw: traced.append(kw) or kernel.decode_attention(*a, **kw))
+    return traced
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=str)
+@pytest.mark.parametrize("G", [1, 4])
+@pytest.mark.parametrize("D, S", [(64, 384), (128, 512)])
+def test_decode_kernel_equals_the_xla_path(small_blocks, monkeypatch, D, S,
+                                           G, dtype):
+    """One call, slots at different lengths: idle (0), mid-tile, a tile's
+    last position, a later block, S - 1, and S (dropped, nothing written).
+    Same attention within the dtype's rounding, the cache EQUAL bit for
+    bit, other layers included."""
+    L, KV = 2, 2
+    lens = np.array([0, 70, 127, 300, S - 1, S], np.int32)
+    B = len(lens)
+    assert kernel._blocks(KV, D, S, jnp.dtype(dtype).itemsize)[1] == 128
+    ks = jax.random.split(jax.random.PRNGKey(D + G), 5)
+    cache = {
+        "k": jax.random.normal(ks[0], (L, B, KV, D, S), dtype),
+        "v": jax.random.normal(ks[1], (L, B, KV, D, S), dtype),
+    }
+    q = jax.random.normal(ks[2], (B, 1, KV, G, D), dtype)
+    k_new = jax.random.normal(ks[3], (B, 1, KV, D), dtype)
+    v_new = jax.random.normal(ks[4], (B, 1, KV, D), dtype)
+
+    def attend():
+        # a function of its own each time: jit's trace cache is by function
+        return jax.jit(lambda cache: kv_cache.attend(
+            cache, jnp.int32(1), q, k_new, v_new,
+            kv_cache.step(jnp.asarray(lens), 1, S)))(cache)
+
+    got_cache, got = attend()
+    assert small_blocks == [{"interpret": True}]
+    monkeypatch.setattr(kv_cache, "_decode_impl", lambda: "xla")
+    want_cache, want = attend()
+    assert len(small_blocks) == 1
+    assert got.shape == want.shape and got.dtype == want.dtype
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
+    for name in ("k", "v"):
+        assert (np.asarray(got_cache[name]) == np.asarray(want_cache[name])
+                ).all(), name
+        # and what the XLA path wrote is what was asked for
+        new = np.asarray(got_cache[name], np.float32)
+        old = np.asarray(cache[name], np.float32)
+        changed = (new != old).any(axis=(2, 3))              # [L, B, S]
+        assert not changed[0].any()
+        for b, n in enumerate(lens):
+            assert list(np.flatnonzero(changed[1, b])) == ([n] if n < S else [])
+
+
+def test_a_cache_the_lanes_do_not_divide_keeps_the_xla_path(small_blocks):
+    """S = 96: the kernel's DMAs move whole tiles of 128 positions, and the
+    chip's compiler refuses a slice of such a cache; the static shape
+    decides, before anything is lowered."""
+    B, KV, D, S = 2, 2, 64, 96
+    cache = kv_cache.init_kv_cache(1, B, KV, D, S, jnp.float32)
+    new = jnp.ones((B, 1, KV, D), jnp.float32)
+    cache, out = kv_cache.attend(
+        cache, jnp.int32(0), new, new, new,
+        kv_cache.step(jnp.array([0, 95], jnp.int32), 1, S))
+    assert small_blocks == [] and out.shape == new.shape
+    assert np.asarray(cache["k"])[0, :, 0, 0, [0, 95]].tolist() == [
+        [1.0, 0.0], [0.0, 1.0]]
+    with pytest.raises(ValueError, match="whole tiles"):
+        kernel.decode_attention(new[:, 0, :, None], new[:, 0], new[:, 0],
+                                cache["k"], cache["v"], 0, jnp.zeros(B))
+
+
+BIG = {
+    "gpt2": GPT2Config(
+        vocab_size=VOCAB, max_seq_len=256, num_layers=2, num_heads=2,
+        embed_dim=128, dtype=jnp.float32, remat=False,
+    ),
+    "llama_gqa": LlamaConfig(
+        vocab_size=VOCAB, max_seq_len=256, num_layers=2, num_heads=4,
+        num_kv_heads=2, embed_dim=256, dtype=jnp.float32, remat=False,
+    ),
+}
+
+
+@pytest.mark.parametrize("family", list(BIG))
+def test_prefill_then_kernel_steps_equal_the_full_forward(small_blocks,
+                                                          family):
+    """A prompt prefilled (T > 1, the XLA path) into one slot of three,
+    then 8 decode steps through the kernel, across a tile's and a block's
+    edge (positions 124-131), a short slot and an idle one beside it."""
+    cfg = BIG[family]
+    mod = module_for(cfg)
+    params = mod.init_params(cfg, jax.random.PRNGKey(2))
+    prefill, insert, decode, _ = engine_programs(cfg)
+    rng = np.random.RandomState(2)
+    seqs = {0: (124, rng.randint(0, VOCAB, 132).astype(np.int32)),
+            2: (5, rng.randint(0, VOCAB, 13).astype(np.int32))}
+    full = {b: np.asarray(mod.forward(params, jnp.asarray(t[None]), cfg)[0][0])
+            for b, (_, t) in seqs.items()}
+    cache = mod.init_kv_cache(cfg, 3, 256)
+    lens = np.zeros((3,), np.int32)
+    for b, (plen, toks) in seqs.items():
+        padded = np.zeros((1, 128), np.int32)
+        padded[0, :plen] = toks[:plen]
+        _, cache1 = prefill(params, jnp.asarray(padded),
+                            mod.init_kv_cache(cfg, 1, 256),
+                            jnp.zeros((1,), jnp.int32))
+        cache = insert(cache, cache1, b)
+        lens[b] = plen
+    for _ in range(8):
+        toks = np.zeros((3, 1), np.int32)
+        for b, (_, t) in seqs.items():
+            toks[b, 0] = t[lens[b]]
+        logits, cache = decode(params, jnp.asarray(toks), cache,
+                               jnp.asarray(lens))
+        for b in seqs:
+            np.testing.assert_allclose(
+                np.asarray(logits)[b], full[b][lens[b]],
+                rtol=2e-4, atol=2e-4)
+        lens[list(seqs)] += 1
+    assert small_blocks == [{"interpret": True}]  # one trace, in the scan

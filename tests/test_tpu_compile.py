@@ -140,8 +140,8 @@ def _engine_program_args(one_chip, slots, width, cfg=None):
     return cfg, (params, tokens, cache, start, *real)
 
 
-def _cache_sized_copies(hlo_text, cache):
-    """(computation, op, shape) of every ``copy``/``transpose`` in the
+def _cache_sized(hlo_text, cache, ops="copy|transpose"):
+    """(computation, op, shape) of every ``ops`` instruction in the
     optimized HLO whose dimensions are a layer's slice of the cache or the
     whole of it, in any order."""
     import re
@@ -154,7 +154,7 @@ def _cache_sized_copies(hlo_text, cache):
         if line.startswith(("%", "ENTRY")):
             computation = line.split(" ", 1)[0]  # "ENTRY" or its %name
         m = re.match(
-            r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* (copy|transpose)\(",
+            r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* (" + ops + r")\(",
             line)
         if m:
             dims = tuple(sorted(
@@ -164,14 +164,25 @@ def _cache_sized_copies(hlo_text, cache):
     return found
 
 
-def test_decode_program_updates_the_cache_in_place(one_chip):
-    """``jit_decode`` as the engine builds it: the donated cache is the
-    result's buffer, no second cache among the temporaries (what is left
-    there is the weights in bf16), and no copy of a layer's slice or of the
-    whole cache anywhere in the program."""
+@pytest.mark.parametrize("cell", ["gpt2-xl.serve-chat",
+                                  "olmoe-1b-7b.serve-assist"])
+def test_decode_program_updates_the_cache_in_place(one_chip, cell,
+                                                   monkeypatch):
+    """``jit_decode`` as the engine builds it, at both serving cells' sizes
+    (GPT-2 XL, 10 slots of 1,024; OLMoE, 16 slots of 4,096): the donated
+    cache is the result's buffer, no second cache among the temporaries
+    (what is left there is the weights in bf16), no copy of a layer's slice
+    or of the whole cache anywhere in the program; the layer's access is
+    the ``decode_attention`` kernel over the whole cache, and no fusion
+    makes a layer's slice."""
     from ray_tpu.llm.engine import engine_programs
+    from ray_tpu.models import kv_cache
 
-    cfg, args = _engine_program_args(one_chip, slots=10, width=1)
+    # the platform here is the CPU; the described chip gets what a chip gets
+    monkeypatch.setattr(kv_cache, "_decode_impl", lambda: "pallas")
+    slots, cfg = {"gpt2-xl.serve-chat": (10, None),
+                  "olmoe-1b-7b.serve-assist": (16, _olmoe_config())}[cell]
+    cfg, args = _engine_program_args(one_chip, slots=slots, width=1, cfg=cfg)
     decode = engine_programs(cfg)[2]
     compiled = decode.lower(*args).compile()
     cache = args[2]
@@ -183,7 +194,12 @@ def test_decode_program_updates_the_cache_in_place(one_chip):
     assert mem.alias_size_in_bytes == cache_bytes
     assert header.count("-alias)") == 2
     assert mem.temp_size_in_bytes < 4e9
-    assert _cache_sized_copies(text, cache) == []
+    assert _cache_sized(text, cache) == []
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "decode_attention" in line]
+    assert len(calls) == 1  # the layer scan instantiates it once
+    # and no fusion makes a layer's slice: a decode step has no business to
+    assert _cache_sized(text, cache, "fusion") == []
 
 
 def test_prefill_program_copies_no_layer_of_the_cache(one_chip):
@@ -197,7 +213,7 @@ def test_prefill_program_copies_no_layer_of_the_cache(one_chip):
     compiled = prefill.lower(*args).compile()
     text = compiled.as_text()
     assert "jit_prefill" in text.split("\n", 1)[0]
-    copies = _cache_sized_copies(text, args[2])
+    copies = _cache_sized(text, args[2])
     assert [c for c in copies if c[0] != "ENTRY"] == []
     assert len(copies) <= 2
 
@@ -220,7 +236,7 @@ def _olmoe_config():
 @pytest.mark.parametrize("program, slots, width", [
     ("decode", 16, 1), ("prefill", 1, 2048)])
 def test_olmoe_programs_read_the_experts_where_they_lie(
-        one_chip, program, slots, width):
+        one_chip, program, slots, width, monkeypatch):
     """``jit_decode`` at 16 slots and ``jit_prefill`` at the largest bucket,
     at the published widths: they fit the chip beside each other's
     arguments; the three grouped products are the compiler's ragged-dot
@@ -232,7 +248,9 @@ def test_olmoe_programs_read_the_experts_where_they_lie(
     import re
 
     from ray_tpu.llm.engine import engine_programs
+    from ray_tpu.models import kv_cache
 
+    monkeypatch.setattr(kv_cache, "_decode_impl", lambda: "pallas")
     cfg, args = _engine_program_args(one_chip, slots, width, _olmoe_config())
     assert cfg.param_dtype == jnp.bfloat16 and cfg.moe.dropless
     fn = engine_programs(cfg)[0 if program == "prefill" else 2]
@@ -261,4 +279,4 @@ def test_olmoe_programs_read_the_experts_where_they_lie(
         cache_bytes = sum(a.size * a.dtype.itemsize
                           for a in args[2].values())
         assert mem.alias_size_in_bytes == cache_bytes
-        assert _cache_sized_copies(text, args[2]) == []
+        assert _cache_sized(text, args[2]) == []
