@@ -2,11 +2,10 @@
 
 Headline = single_client_tasks_async vs the reference's checked-in number
 (BASELINE.md: 7,096.8 tasks/s on a release CPU node). Extra fields carry the
-other core microbenchmarks plus GPT-2 train throughput on the local
-accelerator (tokens/sec/chip — the BASELINE.json north star; the reference
-publishes no TPU number for it, so vs_baseline stays anchored to tasks/s).
+other core microbenchmarks. These legs run on the host; what the accelerator
+does (train and serve throughput, by cell) is ``benchmarks/run.py``'s.
 
-Usage: python bench.py [--quick] [--no-train]
+Usage: python bench.py [--quick] [--flight | --phases | --serve]
 """
 from __future__ import annotations
 
@@ -15,297 +14,7 @@ import json
 import os
 import time
 
-# Persistent XLA compilation cache: compiles are paid once per machine, not
-# once per bench run. Where JAX_COMPILATION_CACHE_DIR is set from outside it
-# is used as it is. Must be set before jax initializes.
-os.environ.setdefault(
-    "JAX_COMPILATION_CACHE_DIR",
-    os.path.join(os.path.dirname(os.path.abspath(__file__)), ".jax_cache"),
-)
-
 BASELINE_TASKS_ASYNC = 7096.8  # reference release/perf_metrics/microbenchmark.json
-
-# Peak bf16 FLOP/s per chip, keyed by ``device_kind`` as JAX reports it.
-# Source: Google Cloud documentation, "TPU v5e" system architecture page.
-# A device that is not in the table is an error, not a default.
-PEAK_BF16_FLOPS = {
-    "TPU v5 lite": 197e12,
-}
-
-
-def peak_bf16_flops(device_kind: str) -> float:
-    if device_kind not in PEAK_BF16_FLOPS:
-        raise KeyError(
-            f"no peak FLOP/s on record for device_kind {device_kind!r}; "
-            f"known: {sorted(PEAK_BF16_FLOPS)}"
-        )
-    return PEAK_BF16_FLOPS[device_kind]
-
-
-def measure_achievable_tflops() -> float:
-    """Measured matmul roof of the local accelerator (bf16 4k x 4k,
-    chained INSIDE one jit so per-dispatch overhead cannot deflate the
-    roof).
-
-    MFU against the nominal datasheet peak can be misleading: real chips
-    execute below it even on pure matmul chains. Reporting the measured
-    roof lets
-    `gpt2_train_mfu_vs_achievable` say how close the train step is to what
-    this device can actually do."""
-    import time as _t
-
-    import jax
-    import jax.numpy as jnp
-
-    # Transformer-MLP-shaped chain with resident weights — the sustained
-    # rate a well-tiled model layer can actually reach.
-    M, E, H = 32 * 1024, 1024, 4096
-    inner = 12
-    x = jnp.full((M, E), 1.0 / E, jnp.bfloat16)
-    w1 = jnp.full((E, H), 1.0 / H, jnp.bfloat16)
-    w2 = jnp.full((H, E), 1.0 / E, jnp.bfloat16)
-
-    @jax.jit
-    def chain(x):
-        for _ in range(inner):
-            x = (x @ w1) @ w2
-        return x
-
-    out = chain(x)
-    float(jnp.sum(out[:1, :1]))  # real device->host sync
-    steps = 5
-    t0 = _t.perf_counter()
-    for _ in range(steps):
-        out = chain(out)
-    float(jnp.sum(out[:1, :1]))
-    dt = _t.perf_counter() - t0
-    return 2 * M * E * H * 2 * inner * steps / dt
-
-
-def require_tpu():
-    """The train legs measure the chip: anything else is an error, never a
-    smaller model under the same metric name."""
-    import jax
-
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        raise RuntimeError(
-            f"bench.py train legs need a TPU; JAX runs on {dev.platform!r} "
-            f"({dev.device_kind}). Use --no-train off the chip."
-        )
-    return dev
-
-
-def _release_device_memory() -> int:
-    """Drop what a finished leg left on the chip — its compiled programs
-    and any array only a cycle keeps alive — and return the bytes still in
-    use. The legs share one process (one process per chip), so the next
-    one needs the HBM the last one held."""
-    import gc
-
-    import jax
-
-    gc.collect()
-    jax.clear_caches()
-    return (jax.devices()[0].memory_stats() or {}).get("bytes_in_use", 0)
-
-
-def _time_train_steps(config, B: int, T: int, steps: int):
-    """(tokens/s, final loss) of ``steps`` train steps of ``config`` on one
-    repeated batch, after a compile+warm-up step."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from ray_tpu.train.step import (
-        OptimizerConfig,
-        create_train_state,
-        make_train_step,
-    )
-
-    opt = OptimizerConfig().build()
-    state = create_train_state(config, opt, jax.random.PRNGKey(0))
-    step = make_train_step(config, opt)
-    rng = np.random.RandomState(0)
-    batch = {
-        "tokens": jnp.asarray(rng.randint(0, config.vocab_size, (B, T + 1)))
-    }
-    state, m = step(state, batch)  # compile
-    jax.block_until_ready((jax.tree.leaves(state), m["loss"]))
-    t0 = time.perf_counter()
-    for _ in range(steps):
-        state, m = step(state, batch)
-    # Block on the FULL final state and read the loss on the host: the
-    # timed region ends only when the last step's bytes exist.
-    jax.block_until_ready(jax.tree.leaves(state))
-    loss = float(m["loss"])
-    dt = time.perf_counter() - t0
-    return steps * B * T / dt, loss
-
-
-def bench_train_tokens_per_sec(quick: bool = False):
-    """GPT-2-small train step on the local chip. One configuration: B=32
-    with remat ("dots" policy) — the v5e compiler refuses B=32 without remat
-    (25.85 GB of 15.75 GB HBM; sandbox compile, PR 21)."""
-    from ray_tpu.models import gpt2
-
-    dev = require_tpu()
-    peak = peak_bf16_flops(dev.device_kind)
-    config = gpt2.GPT2Config(
-        vocab_size=50304, max_seq_len=1024, num_layers=12, num_heads=12,
-        embed_dim=768, remat=True,
-    )
-    B, T = 32, 1024
-    tokens_per_sec, loss = _time_train_steps(
-        config, B, T, steps=5 if quick else 20
-    )
-    flops = gpt2.flops_per_token(config) * tokens_per_sec
-    out = {
-        "gpt2_train_tokens_per_sec_per_chip": tokens_per_sec,
-        "gpt2_train_loss": loss,
-        "gpt2_train_mfu_est": flops / peak,
-        "gpt2_train_remat": bool(config.remat),
-        "gpt2_train_batch": B,
-        "train_backend": dev.platform,
-        "train_device_kind": dev.device_kind,
-    }
-    _release_device_memory()
-    roof = measure_achievable_tflops()
-    out["tpu_matmul_tflops_measured"] = roof / 1e12
-    out["gpt2_train_mfu_vs_achievable"] = flops / roof
-    _release_device_memory()
-    ref = bench_reference_jax_step(quick=quick)
-    out.update(ref)
-    if ref:
-        out["gpt2_train_vs_reference_impl"] = (
-            tokens_per_sec / ref["gpt2_reference_impl_tokens_per_sec"]
-        )
-    if not quick:
-        out["hbm_bytes_in_use_before_medium"] = _release_device_memory()
-        out.update(bench_train_medium())
-    return out
-
-
-def bench_train_medium():
-    """GPT-2-medium (350M) tokens/sec/chip — the BASELINE.md north-star
-    model size, in this process (one process per chip). One configuration:
-    B=16 with remat ("dots" policy)."""
-    from ray_tpu.models import gpt2
-
-    dev = require_tpu()
-    config = gpt2.GPT2Config(
-        vocab_size=50304, max_seq_len=1024, num_layers=24, num_heads=16,
-        embed_dim=1024, remat=True,
-    )
-    B = 16
-    tps, _ = _time_train_steps(config, B, 1024, steps=10)
-    return {
-        "gpt2_medium_tokens_per_sec_per_chip": tps,
-        "gpt2_medium_mfu_est": (
-            gpt2.flops_per_token(config) * tps
-            / peak_bf16_flops(dev.device_kind)
-        ),
-        "gpt2_medium_remat": True,
-        "gpt2_medium_batch": B,
-    }
-
-
-def bench_reference_jax_step(quick: bool = False):
-    """A deliberately *stock* JAX GPT-2-small train step, written the way a
-    typical user would (plain remat'd blocks, optax softmax-xent on full
-    logits, no pallas / no vocab chunking / no fused policies). Same chip,
-    same model dims, same token budget — the denominator the north-star
-    metric needs in the absence of a torch-xla install (BASELINE.md: target
-    >=90% of a stock SPMD implementation; we aim to beat it outright)."""
-    import time as _t
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    import optax
-
-    if quick:
-        return {}
-    V, T, L, H, E = 50304, 1024, 12, 12, 768
-    key = jax.random.PRNGKey(0)
-
-    def init(key):
-        ks = jax.random.split(key, 6)
-        def nrm(k, shape, s=0.02):
-            return (s * jax.random.normal(k, shape)).astype(jnp.float32)
-        return {
-            "wte": nrm(ks[0], (V, E)),
-            "wpe": nrm(ks[1], (T, E)),
-            "blocks": {
-                "ln1": jnp.ones((L, E)), "ln1b": jnp.zeros((L, E)),
-                "qkv": nrm(ks[2], (L, E, 3 * E)), "qkvb": jnp.zeros((L, 3 * E)),
-                "proj": nrm(ks[3], (L, E, E)), "projb": jnp.zeros((L, E)),
-                "ln2": jnp.ones((L, E)), "ln2b": jnp.zeros((L, E)),
-                "fc": nrm(ks[4], (L, E, 4 * E)), "fcb": jnp.zeros((L, 4 * E)),
-                "out": nrm(ks[5], (L, 4 * E, E)), "outb": jnp.zeros((L, E)),
-            },
-            "lnf": jnp.ones((E,)), "lnfb": jnp.zeros((E,)),
-        }
-
-    def ln(x, g, b):
-        x32 = x.astype(jnp.float32)
-        y = (x32 - x32.mean(-1, keepdims=True)) * jax.lax.rsqrt(
-            x32.var(-1, keepdims=True) + 1e-5)
-        return (y * g + b).astype(x.dtype)
-
-    def block(x, lp):
-        B = x.shape[0]
-        h = ln(x, lp["ln1"], lp["ln1b"])
-        qkv = (h @ lp["qkv"].astype(h.dtype)) + lp["qkvb"].astype(h.dtype)
-        q, k, v = jnp.split(qkv.reshape(B, T, 3, 12, 64), 3, axis=2)
-        q, k, v = (t[:, :, 0].transpose(0, 2, 1, 3) for t in (q, k, v))
-        s = (q @ k.transpose(0, 1, 3, 2)) * (64 ** -0.5)
-        mask = jnp.tril(jnp.ones((T, T), bool))
-        s = jnp.where(mask, s.astype(jnp.float32), -1e30)
-        p = jax.nn.softmax(s, axis=-1).astype(x.dtype)
-        a = (p @ v).transpose(0, 2, 1, 3).reshape(B, T, E)
-        x = x + (a @ lp["proj"].astype(x.dtype)) + lp["projb"].astype(x.dtype)
-        h = ln(x, lp["ln2"], lp["ln2b"])
-        h = jax.nn.gelu((h @ lp["fc"].astype(h.dtype)) + lp["fcb"].astype(h.dtype))
-        return x + (h @ lp["out"].astype(h.dtype)) + lp["outb"].astype(h.dtype)
-
-    def loss_fn(params, tokens):
-        inp, tgt = tokens[:, :-1], tokens[:, 1:]
-        x = params["wte"][inp].astype(jnp.bfloat16)
-        x = x + params["wpe"][None].astype(jnp.bfloat16)
-        body = jax.checkpoint(block)
-        x, _ = jax.lax.scan(
-            lambda c, lp: (body(c, lp), None), x, params["blocks"]
-        )
-        x = ln(x, params["lnf"], params["lnfb"])
-        logits = (x @ params["wte"].T.astype(x.dtype)).astype(jnp.float32)
-        return optax.softmax_cross_entropy_with_integer_labels(
-            logits, tgt).mean()
-
-    opt = optax.chain(optax.clip_by_global_norm(1.0), optax.adamw(3e-4))
-    B = 16  # full f32 logits cap the feasible batch (5.6 GB of temporaries)
-    params = init(key)
-    opt_state = opt.init(params)
-
-    @jax.jit
-    def step(params, opt_state, tokens):
-        l, g = jax.value_and_grad(loss_fn)(params, tokens)
-        up, opt_state = opt.update(g, opt_state, params)
-        return optax.apply_updates(params, up), opt_state, l
-
-    rng = np.random.RandomState(0)
-    tokens = jnp.asarray(rng.randint(0, V, (B, T + 1)))
-    params, opt_state, l = step(params, opt_state, tokens)
-    jax.block_until_ready(jax.tree.leaves(params)); float(l)
-    n = 10
-    t0 = _t.perf_counter()
-    for _ in range(n):
-        params, opt_state, l = step(params, opt_state, tokens)
-    # same sync discipline as the framework-step timing above
-    jax.block_until_ready(jax.tree.leaves(params)); float(l)
-    rate = n * B * T / (_t.perf_counter() - t0)
-    return {"gpt2_reference_impl_tokens_per_sec": rate}
-
 
 def run_flight_benchmarks(quick: bool = False, phases: bool = False,
                           attrib_path: str = None) -> dict:
@@ -610,9 +319,6 @@ def run_serve_benchmarks(quick: bool = False) -> dict:
 def main():
     parser = argparse.ArgumentParser()
     parser.add_argument("--quick", action="store_true")
-    parser.add_argument("--no-train", action="store_true")
-    parser.add_argument("--train-only", action="store_true",
-                        help="skip the core cluster benchmarks (debugging)")
     parser.add_argument(
         "--flight", action="store_true",
         help="flight-instrumented run of queued_tasks + many_actors only: "
@@ -638,99 +344,79 @@ def main():
              "attribution of the serving path")
     args = parser.parse_args()
 
-    import os
-
-    # Sentinel, not 0.0: a --train-only line must never read as a real
-    # throughput collapse to anything parsing the headline contract.
-    core = {"single_client_tasks_async_per_s": None, "core_skipped": True}
     if args.phases:
         args.flight = True
     if args.flight:
         # Recording must be on in every process: workers inherit the env.
         os.environ["RT_FLIGHT_ENABLED"] = "1"
-        args.no_train = True  # flight mode measures the RPC plane only
+    import ray_tpu
+    from ray_tpu._private.perf import run_core_benchmarks
+
+    # Scale worker processes to the machine: task execution is
+    # GIL-bound per process, so on many-core hosts (TPU VMs have ~100
+    # vCPUs) throughput comes from multiple node processes. On tiny CI
+    # hosts stay small.
+    cores = os.cpu_count() or 1
     if args.serve:
-        args.no_train = True  # serve mode measures the serving path only
-    if not args.train_only:
-        import ray_tpu
-        from ray_tpu._private.perf import run_core_benchmarks
-
-        # Scale worker processes to the machine: task execution is
-        # GIL-bound per process, so on many-core hosts (TPU VMs have ~100
-        # vCPUs) throughput comes from multiple node processes. On tiny CI
-        # hosts stay small.
-        cores = os.cpu_count() or 1
+        # Serve bench: replicas/proxy/controller are IO-light actors
+        # sharing node processes — schedule on virtual CPU slots (the
+        # closed loop saturates the proxy event loop, not the cores).
+        ray_tpu.init(num_cpus=16, num_nodes=1)
+    elif cores >= 8:
+        ray_tpu.init(num_cpus=4, num_nodes=min(cores // 4, 8))
+    else:
+        ray_tpu.init(num_cpus=max(cores, 2), num_nodes=1)
+    try:
         if args.serve:
-            # Serve bench: replicas/proxy/controller are IO-light actors
-            # sharing node processes — schedule on virtual CPU slots (the
-            # closed loop saturates the proxy event loop, not the cores).
-            ray_tpu.init(num_cpus=16, num_nodes=1)
-        elif cores >= 8:
-            ray_tpu.init(num_cpus=4, num_nodes=min(cores // 4, 8))
+            core = {
+                "single_client_tasks_async_per_s": None,
+                "serve_bench": True,
+                **run_serve_benchmarks(quick=args.quick),
+            }
+            if args.flight:
+                import sys
+
+                from ray_tpu._private import flight
+                from ray_tpu._private.worker import get_global_worker
+
+                w = get_global_worker()
+                h, _ = w.run_sync(
+                    w._head_call("flight_snapshot", {}), 60
+                )
+                merged = flight.merge_snapshots(h["snapshots"])
+                attrib = flight.attribution(merged)
+                print("--- per-verb attribution: serve bench ---",
+                      file=sys.stderr)
+                print(flight.format_attribution(attrib),
+                      file=sys.stderr, flush=True)
+                path = _attrib_path(args.output_dir)
+                # merge: the core legs' attribution (plain --flight
+                # runs) and the serve leg share the file
+                try:
+                    with open(path) as f:
+                        existing = json.load(f)
+                except (OSError, json.JSONDecodeError):
+                    existing = {}
+                existing["serve_bench"] = {"verbs": attrib}
+                with open(path, "w") as f:
+                    json.dump(existing, f, indent=1)
+                core["flight_attrib_file"] = path
+        elif args.flight:
+            core = {
+                "single_client_tasks_async_per_s": None,
+                **run_flight_benchmarks(
+                    quick=args.quick, phases=args.phases,
+                    attrib_path=_attrib_path(args.output_dir),
+                ),
+            }
         else:
-            ray_tpu.init(num_cpus=max(cores, 2), num_nodes=1)
-        try:
-            if args.serve:
-                core = {
-                    "single_client_tasks_async_per_s": None,
-                    "serve_bench": True,
-                    **run_serve_benchmarks(quick=args.quick),
-                }
-                if args.flight:
-                    import sys
+            core = run_core_benchmarks(quick=args.quick)
+        # Peak store watermark rides every bench JSON: throughput
+        # numbers carry their object-plane memory cost.
+        record_peak_object_store(core)
+    finally:
+        ray_tpu.shutdown()
 
-                    from ray_tpu._private import flight
-                    from ray_tpu._private.worker import get_global_worker
-
-                    w = get_global_worker()
-                    h, _ = w.run_sync(
-                        w._head_call("flight_snapshot", {}), 60
-                    )
-                    merged = flight.merge_snapshots(h["snapshots"])
-                    attrib = flight.attribution(merged)
-                    print("--- per-verb attribution: serve bench ---",
-                          file=sys.stderr)
-                    print(flight.format_attribution(attrib),
-                          file=sys.stderr, flush=True)
-                    path = _attrib_path(args.output_dir)
-                    # merge: the core legs' attribution (plain --flight
-                    # runs) and the serve leg share the file
-                    try:
-                        with open(path) as f:
-                            existing = json.load(f)
-                    except (OSError, json.JSONDecodeError):
-                        existing = {}
-                    existing["serve_bench"] = {"verbs": attrib}
-                    with open(path, "w") as f:
-                        json.dump(existing, f, indent=1)
-                    core["flight_attrib_file"] = path
-            elif args.flight:
-                core = {
-                    "single_client_tasks_async_per_s": None,
-                    **run_flight_benchmarks(
-                        quick=args.quick, phases=args.phases,
-                        attrib_path=_attrib_path(args.output_dir),
-                    ),
-                }
-            else:
-                core = run_core_benchmarks(quick=args.quick)
-            # Peak store watermark rides every bench JSON: throughput
-            # numbers carry their object-plane memory cost.
-            record_peak_object_store(core)
-        finally:
-            ray_tpu.shutdown()
-
-    extra = {}
-    if not args.no_train:
-        # A train leg that raises (no chip, a refused compile, an OOM) ends
-        # the run with a traceback and a non-zero exit code; what the core
-        # legs measured is on stderr by then, stdout keeps its ONE line.
-        if not args.train_only:
-            import sys
-
-            print("[bench] core legs:", json.dumps(core), file=sys.stderr,
-                  flush=True)
-        extra = bench_train_tokens_per_sec(quick=args.quick)
 
     value = core["single_client_tasks_async_per_s"]
     result = {
@@ -744,10 +430,6 @@ def main():
         **{
             k: (round(v, 2) if isinstance(v, float) else v)
             for k, v in core.items()
-        },
-        **{
-            k: (round(v, 2) if isinstance(v, float) else v)
-            for k, v in extra.items()
         },
     }
     print(json.dumps(result))

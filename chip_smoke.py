@@ -48,7 +48,7 @@ GPT2_SMALL = {
 }
 # B=32 needs remat: the v5e compiler refuses the step without it (25.85 GB
 # of 15.75 GB HBM) and accepts it with the "dots" policy (10.5 GB of
-# temporaries). It is the batch bench.py's small leg runs.
+# temporaries).
 TRAIN_BATCH = 32
 TRAIN_STEPS = 6
 # bf16 has 8 mantissa bits: one ulp at the outputs' magnitude (|x| < 8) is

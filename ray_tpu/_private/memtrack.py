@@ -228,19 +228,15 @@ def local_snapshot(worker,
     return snap
 
 
-_gauges: Optional[dict] = None
-
-
 def _gauge_set() -> Optional[dict]:
-    """Lazily register the object-plane gauge family (idempotent: the
-    metrics registry canonicalizes re-registrations into one series)."""
-    global _gauges
-    if _gauges is not None:
-        return _gauges
+    """The object-plane gauge family, registered anew each time it is asked
+    for: the metrics registry canonicalizes re-registrations into one
+    series, and a gauge kept from before ``registry().clear()`` would write
+    into series the registry no longer holds."""
     try:
         from ray_tpu.util.metrics import Gauge
 
-        _gauges = {
+        return {
             "bytes": Gauge(
                 "rt_object_store_bytes",
                 description="Owner-accounted object bytes by kind "
@@ -288,7 +284,7 @@ def _gauge_set() -> Optional[dict]:
         }
     except Exception as e:
         logger.debug("memtrack gauges unavailable: %s", e)
-    return _gauges
+        return None
 
 
 _prev_byte_keys: set = set()

@@ -91,12 +91,8 @@ class LLMConfig:
             dtype=self.dtype,
             attention_impl="xla",
         )
-        if self.model_family == "llama":
-            kwargs["num_kv_heads"] = self.num_kv_heads or self.num_heads
-        elif self.num_kv_heads is not None:
-            kwargs["num_kv_heads"] = self.num_kv_heads
-        for name in ("mlp_dim", "rope_theta", "rms_eps", "qk_norm",
-                     "param_dtype"):
+        for name in ("num_kv_heads", "mlp_dim", "rope_theta", "rms_eps",
+                     "qk_norm", "param_dtype"):
             if getattr(self, name) is not None:
                 kwargs[name] = getattr(self, name)
         if self.moe_num_experts:
@@ -108,9 +104,6 @@ class LLMConfig:
                 # the rest of the batch, so prefill and per-step decode
                 # would disagree (and with the full forward).
                 dropless=True,
-                activation=(
-                    "swiglu" if self.model_family == "llama" else "gelu"
-                ),
             )
             if self.moe_router_init_std is not None:
                 kwargs["moe"]["router_init_std"] = self.moe_router_init_std

@@ -136,14 +136,12 @@ def engine_programs(cfg):
     give one more result after the cache, the experts touched a layer [L]."""
     import jax
 
-    from ray_tpu.models import module_for
-
-    model = module_for(cfg)
+    from ray_tpu.models.decoder import forward_cached
 
     def prefill(params, tokens, cache1, start, *real):
         # start > 0 = continuation from a cached prefix: only the
         # prompt's tail runs through the model
-        return model.forward_cached(params, tokens, cache1, start, cfg, *real)
+        return forward_cached(params, tokens, cache1, start, cfg, *real)
 
     def insert(batch_cache, slot_cache, b):
         return jax.tree.map(
@@ -154,14 +152,14 @@ def engine_programs(cfg):
         )
 
     def decode(params, tokens, cache, lens, *real):
-        logits, *rest = model.forward_cached(
+        logits, *rest = forward_cached(
             params, tokens, cache, lens, cfg, *real)
         return (logits[:, -1], *rest)
 
     def decode_all(params, tokens, cache, lens, *real):
         # speculation verify: logits at EVERY position (position j's
         # row predicts the token after input j)
-        return model.forward_cached(params, tokens, cache, lens, cfg, *real)
+        return forward_cached(params, tokens, cache, lens, cfg, *real)
 
     return (
         jax.jit(prefill),
@@ -176,7 +174,7 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.models import config_for, module_for
+        from ray_tpu.models import config_for, decoder, module_for
 
         self.config = config
         self.model_config = config.model_config()
@@ -217,7 +215,7 @@ class DecodeEngine:
             )
         self.params = params
         B, S = config.max_batch_slots, config.max_seq_len
-        self._cache = model.init_kv_cache(self.model_config, B, S)
+        self._cache = decoder.init_kv_cache(self.model_config, B, S)
         self._rng = np.random.RandomState(seed)
 
         cfg = self.model_config
@@ -227,7 +225,7 @@ class DecodeEngine:
         self._prefill, self._insert, self._decode, decode_all = (
             engine_programs(cfg))
         self._decode_spec = decode_all if self._spec_k > 0 else None
-        self._empty_slot_cache = lambda: model.init_kv_cache(cfg, 1, S)
+        self._empty_slot_cache = lambda: decoder.init_kv_cache(cfg, 1, S)
 
         self._slots = [_Slot() for _ in range(B)]
         self._pending: "queue.Queue" = queue.Queue()

@@ -1,65 +1,78 @@
-"""Model zoo: functional JAX model families sharing one interface.
+"""Model zoo: model families as pieces over one decoder.
 
-Each model module exposes: a frozen ``*Config`` dataclass, ``PRESETS``,
-``init_params``, ``param_axes``, ``forward``, ``forward_cached``,
-``init_kv_cache``, ``loss_fn``, ``count_params``, ``flops_per_token`` (and
-optionally ``forward_pipelined``). Train/LLM layers dispatch on the config
-type via :func:`module_for`, and build a family's config from plain keyword
-arguments via :func:`config_for` — adding a family means adding a module
-here.
+``decoder.py`` holds what every family shares, each written once: the layer
+stack (``forward_features``, ``forward``), the cached forward
+(``init_kv_cache``, ``forward_cached``), the pipeline
+(``forward_pipelined``), ``loss_fn``, ``count_params``. A family's module
+(``FAMILIES``) holds the pieces that differ: its ``Config`` dataclass and
+``PRESETS``, ``init_params`` and ``param_axes``, and the pieces the decoder
+calls (``embed``, ``qkv``, ``attn_out``, ``ffn``, ``final_norm``, ``head``,
+``head_weight``; ``decoder.py`` gives each one's signature), and it hands the
+decoder's functions on under its own name, so ``module_for(cfg).loss_fn`` is
+the one definition. A new architecture is a family module, or a piece of
+one, and one line of ``FAMILIES``.
+
+Train/LLM layers find a config's family via :func:`module_for`, and build a
+family's config from plain keyword arguments via :func:`config_for`.
 The KV cache is ``{"k", "v"}``, each ``[L, B, KV, D, S]`` (``kv_cache.py``):
-callers outside a model module rely on the slot being axis 1 and on nothing
+callers outside this package rely on the slot being axis 1 and on nothing
 else.
 """
 from __future__ import annotations
 
+import importlib
 from typing import Any
+
+# family name -> its module (imported when asked for: they import jax)
+FAMILIES = {
+    "gpt2": "ray_tpu.models.gpt2",
+    "llama": "ray_tpu.models.llama",
+}
+
+
+def family_module(family: str):
+    """The module of the family called ``family``."""
+    if family not in FAMILIES:
+        raise ValueError(
+            f"unknown model_family {family!r} ({' | '.join(FAMILIES)})")
+    return importlib.import_module(FAMILIES[family])
 
 
 def module_for(config: Any):
     """Return the model module that owns this config object."""
-    from ray_tpu.models import gpt2, llama
-
-    if isinstance(config, llama.LlamaConfig):
-        return llama
-    if isinstance(config, gpt2.GPT2Config):
-        return gpt2
+    for family in FAMILIES:
+        module = family_module(family)
+        if isinstance(config, module.Config):
+            return module
     raise TypeError(f"unknown model config type: {type(config).__name__}")
-
-
-FAMILIES = ("gpt2", "llama")
 
 
 def config_for(family: str, **kwargs):
     """The ``family``'s own config object from keyword arguments as a file or
     a bundle states them: ``dtype`` / ``param_dtype`` may be names
-    ("bfloat16"), ``moe`` a dictionary of ``MoEConfig`` fields. A keyword the
-    family's config does not take is its ``TypeError``, by that name."""
+    ("bfloat16"), ``moe`` a dictionary of ``MoEConfig`` fields (experts with
+    no ``activation`` stated get the family's). A keyword the family's
+    config does not take is its ``TypeError``, by that name."""
     import jax.numpy as jnp
 
-    from ray_tpu.models import gpt2, llama
     from ray_tpu.parallel.moe import MoEConfig
 
-    if family not in FAMILIES:
-        raise ValueError(
-            f"unknown model_family {family!r} ({' | '.join(FAMILIES)})")
+    module = family_module(family)
     for key in ("dtype", "param_dtype"):
         if isinstance(kwargs.get(key), str):
             kwargs[key] = jnp.dtype(kwargs[key]).type
     if isinstance(kwargs.get("moe"), dict):
-        kwargs["moe"] = MoEConfig(**kwargs["moe"])
-    cls = llama.LlamaConfig if family == "llama" else gpt2.GPT2Config
-    return cls(**kwargs)
+        kwargs["moe"] = MoEConfig(
+            **{"activation": module.EXPERT_ACTIVATION, **kwargs["moe"]})
+    return module.Config(**kwargs)
 
 
 def get_preset(name: str):
     """Look up a preset config by name across all families."""
-    from ray_tpu.models import gpt2, llama
-
-    for mod in (gpt2, llama):
-        if name in mod.PRESETS:
-            return mod.PRESETS[name]
-    known = sorted(
-        list(gpt2.PRESETS) + list(llama.PRESETS)
-    )
-    raise KeyError(f"unknown model preset {name!r}; known: {known}")
+    presets = {}
+    for family in FAMILIES:
+        presets.update(family_module(family).PRESETS)
+    if name not in presets:
+        raise KeyError(
+            f"unknown model preset {name!r}; known: {sorted(presets)}")
+    return presets[name]

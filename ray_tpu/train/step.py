@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ray_tpu.models import module_for
+from ray_tpu.models import decoder, module_for
 from ray_tpu.parallel.sharding import (
     DEFAULT_RULES,
     named_sharding,
@@ -121,7 +121,6 @@ def make_train_step(
     Stochastic layers (MoE router jitter) draw from a per-step key folded
     from ``seed`` and ``state["step"]``.
     """
-    model = module_for(config)
     moe = getattr(config, "moe", None)
     needs_rng = moe is not None and moe.router_jitter > 0
     p_shard = (
@@ -129,7 +128,7 @@ def make_train_step(
     )
 
     def loss(params, batch, rng):
-        return model.loss_fn(
+        return decoder.loss_fn(
             params, batch, config, mesh,
             pipeline_microbatches=pipeline_microbatches, rng=rng,
         )
@@ -175,9 +174,7 @@ def make_train_step(
 
 
 def make_eval_step(config, mesh=None) -> Callable:
-    model = module_for(config)
-
     def eval_fn(params, batch):
-        return model.loss_fn(params, batch, config, mesh)
+        return decoder.loss_fn(params, batch, config, mesh)
 
     return jax.jit(eval_fn)

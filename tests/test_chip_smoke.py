@@ -105,18 +105,3 @@ def test_main_exits_nonzero_off_the_chip(tmp_path, fake_chip):
         assert "AcceleratorMismatchError" in proc.stderr, proc.stderr[-3000:]
     else:
         assert "exposes 0" in proc.stderr
-
-
-def test_bench_train_legs_refuse_anything_but_a_tpu():
-    """bench.py: no smaller model under the TPU metric's name off the chip,
-    no assumed peak for a device that is not in the table."""
-    import bench
-
-    with pytest.raises(RuntimeError, match="need a TPU"):
-        bench.bench_train_tokens_per_sec(quick=True)
-    with pytest.raises(RuntimeError, match="need a TPU"):
-        bench.bench_train_medium()
-    assert bench.peak_bf16_flops("TPU v5 lite") == 197e12
-    with pytest.raises(KeyError, match="no peak"):
-        bench.peak_bf16_flops("TPU v9 imaginary")
-    assert not hasattr(bench, "_bench_train_medium_subprocess")

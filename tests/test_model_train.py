@@ -302,6 +302,40 @@ def test_loss_fn_chunked_matches_logits_path():
         assert abs(loss - ref) < 1e-4, (mod.__name__, loss, ref)
 
 
+@pytest.mark.parametrize("preset", ["gpt2-tiny", "llama-tiny"])
+def test_remat_keeps_the_flash_residuals(preset):
+    """The one remat policy (``decoder._remat_policy``) saves the flash
+    kernel's named residuals for every family, so a remat'd backward never
+    runs the forward kernel again; loss and gradients are those of the step
+    without remat. (llama's own policy knew neither name before ISSUE 29.)"""
+    import dataclasses
+
+    from ray_tpu.models import decoder, get_preset, module_for
+
+    cfg = dataclasses.replace(
+        get_preset(preset), dtype=jnp.float32,
+        attention_impl="flash_interpret")
+    params = jax.jit(module_for(cfg).init_params, static_argnums=0)(
+        cfg, jax.random.PRNGKey(0))
+    batch = _batch(B=2, T=128, vocab=cfg.vocab_size)
+
+    def step(remat):
+        c = dataclasses.replace(cfg, remat=remat)
+        return jax.value_and_grad(lambda p: decoder.loss_fn(p, batch, c))
+
+    loss, grads = jax.jit(step(True))(params)
+    ref_loss, ref_grads = jax.jit(step(False))(params)
+    assert abs(float(loss) - float(ref_loss)) < 1e-6
+    for g, r in zip(jax.tree.leaves(grads), jax.tree.leaves(ref_grads)):
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(r), atol=1e-6, rtol=1e-5)
+    jaxpr = str(jax.make_jaxpr(step(True))(params))
+    assert "flash_out" in jaxpr and "flash_lse" in jaxpr
+    # saved, not recomputed: the backward holds the two backward kernels and
+    # no second forward one (3 pallas calls a layer body, not 4)
+    assert jaxpr.count("pallas_call") == 3, jaxpr.count("pallas_call")
+
+
 def test_optimizer_state_is_sharded_like_its_params(caplog):
     """Found on four real chips (PR 21): adam's mu/nu sat whole on device 0
     (1.49 GB there, 0.41 GB on the others) and step 2 compiled again because
