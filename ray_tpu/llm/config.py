@@ -37,7 +37,11 @@ class LLMConfig:
     rope_theta: Optional[float] = None   # llama: rotary base
     rms_eps: Optional[float] = None      # llama: RMSNorm epsilon
     qk_norm: Optional[str] = None        # llama: "none" | "full" (OLMoE)
-    param_dtype: Optional[str] = None    # dtype the weights are held in
+    # dtype the weights are made (fresh) or loaded (a bundle) in; None = the
+    # family's (float32). What a replica HOLDS follows from it and ``dtype``:
+    # the engine keeps each weight its family's forward rounds to ``dtype``
+    # on use rounded once, at load (``DecodeEngine``)
+    param_dtype: Optional[str] = None
     # Routed experts in place of the MLP (``parallel/moe.py``): their number
     # (0 = dense) and how many a token reaches. GELU experts under gpt2,
     # SwiGLU under llama (Mixtral: 8 / 2; OLMoE: 64 / 8 and

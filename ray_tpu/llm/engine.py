@@ -21,7 +21,9 @@ timeline: ``engine.tick`` (children ``.pack``, ``.dispatch``, ``.fetch``,
 capture runs (``rt profile --xla``). Their arguments are the counters of
 that boundary, and ``stats`` sums the same quantities with no capture:
 ``cache_positions`` of ``engine.tick`` is how much of the KV cache the tick
-needed (the active slots' lengths and the columns it writes).
+needed (the active slots' lengths and the columns it writes). One span is
+the loading thread's: ``engine.weights``, once a replica, around the weights'
+arrival as the engine holds them.
 
 A model with routed experts (``parallel/moe.py``) is told which rows of a
 program carry a token (the slots that decode, a prompt's own positions in
@@ -126,9 +128,28 @@ class _Pending:
     submitted: float
 
 
+def _weights_facts(given, held) -> dict:
+    """What ``engine.weights`` records of the weights a family's
+    ``serving_params`` was ``given`` and of those it handed back: their
+    bytes, and how many leaves are not the leaf given (0: nothing was to be
+    rounded, and nothing ran). Arrays or a trace's values alike."""
+    import jax
+
+    given, held = jax.tree.leaves(given), jax.tree.leaves(held)
+
+    def nbytes(leaves):
+        return sum(a.size * a.dtype.itemsize for a in leaves)
+
+    return {
+        "given_bytes": nbytes(given), "held_bytes": nbytes(held),
+        "leaves_rounded": sum(h is not g for g, h in zip(given, held)),
+    }
+
+
 def engine_programs(cfg):
     """The engine's four XLA programs for model config ``cfg``: (prefill,
-    insert, decode, decode_all), jitted and not yet compiled. The cache is
+    insert, decode, decode_all), jitted and not yet compiled. ``params`` are
+    the weights as the engine holds them (``DecodeEngine``). The cache is
     the model module's pytree with the slot on axis 1; ``insert`` and both
     decodes take it donated and give it back in the same buffer. For a
     model with routed experts the three model programs take one more
@@ -170,6 +191,15 @@ def engine_programs(cfg):
 
 
 class DecodeEngine:
+    """One replica's weights, cache, slots and loop.
+
+    ``params`` (or the bundle at ``config.model_source``, or fresh weights
+    from ``PRNGKey(seed)``) are the weights as they are made or loaded,
+    ``param_dtype`` wide. The engine keeps ``self.params``: the family's
+    ``serving_params`` of them, made once here (span ``engine.weights``:
+    ``given_bytes``, ``held_bytes``, ``leaves_rounded``), and no other tree.
+    A caller's own reference to what it gave is the caller's."""
+
     def __init__(self, config: LLMConfig, params=None, seed: int = 0):
         import jax
         import jax.numpy as jnp
@@ -204,21 +234,39 @@ class DecodeEngine:
             )
             self._moe_layers = self.model_config.num_layers
             self._moe_top_k = self.model_config.moe.top_k
-        model = module_for(self.model_config)
+        cfg = self.model_config
+        model = module_for(cfg)
         self.tokenizer = load_tokenizer(config)
+        self._span = jax.profiler.TraceAnnotation
+        # The weights as the family's cached forward wants them of a caller
+        # that runs it for every token: what it rounds to the activations'
+        # dtype on every use, rounded once, here. Under a trace or on the
+        # arrays themselves; a leaf the family hands back as it was given
+        # costs no operation and no byte (bf16 experts stay where they lie).
+        weights = {}
+
+        def served(given):
+            held = model.serving_params(cfg, given)
+            weights.update(_weights_facts(given, held))
+            return held
+
         if params is None:
             # One program: op by op, each parameter's RNG call compiles on
             # its own, and a cold replica of GPT-2-small spent 48 s there
-            # on a v5e — more than serve.run() waits for a deployment.
-            params = jax.jit(model.init_params, static_argnums=0)(
-                self.model_config, jax.random.PRNGKey(seed)
-            )
-        self.params = params
+            # on a v5e — more than serve.run() waits for a deployment. The
+            # rounding is inside it, so the tree as it is initialised need
+            # never be whole on the chip.
+            params = jax.jit(
+                lambda key: served(model.init_params(cfg, key))
+            )(jax.random.PRNGKey(seed))
+        else:
+            params = served(params)
+        with self._span("engine.weights", **weights):
+            self.params = jax.block_until_ready(params)
         B, S = config.max_batch_slots, config.max_seq_len
-        self._cache = decoder.init_kv_cache(self.model_config, B, S)
+        self._cache = decoder.init_kv_cache(cfg, B, S)
         self._rng = np.random.RandomState(seed)
 
-        cfg = self.model_config
         self._spec_k = max(
             0, int(getattr(config, "speculative_ngram_k", 0) or 0)
         )  # negatives = disabled, never a half-armed dispatch path
@@ -259,7 +307,6 @@ class DecodeEngine:
             "moe_rows": 0, "moe_experts_touched": 0,
             "compiles": compile_count(),  # of the process, not the engine
         }
-        self._span = jax.profiler.TraceAnnotation
         self._rid_seq = itertools.count(1)
 
     # ------------------------------------------------------------- sampling

@@ -7,10 +7,11 @@ stack (``forward_features``, ``forward``), the cached forward
 (``FAMILIES``) holds the pieces that differ: its ``Config`` dataclass and
 ``PRESETS``, ``init_params`` and ``param_axes``, and the pieces the decoder
 calls (``embed``, ``qkv``, ``attn_out``, ``ffn``, ``final_norm``, ``head``,
-``head_weight``; ``decoder.py`` gives each one's signature), and it hands the
-decoder's functions on under its own name, so ``module_for(cfg).loss_fn`` is
-the one definition. A new architecture is a family module, or a piece of
-one, and one line of ``FAMILIES``.
+``head_weight``; ``decoder.py`` gives each one's signature) and the one a
+server calls once (``serving_params``), and it hands the decoder's functions
+on under its own name, so ``module_for(cfg).loss_fn`` is the one
+definition. A new architecture is a family module, or a piece of one, and
+one line of ``FAMILIES``.
 
 Train/LLM layers find a config's family via :func:`module_for`, and build a
 family's config from plain keyword arguments via :func:`config_for`.
@@ -65,6 +66,26 @@ def config_for(family: str, **kwargs):
         kwargs["moe"] = MoEConfig(
             **{"activation": module.EXPERT_ACTIVATION, **kwargs["moe"]})
     return module.Config(**kwargs)
+
+
+def narrowed(tree, dtype, as_given=()):
+    """``tree`` with every leaf that is held wider than ``dtype`` cast to it,
+    but for the leaves whose own name (their last key) is in ``as_given``.
+    Every other leaf of the result IS the leaf given, so where nothing is
+    wider no operation runs and no byte is copied. What a family's
+    ``serving_params`` is made of: the family says which names its cached
+    forward reads as they are."""
+    import jax
+    import jax.numpy as jnp
+
+    width = jnp.dtype(dtype).itemsize
+
+    def held(path, leaf):
+        if path[-1].key in as_given or leaf.dtype.itemsize <= width:
+            return leaf
+        return leaf.astype(dtype)
+
+    return jax.tree_util.tree_map_with_path(held, tree)
 
 
 def get_preset(name: str):

@@ -21,6 +21,16 @@ one signature across families:
 - ``final_norm(config, params, x)``, ``head(config, params, x)`` -> float32
   logits, ``head_weight(params)`` -> the [V, E] matrix the chunked
   cross-entropy multiplies by;
+- ``serving_params(config, params)`` -> the tree as ``forward_cached`` (its
+  cache in ``config.dtype``) wants to be given it by a caller that calls it
+  more than once: each leaf that the pieces above cast to ``config.dtype``
+  before every use held in ``config.dtype``, every other leaf the array
+  given (``models.narrowed``). The casts stay in the pieces, where on such
+  a leaf they are nothing, so the results are the same bit for bit, and a
+  compiled program no longer rounds every weight each time it runs. Nothing
+  here calls it: a server does, once, as it takes its weights
+  (``llm/engine.py``); training and the full forward keep the tree as it
+  was initialised;
 - ``config.num_kv_heads``.
 
 What follows from shapes alone is decided here: a grouped q is flattened and

@@ -12,10 +12,13 @@ each piece is given and returns.
   params (fsdp/tensor), XLA inserts the collectives. Block params carry a
   leading [num_layers] dim: the decoder's stacked super-layer, and the dim
   the "stage" mesh axis splits.
-- bfloat16 activations, f32 params + optimizer (standard mixed precision);
-  the cached forward's residual stream is ``dtype`` too, and the head rounds
-  its logits to ``dtype`` before float32 (llama's does neither: ROADMAP
-  Queue 3, item 3).
+- bfloat16 activations, f32 params + optimizer (standard mixed precision):
+  every product multiplies by its weight rounded to ``dtype``. Training
+  rounds the float32 parameters it updates on use; a server holds them
+  rounded once (``serving_params``), the norms' gains and biases float32 as
+  ``_layer_norm`` reads them. The cached forward's residual stream is
+  ``dtype`` too, and the head rounds its logits to ``dtype`` before float32
+  (llama's does neither: ROADMAP Queue 3, item 3).
 """
 from __future__ import annotations
 
@@ -25,6 +28,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import narrowed
 from ray_tpu.models.decoder import *  # noqa: F401,F403 — what families share
 from ray_tpu.parallel.moe import (
     MoEConfig,
@@ -169,6 +173,16 @@ def _layer_norm(x, g, b, eps=1e-5):
     var = x32.var(-1, keepdims=True)
     y = (x32 - mean) * jax.lax.rsqrt(var + eps)
     return (y * g + b).astype(x.dtype)
+
+
+def serving_params(config: GPT2Config, params):
+    """Every matrix, every bias, ``wte`` (one copy serves ``embed`` and the
+    tied ``head``: both cast it) and ``wpe`` are read through
+    ``.astype(config.dtype)`` alone; ``_layer_norm`` multiplies by its gains
+    and biases in float32, and the router runs in float32
+    (``moe.stacked_for`` leaves it alone too)."""
+    return narrowed(params, config.dtype, as_given=(
+        "ln1_g", "ln1_b", "ln2_g", "ln2_b", "ln_f_g", "ln_f_b", "router_w"))
 
 
 def embed(config: GPT2Config, params, tokens, pos, cached: bool):

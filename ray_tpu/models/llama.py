@@ -16,9 +16,10 @@ returns.
 - plain-pytree params with a parallel logical-axis tree for pjit sharding;
   block params carry a leading [num_layers] dim
 - bfloat16 activations over f32 params (or bf16 params as they are: a cast
-  to the dtype an array already has is no operation); logits are the head's
-  float32 sums, and the cached forward (serving) sums its residual stream in
-  float32 too
+  to the dtype an array already has is no operation; a server holds what
+  the cached forward rounds on use rounded once, ``serving_params``); logits
+  are the head's float32 sums, and the cached forward (serving) sums its
+  residual stream in float32 too
 - k and v leave the preamble with the kv heads the model has (GQA-sized:
   that is what the KV cache holds)
 """
@@ -30,6 +31,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import narrowed
 from ray_tpu.models.decoder import *  # noqa: F401,F403 — what families share
 from ray_tpu.parallel.moe import (
     MoEConfig,
@@ -220,6 +222,17 @@ def _rope(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
     sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
     x1, x2 = x[..., : D // 2], x[..., D // 2:]
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
+
+
+def serving_params(config: LlamaConfig, params):
+    """The projections, the MLP or the experts and ``lm_head`` are read
+    through ``.astype(config.dtype)`` alone. Read as they are: ``wte`` (the
+    cached forward's residual stream is float32), every RMSNorm gain
+    (``_rms_norm`` multiplies in float32) and the router (float32;
+    ``moe.stacked_for`` leaves it alone too)."""
+    return narrowed(params, config.dtype, as_given=(
+        "wte", "attn_norm", "mlp_norm", "q_norm", "k_norm", "norm_f",
+        "router_w"))
 
 
 def embed(config: LlamaConfig, params, tokens, pos, cached: bool):

@@ -164,6 +164,9 @@ def recording(tmp_path_factory):
     jax.profiler.start_trace(logdir, profiler_options=options)
     try:
         time.sleep(0.06)  # the idle engine, inside the capture
+        # a replica that takes its weights while the capture runs: float32
+        # as they are made, activations in bf16
+        bf16 = DecodeEngine(LLMConfig(**{**_MODEL, "dtype": "bfloat16"}))
         traced = _scenario(srv, stop_token)
         reports = _train(os.path.join(root, "run_traced"))
         time.sleep(0.06)
@@ -182,7 +185,36 @@ def recording(tmp_path_factory):
         "stats": stats, "plain_stats": plain_stats, "plain": plain,
         "traced": traced, "reports": reports,
         "files_without_capture": files_without_capture,
+        "float32_engine": srv.engine, "bf16_engine": bf16,
     }
+
+
+def test_an_engine_holds_its_weights_rounded_once(recording):
+    """``engine.weights``: the weights are made in float32 and held as the
+    family's cached forward reads them, the matrices in the activations'
+    dtype and the norms' vectors in float32; one tree, and where nothing is
+    wider than the activations, the tree as it was made."""
+    import jax
+    import jax.numpy as jnp
+
+    def nbytes(tree):
+        return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
+
+    held = recording["bf16_engine"].params
+    norms = {"ln1_g", "ln1_b", "ln2_g", "ln2_b"}
+    for name, leaf in held["blocks"].items():
+        assert leaf.dtype == (jnp.float32 if name in norms else jnp.bfloat16)
+    assert held["wte"].dtype == held["wpe"].dtype == jnp.bfloat16
+    assert held["ln_f_g"].dtype == held["ln_f_b"].dtype == jnp.float32
+    (span,) = recording["spans"].named("engine.weights")
+    assert span.args["leaves_rounded"] == 10  # wte, wpe, 4 matrices + biases
+    assert span.args["held_bytes"] == nbytes(held)
+    given = nbytes(recording["float32_engine"].params)  # the same shapes
+    assert span.args["given_bytes"] == given
+    assert given / 2 < span.args["held_bytes"] < given * 0.51
+    # float32 activations: nothing to round
+    assert {a.dtype for a in jax.tree.leaves(
+        recording["float32_engine"].params)} == {jnp.dtype(jnp.float32)}
 
 
 def _by_rid(spans, name):
@@ -191,7 +223,7 @@ def _by_rid(spans, name):
 
 @pytest.mark.parametrize("name", [
     "engine.tick", *TICK_CHILDREN, "engine.admit", *ADMIT_CHILDREN,
-    "engine.finish", "engine.idle", "llm.request",
+    "engine.finish", "engine.idle", "engine.weights", "llm.request",
     "train.step", *STEP_CHILDREN, "train.report", "train.checkpoint",
 ])
 def test_every_span_is_in_the_trace(recording, name):

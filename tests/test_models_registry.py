@@ -7,9 +7,12 @@ grows its own scan, or a decoder that asks which family it serves, fails
 here.
 """
 import ast
+import dataclasses
 import inspect
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from ray_tpu import models
@@ -17,10 +20,12 @@ from ray_tpu.llm.config import LLMConfig
 from ray_tpu.models import (
     FAMILIES, config_for, decoder, family_module, get_preset, module_for,
 )
+from ray_tpu.parallel.moe import MoEConfig
 
-# what the decoder calls on a family, and what callers outside ask of one
+# what the decoder calls on a family, what a server calls once as it takes
+# its weights, and what callers outside ask of one
 PIECES = ("embed", "qkv", "attn_out", "ffn", "final_norm", "head",
-          "head_weight")
+          "head_weight", "serving_params")
 OWN = ("Config", "PRESETS", "EXPERT_ACTIVATION", "init_params", "param_axes")
 SHARED = ("forward_features", "forward", "init_kv_cache", "forward_cached",
           "forward_pipelined", "loss_fn", "count_params")
@@ -145,3 +150,102 @@ def test_llm_config_builds_every_family(family, experts):
             stated.model_config()
         assert decoder.init_kv_cache(cfg, 3, 16)["k"].shape == (
             4, 3, 4, 16, 16)
+
+
+# ------------------------------------------------- the weights a server holds
+# ``serving_params``: what a family's cached forward rounds on every use,
+# rounded once. The same values by the same operation, so not a bit moves.
+
+TINY = {"gpt2": "gpt2-tiny", "llama": "llama-tiny"}
+
+
+def _tiny(family, experts, **dtypes):
+    cfg = dataclasses.replace(get_preset(TINY[family]), **dtypes)
+    if experts:
+        cfg = dataclasses.replace(cfg, moe=MoEConfig(
+            num_experts=experts, top_k=2, dropless=True,
+            activation=family_module(family).EXPERT_ACTIVATION))
+    return cfg
+
+
+def _perturbed(params):
+    """Every leaf of ones or zeros (norm gains, biases) and the router moved:
+    at init ``bf16(1.0) == 1.0`` hides a gain that was rounded."""
+    keys = iter(jax.random.split(jax.random.PRNGKey(1), 64))
+
+    def moved(path, a):
+        constant = bool((a == a.reshape(-1)[0]).all())  # ones or zeros
+        if not constant and path[-1].key != "router_w":
+            return a
+        return a + 0.37 * jax.random.normal(next(keys), a.shape, a.dtype)
+
+    return jax.tree_util.tree_map_with_path(moved, params)
+
+
+def _prefill_and_three_steps(cfg, params):
+    """Every logit and the cache of a prefill of two prompts and three
+    greedy decode steps of ``forward_cached``."""
+    step = jax.jit(
+        lambda p, t, c, s: decoder.forward_cached(p, t, c, s, cfg))
+    tokens = jnp.asarray(
+        np.random.default_rng(0).integers(2, cfg.vocab_size, (2, 16)),
+        jnp.int32)
+    start = jnp.zeros((2,), jnp.int32)
+    logits, cache = step(params, tokens, decoder.init_kv_cache(cfg, 2, 64),
+                         start)
+    out = [logits]
+    for i in range(3):
+        nxt = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
+        logits, cache = step(params, nxt, cache, start + 16 + i)
+        out.append(logits)
+    return [np.asarray(a) for a in out + [cache["k"], cache["v"]]]
+
+
+@pytest.mark.parametrize("weights", ["init", "perturbed"])
+@pytest.mark.parametrize("experts", [0, 4], ids=["dense", "routed"])
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_serving_params_move_no_bit_of_the_cached_forward(
+        family, experts, weights):
+    module = family_module(family)
+    cfg = _tiny(family, experts)
+    assert cfg.param_dtype == jnp.float32 and cfg.dtype == jnp.bfloat16
+    given = module.init_params(cfg, jax.random.PRNGKey(0))
+    if weights == "perturbed":
+        given = _perturbed(given)
+        assert not any(((a == 1) | (a == 0)).any()
+                       for a in jax.tree.leaves(given))
+    held = module.serving_params(cfg, given)
+    rounded = [h is not g for g, h in zip(
+        jax.tree.leaves(given), jax.tree.leaves(held))]
+    assert any(rounded) and not all(rounded)
+    assert {a.dtype for a in jax.tree.leaves(held)} == {
+        jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)}
+    want = _prefill_and_three_steps(cfg, given)
+    for a, b in zip(want, _prefill_and_three_steps(cfg, held)):
+        np.testing.assert_array_equal(a, b)
+    if weights == "perturbed":
+        # and the comparison sees a leaf rounded that the forward reads as
+        # it is (a norm's gain, llama's ``wte``, the router)
+        everything = jax.tree.map(lambda a: a.astype(cfg.dtype), given)
+        assert any((a != b).any() for a, b in zip(
+            want, _prefill_and_three_steps(cfg, everything)))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("experts", [0, 4], ids=["dense", "routed"])
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_serving_params_are_the_arrays_given_where_none_is_wider(
+        family, experts, dtype):
+    """``param_dtype == dtype``, and bf16 weights under float32
+    activations: nothing to round, so no operation runs and no byte is
+    copied (7.1 GB of bf16 experts stay where they lie)."""
+    module = family_module(family)
+    for cfg in (_tiny(family, experts, dtype=dtype, param_dtype=dtype),
+                _tiny(family, experts, dtype=jnp.float32,
+                      param_dtype=jnp.bfloat16)):
+        given = module.init_params(cfg, jax.random.PRNGKey(0))
+        held = module.serving_params(cfg, given)
+        assert jax.tree.structure(held) == jax.tree.structure(given)
+        for g, h in zip(jax.tree.leaves(given), jax.tree.leaves(held)):
+            assert h is g

@@ -343,8 +343,13 @@ def served(tmp_path_factory):
     options.python_tracer_level = 0
     rng = np.random.default_rng(9)
     prompts = [list(rng.integers(2, 258, n)) for n in (3, 11, 20, 7, 30)]
+    bf16 = LLMConfig(**{**TINY, "dtype": "bfloat16",
+                        "param_dtype": "bfloat16"})
+    bf16_given = _tiny_params(bf16.model_config())
     jax.profiler.start_trace(logdir, profiler_options=options)
     try:
+        # a replica that takes bf16 weights while the capture runs
+        bf16_engine = DecodeEngine(bf16, params=bf16_given)
         futures = [engine.submit(p, SamplingParams(max_new_tokens=n))
                    for p, n in zip(prompts, (6, 3, 9, 1, 5))]
         answers = [list(f.result(timeout=120)) for f in futures]
@@ -353,7 +358,22 @@ def served(tmp_path_factory):
     stats = dict(engine.stats)
     engine.shutdown()
     return {"spans": host_spans.load(logdir), "stats": stats,
-            "prompts": prompts, "answers": answers, "engine": engine}
+            "prompts": prompts, "answers": answers, "engine": engine,
+            "bf16_given": bf16_given, "bf16_engine": bf16_engine}
+
+
+def test_bf16_weights_are_held_as_they_were_given(served):
+    """OLMoE's weights are bf16 as the checkpoint's are: the engine holds
+    the very arrays it was given (no program runs over 7.1 GB of experts),
+    and ``engine.weights`` says that nothing was rounded."""
+    given = jax.tree.leaves(served["bf16_given"])
+    held = jax.tree.leaves(served["bf16_engine"].params)
+    assert len(held) == len(given) and all(
+        h is g for g, h in zip(given, held))
+    (span,) = served["spans"].named("engine.weights")
+    assert span.args["leaves_rounded"] == 0
+    assert span.args["held_bytes"] == span.args["given_bytes"] == sum(
+        a.size * 2 for a in given)
 
 
 def test_moe_counters_equal_the_spans_arguments(served):

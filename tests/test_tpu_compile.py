@@ -117,8 +117,10 @@ def test_flash_attention_compiles_inside_a_pipeline_stage(topo):
 
 def _engine_program_args(one_chip, slots, width, cfg=None):
     """Shapes on the described chip of what an engine program of ``cfg``
-    (default: GPT-2 XL) takes: params, tokens, cache, start, and for a model
-    with routed experts the rows that carry a token."""
+    (default: GPT-2 XL) takes: params as the engine holds them (the
+    family's ``serving_params`` of what it initialises), tokens, cache,
+    start, and for a model with routed experts the rows that carry a
+    token."""
     from ray_tpu.models import gpt2, module_for
 
     cfg = cfg or gpt2.GPT2_XL
@@ -129,8 +131,8 @@ def _engine_program_args(one_chip, slots, width, cfg=None):
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                            sharding=one_chip), tree)
 
-    params = on_chip(jax.eval_shape(
-        lambda: model.init_params(cfg, jax.random.PRNGKey(0))))
+    params = on_chip(jax.eval_shape(lambda: model.serving_params(
+        cfg, model.init_params(cfg, jax.random.PRNGKey(0)))))
     cache = on_chip(jax.eval_shape(
         lambda: model.init_kv_cache(cfg, slots, cfg.max_seq_len)))
     tokens = jax.ShapeDtypeStruct((slots, width), jnp.int32,
@@ -138,6 +140,25 @@ def _engine_program_args(one_chip, slots, width, cfg=None):
     start = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
     real = (start,) if getattr(cfg, "moe", None) is not None else ()
     return cfg, (params, tokens, cache, start, *real)
+
+
+def _weight_converts(hlo_text, params):
+    """Every ``convert`` in the optimized HLO (an instruction of its own or
+    the root a fusion is named after) whose result has the dimensions of a
+    stacked block weight or of an embedding table: a program that rounds a
+    weight it was given."""
+    import re
+
+    shapes = {",".join(map(str, a.shape)) for a in jax.tree.leaves(params)
+              if a.ndim >= 2 and a.size >= 1 << 20}
+    assert len(shapes) >= 5
+    found = []
+    for line in hlo_text.splitlines():
+        m = re.match(
+            r"\s*(?:ROOT )?%?(convert[\w.\-]*) = \w+\[([\d,]*)\]", line)
+        if m and m.group(2) in shapes:
+            found.append((m.group(1), m.group(2)))
+    return found
 
 
 def _cache_sized(hlo_text, cache, ops="copy|transpose"):
@@ -170,11 +191,15 @@ def test_decode_program_updates_the_cache_in_place(one_chip, cell,
                                                    monkeypatch):
     """``jit_decode`` as the engine builds it, at both serving cells' sizes
     (GPT-2 XL, 10 slots of 1,024; OLMoE, 16 slots of 4,096): the donated
-    cache is the result's buffer, no second cache among the temporaries
-    (what is left there is the weights in bf16), no copy of a layer's slice
-    or of the whole cache anywhere in the program; the layer's access is
-    the ``decode_attention`` kernel over the whole cache, and no fusion
-    makes a layer's slice."""
+    cache is the result's buffer, no second cache and no second set of
+    weights among the temporaries, no copy of a layer's slice or of the
+    whole cache anywhere in the program; the layer's access is the
+    ``decode_attention`` kernel over the whole cache, and no fusion makes a
+    layer's slice. The weights arrive as the engine holds them, rounded
+    once at load, so the program converts none: GPT-2 XL's arguments are
+    3.1 GB of weights and the cache, where a tick rounded 6.2 GB of float32
+    and held the 3.0 GB result beside them (13.8 of 21.0 ms; my chip run,
+    PR 28)."""
     from ray_tpu.llm.engine import engine_programs
     from ray_tpu.models import kv_cache
 
@@ -193,7 +218,14 @@ def test_decode_program_updates_the_cache_in_place(one_chip, cell,
     assert "jit_decode" in header
     assert mem.alias_size_in_bytes == cache_bytes
     assert header.count("-alias)") == 2
-    assert mem.temp_size_in_bytes < 4e9
+    assert mem.temp_size_in_bytes < 0.5e9
+    assert _weight_converts(text, args[0]) == []
+    if cell == "gpt2-xl.serve-chat":
+        weights = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(args[0]))
+        assert 3.1e9 < weights < 3.13e9  # 1.56 B parameters in bf16
+        assert abs(mem.argument_size_in_bytes / (weights + cache_bytes) - 1
+                   ) < 0.02
     assert _cache_sized(text, cache) == []
     calls = [line for line in text.splitlines()
              if "custom-call(" in line and "decode_attention" in line]
@@ -205,7 +237,8 @@ def test_decode_program_updates_the_cache_in_place(one_chip, cell,
 def test_prefill_program_copies_no_layer_of_the_cache(one_chip):
     """``jit_prefill`` at bucket 256, B = 1. Its cache is not donated (a
     prefix-cache entry is shared), so the entry computation copies it once;
-    inside the layer loop nothing cache-sized is copied or transposed."""
+    inside the layer loop nothing cache-sized is copied or transposed. It
+    converts no weight either: an admission rounded the whole model too."""
     from ray_tpu.llm.engine import engine_programs
 
     cfg, args = _engine_program_args(one_chip, slots=1, width=256)
@@ -213,6 +246,7 @@ def test_prefill_program_copies_no_layer_of_the_cache(one_chip):
     compiled = prefill.lower(*args).compile()
     text = compiled.as_text()
     assert "jit_prefill" in text.split("\n", 1)[0]
+    assert _weight_converts(text, args[0]) == []
     copies = _cache_sized(text, args[2])
     assert [c for c in copies if c[0] != "ENTRY"] == []
     assert len(copies) <= 2
