@@ -10,25 +10,41 @@ split, KV cache management. TPU-first redesign instead of a port:
 - Prompts prefill at bucketed lengths (few compile variants) into a
   batch=1 cache, then a jitted insert writes the slot row — requests join
   and leave the running batch without recompiling (the "continuous" part).
-- Sampling happens host-side on the [B, V] logits of the tick (greedy /
-  temperature / top-k), which keeps the compiled program sampling-agnostic.
+- Sampling happens host-side on the [B, V] logits of the tick (temperature
+  / top-k / penalties / logprobs), which keeps the compiled program
+  sampling-agnostic. A greedy row with nothing else for the host to do is
+  its logits' argmax: the decode program takes it too, and takes the ids of
+  the tick before as its input tokens without their leaving the chip.
+- So the loop runs one tick ahead of the host wherever every token the next
+  tick needs is known, on the host or on the chip (``_step_locked``): it
+  dispatches tick k+1, then reads tick k's ids. A row whose token the host
+  has to choose from its logits holds the loop to today's order for the
+  ticks it is in. An answer that ends on EOS or a stop token is seen one
+  tick late; the row the tick in flight computed for it is thrown away
+  (``overrun_rows``).
 
 What the loop thread does is in the profiler's own trace, on the device's
-timeline: ``engine.tick`` (children ``.pack``, ``.dispatch``, ``.fetch``,
-``.sample``), ``engine.admit`` (children ``engine.prefill.dispatch``,
-``.fetch``, ``.sample``, ``engine.insert``), ``engine.finish`` and
-``engine.idle`` are ``jax.profiler.TraceAnnotation`` spans, inert unless a
-capture runs (``rt profile --xla``). Their arguments are the counters of
-that boundary, and ``stats`` sums the same quantities with no capture:
-``cache_positions`` of ``engine.tick`` is how much of the KV cache the tick
-needed (the active slots' lengths and the columns it writes). One span is
-the loading thread's: ``engine.weights``, once a replica, around the weights'
-arrival as the engine holds them.
+timeline: ``engine.tick`` (children ``.pack``, ``.dispatch``, then ``.read``
+of the tick before and its ``.sample``, or, with a host row, the tick's own
+``.fetch`` and ``.sample``), ``engine.admit`` (children
+``engine.prefill.dispatch``, ``.fetch``, ``.sample``, ``engine.insert``),
+``engine.finish`` and ``engine.idle`` are ``jax.profiler.TraceAnnotation``
+spans, inert unless a capture runs (``rt profile --xla``). Their arguments
+are the counters of that boundary, and ``stats`` sums the same quantities
+with no capture: ``cache_positions`` of ``engine.tick`` is how much of the
+KV cache the tick needed (the active slots' lengths and the columns it
+writes). ``tick``, ``active``, ``ahead``, ``overrun``, ``cache_positions``
+and ``moe_rows`` of an ``engine.tick`` span are those of the program
+dispatched in it; ``experts_touched`` is the count of the programs read
+since the tick span before: the tick before's (each ``.read`` and ``.fetch``
+names its program by ``tick`` and carries its count) and, with a host row,
+the span's own. One span is the loading thread's: ``engine.weights``, once
+a replica, around the weights' arrival as the engine holds them.
 
 A model with routed experts (``parallel/moe.py``) is told which rows of a
 program carry a token (the slots that decode, a prompt's own positions in
 its prefill bucket), so that nothing else is routed, and its programs hand
-back, beside the logits and in the same fetch, how many distinct experts
+back, beside the logits and in the same read, how many distinct experts
 received a row in each layer: ``experts_touched`` and ``moe_rows`` of
 ``engine.tick`` (with ``moe_layers``, to divide by) and ``engine.admit``,
 ``moe_experts_touched`` and ``moe_rows`` of ``stats``. A dense model's
@@ -128,6 +144,23 @@ class _Pending:
     submitted: float
 
 
+@dataclass
+class _Tick:
+    """A decode program that was dispatched and not read yet."""
+
+    number: int
+    rows: List[int]  # the slots that decode in it
+    drafts: Dict[int, list]  # speculation: slot -> the tokens it verifies
+    # some row's token is the host's to choose, from the row's logits
+    host_rows: bool
+    # its results, on the chip: each row's argmax [B] (None from
+    # ``decode_all``), the logits, and the experts touched a layer as a
+    # list of one or none
+    ids: Any
+    logits: Any
+    touched: list
+
+
 def _weights_facts(given, held) -> dict:
     """What ``engine.weights`` records of the weights a family's
     ``serving_params`` was ``given`` and of those it handed back: their
@@ -152,10 +185,12 @@ def engine_programs(cfg):
     the weights as the engine holds them (``DecodeEngine``). The cache is
     the model module's pytree with the slot on axis 1; ``insert`` and both
     decodes take it donated and give it back in the same buffer. For a
-    model with routed experts the three model programs take one more
-    argument, ``real`` [B] (how many of a row's tokens are tokens), and
-    give one more result after the cache, the experts touched a layer [L]."""
+    model with routed experts the three model programs are told how many
+    of a row's tokens are tokens (``real`` [B]: one more argument, or one
+    more row of ``decode``'s ``packed``), and give one more result after
+    the cache, the experts touched a layer [L]."""
     import jax
+    import jax.numpy as jnp
 
     from ray_tpu.models.decoder import forward_cached
 
@@ -172,10 +207,19 @@ def engine_programs(cfg):
             batch_cache, slot_cache,
         )
 
-    def decode(params, tokens, cache, lens, *real):
+    def decode(params, before, cache, packed):
+        # ``packed`` [2 | 3, B] int32 is all the host sends a tick: each
+        # slot's token, its length and, for routed experts, ``real``. A
+        # token below 0 is not the host's to give: it is the slot's own of
+        # ``before`` [B], the ids the tick before chose, still on the chip.
+        given, lens, *real = packed
+        tokens = jnp.where(given < 0, before, given)
         logits, *rest = forward_cached(
-            params, tokens, cache, lens, cfg, *real)
-        return (logits[:, -1], *rest)
+            params, tokens[:, None], cache, lens, cfg, *real)
+        logits = logits[:, -1]
+        # a greedy row's next token (the first index on a tie, as numpy's)
+        ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return (ids, logits, *rest)
 
     def decode_all(params, tokens, cache, lens, *real):
         # speculation verify: logits at EVERY position (position j's
@@ -274,6 +318,13 @@ class DecodeEngine:
             engine_programs(cfg))
         self._decode_spec = decode_all if self._spec_k > 0 else None
         self._empty_slot_cache = lambda: decoder.init_kv_cache(cfg, 1, S)
+        # the ids the last ``decode`` chose, on the chip, and that program
+        # while the host has not read it (the loop is then one tick ahead)
+        self._ids = jnp.zeros((B,), jnp.int32)
+        self._flying: Optional[_Tick] = None
+        # experts touched that a read has counted and no ``engine.tick``
+        # span carries yet: the next one does
+        self._touched_unspanned = 0
 
         self._slots = [_Slot() for _ in range(B)]
         self._pending: "queue.Queue" = queue.Queue()
@@ -291,6 +342,10 @@ class DecodeEngine:
         # the difference of every key between two moments
         self.stats = {
             "requests": 0, "tokens_generated": 0, "ticks": 0,
+            # ticks dispatched while the tick before was unread, and rows
+            # computed for a slot whose answer had ended in that tick:
+            # slot_ticks == tokens_generated + overrun_rows (no speculation)
+            "ticks_ahead": 0, "overrun_rows": 0,
             "prefix_hits": 0, "prefix_partial_hits": 0,
             "spec_proposed": 0, "spec_accepted": 0,
             # sums at the boundaries the spans mark: submit to admission,
@@ -447,20 +502,20 @@ class DecodeEngine:
 
         return (jnp.asarray(counts, jnp.int32),) if self._moe_layers else ()
 
-    def _fetch(self, logits, touched, real_rows: int):
-        """One fetch for a program's logits and, from a model with routed
-        experts, its experts touched a layer -> (logits on the host, the
-        span's ``moe_rows`` and ``experts_touched``), summed into ``stats``."""
-        import jax
+    def _moe_rows(self, real_rows: int) -> int:
+        """A span's ``moe_rows``: what a program of ``real_rows`` rows that
+        carry a token routes, summed into ``stats``."""
+        rows = real_rows * self._moe_top_k * self._moe_layers
+        self.stats["moe_rows"] += rows
+        return rows
 
-        logits, *touched = jax.device_get((logits, *touched))
-        moe = {
-            "moe_rows": real_rows * self._moe_top_k * self._moe_layers,
-            "experts_touched": int(touched[0].sum()) if touched else 0,
-        }
-        self.stats["moe_rows"] += moe["moe_rows"]
-        self.stats["moe_experts_touched"] += moe["experts_touched"]
-        return logits, moe
+    def _experts_touched(self, touched) -> int:
+        """A span's ``experts_touched`` of a program's count a layer, on the
+        host (a list of one, or of none from a dense model), summed into
+        ``stats``."""
+        experts = int(touched[0].sum()) if touched else 0
+        self.stats["moe_experts_touched"] += experts
+        return experts
 
     def _prefill_locked(self, prompt_ids, params, rng=None):
         """(slot_cache jax pytree, first_token, first_logprob, how). Caller
@@ -469,6 +524,7 @@ class DecodeEngine:
         ``experts_touched`` (what its program routed).
         Consults the prefix cache: an exact hit skips the model entirely; a
         strict-prefix hit prefills only the tail from the cached KV state."""
+        import jax
         import jax.numpy as jnp
 
         span = self._span
@@ -512,8 +568,10 @@ class DecodeEngine:
         with span("engine.prefill.fetch"):
             # the wait for the program and its [1, bucket, V] logits' way
             # to the host
-            logits_np, moe = self._fetch(logits, touched, len(rem))
+            logits_np, touched = jax.device_get((logits, touched))
             logits_np = logits_np[0]
+            moe = {"moe_rows": self._moe_rows(len(rem)),
+                   "experts_touched": self._experts_touched(touched)}
         self._prefix_store_locked(prompt_ids, cache1, logits_np, base)
         with span("engine.prefill.sample"):
             first, lp = self._sample(
@@ -675,10 +733,47 @@ class DecodeEngine:
             slot.future = None
             self.stats["finished_" + reason] += 1
 
-    def _tick_locked(self) -> bool:
-        if self._spec_k:
-            return self._tick_spec_locked()
-        return self._tick_plain_locked()
+    # ------------------------------------------------------- the tick loop
+
+    def _chip_row(self, p: SamplingParams) -> bool:
+        """Whether a request's every next token is its logits' argmax and
+        nothing else, so that the decode program's ``ids`` hold it and the
+        next tick can take it from there. Anything the host has to do with
+        a row's logits first (a draw, a penalty over the history,
+        ``logprobs``, speculation's drafts) makes it a host row."""
+        return (not self._spec_k and p.temperature <= 0 and p.logprobs <= 0
+                and p.repetition_penalty == 1.0
+                and not p.presence_penalty and not p.frequency_penalty)
+
+    def _ends_unread(self, slot: _Slot) -> bool:
+        """Whether the token a slot makes in the tick in flight is known to
+        be its last: the host counts ``produced`` and ``length`` itself."""
+        return (slot.produced + 1 >= slot.params.max_new_tokens
+                or slot.length + 2 >= self.config.max_seq_len)
+
+    def _step_locked(self) -> bool:
+        """One turn of the loop; False where there was nothing to do.
+        Admit, dispatch the next tick, then read the tick before it: the
+        loop is one tick ahead of the host wherever the tokens the next
+        tick needs are known without reading anything. They always are for
+        the slots of the tick in flight: a tick that holds a host row is
+        read in its own turn, and an admission reads first."""
+        if not (self._pending.empty() or all(s.active for s in self._slots)):
+            # no token waits behind a prefill, and a slot is filled only
+            # when every program that decodes it was read
+            self._read_locked(self._flying)
+            self._admit_locked()
+        flying = self._flying
+        rows = [i for i, s in enumerate(self._slots) if s.active and not (
+            flying is not None and i in flying.rows
+            and self._ends_unread(s))]
+        if rows:
+            self._tick_locked(rows)
+        elif flying is None:
+            return False
+        else:
+            self._read_locked(flying)
+        return True
 
     # ------------------------------------------- prompt-lookup speculation
 
@@ -703,43 +798,142 @@ class DecodeEngine:
                     return hist[i + n:i + n + k]
         return []
 
-    def _tick_spec_locked(self) -> bool:
-        """Speculative tick: verify up to k drafted tokens per GREEDY slot
-        in ONE dispatch (accepted prefix + one corrected token all come
-        from the same logits). Stochastic slots ride along with draft
-        length 0. Cache safety: forward_cached writes K/V before
-        attending and masks keys beyond each query position, and later
-        writes overwrite rejected-draft positions — stale KV can never
-        be attended."""
-        active = [i for i, s in enumerate(self._slots) if s.active]
-        if not active:
-            return False
-        K = self._spec_k
-        S = self.config.max_seq_len
-        if any(self._slots[i].length + 1 + K > S for i in active):
-            # near the sequence end the [B, 1+K] write would reach past the
-            # end of the cache — plain ticks finish the tail
-            return self._tick_plain_locked()
+    def _drafts_locked(self, rows) -> Dict[int, list]:
+        """Speculation: up to k drafted tokens per GREEDY slot, verified in
+        ONE dispatch (accepted prefix + one corrected token all come from
+        the same logits). Stochastic slots ride along with draft length 0.
+        Cache safety: forward_cached writes K/V before attending and masks
+        keys beyond each query position, and later writes overwrite
+        rejected-draft positions — stale KV can never be attended. No
+        draft, a plain tick: the (1+K)-wide dispatch would pay ~K x
+        attention/logits cost for zero benefit, and near the sequence end
+        its [B, 1+K] write would reach past the end of the cache."""
+        K, S = self._spec_k, self.config.max_seq_len
         drafts: Dict[int, list] = {}
-        for i in active:
+        if any(self._slots[i].length + 1 + K > S for i in rows):
+            return drafts
+        for i in rows:
             slot = self._slots[i]
             if slot.params.temperature <= 0:
                 d = self._propose_draft(slot, K)
                 if d:
                     drafts[i] = d
                     self.stats["spec_proposed"] += len(d)
-        if not drafts:
-            # nothing to verify: the (1+K)-wide dispatch would pay ~K x
-            # attention/logits cost for zero benefit
-            return self._tick_plain_locked()
+        return drafts
 
-        def sample(logits):
-            for i in active:
+    # ------------------------------------------------------------- one tick
+
+    def _tick_locked(self, rows) -> None:
+        """One decode program over every slot, between its spans: pack
+        what the host knows of ``rows`` (a slot's last token, or "the
+        chip's" where the tick in flight makes it; its length; its draft),
+        dispatch, then read the tick in flight, whose results the program
+        just dispatched no longer waits for. A tick with a host row is
+        read in its own span: its tokens are the next tick's input."""
+        import jax.numpy as jnp
+
+        span = self._span
+        flying = self._flying
+        drafts = self._drafts_locked(rows) if self._spec_k else {}
+        compiles = self.stats["compiles"]
+        with span("engine.tick", tick=self.stats["ticks"], active=len(rows),
+                  moe_layers=self._moe_layers,
+                  ahead=int(flying is not None)) as tick:
+            with span("engine.tick.pack"):
+                B = len(self._slots)
+                toks = np.zeros((B, 1 + self._spec_k if drafts else 1),
+                                np.int32)
+                lens = np.zeros((B,), np.int32)
+                real = np.zeros((B,), np.int32)
+                for i in rows:
+                    slot = self._slots[i]
+                    unread = flying is not None and i in flying.rows
+                    toks[i, :] = -1 if unread else slot.last_token
+                    lens[i] = slot.length + unread
+                    d = drafts.get(i, ())
+                    toks[i, 1:1 + len(d)] = d
+                    real[i] = 1 + len(d)
+                cache_positions = int(lens.sum() + real.sum())
+                if drafts:
+                    sent = (jnp.asarray(toks), jnp.asarray(lens),
+                            *self._real(real))
+                else:
+                    sent = jnp.asarray(np.stack(
+                        [toks[:, 0], lens, real][:3 if self._moe_layers
+                                                 else 2]))
+            with span("engine.tick.dispatch"):
+                if drafts:
+                    ids = None
+                    logits, self._cache, *touched = self._decode_spec(
+                        self.params, sent[0], self._cache, *sent[1:])
+                else:
+                    ids, logits, self._cache, *touched = self._decode(
+                        self.params, self._ids, self._cache, sent)
+                    self._ids = ids
+                    for small in (ids, *touched):
+                        small.copy_to_host_async()
+            self._flying = now = _Tick(
+                self.stats["ticks"], rows, drafts,
+                any(not self._chip_row(self._slots[i].params) for i in rows),
+                ids, logits, touched)
+            self.stats["ticks"] += 1
+            self.stats["ticks_ahead"] += flying is not None
+            self.stats["slot_ticks"] += len(rows)
+            self.stats["cache_positions"] += cache_positions
+            self.stats["compiles"] = compile_count()
+            moe_rows = self._moe_rows(int(real.sum()))
+            self._read_locked(flying)
+            # a row of this program whose answer ended in that read
+            overrun = sum(not self._slots[i].active for i in rows)
+            self.stats["overrun_rows"] += overrun
+            if now.host_rows:
+                self._read_locked(now)
+            # ``experts_touched``: of the programs read since the tick span
+            # before, that is the tick before's and, with a host row, this
+            # one's own
+            tick.set_metadata(
+                compiled=int(self.stats["compiles"] > compiles),
+                cache_positions=cache_positions, overrun=overrun,
+                moe_rows=moe_rows, experts_touched=self._touched_unspanned)
+            self._touched_unspanned = 0
+
+    def _read_locked(self, tick: Optional[_Tick]) -> None:
+        """Bring ``tick``'s results to the host and do its rows' bookkeeping
+        (None: nothing is in flight). The wait for the program, then its
+        ids' way to the host (``engine.tick.read``: 40-64 bytes whose copy
+        began at the dispatch) or, where a row's token is the host's to
+        choose, its logits' (``engine.tick.fetch``)."""
+        import jax
+
+        if tick is None:
+            return
+        if self._flying is tick:
+            self._flying = None
+        with self._span("engine.tick.fetch" if tick.host_rows
+                        else "engine.tick.read", tick=tick.number) as read:
+            ids, logits, touched = jax.device_get((
+                tick.ids, tick.logits if tick.host_rows else None,
+                tick.touched))
+            experts = self._experts_touched(touched)
+            self._touched_unspanned += experts
+            read.set_metadata(experts_touched=experts)
+        with self._span("engine.tick.sample"):
+            for i in tick.rows:
                 slot = self._slots[i]
-                draft = drafts.get(i, [])
+                if not slot.active:
+                    # its answer ended in the tick before this one: the row
+                    # is thrown away (counted as ``overrun`` of this tick)
+                    continue
+                if self._chip_row(slot.params):
+                    self._emit_token_locked(i, int(ids[i]), None)
+                    continue
+                draft = tick.drafts.get(i, ())
+                # [1 + K, V]: position j's row predicts the token after
+                # input j
+                at = logits[i].reshape(-1, logits.shape[-1])
                 for j in range(len(draft) + 1):
                     nxt, lp = self._sample(
-                        logits[i, j], slot.params, slot.prompt_ids,
+                        at[j], slot.params, slot.prompt_ids,
                         slot.token_ids, slot.rng,
                     )
                     self._emit_token_locked(i, nxt, lp)
@@ -750,51 +944,8 @@ class DecodeEngine:
                             break  # mismatch: later logits had wrong context
                         self.stats["spec_accepted"] += 1
 
-        return self._run_tick_locked(
-            active, self._decode_spec, 1 + K, drafts, sample)
-
-    def _run_tick_locked(self, active, program, width, drafts, sample) -> bool:
-        """One decode program over every slot, between its spans: pack the
-        ``[B, width]`` tokens (each active slot's last token, then its
-        draft), dispatch, fetch the logits, ``sample(logits)``."""
-        import jax.numpy as jnp
-
-        span = self._span
-        compiles = self.stats["compiles"]
-        with span("engine.tick", tick=self.stats["ticks"],
-                  active=len(active), moe_layers=self._moe_layers) as tick:
-            with span("engine.tick.pack"):
-                toks = np.zeros((len(self._slots), width), np.int32)
-                lens = np.zeros((len(self._slots),), np.int32)
-                real = np.zeros((len(self._slots),), np.int32)
-                for i in active:
-                    slot = self._slots[i]
-                    toks[i, :] = slot.last_token
-                    lens[i] = slot.length
-                    d = drafts.get(i, ())
-                    toks[i, 1:1 + len(d)] = d
-                    real[i] = 1 + len(d)
-                cache_positions = int(lens.sum() + real.sum())
-                toks, lens = jnp.asarray(toks), jnp.asarray(lens)
-            with span("engine.tick.dispatch"):
-                logits, self._cache, *touched = program(
-                    self.params, toks, self._cache, lens, *self._real(real))
-            with span("engine.tick.fetch"):
-                # the wait for the device, then the logits' way to the host
-                logits, moe = self._fetch(logits, touched, int(real.sum()))
-            with span("engine.tick.sample"):
-                sample(logits)
-            self.stats["ticks"] += 1
-            self.stats["slot_ticks"] += len(active)
-            self.stats["cache_positions"] += cache_positions
-            self.stats["compiles"] = compile_count()
-            tick.set_metadata(
-                compiled=int(self.stats["compiles"] > compiles),
-                cache_positions=cache_positions, **moe)
-        return True
-
     def _emit_token_locked(self, i: int, nxt: int, lp) -> None:
-        """Shared per-token bookkeeping for plain and speculative ticks."""
+        """Per-token bookkeeping, whoever chose the token."""
         slot = self._slots[i]
         slot.token_ids.append(nxt)
         if lp is not None:
@@ -806,22 +957,6 @@ class DecodeEngine:
         slot.length += 1
         self.stats["tokens_generated"] += 1
         self._finish_if_done_locked(i)
-
-    def _tick_plain_locked(self) -> bool:
-        active = [i for i, s in enumerate(self._slots) if s.active]
-        if not active:
-            return False
-
-        def sample(logits):
-            for i in active:
-                slot = self._slots[i]
-                nxt, lp = self._sample(
-                    logits[i], slot.params, slot.prompt_ids, slot.token_ids,
-                    slot.rng,
-                )
-                self._emit_token_locked(i, nxt, lp)
-
-        return self._run_tick_locked(active, self._decode, 1, {}, sample)
 
     # ------------------------------------------------------------- public
 
@@ -949,12 +1084,12 @@ class DecodeEngine:
         while not self._stopped:
             try:
                 with self._lock:
-                    self._admit_locked()
-                    busy = self._tick_locked()
+                    busy = self._step_locked()
             except Exception as e:
                 # Never die holding unresolved futures: fail every in-flight
                 # request, clear the slots, keep serving.
                 with self._lock:
+                    self._flying = None
                     for slot in self._slots:
                         if slot.active and slot.future is not None:
                             slot.future.set_exception(e)

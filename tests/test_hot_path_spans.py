@@ -379,6 +379,131 @@ def test_without_a_capture_no_trace_and_the_same_tokens(recording):
                 if "trac" in t.name.lower() or "span" in t.name.lower()]
 
 
+# ------------------------------------------------ the loop one tick ahead
+# A recording of its own beside the sampled one: greedy requests are chip
+# rows, and the loop dispatches tick k+1 before it has read tick k.
+
+
+@pytest.fixture(scope="module")
+def greedy_recording(recording, tmp_path_factory):
+    """One greedy request alone, then five at once on two slots (one ends
+    on a stop token, one is streamed), under a capture of their own, taken
+    after the sampled recording's was closed."""
+    import jax
+
+    from benchmarks.lib import host_spans
+    from ray_tpu.models import module_for
+
+    config = LLMConfig(**_MODEL)
+    cfg = config.model_config()
+    # the init's times 8: at the init's own scale the toy's greedy answer
+    # is one token over and over, and a stop has to be a token that comes late
+    engine = DecodeEngine(config, params=jax.tree.map(
+        lambda a: a * 8 if a.ndim >= 2 else a,
+        module_for(cfg).init_params(cfg, jax.random.PRNGKey(0))))
+    prompts = [[3 + i, 9, 40 + i, 7] for i in range(5)]
+    probe = list(engine.generate(prompts[1], SamplingParams(
+        max_new_tokens=8)))
+    stop = probe[4]
+    assert stop not in probe[:4]
+    logdir = str(tmp_path_factory.mktemp("spans_greedy"))
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    before = dict(engine.stats)
+    jax.profiler.start_trace(logdir, profiler_options=options)
+    try:
+        alone = list(engine.generate(
+            [5, 6, 7, 8, 9, 10, 11], SamplingParams(max_new_tokens=9)))
+        first = {k: engine.stats[k] - before[k] for k in before}
+        # long: its slot is not the one that frees when the stop is seen
+        streamed = engine.submit_stream(
+            prompts[0], SamplingParams(max_new_tokens=14))
+        futures = [engine.submit(p, SamplingParams(
+            max_new_tokens=n, stop_token_ids=s))
+            for p, n, s in zip(prompts[1:], (12, 10, 3, 6),
+                               ((stop,), (), (), ()))]
+        answers = [list(streamed)] + [list(f.result(120)) for f in futures]
+    finally:
+        jax.profiler.stop_trace()
+    stats = {k: engine.stats[k] - before[k] for k in before}
+    engine.shutdown()
+    spans = host_spans.load(logdir)
+    line = max(spans.lines,
+               key=lambda l: sum(s.name == "engine.tick" for s in l))
+    return {"spans": spans, "line": line, "stats": stats, "first": first,
+            "alone": alone, "answers": answers, "probe": probe}
+
+
+def test_ahead_counters_equal_the_sums_of_the_spans_arguments(
+        greedy_recording):
+    spans, stats = greedy_recording["spans"], greedy_recording["stats"]
+    ticks = spans.named("engine.tick")
+    assert stats["ticks"] == len(ticks) > 0
+    assert stats["slot_ticks"] == sum(t.args["active"] for t in ticks)
+    assert stats["ticks_ahead"] == sum(t.args["ahead"] for t in ticks)
+    assert stats["overrun_rows"] == sum(t.args["overrun"] for t in ticks)
+    assert stats["slot_ticks"] == stats["tokens_generated"] + stats[
+        "overrun_rows"]
+    assert stats["cache_positions"] == sum(
+        t.args["cache_positions"] for t in ticks)
+    # six requests' ticks, and only the first after the engine was idle or
+    # had admitted did not run ahead
+    assert stats["requests"] == 6
+    assert stats["ticks_ahead"] >= stats["ticks"] - 6 - 1
+    # the answer that ended on its stop token, seen one tick late while
+    # three requests waited for a slot (so no admission read first)
+    assert greedy_recording["answers"][1] == greedy_recording["probe"][:4]
+    assert stats["finished_stop"] == 1 and stats["overrun_rows"] == 1
+    assert all(t.args["compiled"] == 0 for t in ticks)
+
+
+def test_a_tick_span_holds_its_own_dispatch_and_the_read_of_the_tick_before(
+        greedy_recording):
+    from benchmarks.lib import host_spans
+
+    line = greedy_recording["line"]
+    ticks = [s for s in line if s.name == "engine.tick"]
+    numbers = [t.args["tick"] for t in ticks]
+    assert numbers == list(range(numbers[0], numbers[0] + len(numbers)))
+    reads = [s for s in line if s.name == "engine.tick.read"]
+    # every tick was read once, by its number, in order
+    assert [r.args["tick"] for r in reads] == numbers
+    assert not [s for s in line if s.name == "engine.tick.fetch"]
+    for t in ticks:
+        kids = [s for s in host_spans.children(line, t)
+                if s.name.startswith("engine.tick.")]
+        names = [s.name for s in kids]
+        assert names == ["engine.tick.pack", "engine.tick.dispatch"] + [
+            "engine.tick.read", "engine.tick.sample"] * t.args["ahead"], t
+        for a, b in zip(kids, kids[1:]):
+            assert a.end_ns <= b.start_ns
+        if t.args["ahead"]:
+            # what the span read is the program before its own
+            assert kids[2].args["tick"] == t.args["tick"] - 1
+        assert t.args["experts_touched"] == t.args["moe_rows"] == 0
+    # a read with no dispatch around it: before an admission, or when no
+    # slot is left to decode
+    assert sum(t.args["ahead"] for t in ticks) < len(reads) == len(ticks)
+    assert len([s for s in line if s.name == "engine.tick.sample"]) == len(
+        reads)
+
+
+def test_a_tick_spans_counters_are_those_of_the_program_it_dispatched(
+        greedy_recording):
+    """One request alone, a prompt of 7 and 9 tokens: eight ticks, one slot
+    each, the j-th over 7 + j cached positions and the column it writes,
+    all known at the dispatch; all but the first ran ahead."""
+    first = greedy_recording["first"]
+    assert len(greedy_recording["alone"]) == 9
+    assert first["ticks"] == 8 and first["ticks_ahead"] == 7
+    ticks = greedy_recording["spans"].named("engine.tick")[:8]
+    assert [t.args["active"] for t in ticks] == [1] * 8
+    assert [t.args["ahead"] for t in ticks] == [0] + [1] * 7
+    assert [t.args["cache_positions"] for t in ticks] == [
+        7 + j + 1 for j in range(8)]
+    assert [t.args["overrun"] for t in ticks] == [0] * 8
+
+
 def test_unattributed_idle_arithmetic():
     """The reduction the ``trace.idle_unattributed_share`` readers share,
     on numbers small enough to do by hand."""

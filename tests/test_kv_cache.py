@@ -91,6 +91,21 @@ class _Model:
             self.tokens[b][lens[b]: lens[b] + width] for b in range(SLOTS)
         ]))
 
+    def tick(self, toks, cache, lens, before=None):
+        """``decode`` as the engine calls it: the host's ``[B, 1]`` tokens
+        and the lengths in one packed array, beside the ids of the tick
+        before (zeros: every token here is the host's) -> (logits, cache),
+        the program's ids held to its logits."""
+        if before is None:
+            before = jnp.zeros((SLOTS,), jnp.int32)
+        ids, logits, cache = self.decode(
+            self.params, before, cache,
+            jnp.stack([jnp.asarray(toks)[:, 0], jnp.asarray(lens)]))
+        assert ids.dtype == jnp.int32
+        np.testing.assert_array_equal(
+            np.asarray(ids), np.asarray(logits).argmax(-1))
+        return logits, cache
+
 
 @pytest.fixture(scope="module", params=list(CONFIGS))
 def model(request):
@@ -100,15 +115,28 @@ def model(request):
 def test_prefill_insert_then_ticks_at_different_lengths(model):
     cache, lens = model.batch_after_prefill()
     for _ in range(5):
-        logits, cache = model.decode(
-            model.params, model.step_tokens(lens, 1), cache,
-            jnp.asarray(lens))
+        logits, cache = model.tick(model.step_tokens(lens, 1), cache, lens)
         for b in range(SLOTS):
             np.testing.assert_allclose(
                 np.asarray(logits)[b], model.full[b][lens[b]],
                 rtol=1e-4, atol=1e-4,
             )
         lens = lens + 1
+
+
+def test_a_token_below_zero_is_the_tick_befores_own(model):
+    """The engine one tick ahead: where the host gives no token (-1), a
+    slot decodes the id the tick before chose for it, which never left the
+    chip; where it gives one, that one, whatever ``before`` holds."""
+    cache, lens = model.batch_after_prefill()
+    toks = np.asarray(model.step_tokens(lens, 1))
+    want, _ = model.tick(toks, jax.tree.map(jnp.copy, cache), lens)
+    mixed = toks.copy()
+    mixed[[0, 2], 0] = -1
+    before = toks[:, 0].copy()
+    before[1] = (before[1] + 7) % VOCAB  # slot 1's token is the host's
+    got, _ = model.tick(mixed, cache, lens, before=jnp.asarray(before))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_prefix_continuation(model):
@@ -123,8 +151,7 @@ def test_prefix_continuation(model):
     toks = np.zeros((SLOTS, 1), np.int32)
     toks[-1, 0] = model.tokens[b][11]
     lens = np.array([0] * (SLOTS - 1) + [11], np.int32)
-    logits, _ = model.decode(
-        model.params, jnp.asarray(toks), cache, jnp.asarray(lens))
+    logits, _ = model.tick(toks, cache, lens)
     np.testing.assert_allclose(
         np.asarray(logits)[-1], model.full[b][11], rtol=1e-4, atol=1e-4)
 
@@ -143,8 +170,7 @@ def test_decode_all_verifies_a_draft(model):
         )
     # a rejected draft's positions are written over by the next tick
     lens = lens + 1
-    logits, _ = model.decode(
-        model.params, model.step_tokens(lens, 1), cache, jnp.asarray(lens))
+    logits, _ = model.tick(model.step_tokens(lens, 1), cache, lens)
     for b in range(SLOTS):
         np.testing.assert_allclose(
             np.asarray(logits)[b], model.full[b][lens[b]],
@@ -156,10 +182,12 @@ def test_decode_all_verifies_a_draft(model):
 def test_a_tick_changes_only_the_positions_it_writes(model, width):
     cache, lens = model.batch_after_prefill()
     before = {k: np.asarray(v).copy() for k, v in cache.items()}
-    program = model.decode if width == 1 else model.decode_all
-    _, cache = program(
-        model.params, model.step_tokens(lens, width), cache,
-        jnp.asarray(lens))
+    if width == 1:
+        _, cache = model.tick(model.step_tokens(lens, 1), cache, lens)
+    else:
+        _, cache = model.decode_all(
+            model.params, model.step_tokens(lens, width), cache,
+            jnp.asarray(lens))
     written = np.zeros((SLOTS, S), bool)
     for b in range(SLOTS):
         written[b, lens[b]: lens[b] + width] = True
@@ -312,8 +340,9 @@ def test_prefill_then_kernel_steps_equal_the_full_forward(small_blocks,
         toks = np.zeros((3, 1), np.int32)
         for b, (_, t) in seqs.items():
             toks[b, 0] = t[lens[b]]
-        logits, cache = decode(params, jnp.asarray(toks), cache,
-                               jnp.asarray(lens))
+        _, logits, cache = decode(
+            params, jnp.zeros((3,), jnp.int32), cache,
+            jnp.asarray(np.stack([toks[:, 0], lens])))
         for b in seqs:
             np.testing.assert_allclose(
                 np.asarray(logits)[b], full[b][lens[b]],

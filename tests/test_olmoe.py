@@ -260,9 +260,9 @@ def _decode(engine, sequences, active, garbage=0):
     for b in active:
         toks[b, 0], lens[b], real[b] = (
             sequences[b][-1], len(sequences[b]) - 1, 1)
-    logits, engine._cache, touched = engine._decode(
-        engine.params, jnp.asarray(toks), engine._cache, jnp.asarray(lens),
-        *engine._real(real))
+    _, logits, engine._cache, touched = engine._decode(
+        engine.params, engine._ids, engine._cache,
+        jnp.asarray(np.stack([toks[:, 0], lens, real])))
     return np.asarray(logits), np.asarray(touched)
 
 
@@ -350,8 +350,11 @@ def served(tmp_path_factory):
     try:
         # a replica that takes bf16 weights while the capture runs
         bf16_engine = DecodeEngine(bf16, params=bf16_given)
-        futures = [engine.submit(p, SamplingParams(max_new_tokens=n))
-                   for p, n in zip(prompts, (6, 3, 9, 1, 5))]
+        # host rows (``logprobs``): each tick is read in its own span, so a
+        # span's count of touched experts is its own program's
+        futures = [engine.submit(p, SamplingParams(
+            max_new_tokens=n, logprobs=1))
+            for p, n in zip(prompts, (6, 3, 9, 1, 5))]
         answers = [list(f.result(timeout=120)) for f in futures]
     finally:
         jax.profiler.stop_trace()

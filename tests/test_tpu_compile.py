@@ -142,6 +142,16 @@ def _engine_program_args(one_chip, slots, width, cfg=None):
     return cfg, (params, tokens, cache, start, *real)
 
 
+def _decode_args(one_chip, args):
+    """``jit_decode``'s arguments from a model program's at width 1: the
+    ids of the tick before [slots], and the host's one packed array of
+    tokens, lengths and (routed experts) ``real`` in the tokens' place."""
+    params, _, cache, start, *real = args
+    packed = jax.ShapeDtypeStruct((2 + len(real), *start.shape), jnp.int32,
+                                  sharding=one_chip)
+    return params, start, cache, packed
+
+
 def _weight_converts(hlo_text, params):
     """Every ``convert`` in the optimized HLO (an instruction of its own or
     the root a fusion is named after) whose result has the dimensions of a
@@ -199,7 +209,8 @@ def test_decode_program_updates_the_cache_in_place(one_chip, cell,
     once at load, so the program converts none: GPT-2 XL's arguments are
     3.1 GB of weights and the cache, where a tick rounded 6.2 GB of float32
     and held the 3.0 GB result beside them (13.8 of 21.0 ms; my chip run,
-    PR 28)."""
+    PR 28). And it hands back ``ids`` [slots] int32, each row's argmax,
+    which the next tick reads on the chip."""
     from ray_tpu.llm.engine import engine_programs
     from ray_tpu.models import kv_cache
 
@@ -209,6 +220,7 @@ def test_decode_program_updates_the_cache_in_place(one_chip, cell,
                   "olmoe-1b-7b.serve-assist": (16, _olmoe_config())}[cell]
     cfg, args = _engine_program_args(one_chip, slots=slots, width=1, cfg=cfg)
     decode = engine_programs(cfg)[2]
+    args = _decode_args(one_chip, args)
     compiled = decode.lower(*args).compile()
     cache = args[2]
     cache_bytes = sum(a.size * a.dtype.itemsize for a in cache.values())
@@ -216,6 +228,8 @@ def test_decode_program_updates_the_cache_in_place(one_chip, cell,
     text = compiled.as_text()
     header = text.split("\n", 1)[0]  # HloModule jit_decode, ..._alias={...}
     assert "jit_decode" in header
+    # its first result: each row's argmax, for the tick after this one
+    assert f"->(s32[{slots}]" in header
     assert mem.alias_size_in_bytes == cache_bytes
     assert header.count("-alias)") == 2
     assert mem.temp_size_in_bytes < 0.5e9
@@ -288,6 +302,8 @@ def test_olmoe_programs_read_the_experts_where_they_lie(
     cfg, args = _engine_program_args(one_chip, slots, width, _olmoe_config())
     assert cfg.param_dtype == jnp.bfloat16 and cfg.moe.dropless
     fn = engine_programs(cfg)[0 if program == "prefill" else 2]
+    if program == "decode":
+        args = _decode_args(one_chip, args)
     compiled = fn.lower(*args).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
     param_bytes = sum(a.size * a.dtype.itemsize
