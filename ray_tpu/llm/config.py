@@ -21,7 +21,7 @@ class LLMConfig:
     # to a pickled {"family": ..., "config": config kwargs, "params": pytree}
     # bundle ("family" defaults to gpt2 for old bundles).
     model_source: Optional[str] = None
-    model_family: str = "gpt2"  # "gpt2" | "llama"
+    model_family: str = "gpt2"  # "gpt2" | "llama" | "afmoe"
     vocab_size: int = 512
     max_seq_len: int = 1024
     num_layers: int = 4
@@ -37,6 +37,14 @@ class LLMConfig:
     rope_theta: Optional[float] = None   # llama: rotary base
     rms_eps: Optional[float] = None      # llama: RMSNorm epsilon
     qk_norm: Optional[str] = None        # llama: "none" | "full" (OLMoE)
+    head_dim: Optional[int] = None       # afmoe: a head's size, stated
+    moe_mlp_dim: Optional[int] = None    # afmoe: one expert's width
+    num_dense_layers: Optional[int] = None    # afmoe: leading dense layers
+    num_shared_experts: Optional[int] = None  # afmoe: beside the routed
+    # afmoe: "sliding_attention" | "full_attention" a layer, and the window
+    layer_types: Optional[Any] = None
+    sliding_window: Optional[int] = None
+    mup_enabled: Optional[bool] = None   # afmoe: embedding x sqrt(embed_dim)
     # dtype the weights are made (fresh) or loaded (a bundle) in; None = the
     # family's (float32). What a replica HOLDS follows from it and ``dtype``:
     # the engine keeps each weight its family's forward rounds to ``dtype``
@@ -55,6 +63,13 @@ class LLMConfig:
     # Random weights served in place of a trained model's state a larger
     # one (``MoEConfig.router_init_std`` says why).
     moe_router_init_std: Optional[float] = None
+    # The router's score ("softmax" | "sigmoid"), a factor on the k gates,
+    # and the deviation fresh weights draw ``expert_bias`` with (stated: the
+    # k are chosen under that bias, which the gates do not carry); None =
+    # ``MoEConfig``'s (softmax, 1, no bias)
+    moe_score_func: Optional[str] = None
+    moe_route_scale: Optional[float] = None
+    moe_expert_bias_init_std: Optional[float] = None
 
     # Engine knobs (reference: engine_kwargs tensor_parallel_size etc.)
     max_batch_slots: int = 8
@@ -96,7 +111,9 @@ class LLMConfig:
             attention_impl="xla",
         )
         for name in ("num_kv_heads", "mlp_dim", "rope_theta", "rms_eps",
-                     "qk_norm", "param_dtype"):
+                     "qk_norm", "param_dtype", "head_dim", "moe_mlp_dim",
+                     "num_dense_layers", "num_shared_experts", "layer_types",
+                     "sliding_window", "mup_enabled"):
             if getattr(self, name) is not None:
                 kwargs[name] = getattr(self, name)
         if self.moe_num_experts:
@@ -109,13 +126,22 @@ class LLMConfig:
                 # would disagree (and with the full forward).
                 dropless=True,
             )
-            if self.moe_router_init_std is not None:
-                kwargs["moe"]["router_init_std"] = self.moe_router_init_std
+            for stated, name in (
+                    (self.moe_router_init_std, "router_init_std"),
+                    (self.moe_score_func, "score_func"),
+                    (self.moe_route_scale, "route_scale"),
+                    (self.moe_expert_bias_init_std, "expert_bias_init_std")):
+                if stated is not None:
+                    kwargs["moe"][name] = stated
+            kwargs["moe"]["expert_bias"] = (
+                self.moe_expert_bias_init_std is not None)
         return config_for(self.model_family, **kwargs)
 
     def to_dict(self) -> dict:
         d = dict(self.__dict__)
         d["prefill_buckets"] = list(self.prefill_buckets)
+        if isinstance(self.layer_types, tuple):
+            d["layer_types"] = list(self.layer_types)
         return d
 
     @classmethod

@@ -10,6 +10,11 @@ split, KV cache management. TPU-first redesign instead of a port:
 - Prompts prefill at bucketed lengths (few compile variants) into a
   batch=1 cache, then a jitted insert writes the slot row — requests join
   and leave the running batch without recompiling (the "continuous" part).
+  A prompt longer than the largest bucket prefills in chunks of that
+  bucket, each continuing the cache of the one before (``start > 0``), all
+  under the lock: no tick runs between two chunks. The prefill's head runs
+  on the rows the host reads (the prompt's last, and the bucket boundaries
+  the prefix store keeps), not on the whole bucket.
 - Sampling happens host-side on the [B, V] logits of the tick (temperature
   / top-k / penalties / logprobs), which keeps the compiled program
   sampling-agnostic. A greedy row with nothing else for the host to do is
@@ -33,8 +38,12 @@ spans, inert unless a capture runs (``rt profile --xla``). Their arguments
 are the counters of that boundary, and ``stats`` sums the same quantities
 with no capture: ``cache_positions`` of ``engine.tick`` is how much of the
 KV cache the tick needed (the active slots' lengths and the columns it
-writes). ``tick``, ``active``, ``ahead``, ``overrun``, ``cache_positions``
-and ``moe_rows`` of an ``engine.tick`` span are those of the program
+writes) of a layer that attends everything, ``cache_positions_full`` the
+same where the model has such layers (``layers_full`` of them) and
+``cache_positions_window`` what it needed of a window layer (each slot's
+length or the window, whichever is less; ``layers_window``). ``tick``,
+``active``, ``ahead``, ``overrun``, the ``cache_positions`` and
+``moe_rows`` of an ``engine.tick`` span are those of the program
 dispatched in it; ``experts_touched`` is the count of the programs read
 since the tick span before: the tick before's (each ``.read`` and ``.fetch``
 names its program by ``tick`` and carries its count) and, with a host row,
@@ -194,10 +203,12 @@ def engine_programs(cfg):
 
     from ray_tpu.models.decoder import forward_cached
 
-    def prefill(params, tokens, cache1, start, *real):
-        # start > 0 = continuation from a cached prefix: only the
-        # prompt's tail runs through the model
-        return forward_cached(params, tokens, cache1, start, cfg, *real)
+    def prefill(params, tokens, cache1, start, *real, rows=None):
+        # start > 0 = continuation from a cached prefix or from the chunk
+        # before: only the prompt's tail runs through the model. ``rows``:
+        # the tokens whose logits the host reads (None: every one)
+        return forward_cached(
+            params, tokens, cache1, start, cfg, *real, rows=rows)
 
     def insert(batch_cache, slot_cache, b):
         return jax.tree.map(
@@ -276,10 +287,16 @@ class DecodeEngine:
                 self.model_config,
                 moe=dataclasses.replace(self.model_config.moe, dropless=True),
             )
-            self._moe_layers = self.model_config.num_layers
             self._moe_top_k = self.model_config.moe.top_k
         cfg = self.model_config
         model = module_for(cfg)
+        kinds = decoder.layer_kinds(cfg)
+        if self._moe_top_k:
+            # the layers with routed experts (a model may lead with dense)
+            self._moe_layers = sum(k.routed for k in kinds)
+        # window layers: how many, and their window (0: none)
+        self._layers_window = sum(k.window is not None for k in kinds)
+        self._window = max((k.window or 0 for k in kinds), default=0)
         self.tokenizer = load_tokenizer(config)
         self._span = jax.profiler.TraceAnnotation
         # The weights as the family's cached forward wants them of a caller
@@ -308,16 +325,24 @@ class DecodeEngine:
         with self._span("engine.weights", **weights):
             self.params = jax.block_until_ready(params)
         B, S = config.max_batch_slots, config.max_seq_len
-        self._cache = decoder.init_kv_cache(cfg, B, S)
-        self._rng = np.random.RandomState(seed)
-
         self._spec_k = max(
             0, int(getattr(config, "speculative_ngram_k", 0) or 0)
         )  # negatives = disabled, never a half-armed dispatch path
+        # the longest block of tokens one program writes: a window layer's
+        # ring has room for it beside the window (``models/kv_cache.py``)
+        block = max(*config.prefill_buckets, 1 + self._spec_k)
+        self._cache = decoder.init_kv_cache(cfg, B, S, block=block)
+        self._layers_full = len(kinds) - self._layers_window
+        # the lengths short of a whole prompt that the prefix store keeps
+        self._boundaries = tuple(config.prefill_buckets) if (
+            config.prefix_cache_size > 0 and not self._layers_window) else ()
+        self._rng = np.random.RandomState(seed)
+
         self._prefill, self._insert, self._decode, decode_all = (
             engine_programs(cfg))
         self._decode_spec = decode_all if self._spec_k > 0 else None
-        self._empty_slot_cache = lambda: decoder.init_kv_cache(cfg, 1, S)
+        self._empty_slot_cache = lambda: decoder.init_kv_cache(
+            cfg, 1, S, block=block)
         # the ids the last ``decode`` chose, on the chip, and that program
         # while the host has not read it (the loop is then one tick ahead)
         self._ids = jnp.zeros((B,), jnp.int32)
@@ -354,6 +379,9 @@ class DecodeEngine:
             # positions of the cache the ticks needed: the active slots'
             # lengths, each with the columns its tick writes
             "cache_positions": 0,
+            # the same of a model's full layers, and of its window layers
+            # (a slot's length or the window, whichever is less), a layer
+            "cache_positions_full": 0, "cache_positions_window": 0,
             "finished_length": 0, "finished_eos": 0, "finished_stop": 0,
             "finished_context": 0,
             # routed (token, expert, layer) rows (real rows x k x layers) and
@@ -468,29 +496,25 @@ class DecodeEngine:
             self._prefix_cache.move_to_end(best_key)
         return best, best_len
 
-    def _prefix_store_locked(self, prompt_ids, cache1, logits_np, base):
-        """Store the full prompt AND its bucket-boundary prefixes (system
-        prompts shared by many requests match through these). All entries
-        alias the same immutable cache pytree; ``logits_np`` rows cover
-        absolute positions base..base+rows-1."""
+    def _prefix_lengths(self, n: int, base: int) -> set:
+        """The lengths of a prompt of ``n`` tokens, prefilled from ``base``
+        on, whose last row's logits the host reads: the prompt's own, and
+        the bucket boundaries the prefix store keeps (system prompts shared
+        by many requests match through these). A model with window layers
+        keeps whole prompts only: a ring that went on past a boundary is
+        not that prefix's cache."""
+        return {n} | {b for b in self._boundaries if base < b < n}
+
+    def _prefix_store_locked(self, prompt_ids, cache1, logits_rows):
+        """Store the prefixes of ``logits_rows`` (length -> the logits after
+        its last token). All entries alias the same immutable cache
+        pytree."""
         cap = self.config.prefix_cache_size
         if cap <= 0:
             return
-        n = len(prompt_ids)
-        lengths = {n}
-        for b in self.config.prefill_buckets:
-            if base < b < n:
-                lengths.add(b)
-        for ln in lengths:
-            row_idx = ln - base - 1
-            if not (0 <= row_idx < logits_np.shape[0]):
-                continue
+        for ln, row in logits_rows.items():
             key = tuple(prompt_ids[:ln])
-            self._prefix_cache[key] = {
-                "cache": cache1,
-                # copy: a view would pin the whole [Tpad, vocab] buffer
-                "logits_row": logits_np[row_idx].copy(),
-            }
+            self._prefix_cache[key] = {"cache": cache1, "logits_row": row}
             self._prefix_cache.move_to_end(key)
         while len(self._prefix_cache) > cap:
             self._prefix_cache.popitem(last=False)
@@ -520,8 +544,9 @@ class DecodeEngine:
     def _prefill_locked(self, prompt_ids, params, rng=None):
         """(slot_cache jax pytree, first_token, first_logprob, how). Caller
         holds the lock. ``how`` is the admission span's ``bucket`` (0: no
-        program ran), ``prefix`` (none | partial | exact), ``moe_rows`` and
-        ``experts_touched`` (what its program routed).
+        program ran; the last chunk's), ``chunks`` (programs run), ``prefix``
+        (none | partial | exact), ``moe_rows`` and ``experts_touched`` (what
+        its programs routed).
         Consults the prefix cache: an exact hit skips the model entirely; a
         strict-prefix hit prefills only the tail from the cached KV state."""
         import jax
@@ -529,8 +554,12 @@ class DecodeEngine:
 
         span = self._span
         n = len(prompt_ids)
-        self._bucket(n)  # uniform length limit: acceptance must not depend
-        # on transient prefix-cache residency
+        # uniform length limit: acceptance must not depend on transient
+        # prefix-cache residency
+        if n >= self.config.max_seq_len:
+            raise ValueError(
+                f"prompt length {n} leaves no room for an answer in "
+                f"max_seq_len {self.config.max_seq_len}")
         entry, matched = (
             self._prefix_lookup_locked(prompt_ids)
             if self.config.prefix_cache_size > 0
@@ -543,9 +572,10 @@ class DecodeEngine:
                     entry["logits_row"], params, prompt_ids, (), rng
                 )
             return entry["cache"], first, lp, {
-                "bucket": 0, "prefix": "exact", "moe_rows": 0,
+                "bucket": 0, "chunks": 0, "prefix": "exact", "moe_rows": 0,
                 "experts_touched": 0}
-        if entry is not None and (
+        largest = max(self.config.prefill_buckets)
+        if entry is not None and n - matched <= largest and (
             matched + self._bucket(n - matched) > self.config.max_seq_len
         ):
             # the padded tail would reach past the end of the cache — full
@@ -553,32 +583,50 @@ class DecodeEngine:
             entry, matched = None, 0
         if entry is not None:
             self.stats["prefix_partial_hits"] += 1
-        # base > 0 = continuation: only the prompt's tail runs
-        base, rem = matched, prompt_ids[matched:]
-        Tpad = self._bucket(len(rem))
+        # base > 0 = continuation: only the prompt's tail runs, in chunks
+        # of the largest bucket where it is longer than that
+        base = matched
+        wanted = sorted(self._prefix_lengths(n, base))
+        # rows of one program's logits: as many as one chunk can be asked
+        # for, a static shape (the unused ones repeat the chunk's last)
+        width = 1 + len(self._boundaries)
+        cache1 = (entry["cache"] if entry is not None
+                  else self._empty_slot_cache())
+        programs = []  # (lengths read of it, its logits, its touched)
         with span("engine.prefill.dispatch"):
-            toks = np.zeros((1, Tpad), np.int32)
-            toks[0, : len(rem)] = rem
-            logits, cache1, *touched = self._prefill(
-                self.params, jnp.asarray(toks),
-                entry["cache"] if entry is not None
-                else self._empty_slot_cache(),
-                jnp.full((1,), base, jnp.int32), *self._real([len(rem)]),
-            )
+            for at in range(base, n, largest):
+                piece = prompt_ids[at:at + largest]
+                Tpad = self._bucket(len(piece))
+                toks = np.zeros((1, Tpad), np.int32)
+                toks[0, : len(piece)] = piece
+                lengths = [ln for ln in wanted if at < ln <= at + len(piece)]
+                rows = np.full((width,), len(piece) - 1, np.int32)
+                rows[:len(lengths)] = [ln - at - 1 for ln in lengths]
+                logits, cache1, *touched = self._prefill(
+                    self.params, jnp.asarray(toks), cache1,
+                    jnp.full((1,), at, jnp.int32),
+                    *self._real([len(piece)]), rows=jnp.asarray(rows),
+                )
+                programs.append((lengths, logits, touched))
         with span("engine.prefill.fetch"):
-            # the wait for the program and its [1, bucket, V] logits' way
+            # the wait for the programs and their [1, width, V] logits' way
             # to the host
-            logits_np, touched = jax.device_get((logits, touched))
-            logits_np = logits_np[0]
-            moe = {"moe_rows": self._moe_rows(len(rem)),
-                   "experts_touched": self._experts_touched(touched)}
-        self._prefix_store_locked(prompt_ids, cache1, logits_np, base)
+            fetched = jax.device_get([p[1:] for p in programs])
+            logits_rows = {
+                ln: logits[0, i]
+                for (lengths, *_), (logits, _) in zip(programs, fetched)
+                for i, ln in enumerate(lengths)}
+            moe = {"moe_rows": self._moe_rows(n - base),
+                   "experts_touched": sum(
+                       self._experts_touched(t) for _, t in fetched)}
+        self._prefix_store_locked(prompt_ids, cache1, logits_rows)
         with span("engine.prefill.sample"):
             first, lp = self._sample(
-                logits_np[len(rem) - 1], params, prompt_ids, (), rng
+                logits_rows[n], params, prompt_ids, (), rng
             )
         return cache1, first, lp, {
-            "bucket": Tpad, "prefix": "partial" if base else "none", **moe}
+            "bucket": Tpad, "chunks": len(programs),
+            "prefix": "partial" if base else "none", **moe}
 
     def _activate_slot_locked(self, b, cache1, first, req: _Pending,
                               prompt_len, prompt_ids=(), first_lp=None,
@@ -654,8 +702,8 @@ class DecodeEngine:
             first = int(prefilled["first_token"])
             prompt_ids = tuple(prefilled.get("prompt_ids", ()))
             first_lp = prefilled.get("first_logprob")
-            how = {"bucket": 0, "prefix": "none", "moe_rows": 0,
-                   "experts_touched": 0}
+            how = {"bucket": 0, "chunks": 0, "prefix": "none",
+                   "moe_rows": 0, "experts_touched": 0}
             if params.seed is not None:
                 rng = self._rng_for(params)
                 if params.temperature > 0:
@@ -853,7 +901,13 @@ class DecodeEngine:
                     d = drafts.get(i, ())
                     toks[i, 1:1 + len(d)] = d
                     real[i] = 1 + len(d)
-                cache_positions = int(lens.sum() + real.sum())
+                needed = lens + real     # a slot's length and its columns
+                cache_positions = int(needed.sum())
+                positions = {
+                    "cache_positions_full":
+                        cache_positions if self._layers_full else 0,
+                    "cache_positions_window": int(np.minimum(
+                        needed, self._window).sum())}
                 if drafts:
                     sent = (jnp.asarray(toks), jnp.asarray(lens),
                             *self._real(real))
@@ -880,6 +934,8 @@ class DecodeEngine:
             self.stats["ticks_ahead"] += flying is not None
             self.stats["slot_ticks"] += len(rows)
             self.stats["cache_positions"] += cache_positions
+            for name, count in positions.items():
+                self.stats[name] += count
             self.stats["compiles"] = compile_count()
             moe_rows = self._moe_rows(int(real.sum()))
             self._read_locked(flying)
@@ -894,7 +950,9 @@ class DecodeEngine:
             tick.set_metadata(
                 compiled=int(self.stats["compiles"] > compiles),
                 cache_positions=cache_positions, overrun=overrun,
-                moe_rows=moe_rows, experts_touched=self._touched_unspanned)
+                moe_rows=moe_rows, experts_touched=self._touched_unspanned,
+                layers_full=self._layers_full,
+                layers_window=self._layers_window, **positions)
             self._touched_unspanned = 0
 
     def _read_locked(self, tick: Optional[_Tick]) -> None:

@@ -6,8 +6,8 @@ stack (``forward_features``, ``forward``), the cached forward
 (``forward_pipelined``), ``loss_fn``, ``count_params``. A family's module
 (``FAMILIES``) holds the pieces that differ: its ``Config`` dataclass and
 ``PRESETS``, ``init_params`` and ``param_axes``, and the pieces the decoder
-calls (``embed``, ``qkv``, ``attn_out``, ``ffn``, ``final_norm``, ``head``,
-``head_weight``; ``decoder.py`` gives each one's signature) and the one a
+calls (``layers``, ``embed``, ``qkv``, ``attn_out``, ``ffn``, ``final_norm``,
+``head``, ``head_weight``; ``decoder.py`` gives each one's signature) and the one a
 server calls once (``serving_params``), and it hands the decoder's functions
 on under its own name, so ``module_for(cfg).loss_fn`` is the one
 definition. A new architecture is a family module, or a piece of one, and
@@ -15,9 +15,9 @@ one line of ``FAMILIES``.
 
 Train/LLM layers find a config's family via :func:`module_for`, and build a
 family's config from plain keyword arguments via :func:`config_for`.
-The KV cache is ``{"k", "v"}``, each ``[L, B, KV, D, S]`` (``kv_cache.py``):
-callers outside this package rely on the slot being axis 1 and on nothing
-else.
+The KV cache is ``{"k", "v"}``, each ``[L, B, KV, D, S]``, and for a model with
+window layers their rings beside it (``kv_cache.py``): callers outside this
+package rely on the slot being axis 1 of every leaf and on nothing else.
 """
 from __future__ import annotations
 
@@ -28,6 +28,7 @@ from typing import Any
 FAMILIES = {
     "gpt2": "ray_tpu.models.gpt2",
     "llama": "ray_tpu.models.llama",
+    "afmoe": "ray_tpu.models.afmoe",
 }
 
 

@@ -8,16 +8,21 @@ one signature across families:
 - ``embed(config, params, tokens, pos, cached)`` -> the residual stream
   [B, T, E] (positions added where the family learns them; its dtype in the
   full and in the cached forward is the family's);
-- ``qkv(config, layer, x, pos)`` -> q, and k and v [B, T, KV, D], from the
-  stream (the family's norm, RoPE and q/k norm inside). q is [B, T, H, D],
-  or [B, T, KV, G, D] where G query heads share a kv head;
+- ``layers(config, blocks, cached)`` -> (the stack as ``Segment``s, the
+  experts' weights of every routed layer or None): which kinds of layer
+  follow one another, and where each one's weights are in ``blocks``
+  (``Layer``, ``Segment`` below). ``blocks`` None asks for the kinds alone;
+- ``qkv(config, kind, layer, x, pos)`` -> q, and k and v [B, T, KV, D], from
+  the stream (the family's norm, RoPE and q/k norm inside; ``kind`` is the
+  ``Layer.name`` the family gave this layer). q is [B, T, H, D], or
+  [B, T, KV, G, D] where G query heads share a kv head;
 - ``attn_out(config, layer, x, attn)`` -> the stream after the output
   projection and the residual;
-- ``ffn(config, layer, x, rng, row_mask, stacked)`` -> (stream, aux loss,
-  experts that received a row: 0 for a dense feed-forward). Only a router
-  asks for the last three: ``rng`` its jitter, ``row_mask`` [B, T] the rows
-  that carry a token, ``stacked`` (every layer's expert weights, this
-  layer's index) where the caller kept them out of its layer scan;
+- ``ffn(config, kind, layer, x, rng, row_mask, stacked)`` -> (stream, aux
+  loss, experts that received a row: 0 for a dense feed-forward). Only a
+  router asks for the last three: ``rng`` its jitter, ``row_mask`` [B, T] the
+  rows that carry a token, ``stacked`` (every routed layer's expert weights,
+  this layer's index among them) where they are kept out of the layer scan;
 - ``final_norm(config, params, x)``, ``head(config, params, x)`` -> float32
   logits, ``head_weight(params)`` -> the [V, E] matrix the chunked
   cross-entropy multiplies by;
@@ -38,13 +43,20 @@ k and v repeated G times for the full forward, the cache is attended with q
 as it came (``kv_cache.attend`` takes either), and the output projection
 gets [B, T, H, D] from both.
 
-Layers are stacked into one scanned super-layer (``lax.scan`` over depth:
+Layers are stacked into scanned super-layers (``lax.scan`` over depth:
 O(1) compile time in depth, and the layout the "stage" mesh axis splits),
-with ``jax.checkpoint`` on the block body (remat trades FLOPs for HBM).
+with ``jax.checkpoint`` on the block body (remat trades FLOPs for HBM). A
+stack of one kind of layer (``gpt2``, ``llama``) is one scan over the layers.
+A stack of several kinds is a few ``Segment``s, each one period of kinds
+scanned as many times as it repeats (a leading dense layer, then periods of
+three window layers and a full one): every kind is compiled once, whatever
+the depth. What the skeleton must know of a kind is in ``Layer``: whether
+its attention is windowed (its cache is then a ring, ``kv_cache.py``) and
+whether its feed-forward reads the experts' stack.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,10 +66,54 @@ from ray_tpu.models import kv_cache, module_for
 from ray_tpu.ops.attention import attention
 from ray_tpu.parallel.moe import stacked_for
 
+
+
+class Layer(NamedTuple):
+    """One kind of layer, as far as the skeleton has to know it."""
+    name: Optional[str] = None    # the family's word for it: ``kind`` of
+    #                               its ``qkv`` and ``ffn``
+    # attention over the token itself and the ``window - 1`` before it (its
+    # cache a ring); None: over everything before it
+    window: Optional[int] = None
+    routed: bool = False          # its ``ffn`` reads the experts' stack
+
+
+class Segment(NamedTuple):
+    """``repeats`` periods of ``kinds``: ``params[j]`` holds the weights of
+    the period's j-th layer in every repeat, leaves ``[repeats, ...]`` (None
+    where only the kinds were asked for)."""
+    kinds: Tuple[Layer, ...]
+    params: tuple
+    repeats: int
+
+
+def single_kind(config, blocks, cached: bool):
+    """``layers`` of a family whose layers are all alike: one segment of
+    ``blocks`` as they are, leaves ``[num_layers, ...]``. Routed experts
+    served dropless leave the layer scan of the cached forward as one stack
+    (``forward_cached`` says why); everywhere else a layer's experts are its
+    slice of ``blocks["moe"]``."""
+    out = cached and config.moe is not None and config.moe.dropless
+    experts = None
+    if out and blocks is not None:
+        blocks = dict(blocks)
+        experts = blocks.pop("moe")
+    return ([Segment((Layer(routed=out),), (blocks,), config.num_layers)],
+            experts)
+
+
+def layer_kinds(config) -> Tuple[Layer, ...]:
+    """Every layer's kind, first to last."""
+    segments, _ = module_for(config).layers(config, None, cached=True)
+    return tuple(k for s in segments for _ in range(s.repeats)
+                 for k in s.kinds)
+
+
 # what a family module hands on under its own name (``gpt2.forward``,
 # ``module_for(cfg).loss_fn``): each the one definition below
 __all__ = ["forward_features", "forward", "init_kv_cache", "forward_cached",
-           "forward_pipelined", "loss_fn", "count_params"]
+           "forward_pipelined", "loss_fn", "count_params", "Layer", "Segment",
+           "single_kind"]
 
 
 def _remat_policy(config):
@@ -107,22 +163,39 @@ def _repeat_kv(x: jax.Array, n: int) -> jax.Array:
     ).reshape(B, T, KV * n, D)
 
 
-def _body(config, mesh: Optional[Mesh], pos):
-    """One decoder block as a layer scan calls it, remat applied:
-    ``(x [B, T, E], layer, rng=None) -> (x, aux)``. layer: one slice of the
-    stacked block params, pos: [B, T] absolute. ``rng`` (optional) feeds MoE
-    router jitter."""
+def _window_attention(q, k, v, window: int):
+    """Causal attention over the token itself and the ``window - 1`` before
+    it, [B, T, H, D] each: a band mask in plain XLA (the full forward of a
+    model with window layers is its tests' and its loss's)."""
+    i = jnp.arange(q.shape[1])
+    band = (i[None, :] <= i[:, None]) & (i[None, :] > i[:, None] - window)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
+    scores = scores / jnp.sqrt(jnp.float32(q.shape[-1]))
+    probs = jax.nn.softmax(jnp.where(band, scores, -1e30), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), v)
+
+
+def _body(config, mesh: Optional[Mesh], pos, kind: Layer = Layer()):
+    """One decoder block of ``kind`` as a layer scan calls it, remat applied:
+    ``(x [B, T, E], layer, rng=None, stacked=None) -> (x, aux)``. layer: one
+    slice of the stacked block params, pos: [B, T] absolute. ``rng``
+    (optional) feeds MoE router jitter; ``stacked`` is the experts' stack and
+    this layer's index in it, where the family keeps them out of the scan."""
     family = module_for(config)
 
-    def block(x, layer, rng=None):
-        q, k, v = family.qkv(config, layer, x, pos)
+    def block(x, layer, rng=None, stacked=None):
+        q, k, v = family.qkv(config, kind.name, layer, x, pos)
         if q.ndim == 5:  # [B, T, KV, G, D]: G query heads share a kv head
             k, v = _repeat_kv(k, q.shape[3]), _repeat_kv(v, q.shape[3])
             q = q.reshape(*q.shape[:2], -1, q.shape[-1])
-        attn = _attention_dispatch(config, q, k, v, mesh)
+        if kind.window is None:
+            attn = _attention_dispatch(config, q, k, v, mesh)
+        else:
+            attn = _window_attention(q, k, v, kind.window)
         x = family.attn_out(config, layer, x, attn)
         x, aux, _ = family.ffn(
-            config, layer, x, rng=rng, row_mask=None, stacked=None)
+            config, kind.name, layer, x, rng=rng, row_mask=None,
+            stacked=stacked)
         return x, aux
 
     if config.remat:
@@ -138,6 +211,43 @@ def _embed(params, tokens, config):
         config, params, tokens, pos, cached=False), pos
 
 
+def _nth(repeat, every: int, first: int):
+    """The index, in a stack of their own, of a layer that comes ``every``
+    times a period, ``first`` of them before it in the first: in period
+    ``repeat`` (a scan's counter, or a plain 0)."""
+    at = repeat if every == 1 else repeat * every
+    return at + first if first else at
+
+
+def _places(segments, stack_of):
+    """Where each layer's share of a stack that only some kinds of layer
+    have lies (a cache of its sort, the routed layers' experts):
+    ``stack_of(kind)`` names the stack or is None. For every segment, for
+    every place in its period, ``_nth``'s (every, first) or None: the layers
+    of that stack in a period, and those before this one in the segments
+    before and in its own period."""
+    before: Dict[Any, int] = {}
+    out = []
+    for kinds, _, repeats in segments:
+        names = [stack_of(kind) for kind in kinds]
+        out.append([
+            None if name is None else
+            (names.count(name), before.get(name, 0) + names[:j].count(name))
+            for j, name in enumerate(names)])
+        for name in set(names) - {None}:
+            before[name] = before.get(name, 0) + repeats * names.count(name)
+    return out
+
+
+def _scanned(period, carry, xs, repeats: int):
+    """``period(carry, one repeat of xs) -> (carry, ys)`` over ``repeats``;
+    a single one is called as it is, on the only slice there is."""
+    if repeats == 1:
+        carry, ys = period(carry, jax.tree.map(lambda a: a[0], xs))
+        return carry, jax.tree.map(lambda y: y[None], ys)
+    return jax.lax.scan(period, carry, xs)
+
+
 def forward_features(
     params: Dict[str, Any],
     tokens: jax.Array,
@@ -151,17 +261,36 @@ def forward_features(
     ``rng``: optional key enabling stochastic layers (MoE router jitter),
     one key a layer."""
     x, pos = _embed(params, tokens, config)
-    body = _body(config, mesh, pos)
-    xs = (params["blocks"],)
-    if rng is not None:
-        xs += (jax.random.split(rng, config.num_layers),)
+    segments, experts = module_for(config).layers(
+        config, params["blocks"], cached=False)
+    if experts is not None:  # a stack of their own: cast once, not a layer
+        experts = stacked_for(experts, config.dtype)
+    keys = None if rng is None else jax.random.split(rng, config.num_layers)
+    aux = jnp.float32(0.0)
+    routed_at = _places(
+        segments, lambda kind: "experts" if kind.routed and experts is not None
+        else None)
+    layers_before = 0
+    for (kinds, layers, repeats), routed in zip(segments, routed_at):
+        bodies = [_body(config, mesh, pos, kind) for kind in kinds]
+        xs = (layers,)
+        if keys is not None:
+            n = repeats * len(kinds)
+            xs += (keys[layers_before:layers_before + n].reshape(
+                repeats, len(kinds), *keys.shape[1:]),)
+            layers_before += n
 
-    def scan_fn(carry, xs):
-        x, aux = carry
-        x, layer_aux = body(x, *xs)
-        return (x, aux + layer_aux), None
+        def period(carry, xs, bodies=bodies, routed=routed):
+            x, aux, repeat = carry
+            layers, *rngs = xs
+            for j, body in enumerate(bodies):
+                stacked = routed[j] and (experts, _nth(repeat, *routed[j]))
+                x, layer_aux = body(
+                    x, layers[j], rngs[0][j] if rngs else None, stacked)
+                aux = aux + layer_aux
+            return (x, aux, repeat + 1), None
 
-    (x, aux), _ = jax.lax.scan(scan_fn, (x, jnp.float32(0.0)), xs)
+        (x, aux, _), _ = _scanned(period, (x, aux, 0), xs, repeats)
     return module_for(config).final_norm(config, params, x), aux
 
 
@@ -177,17 +306,26 @@ def forward(
     return module_for(config).head(config, params, x), aux
 
 
-def init_kv_cache(config, batch: int, max_len: int,
-                  dtype=None) -> Dict[str, jax.Array]:
+def init_kv_cache(config, batch: int, max_len: int, dtype=None,
+                  block: int = 1) -> Dict[str, jax.Array]:
     """Static-shape KV cache for incremental decoding: ``{"k", "v"}``, each
     [L, B, KV, D, S], position minor (``models/kv_cache.py`` says why) — kv
     heads only, an H/KV-fold HBM saving over caching query-expanded heads.
+    A model with window layers holds those layers' columns in rings of
+    their own beside it (``{"k_window", "v_window"}``), long enough for the
+    window and the longest ``block`` of tokens one call will write.
     (Reference capability analog: the vLLM engine Ray LLM delegates to —
     ``llm/_internal/serve/engines/vllm``; here the cache is a jax pytree so
     the whole decode step stays one XLA program.)"""
+    windows = [k.window for k in layer_kinds(config)]
+    lengths = {w for w in windows if w is not None}
+    if len(lengths) > 1:
+        raise ValueError(f"window layers of several lengths: {lengths}")
+    ring = kv_cache.ring_length(lengths.pop(), block, max_len) if lengths else 0
     return kv_cache.init_kv_cache(
-        config.num_layers, batch, config.num_kv_heads, config.head_dim,
-        max_len, dtype or config.dtype,
+        windows.count(None), batch, config.num_kv_heads, config.head_dim,
+        max_len, dtype or config.dtype, len(windows) - windows.count(None),
+        ring,
     )
 
 
@@ -198,6 +336,7 @@ def forward_cached(
     start: jax.Array,
     config,
     real: Optional[jax.Array] = None,
+    rows: Optional[jax.Array] = None,
 ) -> tuple:
     """Incremental forward: attend over the KV cache, append new K/V.
 
@@ -207,7 +346,9 @@ def forward_cached(
     static and every slot at its own offset, so slot-based continuous
     batching is one compiled program. The whole cache rides the layer scan
     as its carry and only the new tokens' columns change: a caller that
-    donates the cache gets it back in the same buffer.
+    donates the cache gets it back in the same buffer. ``rows`` [R] int32
+    names the tokens whose logits the caller will read (a prefill uses its
+    last one): the head then runs on those alone, logits [B, R, V].
 
     Two results for a caller that gives no ``real``, three for one that
     does, the one place where the arity follows an argument (a third result,
@@ -220,36 +361,67 @@ def forward_cached(
     received a row in each layer, [L] int32."""
     family = module_for(config)
     B, T = tokens.shape
-    S = cache["k"].shape[-1]
     pos = start[:, None] + jnp.arange(T)[None, :]          # [B, T] absolute
     x = family.embed(config, params, tokens, pos, cached=True)
 
-    at = kv_cache.step(start, T, S)
-    rows = None if real is None else jnp.arange(T)[None, :] < real[:, None]
+    segments, experts = family.layers(config, params["blocks"], cached=True)
+    windows = {k.window for s in segments for k in s.kinds} - {None}
+    at = kv_cache.step(start, T, cache, *windows)
+    mask = None if real is None else jnp.arange(T)[None, :] < real[:, None]
     # The experts' weights stay out of the scan: it would hand each layer
     # its slice, and a slice that feeds a kernel is a copy (``moe._experts``)
-    blocks = dict(params["blocks"])
-    dropless = config.moe is not None and config.moe.dropless
-    moe = stacked_for(blocks.pop("moe"), config.dtype) if dropless else None
+    if experts is not None:
+        experts = stacked_for(experts, config.dtype)
 
-    def block(carry, layer):
-        x, i, cache = carry
-        q, k_new, v_new = family.qkv(config, layer, x, pos)
+    def block(kind, x, layer, index, stacked, cache):
+        q, k_new, v_new = family.qkv(config, kind.name, layer, x, pos)
         # the cache is attended as the family groups its heads (GQA: the
         # query heads of a kv head together); the projection takes them flat
-        cache, attn = kv_cache.attend(cache, i, q, k_new, v_new, at)
+        cache, attn = kv_cache.attend(
+            cache, index, q, k_new, v_new, at, kind.window is not None)
         x = family.attn_out(
             config, layer, x, attn.reshape(B, T, -1, attn.shape[-1]))
         x, _, touched = family.ffn(
-            config, layer, x, rng=None, row_mask=rows,
-            stacked=(moe, i) if dropless else None)
-        return (x, i + 1, cache), None if real is None else touched
+            config, kind.name, layer, x, rng=None, row_mask=mask,
+            stacked=stacked)
+        return x, cache, touched
 
-    (x, _, cache), touched = jax.lax.scan(
-        block, (x, jnp.int32(0), cache), blocks
-    )
+    cache_at = _places(
+        segments, lambda kind: "ring" if kind.window is not None else "full")
+    routed_at = _places(
+        segments, lambda kind: "experts" if kind.routed and experts is not None
+        else None)
+    every_touched = []
+    for (kinds, layers, repeats), in_cache, routed in zip(
+            segments, cache_at, routed_at):
+
+        def period(carry, layers, kinds=kinds, in_cache=in_cache,
+                   routed=routed):
+            x, repeat, cache = carry
+            touched = []
+            for j, kind in enumerate(kinds):
+                x, cache, n = block(
+                    kind, x, layers[j], _nth(repeat, *in_cache[j]),
+                    routed[j] and (experts, _nth(repeat, *routed[j])), cache)
+                touched.append(n)
+            if real is None:
+                return (x, repeat + 1, cache), None
+            return (x, repeat + 1, cache), (
+                touched[0] if len(kinds) == 1 else jnp.stack(touched))
+
+        (x, _, cache), touched = _scanned(
+            period, (x, jnp.int32(0) if repeats > 1 else 0, cache), layers,
+            repeats)
+        every_touched.append(touched)
+    if rows is not None:
+        x = x[:, rows]
     logits = family.head(config, params, family.final_norm(config, params, x))
-    return (logits, cache) if real is None else (logits, cache, touched)
+    if real is None:
+        return logits, cache
+    touched = (every_touched[0] if len(every_touched) == 1
+               and every_touched[0].ndim == 1 else jnp.concatenate(
+                   [t.reshape(-1) for t in every_touched]))
+    return logits, cache, touched
 
 
 def loss_fn(
@@ -309,6 +481,11 @@ def forward_pipelined(
     family = module_for(config)
     x, pos = _embed(params, tokens, config)
     collect_aux = config.moe is not None
+    segments, experts = family.layers(config, params["blocks"], cached=False)
+    if len(segments) > 1 or len(segments[0].kinds) > 1 or experts is not None:
+        raise ValueError(
+            "the pipeline splits a stack of one kind of layer into stages: "
+            "one of several kinds has no pipelined forward")
 
     def apply_stage(local_blocks, mb):
         # Microbatches split the batch dim; positions are batch-invariant.

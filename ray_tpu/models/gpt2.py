@@ -185,6 +185,10 @@ def serving_params(config: GPT2Config, params):
         "ln1_g", "ln1_b", "ln2_g", "ln2_b", "ln_f_g", "ln_f_b", "router_w"))
 
 
+def layers(config: GPT2Config, blocks, cached: bool):
+    return single_kind(config, blocks, cached)   # every layer alike
+
+
 def embed(config: GPT2Config, params, tokens, pos, cached: bool):
     """Token plus learned position embeddings, in ``config.dtype``. The full
     forward's positions are 0..T-1, a slice of the table."""
@@ -193,7 +197,7 @@ def embed(config: GPT2Config, params, tokens, pos, cached: bool):
     return x + wpe.astype(config.dtype)
 
 
-def qkv(config: GPT2Config, layer, x, pos):
+def qkv(config: GPT2Config, kind, layer, x, pos):
     """ln1 + the fused projection: [B, T, E] → (q, k, v) each [B, T, H, D]."""
     h = _layer_norm(x, layer["ln1_g"], layer["ln1_b"])
     qkv = jnp.einsum("bte,eshd->btshd", h, layer["qkv_w"].astype(h.dtype))
@@ -207,7 +211,7 @@ def attn_out(config: GPT2Config, layer, x, attn):
     return x + attn + layer["proj_b"].astype(x.dtype)
 
 
-def ffn(config: GPT2Config, layer, x, rng, row_mask, stacked):
+def ffn(config: GPT2Config, kind, layer, x, rng, row_mask, stacked):
     """ln2 + MLP (or MoE) + residual. Returns (x, aux_loss, experts that
     received a row: 0 for the dense MLP)."""
     h = _layer_norm(x, layer["ln2_g"], layer["ln2_b"])

@@ -17,10 +17,20 @@ cache that reads the filled positions and writes one tile a slot. A block
 of tokens (prefill at B = 1, speculation's verify) takes the layer's slice
 out, writes it whole and puts it back: the right cost where a block of
 columns lands in a one-slot cache and the query block feeds the MXU.
+
+A model with window layers (attention over the token and the ``window - 1``
+before it) holds a second pair in the same pytree, ``{"k_window",
+"v_window"}``, each ``[Lw, B, KV, D, R]``: a ring, position ``p`` at
+``p mod R``. ``R`` (``ring_length``) is the window and the longest block of
+tokens one call writes: a block's last token then never lands on a position
+its first token still sees, nor a rejected draft on one that the token it is
+rolled back to sees. A ring slot's position follows from the last position
+written, so nothing beside the arrays is kept. The full layers keep ``{"k",
+"v"}`` and count their own layers, the window layers theirs.
 """
 from __future__ import annotations
 
-from typing import Dict, NamedTuple
+from typing import Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -28,28 +38,68 @@ import jax.numpy as jnp
 from ray_tpu.ops.decode_attention import TILE, decode_attention
 
 
+FULL, WINDOW = ("k", "v"), ("k_window", "v_window")
+
+
+def ring_length(window: int, block: int, max_len: int) -> int:
+    """Positions a window layer's ring holds where one call writes at most
+    ``block`` tokens: whole lane tiles where it has one (the decode kernel
+    moves tiles), and never more than a full layer would hold."""
+    ring = window + block
+    if ring >= TILE:
+        ring = -(-ring // TILE) * TILE
+    return min(ring, max_len)
+
+
 def init_kv_cache(num_layers: int, batch: int, kv_heads: int, head_dim: int,
-                  max_len: int, dtype) -> Dict[str, jax.Array]:
-    shape = (num_layers, batch, kv_heads, head_dim, max_len)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+                  max_len: int, dtype, window_layers: int = 0,
+                  ring: int = 0) -> Dict[str, jax.Array]:
+    """``num_layers`` full layers of ``max_len`` positions and
+    ``window_layers`` rings of ``ring``."""
+    cache = {}
+    for names, layers, length in ((FULL, num_layers, max_len),
+                                  (WINDOW, window_layers, ring)):
+        if layers or (names is FULL and not window_layers):
+            shape = (layers, batch, kv_heads, head_dim, length)
+            cache.update({n: jnp.zeros(shape, dtype) for n in names})
+    return cache
 
 
 class Step(NamedTuple):
     """What every layer of one ``forward_cached`` shares: where each slot's
-    tokens start, which positions each token sees and which it lands on."""
+    tokens start, which positions each token sees and which it lands on,
+    in the full layers' cache and in the window layers' ring (None where
+    the model has no such layer)."""
     start: jax.Array   # [B] int32
-    mask: jax.Array    # [B, T, S] bool
-    hit: jax.Array     # [B, T, S] bool
+    mask: Optional[jax.Array]    # [B, T, S] bool
+    hit: Optional[jax.Array]     # [B, T, S] bool
+    window: Optional[int] = None
+    ring_mask: Optional[jax.Array] = None   # [B, T, R] bool
+    ring_hit: Optional[jax.Array] = None    # [B, T, R] bool
 
 
-def step(start: jax.Array, T: int, S: int) -> Step:
+def step(start: jax.Array, T: int, cache: Dict[str, jax.Array],
+         window: Optional[int] = None) -> Step:
     """Token t of slot b sits at position ``start[b] + t``, sees the keys up
     to itself and lands on its own position. A position past the end marks
     nothing, so such a token is dropped (a ``dynamic_update_slice`` would
-    move the whole write back over valid rows)."""
+    move the whole write back over valid rows). In a ring it lands on its
+    position ``mod R``; once the block is in, slot s holds the last position
+    congruent to s that was written, and a token sees the slots whose
+    position is its own or one of the ``window - 1`` before it."""
     pos = (start[:, None] + jnp.arange(T)[None, :])[:, :, None]
-    key_pos = jnp.arange(S)[None, None, :]
-    return Step(start, key_pos <= pos, pos == key_pos)
+    mask = hit = ring_mask = ring_hit = None
+    if FULL[0] in cache:
+        key_pos = jnp.arange(cache[FULL[0]].shape[-1])[None, None, :]
+        mask, hit = key_pos <= pos, pos == key_pos
+    if WINDOW[0] in cache:
+        R = cache[WINDOW[0]].shape[-1]
+        slot = jnp.arange(R)[None, None, :]
+        last = pos[:, -1:, :]
+        held = last - (last - slot) % R
+        ring_mask = (held >= 0) & (held <= pos) & (held > pos - window)
+        ring_hit = pos % R == slot
+    return Step(start, mask, hit, window, ring_mask, ring_hit)
 
 
 def _decode_impl() -> str:
@@ -60,50 +110,81 @@ def _decode_impl() -> str:
 
 
 def attend(cache: Dict[str, jax.Array], layer: jax.Array, q: jax.Array,
-           k_new: jax.Array, v_new: jax.Array, at: Step):
-    """Layer ``layer`` of ``cache`` with ``k_new`` / ``v_new`` [B, T, KV, D]
+           k_new: jax.Array, v_new: jax.Array, at: Step,
+           windowed: bool = False):
+    """Full layer ``layer`` of ``cache`` (``windowed``: window layer
+    ``layer``, in the ring) with ``k_new`` / ``v_new`` [B, T, KV, D]
     in place, and q attended over it -> (cache, what q's shape is): q is
     [B, T, KV, D] or, G query heads sharing a kv head, [B, T, KV, G, D]. The
     cache is a scan's carry and, donated, one buffer from the program's
     argument to its result on either path."""
     B, T, KV = q.shape[:3]
+    names = WINDOW if windowed else FULL
     # the kernel moves whole lane tiles of positions: a cache whose length
     # they do not divide (the chip's compiler refuses a slice of it) keeps
     # the XLA path, as a block of tokens does
-    kernel = T == 1 and cache["k"].shape[-1] % TILE == 0
+    kernel = T == 1 and cache[names[0]].shape[-1] % TILE == 0
     impl = _decode_impl() if kernel else "xla"
     if impl == "xla":
-        return _attend_xla(cache, layer, q, k_new, v_new, at)
+        return _attend_xla(cache, names, layer, q, k_new, v_new, *(
+            (at.ring_mask, at.ring_hit) if windowed else (at.mask, at.hit)))
     out, k, v = decode_attention(
         q.reshape(B, KV, -1, q.shape[-1]), k_new[:, 0], v_new[:, 0],
-        cache["k"], cache["v"], layer, at.start,
+        cache[names[0]], cache[names[1]], layer, at.start,
+        window=at.window if windowed else None,
         interpret=impl == "pallas_interpret")
-    return {"k": k, "v": v}, out.reshape(q.shape)
+    return {**cache, names[0]: k, names[1]: v}, out.reshape(q.shape)
 
 
-def _attend_xla(cache, layer, q, k_new, v_new, at: Step):
-    ck, cv = (
-        _write(jax.lax.dynamic_index_in_dim(cache[name], layer, 0, False),
-               new, at.hit)
-        for name, new in (("k", k_new), ("v", v_new))
-    )
+# float32 scores of one product, in elements (512 MiB): a block of tokens
+# whose scores against the whole cache would be more attends in blocks of
+# queries, one after another
+SCORES_AT_ONCE = 1 << 27
+
+
+def _attention(q, ck, cv, mask):
+    """q [B, T, KV, (G,) D] over ck / cv [B, KV, D, S] where ``mask``
+    [B, T, S] allows."""
     g = "g" if q.ndim == 5 else ""    # each family the products it had
     scores = jnp.einsum(f"btk{g}d,bkds->bk{g}ts", q, ck).astype(jnp.float32)
     scores = scores / jnp.sqrt(jnp.float32(q.shape[-1]))
-    mask = jnp.expand_dims(at.mask, tuple(range(1, q.ndim - 2)))
+    mask = jnp.expand_dims(mask, tuple(range(1, q.ndim - 2)))
     scores = jnp.where(mask, scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    attn = jnp.einsum(f"bk{g}ts,bkds->btk{g}d", probs, cv)
+    return jnp.einsum(f"bk{g}ts,bkds->btk{g}d", probs, cv)
+
+
+def _attend_xla(cache, names, layer, q, k_new, v_new, mask, hit):
+    ck, cv = (
+        _write(jax.lax.dynamic_index_in_dim(cache[name], layer, 0, False),
+               new, hit)
+        for name, new in zip(names, (k_new, v_new))
+    )
+    B, T = q.shape[:2]
+    blocks = 1
+    while (q.size // q.shape[-1] // blocks * ck.shape[-1] > SCORES_AT_ONCE
+           and T % (2 * blocks) == 0):
+        blocks *= 2
+    if blocks == 1:
+        attn = _attention(q, ck, cv, mask)
+    else:
+        def cut(a):       # [B, T, ..] -> [blocks, B, T / blocks, ..]
+            return jnp.moveaxis(
+                a.reshape(B, blocks, T // blocks, *a.shape[2:]), 1, 0)
+
+        attn = jax.lax.map(
+            lambda qm: _attention(qm[0], ck, cv, qm[1]), (cut(q), cut(mask)))
+        attn = jnp.moveaxis(attn, 0, 1).reshape(q.shape)
     # The rows go back into the whole cache once attention has read them:
     # the in-place carry holds only while nothing reads the old rows once
     # the new ones are in. The barrier says so: left to itself the compiler
     # re-reads the old rows inside a later product, and copies the whole
     # cache every layer to keep them (prefill at B = 1).
     attn, ck, cv, cache = jax.lax.optimization_barrier((attn, ck, cv, cache))
-    cache = {
+    cache = {**cache, **{
         name: jax.lax.dynamic_update_index_in_dim(cache[name], rows, layer, 0)
-        for name, rows in (("k", ck), ("v", cv))
-    }
+        for name, rows in zip(names, (ck, cv))
+    }}
     return cache, attn
 
 

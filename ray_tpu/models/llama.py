@@ -235,6 +235,10 @@ def serving_params(config: LlamaConfig, params):
         "router_w"))
 
 
+def layers(config: LlamaConfig, blocks, cached: bool):
+    return single_kind(config, blocks, cached)   # every layer alike
+
+
 def embed(config: LlamaConfig, params, tokens, pos, cached: bool):
     """Token embeddings (positions enter in ``qkv``). The cached forward
     sums its residual stream in float32 (the sublayers compute in
@@ -244,7 +248,7 @@ def embed(config: LlamaConfig, params, tokens, pos, cached: bool):
         jnp.float32 if cached else config.dtype)
 
 
-def qkv(config: LlamaConfig, layer, x, pos):
+def qkv(config: LlamaConfig, kind, layer, x, pos):
     """The attention preamble: x [B, T, E] normed, pos [B, T] absolute -> q
     [B, T, KV, G, D] (the G query heads of a kv head together, at G = 1
     too) and k [B, T, KV, D], both rotated, and v [B, T, KV, D]."""
@@ -269,7 +273,7 @@ def attn_out(config: LlamaConfig, layer, x, attn):
                           layer["wo"].astype(attn.dtype))
 
 
-def ffn(config: LlamaConfig, layer, x, rng, row_mask, stacked):
+def ffn(config: LlamaConfig, kind, layer, x, rng, row_mask, stacked):
     """mlp_norm + SwiGLU MLP (or routed experts) + residual -> (x, aux_loss,
     experts that received a row: 0 for the dense MLP)."""
     h = _rms_norm(x, layer["mlp_norm"], config.rms_eps, config.dtype)

@@ -21,6 +21,15 @@ steer the kernel's own DMAs:
 Chunk sizes follow the shapes given (``_blocks``), nothing else; S is a
 multiple of the 128 lanes (the DMAs move whole tiles). What is
 small (the queries, the new columns, the result) sits in VMEM whole.
+
+With ``window`` the cache is a ring of S positions (``models/kv_cache.py``:
+position p at ``p mod S``, S at least the window) and a slot attends its new
+column and the ``window - 1`` positions before it: the visit reads the
+chunks that hold those positions and no other, from the one that holds
+position ``lens[b] - window + 1`` round the ring to the one that holds
+``lens[b] mod S``, the tile it writes. Where those are one and the same
+chunk of the ring (the window's two ends in it), it is read twice, each
+time masked to its own end.
 """
 from __future__ import annotations
 
@@ -51,7 +60,7 @@ def _blocks(KV: int, D: int, S: int, itemsize: int):
 def _kernel(layer_ref, lens_ref, q_ref, kn_row_ref, vn_row_ref, kn_col_ref,
             vn_col_ref, k_hbm, v_hbm, o_ref, ko_hbm, vo_hbm, kbuf, vbuf,
             ktile, vtile, read_sem, write_sem, m_sc, l_sc, acc_sc, *,
-            hb: int, bs: int, scale: float):
+            hb: int, bs: int, scale: float, window):
     B, KV = q_ref.shape[:2]
     S = k_hbm.shape[-1]
     groups = KV // hb
@@ -61,10 +70,22 @@ def _kernel(layer_ref, lens_ref, q_ref, kn_row_ref, vn_row_ref, kn_col_ref,
     def span(i, size):
         return pl.ds(pl.multiple_of(i * size, size), size)
 
+    def reach(b):
+        """Of slot ``b``: (the first position it attends, the chunk that
+        holds it counted from position 0, the place its new column lands
+        on). Without a window: 0, 0 and the last place for a column past
+        the end, which is then dropped."""
+        n = lens_ref[b]
+        if window is None:
+            return 0, 0, jnp.minimum(n, S - 1)
+        first = jnp.maximum(n - (window - 1), 0)
+        return first, first // bs, n % S
+
     def read(v, c, buf):
         """The DMAs of chunk ``c`` of visit ``v`` into buffer ``buf``."""
         b, heads = v // groups, pl.ds((v % groups) * hb, hb)
-        at = span(c, bs)
+        at = span(c if window is None
+                  else (reach(b)[1] + c) % (S // bs), bs)
         return [pltpu.make_async_copy(
             hbm.at[layer, b, heads, :, at], dst.at[buf], read_sem.at[i, buf])
             for i, (hbm, dst) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf)))]
@@ -72,7 +93,7 @@ def _kernel(layer_ref, lens_ref, q_ref, kn_row_ref, vn_row_ref, kn_col_ref,
     def write(v, buf):
         """The DMAs of visit ``v``'s tile out of buffer ``buf``."""
         b, heads = v // groups, pl.ds((v % groups) * hb, hb)
-        at = span(jnp.minimum(lens_ref[b], S - 1) // TILE, TILE)
+        at = span(reach(b)[2] // TILE, TILE)
         return [pltpu.make_async_copy(
             src.at[buf], hbm.at[layer, b, heads, :, at], write_sem.at[i, buf])
             for i, (src, hbm) in enumerate(((ktile, ko_hbm), (vtile, vo_hbm)))]
@@ -83,8 +104,12 @@ def _kernel(layer_ref, lens_ref, q_ref, kn_row_ref, vn_row_ref, kn_col_ref,
         b, g = v // groups, v % groups
         heads = pl.ds(g * hb, hb)
         n = lens_ref[b]                   # the new column's position
-        kept = n < S                      # past the end it is dropped
-        count = jnp.minimum(n, S - 1) // bs + 1   # chunks this visit reads
+        first, chunk0, place = reach(b)
+        # past the end of a cache that is no ring it is dropped
+        kept = n < S if window is None else True
+        # chunks this visit reads: up to the one that holds ``place``
+        count = (place // bs + 1 if window is None
+                 else n // bs - chunk0 + 1)
         q = q_ref[b, heads]               # [hb, G, D]
         # the running softmax opens on the new column alone (p = 1)
         s_new = jnp.sum(
@@ -111,6 +136,9 @@ def _kernel(layer_ref, lens_ref, q_ref, kn_row_ref, vn_row_ref, kn_col_ref,
             for dma in read(v, c, buf):
                 dma.wait()
 
+            if window is not None:
+                c = chunk0 + c            # counted from position 0
+
             @pl.when(c * bs < n)          # a filled position among them:
             def _():                      # an idle slot's chunk has none
                 attend(c, kbuf[buf], vbuf[buf])
@@ -121,7 +149,9 @@ def _kernel(layer_ref, lens_ref, q_ref, kn_row_ref, vn_row_ref, kn_col_ref,
             sc = jnp.einsum("hgd,hds->hgs", q.astype(kt), k.astype(kt),
                             preferred_element_type=jnp.float32) * scale
             pos = c * bs + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 2)
-            old = pos < n                 # the filled positions
+            old = pos < n                 # the filled positions it sees
+            if window is not None:
+                old &= pos >= first
             sc = jnp.where(old, sc, NEG_INF)
             m_prev = m_sc[...]
             m_next = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
@@ -146,16 +176,17 @@ def _kernel(layer_ref, lens_ref, q_ref, kn_row_ref, vn_row_ref, kn_col_ref,
             for dma in write(v - 2, out):
                 dma.wait()
 
-        at = jnp.minimum(n, S - 1)
-        within = span((at % bs) // TILE, TILE)
-        lane = (at // TILE) * TILE + jax.lax.broadcasted_iota(
+        within = span((place % bs) // TILE, TILE)
+        lane = (place // TILE) * TILE + jax.lax.broadcasted_iota(
             jnp.int32, (1, TILE), 1)
+        if window is None:
+            place = n                     # past the end: no lane is n
         for new_ref, chunk_ref, tile_ref in ((kn_col_ref, kbuf, ktile),
                                              (vn_col_ref, vbuf, vtile)):
             new = new_ref[b, g]                              # [D, hb]
             for h in range(hb):
                 tile_ref[out, h] = jnp.where(
-                    lane == n, new[:, h:h + 1],
+                    lane == place, new[:, h:h + 1],
                     chunk_ref[held, h, :, within])
         for dma in write(v, out):
             dma.start()
@@ -170,12 +201,15 @@ def _kernel(layer_ref, lens_ref, q_ref, kn_row_ref, vn_row_ref, kn_col_ref,
 
 
 def decode_attention(q, k_new, v_new, k_cache, v_cache, layer, lens, *,
-                     interpret: bool = False):
+                     window=None, interpret: bool = False):
     """q [B, KV, G, D] attends layer ``layer``'s filled positions
     (``< lens[b]``) of ``k_cache`` / ``v_cache`` [L, B, KV, D, S] and the
     new column ``k_new`` / ``v_new`` [B, KV, D], which is written to position
     ``lens[b]`` (dropped where that is S) -> (out [B, KV, G, D], k_cache,
-    v_cache): the caches are the operands' own buffers."""
+    v_cache): the caches are the operands' own buffers. With ``window`` the
+    caches are rings (position p at ``p mod S``), the filled positions
+    attended are the ``window - 1`` before ``lens[b]``, and the new column
+    is written to ``lens[b] mod S``."""
     B, KV, G, D = q.shape
     S = k_cache.shape[-1]
     cdt = k_cache.dtype
@@ -183,6 +217,8 @@ def decode_attention(q, k_new, v_new, k_cache, v_cache, layer, lens, *,
         raise ValueError(
             f"the kernel moves whole tiles of {TILE} positions: a cache of "
             f"{S} is the XLA path's (models/kv_cache.py:attend)")
+    if window is not None and not 0 < window <= S:
+        raise ValueError(f"a ring of {S} positions holds no window of {window}")
     hb, bs = _blocks(KV, D, S, cdt.itemsize)
     k_new, v_new = k_new.astype(cdt), v_new.astype(cdt)
     out_dtype = jnp.promote_types(q.dtype, cdt)
@@ -195,7 +231,8 @@ def decode_attention(q, k_new, v_new, k_cache, v_cache, layer, lens, *,
     cache_shape = jax.ShapeDtypeStruct(k_cache.shape, cdt)
     chunk_bytes = hb * D * bs * cdt.itemsize
     o, k_cache, v_cache = pl.pallas_call(
-        functools.partial(_kernel, hb=hb, bs=bs, scale=D ** -0.5),
+        functools.partial(_kernel, hb=hb, bs=bs, scale=D ** -0.5,
+                          window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(1,),
@@ -221,7 +258,9 @@ def decode_attention(q, k_new, v_new, k_cache, v_cache, layer, lens, *,
         # small operands, whose rows pad to whole tiles
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=8 * chunk_bytes + (32 << 20)),
-        name="decode_attention",
+        # a trace's reader tells the two kinds of cache apart by the name
+        name="decode_attention" if window is None
+        else "decode_attention_window",
         interpret=interpret,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), lens.astype(jnp.int32), q,
       k_new[:, :, None, :], v_new[:, :, None, :], columns(k_new),

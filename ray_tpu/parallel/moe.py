@@ -1,9 +1,12 @@
 """Mixture-of-Experts: routed expert layers, two dispatches.
 
 The reference delegates EP entirely to vLLM (SURVEY.md §2.3); here experts are
-a mesh axis. Routing is one float32 softmax over all experts and a top-k
-(Switch/GShard, Mixtral, OLMoE: ``norm_topk_prob`` says whether the k gates
-are renormalised to sum to 1). What follows is one of two dispatches:
+a mesh axis. Routing is one float32 score over all experts and a top-k: a
+softmax (Switch/GShard, Mixtral, OLMoE: ``norm_topk_prob`` says whether the
+k gates are renormalised to sum to 1), or a sigmoid an expert, the k chosen
+under a learned bias that the gates do not carry, renormalised and scaled
+(``score_func``, ``expert_bias``, ``route_scale``: the DeepSeek-V3 router
+that Trinity's ``afmoe`` takes). What follows is one of two dispatches:
 
 - capacity (training default): the sharded-einsum formulation of GShard/Switch.
   Routing builds a dispatch one-hot [tokens, experts, capacity]; einsums
@@ -28,7 +31,9 @@ are renormalised to sum to 1). What follows is one of two dispatches:
 
 Both are differentiable; auxiliary load-balancing loss included. The device
 operations carry the scopes ``moe.route``, ``moe.dispatch``, ``moe.experts``
-and ``moe.combine`` (``jax.named_scope``) for a trace's reader.
+and ``moe.combine`` (``jax.named_scope``) for a trace's reader; a shared
+expert that every token passes beside the routed ones is a plain gated MLP
+under ``moe.shared`` (``shared_expert``).
 """
 from __future__ import annotations
 
@@ -57,6 +62,16 @@ class MoEConfig:
     # a trained router is peaked, its gates carry the experts' share of the
     # residual stream, and the expert at the k-th place has a small gate.
     router_init_std: float = 0.02
+    # What an expert's score is: "softmax" over all experts, or "sigmoid" of
+    # its own logit alone.
+    score_func: str = "softmax"
+    # A parameter ``expert_bias`` [E] (a trained model's load balancer) is
+    # added to the scores for the CHOICE of the k; the gates are the scores
+    # without it. Fresh weights draw it with ``expert_bias_init_std``.
+    expert_bias: bool = False
+    expert_bias_init_std: float = 0.0
+    # The k gates times this, after any renormalising.
+    route_scale: float = 1.0
     # Dropless routing (inference): every token reaches its
     # top-k experts, no capacity queues. Required for KV-cache decode to
     # reproduce full-forward outputs — capacity drops depend on the other
@@ -71,6 +86,10 @@ class MoEConfig:
                 f"MoEConfig.activation must be 'gelu' or 'swiglu', got "
                 f"{self.activation!r}"
             )
+        if self.score_func not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"MoEConfig.score_func must be 'softmax' or 'sigmoid', got "
+                f"{self.score_func!r}")
 
 
 def init_moe_params(
@@ -81,7 +100,7 @@ def init_moe_params(
     """Per-layer expert weights; with num_layers, adds a leading stacked dim.
     ``out_std`` is the scale of the experts' output projection: a model
     passes what it gives its other projections into the residual stream."""
-    k1, k2, k3, k4 = jax.random.split(key, 4)
+    k1, k2, k3, k4, k5 = jax.random.split(key, 5)
     lead = () if num_layers is None else (num_layers,)
     E = config.num_experts
 
@@ -96,6 +115,10 @@ def init_moe_params(
     if config.activation == "swiglu":
         # Mixtral-style gated experts: fc is the "up" proj, gate multiplies
         params["expert_gate"] = normal(k4, lead + (E, embed_dim, mlp_dim))
+    if config.expert_bias:
+        # float32 whatever the weights': it decides a choice among scores
+        params["expert_bias"] = config.expert_bias_init_std * (
+            jax.random.normal(k5, lead + (E,), jnp.float32))
     return params
 
 
@@ -109,6 +132,8 @@ def moe_param_axes(num_layers: Optional[int] = None,
     }
     if config is not None and config.activation == "swiglu":
         axes["expert_gate"] = lead + ("expert", "embed", "mlp")
+    if config is not None and config.expert_bias:
+        axes["expert_bias"] = lead + (None,)
     return axes
 
 
@@ -118,17 +143,21 @@ def stacked_for(params: Dict[str, jax.Array], dtype) -> Dict[str, jax.Array]:
     where they already are; the router's stay as they are, it runs in
     float32). A caller does this ONCE, outside its layer loop: a cast of the
     stack is a copy of every layer's experts."""
-    return {name: w if name == "router_w" else w.astype(dtype)
-            for name, w in params.items()}
+    return {name: w if name in ("router_w", "expert_bias")
+            else w.astype(dtype) for name, w in params.items()}
 
 
 def _route(params, tokens, config: MoEConfig, rng, layer):
-    """tokens [T, D] -> (probs [T, E] float32 over ALL experts, the k chosen
-    experts' gates [T, k] and indices [T, k])."""
+    """tokens [T, D] -> (scores [T, E] float32 over ALL experts, the k chosen
+    experts' gates [T, k] (their scores as they are: ``_normalised`` does
+    the rest) and indices [T, k])."""
     with jax.named_scope("moe.route"):
-        router_w = params["router_w"]
-        if layer is not None:
-            router_w = jax.lax.dynamic_index_in_dim(router_w, layer, 0, False)
+        def own(name):
+            w = params[name]
+            return w if layer is None else jax.lax.dynamic_index_in_dim(
+                w, layer, 0, False)
+
+        router_w = own("router_w")
         router_logits = jnp.einsum(
             "td,de->te", tokens.astype(jnp.float32),
             router_w.astype(jnp.float32),
@@ -137,17 +166,35 @@ def _route(params, tokens, config: MoEConfig, rng, layer):
             router_logits += config.router_jitter * jax.random.normal(
                 rng, router_logits.shape
             )
-        probs = jax.nn.softmax(router_logits, axis=-1)
-        gates, chosen = jax.lax.top_k(probs, config.top_k)
+        if config.score_func == "sigmoid":
+            probs = jax.nn.sigmoid(router_logits)
+        else:
+            probs = jax.nn.softmax(router_logits, axis=-1)
+        if config.expert_bias:
+            # the bias moves the choice and not the gates
+            _, chosen = jax.lax.top_k(
+                probs + own("expert_bias").astype(jnp.float32), config.top_k)
+            gates = jnp.take_along_axis(probs, chosen, axis=-1)
+        else:
+            gates, chosen = jax.lax.top_k(probs, config.top_k)
     return probs, gates, chosen
 
 
 def _normalised(gates: jax.Array, config: MoEConfig) -> jax.Array:
     """A token's gates [..., k or E] divided by their sum, where the
-    configuration says so."""
-    if not config.norm_topk_prob:
-        return gates
-    return gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    configuration says so, times its ``route_scale``."""
+    if config.norm_topk_prob:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    return gates if config.route_scale == 1.0 else gates * config.route_scale
+
+
+def shared_expert(x: jax.Array, w_gate, w_up, w_down) -> jax.Array:
+    """The gated MLP every token passes beside its routed experts, x
+    [.., D] in its own dtype: plain products, under ``moe.shared``."""
+    with jax.named_scope("moe.shared"):
+        h = jax.nn.silu(x @ w_gate.astype(x.dtype)) * (
+            x @ w_up.astype(x.dtype))
+        return h @ w_down.astype(x.dtype)
 
 
 def _aux_loss(probs, chosen_share, config: MoEConfig):

@@ -70,6 +70,18 @@ class Replica:
         # live generator streams: stream_id -> [iter, last_access, model_id]
         self._streams: Dict[str, list] = {}
         self._stream_seq = 0
+        # A sync generator's body runs in a thread for as long as a pull
+        # waits for its chunks, one thread a live stream: a pool of their
+        # own, which grows with the streams this replica was given
+        # (``max_ongoing_requests`` bounds them). The event loop's default
+        # executor has cpu_count + 4 threads: on a 13-core host the 18th
+        # stream's first pull, the one that submits its request, waited
+        # for another stream's 16 chunks, and a replica of 32 slots never
+        # held more than 17 requests (my chip run, PR 34).
+        from concurrent.futures import ThreadPoolExecutor
+
+        self._stream_pulls = ThreadPoolExecutor(
+            max_workers=1024, thread_name_prefix="rt-stream-pull")
         # Graceful drain: the controller stopped routing to this replica
         # and is waiting for _ongoing + _streams to reach zero before
         # stopping it (requests already in the mailbox still run — zero
@@ -222,7 +234,7 @@ class Replica:
 
                 call_ctx = contextvars.copy_context()
                 chunks, done = await loop.run_in_executor(
-                    None, lambda: call_ctx.run(pull)
+                    self._stream_pulls, lambda: call_ctx.run(pull)
                 )
                 if done:
                     self._streams.pop(stream_id, None)

@@ -329,6 +329,15 @@ def test_counters_equal_the_sums_of_the_spans_arguments(recording):
     assert stats["cache_positions"] == sum(
         t.args["cache_positions"] for t in ticks)
     assert stats["cache_positions"] > stats["slot_ticks"]
+    # a model of one kind of layer: all of it of the full layers' cache,
+    # none of a window's; every admission one program
+    for name, want in (("cache_positions_full", stats["cache_positions"]),
+                       ("cache_positions_window", 0)):
+        assert stats[name] == sum(t.args[name] for t in ticks) == want
+    assert {(t.args["layers_full"], t.args["layers_window"])
+            for t in ticks} == {(
+        recording["float32_engine"].model_config.num_layers, 0)}
+    assert {a.args["chunks"] for a in admits} == {1}
     assert stats["queue_wait_s"] == pytest.approx(
         sum(a.args["queued_ms"] for a in admits) / 1e3, abs=1e-5)
     # the host's clock is read just outside the span: never less, and more

@@ -257,10 +257,10 @@ def test_decode_kernel_equals_the_xla_path(small_blocks, monkeypatch, D, S,
         # a function of its own each time: jit's trace cache is by function
         return jax.jit(lambda cache: kv_cache.attend(
             cache, jnp.int32(1), q, k_new, v_new,
-            kv_cache.step(jnp.asarray(lens), 1, S)))(cache)
+            kv_cache.step(jnp.asarray(lens), 1, cache)))(cache)
 
     got_cache, got = attend()
-    assert small_blocks == [{"interpret": True}]
+    assert small_blocks == [{"interpret": True, "window": None}]
     monkeypatch.setattr(kv_cache, "_decode_impl", lambda: "xla")
     want_cache, want = attend()
     assert len(small_blocks) == 1
@@ -290,7 +290,7 @@ def test_a_cache_the_lanes_do_not_divide_keeps_the_xla_path(small_blocks):
     new = jnp.ones((B, 1, KV, D), jnp.float32)
     cache, out = kv_cache.attend(
         cache, jnp.int32(0), new, new, new,
-        kv_cache.step(jnp.array([0, 95], jnp.int32), 1, S))
+        kv_cache.step(jnp.array([0, 95], jnp.int32), 1, cache))
     assert small_blocks == [] and out.shape == new.shape
     assert np.asarray(cache["k"])[0, :, 0, 0, [0, 95]].tolist() == [
         [1.0, 0.0], [0.0, 1.0]]
@@ -348,4 +348,4 @@ def test_prefill_then_kernel_steps_equal_the_full_forward(small_blocks,
                 np.asarray(logits)[b], full[b][lens[b]],
                 rtol=2e-4, atol=2e-4)
         lens[list(seqs)] += 1
-    assert small_blocks == [{"interpret": True}]  # one trace, in the scan
+    assert small_blocks == [{"interpret": True, "window": None}]  # one trace, in the scan

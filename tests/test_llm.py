@@ -89,9 +89,16 @@ def test_temperature_sampling_runs():
 
 
 def test_prompt_too_long_rejected():
+    """Too long is longer than the context leaves room for an answer; a
+    prompt longer than the largest prefill bucket is admitted in chunks
+    (PR 34)."""
     eng = _engine()
-    with pytest.raises(ValueError):
-        eng.generate(list(range(2, 60)), SamplingParams(max_new_tokens=2))
+    assert 58 > max(eng.config.prefill_buckets)
+    out = eng.generate(list(range(2, 60)), SamplingParams(max_new_tokens=2))
+    assert len(out) == 2
+    with pytest.raises(ValueError, match="no room for an answer"):
+        eng.generate(list(range(2, 2 + eng.config.max_seq_len)),
+                     SamplingParams(max_new_tokens=2))
 
 
 def test_byte_tokenizer_roundtrip():

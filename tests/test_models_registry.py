@@ -24,7 +24,7 @@ from ray_tpu.parallel.moe import MoEConfig
 
 # what the decoder calls on a family, what a server calls once as it takes
 # its weights, and what callers outside ask of one
-PIECES = ("embed", "qkv", "attn_out", "ffn", "final_norm", "head",
+PIECES = ("layers", "embed", "qkv", "attn_out", "ffn", "final_norm", "head",
           "head_weight", "serving_params")
 OWN = ("Config", "PRESETS", "EXPERT_ACTIVATION", "init_params", "param_axes")
 SHARED = ("forward_features", "forward", "init_kv_cache", "forward_cached",
@@ -132,8 +132,10 @@ def test_llm_config_builds_every_family(family, experts):
     if experts:
         assert cfg.moe.num_experts == 4 and cfg.moe.dropless
         assert cfg.moe.activation == module.EXPERT_ACTIVATION
-        params = module.init_params(cfg, jax.random.PRNGKey(0))
-        gated = "expert_gate" in params["blocks"]["moe"]
+        blocks = module.init_params(cfg, jax.random.PRNGKey(0))["blocks"]
+        # a family of one kind of layer keeps them with the layer, one of
+        # several in a stack of their own
+        gated = "expert_gate" in (blocks.get("moe") or blocks["experts"])
         assert gated == (module.EXPERT_ACTIVATION == "swiglu")
     else:
         assert cfg.moe is None
@@ -156,11 +158,11 @@ def test_llm_config_builds_every_family(family, experts):
 # ``serving_params``: what a family's cached forward rounds on every use,
 # rounded once. The same values by the same operation, so not a bit moves.
 
-TINY = {"gpt2": "gpt2-tiny", "llama": "llama-tiny"}
+TINY = {"gpt2": "gpt2-tiny", "llama": "llama-tiny", "afmoe": "afmoe-tiny"}
 
 
 def _tiny(family, experts, **dtypes):
-    cfg = dataclasses.replace(get_preset(TINY[family]), **dtypes)
+    cfg = dataclasses.replace(get_preset(TINY[family]), moe=None, **dtypes)
     if experts:
         cfg = dataclasses.replace(cfg, moe=MoEConfig(
             num_experts=experts, top_k=2, dropless=True,
@@ -191,14 +193,14 @@ def _prefill_and_three_steps(cfg, params):
         np.random.default_rng(0).integers(2, cfg.vocab_size, (2, 16)),
         jnp.int32)
     start = jnp.zeros((2,), jnp.int32)
-    logits, cache = step(params, tokens, decoder.init_kv_cache(cfg, 2, 64),
-                         start)
+    logits, cache = step(
+        params, tokens, decoder.init_kv_cache(cfg, 2, 64, block=16), start)
     out = [logits]
     for i in range(3):
         nxt = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
         logits, cache = step(params, nxt, cache, start + 16 + i)
         out.append(logits)
-    return [np.asarray(a) for a in out + [cache["k"], cache["v"]]]
+    return [np.asarray(a) for a in out + list(cache.values())]
 
 
 @pytest.mark.parametrize("weights", ["init", "perturbed"])
@@ -249,3 +251,91 @@ def test_serving_params_are_the_arrays_given_where_none_is_wider(
         assert jax.tree.structure(held) == jax.tree.structure(given)
         for g, h in zip(jax.tree.leaves(given), jax.tree.leaves(held)):
             assert h is g
+
+
+# ------------------------------------------------------- kinds of layer
+
+
+@pytest.mark.parametrize("family", ["gpt2", "llama"])
+@pytest.mark.parametrize("experts", [0, 4], ids=["dense", "routed"])
+def test_a_family_of_one_kind_of_layer_is_one_scan(family, experts):
+    """``gpt2`` and ``llama`` state one kind of layer: one segment over the
+    blocks as they are stacked, no window, one cache."""
+    module = family_module(family)
+    cfg = _tiny(family, experts)
+    params = module.init_params(cfg, jax.random.PRNGKey(0))
+    for cached in (False, True):
+        segments, stack = module.layers(cfg, params["blocks"], cached)
+        (kinds, (blocks,), repeats), = segments
+        assert kinds == (decoder.Layer(routed=bool(experts) and cached),)
+        assert repeats == cfg.num_layers
+        # routed experts leave the scan of the cached forward alone
+        assert (stack is not None) == (bool(experts) and cached)
+        assert ("moe" in blocks) == (bool(experts) and not cached)
+    assert set(decoder.layer_kinds(cfg)) == {
+        decoder.Layer(routed=bool(experts))}
+    assert sorted(decoder.init_kv_cache(cfg, 2, 32)) == ["k", "v"]
+
+
+def test_a_stack_of_several_kinds_is_a_lead_and_whole_periods():
+    from ray_tpu.models import afmoe
+
+    S, F = afmoe.SLIDING, afmoe.FULL
+    published = afmoe.Config(
+        num_layers=32, num_dense_layers=2, sliding_window=2048,
+        layer_types=(S, S, S, F) * 8, moe=MoEConfig(num_experts=128, top_k=8))
+    segments, _ = afmoe.layers(published, None, cached=True)
+    assert [(len(s.kinds), s.repeats) for s in segments] == [(4, 1), (4, 7)]
+    assert [k.name for k in segments[0].kinds] == [
+        "sliding/dense", "sliding/dense", "sliding/routed", "full/routed"]
+    assert [k.name for k in segments[1].kinds] == [
+        "sliding/routed"] * 3 + ["full/routed"]
+    kinds = decoder.layer_kinds(published)
+    assert len(kinds) == 32 and sum(k.routed for k in kinds) == 30
+    assert [k.window for k in kinds[:4]] == [2048, 2048, 2048, None]
+    # the cell's cut: five kinds, nothing repeats, so nothing is scanned
+    cut = dataclasses.replace(published, num_layers=5, num_dense_layers=1,
+                              layer_types=(S, S, S, S, F))
+    (kinds, _, repeats), = afmoe.layers(cut, None, cached=True)[0]
+    assert len(kinds) == 5 and repeats == 1
+    cache = jax.eval_shape(
+        lambda: decoder.init_kv_cache(cut, 32, 8192, block=2048))
+    assert {k: v.shape for k, v in cache.items()} == {
+        "k": (1, 32, 32, 64, 8192), "v": (1, 32, 32, 64, 8192),
+        "k_window": (4, 32, 32, 64, 4096), "v_window": (4, 32, 32, 64, 4096)}
+    with pytest.raises(ValueError, match="layer_types"):
+        dataclasses.replace(cut, layer_types=(S, S, S, S, "S"))
+    with pytest.raises(ValueError, match="pipeline"):
+        decoder.forward_pipelined(
+            afmoe.init_params(afmoe.AFMOE_TINY, jax.random.PRNGKey(0)),
+            jnp.zeros((2, 8), jnp.int32), afmoe.AFMOE_TINY, None)
+
+
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_a_prompt_longer_than_the_largest_bucket_is_admitted_in_chunks(
+        family):
+    """17 tokens through buckets of 8 and 16 (one chunk of 16 and one of 1,
+    the second continuing the first's cache) give the tokens of one
+    unchunked prefill in a bucket of 32."""
+    from ray_tpu.llm import DecodeEngine, SamplingParams
+
+    extra = dict(layer_types=("sliding_attention",) * 3 + ("full_attention",),
+                 sliding_window=8, num_dense_layers=1,
+                 moe_num_experts=4, moe_score_func="sigmoid"
+                 ) if family == "afmoe" else {}
+    prompt = [int(t) for t in np.random.default_rng(5).integers(2, 300, 17)]
+    answers = []
+    for buckets in ((8, 16), (32,)):
+        engine = DecodeEngine(LLMConfig(
+            model_family=family, vocab_size=300, max_seq_len=64,
+            num_layers=4, num_heads=4, embed_dim=64, dtype="float32",
+            max_batch_slots=2, prefill_buckets=buckets, **extra))
+        out = engine.generate(prompt, SamplingParams(max_new_tokens=6))
+        answers.append(list(out))
+        engine.shutdown()
+        assert len(out) == 6
+    assert answers[0] == answers[1]
+    with pytest.raises(ValueError, match="no room for an answer"):
+        DecodeEngine(LLMConfig(
+            model_family=family, max_seq_len=16, prefill_buckets=(8,),
+            **extra)).generate(list(range(2, 18)))

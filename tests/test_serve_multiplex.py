@@ -128,3 +128,39 @@ def test_streaming_async_generator(serve_cluster):
     out = handle.stream_async.remote(5).result()
     assert list(out) == [0, 10, 20, 30, 40]
     serve.delete("streamer2")
+
+
+@serve.deployment(max_ongoing_requests=48)
+class Rendezvous:
+    """Every stream's first chunk waits until all of them have started: a
+    replica that runs fewer generator bodies at once than it was given
+    streams never gets there."""
+
+    def __init__(self):
+        import threading
+
+        self.barrier = threading.Barrier(40)
+
+    def tokens(self, i: int):
+        self.barrier.wait(timeout=30)
+        for j in range(3):
+            yield (i, j)
+
+
+def test_as_many_sync_streams_run_at_once_as_the_replica_was_given(
+        serve_cluster):
+    """40 streams on one replica (``max_ongoing_requests`` 48): a sync
+    generator's body runs in a thread while a pull waits for it, and the
+    event loop's default executor has only cpu_count + 4 of them."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    handle = serve.run(Rendezvous.bind(), name="rendezvous")
+
+    def one(i):
+        it = handle.options(stream=True).tokens.remote(i).result()
+        return list(it)
+
+    with ThreadPoolExecutor(40) as pool:
+        got = list(pool.map(one, range(40)))
+    assert got == [[(i, j) for j in range(3)] for i in range(40)]
+    serve.delete("rendezvous")

@@ -115,7 +115,7 @@ def test_flash_attention_compiles_inside_a_pipeline_stage(topo):
 # The serving cell's size: GPT-2 XL, 10 slots, 1024 positions.
 
 
-def _engine_program_args(one_chip, slots, width, cfg=None):
+def _engine_program_args(one_chip, slots, width, cfg=None, block=1):
     """Shapes on the described chip of what an engine program of ``cfg``
     (default: GPT-2 XL) takes: params as the engine holds them (the
     family's ``serving_params`` of what it initialises), tokens, cache,
@@ -134,7 +134,8 @@ def _engine_program_args(one_chip, slots, width, cfg=None):
     params = on_chip(jax.eval_shape(lambda: model.serving_params(
         cfg, model.init_params(cfg, jax.random.PRNGKey(0)))))
     cache = on_chip(jax.eval_shape(
-        lambda: model.init_kv_cache(cfg, slots, cfg.max_seq_len)))
+        lambda: model.init_kv_cache(cfg, slots, cfg.max_seq_len,
+                                    block=block)))
     tokens = jax.ShapeDtypeStruct((slots, width), jnp.int32,
                                   sharding=one_chip)
     start = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
@@ -330,3 +331,83 @@ def test_olmoe_programs_read_the_experts_where_they_lie(
                           for a in args[2].values())
         assert mem.alias_size_in_bytes == cache_bytes
         assert _cache_sized(text, args[2]) == []
+
+
+# -------------------------------------------------- Trinity-Mini in the engine
+# The third serving cell's size: afmoe at depth 5, bf16 weights, 32 slots,
+# one full layer of 8192 positions and four rings of 4096
+# (``benchmarks/configs/trinity-mini.json``).
+
+
+def _trinity_config():
+    from benchmarks import run
+    from benchmarks.lib import program
+
+    return program.model_config(
+        run.load_cell("trinity-mini.serve-mixed")[2])
+
+
+@pytest.mark.parametrize("program, slots, width", [
+    ("decode", 32, 1), ("prefill", 1, 2048)])
+def test_trinity_programs_fit_the_chip_and_keep_both_caches_in_place(
+        one_chip, program, slots, width, monkeypatch):
+    """``jit_decode`` at 32 slots and ``jit_prefill`` at the largest bucket,
+    at the published widths: the v5e compiler takes the decode kernel at
+    G = 8, with the window over the rings and without it over the full
+    layer, once a layer (nothing is scanned: five kinds, one of each); the
+    grouped products run over the four routed layers' experts as one operand
+    ([4 x 128, K, N]); a program's arguments and temporaries fit the chip;
+    a prefill chunk's float32 scores are taken in blocks of queries, so its
+    temporaries stay under 3 GB; the prefill's logits are the one row the
+    host reads, not 2048 rows of 200192."""
+    import re
+
+    from ray_tpu.llm.engine import engine_programs
+    from ray_tpu.models import kv_cache
+
+    monkeypatch.setattr(kv_cache, "_decode_impl", lambda: "pallas")
+    cfg, args = _engine_program_args(
+        one_chip, slots, width, _trinity_config(), block=2048)
+    assert cfg.param_dtype == jnp.bfloat16 and cfg.moe.dropless
+    cache = args[2]
+    assert {k: v.shape for k, v in cache.items()} == {
+        "k": (1, slots, 4, 128, 8192), "v": (1, slots, 4, 128, 8192),
+        "k_window": (4, slots, 4, 128, 4096),
+        "v_window": (4, slots, 4, 128, 4096)}
+    if program == "decode":
+        compiled = engine_programs(cfg)[2].lower(
+            *_decode_args(one_chip, args)).compile()
+    else:
+        rows = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+        compiled = engine_programs(cfg)[0].lower(*args, rows=rows).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    param_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(args[0]))
+    assert 8.4e9 < param_bytes < 8.6e9      # 4.24 B parameters in bf16
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in cache.values())
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < (
+        15.0e9 if program == "decode" else 12.0e9)
+    kernels = re.findall(
+        r"%ragged-dot-none[.\d]* = (\w+)\[(\d+),(\d+)\].* custom-call\((.*)",
+        text)
+    assert len(kernels) == 3 * 4            # four routed layers, unrolled
+    for _, m, _, operands in kernels:
+        assert int(m) == slots * width * cfg.moe.top_k
+        assert re.search(r"bf16\[512,(2048,1024|1024,2048)\]", operands)
+    big = re.compile(
+        r"= \w+\[(128|512),(2048,1024|1024,2048)\]\S* "
+        r"(copy|convert|dynamic-slice|fusion)\(")
+    assert [line[:160] for line in text.splitlines() if big.search(line)
+            ] == []
+    if program == "decode":
+        assert 1.5e9 < cache_bytes < 1.7e9  # 0.54 GB full + 1.07 GB rings
+        assert mem.alias_size_in_bytes == cache_bytes
+        assert mem.temp_size_in_bytes < 0.5e9
+        calls = [line for line in text.splitlines() if "custom-call(" in line
+                 and re.match(r"\s*%?decode_attention", line)]
+        assert len([c for c in calls if "decode_attention_window" in c]) == 4
+        assert len(calls) == 5
+        assert _weight_converts(text, args[0]) == []
+    else:
+        assert mem.temp_size_in_bytes < 3.0e9
+        assert "f32[1,1,200192]" in text.split("\n", 1)[0]
