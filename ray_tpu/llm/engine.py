@@ -19,7 +19,12 @@ split, KV cache management. TPU-first redesign instead of a port:
   / top-k / penalties / logprobs), which keeps the compiled program
   sampling-agnostic. A greedy row with nothing else for the host to do is
   its logits' argmax: the decode program takes it too, and takes the ids of
-  the tick before as its input tokens without their leaving the chip.
+  the tick before as its input tokens without their leaving the chip. All
+  the host sends a tick is ``packed`` [3, B] int32: each slot's token (-1:
+  the chip's own), its length, and 1 where the slot decodes in this tick, 0
+  where it does not: what the loop knows (its ``rows``), never read off a
+  length. The model step keeps its hands off the cache of a slot that does
+  not decode, and routes its row to no expert.
 - So the loop runs one tick ahead of the host wherever every token the next
   tick needs is known, on the host or on the chip (``_step_locked``): it
   dispatches tick k+1, then reads tick k's ids. A row whose token the host
@@ -41,8 +46,12 @@ KV cache the tick needed (the active slots' lengths and the columns it
 writes) of a layer that attends everything, ``cache_positions_full`` the
 same where the model has such layers (``layers_full`` of them) and
 ``cache_positions_window`` what it needed of a window layer (each slot's
-length or the window, whichever is less; ``layers_window``). ``tick``,
-``active``, ``ahead``, ``overrun``, the ``cache_positions`` and
+length or the window, whichever is less; ``layers_window``). ``active`` of
+``slots`` decode in a tick, and the decode kernel visits those and no other
+(``ops/decode_attention.py``): ``slots_skipped`` of ``stats`` sums the
+visits a layer that it did not make, ``slots - active``, beside
+``slot_ticks``, the ones it made. ``tick``,
+``active``, ``slots``, ``ahead``, ``overrun``, the ``cache_positions`` and
 ``moe_rows`` of an ``engine.tick`` span are those of the program
 dispatched in it; ``experts_touched`` is the count of the programs read
 since the tick span before: the tick before's (each ``.read`` and ``.fetch``
@@ -195,13 +204,15 @@ def engine_programs(cfg):
     the model module's pytree with the slot on axis 1; ``insert`` and both
     decodes take it donated and give it back in the same buffer. For a
     model with routed experts the three model programs are told how many
-    of a row's tokens are tokens (``real`` [B]: one more argument, or one
-    more row of ``decode``'s ``packed``), and give one more result after
-    the cache, the experts touched a layer [L]."""
+    of a row's tokens are tokens (``real`` [B]: one more argument; the last
+    row of ``decode``'s ``packed``, which every model's has), and give one
+    more result after the cache, the experts touched a layer [L]."""
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models.decoder import forward_cached
+    from ray_tpu.models.decoder import forward_cached, layer_kinds
+
+    routed = any(kind.routed for kind in layer_kinds(cfg))
 
     def prefill(params, tokens, cache1, start, *real, rows=None):
         # start > 0 = continuation from a cached prefix or from the chunk
@@ -219,14 +230,17 @@ def engine_programs(cfg):
         )
 
     def decode(params, before, cache, packed):
-        # ``packed`` [2 | 3, B] int32 is all the host sends a tick: each
-        # slot's token, its length and, for routed experts, ``real``. A
-        # token below 0 is not the host's to give: it is the slot's own of
-        # ``before`` [B], the ids the tick before chose, still on the chip.
-        given, lens, *real = packed
+        # ``packed`` [3, B] int32 is all the host sends a tick: each slot's
+        # token, its length and ``real``, 1 where the slot decodes and 0
+        # where it does not: the rows routed to experts, and the slots
+        # whose cache the step reads and writes. A token below 0 is not the
+        # host's to give: it is the slot's own of ``before`` [B], the ids
+        # the tick before chose, still on the chip.
+        given, lens, real = packed
         tokens = jnp.where(given < 0, before, given)
         logits, *rest = forward_cached(
-            params, tokens[:, None], cache, lens, cfg, *real)
+            params, tokens[:, None], cache, lens, cfg,
+            *((real,) if routed else ()), live=real > 0)
         logits = logits[:, -1]
         # a greedy row's next token (the first index on a tie, as numpy's)
         ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -376,6 +390,9 @@ class DecodeEngine:
             # sums at the boundaries the spans mark: submit to admission,
             # the admissions themselves, active slots over ticks
             "queue_wait_s": 0.0, "admit_s": 0.0, "slot_ticks": 0,
+            # slots that did not decode over ticks: the visits a layer the
+            # decode kernel left out (slot_ticks: the ones it made)
+            "slots_skipped": 0,
             # positions of the cache the ticks needed: the active slots'
             # lengths, each with the columns its tick writes
             "cache_positions": 0,
@@ -884,11 +901,11 @@ class DecodeEngine:
         flying = self._flying
         drafts = self._drafts_locked(rows) if self._spec_k else {}
         compiles = self.stats["compiles"]
+        B = len(self._slots)
         with span("engine.tick", tick=self.stats["ticks"], active=len(rows),
-                  moe_layers=self._moe_layers,
+                  slots=B, moe_layers=self._moe_layers,
                   ahead=int(flying is not None)) as tick:
             with span("engine.tick.pack"):
-                B = len(self._slots)
                 toks = np.zeros((B, 1 + self._spec_k if drafts else 1),
                                 np.int32)
                 lens = np.zeros((B,), np.int32)
@@ -912,9 +929,7 @@ class DecodeEngine:
                     sent = (jnp.asarray(toks), jnp.asarray(lens),
                             *self._real(real))
                 else:
-                    sent = jnp.asarray(np.stack(
-                        [toks[:, 0], lens, real][:3 if self._moe_layers
-                                                 else 2]))
+                    sent = jnp.asarray(np.stack([toks[:, 0], lens, real]))
             with span("engine.tick.dispatch"):
                 if drafts:
                     ids = None
@@ -933,6 +948,7 @@ class DecodeEngine:
             self.stats["ticks"] += 1
             self.stats["ticks_ahead"] += flying is not None
             self.stats["slot_ticks"] += len(rows)
+            self.stats["slots_skipped"] += B - len(rows)
             self.stats["cache_positions"] += cache_positions
             for name, count in positions.items():
                 self.stats[name] += count

@@ -337,6 +337,7 @@ def forward_cached(
     config,
     real: Optional[jax.Array] = None,
     rows: Optional[jax.Array] = None,
+    live: Optional[jax.Array] = None,
 ) -> tuple:
     """Incremental forward: attend over the KV cache, append new K/V.
 
@@ -349,6 +350,9 @@ def forward_cached(
     donates the cache gets it back in the same buffer. ``rows`` [R] int32
     names the tokens whose logits the caller will read (a prefill uses its
     last one): the head then runs on those alone, logits [B, R, V].
+    ``live`` [B] bool names the slots whose tokens are tokens (None: every
+    slot): a decode step's kernel leaves the cache of any other slot as it
+    is (``kv_cache.Step``), and such a slot's logits mean nothing.
 
     Two results for a caller that gives no ``real``, three for one that
     does, the one place where the arity follows an argument (a third result,
@@ -366,7 +370,7 @@ def forward_cached(
 
     segments, experts = family.layers(config, params["blocks"], cached=True)
     windows = {k.window for s in segments for k in s.kinds} - {None}
-    at = kv_cache.step(start, T, cache, *windows)
+    at = kv_cache.step(start, T, cache, *windows, live=live)
     mask = None if real is None else jnp.arange(T)[None, :] < real[:, None]
     # The experts' weights stay out of the scan: it would hand each layer
     # its slice, and a slice that feeds a kernel is a copy (``moe._experts``)

@@ -13,7 +13,9 @@ position-minor to begin with, the slice feeds both products as it lies.
 ``attend`` is a layer's whole access: place the new tokens' columns, attend
 over what is filled. Two paths, parted by static shapes alone. A decode step
 (T == 1; S a multiple of the chip's 128 lanes) on a TPU is one kernel (``ops/decode_attention.py``) over the whole
-cache that reads the filled positions and writes one tile a slot. A block
+cache that reads the filled positions and writes one tile a slot, of the
+slots it is told decode (``Step.live``) and of no other: a slot that does
+not decode keeps its cache as it is and gets zeros for its row. A block
 of tokens (prefill at B = 1, speculation's verify) takes the layer's slice
 out, writes it whole and puts it back: the right cost where a block of
 columns lands in a one-slot cache and the query block feeds the MXU.
@@ -35,7 +37,7 @@ from typing import Dict, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.decode_attention import TILE, decode_attention
+from ray_tpu.ops.decode_attention import TILE, decode_attention, live_slots
 
 
 FULL, WINDOW = ("k", "v"), ("k_window", "v_window")
@@ -69,24 +71,32 @@ class Step(NamedTuple):
     """What every layer of one ``forward_cached`` shares: where each slot's
     tokens start, which positions each token sees and which it lands on,
     in the full layers' cache and in the window layers' ring (None where
-    the model has no such layer)."""
+    the model has no such layer). ``live``: the slots whose tokens are
+    tokens, as the decode kernel takes them (``live_slots``; None: every
+    slot). What the caller says of a slot, never read off its length. The
+    kernel leaves any other slot alone; the XLA path computes every slot and
+    the caller drops the rows it did not ask for."""
     start: jax.Array   # [B] int32
     mask: Optional[jax.Array]    # [B, T, S] bool
     hit: Optional[jax.Array]     # [B, T, S] bool
     window: Optional[int] = None
     ring_mask: Optional[jax.Array] = None   # [B, T, R] bool
     ring_hit: Optional[jax.Array] = None    # [B, T, R] bool
+    live: Optional[jax.Array] = None        # [B + 1] int32
 
 
 def step(start: jax.Array, T: int, cache: Dict[str, jax.Array],
-         window: Optional[int] = None) -> Step:
+         window: Optional[int] = None,
+         live: Optional[jax.Array] = None) -> Step:
     """Token t of slot b sits at position ``start[b] + t``, sees the keys up
     to itself and lands on its own position. A position past the end marks
     nothing, so such a token is dropped (a ``dynamic_update_slice`` would
     move the whole write back over valid rows). In a ring it lands on its
     position ``mod R``; once the block is in, slot s holds the last position
     congruent to s that was written, and a token sees the slots whose
-    position is its own or one of the ``window - 1`` before it."""
+    position is its own or one of the ``window - 1`` before it. ``live``
+    [B] bool: the slots that decode (None: every slot), compacted here, once
+    for every layer."""
     pos = (start[:, None] + jnp.arange(T)[None, :])[:, :, None]
     mask = hit = ring_mask = ring_hit = None
     if FULL[0] in cache:
@@ -99,7 +109,8 @@ def step(start: jax.Array, T: int, cache: Dict[str, jax.Array],
         held = last - (last - slot) % R
         ring_mask = (held >= 0) & (held <= pos) & (held > pos - window)
         ring_hit = pos % R == slot
-    return Step(start, mask, hit, window, ring_mask, ring_hit)
+    return Step(start, mask, hit, window, ring_mask, ring_hit,
+                None if live is None else live_slots(live))
 
 
 def _decode_impl() -> str:
@@ -130,7 +141,7 @@ def attend(cache: Dict[str, jax.Array], layer: jax.Array, q: jax.Array,
             (at.ring_mask, at.ring_hit) if windowed else (at.mask, at.hit)))
     out, k, v = decode_attention(
         q.reshape(B, KV, -1, q.shape[-1]), k_new[:, 0], v_new[:, 0],
-        cache[names[0]], cache[names[1]], layer, at.start,
+        cache[names[0]], cache[names[1]], layer, at.start, live=at.live,
         window=at.window if windowed else None,
         interpret=impl == "pallas_interpret")
     return {**cache, names[0]: k, names[1]: v}, out.reshape(q.shape)

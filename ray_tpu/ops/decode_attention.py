@@ -5,18 +5,23 @@ columns that are filled and one new column written. ``decode_attention``
 does both over the WHOLE cache ``[L, B, KV, D, S]`` (``models/kv_cache.py``),
 aliased to its results: an operand of a custom call is a whole array, so a
 layer's slice cut by the scan would be copied for it. The cache stays in
-HBM; the layer index and ``lens`` ride as scalar-prefetch arguments and
-steer the kernel's own DMAs:
+HBM; the layer index, ``lens`` and the live slots ride as scalar-prefetch
+arguments and steer the kernel's own DMAs. The kernel visits the slots it is
+told decode (``live``: their indices, ascending, then their count), in that
+order, and no other:
 
-- read: slot by slot (and group of kv heads), chunk by chunk of positions,
-  only the chunks that hold positions ``< lens[b]`` (one at least: it
-  holds the tile to write). Two buffers: a chunk is in flight while the one
-  before is computed on, across the slots' edges too. Online softmax in
-  float32, products accumulated in float32. The new column never comes from
-  the cache: its score and value open the running softmax.
+- read: live slot by live slot (and group of kv heads), chunk by chunk of
+  positions, only the chunks that hold positions ``< lens[b]`` (one at
+  least: it holds the tile to write). Two buffers: a chunk is in flight
+  while the one before is computed on, across the slots' edges too. Online
+  softmax in float32, products accumulated in float32. The new column never
+  comes from the cache: its score and value open the running softmax.
 - write: the one 128-position tile that holds position ``lens[b]``: the old
   tile out of the chunk already in VMEM, the new column selected in, sent
   back. A position past the end selects nothing.
+- a slot that is not live starts no DMA in either direction: its K and V
+  leave the call as they entered, and its row of the result is zeros. No
+  live slot at all is no DMA at all.
 
 Chunk sizes follow the shapes given (``_blocks``), nothing else; S is a
 multiple of the 128 lanes (the DMAs move whole tiles). What is
@@ -57,15 +62,20 @@ def _blocks(KV: int, D: int, S: int, itemsize: int):
     return hb, bs
 
 
-def _kernel(layer_ref, lens_ref, q_ref, kn_row_ref, vn_row_ref, kn_col_ref,
-            vn_col_ref, k_hbm, v_hbm, o_ref, ko_hbm, vo_hbm, kbuf, vbuf,
-            ktile, vtile, read_sem, write_sem, m_sc, l_sc, acc_sc, *,
+def _kernel(layer_ref, lens_ref, live_ref, q_ref, kn_row_ref, vn_row_ref,
+            kn_col_ref, vn_col_ref, k_hbm, v_hbm, o_ref, ko_hbm, vo_hbm, kbuf,
+            vbuf, ktile, vtile, read_sem, write_sem, m_sc, l_sc, acc_sc, *,
             hb: int, bs: int, scale: float, window):
     B, KV = q_ref.shape[:2]
     S = k_hbm.shape[-1]
     groups = KV // hb
-    visits = B * groups               # a visit: one slot, one group of heads
+    # a visit: one live slot, one group of heads; ``live_ref`` holds the
+    # live slots' indices and, behind them, their count
+    visits = live_ref[B] * groups
     layer = layer_ref[0]
+
+    def slot(v):
+        return live_ref[v // groups]
 
     def span(i, size):
         return pl.ds(pl.multiple_of(i * size, size), size)
@@ -83,7 +93,7 @@ def _kernel(layer_ref, lens_ref, q_ref, kn_row_ref, vn_row_ref, kn_col_ref,
 
     def read(v, c, buf):
         """The DMAs of chunk ``c`` of visit ``v`` into buffer ``buf``."""
-        b, heads = v // groups, pl.ds((v % groups) * hb, hb)
+        b, heads = slot(v), pl.ds((v % groups) * hb, hb)
         at = span(c if window is None
                   else (reach(b)[1] + c) % (S // bs), bs)
         return [pltpu.make_async_copy(
@@ -92,7 +102,7 @@ def _kernel(layer_ref, lens_ref, q_ref, kn_row_ref, vn_row_ref, kn_col_ref,
 
     def write(v, buf):
         """The DMAs of visit ``v``'s tile out of buffer ``buf``."""
-        b, heads = v // groups, pl.ds((v % groups) * hb, hb)
+        b, heads = slot(v), pl.ds((v % groups) * hb, hb)
         at = span(reach(b)[2] // TILE, TILE)
         return [pltpu.make_async_copy(
             src.at[buf], hbm.at[layer, b, heads, :, at], write_sem.at[i, buf])
@@ -101,7 +111,7 @@ def _kernel(layer_ref, lens_ref, q_ref, kn_row_ref, vn_row_ref, kn_col_ref,
     def visit(v, buf):
         """Visit ``v``, whose first chunk is on its way into ``buf`` -> the
         buffer the next visit's first chunk is on its way into."""
-        b, g = v // groups, v % groups
+        b, g = slot(v), v % groups
         heads = pl.ds(g * hb, hb)
         n = lens_ref[b]                   # the new column's position
         first, chunk0, place = reach(b)
@@ -140,7 +150,7 @@ def _kernel(layer_ref, lens_ref, q_ref, kn_row_ref, vn_row_ref, kn_col_ref,
                 c = chunk0 + c            # counted from position 0
 
             @pl.when(c * bs < n)          # a filled position among them:
-            def _():                      # an idle slot's chunk has none
+            def _():                      # a slot's first column has none
                 attend(c, kbuf[buf], vbuf[buf])
             return 1 - buf
 
@@ -192,16 +202,34 @@ def _kernel(layer_ref, lens_ref, q_ref, kn_row_ref, vn_row_ref, kn_col_ref,
             dma.start()
         return after
 
-    for dma in read(0, 0, 0):
-        dma.start()
+    # what no visit writes, a slot that is not live, leaves as zeros
+    o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    @pl.when(visits > 0)
+    def _():
+        for dma in read(0, 0, 0):
+            dma.start()
+
     jax.lax.fori_loop(0, visits, visit, 0)
-    for v in range(max(visits - 2, 0), visits):
-        for dma in write(v, v % 2):
-            dma.wait()
+    for back in (2, 1):                   # the last two visits' tiles
+
+        @pl.when(visits >= back)
+        def _():
+            for dma in write(visits - back, (visits - back) % 2):
+                dma.wait()
+
+
+def live_slots(live: jax.Array) -> jax.Array:
+    """``live`` [B] bool -> [B + 1] int32 as the kernel takes it: the indices
+    of the slots that are live, ascending, and in the last place how many
+    they are (what lies between is not read)."""
+    B = live.shape[0]
+    order, = jnp.nonzero(live, size=B, fill_value=0)
+    return jnp.append(order, live.sum()).astype(jnp.int32)
 
 
 def decode_attention(q, k_new, v_new, k_cache, v_cache, layer, lens, *,
-                     window=None, interpret: bool = False):
+                     live=None, window=None, interpret: bool = False):
     """q [B, KV, G, D] attends layer ``layer``'s filled positions
     (``< lens[b]``) of ``k_cache`` / ``v_cache`` [L, B, KV, D, S] and the
     new column ``k_new`` / ``v_new`` [B, KV, D], which is written to position
@@ -209,7 +237,9 @@ def decode_attention(q, k_new, v_new, k_cache, v_cache, layer, lens, *,
     v_cache): the caches are the operands' own buffers. With ``window`` the
     caches are rings (position p at ``p mod S``), the filled positions
     attended are the ``window - 1`` before ``lens[b]``, and the new column
-    is written to ``lens[b] mod S``."""
+    is written to ``lens[b] mod S``. ``live`` (``live_slots``' [B + 1];
+    None: every slot) names the slots this holds for: any other slot's cache
+    is left as it is and its row of ``out`` is zeros."""
     B, KV, G, D = q.shape
     S = k_cache.shape[-1]
     cdt = k_cache.dtype
@@ -220,6 +250,8 @@ def decode_attention(q, k_new, v_new, k_cache, v_cache, layer, lens, *,
     if window is not None and not 0 < window <= S:
         raise ValueError(f"a ring of {S} positions holds no window of {window}")
     hb, bs = _blocks(KV, D, S, cdt.itemsize)
+    if live is None:                      # slots 0 .. B - 1, and B of them
+        live = jnp.arange(B + 1, dtype=jnp.int32)
     k_new, v_new = k_new.astype(cdt), v_new.astype(cdt)
     out_dtype = jnp.promote_types(q.dtype, cdt)
 
@@ -234,7 +266,7 @@ def decode_attention(q, k_new, v_new, k_cache, v_cache, layer, lens, *,
         functools.partial(_kernel, hb=hb, bs=bs, scale=D ** -0.5,
                           window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(1,),
             in_specs=[vmem, vmem, vmem, vmem, vmem, hbm, hbm],
             out_specs=[vmem, hbm, hbm],
@@ -253,7 +285,7 @@ def decode_attention(q, k_new, v_new, k_cache, v_cache, layer, lens, *,
         out_shape=[jax.ShapeDtypeStruct((B, KV, G, D), out_dtype),
                    cache_shape, cache_shape],
         # operands count from the scalar-prefetch arguments on
-        input_output_aliases={7: 1, 8: 2},
+        input_output_aliases={8: 1, 9: 2},
         # four chunks and four tiles held, the chunk's temporaries, and the
         # small operands, whose rows pad to whole tiles
         compiler_params=pltpu.CompilerParams(
@@ -262,7 +294,7 @@ def decode_attention(q, k_new, v_new, k_cache, v_cache, layer, lens, *,
         name="decode_attention" if window is None
         else "decode_attention_window",
         interpret=interpret,
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), lens.astype(jnp.int32), q,
-      k_new[:, :, None, :], v_new[:, :, None, :], columns(k_new),
-      columns(v_new), k_cache, v_cache)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), lens.astype(jnp.int32),
+      live.astype(jnp.int32), q, k_new[:, :, None, :], v_new[:, :, None, :],
+      columns(k_new), columns(v_new), k_cache, v_cache)
     return o, k_cache, v_cache

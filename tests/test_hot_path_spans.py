@@ -324,6 +324,9 @@ def test_counters_equal_the_sums_of_the_spans_arguments(recording):
     assert stats["ticks"] == len(ticks)
     assert stats["slot_ticks"] == sum(t.args["active"] for t in ticks)
     assert stats["slot_ticks"] == stats["tokens_generated"]
+    # and the slots that did not decode: the visits the kernel leaves out
+    assert stats["slots_skipped"] == sum(
+        t.args["slots"] - t.args["active"] for t in ticks) > 0
     # what the ticks needed of the cache: every active slot's length and
     # the column it writes, so more than a position a token
     assert stats["cache_positions"] == sum(
@@ -449,6 +452,8 @@ def test_ahead_counters_equal_the_sums_of_the_spans_arguments(
     ticks = spans.named("engine.tick")
     assert stats["ticks"] == len(ticks) > 0
     assert stats["slot_ticks"] == sum(t.args["active"] for t in ticks)
+    assert stats["slots_skipped"] == sum(
+        t.args["slots"] - t.args["active"] for t in ticks)
     assert stats["ticks_ahead"] == sum(t.args["ahead"] for t in ticks)
     assert stats["overrun_rows"] == sum(t.args["overrun"] for t in ticks)
     assert stats["slot_ticks"] == stats["tokens_generated"] + stats[

@@ -93,14 +93,16 @@ class _Model:
 
     def tick(self, toks, cache, lens, before=None):
         """``decode`` as the engine calls it: the host's ``[B, 1]`` tokens
-        and the lengths in one packed array, beside the ids of the tick
-        before (zeros: every token here is the host's) -> (logits, cache),
+        the lengths and ``real`` (every slot decodes) in one packed array,
+        beside the ids of the tick before (zeros: every token here is the
+        host's) -> (logits, cache),
         the program's ids held to its logits."""
         if before is None:
             before = jnp.zeros((SLOTS,), jnp.int32)
         ids, logits, cache = self.decode(
             self.params, before, cache,
-            jnp.stack([jnp.asarray(toks)[:, 0], jnp.asarray(lens)]))
+            jnp.stack([jnp.asarray(toks)[:, 0], jnp.asarray(lens),
+                       jnp.ones((SLOTS,), jnp.int32)]))
         assert ids.dtype == jnp.int32
         np.testing.assert_array_equal(
             np.asarray(ids), np.asarray(logits).argmax(-1))
@@ -224,10 +226,12 @@ def small_blocks(monkeypatch):
     the kernel, interpreted, where the platform would choose XLA."""
     monkeypatch.setattr(kernel, "BLOCK_BYTES", 2 * 64 * 128 * 4)
     monkeypatch.setattr(kv_cache, "_decode_impl", lambda: "pallas_interpret")
-    traced = []  # the options of each kernel call that was traced
+    # the options of each kernel call that was traced (``live``: given?)
+    traced = []
     monkeypatch.setattr(
         kv_cache, "decode_attention",
-        lambda *a, **kw: traced.append(kw) or kernel.decode_attention(*a, **kw))
+        lambda *a, **kw: traced.append(dict(kw, live=kw["live"] is not None))
+        or kernel.decode_attention(*a, **kw))
     return traced
 
 
@@ -260,7 +264,8 @@ def test_decode_kernel_equals_the_xla_path(small_blocks, monkeypatch, D, S,
             kv_cache.step(jnp.asarray(lens), 1, cache)))(cache)
 
     got_cache, got = attend()
-    assert small_blocks == [{"interpret": True, "window": None}]
+    assert small_blocks == [
+        {"interpret": True, "window": None, "live": False}]
     monkeypatch.setattr(kv_cache, "_decode_impl", lambda: "xla")
     want_cache, want = attend()
     assert len(small_blocks) == 1
@@ -279,6 +284,58 @@ def test_decode_kernel_equals_the_xla_path(small_blocks, monkeypatch, D, S,
         assert not changed[0].any()
         for b, n in enumerate(lens):
             assert list(np.flatnonzero(changed[1, b])) == ([n] if n < S else [])
+
+
+LIVE = {"all": [1, 1, 1, 1, 1], "one": [0, 0, 1, 0, 0],
+        "alternate": [1, 0, 1, 0, 1], "last": [0, 0, 0, 0, 1],
+        "none": [0, 0, 0, 0, 0], "unsaid": None}
+
+
+@pytest.mark.parametrize("pattern", list(LIVE))
+@pytest.mark.parametrize("G", [1, 8])
+@pytest.mark.parametrize("window", [None, 200], ids=["full", "ring"])
+def test_decode_kernel_visits_the_live_slots_alone(small_blocks, window, G,
+                                                   pattern):
+    """The slots the call names decode as they do where every slot does,
+    bit for bit: their rows of the result, their K and V. Any other slot,
+    whatever its length says, keeps its K and V byte for byte and gets
+    zeros; no slot named is no write at all, none said is all named. Two
+    groups of kv heads a slot, several chunks a visit, lengths of 0 (a
+    live slot may be empty), past a chunk's edge and round the ring."""
+    L, KV, D, S = 2, 4, 64, 384
+    lens = jnp.array([0, 70, 300, 129, 500 if window else S - 1], jnp.int32)
+    B = len(lens)
+    assert kernel._blocks(KV, D, S, 4) == (2, 128)
+    ks = jax.random.split(jax.random.PRNGKey(G), 5)
+    names = kv_cache.WINDOW if window else kv_cache.FULL
+    cache = {name: jax.random.normal(key, (L, B, KV, D, S), jnp.float32)
+             for name, key in zip(names, ks)}
+    q = jax.random.normal(ks[2], (B, 1, KV, G, D), jnp.float32)
+    k_new = jax.random.normal(ks[3], (B, 1, KV, D), jnp.float32)
+    v_new = jax.random.normal(ks[4], (B, 1, KV, D), jnp.float32)
+
+    def attend(live):
+        if live is not None:
+            live = jnp.asarray(live, bool)
+        got_cache, got = jax.jit(lambda cache: kv_cache.attend(
+            cache, jnp.int32(1), q, k_new, v_new,
+            kv_cache.step(lens, 1, cache, window, live=live),
+            windowed=window is not None))(cache)
+        return jax.tree.map(np.asarray, (got_cache, got))
+
+    want_cache, want = attend(LIVE["all"])
+    got_cache, got = attend(LIVE[pattern])
+    assert [call["window"] for call in small_blocks] == [window, window]
+    live = np.array(LIVE[pattern] or LIVE["all"], bool)
+    assert (got[live] == want[live]).all() and not got[~live].any()
+    assert want.all()                     # a row computed is no row of zeros
+    for name in names:
+        new, old = got_cache[name], np.asarray(cache[name])
+        assert (new[:, live] == want_cache[name][:, live]).all(), name
+        assert (new[:, ~live] == old[:, ~live]).all(), name
+        assert (new[0] == old[0]).all(), name
+        # and a live slot's one column was written, on the layer asked
+        assert ((new[1] != old[1]).any(axis=(1, 2)).sum(-1) == live).all()
 
 
 def test_a_cache_the_lanes_do_not_divide_keeps_the_xla_path(small_blocks):
@@ -342,10 +399,46 @@ def test_prefill_then_kernel_steps_equal_the_full_forward(small_blocks,
             toks[b, 0] = t[lens[b]]
         _, logits, cache = decode(
             params, jnp.zeros((3,), jnp.int32), cache,
-            jnp.asarray(np.stack([toks[:, 0], lens])))
+            jnp.asarray(np.stack([toks[:, 0], lens, lens > 0])))
         for b in seqs:
             np.testing.assert_allclose(
                 np.asarray(logits)[b], full[b][lens[b]],
                 rtol=2e-4, atol=2e-4)
         lens[list(seqs)] += 1
-    assert small_blocks == [{"interpret": True, "window": None}]  # one trace, in the scan
+    # one trace, in the scan, told which slots decode
+    assert small_blocks == [{"interpret": True, "window": None, "live": True}]
+
+
+def test_two_busy_slots_of_eight_answer_as_each_alone(small_blocks):
+    """An engine through the kernel: two requests decode side by side in a
+    replica of eight slots, six of which the kernel never visits, and
+    their greedy answers are those of the same requests one at a time
+    (then seven are left out)."""
+    from ray_tpu.llm import DecodeEngine, LLMConfig, SamplingParams
+
+    config = LLMConfig(
+        vocab_size=300, max_seq_len=256, num_layers=2, num_heads=2,
+        embed_dim=128, dtype="float32", max_batch_slots=8,
+        prefill_buckets=(128,), prefix_cache_size=0)
+    cfg = config.model_config()
+    params = jax.tree.map(
+        lambda a: a * 8 if a.ndim >= 2 else a,  # answers that move about
+        module_for(cfg).init_params(cfg, jax.random.PRNGKey(3)))
+    engine = DecodeEngine(config, params=params)
+    rng = np.random.RandomState(3)
+    # one prompt ends just short of a chunk's edge: its answer crosses it
+    prompts = [[int(t) for t in rng.randint(2, 300, n)] for n in (125, 9)]
+    greedy = SamplingParams(max_new_tokens=10)
+    try:
+        together = [engine.submit(p, greedy) for p in prompts]
+        together = [list(f.result(timeout=300)) for f in together]
+        stats = dict(engine.stats)
+        alone = [list(engine.submit(p, greedy).result(timeout=300))
+                 for p in prompts]
+    finally:
+        engine.shutdown()
+    assert together == alone and len(set(map(tuple, alone))) == 2
+    assert small_blocks == [{"interpret": True, "window": None, "live": True}]
+    # both decoded in most ticks, and every slot of a tick is on one side
+    assert stats["ticks"] < stats["slot_ticks"] <= 2 * stats["ticks"]
+    assert stats["slots_skipped"] == 8 * stats["ticks"] - stats["slot_ticks"]

@@ -146,9 +146,9 @@ def _engine_program_args(one_chip, slots, width, cfg=None, block=1):
 def _decode_args(one_chip, args):
     """``jit_decode``'s arguments from a model program's at width 1: the
     ids of the tick before [slots], and the host's one packed array of
-    tokens, lengths and (routed experts) ``real`` in the tokens' place."""
-    params, _, cache, start, *real = args
-    packed = jax.ShapeDtypeStruct((2 + len(real), *start.shape), jnp.int32,
+    tokens, lengths and ``real`` in the tokens' place."""
+    params, _, cache, start, *_ = args
+    packed = jax.ShapeDtypeStruct((3, *start.shape), jnp.int32,
                                   sharding=one_chip)
     return params, start, cache, packed
 
@@ -245,6 +245,13 @@ def test_decode_program_updates_the_cache_in_place(one_chip, cell,
     calls = [line for line in text.splitlines()
              if "custom-call(" in line and "decode_attention" in line]
     assert len(calls) == 1  # the layer scan instantiates it once
+    # told which slots decode (their indices and count), and the whole
+    # cache still the first operand of its rank, where a trace's reader
+    # (``benchmarks/lib/decode_attn.py``) takes the sizes from
+    from benchmarks.lib import decode_attn
+
+    assert f"s32[{slots + 1}]" in calls[0]
+    assert decode_attn.cache_shape(calls[0])[:5] == cache["k"].shape
     # and no fusion makes a layer's slice: a decode step has no business to
     assert _cache_sized(text, cache, "fusion") == []
 
@@ -407,6 +414,7 @@ def test_trinity_programs_fit_the_chip_and_keep_both_caches_in_place(
                  and re.match(r"\s*%?decode_attention", line)]
         assert len([c for c in calls if "decode_attention_window" in c]) == 4
         assert len(calls) == 5
+        assert all(f"s32[{slots + 1}]" in c for c in calls)  # the live slots
         assert _weight_converts(text, args[0]) == []
     else:
         assert mem.temp_size_in_bytes < 3.0e9
