@@ -37,9 +37,24 @@ What the loop thread does is in the profiler's own trace, on the device's
 timeline: ``engine.tick`` (children ``.pack``, ``.dispatch``, then ``.read``
 of the tick before and its ``.sample``, or, with a host row, the tick's own
 ``.fetch`` and ``.sample``), ``engine.admit`` (children
-``engine.prefill.dispatch``, ``.fetch``, ``.sample``, ``engine.insert``),
-``engine.finish`` and ``engine.idle`` are ``jax.profiler.TraceAnnotation``
-spans, inert unless a capture runs (``rt profile --xla``). Their arguments
+``engine.admit.cache``, the empty slot cache of a prompt with no stored
+prefix, ``engine.prefill.dispatch``, ``.fetch``, ``.sample``,
+``engine.insert``), ``engine.finish`` and ``engine.idle`` are
+``jax.profiler.TraceAnnotation`` spans, inert unless a capture runs (``rt
+profile --xla``). A request's ledger is on its ``engine.finish``:
+``queued_ms`` (``submit`` to the start of its admission, also on its
+``engine.admit``), ``admit_ms`` (that to its first token: its own
+admission), ``stalled_ms`` (every OTHER request's admission that ran while
+its slot was active) and ``prompt_tokens`` beside ``first_token_ms``
+(``queued_ms + admit_ms``) and ``total_ms``; what ``total_ms`` has beyond
+the three is the request's decode ticks and their reads. ``held`` of
+``engine.admit`` is how many slots were decoding and stood still for it;
+``stalled_s`` of ``stats`` sums each admission's length times its ``held``
+where ``admit_s`` sums the lengths, so ``stalled_s / admit_s`` slots wait
+for an admission and ``queue_wait_s / requests`` is a request's wait for
+its own. The answer carries ``time.monotonic()`` of its ``engine.finish``
+(``GenerationResult.finished_at``, ``TokenStream.finished_at``) for the
+serving layer's ``llm.done``. The other spans' arguments
 are the counters of that boundary, and ``stats`` sums the same quantities
 with no capture: ``cache_positions`` of ``engine.tick`` is how much of the
 KV cache the tick needed (the active slots' lengths and the columns it
@@ -108,18 +123,23 @@ class GenerationResult(list):
     """Generated token ids; quacks as the plain list older callers expect,
     with per-token logprob entries riding along when requested."""
 
-    def __init__(self, token_ids, logprobs=None, finish_reason=""):
+    def __init__(self, token_ids, logprobs=None, finish_reason="",
+                 finished_at=0.0):
         super().__init__(token_ids)
         self.logprobs = logprobs or []
         # how the answer ended: length | eos | stop | context
         self.finish_reason = finish_reason
+        # time.monotonic() of its ``engine.finish``
+        self.finished_at = finished_at
 
 
 class TokenStream:
     """What ``submit_stream`` returns: an iterator over the generated token
-    ids that knows, once exhausted, how the answer ended."""
+    ids that knows, once exhausted, how the answer ended and when
+    (``time.monotonic()`` of its ``engine.finish``)."""
 
     finish_reason = ""
+    finished_at = 0.0
 
     def __init__(self, tokens):
         self._tokens = tokens(self)
@@ -147,7 +167,12 @@ class _Slot:
     stream_q: Optional[Any] = None  # queue.Queue for token streaming
     rid: str = ""  # the id the spans of this request share
     submitted: float = 0.0  # time.monotonic() at submit
-    first_token_ms: float = 0.0  # submit to the first token, at admission
+    # the request's ledger, what ``engine.finish`` says of it: submit to the
+    # start of its admission, that to its first token, and every other
+    # request's admission that ran while this slot was active
+    queued_ms: float = 0.0
+    admit_ms: float = 0.0
+    stalled_ms: float = 0.0
 
 
 @dataclass
@@ -160,6 +185,7 @@ class _Pending:
     future: Future
     rid: str
     submitted: float
+    admitted: float = 0.0  # time.monotonic() when its admission began
 
 
 @dataclass
@@ -390,6 +416,8 @@ class DecodeEngine:
             # sums at the boundaries the spans mark: submit to admission,
             # the admissions themselves, active slots over ticks
             "queue_wait_s": 0.0, "admit_s": 0.0, "slot_ticks": 0,
+            # each admission's length times the slots it held
+            "stalled_s": 0.0,
             # slots that did not decode over ticks: the visits a layer the
             # decode kernel left out (slot_ticks: the ones it made)
             "slots_skipped": 0,
@@ -607,8 +635,11 @@ class DecodeEngine:
         # rows of one program's logits: as many as one chunk can be asked
         # for, a static shape (the unused ones repeat the chunk's last)
         width = 1 + len(self._boundaries)
-        cache1 = (entry["cache"] if entry is not None
-                  else self._empty_slot_cache())
+        if entry is not None:
+            cache1 = entry["cache"]
+        else:
+            with span("engine.admit.cache"):
+                cache1 = self._empty_slot_cache()
         programs = []  # (lengths read of it, its logits, its touched)
         with span("engine.prefill.dispatch"):
             for at in range(base, n, largest):
@@ -666,7 +697,9 @@ class DecodeEngine:
         slot.rid, slot.submitted = req.rid, req.submitted
         if slot.stream_q is not None:
             slot.stream_q.put(first)
-        slot.first_token_ms = (time.monotonic() - req.submitted) * 1e3
+        slot.queued_ms = (req.admitted - req.submitted) * 1e3
+        slot.admit_ms = (time.monotonic() - req.admitted) * 1e3
+        slot.stalled_ms = 0.0
         self.stats["requests"] += 1
         self._finish_if_done_locked(b)
 
@@ -678,13 +711,16 @@ class DecodeEngine:
             except queue.Empty:
                 break
             b = free.pop(0)
-            t0 = time.monotonic()
+            # the slots that decode stand still for this admission
+            held = [s for s in self._slots if s.active]
+            req.admitted = t0 = time.monotonic()
             queued = t0 - req.submitted
             try:
                 with self._span(
                     "engine.admit", rid=req.rid,
                     queued_ms=round(queued * 1e3, 3),
                     prompt_tokens=self._prompt_len(req), slot=b,
+                    held=len(held),
                 ) as admit:
                     admit.set_metadata(**self._admit_one_locked(req, b))
             except Exception as e:
@@ -694,8 +730,12 @@ class DecodeEngine:
                 req.future.set_exception(e)
                 free.insert(0, b)
             # an admission holds every decoding slot for its whole length
+            took = time.monotonic() - t0
             self.stats["queue_wait_s"] += queued
-            self.stats["admit_s"] += time.monotonic() - t0
+            self.stats["admit_s"] += took
+            self.stats["stalled_s"] += took * len(held)
+            for slot in held:
+                slot.stalled_ms += took * 1e3
         self.stats["compiles"] = compile_count()
 
     @staticmethod
@@ -779,20 +819,25 @@ class DecodeEngine:
                     break
         if reason is None:
             return
+        now = time.monotonic()
         with self._span(
             "engine.finish", rid=slot.rid, produced=slot.produced,
-            reason=reason, first_token_ms=round(slot.first_token_ms, 3),
-            total_ms=round((time.monotonic() - slot.submitted) * 1e3, 3),
+            reason=reason, prompt_tokens=slot.prompt_len,
+            queued_ms=round(slot.queued_ms, 3),
+            admit_ms=round(slot.admit_ms, 3),
+            stalled_ms=round(slot.stalled_ms, 3),
+            first_token_ms=round(slot.queued_ms + slot.admit_ms, 3),
+            total_ms=round((now - slot.submitted) * 1e3, 3),
         ):
             if out is None:
                 out = slot.token_ids
                 if out and out[-1] in stop:
                     out = out[:-1]
             if slot.stream_q is not None:
-                slot.stream_q.put(("__done__", len(out), reason))
+                slot.stream_q.put(("__done__", len(out), reason, now))
             if slot.future is not None:
                 slot.future.set_result(GenerationResult(
-                    out, slot.logprobs[: len(out)], reason
+                    out, slot.logprobs[: len(out)], reason, now
                 ))
             slot.active = False
             slot.future = None
@@ -1116,7 +1161,7 @@ class DecodeEngine:
                 except _q.Empty:
                     continue
                 if isinstance(item, tuple) and item[0] == "__done__":
-                    stream.finish_reason = item[2]
+                    stream.finish_reason, stream.finished_at = item[2:]
                     return
                 # a stop TOKEN ends the request without being part of the
                 # output; the done marker's kept-length already excludes

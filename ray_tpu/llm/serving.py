@@ -8,6 +8,7 @@ engine mesh config rather than vLLM kwargs.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import time
 import uuid
@@ -88,6 +89,22 @@ def completion_response(config: LLMConfig, prompt_tokens: int,
     }
 
 
+@contextlib.contextmanager
+def _done(rid: str, stream: bool, tokens: int, finished_at: float):
+    """The ``llm.done`` span of an answer the serving thread has whole
+    (``finished_at``: ``time.monotonic()`` of its ``engine.finish``), around
+    the making of its last piece, which the caller counts into the span's
+    ``chunks``. ``after_finish_ms``: from the finish to that piece being
+    ready to leave the replica."""
+    from jax.profiler import TraceAnnotation
+
+    with TraceAnnotation("llm.done", rid=rid, stream=int(stream),
+                         tokens=tokens) as done:
+        yield done
+        done.set_metadata(after_finish_ms=round(
+            (time.monotonic() - finished_at) * 1e3, 3))
+
+
 class LLMServer:
     """Serve deployment target wrapping one engine replica."""
 
@@ -133,10 +150,15 @@ class LLMServer:
     def _sampling(self, payload: dict) -> SamplingParams:
         return extract_sampling(payload, self.config)
 
-    def _submit(self, prompt: str, payload: dict, rid: str, stream: bool):
+    def _submit(self, prompt: str, payload: dict, rid: str, stream: bool,
+                called: float):
         """(prompt ids, the engine's future or token stream): tokenizing and
         the hand-over to the engine, under the request's ``llm.request``
-        span on the serving thread."""
+        span on the serving thread. ``called`` is ``time.monotonic()`` of
+        the call of the endpoint; ``since_call_ms`` of the span is from
+        there to the hand-over: for a stream, whose body runs at the
+        proxy's first pull, the reply to the proxy and that pull's way
+        back."""
         from jax.profiler import TraceAnnotation
 
         params = self._sampling(payload)
@@ -145,26 +167,35 @@ class LLMServer:
             stream=int(stream),
         ) as request:
             ids = self.engine.tokenizer.encode(prompt)
-            request.set_metadata(prompt_tokens=len(ids))
+            request.set_metadata(
+                prompt_tokens=len(ids),
+                since_call_ms=round((time.monotonic() - called) * 1e3, 3))
             send = (self.engine.submit_stream if stream
                     else self.engine.submit)
             return ids, send(ids, params, rid=rid)
 
-    def completions(self, payload: dict) -> dict:
-        rid = new_request_id()
-        ids, fut = self._submit(payload.get("prompt", ""), payload, rid,
-                                False)
+    def _answer(self, prompt: str, payload: dict, rid: str, called: float):
+        """(prompt ids, the engine's answer, its text) of a unary request."""
+        ids, fut = self._submit(prompt, payload, rid, False, called)
         out = fut.result(600)
-        text = self.engine.tokenizer.decode(out)
+        with _done(rid, False, len(out), out.finished_at) as done:
+            text = self.engine.tokenizer.decode(out)
+            done.set_metadata(chunks=1)
+        return ids, out, text
+
+    def completions(self, payload: dict) -> dict:
+        called = time.monotonic()
+        rid = new_request_id()
+        ids, out, text = self._answer(
+            payload.get("prompt", ""), payload, rid, called)
         return completion_response(self.config, len(ids), out, text, rid=rid)
 
     def chat_completions(self, payload: dict) -> dict:
+        called = time.monotonic()
         rid = new_request_id(chat=True)
-        ids, fut = self._submit(
+        ids, out, text = self._answer(
             self._chat_prompt(payload.get("messages", [])), payload, rid,
-            False)
-        out = fut.result(600)
-        text = self.engine.tokenizer.decode(out)
+            called)
         choice = {
             "index": 0,
             "message": {"role": "assistant", "content": text},
@@ -195,16 +226,29 @@ class LLMServer:
         """Generator of OpenAI SSE chunk lines (stream=true). Deltas are
         detokenized incrementally; the final line is ``data: [DONE]``
         (reference: ray.llm / vLLM streaming responses)."""
+        # a generator's body runs at its first ``next`` (the proxy's first
+        # pull), so the call is stamped here, outside it
+        return self._stream(payload, chat, time.monotonic())
+
+    def _stream(self, payload: dict, chat: bool, called: float):
         if chat:
             prompt = self._chat_prompt(payload.get("messages", []))
         else:
             prompt = payload.get("prompt", "")
         rid = new_request_id(chat)
-        _, tokens = self._submit(prompt, payload, rid, True)
+        _, tokens = self._submit(prompt, payload, rid, True, called)
         created = int(time.time())
         obj = "chat.completion.chunk" if chat else "text_completion"
+
+        def line(choice: dict) -> str:
+            return "data: " + json.dumps({
+                "id": rid, "object": obj, "created": created,
+                "model": self.config.model_id, "choices": [choice],
+            }) + "\n\n"
+
         produced: List[int] = []
         prev_text = ""
+        sent = 0
         for tok in tokens:
             produced.append(tok)
             text = self.engine.tokenizer.decode(produced)
@@ -220,30 +264,29 @@ class LLMServer:
                           "finish_reason": None}
             else:
                 choice = {"index": 0, "text": delta, "finish_reason": None}
-            yield "data: " + json.dumps({
-                "id": rid, "object": obj, "created": created,
-                "model": self.config.model_id, "choices": [choice],
-            }) + "\n\n"
-        # flush anything held back (a genuinely invalid trailing byte in
-        # the final output emits as U+FFFD here, matching non-streaming)
-        tail = self.engine.tokenizer.decode(produced)[len(prev_text):]
-        if tail:
-            tc = ({"index": 0, "delta": {"content": tail},
-                   "finish_reason": None} if chat else
-                  {"index": 0, "text": tail, "finish_reason": None})
-            yield "data: " + json.dumps({
-                "id": rid, "object": obj, "created": created,
-                "model": self.config.model_id, "choices": [tc],
-            }) + "\n\n"
-        ended = finish_reason(tokens.finish_reason)
-        final = ({"index": 0, "delta": {}, "finish_reason": ended}
-                 if chat else
-                 {"index": 0, "text": "", "finish_reason": ended})
-        yield "data: " + json.dumps({
-            "id": rid, "object": obj, "created": created,
-            "model": self.config.model_id, "choices": [final],
-        }) + "\n\n"
-        yield "data: [DONE]\n\n"
+            sent += 1
+            yield line(choice)
+        # The whole answer is here: its last lines are made under
+        # ``llm.done`` and sent after it (no span stays open across a
+        # ``yield``: the next pull may run on another thread).
+        with _done(rid, True, len(produced), tokens.finished_at) as done:
+            closing = []
+            # flush anything held back (a genuinely invalid trailing byte
+            # in the final output emits as U+FFFD here, matching
+            # non-streaming)
+            tail = self.engine.tokenizer.decode(produced)[len(prev_text):]
+            if tail:
+                closing.append(line(
+                    {"index": 0, "delta": {"content": tail},
+                     "finish_reason": None} if chat else
+                    {"index": 0, "text": tail, "finish_reason": None}))
+            ended = finish_reason(tokens.finish_reason)
+            closing.append(line(
+                {"index": 0, "delta": {}, "finish_reason": ended} if chat
+                else {"index": 0, "text": "", "finish_reason": ended}))
+            closing.append("data: [DONE]\n\n")
+            done.set_metadata(chunks=sent + len(closing))
+        yield from closing
 
     def health_check(self) -> bool:
         return True

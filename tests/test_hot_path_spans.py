@@ -23,8 +23,9 @@ _MODEL = dict(
 )
 TICK_CHILDREN = ["engine.tick.pack", "engine.tick.dispatch",
                  "engine.tick.fetch", "engine.tick.sample"]
-ADMIT_CHILDREN = ["engine.prefill.dispatch", "engine.prefill.fetch",
-                  "engine.prefill.sample", "engine.insert"]
+ADMIT_CHILDREN = ["engine.admit.cache", "engine.prefill.dispatch",
+                  "engine.prefill.fetch", "engine.prefill.sample",
+                  "engine.insert"]
 STEP_CHILDREN = ["train.next_batch", "train.dispatch", "train.loss_fetch"]
 
 
@@ -185,7 +186,7 @@ def recording(tmp_path_factory):
         "stats": stats, "plain_stats": plain_stats, "plain": plain,
         "traced": traced, "reports": reports,
         "files_without_capture": files_without_capture,
-        "float32_engine": srv.engine, "bf16_engine": bf16,
+        "float32_engine": srv.engine, "bf16_engine": bf16, "logdir": logdir,
     }
 
 
@@ -224,7 +225,7 @@ def _by_rid(spans, name):
 @pytest.mark.parametrize("name", [
     "engine.tick", *TICK_CHILDREN, "engine.admit", *ADMIT_CHILDREN,
     "engine.finish", "engine.idle", "engine.weights", "llm.request",
-    "train.step", *STEP_CHILDREN, "train.report", "train.checkpoint",
+    "llm.done", "train.step", *STEP_CHILDREN, "train.report", "train.checkpoint",
 ])
 def test_every_span_is_in_the_trace(recording, name):
     assert recording["spans"].named(name), f"no {name} span in the capture"
@@ -357,6 +358,212 @@ def test_counters_equal_the_sums_of_the_spans_arguments(recording):
         assert 0 < f.args["first_token_ms"] <= f.args["total_ms"]
 
 
+def test_every_requests_ledger_closes(recording):
+    """``engine.finish`` says what its request cost beyond its decode ticks:
+    the wait for its admission, the admission, and the admissions of others
+    that held its slot; what is left of ``total_ms`` is ticks and reads."""
+    spans = recording["spans"]
+    admits, finishes = _by_rid(spans, "engine.admit"), _by_rid(
+        spans, "engine.finish")
+    assert len(finishes) == 5
+    for rid, f in finishes.items():
+        a, admit = f.args, admits[rid]
+        # each part is rounded to the microsecond on its own
+        assert a["queued_ms"] + a["admit_ms"] + a["stalled_ms"] <= a[
+            "total_ms"] + 0.002, a
+        assert a["first_token_ms"] == pytest.approx(
+            a["queued_ms"] + a["admit_ms"], abs=1.0)
+        assert a["queued_ms"] == admit.args["queued_ms"]
+        assert a["prompt_tokens"] == admit.args["prompt_tokens"]
+        # its own admission up to its first token: the span, whose clock
+        # starts after the engine's by what the thread waited for the
+        # interpreter in between
+        assert 0 < a["admit_ms"] <= admit.duration_ns / 1e6 + 50
+        assert a["stalled_ms"] >= 0 and a["produced"] >= 1
+    # two slots: an admission holds the other slot or none
+    assert {a.args["held"] for a in admits.values()} == {0, 1}
+
+
+def test_stalled_seconds_equal_both_sums_of_the_spans(recording):
+    """``stalled_s``: an admission's length times the slots it held, summed
+    where ``admit_s`` is; the same time again as the ``stalled_ms`` of the
+    requests that stood still (all five were admitted and finished inside
+    the capture)."""
+    spans, stats = recording["spans"], recording["stats"]
+    admits = spans.named("engine.admit")
+    by_admission = sum(
+        a.duration_ns * a.args["held"] for a in admits) / 1e9
+    held = sum(a.args["held"] for a in admits)
+    assert held >= 1 and stats["stalled_s"] > 0
+    # the host's clock is read just outside the span (see ``admit_s``)
+    assert by_admission - 1e-4 <= stats["stalled_s"] <= (
+        by_admission + 0.05 * held)
+    by_request = sum(
+        f.args["stalled_ms"] for f in spans.named("engine.finish")) / 1e3
+    assert stats["stalled_s"] == pytest.approx(by_request, abs=1e-5)
+    assert stats["stalled_s"] <= stats["admit_s"]  # one other slot at most
+
+
+def test_one_request_and_one_done_a_served_request(recording):
+    spans, traced = recording["spans"], recording["traced"]
+    requests, dones = _by_rid(spans, "llm.request"), _by_rid(
+        spans, "llm.done")
+    finishes = _by_rid(spans, "engine.finish")
+    assert len(spans.named("llm.request")) == len(requests) == 4
+    assert len(spans.named("llm.done")) == len(dones) == 4
+    assert set(requests) == set(dones) <= set(finishes)
+    for rid, done in dones.items():
+        request, finish = requests[rid], finishes[rid]
+        assert done.args["stream"] == request.args["stream"]
+        assert done.args["tokens"] <= request.args["max_tokens"]
+        # ``submit`` takes the engine's lock, which the loop holds for a
+        # whole turn: on a short answer ``llm.request`` may end after the
+        # ``engine.finish``, never begin after it
+        assert request.start_ns <= finish.start_ns <= done.end_ns
+        assert 0 <= request.args["since_call_ms"] < 1000
+        # the finish stamps its time just before its span opens
+        assert 0 <= done.args["after_finish_ms"] <= (
+            done.end_ns - finish.start_ns) / 1e6 + 50
+    assert sorted(r.args["max_tokens"] for r in requests.values()) == [
+        4, 5, 6, 7]  # as asked: "s", "a", "eos", "b"
+    for key in ("a", "b", "eos"):
+        done = dones[traced[key]["id"]].args
+        assert (done["stream"], done["chunks"]) == (0, 1)
+        assert done["tokens"] == traced[key]["usage"]["completion_tokens"]
+    (streamed,) = [d.args for d in dones.values() if d.args["stream"]]
+    # every line the stream sent, ``[DONE]`` among them
+    assert streamed["chunks"] == len(traced["s"])
+    assert traced["s"][-1] == "data: [DONE]\n\n"
+    assert streamed["tokens"] == 4
+
+
+def _mean(values):
+    values = list(values)
+    return sum(values) / len(values)
+
+
+READERS = {
+    "engine.request_ms_per_token": lambda spans: sorted(
+        f.args["total_ms"] / f.args["produced"]
+        for f in spans.named("engine.finish"))[2],  # the median of five
+    "engine.queue_wait_ms": lambda spans: _mean(
+        a.args["queued_ms"] for a in spans.named("engine.admit")),
+    "engine.stalled_share": lambda spans: 100 * sum(
+        f.args["stalled_ms"] for f in spans.named("engine.finish")) / sum(
+        f.args["total_ms"] for f in spans.named("engine.finish")),
+    # off the TPU the capture holds no program of a chip
+    "engine.admit_device_ms": lambda spans: None,
+    "engine.admit_cache_ms": lambda spans: _mean(
+        s.duration_ns for s in spans.named("engine.admit.cache")) / 1e6,
+    "serve.submit_delay_ms": lambda spans: _mean(
+        r.args["since_call_ms"] for r in spans.named("llm.request")),
+    "serve.deliver_ms": lambda spans: _mean(
+        d.args["after_finish_ms"] for d in spans.named("llm.done")),
+}
+
+
+@pytest.mark.parametrize("metric", sorted(READERS))
+def test_a_reader_returns_the_hand_sum_over_the_spans(
+        recording, monkeypatch, metric):
+    """The benchmark's seven readers of the request's ledger
+    (``benchmarks/layer_metrics/<metric>.py``), over the CPU recording."""
+    import json
+
+    from benchmarks import run
+    from benchmarks.lib import host_spans
+
+    with open(os.path.join(run.CHECKOUT, "BENCHMARK.json")) as f:
+        listed = {m["name"]: m for m in json.load(f)["per_layer"]}
+    assert listed[metric]["moves"] == "per_token_p50_ms"
+    assert len(listed[metric]["workloads"]) == 3
+    monkeypatch.setattr(host_spans, "TRACE_ROOT", recording["logdir"])
+    got = run.read_layer_metric(
+        metric, None, {"decode_program": "jit_decode"})
+    want = READERS[metric](recording["spans"])
+    assert got == (None if want is None else pytest.approx(want, rel=1e-9))
+    assert want is None or want > 0 or metric == "engine.stalled_share"
+
+
+def test_a_reader_finds_nothing_where_there_is_no_trace(
+        monkeypatch, tmp_path):
+    from benchmarks import run
+    from benchmarks.lib import host_spans
+
+    monkeypatch.setattr(host_spans, "TRACE_ROOT", str(tmp_path))
+    for metric in READERS:
+        assert run.read_layer_metric(
+            metric, None, {"decode_program": "jit_decode"}) is None
+
+
+def test_an_admissions_device_time_is_its_own_programs():
+    """``request_spans.admit_device_ms`` on numbers small enough to do by
+    hand: the programs whose enqueue starts inside the span, an admission
+    with a program the capture does not hold left out, and a decode program
+    inside an admission an error."""
+    from benchmarks.lib import host_spans as H
+    from benchmarks.lib import request_spans as R
+
+    first = H.Span("engine.admit", 100, 50, {"rid": "a"})
+    second = H.Span("engine.admit", 300, 40, {"rid": "b"})
+    programs = [
+        (90, "jit_decode", 9_000_000),        # before: the tick in flight
+        (110, "jit_broadcast_in_dim", 1_000_000),
+        (120, "jit_prefill", 5_000_000), (149, "jit_insert", 2_000_000),
+        (150, "jit_decode", 9_000_000),       # at the span's end: outside
+        (310, "jit_prefill", 3_000_000), (339, None, 0),
+    ]
+    assert R.admit_device_ms([first], programs, "jit_decode") == 8.0
+    # the second's insert ran after the capture's end: not whole
+    assert R.admit_device_ms([first, second], programs, "jit_decode") == 8.0
+    assert R.admit_device_ms([second], programs, "jit_decode") is None
+    assert R.admit_device_ms([first], [], "jit_decode") is None
+    with pytest.raises(RuntimeError, match="reads the tick in flight"):
+        R.admit_device_ms([first], programs + [(130, "jit_decode(7)", 1)],
+                          "jit_decode")
+
+
+def test_a_recorded_admission_pairs_with_its_programs_by_run_id():
+    """On a capture taken on the chip (PR 24's recording of a toy engine):
+    every admission's programs, by the runtime's ``run_id`` and no shifted
+    clock, held against a walk over the trace written out here."""
+    from jax.profiler import ProfileData
+
+    from benchmarks import run
+    from benchmarks.lib import host_spans as H
+    from benchmarks.lib import request_spans as R
+
+    path = os.path.join(run.BENCH_DIR, "tests", "data",
+                        "v5e_1chip_spans.xplane.pb")
+    admits = H.load(path).named("engine.admit")
+    assert len(admits) == 4
+    ran, enqueues = {}, []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if plane.name == "/device:TPU:0" and (
+                        line.name == "XLA Modules"):
+                    ran[dict(ev.stats)["run_id"]] = (ev.name, ev.duration_ns)
+                elif ev.name == "DoEnqueueProgram":
+                    enqueues.append((ev.start_ns, dict(ev.stats)["run_id"]))
+    per_admit = []
+    for admit in admits:
+        inside = sorted((start, *ran[run_id]) for start, run_id in enqueues
+                        if admit.start_ns <= start < admit.end_ns)
+        names = [name.split("(")[0] for _, name, _ in inside]
+        # two ``zeros``, the prefill; the insert's enqueue may fall just
+        # after the span (the fourth's does)
+        assert names[:7] == [
+            "jit_convert_element_type", "jit_broadcast_in_dim"] * 2 + [
+            "jit_convert_element_type"] * 2 + ["jit_prefill"], names
+        assert names[7:] in ([], ["jit_insert"])
+        per_admit.append(sum(ns for _, _, ns in inside))
+    found = R.enqueued(path)
+    assert [sum(a.start_ns <= start < a.end_ns for start, _, _ in found)
+            for a in admits] == [8, 8, 8, 7]
+    assert R.admit_device_ms(admits, found, "jit_decode") == pytest.approx(
+        sum(per_admit) / 4 / 1e6, rel=1e-12)
+
+
 def test_idle_only_while_nothing_is_active_or_pending(recording):
     spans = recording["spans"]
     idles = spans.named("engine.idle")
@@ -382,8 +589,10 @@ def test_idle_only_while_nothing_is_active_or_pending(recording):
 def test_without_a_capture_no_trace_and_the_same_tokens(recording):
     assert recording["files_without_capture"] == []
     assert _tokens(recording["plain"]) == _tokens(recording["traced"])
-    same = [k for k in recording["stats"]
-            if k not in ("queue_wait_s", "admit_s", "compiles")]
+    # what five threads at once cannot move: how many ticks the answers
+    # took and who shared them follows how the submissions fell
+    same = ["requests", "tokens_generated", "finished_length",
+            "finished_eos", "finished_stop", "finished_context"]
     assert {k: recording["stats"][k] for k in same} == {
         k: recording["plain_stats"][k] for k in same}
     # no thread was started for tracing
