@@ -6,11 +6,16 @@ ours. Design:
 
 - ``attention_xla``: einsum softmax attention. XLA fuses this well on TPU and
   it is the autodiff path.
-- ``flash_attention``: blockwise online-softmax pallas kernel (VMEM-resident
-  q/k/v blocks, f32 accumulators, causal short-circuit per block row).
-  Forward AND backward are pallas (FlashAttention-2-style tiling): the
-  forward saves per-row logsumexp; the backward streams K/V (dq) and Q/dO
-  (dk/dv) blocks and never materializes the [Tq, Tk] score matrix.
+- ``flash_attention``: blockwise online-softmax pallas kernels (VMEM-resident
+  q/k/v blocks, bf16 operands, f32 accumulators and statistics), forward AND
+  backward (FlashAttention-2-style tiling): the forward saves per-row
+  logsumexp; the backward is ONE kernel over key blocks that computes S, P
+  and dP once a block pair (five products) and carries dq in VMEM across the
+  key blocks; the [Tq, Tk] score matrix is never materialized. Both hold
+  scores keys x queries, skip what lies wholly above the diagonal, and mask
+  only the block the diagonal crosses or the padded keys sit in
+  (``flash_block_counts`` says what a call computes). Blocks are chosen from
+  the sequence length; swept on a v5e in PR 37 (see ``DEFAULT_BLOCK_Q``).
 - ``attention``: dispatcher — pallas on TPU, XLA elsewhere; tests run the
   same kernel code on the CPU mesh through ``impl="flash_interpret"``.
 
@@ -19,11 +24,13 @@ Shapes follow [batch, seq, heads, head_dim] throughout.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -66,265 +73,415 @@ def attention_xla(
 
 # --------------------------------------------------------------------- pallas
 
-DEFAULT_BLOCK_Q = 512  # swept on v5e (B=32, T=1024, D=64): 512/512 runs the
-DEFAULT_BLOCK_K = 512  # fwd 23% and fwd+bwd 23% faster than 256/256
+# The largest block a call takes when the caller names none (``_blocks``
+# chooses from the sequence). Swept on a v5e in PR 37 with the kernels as they
+# are below, causal, forward + backward kernel time a call, at the training
+# cells' shapes and at D = 128:
+#   [256, 1024, 64] (one chip):      1024: 1.92 ms   512: 2.32   256: 3.98
+#   [100, 1024, 64] (a chip of four): 1024: 0.75     512: 0.89   256: 1.46
+#   [64, 2048, 128]:                  1024: 1.78     512: 1.99   256: 3.61
+#   [32, 4096, 128]:                  1024: 3.22     512: 3.57
+# (the kernels before PR 37, two backward passes and masks on every block, at
+# their 512: 3.95, 1.53, 3.02, 5.19). At T = 1024 one block is the whole
+# head: every step is known when the kernel is traced and nothing loops.
+DEFAULT_BLOCK_Q = 1024
+DEFAULT_BLOCK_K = 1024
+LANES = 128
+# Queries a step takes at once (same sweep, [256, 1024, 64], kernel ms).
+# Forward: 512 (0.58; 256: 0.75, 1024: 0.70, 128: 1.15): q is the score
+# product's stationary operand, and 512 queries are four tiles of MXU
+# weights, one for each MXU; the 128-wide strip that would leave out more of
+# what lies above the diagonal keeps one MXU of four busy. Backward: 128 on
+# the block the diagonal crosses (1.34; 256: 1.47, 512: 1.62: its five
+# products hold other weights, and skipping pays), 512 below it.
+_FWD_STRIP = 512
+_BWD_STRIP = 128
+_BWD_CHUNK = 512
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, block_k: int,
-                  causal: bool, scale: float, seq_k: int):
+def _pad_to(n: int, block: int) -> int:
+    return block * ((n + block - 1) // block)
+
+
+def _block(seq: int, block: Optional[int], largest: int) -> int:
+    """The block a call runs with: the caller's, cut to the sequence; else
+    ``largest``, a half or a quarter of it, cut to the sequence in whole
+    lanes: the one that pads the sequence least, the larger on a tie."""
+    if block is not None:
+        return min(block, seq)
+    lanes = _pad_to(seq, LANES)
+    return min((min(largest // f, lanes) for f in (1, 2, 4)),
+               key=lambda b: (_pad_to(seq, b), -b))
+
+
+def _blocks(seq_q: int, seq_k: int, block_q: Optional[int],
+            block_k: Optional[int]) -> tuple:
+    return (_block(seq_q, block_q, DEFAULT_BLOCK_Q),
+            _block(seq_k, block_k, DEFAULT_BLOCK_K))
+
+
+def _strip(block: int, want: int) -> int:
+    """Width of the steps a block is walked in: ``want`` where that divides
+    the block, else the block whole."""
+    return want if block % want == 0 else block
+
+
+def _square(seq_q: int, seq_k: int, block_q: int, block_k: int,
+            causal: bool) -> bool:
+    """Causal with equal blocks over equally padded sequences: the one block
+    of a row the diagonal crosses is block ``i == j``, known when the kernel
+    is traced, and is walked in strips of queries, each against the keys at
+    or before its last query alone."""
+    return (causal and block_q == block_k
+            and _pad_to(seq_q, block_q) == _pad_to(seq_k, block_k))
+
+
+def flash_block_counts(seq_q: int, seq_k: int, block_q: Optional[int] = None,
+                       block_k: Optional[int] = None,
+                       causal: bool = True) -> dict:
+    """What the flash kernels do for one head, from the shapes alone: the
+    block pairs they visit, how many of those take the masked path (the
+    diagonal crosses them, or they hold the padded tail of the keys), and
+    the score elements the forward and the backward compute (a block on the
+    diagonal is walked in strips of queries, 512 wide forward and 128
+    backward, and what lies above a strip's last query is left out)."""
+    block_q, block_k = _blocks(seq_q, seq_k, block_q, block_k)
+    n_q = _pad_to(seq_q, block_q) // block_q
+    n_k = _pad_to(seq_k, block_k) // block_k
+    square = _square(seq_q, seq_k, block_q, block_k, causal)
+    visited = masked = 0
+    for i in range(n_q):
+        for j in range(n_k):
+            if causal and j * block_k > (i + 1) * block_q - 1:
+                continue  # wholly above the diagonal
+            visited += 1
+            plain = ((j + 1) * block_k <= seq_k
+                     and (not causal or (j + 1) * block_k - 1 <= i * block_q))
+            masked += not plain
+
+    def elements(want: int) -> int:
+        on_diagonal = block_q * block_k
+        if square:  # every masked block is the diagonal's
+            w = _strip(block_q, want)
+            on_diagonal = sum(w * (c + w) for c in range(0, block_q, w))
+        return (visited - masked) * block_q * block_k + masked * on_diagonal
+
+    return {"visited": visited, "masked": masked,
+            "elements_fwd": elements(_FWD_STRIP),
+            "elements_bwd": elements(_BWD_STRIP)}
+
+
+def _fold_scale(dtype, scale: float) -> bool:
+    """May the softmax scale ride on a [block, D] operand instead of the
+    f32 scores? Where it rounds nothing: a power of two (D = 64: 1/8), or
+    f32 operands."""
+    return dtype == jnp.float32 or math.log2(scale).is_integer()
+
+
+def _keep(shape, *, lead, causal: bool, k_left):
+    """Which scores of a [keys, queries] tile count: key before ``k_left``
+    (None: all are, the tile holds no padded key) and, if causal, not after
+    its query. ``lead``: the first query's position less the first key's."""
+    k_i = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    keep = None
+    if k_left is not None:
+        keep = k_i < k_left
+    if causal:
+        q_i = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        under = (k_i - q_i) <= lead
+        keep = under if keep is None else keep & under
+    return keep
+
+
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_k: int,
+                  strip: int, causal: bool, scale: float, fold: bool,
+                  seq_k: int, square: bool):
     """One (batch*head, q_block) program: stream K/V blocks with online
-    softmax. Block shapes: q/o [1, Bq, D], k/v [1, Tk, D], lse [1, 8, Bq].
-    The logsumexp row statistics (written only when the training path asks
-    for them) feed the pallas backward."""
-    q_idx = pl.program_id(1)
+    softmax. Block shapes: q/o [1, Bq, D], k/v [1, Tk, D], lse [1, 8, Bq]
+    (written only when the training path asks for it: it feeds the backward);
+    scratch m/l [1, Bq], acc [D, Bq]. Scores are held keys x queries
+    ([n, strip]): a query's max and sum reduce along sublanes and broadcast
+    back along them, and q is the product's stationary operand.
+
+    A block wholly under the diagonal and inside the true sequence takes the
+    plain step: no iota, compare or select. Only the block the diagonal
+    crosses, or the one with the padded keys, is masked. Where that block is
+    known when the kernel is traced (``square``) each strip of its queries
+    meets the keys up to the strip's last query in one step, masked on the
+    strip's own keys alone; with one key block in all, that step is the
+    whole softmax and nothing is rescaled."""
+    *lse_ref, m_ref, l_ref, acc_ref = rest
+    qi = pl.program_id(1)
     block_q = q_ref.shape[1]
-    d = q_ref.shape[2]
+    q_off = qi * block_q
     # Operands stay in the input dtype (bf16): the MXU runs low-precision
     # multiplies with f32 accumulation (preferred_element_type) at ~2x the
-    # f32xf32 rate — casting up front would halve kernel throughput. The
-    # scale is applied to the f32 scores, not the bf16 q (no rounding).
+    # f32xf32 rate. The softmax scale rides on q where that rounds nothing
+    # (a power of two, or f32 operands), else on the f32 scores.
     q = q_ref[0]  # [Bq, D]
+    if fold:
+        q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    tail = seq_k % block_k != 0
+    only_block = square and k_ref.shape[1] == block_k
 
-    num_k_blocks = pl.cdiv(seq_k, block_k)
-    if causal:
-        # Highest K block this Q block row can see (short-circuits the rest).
-        last_block = ((q_idx + 1) * block_q - 1) // block_k + 1
-        num_iter = jnp.minimum(num_k_blocks, last_block)
-    else:
-        num_iter = num_k_blocks
+    def scores(k_off, n: int, c: int, lead):
+        """Keys ``k_off:+n`` against queries ``c:+strip`` of the block;
+        ``lead`` as in ``_keep``, None for the plain step."""
+        s = jax.lax.dot_general(k_ref[0, pl.ds(k_off, n), :], q[c:c + strip],
+                                _NT, preferred_element_type=jnp.float32)
+        if not fold:
+            s = s * scale
+        if lead is not None:
+            keep = _keep(s.shape, lead=lead, causal=causal,
+                         k_left=seq_k - k_off if tail else None)
+            s = jnp.where(keep, s, NEG_INF)
+        return s
 
-    def body(i, carry):
-        o_acc, m, l = carry
-        k_blk = k_ref[0, pl.ds(i * block_k, block_k), :]
-        v_blk = v_ref[0, pl.ds(i * block_k, block_k), :]
-        s = jnp.dot(q, k_blk.T,
-                    preferred_element_type=jnp.float32) * scale  # [Bq, Bk]
-        k_pos = i * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        # Inputs are padded to block multiples; mask keys past the true
-        # sequence end so the pad rows never contribute.
-        mask = k_pos < seq_k
+    def update(parts, c: int, first: bool):
+        """Take ``parts`` [(scores, k_off, n)] into the softmax of queries
+        ``c:+strip``; ``first``: nothing was taken before."""
+        cols = slice(c, c + strip)
+        m_new = functools.reduce(jnp.maximum, [
+            jnp.max(s, axis=0, keepdims=True) for s, _, _ in parts])
+        if not first:
+            m_prev = m_ref[:, cols]
+            m_new = jnp.maximum(m_prev, m_new)
+        l_new = acc = 0.0
+        for s, k_off, n in parts:
+            p = jnp.exp(s - m_new)
+            l_new = l_new + jnp.sum(p, axis=0, keepdims=True)
+            v_c = v_ref[0, pl.ds(k_off, n), :]
+            # p in [0, 1]: bf16 rounding is harmless and keeps PV on the fast
+            # MXU path (the f32 accumulator preserves the sum's precision).
+            acc = acc + jax.lax.dot_general(
+                v_c, p.astype(v_c.dtype), _TN,
+                preferred_element_type=jnp.float32)  # [D, strip]
+        if not first:
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = alpha * l_ref[:, cols] + l_new
+            acc = alpha * acc_ref[:, cols] + acc
+        m_ref[:, cols] = m_new
+        l_ref[:, cols] = l_new
+        acc_ref[:, cols] = acc
+
+    def block(masked: bool):
+        def body(j, _):
+            k_off = pl.multiple_of(j * block_k, block_k)
+            for c in range(0, block_q, strip):
+                lead = q_off + c - k_off if masked else None
+                update([(scores(k_off, block_k, c, lead), k_off, block_k)],
+                       c, False)
+        return body
+
+    if not only_block:
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+        n_visit = k_ref.shape[1] // block_k
+        n_plain = seq_k // block_k  # blocks with no padded key
         if causal:
-            q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            mask = mask & (q_pos >= k_pos)
-        s = jnp.where(mask, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        # p in [0, 1]: bf16 rounding is harmless and keeps PV on the fast
-        # MXU path (f32 accumulator preserves the sum's precision).
-        o_new = o_acc * alpha + jnp.dot(
-            p.astype(v_blk.dtype), v_blk, preferred_element_type=jnp.float32
-        )
-        return o_new, m_new, l_new
+            n_plain = jnp.minimum(n_plain, (q_off + 1) // block_k)
+            n_visit = jnp.minimum(n_visit,
+                                  (q_off + block_q - 1) // block_k + 1)
+        jax.lax.fori_loop(0, n_plain, block(False), None)
+        if not square and (causal or tail):
+            jax.lax.fori_loop(n_plain, n_visit, block(True), None)
+    if square:
+        k0 = pl.multiple_of(q_off, block_q)
+        for c in range(0, block_q, strip):
+            if c and not tail:
+                parts = [(scores(k0, c, c, None), k0, c),
+                         (scores(k0 + c, strip, c, 0), k0 + c, strip)]
+            else:
+                parts = [(scores(k0, c + strip, c, c), k0, c + strip)]
+            update(parts, c, only_block)
 
-    o0 = jnp.zeros((block_q, d), jnp.float32)
-    m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    o_acc, m, l = jax.lax.fori_loop(0, num_iter, body, (o0, m0, l0))
-    o_ref[0] = (o_acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-    if lse_ref is not None:
+    l = l_ref[...]
+    o_ref[0] = (acc_ref[...] / l).T.astype(o_ref.dtype)
+    if lse_ref:
         # lse = m + log(l). Stored 8x-replicated on the sublane dim: mosaic
         # requires block shapes (8, 128)-divisible, so a [Bq]-vector per
         # program rides as an [8, Bq] tile (negligible bytes, legal layout).
-        lse = jnp.maximum(m, NEG_INF) + jnp.log(jnp.maximum(l, 1e-30))
-        lse_ref[0] = jnp.broadcast_to(lse[:, 0][None, :], (8, block_q))
+        lse_ref[0][0] = jnp.broadcast_to(m_ref[...] + jnp.log(l),
+                                         (8, block_q))
 
 
-def _flash_fwd_impl(q, k, v, *, causal: bool, block_q: int, block_k: int,
-                    interpret: bool, with_lse: bool = False):
+def _flash_fwd_impl(q, k, v, *, causal: bool, block_q: Optional[int],
+                    block_k: Optional[int], interpret: bool,
+                    with_lse: bool = False):
     B, Tq, H, D = q.shape
     _, Tk, Hkv, _ = k.shape
-    if Hkv != H:
-        k = jnp.repeat(k, H // Hkv, axis=2)
-        v = jnp.repeat(v, H // Hkv, axis=2)
-    block_q = min(block_q, Tq)
-    block_k = min(block_k, Tk)
-    scale = D ** -0.5
+    if H != Hkv:
+        rep = H // Hkv
+        k = jnp.repeat(k, rep, axis=2)
+        v = jnp.repeat(v, rep, axis=2)
+    block_q, block_k = _blocks(Tq, Tk, block_q, block_k)
     # Pad sequences to block multiples: in-kernel dynamic slices on a
     # non-multiple tail would clamp and silently re-read earlier rows.
     # Pad keys are masked in-kernel via seq_k; pad q rows are sliced off.
-    Tq_p = block_q * ((Tq + block_q - 1) // block_q)
-    Tk_p = block_k * ((Tk + block_k - 1) // block_k)
+    Tq_p, Tk_p = _pad_to(Tq, block_q), _pad_to(Tk, block_k)
     if Tq_p != Tq:
         q = jnp.pad(q, ((0, 0), (0, Tq_p - Tq), (0, 0), (0, 0)))
     if Tk_p != Tk:
         k = jnp.pad(k, ((0, 0), (0, Tk_p - Tk), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, Tk_p - Tk), (0, 0), (0, 0)))
-    # Fold batch and heads into the grid's leading dim.
+    scale = D ** -0.5
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, Tq_p, D)
     kf = k.transpose(0, 2, 1, 3).reshape(B * H, Tk_p, D)
     vf = v.transpose(0, 2, 1, 3).reshape(B * H, Tk_p, D)
-    grid = (B * H, Tq_p // block_q)
     kernel = functools.partial(
-        _flash_kernel, block_k=block_k, causal=causal, scale=scale, seq_k=Tk
+        _flash_kernel, block_k=block_k, strip=_strip(block_q, _FWD_STRIP),
+        causal=causal, scale=scale, fold=_fold_scale(q.dtype, scale),
+        seq_k=Tk, square=_square(Tq, Tk, block_q, block_k, causal),
     )
-    in_specs = [
-        pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-        pl.BlockSpec((1, Tk_p, D), lambda b, i: (b, 0, 0)),
-        pl.BlockSpec((1, Tk_p, D), lambda b, i: (b, 0, 0)),
-    ]
-    o_shape = jax.ShapeDtypeStruct((B * H, Tq_p, D), q.dtype)
-    o_spec = pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0))
+    out_shape = [jax.ShapeDtypeStruct((B * H, Tq_p, D), q.dtype)]
+    out_specs = [pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0))]
     if with_lse:
-        out, lse = pl.pallas_call(
-            kernel,
-            out_shape=(
-                o_shape,
-                jax.ShapeDtypeStruct((B * H, 8, Tq_p), jnp.float32),
-            ),
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=(
-                o_spec,
-                pl.BlockSpec((1, 8, block_q), lambda b, i: (b, 0, i)),
-            ),
-            interpret=interpret,
-        )(qf, kf, vf)
-    else:
-        # Inference/no-grad path: skip the LSE output entirely (it would be
-        # pure wasted write bandwidth on every serving forward).
-        out = pl.pallas_call(
-            kernel,
-            out_shape=o_shape,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=o_spec,
-            interpret=interpret,
-        )(qf, kf, vf)
-        lse = None
+        out_shape.append(jax.ShapeDtypeStruct((B * H, 8, Tq_p), jnp.float32))
+        out_specs.append(pl.BlockSpec((1, 8, block_q), lambda b, i: (b, 0, i)))
+    # Without ``with_lse`` (inference, no grad) the LSE output does not
+    # exist: it would be wasted write bandwidth on every forward.
+    out, *lse = pl.pallas_call(
+        kernel,
+        out_shape=out_shape,
+        grid=(B * H, Tq_p // block_q),
+        in_specs=[
+            pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, Tk_p, D), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, Tk_p, D), lambda b, i: (b, 0, 0)),
+        ],
+        out_specs=out_specs,
+        scratch_shapes=[
+            pltpu.VMEM((1, block_q), jnp.float32),  # running max
+            pltpu.VMEM((1, block_q), jnp.float32),  # running sum
+            pltpu.VMEM((D, block_q), jnp.float32),  # o accumulator
+        ],
+        interpret=interpret,
+        name="flash_fwd",
+    )(qf, kf, vf)
     out = out.reshape(B, H, Tq_p, D).transpose(0, 2, 1, 3)
     if Tq_p != Tq:
         out = out[:, :Tq]
     if with_lse:
-        return out, lse  # lse stays in [B*H, Tq_p] layout for the backward
+        return out, lse[0]  # [B*H, 8, Tq_p], the backward's layout
     return out
 
 
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, *, block_q: int, causal: bool,
-                          scale: float, seq_q: int, seq_k: int):
-    """One (batch*head, k_block) program: accumulate dK/dV for this key
-    block by streaming Q/dO blocks. Shapes: k/v/dk/dv [1, Bk, D];
-    q/do [1, Tq, D]; lse/delta [1, 8, Tq] (row 0 is the data; the 8 rows
-    are sublane replication for mosaic's block-shape rules)."""
-    k_idx = pl.program_id(1)
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                      block_q: int, strip: int, chunk: int, causal: bool,
+                      scale: float, fold: bool, seq_k: int, square: bool):
+    """One (batch*head, k_block) program of the ONE backward pass: dK/dV of
+    this key block and this key block's part of dQ, from S, P and dP computed
+    once a block pair (five products). Scores are held keys x queries
+    ([n, width]), so the saved row statistics broadcast along sublanes as
+    they are stored and only dQ's product contracts over rows. Shapes:
+    k/v/dk/dv [1, Bk, D]; q/do/dq [1, Tq, D]; lse/delta [1, 8, Tq] (row 0 is
+    the data; the 8 rows are sublane replication for mosaic's block-shape
+    rules); scratch dq_acc [Tq, D] f32, alive across the key-block axis and
+    written at its last step, dk_acc/dv_acc [Bk, D] f32.
+
+    Padded queries need no mask (their dO and delta are zero and their lse
+    is finite); padded keys and the diagonal do, on the blocks that hold
+    them alone. The block the diagonal crosses, where it is known when the
+    kernel is traced (``square``), is walked in strips of queries, each
+    against the keys at or before its last query."""
+    kj = pl.program_id(1)
+    n_kb = pl.num_programs(1)
     block_k = k_ref.shape[1]
-    d = k_ref.shape[2]
-    # bf16 operands + f32 accumulation on every dot (see _flash_kernel).
+    k_off = kj * block_k
     k = k_ref[0]  # [Bk, D]
     v = v_ref[0]
+    ks = k
+    if fold:
+        ks = (k.astype(jnp.float32) * scale).astype(k.dtype)
+    tail = seq_k % block_k != 0
 
-    num_q_blocks = pl.cdiv(seq_q, block_q)
-    if causal:
-        # Lowest Q block that can see this K block (earlier ones are fully
-        # masked): first q with q_pos >= k_idx*block_k.
-        start = (k_idx * block_k) // block_q
-    else:
-        start = 0
+    @pl.when(kj == 0)
+    def _():
+        dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
 
-    def body(i, carry):
-        dk_acc, dv_acc = carry
-        q_blk = q_ref[0, pl.ds(i * block_q, block_q), :]
-        do_blk = do_ref[0, pl.ds(i * block_q, block_q), :]
-        lse = lse_ref[0, 0, pl.ds(i * block_q, block_q)]
-        delta = delta_ref[0, 0, pl.ds(i * block_q, block_q)]
-        s = scale * jnp.dot(q_blk, k.T, preferred_element_type=jnp.float32)
-        q_pos = i * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        k_pos = k_idx * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        mask = (q_pos < seq_q) & (k_pos < seq_k)
-        if causal:
-            mask = mask & (q_pos >= k_pos)
-        # exp(NEG_INF - lse) underflows to 0 for masked/pad rows; force it
-        # for bit-exact zeros.
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)  # [Bq, Bk]
-        pcast = p.astype(do_blk.dtype)
-        dv_new = dv_acc + jnp.dot(pcast.T, do_blk,
-                                  preferred_element_type=jnp.float32)
-        dp = jnp.dot(do_blk, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
-        dk_new = dk_acc + jnp.dot(ds.astype(q_blk.dtype).T, q_blk,
-                                  preferred_element_type=jnp.float32)
-        return dk_new, dv_new
+    dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
+    dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
 
-    dk0 = jnp.zeros((block_k, d), jnp.float32)
-    dv0 = jnp.zeros((block_k, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(start, num_q_blocks, body, (dk0, dv0))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
-
-
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, *, block_k: int, causal: bool, scale: float,
-                         seq_k: int):
-    """One (batch*head, q_block) program: accumulate dQ for this query block
-    by streaming K/V blocks. Shapes: q/do/dq [1, Bq, D]; k/v [1, Tk, D];
-    lse/delta [1, 8, Bq] (row 0 is the data)."""
-    q_idx = pl.program_id(1)
-    block_q = q_ref.shape[1]
-    d = q_ref.shape[2]
-    # bf16 operands + f32 accumulation on every dot (see _flash_kernel).
-    q = q_ref[0]
-    do = do_ref[0]
-    lse = lse_ref[0, 0]
-    delta = delta_ref[0, 0]
-
-    num_k_blocks = pl.cdiv(seq_k, block_k)
-    if causal:
-        last_block = ((q_idx + 1) * block_q - 1) // block_k + 1
-        num_iter = jnp.minimum(num_k_blocks, last_block)
-    else:
-        num_iter = num_k_blocks
-
-    def body(i, dq_acc):
-        k_blk = k_ref[0, pl.ds(i * block_k, block_k), :]
-        v_blk = v_ref[0, pl.ds(i * block_k, block_k), :]
-        s = scale * jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)
-        q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        k_pos = i * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        mask = k_pos < seq_k
-        if causal:
-            mask = mask & (q_pos >= k_pos)
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
-        dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
-        return dq_acc + jnp.dot(ds.astype(k_blk.dtype), k_blk,
+    def step(q_off, width: int, n: int, lead):
+        """Keys ``:n`` of the block against queries ``q_off:+width``;
+        ``lead`` as in ``_keep``, None for the plain step."""
+        cols = pl.ds(q_off, width)
+        q_c = q_ref[0, cols, :]
+        do_c = do_ref[0, cols, :]
+        s = jax.lax.dot_general(ks[:n], q_c, _NT,
                                 preferred_element_type=jnp.float32)
+        if not fold:
+            s = s * scale
+        p = jnp.exp(s - lse_ref[0, 0:1, cols])
+        if lead is not None:
+            keep = _keep(s.shape, lead=lead, causal=causal,
+                         k_left=seq_k - k_off if tail else None)
+            # exp(NEG_INF - lse) would underflow to 0 anyway; the select
+            # after the exp gives bit-exact zeros.
+            p = jnp.where(keep, p, 0.0)
+        dv_acc[:n, :] += jnp.dot(p.astype(do_c.dtype), do_c,
+                                 preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(v[:n], do_c, _NT,
+                                 preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_ref[0, 0:1, cols])).astype(q_c.dtype)
+        dk_acc[:n, :] += jnp.dot(ds, q_c, preferred_element_type=jnp.float32)
+        dq_acc[cols, :] += jax.lax.dot_general(
+            ds, k[:n], _TN, preferred_element_type=jnp.float32)
 
-    dq = jax.lax.fori_loop(
-        0, num_iter, body, jnp.zeros((block_q, d), jnp.float32)
-    )
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+    def block(masked: bool):
+        def body(i, _):
+            for c in range(0, block_q, chunk):
+                q_off = pl.multiple_of(i * block_q + c, chunk)
+                step(q_off, chunk, block_k,
+                     q_off - k_off if masked else None)
+        return body
+
+    n_q = q_ref.shape[1] // block_q
+    if square:
+        for c in range(0, block_q, strip):
+            step(pl.multiple_of(k_off + c, strip), strip, c + strip, c)
+        jax.lax.fori_loop(kj + 1, n_q, block(False), None)
+    else:
+        start = first_plain = 0
+        if causal:
+            # Lowest Q block that can see this K block, and the lowest one
+            # that sees all of it.
+            start = k_off // block_q
+            first_plain = (k_off + block_k + block_q - 2) // block_q
+        if tail:
+            first_plain = jnp.where(kj == n_kb - 1, n_q, first_plain)
+        if causal or tail:
+            first_plain = jnp.minimum(first_plain, n_q)
+            jax.lax.fori_loop(start, first_plain, block(True), None)
+        jax.lax.fori_loop(first_plain, n_q, block(False), None)
+
+    # dS was left unscaled: the scale goes on the [*, D] results.
+    dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+    dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when(kj == n_kb - 1)
+    def _():
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
-def _flash_bwd_impl(q, k, v, out, lse, g, *, causal: bool, block_q: int,
-                    block_k: int, interpret: bool):
-    """Pallas flash backward: no [Tq, Tk] materialization (reference-free
-    design; same tiling as FlashAttention-2). Returns (dq, dk, dv) with
-    GQA head-group reduction applied."""
+def _flash_bwd_impl(q, k, v, out, lse, g, *, causal: bool,
+                    block_q: Optional[int], block_k: Optional[int],
+                    interpret: bool):
+    """Pallas flash backward, one call: no [Tq, Tk] materialization. Returns
+    (dq, dk, dv) with GQA head-group reduction applied."""
     B, Tq, H, D = q.shape
     _, Tk, Hkv, _ = k.shape
     rep = H // Hkv
     if rep != 1:
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
-    block_q = min(block_q, Tq)
-    block_k = min(block_k, Tk)
+    block_q, block_k = _blocks(Tq, Tk, block_q, block_k)
     scale = D ** -0.5
-    Tq_p = block_q * ((Tq + block_q - 1) // block_q)
-    Tk_p = block_k * ((Tk + block_k - 1) // block_k)
+    Tq_p, Tk_p = _pad_to(Tq, block_q), _pad_to(Tk, block_k)
     if Tq_p != Tq:
         q = jnp.pad(q, ((0, 0), (0, Tq_p - Tq), (0, 0), (0, 0)))
         g = jnp.pad(g, ((0, 0), (0, Tq_p - Tq), (0, 0), (0, 0)))
@@ -342,49 +499,34 @@ def _flash_bwd_impl(q, k, v, out, lse, g, *, causal: bool, block_q: int,
     delta = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32), axis=-1)
     delta = jnp.broadcast_to(delta[:, None, :], (B * H, 8, Tq_p))
 
-    dkv = pl.pallas_call(
+    whole_q = pl.BlockSpec((1, Tq_p, D), lambda b, j: (b, 0, 0))
+    k_block = pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0))
+    stats = pl.BlockSpec((1, 8, Tq_p), lambda b, j: (b, 0, 0))
+    dq, dk, dv = pl.pallas_call(
         functools.partial(
-            _flash_bwd_dkv_kernel, block_q=block_q, causal=causal,
-            scale=scale, seq_q=Tq, seq_k=Tk,
+            _flash_bwd_kernel, block_q=block_q,
+            strip=_strip(block_q, _BWD_STRIP),
+            chunk=_strip(block_q, _BWD_CHUNK), causal=causal, scale=scale,
+            fold=_fold_scale(q.dtype, scale), seq_k=Tk,
+            square=_square(Tq, Tk, block_q, block_k, causal),
         ),
         out_shape=(
+            jax.ShapeDtypeStruct((B * H, Tq_p, D), q.dtype),
             jax.ShapeDtypeStruct((B * H, Tk_p, D), q.dtype),
             jax.ShapeDtypeStruct((B * H, Tk_p, D), q.dtype),
         ),
         grid=(B * H, Tk_p // block_k),
-        in_specs=[
-            pl.BlockSpec((1, Tq_p, D), lambda b, j: (b, 0, 0)),   # q
-            pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),  # k
-            pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),  # v
-            pl.BlockSpec((1, Tq_p, D), lambda b, j: (b, 0, 0)),   # do
-            pl.BlockSpec((1, 8, Tq_p), lambda b, j: (b, 0, 0)),   # lse
-            pl.BlockSpec((1, 8, Tq_p), lambda b, j: (b, 0, 0)),   # delta
+        in_specs=[whole_q, k_block, k_block, whole_q, stats, stats],
+        out_specs=(whole_q, k_block, k_block),
+        scratch_shapes=[
+            pltpu.VMEM((Tq_p, D), jnp.float32),     # dq, across key blocks
+            pltpu.VMEM((block_k, D), jnp.float32),  # dk
+            pltpu.VMEM((block_k, D), jnp.float32),  # dv
         ],
-        out_specs=(
-            pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),
-        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(qf, kf, vf, dof, lse, delta)
-    dk, dv = dkv
-
-    dq = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dq_kernel, block_k=block_k, causal=causal,
-            scale=scale, seq_k=Tk,
-        ),
-        out_shape=jax.ShapeDtypeStruct((B * H, Tq_p, D), q.dtype),
-        grid=(B * H, Tq_p // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),  # q
-            pl.BlockSpec((1, Tk_p, D), lambda b, i: (b, 0, 0)),   # k
-            pl.BlockSpec((1, Tk_p, D), lambda b, i: (b, 0, 0)),   # v
-            pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),  # do
-            pl.BlockSpec((1, 8, block_q), lambda b, i: (b, 0, i)),  # lse
-            pl.BlockSpec((1, 8, block_q), lambda b, i: (b, 0, i)),  # delta
-        ],
-        out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-        interpret=interpret,
+        name="flash_bwd",
     )(qf, kf, vf, dof, lse, delta)
 
     dq = dq.reshape(B, H, Tq_p, D).transpose(0, 2, 1, 3)[:, :Tq]
@@ -398,8 +540,8 @@ def _flash_bwd_impl(q, k, v, out, lse, g, *, causal: bool, block_q: int,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal: bool = True,
-                    block_q: int = DEFAULT_BLOCK_Q,
-                    block_k: int = DEFAULT_BLOCK_K,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     interpret: bool = False):
     """Flash attention: pallas forward AND pallas backward (LSE saved by
     the forward; backward never materializes the [Tq, Tk] score matrix —
@@ -439,7 +581,7 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 def attention(
     q, k, v, *, causal: bool = True, impl: str = "auto",
-    block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K,
+    block_q: Optional[int] = None, block_k: Optional[int] = None,
     mesh=None,
 ):
     """Dispatcher. impl: auto | xla | flash | flash_interpret. ``auto`` is

@@ -5,7 +5,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import attention_xla, flash_attention
+from ray_tpu.ops.attention import (
+    attention_xla, flash_attention, flash_block_counts,
+)
 from ray_tpu.parallel.mesh import MeshConfig
 from ray_tpu.parallel.ring_attention import ring_attention, ulysses_attention
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -98,29 +100,44 @@ def test_gqa_xla():
 
 @pytest.mark.parametrize("causal", [True, False])
 @pytest.mark.parametrize("T", [96, 1000])
-def test_flash_ragged_seq_len(causal, T):
+@pytest.mark.parametrize("block", [64, None], ids=["b64", "chosen"])
+def test_flash_ragged_seq_len(causal, T, block):
     """Seq lengths not divisible by the block size (regression: the kernel's
-    clamped dynamic slice silently re-read earlier K rows)."""
+    clamped dynamic slice silently re-read earlier K rows), at a given block
+    and at the one the kernel chooses from the length."""
     q, k, v = _make_qkv(B=1, T=T, H=2, D=16)
-    out = flash_attention(q, k, v, causal, 64, 64, True)
+    out = flash_attention(q, k, v, causal, block, block, True)
     ref = attention_xla(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
 
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_bwd_kernel_gqa_and_ragged(causal):
-    """Pallas backward kernel: GQA head-group reduction + pad-row masking
-    (q rows past seq end must contribute nothing to dk/dv)."""
+@pytest.mark.parametrize("T,block,D,H,Hkv", [
+    (100, 64, 32, 4, 2),       # ragged, GQA, small blocks
+    (1024, 512, 64, 2, 2),     # two blocks a side, the diagonal crossed twice
+    (1024, 256, 64, 2, 1),     # plain blocks under the diagonal, GQA
+    (1024, None, 64, 1, 1),    # the training cells' call: one block, strips
+    (1536, 512, 128, 1, 1),    # three blocks, D = 128 (scale not folded)
+    (1536, 256, 64, 2, 1),
+    (1000, 256, 64, 2, 1),     # ragged: the padded keys in the last block
+    (1000, None, 128, 1, 1),
+], ids=str)
+def test_flash_bwd_kernel_gqa_and_ragged(causal, T, block, D, H, Hkv):
+    """The ONE Pallas backward kernel (dq, dk, dv from one pass): GQA
+    head-group reduction, pad-row masking (q rows past seq end must
+    contribute nothing to dk/dv), several blocks with a crossed diagonal,
+    the blocks the kernel chooses itself."""
     key = jax.random.PRNGKey(3)
     ks = jax.random.split(key, 4)
-    B, T, H, Hkv, D = 1, 100, 4, 2, 32
+    B = 1
     q = jax.random.normal(ks[0], (B, T, H, D), jnp.float32)
     k = jax.random.normal(ks[1], (B, T, Hkv, D), jnp.float32)
     v = jax.random.normal(ks[2], (B, T, Hkv, D), jnp.float32)
     g = jax.random.normal(ks[3], (B, T, H, D), jnp.float32)
 
     def loss_flash(q, k, v):
-        return jnp.vdot(flash_attention(q, k, v, causal, 64, 64, True), g)
+        return jnp.vdot(flash_attention(q, k, v, causal, block, block, True),
+                        g)
 
     def loss_xla(q, k, v):
         return jnp.vdot(attention_xla(q, k, v, causal=causal), g)
@@ -129,6 +146,28 @@ def test_flash_bwd_kernel_gqa_and_ragged(causal):
     g2 = jax.grad(loss_xla, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize("T,block,causal,visited,masked,fwd,bwd", [
+    # T = 1024 causal: 0.5625 T^2 in the backward (128-wide strips of the
+    # diagonal block), 0.75 T^2 in the forward (512-wide), whatever the block
+    (1024, None, True, 1, 1, 0.75, 0.5625),
+    (1024, 512, True, 3, 2, 0.75, 0.5625),
+    (1024, 256, True, 10, 4, 0.625, 0.5625),
+    (1024, 512, False, 4, 0, 1.0, 1.0),
+    # ragged: padded to 1024; not causal, the last key block alone is masked
+    (1000, None, True, 1, 1, 0.75, 0.5625),
+    (1000, 256, False, 16, 4, 1.0, 1.0),
+    (1536, 512, True, 6, 3, 1.5, 1.21875),
+], ids=str)
+def test_flash_block_counts(T, block, causal, visited, masked, fwd, bwd):
+    """The work the kernels do, from the shapes alone: block pairs visited,
+    those on the masked path (the diagonal's, or the padded tail's), and the
+    score elements computed, as shares of 1024^2."""
+    got = flash_block_counts(T, T, block, block, causal)
+    assert got == {"visited": visited, "masked": masked,
+                   "elements_fwd": int(fwd * 1024 ** 2),
+                   "elements_bwd": int(bwd * 1024 ** 2)}
 
 
 def test_auto_is_decided_by_platform_and_never_falls_back(monkeypatch):

@@ -52,7 +52,7 @@ def one_chip(topo):
 
 
 def _flash(q, k, v):
-    return flash_attention(q, k, v, True, 512, 512, False)
+    return flash_attention(q, k, v, True)  # blocks: the kernel's choice
 
 
 def _flash_grads(q, k, v):
@@ -67,12 +67,37 @@ WIDTHS = [(32, 1024, 12, 64), (16, 1024, 16, 64), (4, 2048, 16, 128)]
 
 
 @pytest.mark.parametrize("shape", WIDTHS, ids=str)
-@pytest.mark.parametrize("fn,kernels", [(_flash, 1), (_flash_grads, 3)],
+@pytest.mark.parametrize("fn,kernels", [(_flash, 1), (_flash_grads, 2)],
                          ids=["fwd", "fwd_bwd"])
 def test_flash_attention_compiles_for_v5e(one_chip, shape, fn, kernels):
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
     compiled = jax.jit(fn).lower(x, x, x).compile()
     assert compiled.as_text().count("tpu_custom_call") == kernels
+
+
+@pytest.mark.parametrize("blocks", [(None, None), (512, 512), (256, 256)],
+                         ids=str)
+def test_flash_calls_are_what_the_roofline_reader_looks_for(one_chip, blocks):
+    """``benchmarks/lib/kernels.py`` knows the flash kernels by their call
+    alone: three operands is the forward, four or more the backward (ONE
+    call), and every Pallas call's first result is [batch x heads, seq, head
+    size]. A change that would silence the rooflines fails here, not on the
+    chip."""
+    from benchmarks.lib import kernels, trace
+
+    B, T, H, D = WIDTHS[1]
+    x = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16, sharding=one_chip)
+
+    def grads(q, k, v):
+        return jax.grad(
+            lambda q, k, v: flash_attention(q, k, v, True, *blocks)
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(grads).lower(x, x, x).compile().as_text()
+    calls = [line for line in text.splitlines() if trace.is_kernel(line)]
+    assert sorted(trace.operand_count(c) for c in calls) == [3, 6]
+    for call in calls:
+        assert kernels.FIRST_RESULT.search(call).group(1) == f"{B * H},{T},{D}"
 
 
 def test_flash_attention_compiles_inside_a_sharded_jit(topo):
