@@ -21,7 +21,7 @@ class LLMConfig:
     # to a pickled {"family": ..., "config": config kwargs, "params": pytree}
     # bundle ("family" defaults to gpt2 for old bundles).
     model_source: Optional[str] = None
-    model_family: str = "gpt2"  # "gpt2" | "llama" | "afmoe"
+    model_family: str = "gpt2"  # a name of ``models.FAMILIES``
     vocab_size: int = 512
     max_seq_len: int = 1024
     num_layers: int = 4
@@ -45,6 +45,8 @@ class LLMConfig:
     layer_types: Optional[Any] = None
     sliding_window: Optional[int] = None
     mup_enabled: Optional[bool] = None   # afmoe: embedding x sqrt(embed_dim)
+    # smallthinker: 1 a layer with the window (and RoPE), 0 a global one
+    sliding_window_layout: Optional[Any] = None
     # dtype the weights are made (fresh) or loaded (a bundle) in; None = the
     # family's (float32). What a replica HOLDS follows from it and ``dtype``:
     # the engine keeps each weight its family's forward rounds to ``dtype``
@@ -70,6 +72,11 @@ class LLMConfig:
     moe_score_func: Optional[str] = None
     moe_route_scale: Optional[float] = None
     moe_expert_bias_init_std: Optional[float] = None
+    # This replica's share of every layer's experts: ``moe_num_held`` of the
+    # ``moe_num_experts`` the router scores, from ``moe_first_held`` on (one
+    # chip of an expert-parallel group; ``MoEConfig.num_held``). None = all.
+    moe_num_held: Optional[int] = None
+    moe_first_held: Optional[int] = None
 
     # Engine knobs (reference: engine_kwargs tensor_parallel_size etc.)
     max_batch_slots: int = 8
@@ -113,7 +120,8 @@ class LLMConfig:
         for name in ("num_kv_heads", "mlp_dim", "rope_theta", "rms_eps",
                      "qk_norm", "param_dtype", "head_dim", "moe_mlp_dim",
                      "num_dense_layers", "num_shared_experts", "layer_types",
-                     "sliding_window", "mup_enabled"):
+                     "sliding_window", "mup_enabled",
+                     "sliding_window_layout"):
             if getattr(self, name) is not None:
                 kwargs[name] = getattr(self, name)
         if self.moe_num_experts:
@@ -130,7 +138,9 @@ class LLMConfig:
                     (self.moe_router_init_std, "router_init_std"),
                     (self.moe_score_func, "score_func"),
                     (self.moe_route_scale, "route_scale"),
-                    (self.moe_expert_bias_init_std, "expert_bias_init_std")):
+                    (self.moe_expert_bias_init_std, "expert_bias_init_std"),
+                    (self.moe_num_held, "num_held"),
+                    (self.moe_first_held, "first_held")):
                 if stated is not None:
                     kwargs["moe"][name] = stated
             kwargs["moe"]["expert_bias"] = (
@@ -140,8 +150,9 @@ class LLMConfig:
     def to_dict(self) -> dict:
         d = dict(self.__dict__)
         d["prefill_buckets"] = list(self.prefill_buckets)
-        if isinstance(self.layer_types, tuple):
-            d["layer_types"] = list(self.layer_types)
+        for key in ("layer_types", "sliding_window_layout"):
+            if isinstance(d[key], tuple):
+                d[key] = list(d[key])
         return d
 
     @classmethod

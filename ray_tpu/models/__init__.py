@@ -29,6 +29,7 @@ FAMILIES = {
     "gpt2": "ray_tpu.models.gpt2",
     "llama": "ray_tpu.models.llama",
     "afmoe": "ray_tpu.models.afmoe",
+    "smallthinker": "ray_tpu.models.smallthinker",
 }
 
 
@@ -49,12 +50,27 @@ def module_for(config: Any):
     raise TypeError(f"unknown model config type: {type(config).__name__}")
 
 
+# a router's numbers under the flat names a configuration file and
+# ``LLMConfig`` give them -> the ``MoEConfig`` field each one is
+MOE_KEYS = {
+    "moe_num_experts": "num_experts", "moe_top_k": "top_k",
+    "moe_norm_topk_prob": "norm_topk_prob",
+    "moe_router_init_std": "router_init_std",
+    "moe_score_func": "score_func", "moe_route_scale": "route_scale",
+    "moe_expert_bias_init_std": "expert_bias_init_std",
+    "moe_num_held": "num_held", "moe_first_held": "first_held",
+    "moe_dropless": "dropless",
+}
+
+
 def config_for(family: str, **kwargs):
     """The ``family``'s own config object from keyword arguments as a file or
     a bundle states them: ``dtype`` / ``param_dtype`` may be names
     ("bfloat16"), ``moe`` a dictionary of ``MoEConfig`` fields (experts with
-    no ``activation`` stated get the family's). A keyword the family's
-    config does not take is its ``TypeError``, by that name."""
+    no ``activation`` stated get the family's), or the router's numbers flat
+    (``MOE_KEYS``; ``moe_num_experts`` 0 is a dense model; a stated
+    ``moe_expert_bias_init_std`` is a router with ``expert_bias``). A keyword
+    the family's config does not take is its ``TypeError``, by that name."""
     import jax.numpy as jnp
 
     from ray_tpu.parallel.moe import MoEConfig
@@ -63,6 +79,11 @@ def config_for(family: str, **kwargs):
     for key in ("dtype", "param_dtype"):
         if isinstance(kwargs.get(key), str):
             kwargs[key] = jnp.dtype(kwargs[key]).type
+    flat = {MOE_KEYS[key]: kwargs.pop(key) for key in list(kwargs)
+            if key in MOE_KEYS}
+    if flat.get("num_experts"):
+        flat["expert_bias"] = "expert_bias_init_std" in flat
+        kwargs["moe"] = {**flat, **(kwargs.get("moe") or {})}
     if isinstance(kwargs.get("moe"), dict):
         kwargs["moe"] = MoEConfig(
             **{"activation": module.EXPERT_ACTIVATION, **kwargs["moe"]})

@@ -315,7 +315,13 @@ def attn_out(config: AfmoeConfig, layer, x, attn):
     return x + _rms_norm(out, layer["post_attn_norm"], config.rms_eps)
 
 
-def ffn(config: AfmoeConfig, kind, layer, x, rng, row_mask, stacked):
+def at_input(config: AfmoeConfig, kind, layer, x, stacked):
+    """Nothing of a block's input is kept for its feed-forward."""
+    return None
+
+
+def ffn(config: AfmoeConfig, kind, layer, x, rng, row_mask, stacked,
+        from_input=None):
     """pre_mlp_norm, the dense MLP or the routed experts beside the shared
     one, post_mlp_norm on the sum, residual -> (x, aux loss, experts that
     received a row)."""
@@ -323,7 +329,7 @@ def ffn(config: AfmoeConfig, kind, layer, x, rng, row_mask, stacked):
     aux, touched = jnp.float32(0.0), jnp.int32(0)
     if kind.endswith("routed"):
         moe, index = stacked
-        if not config.moe.dropless:
+        if not config.moe.dropless and index is not None:
             # capacity queues (training) take a layer's own weights
             moe, index = jax.tree.map(lambda w: w[index], moe), None
         y, aux, touched = moe_layer_counted(

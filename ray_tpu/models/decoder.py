@@ -18,11 +18,17 @@ one signature across families:
   [B, T, KV, G, D] where G query heads share a kv head;
 - ``attn_out(config, layer, x, attn)`` -> the stream after the output
   projection and the residual;
-- ``ffn(config, kind, layer, x, rng, row_mask, stacked)`` -> (stream, aux
-  loss, experts that received a row: 0 for a dense feed-forward). Only a
-  router asks for the last three: ``rng`` its jitter, ``row_mask`` [B, T] the
-  rows that carry a token, ``stacked`` (every routed layer's expert weights,
-  this layer's index among them) where they are kept out of the layer scan;
+- ``at_input(config, kind, layer, x, stacked)`` -> whatever the family's
+  ``ffn`` wants of the block's INPUT, the stream before the attention's norm
+  (a router that reads it gives its logits), or None;
+- ``ffn(config, kind, layer, x, rng, row_mask, stacked, from_input)`` ->
+  (stream, aux loss, experts that received a row: 0 for a dense
+  feed-forward). Only a router asks for the last four: ``rng`` its jitter,
+  ``row_mask`` [B, T] the rows that carry a token, ``stacked`` (every routed
+  layer's expert weights, this layer's index among them) where they are kept
+  out of the layer scan, ``from_input`` what ``at_input`` gave. The aux loss
+  is a scalar, or for a layer that holds a share of the experts the loss
+  beside its counts of rows (``moe.aux_zero``): the stack adds up either;
 - ``final_norm(config, params, x)``, ``head(config, params, x)`` -> float32
   logits, ``head_weight(params)`` -> the [V, E] matrix the chunked
   cross-entropy multiplies by;
@@ -38,10 +44,10 @@ one signature across families:
   was initialised;
 - ``config.num_kv_heads``.
 
-What follows from shapes alone is decided here: a grouped q is flattened and
-k and v repeated G times for the full forward, the cache is attended with q
-as it came (``kv_cache.attend`` takes either), and the output projection
-gets [B, T, H, D] from both.
+What follows from shapes alone is decided here: a grouped q is flattened for
+the full forward (the attention takes k and v with the kv heads they have),
+the cache is attended with q as it came (``kv_cache.attend`` takes either),
+and the output projection gets [B, T, H, D] from both.
 
 Layers are stacked into scanned super-layers (``lax.scan`` over depth:
 O(1) compile time in depth, and the layout the "stage" mesh axis splits),
@@ -64,7 +70,7 @@ from jax.sharding import Mesh
 
 from ray_tpu.models import kv_cache, module_for
 from ray_tpu.ops.attention import attention
-from ray_tpu.parallel.moe import stacked_for
+from ray_tpu.parallel.moe import aux_loss_of, aux_zero, stacked_for
 
 
 
@@ -138,19 +144,24 @@ def _remat_policy(config):
     )
 
 
-def _attention_dispatch(config, q, k, v, mesh: Optional[Mesh]):
+def _attention_dispatch(config, q, k, v, mesh: Optional[Mesh],
+                        window: Optional[int] = None):
     """Adds the mesh-aware ring/ulysses branches on top of the shared
-    single-device dispatcher (``ops.attention.attention``)."""
+    single-device dispatcher (``ops.attention.attention``). q [B, T, H, D],
+    k and v [B, T, KV, D]; ``window``: of a layer whose attention has one."""
     impl = config.attention_impl
-    if impl == "ring":
-        from ray_tpu.parallel.ring_attention import ring_attention
+    if impl in ("ring", "ulysses"):
+        from ray_tpu.parallel import ring_attention
 
-        return ring_attention(q, k, v, mesh=mesh, axis=config.seq_axis, causal=True)
-    if impl == "ulysses":
-        from ray_tpu.parallel.ring_attention import ulysses_attention
-
-        return ulysses_attention(q, k, v, mesh=mesh, axis=config.seq_axis, causal=True)
-    return attention(q, k, v, causal=True, impl=impl, mesh=mesh)
+        if window is not None:
+            raise ValueError(f"attention_impl {impl!r} has no window")
+        rep = q.shape[2] // k.shape[2]
+        return {"ring": ring_attention.ring_attention,
+                "ulysses": ring_attention.ulysses_attention}[impl](
+            q, _repeat_kv(k, rep), _repeat_kv(v, rep), mesh=mesh,
+            axis=config.seq_axis, causal=True)
+    return attention(q, k, v, causal=True, impl=impl, mesh=mesh,
+                     window=window)
 
 
 def _repeat_kv(x: jax.Array, n: int) -> jax.Array:
@@ -165,8 +176,9 @@ def _repeat_kv(x: jax.Array, n: int) -> jax.Array:
 
 def _window_attention(q, k, v, window: int):
     """Causal attention over the token itself and the ``window - 1`` before
-    it, [B, T, H, D] each: a band mask in plain XLA (the full forward of a
-    model with window layers is its tests' and its loss's)."""
+    it, [B, T, H, D] each: a band mask over whole [B, H, T, T] scores in
+    plain XLA. What the kernels' window is held to (``tests/``); the layer
+    stack goes through ``_attention_dispatch``."""
     i = jnp.arange(q.shape[1])
     band = (i[None, :] <= i[:, None]) & (i[None, :] > i[:, None] - window)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
@@ -175,27 +187,34 @@ def _window_attention(q, k, v, window: int):
     return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), v)
 
 
-def _body(config, mesh: Optional[Mesh], pos, kind: Layer = Layer()):
+def _body(config, mesh: Optional[Mesh], pos, kind: Layer = Layer(),
+          own: Optional[int] = None):
     """One decoder block of ``kind`` as a layer scan calls it, remat applied:
     ``(x [B, T, E], layer, rng=None, stacked=None) -> (x, aux)``. layer: one
     slice of the stacked block params, pos: [B, T] absolute. ``rng``
     (optional) feeds MoE router jitter; ``stacked`` is the experts' stack and
-    this layer's index in it, where the family keeps them out of the scan."""
+    this layer's index in it, where the family keeps them out of the scan.
+    ``own``: that index where it is known as the program is traced (a
+    segment that is not scanned). The block then cuts the layer's experts
+    out of the stack itself, inside the remat, and hands the family
+    ``(the layer's own weights, None)``: their gradient is the layer's, not
+    a stack of zeros around it (four layers' worth of those were 2.9 GB at
+    16 experts of 2560 x 768), and the cut is made again in the backward
+    pass instead of being kept."""
     family = module_for(config)
 
     def block(x, layer, rng=None, stacked=None):
+        if own is not None:
+            stacked = (jax.tree.map(lambda w: w[own], stacked[0]), None)
+        from_input = family.at_input(config, kind.name, layer, x, stacked)
         q, k, v = family.qkv(config, kind.name, layer, x, pos)
         if q.ndim == 5:  # [B, T, KV, G, D]: G query heads share a kv head
-            k, v = _repeat_kv(k, q.shape[3]), _repeat_kv(v, q.shape[3])
             q = q.reshape(*q.shape[:2], -1, q.shape[-1])
-        if kind.window is None:
-            attn = _attention_dispatch(config, q, k, v, mesh)
-        else:
-            attn = _window_attention(q, k, v, kind.window)
+        attn = _attention_dispatch(config, q, k, v, mesh, kind.window)
         x = family.attn_out(config, layer, x, attn)
         x, aux, _ = family.ffn(
             config, kind.name, layer, x, rng=rng, row_mask=None,
-            stacked=stacked)
+            stacked=stacked, from_input=from_input)
         return x, aux
 
     if config.remat:
@@ -255,7 +274,8 @@ def forward_features(
     mesh: Optional[Mesh] = None,
     rng: Optional[jax.Array] = None,
 ) -> tuple:
-    """tokens [B, T] int32 → (final-trunk features [B, T, E], aux loss).
+    """tokens [B, T] int32 → (final-trunk features [B, T, E], aux loss: the
+    layers' summed, in the form their ``ffn`` gives it).
     The loss path consumes features directly (vocab-chunked cross entropy,
     ``ops/xent.py``) so the [B, T, V] logits tensor never materializes.
     ``rng``: optional key enabling stochastic layers (MoE router jitter),
@@ -263,16 +283,23 @@ def forward_features(
     x, pos = _embed(params, tokens, config)
     segments, experts = module_for(config).layers(
         config, params["blocks"], cached=False)
-    if experts is not None:  # a stack of their own: cast once, not a layer
-        experts = stacked_for(experts, config.dtype)
     keys = None if rng is None else jax.random.split(rng, config.num_layers)
-    aux = jnp.float32(0.0)
+    aux = aux_zero(config.moe)
     routed_at = _places(
         segments, lambda kind: "experts" if kind.routed and experts is not None
         else None)
+    scanned = None
+    if experts is not None and any(s.repeats > 1 for s in segments):
+        # a scan reads the stack by a traced index: cast once, not a layer
+        scanned = stacked_for(experts, config.dtype)
     layers_before = 0
     for (kinds, layers, repeats), routed in zip(segments, routed_at):
-        bodies = [_body(config, mesh, pos, kind) for kind in kinds]
+        # one repeat is no scan: every layer's place in the stack is known
+        bodies = [
+            _body(config, mesh, pos, kind,
+                  own=place[1] if repeats == 1 and place else None)
+            for kind, place in zip(kinds, routed)]
+        stack = experts if repeats == 1 else scanned
         xs = (layers,)
         if keys is not None:
             n = repeats * len(kinds)
@@ -280,14 +307,14 @@ def forward_features(
                 repeats, len(kinds), *keys.shape[1:]),)
             layers_before += n
 
-        def period(carry, xs, bodies=bodies, routed=routed):
+        def period(carry, xs, bodies=bodies, routed=routed, stack=stack):
             x, aux, repeat = carry
             layers, *rngs = xs
             for j, body in enumerate(bodies):
-                stacked = routed[j] and (experts, _nth(repeat, *routed[j]))
+                stacked = routed[j] and (stack, _nth(repeat, *routed[j]))
                 x, layer_aux = body(
                     x, layers[j], rngs[0][j] if rngs else None, stacked)
-                aux = aux + layer_aux
+                aux = jax.tree.map(jnp.add, aux, layer_aux)
             return (x, aux, repeat + 1), None
 
         (x, aux, _), _ = _scanned(period, (x, aux, 0), xs, repeats)
@@ -378,6 +405,7 @@ def forward_cached(
         experts = stacked_for(experts, config.dtype)
 
     def block(kind, x, layer, index, stacked, cache):
+        from_input = family.at_input(config, kind.name, layer, x, stacked)
         q, k_new, v_new = family.qkv(config, kind.name, layer, x, pos)
         # the cache is attended as the family groups its heads (GQA: the
         # query heads of a kv head together); the projection takes them flat
@@ -387,7 +415,7 @@ def forward_cached(
             config, layer, x, attn.reshape(B, T, -1, attn.shape[-1]))
         x, _, touched = family.ffn(
             config, kind.name, layer, x, rng=None, row_mask=mask,
-            stacked=stacked)
+            stacked=stacked, from_input=from_input)
         return x, cache, touched
 
     cache_at = _places(
@@ -435,10 +463,13 @@ def loss_fn(
     mesh: Optional[Mesh] = None,
     pipeline_microbatches: Optional[int] = None,
     rng: Optional[jax.Array] = None,
+    parts: bool = False,
 ) -> jax.Array:
-    """Next-token cross entropy. batch: {"tokens": [B, T+1]} or
-    {"inputs": [B,T], "targets": [B,T]}. ``rng`` feeds MoE router jitter
-    (unpipelined path only)."""
+    """Next-token cross entropy plus the layers' auxiliary loss. batch:
+    {"tokens": [B, T+1]} or {"inputs": [B,T], "targets": [B,T]}. ``rng``
+    feeds MoE router jitter (unpipelined path only). ``parts``: the cross
+    entropy and what the layers added up beside it (``forward_features``),
+    apart, for a caller that reports them apart."""
     if "tokens" in batch:
         inputs = batch["tokens"][:, :-1]
         targets = batch["tokens"][:, 1:]
@@ -457,9 +488,10 @@ def loss_fn(
     from ray_tpu.ops.xent import chunked_softmax_xent
 
     x, aux = forward_features(params, inputs, config, mesh, rng=rng)
-    return chunked_softmax_xent(
+    xent = chunked_softmax_xent(
         x, module_for(config).head_weight(params), targets, batch.get("mask")
-    ) + aux
+    )
+    return (xent, aux) if parts else xent + aux_loss_of(aux)
 
 
 def count_params(params) -> int:

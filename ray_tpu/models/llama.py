@@ -273,7 +273,13 @@ def attn_out(config: LlamaConfig, layer, x, attn):
                           layer["wo"].astype(attn.dtype))
 
 
-def ffn(config: LlamaConfig, kind, layer, x, rng, row_mask, stacked):
+def at_input(config: LlamaConfig, kind, layer, x, stacked):
+    """Nothing of a block's input is kept for its feed-forward."""
+    return None
+
+
+def ffn(config: LlamaConfig, kind, layer, x, rng, row_mask, stacked,
+        from_input=None):
     """mlp_norm + SwiGLU MLP (or routed experts) + residual -> (x, aux_loss,
     experts that received a row: 0 for the dense MLP)."""
     h = _rms_norm(x, layer["mlp_norm"], config.rms_eps, config.dtype)

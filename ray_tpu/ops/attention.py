@@ -16,6 +16,12 @@ ours. Design:
   only the block the diagonal crosses or the padded keys sit in
   (``flash_block_counts`` says what a call computes). Blocks are chosen from
   the sequence length; swept on a v5e in PR 37 (see ``DEFAULT_BLOCK_Q``).
+  With a ``window`` (causal only) a query sees itself and the ``window - 1``
+  positions before it: a query block visits the key blocks from the
+  window's edge to the diagonal and no others, and the edge is one more
+  boundary that is masked on the blocks it crosses alone. Query heads that
+  share a kv head read that head's k and v where they lie (a block index,
+  no copy).
 - ``attention``: dispatcher — pallas on TPU, XLA elsewhere; tests run the
   same kernel code on the CPU mesh through ``impl="flash_interpret"``.
 
@@ -44,8 +50,11 @@ def attention_xla(
     bias: Optional[jax.Array] = None,
     q_offset: int | jax.Array = 0,
     kv_offset: int | jax.Array = 0,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Dense attention. q: [B, Tq, H, D]; k/v: [B, Tk, Hkv, D].
+    ``window`` (causal only): a query sees itself and the ``window - 1``
+    positions before it, a band mask.
 
     Supports grouped-query attention (H a multiple of Hkv) and absolute
     position offsets so callers holding only a chunk of the sequence (ring /
@@ -66,6 +75,8 @@ def attention_xla(
         q_pos = jax.lax.broadcasted_iota(jnp.int32, (Tq, Tk), 0) + q_offset
         k_pos = jax.lax.broadcasted_iota(jnp.int32, (Tq, Tk), 1) + kv_offset
         mask = q_pos >= k_pos
+        if window is not None:
+            mask &= k_pos > q_pos - window
         logits = jnp.where(mask[None, None], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
@@ -129,44 +140,65 @@ def _strip(block: int, want: int) -> int:
 
 
 def _square(seq_q: int, seq_k: int, block_q: int, block_k: int,
-            causal: bool) -> bool:
+            causal: bool, window: Optional[int] = None) -> bool:
     """Causal with equal blocks over equally padded sequences: the one block
     of a row the diagonal crosses is block ``i == j``, known when the kernel
     is traced, and is walked in strips of queries, each against the keys at
-    or before its last query alone."""
+    or before its last query alone. A window shorter than the block would
+    cut into that block too: it is then masked like any other."""
     return (causal and block_q == block_k
-            and _pad_to(seq_q, block_q) == _pad_to(seq_k, block_k))
+            and _pad_to(seq_q, block_q) == _pad_to(seq_k, block_k)
+            and (window is None or window >= block_q))
+
+
+def _window_of(window: Optional[int], causal: bool, seq_k: int):
+    """The window a call runs with: None where it leaves no key out."""
+    if window is None:
+        return None
+    if not causal or window < 1:
+        raise ValueError(f"a window ({window}) is causal and at least 1")
+    return window if window < seq_k else None
 
 
 def flash_block_counts(seq_q: int, seq_k: int, block_q: Optional[int] = None,
                        block_k: Optional[int] = None,
-                       causal: bool = True) -> dict:
+                       causal: bool = True,
+                       window: Optional[int] = None) -> dict:
     """What the flash kernels do for one head, from the shapes alone: the
     block pairs they visit, how many of those take the masked path (the
     diagonal crosses them, or they hold the padded tail of the keys), and
     the score elements the forward and the backward compute (a block on the
     diagonal is walked in strips of queries, 512 wide forward and 128
-    backward, and what lies above a strip's last query is left out)."""
+    backward, and what lies above a strip's last query is left out). With a
+    ``window`` the blocks wholly before it are not visited and the blocks
+    its edge crosses are masked, whole."""
     block_q, block_k = _blocks(seq_q, seq_k, block_q, block_k)
+    window = _window_of(window, causal, seq_k)
     n_q = _pad_to(seq_q, block_q) // block_q
     n_k = _pad_to(seq_k, block_k) // block_k
-    square = _square(seq_q, seq_k, block_q, block_k, causal)
-    visited = masked = 0
+    square = _square(seq_q, seq_k, block_q, block_k, causal, window)
+    visited = masked = on_diagonal = 0
     for i in range(n_q):
         for j in range(n_k):
             if causal and j * block_k > (i + 1) * block_q - 1:
                 continue  # wholly above the diagonal
+            if window is not None and (j + 1) * block_k <= i * block_q - window + 1:
+                continue  # wholly before the first query's window
             visited += 1
             plain = ((j + 1) * block_k <= seq_k
-                     and (not causal or (j + 1) * block_k - 1 <= i * block_q))
+                     and (not causal or (j + 1) * block_k - 1 <= i * block_q)
+                     and (window is None
+                          or j * block_k > (i + 1) * block_q - 1 - window))
             masked += not plain
+            on_diagonal += square and i == j
 
     def elements(want: int) -> int:
-        on_diagonal = block_q * block_k
-        if square:  # every masked block is the diagonal's
+        strips = block_q * block_k
+        if square:  # the diagonal's block is walked in strips
             w = _strip(block_q, want)
-            on_diagonal = sum(w * (c + w) for c in range(0, block_q, w))
-        return (visited - masked) * block_q * block_k + masked * on_diagonal
+            strips = sum(w * (c + w) for c in range(0, block_q, w))
+        return ((visited - on_diagonal) * block_q * block_k
+                + on_diagonal * strips)
 
     return {"visited": visited, "masked": masked,
             "elements_fwd": elements(_FWD_STRIP),
@@ -180,10 +212,11 @@ def _fold_scale(dtype, scale: float) -> bool:
     return dtype == jnp.float32 or math.log2(scale).is_integer()
 
 
-def _keep(shape, *, lead, causal: bool, k_left):
+def _keep(shape, *, lead, causal: bool, k_left, window=None):
     """Which scores of a [keys, queries] tile count: key before ``k_left``
     (None: all are, the tile holds no padded key) and, if causal, not after
-    its query. ``lead``: the first query's position less the first key's."""
+    its query, nor ``window`` or more before it. ``lead``: the first query's
+    position less the first key's."""
     k_i = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     keep = None
     if k_left is not None:
@@ -192,12 +225,14 @@ def _keep(shape, *, lead, causal: bool, k_left):
         q_i = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
         under = (k_i - q_i) <= lead
         keep = under if keep is None else keep & under
+        if window is not None:
+            keep &= (k_i - q_i) > lead - window
     return keep
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_k: int,
                   strip: int, causal: bool, scale: float, fold: bool,
-                  seq_k: int, square: bool):
+                  seq_k: int, square: bool, window: Optional[int] = None):
     """One (batch*head, q_block) program: stream K/V blocks with online
     softmax. Block shapes: q/o [1, Bq, D], k/v [1, Tk, D], lse [1, 8, Bq]
     (written only when the training path asks for it: it feeds the backward);
@@ -211,7 +246,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_k: int,
     known when the kernel is traced (``square``) each strip of its queries
     meets the keys up to the strip's last query in one step, masked on the
     strip's own keys alone; with one key block in all, that step is the
-    whole softmax and nothing is rescaled."""
+    whole softmax and nothing is rescaled. With a ``window`` the walk starts
+    at the block that holds the first query's oldest visible key, and the
+    blocks before the first one that every query of the block sees whole
+    are masked too."""
     *lse_ref, m_ref, l_ref, acc_ref = rest
     qi = pl.program_id(1)
     block_q = q_ref.shape[1]
@@ -235,7 +273,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_k: int,
             s = s * scale
         if lead is not None:
             keep = _keep(s.shape, lead=lead, causal=causal,
-                         k_left=seq_k - k_off if tail else None)
+                         k_left=seq_k - k_off if tail else None,
+                         window=window)
             s = jnp.where(keep, s, NEG_INF)
         return s
 
@@ -285,7 +324,15 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_k: int,
             n_plain = jnp.minimum(n_plain, (q_off + 1) // block_k)
             n_visit = jnp.minimum(n_visit,
                                   (q_off + block_q - 1) // block_k + 1)
-        jax.lax.fori_loop(0, n_plain, block(False), None)
+        first_plain = 0
+        if window is not None:
+            # the block of the first query's oldest visible key, and the
+            # first block every query of this one sees whole
+            first = jnp.maximum(q_off - window + 1, 0) // block_k
+            first_plain = jnp.minimum(n_plain, jnp.maximum(
+                q_off + block_q - window + block_k - 1, 0) // block_k)
+            jax.lax.fori_loop(first, first_plain, block(True), None)
+        jax.lax.fori_loop(first_plain, n_plain, block(False), None)
         if not square and (causal or tail):
             jax.lax.fori_loop(n_plain, n_visit, block(True), None)
     if square:
@@ -308,39 +355,66 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_k: int,
                                          (8, block_q))
 
 
+def _vmem_limit(resident_bytes: int):
+    """Compiler parameters for a call that keeps ``resident_bytes`` of
+    blocks and scratch in VMEM: the compiler's own limit (16 MiB) where that
+    holds them twice over (every block is double-buffered), else room for
+    them and the steps' temporaries, inside a v5e core's 128 MiB."""
+    need = 2 * resident_bytes + (8 << 20)
+    if need <= 16 << 20:
+        return {}
+    return {"vmem_limit_bytes": min(need, 100 << 20)}
+
+
+def _folded(x, seq_p: int):
+    """[B, T, H, D] -> [B*H, T padded to ``seq_p``, D]."""
+    B, T, H, D = x.shape
+    if seq_p != T:
+        x = jnp.pad(x, ((0, 0), (0, seq_p - T), (0, 0), (0, 0)))
+    return x.transpose(0, 2, 1, 3).reshape(B * H, seq_p, D)
+
+
+def _kv_index(rep: int):
+    """Block index of the kv head that query head ``b`` of the folded
+    [B*H] axis reads: its own, or the one its group of ``rep`` shares (the
+    heads of a group are neighbours, so the block stays where it is from one
+    program to the next and is fetched once a kv head)."""
+    if rep == 1:
+        return lambda b: b
+    return lambda b: b // rep
+
+
 def _flash_fwd_impl(q, k, v, *, causal: bool, block_q: Optional[int],
                     block_k: Optional[int], interpret: bool,
-                    with_lse: bool = False):
+                    with_lse: bool = False, window: Optional[int] = None):
     B, Tq, H, D = q.shape
     _, Tk, Hkv, _ = k.shape
-    if H != Hkv:
-        rep = H // Hkv
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
+    rep = H // Hkv
+    window = _window_of(window, causal, Tk)
+    if window is not None and Tq != Tk:
+        raise ValueError("a window is for self-attention: Tq == Tk")
     block_q, block_k = _blocks(Tq, Tk, block_q, block_k)
     # Pad sequences to block multiples: in-kernel dynamic slices on a
     # non-multiple tail would clamp and silently re-read earlier rows.
     # Pad keys are masked in-kernel via seq_k; pad q rows are sliced off.
     Tq_p, Tk_p = _pad_to(Tq, block_q), _pad_to(Tk, block_k)
-    if Tq_p != Tq:
-        q = jnp.pad(q, ((0, 0), (0, Tq_p - Tq), (0, 0), (0, 0)))
-    if Tk_p != Tk:
-        k = jnp.pad(k, ((0, 0), (0, Tk_p - Tk), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, Tk_p - Tk), (0, 0), (0, 0)))
     scale = D ** -0.5
-    qf = q.transpose(0, 2, 1, 3).reshape(B * H, Tq_p, D)
-    kf = k.transpose(0, 2, 1, 3).reshape(B * H, Tk_p, D)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * H, Tk_p, D)
+    qf, kf, vf = _folded(q, Tq_p), _folded(k, Tk_p), _folded(v, Tk_p)
     kernel = functools.partial(
         _flash_kernel, block_k=block_k, strip=_strip(block_q, _FWD_STRIP),
         causal=causal, scale=scale, fold=_fold_scale(q.dtype, scale),
-        seq_k=Tk, square=_square(Tq, Tk, block_q, block_k, causal),
+        seq_k=Tk, square=_square(Tq, Tk, block_q, block_k, causal, window),
+        window=window,
     )
     out_shape = [jax.ShapeDtypeStruct((B * H, Tq_p, D), q.dtype)]
     out_specs = [pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0))]
     if with_lse:
         out_shape.append(jax.ShapeDtypeStruct((B * H, 8, Tq_p), jnp.float32))
         out_specs.append(pl.BlockSpec((1, 8, block_q), lambda b, i: (b, 0, i)))
+    kv = _kv_index(rep)
+    size = q.dtype.itemsize
+    limit = _vmem_limit(2 * Tk_p * D * size + 2 * block_q * D * size
+                        + block_q * D * 4)
     # Without ``with_lse`` (inference, no grad) the LSE output does not
     # exist: it would be wasted write bandwidth on every forward.
     out, *lse = pl.pallas_call(
@@ -349,8 +423,8 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, block_q: Optional[int],
         grid=(B * H, Tq_p // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, Tk_p, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, Tk_p, D), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, Tk_p, D), lambda b, i: (kv(b), 0, 0)),
+            pl.BlockSpec((1, Tk_p, D), lambda b, i: (kv(b), 0, 0)),
         ],
         out_specs=out_specs,
         scratch_shapes=[
@@ -358,8 +432,10 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, block_q: Optional[int],
             pltpu.VMEM((1, block_q), jnp.float32),  # running sum
             pltpu.VMEM((D, block_q), jnp.float32),  # o accumulator
         ],
+        **({"compiler_params": pltpu.CompilerParams(**limit)}
+           if limit else {}),
         interpret=interpret,
-        name="flash_fwd",
+        name="flash_fwd" if window is None else "flash_window_fwd",
     )(qf, kf, vf)
     out = out.reshape(B, H, Tq_p, D).transpose(0, 2, 1, 3)
     if Tq_p != Tq:
@@ -372,7 +448,8 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, block_q: Optional[int],
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
                       block_q: int, strip: int, chunk: int, causal: bool,
-                      scale: float, fold: bool, seq_k: int, square: bool):
+                      scale: float, fold: bool, seq_k: int, square: bool,
+                      window: Optional[int] = None):
     """One (batch*head, k_block) program of the ONE backward pass: dK/dV of
     this key block and this key block's part of dQ, from S, P and dP computed
     once a block pair (five products). Scores are held keys x queries
@@ -387,7 +464,10 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     is finite); padded keys and the diagonal do, on the blocks that hold
     them alone. The block the diagonal crosses, where it is known when the
     kernel is traced (``square``), is walked in strips of queries, each
-    against the keys at or before its last query."""
+    against the keys at or before its last query. With a ``window`` the walk
+    over the query blocks ends at the last one that sees a key of this
+    block, and the blocks after the last one whose every query sees the
+    block whole are masked too."""
     kj = pl.program_id(1)
     n_kb = pl.num_programs(1)
     block_k = k_ref.shape[1]
@@ -419,7 +499,8 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         p = jnp.exp(s - lse_ref[0, 0:1, cols])
         if lead is not None:
             keep = _keep(s.shape, lead=lead, causal=causal,
-                         k_left=seq_k - k_off if tail else None)
+                         k_left=seq_k - k_off if tail else None,
+                         window=window)
             # exp(NEG_INF - lse) would underflow to 0 anyway; the select
             # after the exp gives bit-exact zeros.
             p = jnp.where(keep, p, 0.0)
@@ -441,10 +522,29 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         return body
 
     n_q = q_ref.shape[1] // block_q
+    end = plain_end = n_q
+    if window is not None:
+        # past the last query block that sees a key of this block, and past
+        # the last one whose every query sees all of it
+        end = jnp.minimum(n_q, (k_off + block_k + window - 2) // block_q + 1)
+        plain_end = jnp.minimum(end, (k_off + window) // block_q)
     if square:
         for c in range(0, block_q, strip):
             step(pl.multiple_of(k_off + c, strip), strip, c + strip, c)
-        jax.lax.fori_loop(kj + 1, n_q, block(False), None)
+        jax.lax.fori_loop(kj + 1, plain_end, block(False), None)
+        if window is not None:
+            jax.lax.fori_loop(jnp.maximum(plain_end, kj + 1), end,
+                              block(True), None)
+    elif window is not None:
+        start = k_off // block_q
+        first_plain = (k_off + block_k + block_q - 2) // block_q
+        if tail:
+            first_plain = jnp.where(kj == n_kb - 1, n_q, first_plain)
+        first_plain = jnp.minimum(first_plain, end)
+        plain_end = jnp.maximum(plain_end, first_plain)
+        jax.lax.fori_loop(start, first_plain, block(True), None)
+        jax.lax.fori_loop(first_plain, plain_end, block(False), None)
+        jax.lax.fori_loop(plain_end, end, block(True), None)
     else:
         start = first_plain = 0
         if causal:
@@ -470,37 +570,32 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd_impl(q, k, v, out, lse, g, *, causal: bool,
                     block_q: Optional[int], block_k: Optional[int],
-                    interpret: bool):
+                    interpret: bool, window: Optional[int] = None):
     """Pallas flash backward, one call: no [Tq, Tk] materialization. Returns
-    (dq, dk, dv) with GQA head-group reduction applied."""
+    (dq, dk, dv) with GQA head-group reduction applied: every query head
+    reads its kv head's k and v where they lie and writes a dk and dv of its
+    own, which are summed over the group afterwards."""
     B, Tq, H, D = q.shape
     _, Tk, Hkv, _ = k.shape
     rep = H // Hkv
-    if rep != 1:
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
+    window = _window_of(window, causal, Tk)
     block_q, block_k = _blocks(Tq, Tk, block_q, block_k)
     scale = D ** -0.5
     Tq_p, Tk_p = _pad_to(Tq, block_q), _pad_to(Tk, block_k)
-    if Tq_p != Tq:
-        q = jnp.pad(q, ((0, 0), (0, Tq_p - Tq), (0, 0), (0, 0)))
-        g = jnp.pad(g, ((0, 0), (0, Tq_p - Tq), (0, 0), (0, 0)))
-        out = jnp.pad(out, ((0, 0), (0, Tq_p - Tq), (0, 0), (0, 0)))
-    if Tk_p != Tk:
-        k = jnp.pad(k, ((0, 0), (0, Tk_p - Tk), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, Tk_p - Tk), (0, 0), (0, 0)))
-    qf = q.transpose(0, 2, 1, 3).reshape(B * H, Tq_p, D)
-    kf = k.transpose(0, 2, 1, 3).reshape(B * H, Tk_p, D)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * H, Tk_p, D)
-    dof = g.transpose(0, 2, 1, 3).reshape(B * H, Tq_p, D)
-    of = out.transpose(0, 2, 1, 3).reshape(B * H, Tq_p, D)
+    qf, dof, of = _folded(q, Tq_p), _folded(g, Tq_p), _folded(out, Tq_p)
+    kf, vf = _folded(k, Tk_p), _folded(v, Tk_p)
     # delta_i = rowsum(dO_i * O_i) — cheap elementwise reduce in XLA,
     # replicated to the same [B*H, 8, Tq] sublane layout as lse.
     delta = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32), axis=-1)
     delta = jnp.broadcast_to(delta[:, None, :], (B * H, 8, Tq_p))
 
+    kv = _kv_index(rep)
+    size = q.dtype.itemsize
+    limit = _vmem_limit(3 * Tq_p * D * size + Tq_p * D * 4
+                        + 2 * 8 * Tq_p * 4 + 4 * block_k * D * (size + 2))
     whole_q = pl.BlockSpec((1, Tq_p, D), lambda b, j: (b, 0, 0))
-    k_block = pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0))
+    k_block = pl.BlockSpec((1, block_k, D), lambda b, j: (kv(b), j, 0))
+    dk_block = pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0))
     stats = pl.BlockSpec((1, 8, Tq_p), lambda b, j: (b, 0, 0))
     dq, dk, dv = pl.pallas_call(
         functools.partial(
@@ -508,7 +603,8 @@ def _flash_bwd_impl(q, k, v, out, lse, g, *, causal: bool,
             strip=_strip(block_q, _BWD_STRIP),
             chunk=_strip(block_q, _BWD_CHUNK), causal=causal, scale=scale,
             fold=_fold_scale(q.dtype, scale), seq_k=Tk,
-            square=_square(Tq, Tk, block_q, block_k, causal),
+            square=_square(Tq, Tk, block_q, block_k, causal, window),
+            window=window,
         ),
         out_shape=(
             jax.ShapeDtypeStruct((B * H, Tq_p, D), q.dtype),
@@ -517,16 +613,16 @@ def _flash_bwd_impl(q, k, v, out, lse, g, *, causal: bool,
         ),
         grid=(B * H, Tk_p // block_k),
         in_specs=[whole_q, k_block, k_block, whole_q, stats, stats],
-        out_specs=(whole_q, k_block, k_block),
+        out_specs=(whole_q, dk_block, dk_block),
         scratch_shapes=[
             pltpu.VMEM((Tq_p, D), jnp.float32),     # dq, across key blocks
             pltpu.VMEM((block_k, D), jnp.float32),  # dk
             pltpu.VMEM((block_k, D), jnp.float32),  # dv
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"), **limit),
         interpret=interpret,
-        name="flash_bwd",
+        name="flash_bwd" if window is None else "flash_window_bwd",
     )(qf, kf, vf, dof, lse, delta)
 
     dq = dq.reshape(B, H, Tq_p, D).transpose(0, 2, 1, 3)[:, :Tq]
@@ -538,25 +634,28 @@ def _flash_bwd_impl(q, k, v, out, lse, g, *, causal: bool,
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal: bool = True,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: bool = False):
+                    interpret: bool = False,
+                    window: Optional[int] = None):
     """Flash attention: pallas forward AND pallas backward (LSE saved by
     the forward; backward never materializes the [Tq, Tk] score matrix —
     round 2 recomputed attention in XLA for grads, which put three dense
-    [B, H, Tq, Tk] tensors back into every train step)."""
+    [B, H, Tq, Tk] tensors back into every train step). q [B, T, H, D], k
+    and v [B, T, Hkv, D] with H a multiple of Hkv; ``window`` as
+    ``attention_xla``'s."""
     return _flash_fwd_impl(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret,
+        interpret=interpret, window=window,
     )
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, window):
     out, lse = _flash_fwd_impl(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret, with_lse=True,
+        interpret=interpret, with_lse=True, window=window,
     )
     # Named so remat policies can keep them: without this, a jax.checkpoint
     # around the transformer block re-runs the flash forward a second time
@@ -568,11 +667,11 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, res, g):
+def _flash_bwd(causal, block_q, block_k, interpret, window, res, g):
     q, k, v, out, lse = res
     return _flash_bwd_impl(
         q, k, v, out, lse, g, causal=causal, block_q=block_q,
-        block_k=block_k, interpret=interpret,
+        block_k=block_k, interpret=interpret, window=window,
     )
 
 
@@ -582,7 +681,7 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 def attention(
     q, k, v, *, causal: bool = True, impl: str = "auto",
     block_q: Optional[int] = None, block_k: Optional[int] = None,
-    mesh=None,
+    mesh=None, window: Optional[int] = None,
 ):
     """Dispatcher. impl: auto | xla | flash | flash_interpret. ``auto`` is
     decided by platform alone: the pallas kernel on TPU (a kernel that fails
@@ -596,13 +695,14 @@ def attention(
     if impl == "auto":
         impl = "flash" if jax.default_backend() == "tpu" else "xla"
     if impl == "xla":
-        return attention_xla(q, k, v, causal=causal)
+        return attention_xla(q, k, v, causal=causal, window=window)
     if impl not in ("flash", "flash_interpret"):
         raise ValueError(f"unknown attention impl {impl}")
 
     def kernel(q, k, v):
         return flash_attention(
-            q, k, v, causal, block_q, block_k, impl == "flash_interpret"
+            q, k, v, causal, block_q, block_k, impl == "flash_interpret",
+            window,
         )
 
     if mesh is not None and mesh.size > 1:
@@ -618,6 +718,11 @@ def attention(
         batch = tuple(a for a in ("data", "fsdp") if a in free)
         heads = "tensor" if "tensor" in free else None
         spec = P(batch or None, None, heads, None)
+        if heads and k.shape[2] % mesh.shape["tensor"]:
+            # fewer kv heads than the axis divides: every shard needs whole
+            # groups, so the kv heads are spread to the query heads first
+            rep = q.shape[2] // k.shape[2]
+            k, v = (jnp.repeat(a, rep, axis=2) for a in (k, v))
         if free:
             kernel = jax.shard_map(
                 kernel, mesh=None if held else mesh, axis_names=free,

@@ -29,6 +29,16 @@ that Trinity's ``afmoe`` takes). What follows is one of two dispatches:
   kernel is a copy, which the cached forward of a served model pays every
   tick (``_experts``).
 
+A layer may hold a SHARE of the experts (``MoEConfig.num_held`` of
+``num_experts``, from ``first_held``): one chip of an expert-parallel group.
+The router scores all ``num_experts``, the auxiliary loss is over all of
+them, and the layer's result is the held experts' part: the pairs routed to
+the others are left out before the gather, and nothing stands in for the
+chips that hold them (``_grouped_share``; dropless only). The router's
+logits may be computed elsewhere and handed in (``logits=``,
+``router_logits``): a model whose router reads another tensor than the
+experts do.
+
 Both are differentiable; auxiliary load-balancing loss included. The device
 operations carry the scopes ``moe.route``, ``moe.dispatch``, ``moe.experts``
 and ``moe.combine`` (``jax.named_scope``) for a trace's reader; a shared
@@ -52,6 +62,7 @@ class MoEConfig:
     aux_loss_weight: float = 0.01
     router_jitter: float = 0.0
     # "gelu" (Switch-style experts) | "swiglu" (Mixtral/OLMoE gated experts)
+    # | "reglu" (gated by ReLU: SmallThinker's sparse experts)
     activation: str = "gelu"
     # The k chosen gates divided by their sum (Switch, Mixtral). OLMoE states
     # false: the k softmax probabilities weight the experts as they are.
@@ -79,17 +90,37 @@ class MoEConfig:
     # The decode engine flips this on; training defaults to capacity
     # (bounded per-expert work => static shapes for the all-to-alls).
     dropless: bool = False
+    # This layer's share of the experts: ``num_held`` of them from
+    # ``first_held`` on (None: all). The router still scores ``num_experts``.
+    num_held: Optional[int] = None
+    first_held: int = 0
 
     def __post_init__(self):
-        if self.activation not in ("gelu", "swiglu"):
+        if self.activation not in ("gelu", "swiglu", "reglu"):
             raise ValueError(
-                f"MoEConfig.activation must be 'gelu' or 'swiglu', got "
-                f"{self.activation!r}"
+                f"MoEConfig.activation must be 'gelu', 'swiglu' or 'reglu', "
+                f"got {self.activation!r}"
             )
+        if self.num_held is not None:
+            if not self.dropless:
+                raise ValueError(
+                    "MoEConfig.num_held: a share of the experts is routed "
+                    "dropless (the capacity path holds every expert)")
+            if not (0 <= self.first_held
+                    and 0 < self.num_held
+                    and self.first_held + self.num_held <= self.num_experts):
+                raise ValueError(
+                    f"MoEConfig: experts {self.first_held} to "
+                    f"{self.first_held + self.num_held - 1} of "
+                    f"{self.num_experts}")
         if self.score_func not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"MoEConfig.score_func must be 'softmax' or 'sigmoid', got "
                 f"{self.score_func!r}")
+
+
+def _gated(config: MoEConfig) -> bool:
+    return config.activation in ("swiglu", "reglu")
 
 
 def init_moe_params(
@@ -102,17 +133,19 @@ def init_moe_params(
     passes what it gives its other projections into the residual stream."""
     k1, k2, k3, k4, k5 = jax.random.split(key, 5)
     lead = () if num_layers is None else (num_layers,)
-    E = config.num_experts
+    # the router scores every expert; the weights are those of the share
+    X = config.num_experts
+    E = X if config.num_held is None else config.num_held
 
     def normal(key, shape, s=0.02):
         return (jax.random.normal(key, shape) * s).astype(param_dtype)
 
     params = {
-        "router_w": normal(k1, lead + (embed_dim, E), config.router_init_std),
+        "router_w": normal(k1, lead + (embed_dim, X), config.router_init_std),
         "expert_fc": normal(k2, lead + (E, embed_dim, mlp_dim)),
         "expert_out": normal(k3, lead + (E, mlp_dim, embed_dim), out_std),
     }
-    if config.activation == "swiglu":
+    if _gated(config):
         # Mixtral-style gated experts: fc is the "up" proj, gate multiplies
         params["expert_gate"] = normal(k4, lead + (E, embed_dim, mlp_dim))
     if config.expert_bias:
@@ -130,7 +163,7 @@ def moe_param_axes(num_layers: Optional[int] = None,
         "expert_fc": lead + ("expert", "embed", "mlp"),
         "expert_out": lead + ("expert", "mlp", "embed"),
     }
-    if config is not None and config.activation == "swiglu":
+    if config is not None and _gated(config):
         axes["expert_gate"] = lead + ("expert", "embed", "mlp")
     if config is not None and config.expert_bias:
         axes["expert_bias"] = lead + (None,)
@@ -147,20 +180,32 @@ def stacked_for(params: Dict[str, jax.Array], dtype) -> Dict[str, jax.Array]:
             else w.astype(dtype) for name, w in params.items()}
 
 
-def _route(params, tokens, config: MoEConfig, rng, layer):
+def _own(params, name, layer):
+    """A layer's ``name``: ``params``' own, or its slice of a stack."""
+    w = params[name]
+    return w if layer is None else jax.lax.dynamic_index_in_dim(
+        w, layer, 0, False)
+
+
+def router_logits(params, x, layer=None):
+    """x [..., D] -> the router's logits [rows, experts], float32: for a
+    caller whose router reads another tensor than its experts do, to hand
+    to ``moe_layer_counted(logits=)``."""
+    with jax.named_scope("moe.route"):
+        return jnp.einsum(
+            "td,de->te", x.reshape(-1, x.shape[-1]).astype(jnp.float32),
+            _own(params, "router_w", layer).astype(jnp.float32))
+
+
+def _route(params, tokens, config: MoEConfig, rng, layer, logits=None):
     """tokens [T, D] -> (scores [T, E] float32 over ALL experts, the k chosen
     experts' gates [T, k] (their scores as they are: ``_normalised`` does
-    the rest) and indices [T, k])."""
+    the rest) and indices [T, k]). ``logits`` [T, E]: the router's product,
+    where the caller made it (``router_logits``)."""
     with jax.named_scope("moe.route"):
-        def own(name):
-            w = params[name]
-            return w if layer is None else jax.lax.dynamic_index_in_dim(
-                w, layer, 0, False)
-
-        router_w = own("router_w")
-        router_logits = jnp.einsum(
+        router_logits = logits if logits is not None else jnp.einsum(
             "td,de->te", tokens.astype(jnp.float32),
-            router_w.astype(jnp.float32),
+            _own(params, "router_w", layer).astype(jnp.float32),
         )
         if config.router_jitter and rng is not None:
             router_logits += config.router_jitter * jax.random.normal(
@@ -173,7 +218,8 @@ def _route(params, tokens, config: MoEConfig, rng, layer):
         if config.expert_bias:
             # the bias moves the choice and not the gates
             _, chosen = jax.lax.top_k(
-                probs + own("expert_bias").astype(jnp.float32), config.top_k)
+                probs + _own(params, "expert_bias", layer).astype(
+                    jnp.float32), config.top_k)
             gates = jnp.take_along_axis(probs, chosen, axis=-1)
         else:
             gates, chosen = jax.lax.top_k(probs, config.top_k)
@@ -235,9 +281,10 @@ def _experts(params, rows, counts, config: MoEConfig, layer):
             jnp.zeros((L * E,), counts.dtype), counts, (layer * E,))
     with jax.named_scope("moe.experts"):
         h = jax.lax.ragged_dot(rows, weights("expert_fc"), counts)
-        if config.activation == "swiglu":
+        if _gated(config):
             g = jax.lax.ragged_dot(rows, weights("expert_gate"), counts)
-            h = jax.nn.silu(g) * h
+            act = jax.nn.silu if config.activation == "swiglu" else jax.nn.relu
+            h = act(g) * h
         else:
             h = jax.nn.gelu(h)
         return jax.lax.ragged_dot(
@@ -269,6 +316,113 @@ def _grouped(params, tokens, gates, chosen, row_mask, config: MoEConfig,
     return out.astype(tokens.dtype), counts
 
 
+# A share's row buffer, in units of the rows a uniform router sends it
+# (tokens x top_k x num_held / num_experts): one pass of the grouped products
+# takes that many rows, and a routing that sends the share more takes further
+# passes (``_grouped_share``), so no row is ever dropped. The buffer's rows
+# are gathered and added back whether or not a pair fills them.
+HELD_ROWS_FACTOR = 1.5
+
+
+def held_rows_bound(n_tok: int, config: MoEConfig) -> int:
+    """Rows one pass of a share's grouped products takes: what a uniform
+    router sends the share, times ``HELD_ROWS_FACTOR``, in whole sublanes,
+    and never more than every pair there is."""
+    pairs = n_tok * config.top_k
+    want = pairs * config.num_held / config.num_experts
+    return min(pairs, 8 * -(-int(want * HELD_ROWS_FACTOR) // 8))
+
+
+def _grouped_share(params, tokens, gates, chosen, row_mask,
+                   config: MoEConfig, layer):
+    """The sorted, grouped dispatch of a layer that holds experts
+    ``first_held : first_held + num_held`` alone: tokens [T, D] with their k
+    gates and experts (of all ``num_experts``) -> (the held experts' part of
+    the result [T, D], rows a held expert [num_held]).
+
+    The pairs are sorted by held expert, those routed elsewhere (or masked)
+    behind the last group. The rows gathered, multiplied and added back are
+    the first ``held_rows_bound`` of that order: a pair routed elsewhere is
+    never gathered, and the grouped products stop at the last group's last
+    row. Where the routing sends the share more rows than one pass holds,
+    further passes take the next ``held_rows_bound`` pairs each until none
+    is left (each recomputed in the backward pass, so their residuals do not
+    add up): whatever the routing, every pair of a held expert is computed."""
+    T, D = tokens.shape
+    k, n_held = config.top_k, config.num_held
+    R = held_rows_bound(T, config)
+    passes = -(-T * k // R)
+    with jax.named_scope("moe.dispatch"):
+        expert = chosen.reshape(T * k) - config.first_held
+        here = (expert >= 0) & (expert < n_held)
+        if row_mask is not None:
+            here &= jnp.repeat(row_mask, k)
+        expert = jnp.where(here, expert, n_held)
+        order = jnp.argsort(expert, stable=True)       # pair ids by expert
+        counts = jnp.zeros((n_held,), jnp.int32).at[expert].add(
+            1, mode="drop")
+        ends = jnp.cumsum(counts)
+        total = ends[-1]
+        # pair id T*k: no pair (its token, T, is no row of ``tokens``)
+        order = jnp.pad(order, (0, passes * R - T * k), constant_values=T * k)
+        flat_gates = gates.reshape(T * k)
+
+    def one(tokens, params, start):
+        """The part of the result that pairs ``start : start + R`` of the
+        order make, [T, D] float32."""
+        with jax.named_scope("moe.dispatch"):
+            ids = jax.lax.dynamic_slice(order, (start,), (R,))
+            real = start + jnp.arange(R) < total
+            token = jnp.where(real, ids // k, 0)
+            sizes = (jnp.clip(ends - start, 0, R)
+                     - jnp.clip(ends - counts - start, 0, R))
+            # a buffer row past the last pair is zeros, by a select: what
+            # the grouped products leave in the rows of no group is
+            # undefined (on the chip: whatever the memory held), in the
+            # backward pass too, and the select's transpose keeps that out
+            # of the tokens' gradient
+            rows = jnp.where(real[:, None], tokens[token], 0)   # [R, D]
+        y = _experts(params, rows, sizes, config, layer)
+        with jax.named_scope("moe.combine"):
+            gate = flat_gates[jnp.where(real, ids, 0)]
+            y = jnp.where(real[:, None], y, 0.0) * gate[:, None]
+            return jnp.zeros((T, D), jnp.float32).at[
+                jnp.where(real, token, T)].add(y, mode="drop")
+
+    if passes == 1:
+        out = one(tokens, params, 0)
+    else:
+        def overflow(tokens, params):
+            def step(acc, start):
+                part = jax.lax.cond(
+                    start < total, jax.checkpoint(one),
+                    lambda *_: jnp.zeros((T, D), jnp.float32),
+                    tokens, params, start)
+                return acc + part, None
+
+            return jax.lax.scan(step, jnp.zeros((T, D), jnp.float32),
+                                jnp.arange(passes) * R)[0]
+
+        out = jax.lax.cond(total <= R, lambda t, p: one(t, p, 0), overflow,
+                           tokens, params)
+    return out.astype(tokens.dtype), counts
+
+
+def aux_zero(config: Optional[MoEConfig]):
+    """What a stack of layers adds its layers' auxiliary results up from:
+    the scalar loss, or for a layer that holds a share of the experts the
+    loss and its two counts of rows (``moe_layer_counted``)."""
+    if config is None or config.num_held is None:
+        return jnp.float32(0.0)
+    return {"aux_loss": jnp.float32(0.0), "moe_rows_held": jnp.int32(0),
+            "moe_rows_max_expert": jnp.int32(0)}
+
+
+def aux_loss_of(aux) -> jax.Array:
+    """The auxiliary loss of what ``aux_zero`` and the layers added up."""
+    return aux["aux_loss"] if isinstance(aux, dict) else aux
+
+
 def moe_layer_counted(
     params: Dict[str, jax.Array],
     x: jax.Array,
@@ -277,13 +431,19 @@ def moe_layer_counted(
     rng: Optional[jax.Array] = None,
     row_mask: Optional[jax.Array] = None,
     layer: Optional[jax.Array] = None,
+    logits: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """x: [B, T, D] -> (out [B, T, D], aux_loss scalar, the number of
     distinct experts that received a row). ``row_mask`` [B, T] bool marks the
     rows that carry a token (padding of a prefill bucket and idle decode
     slots do not): the others get no expert and come back as zeros. With
     ``layer`` (dropless only), ``params`` are the weights of ALL layers,
-    stacked, and ``layer`` the index of this one (``_experts`` says why)."""
+    stacked, and ``layer`` the index of this one (``_experts`` says why).
+    ``logits`` [B * T, experts]: the router's logits where the caller
+    computed them (``router_logits``). A layer that holds a share of the
+    experts (``num_held``) gives, in place of the scalar loss, the loss (over
+    ALL experts) beside the rows it computed and the rows of its fullest
+    expert: ``{"aux_loss", "moe_rows_held", "moe_rows_max_expert"}``."""
     B, T, D = x.shape
     E, k = config.num_experts, config.top_k
     tokens = x.reshape(B * T, D)
@@ -291,7 +451,21 @@ def moe_layer_counted(
     mask = None if row_mask is None else row_mask.reshape(n_tok)
     if layer is not None and not config.dropless:
         raise ValueError("stacked weights with a layer index: dropless only")
-    probs, gates, chosen = _route(params, tokens, config, rng, layer)
+    probs, gates, chosen = _route(params, tokens, config, rng, layer, logits)
+
+    if config.num_held is not None:
+        out, counts = _grouped_share(
+            params, tokens, _normalised(gates, config), chosen, mask, config,
+            layer)
+        with jax.named_scope("moe.route"):
+            pairs = chosen.reshape(-1)
+            if mask is not None:
+                pairs = jnp.where(jnp.repeat(mask, k), pairs, E)
+            every = jnp.zeros((E,), jnp.int32).at[pairs].add(1, mode="drop")
+            aux = _aux_loss(probs, every / jnp.maximum(every.sum(), 1), config)
+        return out.reshape(B, T, D), {
+            "aux_loss": aux, "moe_rows_held": counts.sum(),
+            "moe_rows_max_expert": counts.max()}, (counts > 0).sum()
 
     if config.dropless:
         out, counts = _grouped(

@@ -54,10 +54,13 @@ def param_shardings(mesh: Mesh, config, rules=None):
     """``config`` may be any model family's config (GPT2Config,
     LlamaConfig, ...); dispatch goes through ``models.module_for``."""
     axes = module_for(config).param_axes(config)
+    # a leaf is a tuple of axis names; a family of several kinds of layer
+    # also holds its segments in tuples
     return jax.tree.map(
         lambda a: named_sharding(mesh, a, rules),
         axes,
-        is_leaf=lambda x: isinstance(x, tuple),
+        is_leaf=lambda x: isinstance(x, tuple) and all(
+            e is None or isinstance(e, str) for e in x),
     )
 
 
@@ -120,6 +123,11 @@ def make_train_step(
     layouts are honored even if the input state arrived differently sharded.
     Stochastic layers (MoE router jitter) draw from a per-step key folded
     from ``seed`` and ``state["step"]``.
+
+    ``metrics["loss"]`` is the next-token cross entropy. A model with routed
+    experts is trained on that plus its auxiliary loss, which rides beside
+    it as ``metrics["aux_loss"]``, with whatever else its layers counted (a
+    share of the experts: ``moe_rows_held``, ``moe_rows_max_expert``).
     """
     moe = getattr(config, "moe", None)
     needs_rng = moe is not None and moe.router_jitter > 0
@@ -128,10 +136,16 @@ def make_train_step(
     )
 
     def loss(params, batch, rng):
-        return decoder.loss_fn(
-            params, batch, config, mesh,
-            pipeline_microbatches=pipeline_microbatches, rng=rng,
-        )
+        if moe is None or pipeline_microbatches:
+            return decoder.loss_fn(
+                params, batch, config, mesh,
+                pipeline_microbatches=pipeline_microbatches, rng=rng,
+            ), {}
+        xent, aux = decoder.loss_fn(
+            params, batch, config, mesh, rng=rng, parts=True)
+        if not isinstance(aux, dict):
+            aux = {"aux_loss": aux}
+        return xent + aux["aux_loss"], {"loss": xent, **aux}
 
     def step_fn(state, batch):
         params = state["params"]
@@ -141,7 +155,8 @@ def make_train_step(
             jax.random.fold_in(jax.random.PRNGKey(seed), state["step"])
             if needs_rng else None
         )
-        (loss_val), grads = jax.value_and_grad(loss)(params, batch, rng)
+        (loss_val, beside), grads = jax.value_and_grad(
+            loss, has_aux=True)(params, batch, rng)
         state = dict(state, params=params)
         updates, new_opt = opt.update(
             grads, state["opt_state"], state["params"]
@@ -160,6 +175,7 @@ def make_train_step(
             "loss": loss_val,
             "grad_norm": optax.global_norm(grads),
             "step": state["step"] + 1,
+            **beside,
         }
         return (
             {

@@ -89,8 +89,10 @@ def default_jax_train_loop(config: Dict[str, Any]):
     Reports ``{loss, step, tokens_per_sec, compiles, platform, device_kind,
     device_count}`` each step (a worker whose node was granted ``TPU`` fails
     with ``AcceleratorMismatchError`` rather than train on another
-    platform); saves orbax checkpoints; resumes from ``get_checkpoint()``
-    after failures.
+    platform), and beside the loss what a model with routed experts counted
+    in it (``FETCHED``: they ride the loss's one fetch and are the
+    ``train.loss_fetch`` span's arguments); saves orbax checkpoints; resumes
+    from ``get_checkpoint()`` after failures.
 
     Each step is a ``train.step`` in the profiler's own trace (a
     ``StepTraceAnnotation``, so xprof draws step boundaries) with what the
@@ -118,6 +120,8 @@ def default_jax_train_loop(config: Dict[str, Any]):
     )
     from ray_tpu.util.debug import compile_count
 
+    # what is fetched from a step's metrics, in one transfer
+    FETCHED = ("loss", "aux_loss", "moe_rows_held", "moe_rows_max_expert")
     ctx = get_context()
     device_info = local_device_info()
     model = config.get("model", {})
@@ -171,12 +175,18 @@ def default_jax_train_loop(config: Dict[str, Any]):
                 state, metrics = step_fn(state, batch)
             if ctx.should_stop():
                 break
-            with span("train.loss_fetch"):
-                loss = float(metrics["loss"])
+            with span("train.loss_fetch") as fetch:
+                fetched = jax.device_get(
+                    {k: metrics[k] for k in FETCHED if k in metrics})
+                loss = float(fetched.pop("loss"))
+                routed = {k: float(v) for k, v in fetched.items()}
+                if routed:
+                    fetch.set_metadata(**routed)
             dt = max(time.monotonic() - t0, 1e-9)
             t0 = time.monotonic()
             m = {
                 "loss": loss,
+                **routed,
                 "step": step + 1,
                 "tokens_per_sec": batch_size * seq_len / dt,
                 # a rise between two steps: a program was built in between

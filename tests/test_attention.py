@@ -219,3 +219,80 @@ def test_flash_under_mesh_runs_per_shard(axes):
     for a, b in zip(g_flash, g_xla):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-4, rtol=5e-4)
+
+
+def _window_oracle(q, k, v, window):
+    """``decoder._window_attention`` over k and v spread to the query heads:
+    the band mask in plain XLA that the kernels replace on the chip."""
+    from ray_tpu.models.decoder import _repeat_kv, _window_attention
+
+    rep = q.shape[2] // k.shape[2]
+    return _window_attention(q, _repeat_kv(k, rep), _repeat_kv(v, rep), window)
+
+
+@pytest.mark.parametrize("T,block,window,H,Hkv,D", [
+    (384, 128, 200, 7, 1, 32),   # G = 7; the window is no multiple of the
+    #                              block, T above it: edge blocks and plain
+    (384, 128, 256, 7, 1, 32),   # a multiple of the block: the two
+    #                              triangles of one block
+    (256, 128, 256, 14, 2, 32),  # T at the window: nothing is left out
+    (200, 128, 256, 7, 1, 32),   # T below the window, ragged
+    (330, 128, 100, 7, 1, 32),   # a window shorter than the block, ragged:
+    #                              the diagonal's block is cut by it too
+    (1536, 512, 600, 2, 1, 128),  # D = 128, the strips of a square call
+    (640, None, 130, 4, 2, 64),  # the blocks the kernel chooses itself
+], ids=str)
+def test_flash_window_matches_the_band_mask(T, block, window, H, Hkv, D):
+    """The window in the flash kernels, forward and backward, against
+    ``_window_attention``: float32 on both sides, so the two differ in the
+    order of their sums (measured 3e-6 forward, 2e-5 on the gradients; a
+    window off by one position reads 1e-2 and more)."""
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    q = jax.random.normal(ks[0], (2, T, H, D), jnp.float32)
+    k = jax.random.normal(ks[1], (2, T, Hkv, D), jnp.float32)
+    v = jax.random.normal(ks[2], (2, T, Hkv, D), jnp.float32)
+    g = jax.random.normal(ks[3], (2, T, H, D), jnp.float32)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, True, block, block, True, window)
+
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v)),
+        np.asarray(_window_oracle(q, k, v, window)), atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(attention_xla(q, k, v, window=window)),
+        np.asarray(_window_oracle(q, k, v, window)), atol=2e-5, rtol=2e-5)
+    got = jax.grad(lambda *a: jnp.vdot(flash(*a), g), argnums=(0, 1, 2))(
+        q, k, v)
+    want = jax.grad(lambda *a: jnp.vdot(_window_oracle(*a, window), g),
+                    argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize("T,block,window,visited,masked,fwd,bwd", [
+    # 8192 tokens, blocks of 1024, a window of 4096: a query block past the
+    # window visits the edge's block (masked, whole), three plain ones and
+    # the diagonal's: 36 - 6 = 30 of the causal 36, 8 + 4 masked
+    (8192, None, 4096, 30, 12, 22 + 8 * 0.75, 22 + 8 * 0.5625),
+    (8192, None, None, 36, 8, 28 + 8 * 0.75, 28 + 8 * 0.5625),
+    (8192, None, 8192, 36, 8, 28 + 8 * 0.75, 28 + 8 * 0.5625),
+    # a window that is no multiple of the block: two edge blocks a row and
+    # not one plain block
+    (4096, 1024, 1500, 9, 9, 5 + 4 * 0.75, 5 + 4 * 0.5625),
+], ids=str)
+def test_flash_block_counts_with_a_window(T, block, window, visited, masked,
+                                          fwd, bwd):
+    got = flash_block_counts(T, T, block, block, True, window)
+    assert got == {"visited": visited, "masked": masked,
+                   "elements_fwd": int(fwd * 1024 ** 2),
+                   "elements_bwd": int(bwd * 1024 ** 2)}
+
+
+def test_a_window_is_causal_self_attention():
+    q, k, v = _make_qkv(T=128)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, False, None, None, True, 64)
+    with pytest.raises(ValueError, match="self-attention"):
+        flash_attention(q[:, :64], k, v, True, None, None, True, 32)

@@ -24,11 +24,14 @@ from ray_tpu.parallel.moe import MoEConfig
 
 # what the decoder calls on a family, what a server calls once as it takes
 # its weights, and what callers outside ask of one
-PIECES = ("layers", "embed", "qkv", "attn_out", "ffn", "final_norm", "head",
-          "head_weight", "serving_params")
+PIECES = ("layers", "embed", "at_input", "qkv", "attn_out", "ffn",
+          "final_norm", "head", "head_weight", "serving_params")
 OWN = ("Config", "PRESETS", "EXPERT_ACTIVATION", "init_params", "param_axes")
 SHARED = ("forward_features", "forward", "init_kv_cache", "forward_cached",
           "forward_pipelined", "loss_fn", "count_params")
+# families with no dense form: every layer routed, whatever is stated
+ALWAYS_ROUTED = ("smallthinker",)
+GATED = ("swiglu", "reglu")   # activations with a gate matrix
 
 
 def _source(module) -> ast.Module:
@@ -136,7 +139,9 @@ def test_llm_config_builds_every_family(family, experts):
         # a family of one kind of layer keeps them with the layer, one of
         # several in a stack of their own
         gated = "expert_gate" in (blocks.get("moe") or blocks["experts"])
-        assert gated == (module.EXPERT_ACTIVATION == "swiglu")
+        assert gated == (module.EXPERT_ACTIVATION in GATED)
+    elif family in ALWAYS_ROUTED:
+        assert cfg.moe == module.Config().moe   # the family's own
     else:
         assert cfg.moe is None
     # stated: the family's config takes it under its own name, or refuses
@@ -158,11 +163,17 @@ def test_llm_config_builds_every_family(family, experts):
 # ``serving_params``: what a family's cached forward rounds on every use,
 # rounded once. The same values by the same operation, so not a bit moves.
 
-TINY = {"gpt2": "gpt2-tiny", "llama": "llama-tiny", "afmoe": "afmoe-tiny"}
+TINY = {"gpt2": "gpt2-tiny", "llama": "llama-tiny", "afmoe": "afmoe-tiny",
+        "smallthinker": "smallthinker-tiny"}
 
 
 def _tiny(family, experts, **dtypes):
-    cfg = dataclasses.replace(get_preset(TINY[family]), moe=None, **dtypes)
+    if family in ALWAYS_ROUTED:
+        # "dense": the preset's own experts; "routed": the count asked for
+        cfg = dataclasses.replace(get_preset(TINY[family]), **dtypes)
+    else:
+        cfg = dataclasses.replace(
+            get_preset(TINY[family]), moe=None, **dtypes)
     if experts:
         cfg = dataclasses.replace(cfg, moe=MoEConfig(
             num_experts=experts, top_k=2, dropless=True,

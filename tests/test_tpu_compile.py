@@ -444,3 +444,82 @@ def test_trinity_programs_fit_the_chip_and_keep_both_caches_in_place(
     else:
         assert mem.temp_size_in_bytes < 3.0e9
         assert "f32[1,1,200192]" in text.split("\n", 1)[0]
+
+
+# -------------------------------------------- SmallThinker's training step
+# The sixth cell's size: one chip's share of a four-way expert-parallel
+# layer, batch 2 x 8192 (``benchmarks/configs/smallthinker-21b-a3b.json``).
+
+
+@pytest.mark.parametrize("window, fwd, bwd", [
+    (4096, "flash_window_fwd", "flash_window_bwd"),
+    (None, "flash_fwd", "flash_bwd")], ids=["window", "full"])
+def test_flash_with_a_window_and_grouped_heads_compiles_for_v5e(
+        one_chip, window, fwd, bwd):
+    """28 query heads over 4 kv heads of 128 at 8192 tokens, forward and
+    backward: whole k and v (forward) and whole q, dO and dq (backward) of a
+    head are 2 MB each in VMEM, past the compiler's own 16 MiB limit, which
+    the calls raise for themselves; and each kind of call carries its name
+    (``benchmarks/lib/train_moe.py`` costs a call by it)."""
+    q = jax.ShapeDtypeStruct((2, 8192, 28, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    k = jax.ShapeDtypeStruct((2, 8192, 4, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def grads(q, k, v):
+        return jax.grad(
+            lambda q, k, v: flash_attention(
+                q, k, v, True, None, None, False, window)
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(grads).lower(q, k, k).compile().as_text()
+    calls = [line.split(" = ")[0] for line in text.splitlines()
+             if "tpu_custom_call" in line and " = " in line]
+    assert len(calls) == 2
+    assert sum(fwd in c for c in calls) == sum(bwd in c for c in calls) == 1
+    # k and v go in with the kv heads they have: nothing 28 heads wide but
+    # q, o, their gradients and each query head's own dk and dv
+    assert "bf16[2,8192,28,128]" in text and "bf16[8,8192,128]" in text
+
+
+def test_smallthinker_step_fits_the_chip_with_its_window_in_the_kernels(
+        one_chip):
+    """The cell's train step for the described chip: accepted at batch 2 x
+    8192 with remat ``dots`` (7.9 GB of state in, 9.3 GB of temporaries),
+    four flash calls forward and four backward (one full, three windowed
+    each), the grouped products as Mosaic kernels in both directions, and
+    no [B, H, T, T] array anywhere."""
+    import dataclasses
+    import re
+
+    from benchmarks import run
+    from benchmarks.lib import program
+    from ray_tpu.models import config_for
+    from ray_tpu.train.step import (
+        OptimizerConfig, create_train_state, make_train_step)
+
+    _, _, config, _, _ = run.load_cell("smallthinker-21b-a3b.train-seq8k")
+    model = program.trainer_model(config)
+    cfg = dataclasses.replace(
+        config_for(model.pop("family"), **model), attention_impl="flash")
+    assert cfg.moe.dropless and cfg.moe.num_held == 16
+    opt = OptimizerConfig().build()
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(
+            lambda: create_train_state(cfg, opt, jax.random.PRNGKey(0))))
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (2, 8193), jnp.int32, sharding=one_chip)}
+    compiled = make_train_step(cfg, opt).lower(state, batch).compile()
+    mem = compiled.memory_analysis()
+    assert 7.8e9 < mem.argument_size_in_bytes < 7.95e9  # 656.6M x 12 bytes
+    assert mem.temp_size_in_bytes < 9.6e9
+    text = compiled.as_text()
+    names = [line.split(" = ")[0] for line in text.splitlines()
+             if "tpu_custom_call" in line and " = " in line]
+    flash = [n for n in names if "flash_" in n]
+    assert sum("window_fwd" in n for n in flash) == 3
+    assert sum("window_bwd" in n for n in flash) == 3
+    assert len(flash) == 8
+    assert sum("ragged-dot-none" in n for n in names) >= 9 * 4
+    assert not re.search(r"\[\d+,28,8192,8192\]", text)
