@@ -126,7 +126,9 @@ def _remat_policy(config):
     """Checkpoint policy for the block body. "full" recomputes everything;
     "dots" (default) keeps matmul outputs + the flash-attention forward's
     named residuals (out + logsumexp, so the backward never re-runs the
-    attention kernel) and recomputes elementwise ops; "dots_all"
+    attention kernel) and the routed experts' two up-projections (grouped
+    products, which are no ``dot_general``: ``parallel/moe.py:_experts``)
+    and recomputes elementwise ops; "dots_all"
     additionally keeps batched dots — least recompute short of remat=False,
     for chips with HBM headroom."""
     if config.remat_policy == "full":
@@ -139,7 +141,7 @@ def _remat_policy(config):
     return jax.checkpoint_policies.save_from_both_policies(
         base,
         jax.checkpoint_policies.save_only_these_names(
-            "flash_out", "flash_lse"
+            "flash_out", "flash_lse", "moe_fc", "moe_gate"
         ),
     )
 

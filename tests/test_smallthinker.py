@@ -18,6 +18,7 @@ from benchmarks.lib import named
 from benchmarks.lib.cluster import BENCH_DIR
 from ray_tpu.models import decoder, get_preset, module_for
 from ray_tpu.parallel import moe
+from tests.test_moe_counts import _equations
 
 T = 32
 
@@ -208,6 +209,43 @@ def test_a_share_drops_no_row_whatever_the_routing(reference, sent):
                                    atol=2e-5, rtol=1e-4)
 
 
+@pytest.mark.parametrize("held, passes, kept, again", [
+    (8, 1, 10, 12), (2, 3, 25, 27)], ids=["one-pass", "under-the-cond"])
+def test_the_up_projections_are_kept_for_the_backward_pass(
+        held, passes, kept, again):
+    """Under the block's checkpoint policy the backward pass of a routed
+    layer runs the down product again and not the two up-projections (named
+    ``moe_fc`` / ``moe_gate``; a grouped product is no ``dot_general``): two
+    grouped products fewer than under ``remat_policy="full"``, through the
+    ``lax.cond`` of a share that may need further passes too (whose own 15,
+    forward, remat twice over and transposes, carry no name: an outer policy
+    reaches through the checkpoint around a pass, and three passes' worth of
+    residuals would be kept). The gradients are the same to the bit."""
+    config = moe.MoEConfig(num_experts=8, top_k=2, activation="reglu",
+                           dropless=True, num_held=held, first_held=0)
+    assert -(-96 * 2 // moe.held_rows_bound(96, config)) == passes
+    params = moe.init_moe_params(jax.random.PRNGKey(0), 64, 32, config)
+    y = jax.random.normal(jax.random.PRNGKey(1), (1, 96, 64))
+
+    def loss(params, y):
+        out, aux, _ = moe.moe_layer_counted(params, y, config)
+        return (out ** 2).sum() + aux["aux_loss"]
+
+    grads, products = {}, {}
+    for policy in ("dots", "full"):
+        cfg = _config(remat_policy=policy)
+        grad = jax.grad(jax.checkpoint(
+            loss, policy=decoder._remat_policy(cfg)), argnums=(0, 1))
+        products[policy] = sum(
+            e.primitive.name == "ragged_dot_general"
+            for e in _equations(jax.make_jaxpr(grad)(params, y).jaxpr))
+        grads[policy] = jax.jit(grad)(params, y)
+    assert products == {"dots": kept, "full": again}
+    for a, b in zip(jax.tree.leaves(grads["dots"]),
+                    jax.tree.leaves(grads["full"])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_a_share_is_dropless_and_inside_the_experts():
     with pytest.raises(ValueError, match="dropless"):
         moe.MoEConfig(num_experts=8, num_held=2)
@@ -246,9 +284,14 @@ def test_the_step_reports_cross_entropy_and_what_rides_beside_it():
 # Operations of the lowered programs of the three families that stood before
 # this one, counted on the commit before it (0440a15) and unchanged by it:
 # the pieces it added (``at_input``, a window and kv heads in the attention's
-# dispatch, the auxiliary results as a tree) leave them as they were.
-STANDING = {"gpt2-tiny step": 1902, "gpt2 decode": 347, "llama decode": 541,
-            "afmoe decode": 1096}
+# dispatch, the auxiliary results as a tree) leave them as they were. PR 41:
+# a routed layer's count is a compare and a column sum (``moe._count``) where
+# it was a scatter-add of ones with its region and the clamp of its indices,
+# five operations fewer a routed layer that the program spells out (llama's
+# one scanned layer 541 -> 536, afmoe's two 1,096 -> 1,086); the names on the
+# experts' two up-projections lower to nothing, so the dense two stand.
+STANDING = {"gpt2-tiny step": 1902, "gpt2 decode": 347, "llama decode": 536,
+            "afmoe decode": 1086}
 DECODE = {
     "gpt2": {},
     "llama": {"moe_num_experts": 4, "num_kv_heads": 2},
