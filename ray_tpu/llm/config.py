@@ -41,12 +41,40 @@ class LLMConfig:
     moe_mlp_dim: Optional[int] = None    # afmoe: one expert's width
     num_dense_layers: Optional[int] = None    # afmoe: leading dense layers
     num_shared_experts: Optional[int] = None  # afmoe: beside the routed
-    # afmoe: "sliding_attention" | "full_attention" a layer, and the window
+    # afmoe: "sliding_attention" | "full_attention" a layer, and the window;
+    # granite_hybrid: "mamba" | "attention" a layer
     layer_types: Optional[Any] = None
     sliding_window: Optional[int] = None
     mup_enabled: Optional[bool] = None   # afmoe: embedding x sqrt(embed_dim)
     # smallthinker: 1 a layer with the window (and RoPE), 0 a global one
     sliding_window_layout: Optional[Any] = None
+    # granite_hybrid (IBM granitemoehybrid), under config.json's own names:
+    # a state layer (Mamba-2) keeps ``mamba_n_heads`` heads of
+    # ``mamba_d_head`` channels (``mamba_expand`` x embed_dim in all) with a
+    # state of ``mamba_d_state`` each, B and C in ``mamba_n_groups`` groups
+    # (1), behind a causal convolution of ``mamba_d_conv`` taps; a prefill
+    # scans in chunks of ``mamba_chunk_size``; whether the convolution and
+    # the two projections carry a bias
+    mamba_d_state: Optional[int] = None
+    mamba_d_conv: Optional[int] = None
+    mamba_expand: Optional[int] = None
+    mamba_n_heads: Optional[int] = None
+    mamba_d_head: Optional[int] = None
+    mamba_n_groups: Optional[int] = None
+    mamba_chunk_size: Optional[int] = None
+    mamba_conv_bias: Optional[bool] = None
+    mamba_proj_bias: Optional[bool] = None
+    # granite_hybrid's four stated factors: on the embedding, on q . k (in
+    # place of head_dim ** -0.5), on each branch before the residual sum,
+    # and the divisor of the logits
+    embedding_multiplier: Optional[float] = None
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: Optional[float] = None
+    logits_scaling: Optional[float] = None
+    # what the engine's cache holds a state layer's state in: "float32" is
+    # the one value the family takes (decays near 1 over thousands of steps
+    # gather a narrower rounding); stated so that a configuration says it
+    ssm_state_dtype: Optional[str] = None
     # dtype the weights are made (fresh) or loaded (a bundle) in; None = the
     # family's (float32). What a replica HOLDS follows from it and ``dtype``:
     # the engine keeps each weight its family's forward rounds to ``dtype``
@@ -121,7 +149,13 @@ class LLMConfig:
                      "qk_norm", "param_dtype", "head_dim", "moe_mlp_dim",
                      "num_dense_layers", "num_shared_experts", "layer_types",
                      "sliding_window", "mup_enabled",
-                     "sliding_window_layout"):
+                     "sliding_window_layout", "mamba_d_state",
+                     "mamba_d_conv", "mamba_expand", "mamba_n_heads",
+                     "mamba_d_head", "mamba_n_groups", "mamba_chunk_size",
+                     "mamba_conv_bias", "mamba_proj_bias",
+                     "embedding_multiplier", "attention_multiplier",
+                     "residual_multiplier", "logits_scaling",
+                     "ssm_state_dtype"):
             if getattr(self, name) is not None:
                 kwargs[name] = getattr(self, name)
         if self.moe_num_experts:

@@ -74,6 +74,19 @@ names its program by ``tick`` and carries its count) and, with a host row,
 the span's own. One span is the loading thread's: ``engine.weights``, once
 a replica, around the weights' arrival as the engine holds them.
 
+A model with state layers (``ops/ssm.py``: a recurrence in place of
+attention; ``models/kv_cache.py``) keeps a slot's states in the same cache
+pytree, slot on axis 1: a prefill chunk starts from the state the chunk
+before left in the slot cache and leaves the state after its last REAL token
+(its programs take ``real`` as a router's do), ``insert`` writes the whole of
+the admitted request's state over the last tenant's, and a tick reads and
+writes the states of the slots that decode and of no other.
+``state_slot_layers`` of ``engine.tick`` (slots that decode x ``layers_state``)
+and ``ssm_prefill_tokens`` of ``engine.admit`` (real tokens the admission's
+scans took) are those counters, summed in ``stats`` under the same names. The
+prefix store keeps whole prompts only, and prompt-lookup speculation is
+refused at construction: a rejected draft's state cannot be rolled back.
+
 A model with routed experts (``parallel/moe.py``) is told which rows of a
 program carry a token (the slots that decode, a prompt's own positions in
 its prefill bucket), so that nothing else is routed, and its programs hand
@@ -229,16 +242,20 @@ def engine_programs(cfg):
     the weights as the engine holds them (``DecodeEngine``). The cache is
     the model module's pytree with the slot on axis 1; ``insert`` and both
     decodes take it donated and give it back in the same buffer. For a
-    model with routed experts the three model programs are told how many
-    of a row's tokens are tokens (``real`` [B]: one more argument; the last
-    row of ``decode``'s ``packed``, which every model's has), and give one
-    more result after the cache, the experts touched a layer [L]."""
+    model with routed experts or state layers the three model programs are
+    told how many of a row's tokens are tokens (``real`` [B]: one more
+    argument; the last row of ``decode``'s ``packed``, which every model's
+    has), and give one more result after the cache, the experts touched a
+    layer [L] (zeros where no layer is routed)."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.models.decoder import forward_cached, layer_kinds
 
-    routed = any(kind.routed for kind in layer_kinds(cfg))
+    # routed experts route the rows that carry a token and no other, and a
+    # state layer steps on those alone: either takes ``real``
+    takes_real = any(kind.routed or kind.state is not None
+                     for kind in layer_kinds(cfg))
 
     def prefill(params, tokens, cache1, start, *real, rows=None):
         # start > 0 = continuation from a cached prefix or from the chunk
@@ -248,9 +265,11 @@ def engine_programs(cfg):
             params, tokens, cache1, start, cfg, *real, rows=rows)
 
     def insert(batch_cache, slot_cache, b):
+        # the slot is axis 1 of every leaf, whatever its rank (keys and
+        # values 5, a state layer's convolution rows 3)
         return jax.tree.map(
             lambda c, s1: jax.lax.dynamic_update_slice(
-                c, s1.astype(c.dtype), (0, b, 0, 0, 0)
+                c, s1.astype(c.dtype), (0, b) + (0,) * (c.ndim - 2)
             ),
             batch_cache, slot_cache,
         )
@@ -266,7 +285,7 @@ def engine_programs(cfg):
         tokens = jnp.where(given < 0, before, given)
         logits, *rest = forward_cached(
             params, tokens[:, None], cache, lens, cfg,
-            *((real,) if routed else ()), live=real > 0)
+            *((real,) if takes_real else ()), live=real > 0)
         logits = logits[:, -1]
         # a greedy row's next token (the first index on a tie, as numpy's)
         ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -337,6 +356,11 @@ class DecodeEngine:
         # window layers: how many, and their window (0: none)
         self._layers_window = sum(k.window is not None for k in kinds)
         self._window = max((k.window or 0 for k in kinds), default=0)
+        # state layers: what a slot carries through them is a state, whatever
+        # its length (``models/kv_cache.py``)
+        self._layers_state = sum(k.state is not None for k in kinds)
+        # the model programs take ``real``: a router's rows, a state's steps
+        self._takes_real = bool(self._moe_layers or self._layers_state)
         self.tokenizer = load_tokenizer(config)
         self._span = jax.profiler.TraceAnnotation
         # The weights as the family's cached forward wants them of a caller
@@ -368,14 +392,23 @@ class DecodeEngine:
         self._spec_k = max(
             0, int(getattr(config, "speculative_ngram_k", 0) or 0)
         )  # negatives = disabled, never a half-armed dispatch path
+        if self._spec_k and self._layers_state:
+            raise ValueError(
+                "speculative_ngram_k > 0 with state layers: a rejected "
+                "draft's tokens have stepped the state, and a state cannot "
+                "be rolled back to the token before them (a column can be "
+                "overwritten); it needs a snapshot a slot, which is not "
+                "there")
         # the longest block of tokens one program writes: a window layer's
         # ring has room for it beside the window (``models/kv_cache.py``)
         block = max(*config.prefill_buckets, 1 + self._spec_k)
         self._cache = decoder.init_kv_cache(cfg, B, S, block=block)
-        self._layers_full = len(kinds) - self._layers_window
+        self._layers_full = (
+            len(kinds) - self._layers_window - self._layers_state)
         # the lengths short of a whole prompt that the prefix store keeps
         self._boundaries = tuple(config.prefill_buckets) if (
-            config.prefix_cache_size > 0 and not self._layers_window) else ()
+            config.prefix_cache_size > 0 and not self._layers_window
+            and not self._layers_state) else ()
         self._rng = np.random.RandomState(seed)
 
         self._prefill, self._insert, self._decode, decode_all = (
@@ -433,6 +466,10 @@ class DecodeEngine:
             # distinct experts that received one, summed over layers and
             # programs; both 0 for a dense model
             "moe_rows": 0, "moe_experts_touched": 0,
+            # state layers: slots that decode x state layers over ticks (the
+            # states a tick reads and writes), and prompt tokens that went
+            # through a prefill's scan; both 0 for a model with none
+            "state_slot_layers": 0, "ssm_prefill_tokens": 0,
             "compiles": compile_count(),  # of the process, not the engine
         }
         self._rid_seq = itertools.count(1)
@@ -545,9 +582,9 @@ class DecodeEngine:
         """The lengths of a prompt of ``n`` tokens, prefilled from ``base``
         on, whose last row's logits the host reads: the prompt's own, and
         the bucket boundaries the prefix store keeps (system prompts shared
-        by many requests match through these). A model with window layers
-        keeps whole prompts only: a ring that went on past a boundary is
-        not that prefix's cache."""
+        by many requests match through these). A model with window or state
+        layers keeps whole prompts only: a ring or a state that went on
+        past a boundary is not that prefix's."""
         return {n} | {b for b in self._boundaries if base < b < n}
 
     def _prefix_store_locked(self, prompt_ids, cache1, logits_rows):
@@ -566,10 +603,11 @@ class DecodeEngine:
 
     def _real(self, counts) -> tuple:
         """The model programs' last argument: how many of each row's tokens
-        are tokens. A dense model's programs take none."""
+        are tokens. A dense model's programs (attention in every layer, no
+        router) take none."""
         import jax.numpy as jnp
 
-        return (jnp.asarray(counts, jnp.int32),) if self._moe_layers else ()
+        return (jnp.asarray(counts, jnp.int32),) if self._takes_real else ()
 
     def _moe_rows(self, real_rows: int) -> int:
         """A span's ``moe_rows``: what a program of ``real_rows`` rows that
@@ -577,6 +615,16 @@ class DecodeEngine:
         rows = real_rows * self._moe_top_k * self._moe_layers
         self.stats["moe_rows"] += rows
         return rows
+
+    def _state_count(self, name: str, count: int) -> dict:
+        """A span's ``ssm_prefill_tokens`` (real tokens an admission's scans
+        took) or ``state_slot_layers`` (slots that decode x state layers),
+        with ``layers_state`` to divide by; summed into ``stats``. Nothing
+        for a model without state layers."""
+        if not self._layers_state:
+            return {}
+        self.stats[name] += count
+        return {name: count, "layers_state": self._layers_state}
 
     def _experts_touched(self, touched) -> int:
         """A span's ``experts_touched`` of a program's count a layer, on the
@@ -618,7 +666,8 @@ class DecodeEngine:
                 )
             return entry["cache"], first, lp, {
                 "bucket": 0, "chunks": 0, "prefix": "exact", "moe_rows": 0,
-                "experts_touched": 0}
+                "experts_touched": 0,
+                **self._state_count("ssm_prefill_tokens", 0)}
         largest = max(self.config.prefill_buckets)
         if entry is not None and n - matched <= largest and (
             matched + self._bucket(n - matched) > self.config.max_seq_len
@@ -674,7 +723,8 @@ class DecodeEngine:
             )
         return cache1, first, lp, {
             "bucket": Tpad, "chunks": len(programs),
-            "prefix": "partial" if base else "none", **moe}
+            "prefix": "partial" if base else "none", **moe,
+            **self._state_count("ssm_prefill_tokens", n - base)}
 
     def _activate_slot_locked(self, b, cache1, first, req: _Pending,
                               prompt_len, prompt_ids=(), first_lp=None,
@@ -760,7 +810,8 @@ class DecodeEngine:
             prompt_ids = tuple(prefilled.get("prompt_ids", ()))
             first_lp = prefilled.get("first_logprob")
             how = {"bucket": 0, "chunks": 0, "prefix": "none",
-                   "moe_rows": 0, "experts_touched": 0}
+                   "moe_rows": 0, "experts_touched": 0,
+                   **self._state_count("ssm_prefill_tokens", 0)}
             if params.seed is not None:
                 rng = self._rng_for(params)
                 if params.temperature > 0:
@@ -999,6 +1050,8 @@ class DecodeEngine:
                 self.stats[name] += count
             self.stats["compiles"] = compile_count()
             moe_rows = self._moe_rows(int(real.sum()))
+            state = self._state_count(
+                "state_slot_layers", len(rows) * self._layers_state)
             self._read_locked(flying)
             # a row of this program whose answer ended in that read
             overrun = sum(not self._slots[i].active for i in rows)
@@ -1013,7 +1066,7 @@ class DecodeEngine:
                 cache_positions=cache_positions, overrun=overrun,
                 moe_rows=moe_rows, experts_touched=self._touched_unspanned,
                 layers_full=self._layers_full,
-                layers_window=self._layers_window, **positions)
+                layers_window=self._layers_window, **positions, **state)
             self._touched_unspanned = 0
 
     def _read_locked(self, tick: Optional[_Tick]) -> None:

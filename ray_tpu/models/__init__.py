@@ -7,16 +7,18 @@ stack (``forward_features``, ``forward``), the cached forward
 (``FAMILIES``) holds the pieces that differ: its ``Config`` dataclass and
 ``PRESETS``, ``init_params`` and ``param_axes``, and the pieces the decoder
 calls (``layers``, ``embed``, ``qkv``, ``attn_out``, ``ffn``, ``final_norm``,
-``head``, ``head_weight``; ``decoder.py`` gives each one's signature) and the one a
-server calls once (``serving_params``), and it hands the decoder's functions
-on under its own name, so ``module_for(cfg).loss_fn`` is the one
+``head``, ``head_weight``, and ``state_in`` / ``state_out`` / ``state_leaves``
+where a layer keeps a state; ``decoder.py`` gives each one's signature) and
+the one a server calls once (``serving_params``), and it hands the decoder's
+functions on under its own name, so ``module_for(cfg).loss_fn`` is the one
 definition. A new architecture is a family module, or a piece of one, and
 one line of ``FAMILIES``.
 
 Train/LLM layers find a config's family via :func:`module_for`, and build a
 family's config from plain keyword arguments via :func:`config_for`.
 The KV cache is ``{"k", "v"}``, each ``[L, B, KV, D, S]``, and for a model with
-window layers their rings beside it (``kv_cache.py``): callers outside this
+window layers their rings, with state layers their states, beside it
+(``kv_cache.py``): callers outside this
 package rely on the slot being axis 1 of every leaf and on nothing else.
 """
 from __future__ import annotations
@@ -30,6 +32,7 @@ FAMILIES = {
     "llama": "ray_tpu.models.llama",
     "afmoe": "ray_tpu.models.afmoe",
     "smallthinker": "ray_tpu.models.smallthinker",
+    "granite_hybrid": "ray_tpu.models.granite_hybrid",
 }
 
 

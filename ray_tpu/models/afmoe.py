@@ -40,7 +40,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import narrowed
 from ray_tpu.models.decoder import *  # noqa: F401,F403 — what families share
-from ray_tpu.models.decoder import Layer, Segment
+from ray_tpu.models.decoder import Layer, Segment, periods
 from ray_tpu.models.llama import _rms_norm, _rope
 from ray_tpu.parallel.moe import (
     MoEConfig,
@@ -162,18 +162,8 @@ def _kinds(config: AfmoeConfig) -> Tuple[Layer, ...]:
 
 
 def _plan(config: AfmoeConfig):
-    """[(kinds of one period, repeats)]: a lead that fits no period (one
-    repeat) and the shortest period of what follows, chosen so that the
-    kinds to compile, lead + period, are fewest."""
-    kinds = _kinds(config)
-    L = len(kinds)
-    lead, period = min(
-        ((lead, p) for lead in range(L) for p in range(1, L - lead + 1)
-         if (L - lead) % p == 0
-         and kinds[lead:] == kinds[lead:lead + p] * ((L - lead) // p)),
-        key=lambda lp: (sum(lp), lp[0]))
-    plan = [(kinds[:lead], 1)] if lead else []
-    return plan + [(kinds[lead:lead + period], (L - lead) // period)]
+    """[(kinds of one period, repeats)]: ``decoder.periods`` of the stack."""
+    return periods(_kinds(config))
 
 
 def _routed_layers(config: AfmoeConfig) -> int:

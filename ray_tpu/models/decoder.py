@@ -29,6 +29,17 @@ one signature across families:
   out of the layer scan, ``from_input`` what ``at_input`` gave. The aux loss
   is a scalar, or for a layer that holds a share of the experts the loss
   beside its counts of rows (``moe.aux_zero``): the stack adds up either;
+- for a family with state layers (``Layer.state``: a recurrence in place of
+  attention, so no ``qkv`` / ``attn_out``), three pieces more:
+  ``state_in(config, kind, layer, x)`` -> (what enters the layer's
+  convolution [B, T, C], dt [B, T, H] float32 and positive, whatever
+  ``state_out`` wants kept); between the two the skeleton runs
+  ``kv_cache.recur`` (from the cache's state in the cached forward, from
+  zeros in the full one) over the layer's ``conv_w``, ``conv_b``, ``A_log``
+  and ``D``; ``state_out(config, layer, x, y, kept)`` -> the stream after
+  the gate, the output projection and the residual;
+  ``state_leaves(config)`` names such a cache's leaves: name -> (shape a
+  slot, dtype);
 - ``final_norm(config, params, x)``, ``head(config, params, x)`` -> float32
   logits, ``head_weight(params)`` -> the [V, E] matrix the chunked
   cross-entropy multiplies by;
@@ -57,8 +68,9 @@ A stack of several kinds is a few ``Segment``s, each one period of kinds
 scanned as many times as it repeats (a leading dense layer, then periods of
 three window layers and a full one): every kind is compiled once, whatever
 the depth. What the skeleton must know of a kind is in ``Layer``: whether
-its attention is windowed (its cache is then a ring, ``kv_cache.py``) and
-whether its feed-forward reads the experts' stack.
+its attention is windowed (its cache is then a ring, ``kv_cache.py``),
+whether its feed-forward reads the experts' stack, and whether its mixer is
+a state and no attention at all.
 """
 from __future__ import annotations
 
@@ -82,6 +94,11 @@ class Layer(NamedTuple):
     # cache a ring); None: over everything before it
     window: Optional[int] = None
     routed: bool = False          # its ``ffn`` reads the experts' stack
+    # its mixer is a recurrence over a state a sequence carries (the
+    # family's ``state_in`` / ``state_out`` around ``kv_cache.recur``), not
+    # attention over a column a position: the chunk its scan takes a block
+    # of tokens in; None: attention
+    state: Optional[int] = None
 
 
 class Segment(NamedTuple):
@@ -108,6 +125,21 @@ def single_kind(config, blocks, cached: bool):
             experts)
 
 
+def periods(kinds: Tuple[Layer, ...]):
+    """[(kinds of one period, repeats)] over a stack of ``kinds``: a lead
+    that fits no period (one repeat) and the shortest period of what
+    follows, chosen so that the kinds to compile, lead + period, are
+    fewest."""
+    L = len(kinds)
+    lead, period = min(
+        ((lead, p) for lead in range(L) for p in range(1, L - lead + 1)
+         if (L - lead) % p == 0
+         and kinds[lead:] == kinds[lead:lead + p] * ((L - lead) // p)),
+        key=lambda lp: (sum(lp), lp[0]))
+    plan = [(kinds[:lead], 1)] if lead else []
+    return plan + [(kinds[lead:lead + period], (L - lead) // period)]
+
+
 def layer_kinds(config) -> Tuple[Layer, ...]:
     """Every layer's kind, first to last."""
     segments, _ = module_for(config).layers(config, None, cached=True)
@@ -119,7 +151,7 @@ def layer_kinds(config) -> Tuple[Layer, ...]:
 # ``module_for(cfg).loss_fn``): each the one definition below
 __all__ = ["forward_features", "forward", "init_kv_cache", "forward_cached",
            "forward_pipelined", "loss_fn", "count_params", "Layer", "Segment",
-           "single_kind"]
+           "single_kind", "periods"]
 
 
 def _remat_policy(config):
@@ -209,11 +241,14 @@ def _body(config, mesh: Optional[Mesh], pos, kind: Layer = Layer(),
         if own is not None:
             stacked = (jax.tree.map(lambda w: w[own], stacked[0]), None)
         from_input = family.at_input(config, kind.name, layer, x, stacked)
-        q, k, v = family.qkv(config, kind.name, layer, x, pos)
-        if q.ndim == 5:  # [B, T, KV, G, D]: G query heads share a kv head
-            q = q.reshape(*q.shape[:2], -1, q.shape[-1])
-        attn = _attention_dispatch(config, q, k, v, mesh, kind.window)
-        x = family.attn_out(config, layer, x, attn)
+        if kind.state is not None:
+            x = _recur(config, kind, layer, x, None)[0]
+        else:
+            q, k, v = family.qkv(config, kind.name, layer, x, pos)
+            if q.ndim == 5:  # [B, T, KV, G, D]: G query heads a kv head
+                q = q.reshape(*q.shape[:2], -1, q.shape[-1])
+            attn = _attention_dispatch(config, q, k, v, mesh, kind.window)
+            x = family.attn_out(config, layer, x, attn)
         x, aux, _ = family.ffn(
             config, kind.name, layer, x, rng=rng, row_mask=None,
             stacked=stacked, from_input=from_input)
@@ -222,6 +257,18 @@ def _body(config, mesh: Optional[Mesh], pos, kind: Layer = Layer(),
     if config.remat:
         return jax.checkpoint(block, policy=_remat_policy(config))
     return block
+
+
+def _recur(config, kind: Layer, layer, x, carried):
+    """A state layer's mixer between the family's two pieces -> (x, cache):
+    ``carried`` as ``kv_cache.recur`` takes it (None: from a zero state, and
+    the cache that comes back is None)."""
+    family = module_for(config)
+    xbc, dt, kept = family.state_in(config, kind.name, layer, x)
+    cache, y = kv_cache.recur(
+        carried, xbc, dt, layer,
+        family.state_leaves(config)[kv_cache.STATE[0]][0], kind.state)
+    return family.state_out(config, layer, x, y, kept), cache
 
 
 def _embed(params, tokens, config):
@@ -342,19 +389,23 @@ def init_kv_cache(config, batch: int, max_len: int, dtype=None,
     heads only, an H/KV-fold HBM saving over caching query-expanded heads.
     A model with window layers holds those layers' columns in rings of
     their own beside it (``{"k_window", "v_window"}``), long enough for the
-    window and the longest ``block`` of tokens one call will write.
+    window and the longest ``block`` of tokens one call will write; one with
+    state layers their states (``{"ssm", "conv"}``), whatever ``max_len``.
     (Reference capability analog: the vLLM engine Ray LLM delegates to —
     ``llm/_internal/serve/engines/vllm``; here the cache is a jax pytree so
     the whole decode step stays one XLA program.)"""
-    windows = [k.window for k in layer_kinds(config)]
+    kinds = layer_kinds(config)
+    windows = [k.window for k in kinds if k.state is None]
     lengths = {w for w in windows if w is not None}
     if len(lengths) > 1:
         raise ValueError(f"window layers of several lengths: {lengths}")
     ring = kv_cache.ring_length(lengths.pop(), block, max_len) if lengths else 0
+    states = len(kinds) - len(windows)
     return kv_cache.init_kv_cache(
         windows.count(None), batch, config.num_kv_heads, config.head_dim,
         max_len, dtype or config.dtype, len(windows) - windows.count(None),
-        ring,
+        ring, states,
+        module_for(config).state_leaves(config) if states else None,
     )
 
 
@@ -399,7 +450,7 @@ def forward_cached(
 
     segments, experts = family.layers(config, params["blocks"], cached=True)
     windows = {k.window for s in segments for k in s.kinds} - {None}
-    at = kv_cache.step(start, T, cache, *windows, live=live)
+    at = kv_cache.step(start, T, cache, *windows, live=live, real=real)
     mask = None if real is None else jnp.arange(T)[None, :] < real[:, None]
     # The experts' weights stay out of the scan: it would hand each layer
     # its slice, and a slice that feeds a kernel is a copy (``moe._experts``)
@@ -408,20 +459,25 @@ def forward_cached(
 
     def block(kind, x, layer, index, stacked, cache):
         from_input = family.at_input(config, kind.name, layer, x, stacked)
-        q, k_new, v_new = family.qkv(config, kind.name, layer, x, pos)
-        # the cache is attended as the family groups its heads (GQA: the
-        # query heads of a kv head together); the projection takes them flat
-        cache, attn = kv_cache.attend(
-            cache, index, q, k_new, v_new, at, kind.window is not None)
-        x = family.attn_out(
-            config, layer, x, attn.reshape(B, T, -1, attn.shape[-1]))
+        if kind.state is not None:
+            x, cache = _recur(config, kind, layer, x, (cache, index, at))
+        else:
+            q, k_new, v_new = family.qkv(config, kind.name, layer, x, pos)
+            # the cache is attended as the family groups its heads (GQA:
+            # the query heads of a kv head together); the projection takes
+            # them flat
+            cache, attn = kv_cache.attend(
+                cache, index, q, k_new, v_new, at, kind.window is not None)
+            x = family.attn_out(
+                config, layer, x, attn.reshape(B, T, -1, attn.shape[-1]))
         x, _, touched = family.ffn(
             config, kind.name, layer, x, rng=None, row_mask=mask,
             stacked=stacked, from_input=from_input)
         return x, cache, touched
 
     cache_at = _places(
-        segments, lambda kind: "ring" if kind.window is not None else "full")
+        segments, lambda kind: "state" if kind.state is not None
+        else "ring" if kind.window is not None else "full")
     routed_at = _places(
         segments, lambda kind: "experts" if kind.routed and experts is not None
         else None)

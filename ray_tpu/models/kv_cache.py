@@ -29,6 +29,19 @@ its first token still sees, nor a rejected draft on one that the token it is
 rolled back to sees. A ring slot's position follows from the last position
 written, so nothing beside the arrays is kept. The full layers keep ``{"k",
 "v"}`` and count their own layers, the window layers theirs.
+
+A model with state layers (a recurrence in place of attention: ``ops/ssm.py``)
+holds a third pair, ``{"ssm", "conv"}``: ``[Ls, B, H, P, N]`` float32, the
+state a head, and ``[Ls, B, (K - 1) C]``, the last ``K - 1`` rows that
+entered the layer's convolution, one after another (flat: with ``[.., K - 1,
+C]`` or ``[.., C, K - 1]`` minor the chip pads three rows to a tile of 8 or
+128, and its compiler repacks the whole leaf around every layer's write, 0.75
+ms a tick at 48 slots). Slot on axis 1 like the rest, and nothing
+in them grows with the position. ``recur`` is such a layer's whole access,
+as ``attend`` is an attention layer's: a block of tokens takes the layer's
+state out, scans from it and puts back the state after the block's last REAL
+token (``Step.real``); a decode step on a TPU is one kernel over the whole
+state that reads and writes the slots that decode and no other.
 """
 from __future__ import annotations
 
@@ -37,10 +50,12 @@ from typing import Dict, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import ssm
 from ray_tpu.ops.decode_attention import TILE, decode_attention, live_slots
 
 
 FULL, WINDOW = ("k", "v"), ("k_window", "v_window")
+STATE = ("ssm", "conv")
 
 
 def ring_length(window: int, block: int, max_len: int) -> int:
@@ -55,10 +70,14 @@ def ring_length(window: int, block: int, max_len: int) -> int:
 
 def init_kv_cache(num_layers: int, batch: int, kv_heads: int, head_dim: int,
                   max_len: int, dtype, window_layers: int = 0,
-                  ring: int = 0) -> Dict[str, jax.Array]:
-    """``num_layers`` full layers of ``max_len`` positions and
-    ``window_layers`` rings of ``ring``."""
-    cache = {}
+                  ring: int = 0, state_layers: int = 0,
+                  state=None) -> Dict[str, jax.Array]:
+    """``num_layers`` full layers of ``max_len`` positions,
+    ``window_layers`` rings of ``ring`` and ``state_layers`` states:
+    ``state`` names each of their leaves' (shape a slot, dtype)."""
+    cache = {name: jnp.zeros((state_layers, batch, *shape), kind)
+             for name, (shape, kind) in (state or {}).items()
+             if state_layers}
     for names, layers, length in ((FULL, num_layers, max_len),
                                   (WINDOW, window_layers, ring)):
         if layers or (names is FULL and not window_layers):
@@ -75,7 +94,9 @@ class Step(NamedTuple):
     tokens, as the decode kernel takes them (``live_slots``; None: every
     slot). What the caller says of a slot, never read off its length. The
     kernel leaves any other slot alone; the XLA path computes every slot and
-    the caller drops the rows it did not ask for."""
+    the caller drops the rows it did not ask for. ``real``: how many of a
+    slot's T tokens are tokens (None: all), for a state layer, which must
+    not step on the rest."""
     start: jax.Array   # [B] int32
     mask: Optional[jax.Array]    # [B, T, S] bool
     hit: Optional[jax.Array]     # [B, T, S] bool
@@ -83,11 +104,13 @@ class Step(NamedTuple):
     ring_mask: Optional[jax.Array] = None   # [B, T, R] bool
     ring_hit: Optional[jax.Array] = None    # [B, T, R] bool
     live: Optional[jax.Array] = None        # [B + 1] int32
+    real: Optional[jax.Array] = None        # [B] int32
 
 
 def step(start: jax.Array, T: int, cache: Dict[str, jax.Array],
          window: Optional[int] = None,
-         live: Optional[jax.Array] = None) -> Step:
+         live: Optional[jax.Array] = None,
+         real: Optional[jax.Array] = None) -> Step:
     """Token t of slot b sits at position ``start[b] + t``, sees the keys up
     to itself and lands on its own position. A position past the end marks
     nothing, so such a token is dropped (a ``dynamic_update_slice`` would
@@ -110,7 +133,7 @@ def step(start: jax.Array, T: int, cache: Dict[str, jax.Array],
         ring_mask = (held >= 0) & (held <= pos) & (held > pos - window)
         ring_hit = pos % R == slot
     return Step(start, mask, hit, window, ring_mask, ring_hit,
-                None if live is None else live_slots(live))
+                None if live is None else live_slots(live), real)
 
 
 def _decode_impl() -> str:
@@ -210,3 +233,69 @@ def _write(rows: jax.Array, new: jax.Array, hit: jax.Array) -> jax.Array:
         precision=jax.lax.Precision.HIGHEST,
     )
     return jnp.where(hit.any(1)[:, None, None, :], placed, rows)
+
+
+def recur(carried, xbc, dt, layer, shape, chunk: int):
+    """A state layer's recurrence over xbc [B, T, C] (what enters the
+    convolution: x, B and C side by side) and dt [B, T, H] float32, positive:
+    the convolution, then ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t``,
+    ``y_t = S_t C_t + D x_t`` -> (cache, y [B, T, H, P] float32). ``layer``
+    holds ``conv_w`` [C, K], ``conv_b`` [C], ``A_log`` and ``D`` [H];
+    ``shape`` is a slot's state (H, P, N), ``chunk`` the scan's.
+
+    ``carried`` is (cache, the layer's index among the state layers, the
+    ``Step``): the recurrence starts from the cache's state and tail and
+    leaves there what they are after the last real token. None: from zeros
+    (the full forward), and the cache returned is None."""
+    B, T, C = xbc.shape
+    f32 = jnp.float32
+    cache, index, at = carried or (None, None, None)
+    A, D = -jnp.exp(layer["A_log"].astype(f32)), layer["D"].astype(f32)
+    heads, _, d_state = shape
+    inner = C - 2 * d_state
+    real = None if at is None else at.real
+    taps = layer["conv_w"].shape[-1]
+    if cache is None:
+        tail = jnp.zeros((B, taps - 1, C), xbc.dtype)
+        state = jnp.zeros((B, *shape), f32)
+    else:
+        tail = jax.lax.dynamic_index_in_dim(
+            cache[STATE[1]], index, 0, False).reshape(B, taps - 1, C)
+    with jax.named_scope("ssm.conv"):
+        mixed, tail = ssm.conv(xbc, tail, layer["conv_w"], layer["conv_b"],
+                               real)
+        x, Bm, Cm = jnp.split(mixed, [inner, inner + d_state], axis=-1)
+        x = x.reshape(B, T, heads, -1)
+    if real is not None:    # a step that is no token: dt 0 leaves the state
+        dt = jnp.where(jnp.arange(T)[None, :, None] < real[:, None, None],
+                       dt, 0.0)
+    kernel = cache is not None and T == 1 and _decode_impl() != "xla"
+    if kernel:
+        with jax.named_scope("ssm.update"):
+            y, states = ssm.ssm_update(
+                cache[STATE[0]], index, x[:, 0], dt[:, 0], A, Bm[:, 0],
+                Cm[:, 0], live=at.live,
+                interpret=_decode_impl() == "pallas_interpret")
+        y = y[:, None]
+    else:
+        if cache is not None:
+            state = jax.lax.dynamic_index_in_dim(
+                cache[STATE[0]], index, 0, False)
+        if T == 1:
+            with jax.named_scope("ssm.update"):
+                y, state = ssm.ssm_update_xla(
+                    state, x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0],
+                    None if real is None else real > 0)
+            y = y[:, None]
+        else:
+            with jax.named_scope("ssm.scan"):
+                y, state = ssm.ssm_scan(x, dt, A, Bm, Cm, state, chunk)
+    y = y + D[:, None] * x.astype(f32)
+    if cache is None:
+        return None, y
+    if not kernel:
+        states = jax.lax.dynamic_update_index_in_dim(
+            cache[STATE[0]], state, index, 0)
+    return {**cache, STATE[0]: states,
+            STATE[1]: jax.lax.dynamic_update_index_in_dim(
+                cache[STATE[1]], tail.reshape(B, -1), index, 0)}, y

@@ -473,9 +473,15 @@ def test_a_reader_returns_the_hand_sum_over_the_spans(
     from benchmarks.lib import host_spans
 
     with open(os.path.join(run.CHECKOUT, "BENCHMARK.json")) as f:
-        listed = {m["name"]: m for m in json.load(f)["per_layer"]}
+        bench = json.load(f)
+    listed = {m["name"]: m for m in bench["per_layer"]}
     assert listed[metric]["moves"] == "per_token_p50_ms"
-    assert len(listed[metric]["workloads"]) == 3
+    # every serving cell, none dropped: the cells that report the metric
+    # these move (the three of PR 36 and PR 42's)
+    serving = next(m["workloads"] for m in bench["end_to_end"]
+                   if m["name"] == "per_token_p50_ms")
+    assert len(serving) == 4
+    assert listed[metric]["workloads"] == serving
     monkeypatch.setattr(host_spans, "TRACE_ROOT", recording["logdir"])
     got = run.read_layer_metric(
         metric, None, {"decode_program": "jit_decode"})

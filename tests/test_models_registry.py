@@ -26,11 +26,15 @@ from ray_tpu.parallel.moe import MoEConfig
 # its weights, and what callers outside ask of one
 PIECES = ("layers", "embed", "at_input", "qkv", "attn_out", "ffn",
           "final_norm", "head", "head_weight", "serving_params")
+# what a family with state layers has beside them (``decoder.Layer.state``)
+STATE_PIECES = ("state_in", "state_out", "state_leaves")
 OWN = ("Config", "PRESETS", "EXPERT_ACTIVATION", "init_params", "param_axes")
 SHARED = ("forward_features", "forward", "init_kv_cache", "forward_cached",
           "forward_pipelined", "loss_fn", "count_params")
 # families with no dense form: every layer routed, whatever is stated
 ALWAYS_ROUTED = ("smallthinker",)
+# families with no routed form: experts are refused by the key's name
+NEVER_ROUTED = ("granite_hybrid",)
 GATED = ("swiglu", "reglu")   # activations with a gate matrix
 
 
@@ -57,7 +61,10 @@ def test_a_family_is_the_pieces_and_nothing_of_the_skeleton(family):
     public = {n for n, v in vars(module).items()
               if not n.startswith("_") and inspect.isfunction(v)
               and v.__module__ == module.__name__}
-    assert public == set(PIECES) | {"init_params", "param_axes"}
+    stateful = any(kind.state is not None for kind in decoder.layer_kinds(
+        next(iter(module.PRESETS.values()))))
+    assert public == set(PIECES) | {"init_params", "param_axes"} | (
+        set(STATE_PIECES) if stateful else set())
     for name in OWN:
         assert hasattr(module, name), name
     # the shared functions resolve, through the family, to the one definition
@@ -128,6 +135,11 @@ def test_llm_config_builds_every_family(family, experts):
     heads not stated are as many as the query heads, and routed experts get
     the family's activation."""
     module = family_module(family)
+    if experts and family in NEVER_ROUTED:
+        with pytest.raises(ValueError, match="moe"):
+            LLMConfig(model_family=family, num_heads=4, embed_dim=64,
+                      moe_num_experts=experts).model_config()
+        return
     cfg = LLMConfig(model_family=family, num_heads=4, embed_dim=64,
                     moe_num_experts=experts).model_config()
     assert isinstance(cfg, module.Config)
@@ -164,10 +176,13 @@ def test_llm_config_builds_every_family(family, experts):
 # rounded once. The same values by the same operation, so not a bit moves.
 
 TINY = {"gpt2": "gpt2-tiny", "llama": "llama-tiny", "afmoe": "afmoe-tiny",
-        "smallthinker": "smallthinker-tiny"}
+        "smallthinker": "smallthinker-tiny",
+        "granite_hybrid": "granite-hybrid-tiny"}
 
 
 def _tiny(family, experts, **dtypes):
+    if experts and family in NEVER_ROUTED:
+        pytest.skip(f"{family} has no routed form")
     if family in ALWAYS_ROUTED:
         # "dense": the preset's own experts; "routed": the count asked for
         cfg = dataclasses.replace(get_preset(TINY[family]), **dtypes)
@@ -333,7 +348,10 @@ def test_a_prompt_longer_than_the_largest_bucket_is_admitted_in_chunks(
     extra = dict(layer_types=("sliding_attention",) * 3 + ("full_attention",),
                  sliding_window=8, num_dense_layers=1,
                  moe_num_experts=4, moe_score_func="sigmoid"
-                 ) if family == "afmoe" else {}
+                 ) if family == "afmoe" else dict(
+        layer_types=("mamba", "mamba", "attention", "mamba"),
+        mamba_d_state=16, mamba_d_head=16, mamba_chunk_size=8
+        ) if family == "granite_hybrid" else {}
     prompt = [int(t) for t in np.random.default_rng(5).integers(2, 300, 17)]
     answers = []
     for buckets in ((8, 16), (32,)):

@@ -164,7 +164,11 @@ def _engine_program_args(one_chip, slots, width, cfg=None, block=1):
     tokens = jax.ShapeDtypeStruct((slots, width), jnp.int32,
                                   sharding=one_chip)
     start = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
-    real = (start,) if getattr(cfg, "moe", None) is not None else ()
+    from ray_tpu.models.decoder import layer_kinds
+
+    takes_real = getattr(cfg, "moe", None) is not None or any(
+        k.state is not None for k in layer_kinds(cfg))
+    real = (start,) if takes_real else ()
     return cfg, (params, tokens, cache, start, *real)
 
 
@@ -444,6 +448,74 @@ def test_trinity_programs_fit_the_chip_and_keep_both_caches_in_place(
     else:
         assert mem.temp_size_in_bytes < 3.0e9
         assert "f32[1,1,200192]" in text.split("\n", 1)[0]
+
+
+# ---------------------------------- Granite 4.0-H Micro's engine programs
+# The seventh cell's size: the whole model, 48 slots
+# (``benchmarks/configs/granite-4.0-h-micro.json``).
+
+
+@pytest.mark.parametrize("program, slots, width", [
+    ("decode", 48, 1), ("prefill", 1, 1024)])
+def test_granite_programs_fit_the_chip_and_step_the_state_in_place(
+        one_chip, program, slots, width, monkeypatch):
+    """``jit_decode`` at 48 slots and ``jit_prefill`` at the largest bucket,
+    at the published widths and depth: the v5e compiler takes the
+    ``ssm_update`` kernel over the whole state (nine calls, one a state
+    layer of the period, which is scanned four times) and the decode kernel
+    at G = 4 and D = 64; the cache of two kinds is the program's argument
+    and its result in one buffer; the convolution's rows are one flat leaf
+    that no layer's write repacks; the tied table is read where it lies by
+    the gather and by the head."""
+    import re
+
+    from benchmarks import run
+    from benchmarks.lib import program as harness
+    from ray_tpu.llm.engine import engine_programs
+    from ray_tpu.models import kv_cache
+
+    monkeypatch.setattr(kv_cache, "_decode_impl", lambda: "pallas")
+    cfg, args = _engine_program_args(
+        one_chip, slots, width, harness.model_config(
+            run.load_cell("granite-4.0-h-micro.serve-chat")[2]), block=1024)
+    cache = args[2]
+    assert {k: (v.shape, v.dtype) for k, v in cache.items()} == {
+        "k": ((4, slots, 8, 64, 3072), jnp.bfloat16),
+        "v": ((4, slots, 8, 64, 3072), jnp.bfloat16),
+        "ssm": ((36, slots, 64, 64, 128), jnp.float32),
+        "conv": ((36, slots, 3 * 4352), jnp.bfloat16)}
+    param_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(args[0]))
+    assert 6.37e9 < param_bytes < 6.39e9    # 3.19 B parameters in bf16
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in cache.values())
+    if program == "decode":
+        compiled = engine_programs(cfg)[2].lower(
+            *_decode_args(one_chip, args)).compile()
+    else:
+        rows = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+        compiled = engine_programs(cfg)[0].lower(*args, rows=rows).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    # no copy or conversion of the whole table
+    assert not re.search(
+        r"= \w+\[100352,2048\]\S* (copy|transpose)\(", text)
+    if program == "decode":
+        assert 4.8e9 < cache_bytes < 4.9e9  # 3.62 GB of state, 1.21 of KV
+        assert mem.alias_size_in_bytes == cache_bytes
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 11.4e9
+        assert mem.temp_size_in_bytes < 0.05e9
+        calls = [line for line in text.splitlines() if "custom-call(" in line]
+        updates = [c for c in calls if re.match(r"\s*%?ssm_update", c)]
+        assert len(updates) == 9
+        assert all(f"s32[{slots + 1}]" in c
+                   and f"f32[36,{slots},64,64,128]" in c for c in updates)
+        assert len([c for c in calls
+                    if re.match(r"\s*%?decode_attention", c)]) == 1
+        # the rows are not repacked around a layer's write
+        assert "remat_compressed" not in text
+        assert _weight_converts(text, args[0]) == []
+    else:
+        assert mem.temp_size_in_bytes < 0.5e9
+        assert "f32[1,1,100352]" in text.split("\n", 1)[0]
 
 
 # -------------------------------------------- SmallThinker's training step
