@@ -231,46 +231,192 @@ def test_moe_dropless_routing_matches_topk():
     )
 
 
-def test_chunked_xent_matches_naive():
-    """Vocab-chunked cross entropy (no [B,T,V] materialization) must equal
-    the naive log_softmax loss, values and gradients."""
+def _naive_xent(x, w, t, m=None):
+    """The loss ``chunked_softmax_xent`` stands for, float32, whole logits."""
+    logits = jnp.einsum("bte,ve->btv", x.astype(jnp.float32),
+                        w.astype(jnp.float32))
+    logp = jax.nn.log_softmax(logits, -1)
+    ll = jnp.take_along_axis(logp, t[..., None], -1)[..., 0]
+    if m is None:
+        return -ll.mean()
+    return -(ll * m).sum() / jnp.maximum(m.sum(), 1)
+
+
+def _prefix_mask(B, T, keep):
+    return (jnp.arange(T)[None, :] < keep).astype(jnp.float32) * jnp.ones(
+        (B, 1))
+
+
+# what a case changes of: T=48, chunk=16, float32 x, no mask (``keep``: the
+# rows a prefix mask keeps), the loss taken once, value's rtol and
+# gradients' atol 1e-5
+XENT_CASES = {
+    "plain": {},
+    "masked": {"keep": 30},
+    # bf16 logits and dlogits: gradients of 4e-3 (dx) and 6e-2 (dW) at most
+    # read within 1.5e-5 and 1e-4 of float32's
+    "bf16-activations": {"dtype": jnp.bfloat16, "rtol": 1e-3, "atol": 5e-4},
+    "ragged-tail": {"T": 50},
+    "ragged-tail-masked": {"T": 50, "keep": 41},
+    "one-chunk": {"chunk": 512},
+    "all-masked": {"keep": 0},
+    "cotangent-3": {"keep": 30, "factor": 3.0},
+}
+
+
+@pytest.mark.parametrize("case", list(XENT_CASES))
+def test_chunked_xent_matches_naive(case):
+    """Sequence-chunked cross entropy (no [B,T,V] materialization) must equal
+    the naive log_softmax loss, values and gradients: the gradients its
+    forward pass makes (``ops/xent.py``'s ``custom_vjp``) against autodiff
+    of the naive loss in float32."""
     from ray_tpu.ops.xent import chunked_softmax_xent
 
-    rng = jax.random.PRNGKey(0)
-    B, T, E, V = 2, 48, 16, 97
-    x = jax.random.normal(rng, (B, T, E), jnp.float32)
+    case = {"T": 48, "chunk": 16, "dtype": jnp.float32, "keep": None,
+            "factor": 1.0, "rtol": 1e-5, "atol": 1e-5, **XENT_CASES[case]}
+    T, chunk, dtype, keep, factor, rtol, atol = case.values()
+    B, E, V = 2, 16, 97
+    x = jax.random.normal(jax.random.PRNGKey(0), (B, T, E), jnp.float32)
+    x = x.astype(dtype)
     w = jax.random.normal(jax.random.PRNGKey(1), (V, E), jnp.float32) * 0.1
     t = jax.random.randint(jax.random.PRNGKey(2), (B, T), 0, V)
+    m = None if keep is None else _prefix_mask(B, T, keep)
 
     def naive(x, w):
-        logits = jnp.einsum("bte,ve->btv", x, w)
-        logp = jax.nn.log_softmax(logits, -1)
-        return -jnp.take_along_axis(logp, t[..., None], -1)[..., 0].mean()
+        return factor * _naive_xent(x, w, t, m)
 
     def chunked(x, w):
-        return chunked_softmax_xent(x, w, t, chunk=16)
+        return factor * chunked_softmax_xent(x, w, t, mask=m, chunk=chunk)
 
+    # undifferentiated, differentiated, and under jit: one value
+    want = np.asarray(naive(x, w))
+    got, (gx, gw) = jax.value_and_grad(chunked, argnums=(0, 1))(x, w)
+    for value in (chunked(x, w), got, jax.jit(chunked)(x, w)):
+        np.testing.assert_allclose(
+            np.asarray(value), want, rtol=rtol, atol=1e-7)
+    wx, ww = jax.grad(naive, argnums=(0, 1))(x, w)
+    assert gx.dtype == dtype and gx.shape == x.shape
+    assert gw.dtype == jnp.float32 and gw.shape == w.shape
     np.testing.assert_allclose(
-        np.asarray(chunked(x, w)), np.asarray(naive(x, w)), rtol=1e-5
+        np.asarray(gx, np.float32), np.asarray(wx, np.float32), atol=atol)
+    np.testing.assert_allclose(np.asarray(gw), np.asarray(ww), atol=atol)
+    assert np.isfinite(np.asarray(gx, np.float32)).all()
+    assert np.isfinite(np.asarray(gw)).all()
+    if keep is not None:
+        # a row the mask drops gets no gradient, exactly
+        assert not np.asarray(gx, np.float32)[:, keep:].any()
+        assert np.asarray(gx, np.float32)[:, :keep].any() == (keep > 0)
+    if keep == 0:
+        assert float(got) == 0.0 and not np.asarray(gw).any()
+
+
+def test_chunked_xent_refuses_forward_mode():
+    """Reverse mode, first derivatives: what ``ops/xent.py`` says it is."""
+    from ray_tpu.ops.xent import chunked_softmax_xent
+
+    x = jnp.ones((1, 8, 4))
+    w = jnp.ones((5, 4))
+    t = jnp.zeros((1, 8), jnp.int32)
+    with pytest.raises(TypeError, match="custom_vjp"):
+        jax.jvp(lambda x: chunked_softmax_xent(x, w, t), (x,), (x,))
+
+
+def test_tied_head_gradient_is_the_sum_of_both_uses():
+    """GPT-2's ``wte`` is the embedding and the head: its gradient through
+    ``loss_fn`` is the embedding's (a scatter-add, autodiff) plus the
+    head's (made by the loss's forward pass), and the whole is autodiff's
+    of the loss over whole logits."""
+    from ray_tpu.models import decoder
+    from ray_tpu.ops.xent import chunked_softmax_xent
+
+    cfg = gpt2.GPT2Config(
+        vocab_size=512, max_seq_len=64, num_layers=2, num_heads=2,
+        embed_dim=64, dtype=jnp.float32, attention_impl="xla",
     )
-    g1 = jax.grad(naive, argnums=(0, 1))(x, w)
-    g2 = jax.grad(chunked, argnums=(0, 1))(x, w)
-    for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+    params = gpt2.init_params(cfg, jax.random.PRNGKey(0))
+    batch = _batch(B=2, T=32, vocab=512)
+    inputs, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
 
-    # masked variant
-    m = (jnp.arange(T)[None, :] < 30).astype(jnp.float32) * jnp.ones((B, 1))
+    def apart(embedding, head):
+        x, _ = decoder.forward_features(
+            dict(params, wte=embedding), inputs, cfg)
+        return chunked_softmax_xent(x, head, targets)
 
-    def naive_m(x, w):
-        logits = jnp.einsum("bte,ve->btv", x, w)
-        logp = jax.nn.log_softmax(logits, -1)
-        ll = jnp.take_along_axis(logp, t[..., None], -1)[..., 0]
-        return -(ll * m).sum() / m.sum()
+    def naive(p):
+        logits, _ = gpt2.forward(p, inputs, cfg)
+        return _naive_xent(logits, jnp.eye(512), targets)
 
+    tied = jax.grad(lambda p: gpt2.loss_fn(p, batch, cfg))(params)
+    as_embedding, as_head = jax.grad(apart, argnums=(0, 1))(
+        params["wte"], params["wte"])
+    assert np.abs(np.asarray(as_embedding)).max() > 1e-4
+    assert np.abs(np.asarray(as_head)).max() > 1e-4
     np.testing.assert_allclose(
-        np.asarray(chunked_softmax_xent(x, w, t, mask=m, chunk=16)),
-        np.asarray(naive_m(x, w)), rtol=1e-5,
-    )
+        np.asarray(tied["wte"]), np.asarray(as_embedding + as_head),
+        atol=1e-7)
+    want = jax.grad(naive)(params)
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(tied)[0],
+                            jax.tree.leaves(want)):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=1e-5,
+            err_msg=jax.tree_util.keystr(path))
+
+
+def _vocabulary_products(jaxpr, vocab, under=()):
+    """(equation, names of the equations it lies inside) of every
+    ``dot_general`` with an operand or result of the vocabulary's size."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general" and any(
+                vocab in v.aval.shape for v in (*eqn.invars, *eqn.outvars)):
+            yield eqn, under
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (tuple, list))
+                        else (value,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _vocabulary_products(
+                        inner, vocab, under + (eqn.primitive.name,))
+
+
+@pytest.mark.parametrize("preset", ["gpt2-tiny", "smallthinker-tiny"])
+def test_the_loss_head_is_three_products_a_chunk(preset):
+    """The mechanism of ``ops/xent.py``: the differentiated loss projects a
+    chunk's logits ONCE and forms dx and dW beside them, three products of
+    the vocabulary's size inside one scan and none under a ``checkpoint``
+    (a remat'd body ran the logits again: four); the loss nobody
+    differentiates projects the logits and nothing else."""
+    import dataclasses
+
+    from ray_tpu.models import decoder, get_preset, module_for
+
+    cfg = dataclasses.replace(get_preset(preset), attention_impl="xla")
+    vocab = cfg.vocab_size
+    assert vocab not in (cfg.embed_dim, cfg.max_seq_len, 96)
+    params = jax.eval_shape(
+        lambda: module_for(cfg).init_params(cfg, jax.random.PRNGKey(0)))
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 97), jnp.int32)}
+
+    def loss(p, b):
+        return decoder.loss_fn(p, b, cfg)
+
+    plain = list(_vocabulary_products(
+        jax.make_jaxpr(loss)(params, batch).jaxpr, vocab))
+    assert len(plain) == 1
+    assert not {"checkpoint", "remat", "remat2"} & set(plain[0][1])
+    products = list(_vocabulary_products(
+        jax.make_jaxpr(jax.grad(loss))(params, batch).jaxpr, vocab))
+    assert len(products) == 3
+    for eqn, under in products:
+        assert "scan" in under, under
+        assert not {"checkpoint", "remat", "remat2"} & set(under), under
+    # logits [B, c, V] once; dx [B, c, E] and dW [V, E] from dlogits
+    results = sorted(tuple(e.outvars[0].aval.shape) for e, _ in products)
+    assert results == sorted(
+        [(2, 96, vocab), (2, 96, cfg.embed_dim), (vocab, cfg.embed_dim)])
+    # every product in the activation dtype, as autodiff gave them (an fsdp
+    # mesh then reduces a chunk's dW in bf16: ``ops/xent.py``)
+    assert {e.outvars[0].aval.dtype for e, _ in products} == {
+        jnp.dtype(cfg.dtype)}
 
 
 def test_loss_fn_chunked_matches_logits_path():

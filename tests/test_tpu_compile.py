@@ -554,17 +554,35 @@ def test_flash_with_a_window_and_grouped_heads_compiles_for_v5e(
     assert "bf16[2,8192,28,128]" in text and "bf16[8,8192,128]" in text
 
 
-def test_smallthinker_step_fits_the_chip_with_its_window_in_the_kernels(
-        one_chip):
-    """The cell's train step for the described chip: accepted at batch 2 x
-    8192 with remat ``dots`` (7.9 GB of state in, 10.2 GB of temporaries),
-    four flash calls forward and four backward (one full, three windowed
-    each), the grouped products as Mosaic kernels in both directions, the
-    two up-projections kept for the backward pass and not run again, no
-    count made by a scatter-add of ones, and no [B, H, T, T] array
-    anywhere."""
-    import dataclasses
+def _loss_head(text, mem, vocab, embed, was):
+    """The compiled step's loss head (``ops/xent.py``, PR 43): the chunk's
+    logits are projected once, in the forward scan, where dW is made too;
+    no remat of the loss; the backward's scaling of dW by the constant
+    cotangent 1 is folded (no multiply over [V, E] outside the optimizer's);
+    arguments + temporaries within 100 MB of ``was``, the figure with the
+    loss body under ``jax.checkpoint``."""
     import re
+
+    def products(what):
+        return [line for line in text.splitlines()
+                if " convolution(" in line and f"closed_call/{what}/" in line]
+
+    logits, dw = products("bce,ve->bcv"), products("bcv,bce->ve")
+    assert len(logits) == 1 and f",{vocab}]" in logits[0].split(" = ")[1]
+    assert len(dw) == 1 and f"[{vocab},{embed}," in dw[0].split(" = ")[1]
+    assert "transpose(jvp" not in logits[0] + dw[0]
+    assert "rematted_computation/bce,ve->bcv" not in text
+    assert not re.search(
+        rf"\[{vocab},{embed}\]\S* multiply\([^\n]*op_name=\"[^\"]*transpose\(",
+        text)
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            <= was + 100_000_000)
+
+
+def _compiled_train_step(one_chip, cell_name):
+    """(model configuration, compiled step) of a training cell for the
+    described chip: the cell's own widths and batch, the flash kernels."""
+    import dataclasses
 
     from benchmarks import run
     from benchmarks.lib import program
@@ -572,19 +590,46 @@ def test_smallthinker_step_fits_the_chip_with_its_window_in_the_kernels(
     from ray_tpu.train.step import (
         OptimizerConfig, create_train_state, make_train_step)
 
-    _, _, config, _, _ = run.load_cell("smallthinker-21b-a3b.train-seq8k")
+    _, cell, config, _, _ = run.load_cell(cell_name)
     model = program.trainer_model(config)
     cfg = dataclasses.replace(
         config_for(model.pop("family"), **model), attention_impl="flash")
-    assert cfg.moe.dropless and cfg.moe.num_held == 16
     opt = OptimizerConfig().build()
     state = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         jax.eval_shape(
             lambda: create_train_state(cfg, opt, jax.random.PRNGKey(0))))
+    job = cell["job"]
     batch = {"tokens": jax.ShapeDtypeStruct(
-        (2, 8193), jnp.int32, sharding=one_chip)}
-    compiled = make_train_step(cfg, opt).lower(state, batch).compile()
+        (job["batch_size"], job["seq_len"] + 1), jnp.int32,
+        sharding=one_chip)}
+    return cfg, make_train_step(cfg, opt).lower(state, batch).compile()
+
+
+def test_gpt2_medium_step_projects_its_logits_once(one_chip):
+    """``gpt2-medium.train-steady``'s step at its real widths, batch 16 x
+    1024, remat ``dots``: the tied head's loss is three products a chunk
+    and holds what it held (4.26 GB of state in, 13.58 GB of temporaries:
+    17,837,841,408 before, 17,837,905,920 after; sandbox compile, PR 43)."""
+    cfg, compiled = _compiled_train_step(one_chip, "gpt2-medium.train-steady")
+    _loss_head(compiled.as_text(), compiled.memory_analysis(),
+               cfg.vocab_size, cfg.embed_dim, was=17_837_841_408)
+
+
+def test_smallthinker_step_fits_the_chip_with_its_window_in_the_kernels(
+        one_chip):
+    """The cell's train step for the described chip: accepted at batch 2 x
+    8192 with remat ``dots`` (7.9 GB of state in, 10.2 GB of temporaries),
+    four flash calls forward and four backward (one full, three windowed
+    each), the grouped products as Mosaic kernels in both directions, the
+    two up-projections kept for the backward pass and not run again, no
+    count made by a scatter-add of ones, no [B, H, T, T] array anywhere,
+    and a loss head that projects a chunk's logits once (``_loss_head``)."""
+    import re
+
+    cfg, compiled = _compiled_train_step(
+        one_chip, "smallthinker-21b-a3b.train-seq8k")
+    assert cfg.moe.dropless and cfg.moe.num_held == 16
     mem = compiled.memory_analysis()
     assert 7.8e9 < mem.argument_size_in_bytes < 7.95e9  # 656.6M x 12 bytes
     # 10,170,040,832 (sandbox compile, PR 41); 9,356,529,152 before the
@@ -592,6 +637,9 @@ def test_smallthinker_step_fits_the_chip_with_its_window_in_the_kernels(
     # the chip's peak by 0.13 (14.880 -> 15.007 GB, ``memory_peak_bytes``)
     assert mem.temp_size_in_bytes < 10.3e9
     text = compiled.as_text()
+    # 18,048,474,112 with the loss body under remat (sandbox compile, PR 42's
+    # tree); 18,048,409,600 with the gradients made in the loss's forward
+    _loss_head(text, mem, cfg.vocab_size, cfg.embed_dim, was=18_048_474_112)
     names = [line.split(" = ")[0] for line in text.splitlines()
              if "tpu_custom_call" in line and " = " in line]
     flash = [n for n in names if "flash_" in n]
