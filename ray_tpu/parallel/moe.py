@@ -17,8 +17,10 @@ that Trinity's ``afmoe`` takes). What follows is one of two dispatches:
 - dropless (inference; ``MoEConfig.dropless``): one sorted, grouped dispatch.
   The T x k (token, expert) pairs are sorted by expert, the rows gathered in
   that order, and the expert products run as grouped matrix products over
-  the row groups (``jax.lax.ragged_dot``: on a TPU one Mosaic kernel a
-  product, which visits only the experts that received a row). Work is
+  the row groups (``ops/grouped_matmul.py:grouped_dot``: on a TPU a Pallas
+  kernel a product, its tiles chosen from the call's shape, which visits
+  only the experts that received a row; ``jax.lax.ragged_dot`` elsewhere,
+  the platform's choice alone). Work is
   proportional to the routed rows, the weights read are those of the experts
   touched, and no [tokens, experts, width] array exists. One function for a
   2048-token prefill and a 16-row decode tick. It takes the weights in one
@@ -53,6 +55,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+
+from ray_tpu.ops.grouped_matmul import grouped_dot
 
 
 @dataclass(frozen=True)
@@ -274,8 +278,14 @@ def _experts(params, rows, counts, config: MoEConfig, layer, named=True):
     that slice out first: on the TPU the product is a kernel, a kernel's
     operand is a whole array, and a layer's experts cut out of the stack
     were a copy of all of them (6.4 GB a decode tick for OLMoE at depth 8,
-    19.6 of 48 ms; my chip run, PR 27)."""
+    19.6 of 48 ms; my chip run, PR 27).
+
+    The three products are ``grouped_dot``s: on a TPU the Pallas kernel
+    ``grouped_matmul`` (in the backward pass the same kernel with the
+    weights read transposed, and ``grouped_matmul_dw`` for the weights'
+    gradient), ``jax.lax.ragged_dot`` on any other platform."""
     dtype = rows.dtype
+    E = params["expert_fc"].shape[-3]       # the groups that can hold rows
 
     def weights(name):
         w = params[name]
@@ -288,7 +298,7 @@ def _experts(params, rows, counts, config: MoEConfig, layer, named=True):
         return w.reshape((-1,) + w.shape[2:])
 
     if layer is not None:
-        L, E = params["expert_fc"].shape[:2]
+        L = params["expert_fc"].shape[0]
         counts = jax.lax.dynamic_update_slice(
             jnp.zeros((L * E,), counts.dtype), counts, (layer * E,))
     with jax.named_scope("moe.experts"):
@@ -297,18 +307,19 @@ def _experts(params, rows, counts, config: MoEConfig, layer, named=True):
         # pass, where a grouped product is no ``dot_general`` and would run
         # again; anywhere else a name is the identity
         name = checkpoint_name if named else lambda x, _: x
-        h = name(jax.lax.ragged_dot(rows, weights("expert_fc"), counts),
-                 "moe_fc")
+
+        def product(x, w, out=None):
+            return grouped_dot(x, weights(w), counts, out, live_groups=E,
+                               scope="moe.experts")
+
+        h = name(product(rows, "expert_fc"), "moe_fc")
         if _gated(config):
-            g = name(jax.lax.ragged_dot(rows, weights("expert_gate"), counts),
-                     "moe_gate")
+            g = name(product(rows, "expert_gate"), "moe_gate")
             act = jax.nn.silu if config.activation == "swiglu" else jax.nn.relu
             h = act(g) * h
         else:
             h = jax.nn.gelu(h)
-        return jax.lax.ragged_dot(
-            h, weights("expert_out"), counts,
-            preferred_element_type=jnp.float32)
+        return product(h, "expert_out", jnp.float32)
 
 
 def _grouped(params, tokens, gates, chosen, row_mask, config: MoEConfig,

@@ -7,7 +7,9 @@ Own milliseconds a step (chip 0, whole executions of the program only) by
 ``moe.*`` scope x pass (forward / remat: what a checkpoint runs again in the
 backward pass / backward: a transposed operation) x HLO operation (with
 the JAX primitive its ``op_name`` ends in: a fusion carries its root's), the
-grouped-product calls (``ragged-dot-none``) by result shape, and every
+grouped-product calls (``ops/grouped_matmul.py``'s ``grouped_matmul`` and
+``grouped_matmul_dw`` by pass and result shape; ``ragged-dot-none``, the
+compiler's, in a trace from before PR 44, by result shape) and every
 operation of a scatter primitive by result shape (a count made by a
 scatter-add of ones shows as an ``s32[experts]`` result). The readers are
 the benchmark's own (``benchmarks/lib/op_scopes.py``,
@@ -47,6 +49,11 @@ def scope_of(meta: op_scopes.OpMeta):
         return theirs, True
     m = SCOPE.search(meta.op_name)
     return (m.group(1) if m else None), False
+
+
+# the grouped products' kernels by instruction name: this repo's (PR 44 on)
+# and the compiler's own for a ``ragged_dot`` (before)
+GROUPED_PRODUCTS = ("grouped_matmul", "ragged-dot-none")
 
 
 def which_pass(op_name: str) -> str:
@@ -137,13 +144,15 @@ def main(argv=None) -> int:
     print(f"all moe.*: {layer * ms:.2f} ms a step, "
           f"{100.0 * layer / total:.2f}% of it (the benchmark's readers "
           f"see {read * ms:.2f} ms, {100.0 * read / total:.2f}%)")
-    for title, keep in (("grouped products", "ragged-dot-none"),
-                        ("scatters", "scatter")):
+    for title, keep in (("grouped products", GROUPED_PRODUCTS),
+                        ("scatters", ("scatter",))):
         print(f"{title} (calls a step, ms a call, ms a step, result):")
         seen = collections.defaultdict(lambda: [0, 0])
-        for _, _, _, what, own in found:
-            if keep in (what.split(" ")[0] if keep.startswith("ragged")
-                        else what.split(" ")[-1]):
+        for _, _, direction, what, own in found:
+            word = what.split(" ")[0 if keep is GROUPED_PRODUCTS else -1]
+            if any(k in word for k in keep):
+                if keep is GROUPED_PRODUCTS and direction != "any":
+                    what = f"{what} [{direction}]"
                 seen[what][0] += own
                 seen[what][1] += 1
         for what, (own, calls) in sorted(seen.items(),

@@ -209,10 +209,11 @@ def test_a_share_drops_no_row_whatever_the_routing(reference, sent):
                                    atol=2e-5, rtol=1e-4)
 
 
+@pytest.mark.parametrize("products_are", ["ragged_dot_general", "pallas_call"])
 @pytest.mark.parametrize("held, passes, kept, again", [
     (8, 1, 10, 12), (2, 3, 25, 27)], ids=["one-pass", "under-the-cond"])
 def test_the_up_projections_are_kept_for_the_backward_pass(
-        held, passes, kept, again):
+        held, passes, kept, again, products_are, monkeypatch):
     """Under the block's checkpoint policy the backward pass of a routed
     layer runs the down product again and not the two up-projections (named
     ``moe_fc`` / ``moe_gate``; a grouped product is no ``dot_general``): two
@@ -220,7 +221,14 @@ def test_the_up_projections_are_kept_for_the_backward_pass(
     ``lax.cond`` of a share that may need further passes too (whose own 15,
     forward, remat twice over and transposes, carry no name: an outer policy
     reaches through the checkpoint around a pass, and three passes' worth of
-    residuals would be kept). The gradients are the same to the bit."""
+    residuals would be kept). The gradients are the same to the bit.
+    ``pallas_call``: the same counts with the TPU's kernels forced (in
+    interpret mode), whose ``custom_vjp`` keeps the operands it was given
+    and nothing the policy would have to run a product again for."""
+    if products_are == "pallas_call":
+        from ray_tpu.ops import grouped_matmul
+        monkeypatch.setattr(grouped_matmul, "_impl",
+                            lambda: "pallas_interpret")
     config = moe.MoEConfig(num_experts=8, top_k=2, activation="reglu",
                            dropless=True, num_held=held, first_held=0)
     assert -(-96 * 2 // moe.held_rows_bound(96, config)) == passes
@@ -237,7 +245,7 @@ def test_the_up_projections_are_kept_for_the_backward_pass(
         grad = jax.grad(jax.checkpoint(
             loss, policy=decoder._remat_policy(cfg)), argnums=(0, 1))
         products[policy] = sum(
-            e.primitive.name == "ragged_dot_general"
+            e.primitive.name == products_are
             for e in _equations(jax.make_jaxpr(grad)(params, y).jaxpr))
         grads[policy] = jax.jit(grad)(params, y)
     assert products == {"dots": kept, "full": again}

@@ -19,6 +19,7 @@ from jax.sharding import (
     Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding,
 )
 
+from ray_tpu.ops import grouped_matmul
 from ray_tpu.ops.attention import attention, flash_attention
 
 
@@ -303,6 +304,27 @@ def test_prefill_program_copies_no_layer_of_the_cache(one_chip):
     assert len(copies) <= 2
 
 
+def _grouped_products(text):
+    """(dtype, rows, columns, the rest of the line) of a compiled program's
+    ``grouped_matmul`` kernels (``ops/grouped_matmul.py``), each checked for
+    what the benchmark's readers find it by: a Mosaic custom call whose
+    ``op_name`` holds the ``moe.experts`` scope."""
+    import re
+
+    kernels = []
+    for line in text.splitlines():
+        m = re.match(
+            r"\s*%[\w.-]*grouped_matmul[\w.-]* = (\w+)\[(\d+),(\d+)\]"
+            r".* custom-call\((.*)", line)
+        if m is None:
+            continue
+        assert 'custom_call_target="tpu_custom_call"' in line
+        assert "moe.experts" in re.search(
+            r'op_name="([^"]*)"', line).group(1), line[-300:]
+        kernels.append(m.groups())
+    return kernels
+
+
 # ------------------------------------------------------- OLMoE in the engine
 # The second serving cell's size: OLMoE-1B-7B at depth 8, bf16 weights, 16
 # slots of 4096 positions (``benchmarks/configs/olmoe-1b-7b.json``).
@@ -324,8 +346,10 @@ def test_olmoe_programs_read_the_experts_where_they_lie(
         one_chip, program, slots, width, monkeypatch):
     """``jit_decode`` at 16 slots and ``jit_prefill`` at the largest bucket,
     at the published widths: they fit the chip beside each other's
-    arguments; the three grouped products are the compiler's ragged-dot
-    kernels over ALL layers' experts as one operand ([8 x 64, K, N], a
+    arguments; the three grouped products are ``grouped_matmul`` kernels
+    (``ops/grouped_matmul.py``; the compiler's ragged-dot before PR 44),
+    under the ``moe.experts`` scope a trace's reader finds them by,
+    over ALL layers' experts as one operand ([8 x 64, K, N], a
     bitcast of the parameter), so no layer's 805 MB of experts is cut out
     and copied for them (19.6 of 48 ms a tick when it was; my chip run, PR
     27); no weight is converted (they are bf16 and stay so); and the cache
@@ -336,6 +360,7 @@ def test_olmoe_programs_read_the_experts_where_they_lie(
     from ray_tpu.models import kv_cache
 
     monkeypatch.setattr(kv_cache, "_decode_impl", lambda: "pallas")
+    monkeypatch.setattr(grouped_matmul, "_impl", lambda: "pallas")
     cfg, args = _engine_program_args(one_chip, slots, width, _olmoe_config())
     assert cfg.param_dtype == jnp.bfloat16 and cfg.moe.dropless
     fn = engine_programs(cfg)[0 if program == "prefill" else 2]
@@ -347,10 +372,8 @@ def test_olmoe_programs_read_the_experts_where_they_lie(
                       for a in jax.tree.leaves(args[0]))
     assert 7.0e9 < param_bytes < 7.2e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13e9
-    kernels = re.findall(
-        r"%ragged-dot-none[.\d]* = (\w+)\[(\d+),(\d+)\].* custom-call\((.*)",
-        text)
-    assert len(kernels) == 3
+    kernels = _grouped_products(text)
+    assert len(kernels) == 3 and "ragged-dot" not in text
     rows = slots * width * cfg.moe.top_k
     for _, m, _, operands in kernels:
         assert int(m) == rows
@@ -391,8 +414,8 @@ def test_trinity_programs_fit_the_chip_and_keep_both_caches_in_place(
     at the published widths: the v5e compiler takes the decode kernel at
     G = 8, with the window over the rings and without it over the full
     layer, once a layer (nothing is scanned: five kinds, one of each); the
-    grouped products run over the four routed layers' experts as one operand
-    ([4 x 128, K, N]); a program's arguments and temporaries fit the chip;
+    grouped products (``grouped_matmul`` kernels under ``moe.experts``) run
+    over the four routed layers' experts as one operand ([4 x 128, K, N]); a program's arguments and temporaries fit the chip;
     a prefill chunk's float32 scores are taken in blocks of queries, so its
     temporaries stay under 3 GB; the prefill's logits are the one row the
     host reads, not 2048 rows of 200192."""
@@ -402,6 +425,7 @@ def test_trinity_programs_fit_the_chip_and_keep_both_caches_in_place(
     from ray_tpu.models import kv_cache
 
     monkeypatch.setattr(kv_cache, "_decode_impl", lambda: "pallas")
+    monkeypatch.setattr(grouped_matmul, "_impl", lambda: "pallas")
     cfg, args = _engine_program_args(
         one_chip, slots, width, _trinity_config(), block=2048)
     assert cfg.param_dtype == jnp.bfloat16 and cfg.moe.dropless
@@ -423,10 +447,9 @@ def test_trinity_programs_fit_the_chip_and_keep_both_caches_in_place(
     cache_bytes = sum(a.size * a.dtype.itemsize for a in cache.values())
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < (
         15.0e9 if program == "decode" else 12.0e9)
-    kernels = re.findall(
-        r"%ragged-dot-none[.\d]* = (\w+)\[(\d+),(\d+)\].* custom-call\((.*)",
-        text)
+    kernels = _grouped_products(text)
     assert len(kernels) == 3 * 4            # four routed layers, unrolled
+    assert "ragged-dot" not in text
     for _, m, _, operands in kernels:
         assert int(m) == slots * width * cfg.moe.top_k
         assert re.search(r"bf16\[512,(2048,1024|1024,2048)\]", operands)
@@ -579,10 +602,13 @@ def _loss_head(text, mem, vocab, embed, was):
             <= was + 100_000_000)
 
 
-def _compiled_train_step(one_chip, cell_name):
+def _compiled_train_step(one_chip, cell_name, monkeypatch):
     """(model configuration, compiled step) of a training cell for the
-    described chip: the cell's own widths and batch, the flash kernels."""
+    described chip: the cell's own widths and batch, the flash kernels and
+    the grouped products' (the platform's choices, made here for it)."""
     import dataclasses
+
+    monkeypatch.setattr(grouped_matmul, "_impl", lambda: "pallas")
 
     from benchmarks import run
     from benchmarks.lib import program
@@ -606,29 +632,33 @@ def _compiled_train_step(one_chip, cell_name):
     return cfg, make_train_step(cfg, opt).lower(state, batch).compile()
 
 
-def test_gpt2_medium_step_projects_its_logits_once(one_chip):
+def test_gpt2_medium_step_projects_its_logits_once(one_chip, monkeypatch):
     """``gpt2-medium.train-steady``'s step at its real widths, batch 16 x
     1024, remat ``dots``: the tied head's loss is three products a chunk
     and holds what it held (4.26 GB of state in, 13.58 GB of temporaries:
     17,837,841,408 before, 17,837,905,920 after; sandbox compile, PR 43)."""
-    cfg, compiled = _compiled_train_step(one_chip, "gpt2-medium.train-steady")
+    cfg, compiled = _compiled_train_step(
+        one_chip, "gpt2-medium.train-steady", monkeypatch)
     _loss_head(compiled.as_text(), compiled.memory_analysis(),
                cfg.vocab_size, cfg.embed_dim, was=17_837_841_408)
 
 
 def test_smallthinker_step_fits_the_chip_with_its_window_in_the_kernels(
-        one_chip):
+        one_chip, monkeypatch):
     """The cell's train step for the described chip: accepted at batch 2 x
     8192 with remat ``dots`` (7.9 GB of state in, 10.2 GB of temporaries),
     four flash calls forward and four backward (one full, three windowed
-    each), the grouped products as Mosaic kernels in both directions, the
+    each), the grouped products as this repo's Pallas kernels in both
+    directions (``grouped_matmul``, and ``grouped_matmul_dw`` for the
+    weights' gradients: three of a layer's six transposes; every call under
+    the ``moe.experts`` scope, the ``custom_vjp``'s backward too), the
     two up-projections kept for the backward pass and not run again, no
     count made by a scatter-add of ones, no [B, H, T, T] array anywhere,
     and a loss head that projects a chunk's logits once (``_loss_head``)."""
     import re
 
     cfg, compiled = _compiled_train_step(
-        one_chip, "smallthinker-21b-a3b.train-seq8k")
+        one_chip, "smallthinker-21b-a3b.train-seq8k", monkeypatch)
     assert cfg.moe.dropless and cfg.moe.num_held == 16
     mem = compiled.memory_analysis()
     assert 7.8e9 < mem.argument_size_in_bytes < 7.95e9  # 656.6M x 12 bytes
@@ -650,7 +680,16 @@ def test_smallthinker_step_fits_the_chip_with_its_window_in_the_kernels(
     # down product again under remat, 6 transposes; 12 with the two
     # up-projections run again, as before PR 41) and 12 in the branch of
     # further passes, which keeps nothing
-    assert sum("ragged-dot-none" in n for n in names) == (10 + 12) * 4
+    products = [line for line in text.splitlines()
+                if "tpu_custom_call" in line and " = " in line
+                and "grouped_matmul" in line.split(" = ")[0]]
+    assert len(products) == (10 + 12) * 4 and "ragged-dot" not in text
+    # of a branch's six transposes three are the weights' gradients
+    assert sum("grouped_matmul_dw" in line.split(" = ")[0]
+               for line in products) == (3 + 3) * 4
+    for line in products:
+        assert "moe.experts" in re.search(
+            r'op_name="([^"]*)"', line).group(1), line[-300:]
     # the counts of rows an expert are compares and column sums
     assert not re.search(r"= s32\[(16|64)\]\S* scatter\(", text)
     assert not re.search(r"\[\d+,28,8192,8192\]", text)
