@@ -318,36 +318,22 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.models import config_for, decoder, module_for
+        from ray_tpu.models import decoder, module_for
 
         self.config = config
-        self.model_config = config.model_config()
+        bundle = None
         if params is None and config.model_source:
             import pickle
 
             with open(config.model_source, "rb") as f:
                 bundle = pickle.load(f)
             params = jax.tree.map(jnp.asarray, bundle["params"])
-            if "config" in bundle:
-                # checkpoint architecture wins over LLMConfig defaults — a
-                # mismatch would allocate a KV cache with the wrong layout
-                self.model_config = config_for(
-                    bundle.get("family", self.config.model_family),
-                    **bundle["config"])
+        cfg = self.model_config = config.model_config(bundle)
         # routed experts: the layers whose rows and touched experts the
         # programs report (0 = a dense model, whose programs report nothing)
         self._moe_layers = self._moe_top_k = 0
-        if getattr(self.model_config, "moe", None) is not None:
-            # a bundle's configuration may be a training one (capacity
-            # queues); inference routes dropless (``LLMConfig.model_config``)
-            import dataclasses
-
-            self.model_config = dataclasses.replace(
-                self.model_config,
-                moe=dataclasses.replace(self.model_config.moe, dropless=True),
-            )
-            self._moe_top_k = self.model_config.moe.top_k
-        cfg = self.model_config
+        if getattr(cfg, "moe", None) is not None:
+            self._moe_top_k = cfg.moe.top_k
         model = module_for(cfg)
         kinds = decoder.layer_kinds(cfg)
         if self._moe_top_k:

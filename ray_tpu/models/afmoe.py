@@ -112,8 +112,8 @@ class AfmoeConfig:
         given = self.layer_types or (FULL,) * self.num_layers
         return tuple(given[:self.num_layers])
 
-    # the router's numbers under the flat names ``LLMConfig`` and a
-    # configuration file give them
+    # the router's numbers under the flat names a configuration file gives
+    # them (``models.config_for``): ``benchmarks/`` reads a file's keys back
     @property
     def moe_num_experts(self) -> int:
         return self.moe.num_experts if self.moe is not None else 0
@@ -303,11 +303,6 @@ def attn_out(config: AfmoeConfig, layer, x, attn):
     out = jnp.einsum("bthd,hde->bte", attn * gate.astype(attn.dtype),
                      layer["wo"].astype(attn.dtype))
     return x + _rms_norm(out, layer["post_attn_norm"], config.rms_eps)
-
-
-def at_input(config: AfmoeConfig, kind, layer, x, stacked):
-    """Nothing of a block's input is kept for its feed-forward."""
-    return None
 
 
 def ffn(config: AfmoeConfig, kind, layer, x, rng, row_mask, stacked,

@@ -20,7 +20,8 @@ one signature across families:
   projection and the residual;
 - ``at_input(config, kind, layer, x, stacked)`` -> whatever the family's
   ``ffn`` wants of the block's INPUT, the stream before the attention's norm
-  (a router that reads it gives its logits), or None;
+  (a router that reads it gives its logits), or None: the default below,
+  the only one, which a family's ``import *`` brings and its own overrides;
 - ``ffn(config, kind, layer, x, rng, row_mask, stacked, from_input)`` ->
   (stream, aux loss, experts that received a row: 0 for a dense
   feed-forward). Only a router asks for the last four: ``rng`` its jitter,
@@ -147,11 +148,17 @@ def layer_kinds(config) -> Tuple[Layer, ...]:
                  for k in s.kinds)
 
 
+def at_input(config, kind, layer, x, stacked):
+    """The piece's default: nothing of a block's input is kept for its
+    feed-forward."""
+    return None
+
+
 # what a family module hands on under its own name (``gpt2.forward``,
-# ``module_for(cfg).loss_fn``): each the one definition below
+# ``module_for(cfg).loss_fn``): each the one definition here
 __all__ = ["forward_features", "forward", "init_kv_cache", "forward_cached",
            "forward_pipelined", "loss_fn", "count_params", "Layer", "Segment",
-           "single_kind", "periods"]
+           "single_kind", "periods", "at_input"]
 
 
 def _remat_policy(config):

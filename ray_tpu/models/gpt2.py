@@ -211,11 +211,6 @@ def attn_out(config: GPT2Config, layer, x, attn):
     return x + attn + layer["proj_b"].astype(x.dtype)
 
 
-def at_input(config: GPT2Config, kind, layer, x, stacked):
-    """Nothing of a block's input is kept for its feed-forward."""
-    return None
-
-
 def ffn(config: GPT2Config, kind, layer, x, rng, row_mask, stacked,
         from_input=None):
     """ln2 + MLP (or MoE) + residual. Returns (x, aux_loss, experts that

@@ -88,8 +88,8 @@ class LlamaConfig:
         h = int(self.embed_dim * 8 / 3)
         return (h + 127) // 128 * 128
 
-    # the router's numbers under the flat names ``LLMConfig`` and a
-    # configuration file give them
+    # the router's numbers under the flat names a configuration file gives
+    # them (``models.config_for``): ``benchmarks/`` reads a file's keys back
     @property
     def moe_num_experts(self) -> int:
         return self.moe.num_experts if self.moe is not None else 0
@@ -271,11 +271,6 @@ def attn_out(config: LlamaConfig, layer, x, attn):
     """Output projection + residual add."""
     return x + jnp.einsum("bthd,hde->bte", attn,
                           layer["wo"].astype(attn.dtype))
-
-
-def at_input(config: LlamaConfig, kind, layer, x, stacked):
-    """Nothing of a block's input is kept for its feed-forward."""
-    return None
 
 
 def ffn(config: LlamaConfig, kind, layer, x, rng, row_mask, stacked,

@@ -104,8 +104,8 @@ class SmallThinkerConfig:
         given = self.sliding_window_layout or (0,) * self.num_layers
         return tuple(given[:self.num_layers])
 
-    # the router's numbers under the flat names ``LLMConfig`` and a
-    # configuration file give them
+    # the router's numbers under the flat names a configuration file gives
+    # them (``models.config_for``): ``benchmarks/`` reads a file's keys back
     @property
     def moe_num_experts(self) -> int:
         return self.moe.num_experts
