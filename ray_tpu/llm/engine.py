@@ -11,8 +11,10 @@ split, KV cache management. TPU-first redesign instead of a port:
   batch=1 cache, then a jitted insert writes the slot row — requests join
   and leave the running batch without recompiling (the "continuous" part).
   A prompt longer than the largest bucket prefills in chunks of that
-  bucket, each continuing the cache of the one before (``start > 0``), all
-  under the lock: no tick runs between two chunks. The prefill's head runs
+  bucket, each continuing the cache of the one before (``start > 0``) in
+  the same buffer (an admission's own slot cache is donated to the program;
+  a prefix-cache entry's is shared, and the program copies it), all under
+  the lock: no tick runs between two chunks. The prefill's head runs
   on the rows the host reads (the prompt's last, and the bucket boundaries
   the prefix store keeps), not on the whole bucket.
 - Sampling happens host-side on the [B, V] logits of the tick (temperature
@@ -74,16 +76,20 @@ names its program by ``tick`` and carries its count) and, with a host row,
 the span's own. One span is the loading thread's: ``engine.weights``, once
 a replica, around the weights' arrival as the engine holds them.
 
-A model with state layers (``ops/ssm.py``: a recurrence in place of
-attention; ``models/kv_cache.py``) keeps a slot's states in the same cache
-pytree, slot on axis 1: a prefill chunk starts from the state the chunk
+A model with state layers (a recurrence in place of attention: Mamba-2's,
+``ops/ssm.py``, or the gated delta rule's, ``ops/delta_rule.py``;
+``models/kv_cache.py``) keeps a slot's states in the same cache pytree,
+slot on axis 1: a prefill chunk starts from the state the chunk
 before left in the slot cache and leaves the state after its last REAL token
 (its programs take ``real`` as a router's do), ``insert`` writes the whole of
 the admitted request's state over the last tenant's, and a tick reads and
 writes the states of the slots that decode and of no other.
 ``state_slot_layers`` of ``engine.tick`` (slots that decode x ``layers_state``)
 and ``ssm_prefill_tokens`` of ``engine.admit`` (real tokens the admission's
-scans took) are those counters, summed in ``stats`` under the same names. The
+scans took, whichever recurrence scanned them: the name is the first one's)
+are those counters, summed in ``stats`` under the same names; ``chunks`` of
+``engine.admit`` says in how many programs, each from the state the one
+before left. The
 prefix store keeps whole prompts only, and prompt-lookup speculation is
 refused at construction: a rejected draft's state cannot be rolled back.
 
@@ -236,12 +242,14 @@ def _weights_facts(given, held) -> dict:
     }
 
 
-def engine_programs(cfg):
+def engine_programs(cfg, own_cache=False):
     """The engine's four XLA programs for model config ``cfg``: (prefill,
     insert, decode, decode_all), jitted and not yet compiled. ``params`` are
     the weights as the engine holds them (``DecodeEngine``). The cache is
     the model module's pytree with the slot on axis 1; ``insert`` and both
-    decodes take it donated and give it back in the same buffer. For a
+    decodes take it donated and give it back in the same buffer, and so
+    does ``prefill`` with ``own_cache`` (a slot cache that is one
+    admission's alone; a prefix-cache entry's is shared and never is). For a
     model with routed experts or state layers the three model programs are
     told how many of a row's tokens are tokens (``real`` [B]: one more
     argument; the last row of ``decode``'s ``packed``, which every model's
@@ -297,7 +305,7 @@ def engine_programs(cfg):
         return forward_cached(params, tokens, cache, lens, cfg, *real)
 
     return (
-        jax.jit(prefill),
+        jax.jit(prefill, donate_argnums=(2,) if own_cache else ()),
         jax.jit(insert, donate_argnums=(0,)),
         jax.jit(decode, donate_argnums=(2,)),
         jax.jit(decode_all, donate_argnums=(2,)),
@@ -399,6 +407,9 @@ class DecodeEngine:
 
         self._prefill, self._insert, self._decode, decode_all = (
             engine_programs(cfg))
+        # the same program for a slot cache no prefix entry shares (a fresh
+        # one, or the chunk before's): a chunk writes where the last wrote
+        self._prefill_own = engine_programs(cfg, own_cache=True)[0]
         self._decode_spec = decode_all if self._spec_k > 0 else None
         self._empty_slot_cache = lambda: decoder.init_kv_cache(
             cfg, 1, S, block=block)
@@ -685,7 +696,13 @@ class DecodeEngine:
                 lengths = [ln for ln in wanted if at < ln <= at + len(piece)]
                 rows = np.full((width,), len(piece) - 1, np.int32)
                 rows[:len(lengths)] = [ln - at - 1 for ln in lengths]
-                logits, cache1, *touched = self._prefill(
+                # a program's results are allocated as it is enqueued, and a
+                # prompt's chunks are enqueued together: each would hold a
+                # slot cache of its own, were this admission's not donated
+                # (0.57 GB at 30 kv heads of 128 and 8,704 positions)
+                shared = entry is not None and cache1 is entry["cache"]
+                prefill = self._prefill if shared else self._prefill_own
+                logits, cache1, *touched = prefill(
                     self.params, jnp.asarray(toks), cache1,
                     jnp.full((1,), at, jnp.int32),
                     *self._real([len(piece)]), rows=jnp.asarray(rows),

@@ -9,8 +9,9 @@ stack (``forward_features``, ``forward``), the cached forward
 calls (``layers``, ``embed``, ``qkv``, ``attn_out``, ``ffn``, ``final_norm``,
 ``head``, ``head_weight``, ``at_input`` where its feed-forward reads the
 block's input (the decoder's default is None), and ``state_in`` /
-``state_out`` / ``state_leaves`` where a layer keeps a state; ``decoder.py``
-gives each one's signature) and the one a server calls once
+``state_out`` / ``state_leaves`` where a layer keeps a state (Mamba-2's in
+``granite_hybrid``, the gated delta rule's in ``olmo_hybrid``: the kind's
+``Layer.recurrence`` says which); ``decoder.py`` gives each one's signature) and the one a server calls once
 (``serving_params``), and it hands the decoder's functions on under its own
 name, so ``module_for(cfg).loss_fn`` is the one definition. A new
 architecture is a family module, or a piece of one, and one line of
@@ -42,6 +43,7 @@ FAMILIES = {
     "afmoe": "ray_tpu.models.afmoe",
     "smallthinker": "ray_tpu.models.smallthinker",
     "granite_hybrid": "ray_tpu.models.granite_hybrid",
+    "olmo_hybrid": "ray_tpu.models.olmo_hybrid",
 }
 
 

@@ -33,11 +33,12 @@ one signature across families:
 - for a family with state layers (``Layer.state``: a recurrence in place of
   attention, so no ``qkv`` / ``attn_out``), three pieces more:
   ``state_in(config, kind, layer, x)`` -> (what enters the layer's
-  convolution [B, T, C], dt [B, T, H] float32 and positive, whatever
-  ``state_out`` wants kept); between the two the skeleton runs
+  convolution [B, T, C], the recurrence's per-step gates (a pytree of
+  [B, T, H] float32: Mamba-2's dt, the delta rule's log decay and beta),
+  whatever ``state_out`` wants kept); between the two the skeleton runs
   ``kv_cache.recur`` (from the cache's state in the cached forward, from
-  zeros in the full one) over the layer's ``conv_w``, ``conv_b``, ``A_log``
-  and ``D``; ``state_out(config, layer, x, y, kept)`` -> the stream after
+  zeros in the full one) over the layer's ``conv_w`` with the kind's
+  ``Layer.recurrence``; ``state_out(config, layer, x, y, kept)`` -> the stream after
   the gate, the output projection and the residual;
   ``state_leaves(config)`` names such a cache's leaves: name -> (shape a
   slot, dtype);
@@ -100,6 +101,9 @@ class Layer(NamedTuple):
     # attention over a column a position: the chunk its scan takes a block
     # of tokens in; None: attention
     state: Optional[int] = None
+    # which recurrence (an ``ops/ssm.py:Recurrence``: ``ssm.MAMBA2``,
+    # ``delta_rule.GATED_DELTA``); None with ``state``
+    recurrence: Optional[Any] = None
 
 
 class Segment(NamedTuple):
@@ -271,9 +275,9 @@ def _recur(config, kind: Layer, layer, x, carried):
     ``carried`` as ``kv_cache.recur`` takes it (None: from a zero state, and
     the cache that comes back is None)."""
     family = module_for(config)
-    xbc, dt, kept = family.state_in(config, kind.name, layer, x)
+    entering, gates, kept = family.state_in(config, kind.name, layer, x)
     cache, y = kv_cache.recur(
-        carried, xbc, dt, layer,
+        carried, entering, gates, layer, kind.recurrence,
         family.state_leaves(config)[kv_cache.STATE[0]][0], kind.state)
     return family.state_out(config, layer, x, y, kept), cache
 

@@ -44,6 +44,7 @@ from ray_tpu.models import narrowed
 from ray_tpu.models.decoder import *  # noqa: F401,F403 — what families share
 from ray_tpu.models.decoder import Layer, Segment, periods
 from ray_tpu.models.llama import _rms_norm
+from ray_tpu.ops import ssm
 
 MAMBA, ATTENTION = "mamba", "attention"
 
@@ -168,8 +169,8 @@ PRESETS = {"granite-hybrid-tiny": GRANITE_HYBRID_TINY}
 
 def _kinds(config: Config) -> Tuple[Layer, ...]:
     return tuple(
-        Layer(kind, state=config.mamba_chunk_size if kind == MAMBA else None)
-        for kind in config.mixer_types)
+        Layer(kind, state=config.mamba_chunk_size, recurrence=ssm.MAMBA2)
+        if kind == MAMBA else Layer(kind) for kind in config.mixer_types)
 
 
 def state_leaves(config: Config) -> Dict[str, tuple]:

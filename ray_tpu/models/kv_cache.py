@@ -30,15 +30,18 @@ rolled back to sees. A ring slot's position follows from the last position
 written, so nothing beside the arrays is kept. The full layers keep ``{"k",
 "v"}`` and count their own layers, the window layers theirs.
 
-A model with state layers (a recurrence in place of attention: ``ops/ssm.py``)
-holds a third pair, ``{"ssm", "conv"}``: ``[Ls, B, H, P, N]`` float32, the
-state a head, and ``[Ls, B, (K - 1) C]``, the last ``K - 1`` rows that
+A model with state layers (a recurrence in place of attention: Mamba-2's,
+``ops/ssm.py``, or the gated delta rule's, ``ops/delta_rule.py``) holds a
+third pair, ``{"ssm", "conv"}``: ``[Ls, B, H, P, N]`` float32, the state a
+head (the family's ``state_leaves`` says of what shape: Mamba-2's channels x
+states, the delta rule's keys x values), and ``[Ls, B, (K - 1) C]``, the last ``K - 1`` rows that
 entered the layer's convolution, one after another (flat: with ``[.., K - 1,
 C]`` or ``[.., C, K - 1]`` minor the chip pads three rows to a tile of 8 or
 128, and its compiler repacks the whole leaf around every layer's write, 0.75
 ms a tick at 48 slots). Slot on axis 1 like the rest, and nothing
 in them grows with the position. ``recur`` is such a layer's whole access,
-as ``attend`` is an attention layer's: a block of tokens takes the layer's
+as ``attend`` is an attention layer's, and the one place the two
+recurrences share what they share: a block of tokens takes the layer's
 state out, scans from it and puts back the state after the block's last REAL
 token (``Step.real``); a decode step on a TPU is one kernel over the whole
 state that reads and writes the slots that decode and no other.
@@ -235,62 +238,63 @@ def _write(rows: jax.Array, new: jax.Array, hit: jax.Array) -> jax.Array:
     return jnp.where(hit.any(1)[:, None, None, :], placed, rows)
 
 
-def recur(carried, xbc, dt, layer, shape, chunk: int):
-    """A state layer's recurrence over xbc [B, T, C] (what enters the
-    convolution: x, B and C side by side) and dt [B, T, H] float32, positive:
-    the convolution, then ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t``,
-    ``y_t = S_t C_t + D x_t`` -> (cache, y [B, T, H, P] float32). ``layer``
-    holds ``conv_w`` [C, K], ``conv_b`` [C], ``A_log`` and ``D`` [H];
-    ``shape`` is a slot's state (H, P, N), ``chunk`` the scan's.
+def recur(carried, entering, gates, layer, recurrence, shape, chunk: int):
+    """A state layer's mixer between its two projections, whatever its
+    recurrence: ``entering`` [B, T, C] (what enters the convolution) through
+    the layer's ``conv_w`` [C, K] (and ``conv_b`` [C], where it has one),
+    then ``recurrence`` (an ``ops/ssm.py:Recurrence``: Mamba-2's, the gated
+    delta rule's) over what left it and ``gates``, the family's per-step
+    numbers (a pytree of [B, T, H] float32) -> (cache, y [B, T, H, P]
+    float32). ``shape`` is a slot's state (H, ..), ``chunk`` the scan's.
+    Shared, and so written here once: the convolution's tail, the steps
+    that are no token (their gates are zeroed, which every recurrence takes
+    as "leave the state as it is"), the cache's read and write, and which of
+    the scan, the one-token step and its kernel runs.
 
     ``carried`` is (cache, the layer's index among the state layers, the
     ``Step``): the recurrence starts from the cache's state and tail and
     leaves there what they are after the last real token. None: from zeros
     (the full forward), and the cache returned is None."""
-    B, T, C = xbc.shape
+    B, T, C = entering.shape
     f32 = jnp.float32
     cache, index, at = carried or (None, None, None)
-    A, D = -jnp.exp(layer["A_log"].astype(f32)), layer["D"].astype(f32)
-    heads, _, d_state = shape
-    inner = C - 2 * d_state
     real = None if at is None else at.real
     taps = layer["conv_w"].shape[-1]
+    scope = recurrence.scope
     if cache is None:
-        tail = jnp.zeros((B, taps - 1, C), xbc.dtype)
+        tail = jnp.zeros((B, taps - 1, C), entering.dtype)
         state = jnp.zeros((B, *shape), f32)
     else:
         tail = jax.lax.dynamic_index_in_dim(
             cache[STATE[1]], index, 0, False).reshape(B, taps - 1, C)
-    with jax.named_scope("ssm.conv"):
-        mixed, tail = ssm.conv(xbc, tail, layer["conv_w"], layer["conv_b"],
-                               real)
-        x, Bm, Cm = jnp.split(mixed, [inner, inner + d_state], axis=-1)
-        x = x.reshape(B, T, heads, -1)
-    if real is not None:    # a step that is no token: dt 0 leaves the state
-        dt = jnp.where(jnp.arange(T)[None, :, None] < real[:, None, None],
-                       dt, 0.0)
+    with jax.named_scope(f"{scope}.conv"):
+        mixed, tail = ssm.conv(entering, tail, layer["conv_w"],
+                               layer.get("conv_b"), real)
+    if real is not None:    # a step that is no token leaves the state
+        token = jnp.arange(T)[None, :, None] < real[:, None, None]
+        gates = jax.tree.map(lambda g: jnp.where(token, g, 0.0), gates)
     kernel = cache is not None and T == 1 and _decode_impl() != "xla"
+    if T == 1:
+        mixed, gates = mixed[:, 0], jax.tree.map(lambda g: g[:, 0], gates)
     if kernel:
-        with jax.named_scope("ssm.update"):
-            y, states = ssm.ssm_update(
-                cache[STATE[0]], index, x[:, 0], dt[:, 0], A, Bm[:, 0],
-                Cm[:, 0], live=at.live,
-                interpret=_decode_impl() == "pallas_interpret")
-        y = y[:, None]
+        with jax.named_scope(f"{scope}.update"):
+            y, states = recurrence.kernel(
+                layer, cache[STATE[0]], index, mixed, gates, at.live,
+                _decode_impl() == "pallas_interpret")
     else:
         if cache is not None:
             state = jax.lax.dynamic_index_in_dim(
                 cache[STATE[0]], index, 0, False)
         if T == 1:
-            with jax.named_scope("ssm.update"):
-                y, state = ssm.ssm_update_xla(
-                    state, x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0],
+            with jax.named_scope(f"{scope}.update"):
+                y, state = recurrence.step(
+                    layer, state, mixed, gates,
                     None if real is None else real > 0)
-            y = y[:, None]
         else:
-            with jax.named_scope("ssm.scan"):
-                y, state = ssm.ssm_scan(x, dt, A, Bm, Cm, state, chunk)
-    y = y + D[:, None] * x.astype(f32)
+            with jax.named_scope(f"{scope}.scan"):
+                y, state = recurrence.scan(layer, mixed, gates, state, chunk)
+    if T == 1:
+        y = y[:, None]
     if cache is None:
         return None, y
     if not kernel:

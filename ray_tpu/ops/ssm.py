@@ -32,9 +32,18 @@ oracle:
   slot's state in flight while the one before is computed on, and no other:
   a slot that is not live starts no DMA in either direction, its state leaves
   the call as it entered and its row of the result is zeros.
-  ``ssm_update_xla`` is the same step over one layer's slice.
+  ``ssm_update_xla`` is the same step over one layer's slice. The kernel's
+  way through the slots (``visit_live``) is any one-token step's; Mamba-2's
+  own is ``_step``, and ``ops/delta_rule.py`` gives it another.
+
+``MAMBA2`` is the three as ``models/kv_cache.py:recur`` takes a kind of state
+layer's recurrence (``Recurrence``): from what left the convolution, the
+per-step gate and the layer's own ``A_log`` and ``D``.
 """
 from __future__ import annotations
+
+import math
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -47,7 +56,7 @@ STATE_BYTES = 4 << 20
 
 def conv(xbc, tail, weight, bias, real=None):
     """xbc [B, T, C] through the depthwise causal convolution ``weight``
-    [C, K] + ``bias`` [C] and a SiLU, the ``K - 1`` rows before the block
+    [C, K] + ``bias`` [C] (None: none) and a SiLU, the ``K - 1`` rows before the block
     taken from ``tail`` [B, K - 1, C] (zeros before a sequence's first
     token) -> (the result [B, T, C] in xbc's dtype, the tail after the
     block's last real token). ``real`` [B]: how many of a row's T tokens are
@@ -56,8 +65,10 @@ def conv(xbc, tail, weight, bias, real=None):
     K = weight.shape[-1]
     rows = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
     w = weight.astype(jnp.float32)
-    out = bias.astype(jnp.float32) + sum(
-        w[:, j] * rows[:, j:j + T].astype(jnp.float32) for j in range(K))
+    out = sum(w[:, j] * rows[:, j:j + T].astype(jnp.float32)
+              for j in range(K))
+    if bias is not None:
+        out = bias.astype(jnp.float32) + out
     if real is None:
         after = rows[:, T:]
     elif T == 1:            # a decode step: moved on by one row, or not
@@ -157,58 +168,124 @@ def ssm_update_xla(state, x, dt, A, Bm, Cm, live=None):
     return jnp.where(keep, y, 0.0), jnp.where(keep[..., None], new, state)
 
 
-def _kernel(layer_ref, live_ref, decay_ref, xdt_ref, b_ref, c_ref, s_hbm,
-            y_ref, so_hbm, sbuf, obuf, read_sem, write_sem):
-    B, _, H = decay_ref.shape
-    visits = live_ref[B]    # the live slots' indices, then their count
-    layer = layer_ref[0]
+def _visiting(body, operands: int):
+    """The kernel of a one-token step over a whole state ``[L, B, ...]``
+    that stays in HBM, whatever the step: the live slots' states come
+    through VMEM one after another, the next on its way in and the last on
+    its way back while ``body(b, sbuf, obuf, buf, *operands, y_ref)`` makes
+    slot ``b``'s new state ``obuf[buf]`` and its row of ``y_ref`` from
+    ``sbuf[buf]`` and the ``operands`` small arrays in VMEM. A slot that is
+    not live starts no DMA in either direction."""
 
-    def read(v, buf):
-        return pltpu.make_async_copy(
-            s_hbm.at[layer, live_ref[v]], sbuf.at[buf], read_sem.at[buf])
+    def kernel(layer_ref, live_ref, *refs):
+        small = refs[:operands]
+        s_hbm, y_ref, so_hbm, sbuf, obuf, read_sem, write_sem = refs[operands:]
+        B = y_ref.shape[0]
+        visits = live_ref[B]    # the live slots' indices, then their count
+        layer = layer_ref[0]
 
-    def write(v, buf):
-        return pltpu.make_async_copy(
-            obuf.at[buf], so_hbm.at[layer, live_ref[v]], write_sem.at[buf])
+        def read(v, buf):
+            return pltpu.make_async_copy(
+                s_hbm.at[layer, live_ref[v]], sbuf.at[buf], read_sem.at[buf])
 
-    def visit(v, _):
-        buf = v % 2
+        def write(v, buf):
+            return pltpu.make_async_copy(
+                obuf.at[buf], so_hbm.at[layer, live_ref[v]],
+                write_sem.at[buf])
 
-        @pl.when(v + 1 < visits)
-        def _():            # the next slot's state sets out
-            read(v + 1, 1 - buf).start()
+        def visit(v, _):
+            buf = v % 2
 
-        read(v, buf).wait()
+            @pl.when(v + 1 < visits)
+            def _():            # the next slot's state sets out
+                read(v + 1, 1 - buf).start()
 
-        @pl.when(v >= 2)
-        def _():            # the state sent back two visits ago
-            write(v - 2, buf).wait()
+            read(v, buf).wait()
 
-        b = live_ref[v]
-        heard, said = b_ref[b], c_ref[b]                  # [1, N]
-        for h in range(H):
-            # a head's decay and its dt * x lie down the sublanes: [P, 1]
-            new = (decay_ref[b, :, h:h + 1] * sbuf[buf, h]
-                   + xdt_ref[b, :, h:h + 1] * heard)
-            obuf[buf, h] = new
-            y_ref[b, :, h:h + 1] = jnp.sum(
-                new * said, axis=-1, keepdims=True)
-        write(v, buf).start()
-        return 0
+            @pl.when(v >= 2)
+            def _():            # the state sent back two visits ago
+                write(v - 2, buf).wait()
 
-    # what no visit writes, a slot that is not live, leaves as zeros
-    y_ref[...] = jnp.zeros(y_ref.shape, y_ref.dtype)
+            body(live_ref[v], sbuf, obuf, buf, *small, y_ref)
+            write(v, buf).start()
+            return 0
 
-    @pl.when(visits > 0)
-    def _():
-        read(0, 0).start()
+        # what no visit writes, a slot that is not live, leaves as zeros
+        y_ref[...] = jnp.zeros(y_ref.shape, y_ref.dtype)
 
-    jax.lax.fori_loop(0, visits, visit, 0)
-    for back in (2, 1):     # the last two visits' states
-
-        @pl.when(visits >= back)
+        @pl.when(visits > 0)
         def _():
-            write(visits - back, (visits - back) % 2).wait()
+            read(0, 0).start()
+
+        jax.lax.fori_loop(0, visits, visit, 0)
+        for back in (2, 1):     # the last two visits' states
+
+            @pl.when(visits >= back)
+            def _():
+                write(visits - back, (visits - back) % 2).wait()
+
+    return kernel
+
+
+def visit_live(body, name: str, states, layer, live, operands, row,
+               interpret: bool = False):
+    """``body`` (``_visiting``) over layer ``layer`` of ``states``
+    [L, B, ...] float32, in place -> (y [B, *row] float32, states: the
+    operand's own buffer). ``live`` (``decode_attention.live_slots``'
+    [B + 1]; None: every slot) names the slots it visits; ``operands`` are
+    the step's small arrays, each whole in VMEM. ``name`` is the custom
+    call's, which the benchmark's readers know it by."""
+    L, B, *slot = states.shape
+    f32 = jnp.float32
+    held = 4 * math.prod(slot)     # bytes of one slot's state
+    if states.dtype != f32:
+        raise ValueError(f"the kernel steps a float32 state, not "
+                         f"{states.dtype}: the XLA path's ({name}_xla)")
+    if held > STATE_BYTES:
+        raise ValueError(
+            f"a slot's state of {' x '.join(map(str, slot))} is held in "
+            f"VMEM whole, four at a time: more than {STATE_BYTES} bytes is "
+            "not")
+    if live is None:        # slots 0 .. B - 1, and B of them
+        live = jnp.arange(B + 1, dtype=jnp.int32)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        _visiting(body, len(operands)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[vmem] * len(operands) + [hbm],
+            out_specs=[vmem, hbm],
+            scratch_shapes=[
+                pltpu.VMEM((2, *slot), f32),
+                pltpu.VMEM((2, *slot), f32),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((B, *row), f32),
+                   jax.ShapeDtypeStruct(states.shape, f32)],
+        # operands count from the scalar-prefetch arguments on
+        input_output_aliases={2 + len(operands): 1},
+        # four states held, and the small operands, whose rows pad to
+        # whole tiles
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=4 * held + (32 << 20)),
+        name=name,
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), live.astype(jnp.int32),
+      *operands, states)
+
+
+def _step(b, sbuf, obuf, buf, decay_ref, xdt_ref, b_ref, c_ref, y_ref):
+    heard, said = b_ref[b], c_ref[b]                      # [1, N]
+    for h in range(decay_ref.shape[-1]):
+        # a head's decay and its dt * x lie down the sublanes: [P, 1]
+        new = (decay_ref[b, :, h:h + 1] * sbuf[buf, h]
+               + xdt_ref[b, :, h:h + 1] * heard)
+        obuf[buf, h] = new
+        y_ref[b, :, h:h + 1] = jnp.sum(new * said, axis=-1, keepdims=True)
 
 
 def ssm_update(states, layer, x, dt, A, Bm, Cm, *, live=None,
@@ -221,46 +298,66 @@ def ssm_update(states, layer, x, dt, A, Bm, Cm, *, live=None,
     row of ``y`` is zeros."""
     L, B, H, P, N = states.shape
     f32 = jnp.float32
-    if states.dtype != f32:
-        raise ValueError(f"the kernel steps a float32 state, not "
-                         f"{states.dtype}: the XLA path's (ssm_update_xla)")
-    if H * P * N * 4 > STATE_BYTES:
-        raise ValueError(
-            f"a slot's state of {H} x {P} x {N} is held in VMEM whole, "
-            f"four at a time: more than {STATE_BYTES} bytes is not")
-    if live is None:        # slots 0 .. B - 1, and B of them
-        live = jnp.arange(B + 1, dtype=jnp.int32)
     dt = dt.astype(f32)
     # a head's scalars down the sublanes of its [P, N] tile: [B, P, H]
     decay = jnp.broadcast_to(jnp.exp(dt * A)[:, None, :], (B, P, H))
     xdt = jnp.swapaxes(dt[..., None] * x.astype(f32), 1, 2)
-    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
-    hbm = pl.BlockSpec(memory_space=pl.ANY)
-    y, states = pl.pallas_call(
-        _kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(1,),
-            in_specs=[vmem, vmem, vmem, vmem, hbm],
-            out_specs=[vmem, hbm],
-            scratch_shapes=[
-                pltpu.VMEM((2, H, P, N), f32),
-                pltpu.VMEM((2, H, P, N), f32),
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SemaphoreType.DMA((2,)),
-            ],
-        ),
-        out_shape=[jax.ShapeDtypeStruct((B, P, H), f32),
-                   jax.ShapeDtypeStruct(states.shape, f32)],
-        # operands count from the scalar-prefetch arguments on
-        input_output_aliases={6: 1},
-        # four states held, and the small operands, whose rows pad to
-        # whole tiles
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=4 * H * P * N * 4 + (32 << 20)),
-        name="ssm_update",
-        interpret=interpret,
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), live.astype(jnp.int32),
-      decay, xdt, Bm.astype(f32)[:, None, :], Cm.astype(f32)[:, None, :],
-      states)
+    y, states = visit_live(
+        _step, "ssm_update", states, layer, live,
+        (decay, xdt, Bm.astype(f32)[:, None, :], Cm.astype(f32)[:, None, :]),
+        (P, H), interpret)
     return jnp.swapaxes(y, 1, 2), states
+
+
+class Recurrence(NamedTuple):
+    """A kind of state layer's recurrence, as ``kv_cache.recur`` runs it
+    between the convolution and the cache: ``mixed`` is what left the
+    convolution, ``gates`` the family's per-step numbers [.., H] float32
+    (zeros at a step that is no token, which must then leave the state as it
+    is), ``layer`` the layer's weights; y comes back float32 [.., H, P]."""
+    scope: str          # its operations run under ``<scope>.conv|scan|update``
+    # (layer, mixed [B, T, C], gates, state [B, H, ..], chunk) -> (y, state)
+    scan: Callable
+    # (layer, state, mixed [B, C], gates, live [B] bool | None) -> (y, state)
+    step: Callable
+    # (layer, states [L, B, H, ..], index, mixed [B, C], gates, live
+    # [B + 1] | None, interpret) -> (y, states): the kernel, in place
+    kernel: Callable
+
+
+def _operands(layer, mixed, state_shape):
+    """x [.., H, P], B and C [.., N] of what left the convolution (side by
+    side, B and C shared by the heads), and the layer's A and D [H]."""
+    H, _, N = state_shape
+    inner = mixed.shape[-1] - 2 * N
+    x, Bm, Cm = jnp.split(mixed, [inner, inner + N], axis=-1)
+    return (x.reshape(*x.shape[:-1], H, -1), Bm, Cm,
+            -jnp.exp(layer["A_log"].astype(jnp.float32)),
+            layer["D"].astype(jnp.float32))
+
+
+def _skip(y, D, x):
+    """``y_t = S_t C_t + D x_t``."""
+    return y + D[:, None] * x.astype(jnp.float32)
+
+
+def _mamba2_scan(layer, mixed, dt, state, chunk):
+    x, Bm, Cm, A, D = _operands(layer, mixed, state.shape[1:])
+    y, state = ssm_scan(x, dt, A, Bm, Cm, state, chunk)
+    return _skip(y, D, x), state
+
+
+def _mamba2_step(layer, state, mixed, dt, live):
+    x, Bm, Cm, A, D = _operands(layer, mixed, state.shape[1:])
+    y, state = ssm_update_xla(state, x, dt, A, Bm, Cm, live)
+    return _skip(y, D, x), state
+
+
+def _mamba2_kernel(layer, states, index, mixed, dt, live, interpret):
+    x, Bm, Cm, A, D = _operands(layer, mixed, states.shape[2:])
+    y, states = ssm_update(states, index, x, dt, A, Bm, Cm, live=live,
+                           interpret=interpret)
+    return _skip(y, D, x), states
+
+
+MAMBA2 = Recurrence("ssm", _mamba2_scan, _mamba2_step, _mamba2_kernel)

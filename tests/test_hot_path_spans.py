@@ -477,10 +477,10 @@ def test_a_reader_returns_the_hand_sum_over_the_spans(
     listed = {m["name"]: m for m in bench["per_layer"]}
     assert listed[metric]["moves"] == "per_token_p50_ms"
     # every serving cell, none dropped: the cells that report the metric
-    # these move (the three of PR 36 and PR 42's)
+    # these move (the three of PR 36, PR 42's and PR 47's)
     serving = next(m["workloads"] for m in bench["end_to_end"]
                    if m["name"] == "per_token_p50_ms")
-    assert len(serving) == 4
+    assert len(serving) == 5
     assert listed[metric]["workloads"] == serving
     monkeypatch.setattr(host_spans, "TRACE_ROOT", recording["logdir"])
     got = run.read_layer_metric(

@@ -651,3 +651,42 @@ def test_speculative_respects_sequence_end():
     eng = DecodeEngine(cfg, seed=0)
     out = eng.generate([5, 9] * 6, SamplingParams(max_new_tokens=64))
     assert len(out) <= 40 - 12
+
+
+@pytest.mark.parametrize("shared", [True, False], ids=["entry", "own"])
+def test_prefill_donates_an_admissions_own_slot_cache_and_no_entrys(shared):
+    """A prompt's chunks write one slot cache in place (``engine_programs``'
+    ``own_cache``: chunks enqueued together would else hold a cache each),
+    from the fresh one on; a prefix-cache entry's cache is shared by every
+    request that continues it, and the program that starts from it copies
+    it."""
+    import jax
+
+    eng = _engine(prefix_cache_size=4)
+    given = {"own": [], "entry": []}
+    own, entry = eng._prefill_own, eng._prefill
+    eng._prefill_own = lambda params, toks, cache, *a, **k: (
+        given["own"].append(cache) or own(params, toks, cache, *a, **k))
+    eng._prefill = lambda params, toks, cache, *a, **k: (
+        given["entry"].append(cache) or entry(params, toks, cache, *a, **k))
+    base = list(range(2, 18))           # 16 tokens: a bucket's boundary
+    p = SamplingParams(max_new_tokens=3)
+    try:
+        if shared:
+            eng.generate(base, p)       # seeds the prefix cache
+            held = eng._prefix_cache[tuple(base)]["cache"]
+            first = eng.generate(base + [30, 31], p)
+            again = eng.generate(base + [40, 41], p)
+            assert eng.stats["prefix_partial_hits"] == 2
+            assert [c is held for c in given["entry"]] == [True, True]
+            assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(held))
+            assert first != again
+        else:
+            # 40 tokens over buckets up to 32: two chunks, the last padded
+            eng.generate(list(range(2, 42)), p)
+            assert eng.stats["prefix_partial_hits"] == 0
+            assert given["entry"] == [] and len(given["own"]) == 2
+            assert all(leaf.is_deleted() for cache in given["own"]
+                       for leaf in jax.tree.leaves(cache))
+    finally:
+        eng.shutdown()

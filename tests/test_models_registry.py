@@ -37,7 +37,7 @@ SHARED = ("forward_features", "forward", "init_kv_cache", "forward_cached",
 # families with no dense form: every layer routed, whatever is stated
 ALWAYS_ROUTED = ("smallthinker",)
 # families with no routed form: experts are refused by the key's name
-NEVER_ROUTED = ("granite_hybrid",)
+NEVER_ROUTED = ("granite_hybrid", "olmo_hybrid")
 GATED = ("swiglu", "reglu")   # activations with a gate matrix
 
 
@@ -185,7 +185,8 @@ def test_llm_config_builds_every_family(family, experts):
 
 TINY = {"gpt2": "gpt2-tiny", "llama": "llama-tiny", "afmoe": "afmoe-tiny",
         "smallthinker": "smallthinker-tiny",
-        "granite_hybrid": "granite-hybrid-tiny"}
+        "granite_hybrid": "granite-hybrid-tiny",
+        "olmo_hybrid": "olmo-hybrid-tiny"}
 
 
 def _flat(family) -> dict:
@@ -630,7 +631,11 @@ def test_a_prompt_longer_than_the_largest_bucket_is_admitted_in_chunks(
                  ) if family == "afmoe" else dict(
         layer_types=("mamba", "mamba", "attention", "mamba"),
         mamba_d_state=16, mamba_d_head=16, mamba_chunk_size=8
-        ) if family == "granite_hybrid" else {}
+        ) if family == "granite_hybrid" else dict(
+        layer_types=("linear_attention",) * 3 + ("full_attention",),
+        linear_num_key_heads=4, linear_num_value_heads=4,
+        linear_key_head_dim=8, linear_value_head_dim=16, linear_chunk_size=4
+        ) if family == "olmo_hybrid" else {}
     prompt = [int(t) for t in np.random.default_rng(5).integers(2, 300, 17)]
     answers = []
     for buckets in ((8, 16), (32,)):

@@ -439,7 +439,8 @@ def test_trinity_programs_fit_the_chip_and_keep_both_caches_in_place(
             *_decode_args(one_chip, args)).compile()
     else:
         rows = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
-        compiled = engine_programs(cfg)[0].lower(*args, rows=rows).compile()
+        compiled = engine_programs(cfg, own_cache=True)[0].lower(
+            *args, rows=rows).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
     param_bytes = sum(a.size * a.dtype.itemsize
                       for a in jax.tree.leaves(args[0]))
@@ -516,7 +517,8 @@ def test_granite_programs_fit_the_chip_and_step_the_state_in_place(
             *_decode_args(one_chip, args)).compile()
     else:
         rows = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
-        compiled = engine_programs(cfg)[0].lower(*args, rows=rows).compile()
+        compiled = engine_programs(cfg, own_cache=True)[0].lower(
+            *args, rows=rows).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
     # no copy or conversion of the whole table
     assert not re.search(
@@ -538,6 +540,83 @@ def test_granite_programs_fit_the_chip_and_step_the_state_in_place(
         assert _weight_converts(text, args[0]) == []
     else:
         assert mem.temp_size_in_bytes < 0.5e9
+        assert "f32[1,1,100352]" in text.split("\n", 1)[0]
+
+
+# ------------------------------------------ Olmo-Hybrid-7B's engine programs
+# The eighth cell's size: 16 of 32 layers at the published widths, 8 slots
+# of 8,704 positions (``benchmarks/configs/olmo-hybrid-7b.json``).
+
+
+@pytest.mark.parametrize("program, slots, width", [
+    ("decode", 8, 1), ("prefill", 1, 1024)])
+def test_olmo_hybrid_programs_fit_the_chip_and_step_the_state_in_place(
+        one_chip, program, slots, width, monkeypatch):
+    """``jit_decode`` at 8 slots and ``jit_prefill`` at the largest bucket,
+    at the published widths and the cell's depth: the v5e compiler takes the
+    ``delta_update`` kernel over the whole state (three calls, one a state
+    layer of the period, which is scanned four times) and the decode kernel
+    at 30 kv heads of 128 and 8,704 positions; the cache of two kinds is the
+    program's argument and its result in one buffer, an admission's own
+    slot cache too (``own_cache``: a prompt's chunks are enqueued together,
+    and none then holds a second 0.57 GB); the prefill's chunked scan (the
+    inverse by halves a chunk, a scan over the chunks) compiles; the table
+    and the head are read where they lie."""
+    import re
+
+    from benchmarks import run
+    from benchmarks.lib import program as harness
+    from ray_tpu.llm.engine import engine_programs
+    from ray_tpu.models import kv_cache
+
+    monkeypatch.setattr(kv_cache, "_decode_impl", lambda: "pallas")
+    config = run.load_cell("olmo-hybrid-7b.serve-docs")[2]
+    assert config["serve"]["max_batch_slots"] == 8
+    cfg, args = _engine_program_args(
+        one_chip, slots, width, harness.model_config(config), block=1024)
+    cache = args[2]
+    assert {k: (v.shape, v.dtype) for k, v in cache.items()} == {
+        "k": ((4, slots, 30, 128, 8704), jnp.bfloat16),
+        "v": ((4, slots, 30, 128, 8704), jnp.bfloat16),
+        # a head's [96, 192] with its values up to two lane tiles
+        "ssm": ((12, slots, 30, 96, 256), jnp.float32),
+        "conv": ((12, slots, 3 * 11520), jnp.bfloat16)}
+    param_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(args[0]))
+    assert 8.19e9 < param_bytes < 8.21e9    # 4.10 B parameters in bf16
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in cache.values())
+    if program == "decode":
+        compiled = engine_programs(cfg)[2].lower(
+            *_decode_args(one_chip, args)).compile()
+    else:
+        rows = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+        compiled = engine_programs(cfg, own_cache=True)[0].lower(
+            *args, rows=rows).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    print(program, "arguments", mem.argument_size_in_bytes, "temporaries",
+          mem.temp_size_in_bytes, "aliased", mem.alias_size_in_bytes,
+          "cache", cache_bytes)
+    # no copy or conversion of the table or of the head
+    assert not re.search(
+        r"= \w+\[100352,3840\]\S* (copy|transpose)\(", text)
+    if program == "decode":
+        # 4.28 GB of keys and values, 0.28 GB of state, 6.6 MB of rows
+        assert 4.5e9 < cache_bytes < 4.6e9
+        assert mem.alias_size_in_bytes >= cache_bytes
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.0e9
+        assert mem.temp_size_in_bytes < 0.05e9
+        calls = [line for line in text.splitlines() if "custom-call(" in line]
+        updates = [c for c in calls if re.match(r"\s*%?delta_update", c)]
+        assert len(updates) == 3
+        assert all(f"s32[{slots + 1}]" in c
+                   and f"f32[12,{slots},30,96,256]" in c for c in updates)
+        assert len([c for c in calls
+                    if re.match(r"\s*%?decode_attention", c)]) == 1
+        assert _weight_converts(text, args[0]) == []
+    else:
+        assert mem.alias_size_in_bytes >= cache_bytes
+        assert _cache_sized(text, cache) == []
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 10.5e9
         assert "f32[1,1,100352]" in text.split("\n", 1)[0]
 
 
