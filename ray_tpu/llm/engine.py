@@ -67,7 +67,14 @@ length or the window, whichever is less; ``layers_window``). ``active`` of
 ``slots`` decode in a tick, and the decode kernel visits those and no other
 (``ops/decode_attention.py``): ``slots_skipped`` of ``stats`` sums the
 visits a layer that it did not make, ``slots - active``, beside
-``slot_ticks``, the ones it made. ``tick``,
+``slot_ticks``, the ones it made. A prefill chunk attends the filled
+positions of its slot's cache and no other (on a TPU through
+``ops/block_attention.py``): ``prefill_key_positions`` of ``engine.admit`` is
+the key positions the admission's chunks see, summed over chunks and
+attention layers from their starts, their lengths and the window, beside
+``prefill_cache_positions``, all that those layers' caches hold, once a
+chunk; one less their ratio is the share of the cache a chunk is spared.
+``tick``,
 ``active``, ``slots``, ``ahead``, ``overrun``, the ``cache_positions`` and
 ``moe_rows`` of an ``engine.tick`` span are those of the program
 dispatched in it; ``experts_touched`` is the count of the programs read
@@ -326,7 +333,7 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.models import decoder, module_for
+        from ray_tpu.models import decoder, kv_cache, module_for
 
         self.config = config
         bundle = None
@@ -399,6 +406,13 @@ class DecodeEngine:
         self._cache = decoder.init_kv_cache(cfg, B, S, block=block)
         self._layers_full = (
             len(kinds) - self._layers_window - self._layers_state)
+        # (layers, positions held, window) of each kind of attention layer
+        self._attended = [
+            (layers, self._cache[names[0]].shape[-1], window)
+            for layers, names, window in (
+                (self._layers_full, kv_cache.FULL, None),
+                (self._layers_window, kv_cache.WINDOW, self._window))
+            if layers]
         # the lengths short of a whole prompt that the prefix store keeps
         self._boundaries = tuple(config.prefill_buckets) if (
             config.prefix_cache_size > 0 and not self._layers_window
@@ -467,6 +481,10 @@ class DecodeEngine:
             # states a tick reads and writes), and prompt tokens that went
             # through a prefill's scan; both 0 for a model with none
             "state_slot_layers": 0, "ssm_prefill_tokens": 0,
+            # key positions the prefill chunks visited, summed over chunks
+            # and attention layers, and what those layers' caches hold (a
+            # chunk scored against all of it before the block kernel)
+            "prefill_key_positions": 0, "prefill_cache_positions": 0,
             "compiles": compile_count(),  # of the process, not the engine
         }
         self._rid_seq = itertools.count(1)
@@ -623,6 +641,25 @@ class DecodeEngine:
         self.stats[name] += count
         return {name: count, "layers_state": self._layers_state}
 
+    def _prefill_positions(self, chunks) -> dict:
+        """A span's ``prefill_key_positions`` of an admission's ``chunks``
+        [(start, tokens with the padding)]: the key positions its programs
+        visit (``kv_cache.positions_seen``), summed over chunks and
+        attention layers, beside ``prefill_cache_positions``, all that those
+        layers' caches hold, once a chunk; summed into ``stats``."""
+        from ray_tpu.models import kv_cache
+
+        counts = {
+            "prefill_key_positions": sum(
+                layers * kv_cache.positions_seen(start, T, length, window)
+                for start, T in chunks
+                for layers, length, window in self._attended),
+            "prefill_cache_positions": len(chunks) * sum(
+                layers * length for layers, length, _ in self._attended)}
+        for name, count in counts.items():
+            self.stats[name] += count
+        return counts
+
     def _experts_touched(self, touched) -> int:
         """A span's ``experts_touched`` of a program's count a layer, on the
         host (a list of one, or of none from a dense model), summed into
@@ -663,7 +700,7 @@ class DecodeEngine:
                 )
             return entry["cache"], first, lp, {
                 "bucket": 0, "chunks": 0, "prefix": "exact", "moe_rows": 0,
-                "experts_touched": 0,
+                "experts_touched": 0, **self._prefill_positions([]),
                 **self._state_count("ssm_prefill_tokens", 0)}
         largest = max(self.config.prefill_buckets)
         if entry is not None and n - matched <= largest and (
@@ -687,6 +724,7 @@ class DecodeEngine:
             with span("engine.admit.cache"):
                 cache1 = self._empty_slot_cache()
         programs = []  # (lengths read of it, its logits, its touched)
+        chunks = []    # (where it starts, its tokens with the padding)
         with span("engine.prefill.dispatch"):
             for at in range(base, n, largest):
                 piece = prompt_ids[at:at + largest]
@@ -708,6 +746,7 @@ class DecodeEngine:
                     *self._real([len(piece)]), rows=jnp.asarray(rows),
                 )
                 programs.append((lengths, logits, touched))
+                chunks.append((at, Tpad))
         with span("engine.prefill.fetch"):
             # the wait for the programs and their [1, width, V] logits' way
             # to the host
@@ -727,6 +766,7 @@ class DecodeEngine:
         return cache1, first, lp, {
             "bucket": Tpad, "chunks": len(programs),
             "prefix": "partial" if base else "none", **moe,
+            **self._prefill_positions(chunks),
             **self._state_count("ssm_prefill_tokens", n - base)}
 
     def _activate_slot_locked(self, b, cache1, first, req: _Pending,
@@ -814,6 +854,7 @@ class DecodeEngine:
             first_lp = prefilled.get("first_logprob")
             how = {"bucket": 0, "chunks": 0, "prefix": "none",
                    "moe_rows": 0, "experts_touched": 0,
+                   **self._prefill_positions([]),
                    **self._state_count("ssm_prefill_tokens", 0)}
             if params.seed is not None:
                 rng = self._rng_for(params)

@@ -11,14 +11,23 @@ every layer's slice on its way to the attention products and back. Held
 position-minor to begin with, the slice feeds both products as it lies.
 
 ``attend`` is a layer's whole access: place the new tokens' columns, attend
-over what is filled. Two paths, parted by static shapes alone. A decode step
-(T == 1; S a multiple of the chip's 128 lanes) on a TPU is one kernel (``ops/decode_attention.py``) over the whole
-cache that reads the filled positions and writes one tile a slot, of the
-slots it is told decode (``Step.live``) and of no other: a slot that does
-not decode keeps its cache as it is and gets zeros for its row. A block
-of tokens (prefill at B = 1, speculation's verify) takes the layer's slice
-out, writes it whole and puts it back: the right cost where a block of
-columns lands in a one-slot cache and the query block feeds the MXU.
+over what is filled. Which path it takes is parted by static shapes and the
+platform alone (``_impl``). On a TPU, S a multiple of the chip's 128 lanes,
+a kernel over the whole cache does it. A decode step (T == 1) is
+``ops/decode_attention.py``, which reads the filled positions and writes one
+tile a slot, of the slots it is told decode (``Step.live``) and of no other:
+a slot that does not decode keeps its cache as it is and gets zeros for its
+row. A block of tokens of one slot (a prefill chunk: B = 1, whole tiles of 16
+tokens) has its T columns put where they lie by one update of T columns
+(``_place``; a second one round a ring's end) and then attends through
+``ops/block_attention.py``, which reads the blocks of positions that some
+token of a tile may see and no other, and keeps its scores in VMEM: no
+``[.., T, S]`` array, mask or score, is made. Everything else is XLA's
+(``_attend_xla``): any other backend, a cache whose length the lanes do not
+divide, a few tokens at every slot (speculation's verify), a ring too short
+for the block beside the window. It takes the layer's slice out, scores the
+block against all of it, writes it whole and puts it back; the kernels are
+held to it by the tests.
 
 A model with window layers (attention over the token and the ``window - 1``
 before it) holds a second pair in the same pytree, ``{"k_window",
@@ -54,6 +63,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops import ssm
+from ray_tpu.ops.block_attention import TOKENS, block_attention
 from ray_tpu.ops.decode_attention import TILE, decode_attention, live_slots
 
 
@@ -69,6 +79,15 @@ def ring_length(window: int, block: int, max_len: int) -> int:
     if ring >= TILE:
         ring = -(-ring // TILE) * TILE
     return min(ring, max_len)
+
+
+def positions_seen(start: int, T: int, length: int,
+                   window: Optional[int] = None) -> int:
+    """Key positions that T tokens at ``start .. start + T - 1`` see between
+    them in a cache of ``length``: up to the last token's own; in a ring the
+    ``window - 1`` before the first token (those there are) and the tokens'."""
+    first = 0 if window is None else max(start - (window - 1), 0)
+    return min(start + T - first, length)
 
 
 def init_kv_cache(num_layers: int, batch: int, kv_heads: int, head_dim: int,
@@ -91,15 +110,16 @@ def init_kv_cache(num_layers: int, batch: int, kv_heads: int, head_dim: int,
 
 class Step(NamedTuple):
     """What every layer of one ``forward_cached`` shares: where each slot's
-    tokens start, which positions each token sees and which it lands on,
-    in the full layers' cache and in the window layers' ring (None where
-    the model has no such layer). ``live``: the slots whose tokens are
-    tokens, as the decode kernel takes them (``live_slots``; None: every
-    slot). What the caller says of a slot, never read off its length. The
-    kernel leaves any other slot alone; the XLA path computes every slot and
-    the caller drops the rows it did not ask for. ``real``: how many of a
-    slot's T tokens are tokens (None: all), for a state layer, which must
-    not step on the rest."""
+    tokens start and, where a layer attends in XLA, which positions each
+    token sees and which it lands on, in the full layers' cache and in the
+    window layers' ring (None where the model has no such layer, and where
+    a kernel attends: it compares positions itself). ``live``: the slots
+    whose tokens are tokens, as the decode kernel takes them (``live_slots``;
+    None: every slot). What the caller says of a slot, never read off its
+    length. The kernel leaves any other slot alone; the XLA path computes
+    every slot and the caller drops the rows it did not ask for. ``real``:
+    how many of a slot's T tokens are tokens (None: all), for a state layer,
+    which must not step on the rest."""
     start: jax.Array   # [B] int32
     mask: Optional[jax.Array]    # [B, T, S] bool
     hit: Optional[jax.Array]     # [B, T, S] bool
@@ -123,12 +143,14 @@ def step(start: jax.Array, T: int, cache: Dict[str, jax.Array],
     position is its own or one of the ``window - 1`` before it. ``live``
     [B] bool: the slots that decode (None: every slot), compacted here, once
     for every layer."""
+    B = start.shape[0]
     pos = (start[:, None] + jnp.arange(T)[None, :])[:, :, None]
     mask = hit = ring_mask = ring_hit = None
-    if FULL[0] in cache:
+    if FULL[0] in cache and _impl(B, T, cache[FULL[0]].shape[-1]) == "xla":
         key_pos = jnp.arange(cache[FULL[0]].shape[-1])[None, None, :]
         mask, hit = key_pos <= pos, pos == key_pos
-    if WINDOW[0] in cache:
+    if WINDOW[0] in cache and _impl(
+            B, T, cache[WINDOW[0]].shape[-1], window) == "xla":
         R = cache[WINDOW[0]].shape[-1]
         slot = jnp.arange(R)[None, None, :]
         last = pos[:, -1:, :]
@@ -140,10 +162,25 @@ def step(start: jax.Array, T: int, cache: Dict[str, jax.Array],
 
 
 def _decode_impl() -> str:
-    """How a decode step runs, by the platform alone: the kernel on a TPU
-    (one that fails to lower there raises, it never gives way), XLA
-    elsewhere. ``pallas_interpret`` is the tests'."""
+    """How a layer's access runs where a kernel can take it, by the platform
+    alone: the kernel on a TPU (one that fails to lower there raises, it
+    never gives way), XLA elsewhere. ``pallas_interpret`` is the tests'."""
     return "pallas" if jax.default_backend() == "tpu" else "xla"
+
+
+def _impl(B: int, T: int, length: int, window: Optional[int] = None) -> str:
+    """How B slots' T tokens attend a cache of ``length`` positions (a ring
+    where ``window`` is given), by static shapes and the platform alone.
+    The kernels move whole lane tiles of positions: a cache whose length
+    they do not divide (the chip's compiler refuses a slice of it) is XLA's.
+    A block of tokens is the kernel's where it is one slot's, whole tiles of
+    tokens, and a ring holds it beside the window (the kernel attends once
+    the block is in place); speculation's verify, a few tokens at every
+    slot, stays XLA's."""
+    kernel = length % TILE == 0 and (T == 1 or (
+        B == 1 and T % TOKENS == 0 and T <= length
+        and (window is None or length >= window + T)))
+    return _decode_impl() if kernel else "xla"
 
 
 def attend(cache: Dict[str, jax.Array], layer: jax.Array, q: jax.Array,
@@ -154,23 +191,53 @@ def attend(cache: Dict[str, jax.Array], layer: jax.Array, q: jax.Array,
     in place, and q attended over it -> (cache, what q's shape is): q is
     [B, T, KV, D] or, G query heads sharing a kv head, [B, T, KV, G, D]. The
     cache is a scan's carry and, donated, one buffer from the program's
-    argument to its result on either path."""
+    argument to its result on every path."""
     B, T, KV = q.shape[:3]
     names = WINDOW if windowed else FULL
-    # the kernel moves whole lane tiles of positions: a cache whose length
-    # they do not divide (the chip's compiler refuses a slice of it) keeps
-    # the XLA path, as a block of tokens does
-    kernel = T == 1 and cache[names[0]].shape[-1] % TILE == 0
-    impl = _decode_impl() if kernel else "xla"
+    window = at.window if windowed else None
+    impl = _impl(B, T, cache[names[0]].shape[-1], window)
     if impl == "xla":
         return _attend_xla(cache, names, layer, q, k_new, v_new, *(
             (at.ring_mask, at.ring_hit) if windowed else (at.mask, at.hit)))
+    interpret = impl == "pallas_interpret"
+    if T > 1:
+        k, v = (_place(cache[name], layer, new, at.start[0], windowed)
+                for name, new in zip(names, (k_new, v_new)))
+        with jax.named_scope("attn.block"):
+            out = block_attention(q, k, v, layer, at.start, window=window,
+                                  interpret=interpret)
+        return {**cache, names[0]: k, names[1]: v}, out
     out, k, v = decode_attention(
         q.reshape(B, KV, -1, q.shape[-1]), k_new[:, 0], v_new[:, 0],
         cache[names[0]], cache[names[1]], layer, at.start, live=at.live,
-        window=at.window if windowed else None,
-        interpret=impl == "pallas_interpret")
+        window=window, interpret=interpret)
     return {**cache, names[0]: k, names[1]: v}, out.reshape(q.shape)
+
+
+def _place(leaf: jax.Array, layer, new: jax.Array, start, ring: bool):
+    """``leaf`` [L, 1, KV, D, S] with ``new`` [1, T, KV, D] on positions
+    ``start .. start + T - 1`` of layer ``layer``, in place and nothing else
+    touched: one update of T columns where the block lies inside the cache.
+    The update never moves back over valid columns: a block that reaches
+    past the end is written over the last T positions with what was there
+    kept before it, its tokens past the end dropped, or, in a ring, written
+    to the ring's first positions by a second update of T columns."""
+    S, T = leaf.shape[-1], new.shape[1]
+    cols = new[0].transpose(1, 2, 0).astype(leaf.dtype)[None, None]
+    lane = jnp.arange(T)
+    place = start % S if ring else start
+    at = jnp.clip(place, 0, S - T)
+    over = place - at                # columns that do not fit before the end
+    cols = jnp.roll(cols, over, axis=-1)
+
+    def update(leaf, at, mine):
+        where = (layer, 0, 0, 0, at)
+        old = jax.lax.dynamic_slice(leaf, where, (1, 1, *leaf.shape[2:4], T))
+        return jax.lax.dynamic_update_slice(
+            leaf, jnp.where(mine, cols, old), where)
+
+    leaf = update(leaf, at, lane >= over)
+    return update(leaf, 0, lane < over) if ring else leaf
 
 
 # float32 scores of one product, in elements (512 MiB): a block of tokens

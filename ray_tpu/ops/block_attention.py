@@ -1,0 +1,208 @@
+"""A block of tokens' attention over the cache as one pallas TPU kernel.
+
+A prefill chunk (T tokens of one slot, at positions ``start .. start + T -
+1``) attends the positions of its cache ``[L, 1, KV, D, S]``
+(``models/kv_cache.py``) that are filled, its own columns among them: the
+caller has put those in place (``kv_cache._place``), so every key comes from
+the cache as it lies, position-minor. ``block_attention`` reads the WHOLE
+cache, as ``ops/decode_attention.py`` does and for its reason (an operand of
+a custom call is a whole array: a layer's slice cut by the scan would be
+copied for it); the layer index and ``start`` ride as scalar-prefetch
+arguments and steer which blocks of positions the pipeline fetches.
+
+A tile is ``tq`` tokens of one kv head with the G query heads that share it,
+``G x tq`` rows, against one block of ``bs`` positions: ``q [G tq, D] @ k [D,
+bs]``, online softmax in float32, ``p [G tq, bs]`` against ``v [D, bs]``
+contracted over positions, both products accumulated in float32 and the
+probabilities in the cache's dtype for the second. A tile visits only the
+blocks that hold a position one of its tokens may see: ``[0, start + t]`` in
+a full layer; in a window layer (the cache a ring of S positions, position p
+at ``p mod S``) the ``window - 1`` before each token and itself, round the
+ring from the block that holds the first token's first position. A grid step
+past a tile's last block fetches nothing (its block index is the last one's)
+and computes nothing. Masks are position compares inside the kernel: no
+``[.., T, S]`` array exists outside it. A ring's block met twice (the window's
+two ends in it) is masked each time to its own end, which is sound where the
+ring holds the window and the block, ``S >= window + T``: a slot ``p mod S``
+then holds position p for every p a token of the block may see.
+
+Tile sizes follow the shapes given (``tiles``), nothing else; S is a
+multiple of the 128 lanes and T of ``TOKENS``.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops.decode_attention import NEG_INF, TILE
+
+TOKENS = 16             # a tile's fewest tokens: a bfloat16 tile's sublanes
+ROWS = 2048             # query rows of a tile: tokens x the heads that share
+BLOCK_BYTES = 1 << 18   # of one K (or V) block of positions in VMEM
+SCORES = 1 << 21        # float32 scores of a tile against a block (8 MiB)
+
+
+def tiles(T: int, G: int, D: int, S: int, itemsize: int):
+    """(tokens a tile, positions a block) for T tokens of G query heads a
+    kv head against a cache ``[.., D, S]``: blocks as long as a head's fit
+    ``BLOCK_BYTES``, tiles of up to ``ROWS`` rows whose scores against a
+    block fit ``SCORES``; both powers of two (a row's token is read off its
+    index by a mask, a position's block by a shift). Swept on the chip at
+    the serving cells' shapes (PERF.md, PR 48): longer blocks and taller
+    tiles were faster or the same everywhere, up to what VMEM holds."""
+    bs = TILE
+    while S % (2 * bs) == 0 and D * 2 * bs * itemsize <= BLOCK_BYTES:
+        bs *= 2
+    tq = TOKENS
+    while T % (2 * tq) == 0 and G * 2 * tq <= min(ROWS, SCORES // bs):
+        tq *= 2
+    return tq, bs
+
+
+def _visits(start, i, *, tq: int, bs: int, S: int, window):
+    """Of tile ``i`` of a block of tokens that starts at ``start``: (the
+    first block of positions it visits, counted from position 0; how many
+    blocks it visits)."""
+    p0 = start + i * tq
+    p1 = p0 + tq - 1
+    # x >> shift is x // bs: a floor division costs the scalar core, and
+    # the lowering of each index map at every start-up, several times this
+    shift = bs.bit_length() - 1
+    if window is None:        # a token past the end sees the whole cache
+        return 0, (jnp.minimum(p1, S - 1) >> shift) + 1
+    first = jnp.maximum(p0 - (window - 1), 0) >> shift
+    return first, (p1 >> shift) - first + 1
+
+
+def _kernel(layer_ref, start_ref, q_ref, k_ref, v_ref, o_ref, m_sc, l_sc,
+            acc_sc, *, tq: int, bs: int, S: int, scale: float, window):
+    i, j = pl.program_id(1), pl.program_id(2)
+    first, count = _visits(start_ref[0], i, tq=tq, bs=bs, S=S, window=window)
+
+    @pl.when(j == 0)
+    def _():
+        m_sc[...] = jnp.full(m_sc.shape, NEG_INF, jnp.float32)
+        l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    def attend(masked: bool):
+        q, k, v = q_ref[...], k_ref[...], v_ref[...]
+        kt = jnp.promote_types(q.dtype, k.dtype)
+        sc = jnp.dot(q.astype(kt), k.astype(kt),
+                     preferred_element_type=jnp.float32) * scale  # [rows, bs]
+        if masked:
+            # a row is (head, token): its token is its index's low bits
+            row = jax.lax.broadcasted_iota(jnp.int32, (sc.shape[0], 1), 0)
+            pos = p0 + (row & (tq - 1))
+            key = key0 + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+            seen = key <= pos
+            if window is not None:
+                seen &= key > pos - window
+            sc = jnp.where(seen, sc, NEG_INF)
+        m_prev = m_sc[...]
+        m_next = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_next)
+        # a row that has seen nothing yet adds 1s here (NEG_INF - NEG_INF);
+        # the block that holds its own position wipes them out (alpha = 0)
+        p = jnp.exp(sc - m_next)
+        l_sc[...] = alpha * l_sc[...] + jnp.sum(p, axis=-1, keepdims=True)
+        vt = jnp.promote_types(q.dtype, v.dtype)
+        acc_sc[...] = alpha * acc_sc[...] + jax.lax.dot_general(
+            p.astype(q.dtype).astype(vt), v.astype(vt),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        m_sc[...] = m_next
+
+    # positions are compared only in a block that some token of the tile
+    # sees in part: the one its own positions lie in, the window's far end
+    p0 = start_ref[0] + i * tq
+    key0 = (first + j) * bs
+    partly = key0 + bs - 1 > p0
+    if window is not None:
+        partly |= key0 <= p0 + tq - 1 - window
+    pl.when((j < count) & partly)(lambda: attend(True))
+    pl.when((j < count) & ~partly)(lambda: attend(False))
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        o_ref[...] = (acc_sc[...] / l_sc[...]).astype(o_ref.dtype)
+
+
+def block_attention(q, k_cache, v_cache, layer, start, *, window=None,
+                    interpret: bool = False):
+    """q [1, T, KV, D] or [1, T, KV, G, D], the tokens at positions ``start
+    [1] + t``, attends layer ``layer`` of ``k_cache`` / ``v_cache`` [L, 1,
+    KV, D, S], which hold the tokens' own columns already -> what q's shape
+    is. A token sees the positions up to its own; with ``window`` the caches
+    are rings (position p at ``p mod S``, ``S >= window + T``) and it sees
+    the ``window - 1`` before it and itself."""
+    shape = q.shape
+    T, KV, D = shape[1], shape[2], shape[-1]
+    G = q.size // (T * KV * D)
+    S = k_cache.shape[-1]
+    cdt = k_cache.dtype
+    if shape[0] != 1 or S % TILE or T % TOKENS:
+        raise ValueError(
+            f"one slot's block of tokens (a multiple of {TOKENS}) against "
+            f"whole tiles of {TILE} positions: {shape} against {S} is the "
+            f"XLA path's (models/kv_cache.py:attend)")
+    if window is not None and not 0 < window <= S - T:
+        raise ValueError(
+            f"a ring of {S} positions holds no window of {window} beside a "
+            f"block of {T}")
+    tq, bs = tiles(T, G, D, S, cdt.itemsize)
+    nq, rows = T // tq, G * tq
+    # blocks a tile may visit: the whole of a full layer; of a ring the
+    # most that the window and the tile's tokens span, wherever they start
+    nk = S // bs if window is None else (window + tq - 2) // bs + 2
+    out_dtype = jnp.promote_types(q.dtype, cdt)
+
+    # [1, T, KV, G, D] -> [KV, tiles x (G, tq), D]: a tile's rows together
+    def folded(x):
+        x = x.reshape(nq, tq, KV, G, D).transpose(2, 0, 3, 1, 4)
+        return x.reshape(KV, nq * rows, D)
+
+    def unfolded(o):
+        o = o.reshape(KV, nq, G, tq, D).transpose(1, 3, 0, 2, 4)
+        return o.reshape(shape)
+
+    def block(h, i, j, layer_ref, start_ref):
+        first, count = _visits(
+            start_ref[0], i, tq=tq, bs=bs, S=S, window=window)
+        at = first + jnp.minimum(j, count - 1)   # past the last: the last
+        if window is not None:
+            at = jax.lax.rem(at, S // bs)
+        return layer_ref[0], 0, h, 0, at
+
+    tile = pl.BlockSpec((None, rows, D), lambda h, i, j, *_: (h, i, 0))
+    cache = pl.BlockSpec((None, None, None, D, bs), block)
+    block_bytes = D * bs * cdt.itemsize
+    o = pl.pallas_call(
+        functools.partial(_kernel, tq=tq, bs=bs, S=S, scale=D ** -0.5,
+                          window=window),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(KV, nq, nk),
+            in_specs=[tile, cache, cache],
+            out_specs=tile,
+            scratch_shapes=[
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, D), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((KV, nq * rows, D), out_dtype),
+        # two blocks of K and of V in flight, a tile's scores and their
+        # passes in float32, the tiles of q and of the result twice
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=4 * block_bytes + 8 * rows * bs * 4
+            + 8 * rows * max(D, TILE) * 4 + (16 << 20)),
+        name="block_attention",
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), start.astype(jnp.int32),
+      folded(q), k_cache, v_cache)
+    return unfolded(o)

@@ -19,6 +19,7 @@ import pytest
 from ray_tpu.llm import DecodeEngine, LLMConfig, SamplingParams
 from ray_tpu.models import afmoe, decoder, kv_cache
 from ray_tpu.ops import decode_attention as kernel
+from ray_tpu.ops.block_attention import block_attention
 from ray_tpu.parallel import moe
 from ray_tpu.parallel.moe import MoEConfig, init_moe_params
 
@@ -269,11 +270,18 @@ def test_chunked_prefill_and_cached_decode_across_the_rings_wrap(
     ``pallas_interpret`` the engine's caches are whole tiles long (window
     128, ring 256, 384 positions; a prompt of 200 and 70 decoded tokens, past
     the ring's end) and every decode step runs the kernel, at G = 4, in both
-    kinds of cache."""
+    kinds of cache, and every prefill chunk (128, 128 and 64 tokens) attends
+    through the ``block_attention`` kernel, the second one from an unaligned
+    ring position on."""
     sizes = {} if impl == "xla" else dict(
         sliding_window=128, max_seq_len=384, prefill_buckets=(64, 128))
     monkeypatch.setattr(kv_cache, "_decode_impl", lambda: impl)
     monkeypatch.setattr(kernel, "BLOCK_BYTES", 2 * 16 * 128 * 4)
+    blocks = []
+    monkeypatch.setattr(
+        kv_cache, "block_attention",
+        lambda q, *a, **kw: blocks.append((q.shape[1], kw["window"]))
+        or block_attention(q, *a, **kw))
     engine = _engine(**sizes)
     window = engine._window
     n_long, n_new = (int(2.5 * window), 30) if impl == "xla" else (200, 70)
@@ -294,6 +302,9 @@ def test_chunked_prefill_and_cached_decode_across_the_rings_wrap(
         assert np.abs(got - want[at, list(out)]).max() < 5e-5
         assert [int(np.argmax(want[i])) for i in at] == list(out)
     engine.shutdown()
+    # one trace a bucket: four window layers and a full one
+    assert sorted(blocks, key=str) == ([] if impl == "xla" else sorted(
+        [(T, w) for T in (64, 128) for w in (128,) * 4 + (None,)], key=str))
     admits = {a.args["prompt_tokens"]: a.args
               for a in engine._span.named("engine.admit")}
     assert admits[n_long]["chunks"] == (3 if impl == "xla" else 2)
@@ -313,6 +324,29 @@ def test_chunked_prefill_and_cached_decode_across_the_rings_wrap(
     assert both and all(
         t["cache_positions_window"] <= window + 5 + n_new for t in both)
     assert stats["moe_rows"] == (n_long + 5 + stats["slot_ticks"]) * 4 * 4
+
+
+def test_an_admission_counts_the_key_positions_its_chunks_see():
+    """A prompt of 19 tokens is three programs: 8 tokens at 0, 8 at 8, 3
+    padded to 4 at 16. In the full layer (64 positions) a chunk's tokens see
+    the positions up to its last one's: 8 + 16 + 20. In each of the four
+    rings (16 positions, window 8) the 7 before its first token, where there
+    are that many, and its own: 8 + 15 + 11. The caches hold 64 + 4 x 16, once
+    a chunk. A prompt of one chunk beside it; ``stats`` sums both."""
+    engine = _engine()
+    for n in (19, 5):
+        engine.generate([int(t) for t in _tokens((n,), seed=n)],
+                        SamplingParams(max_new_tokens=2))
+    engine.shutdown()
+    admits = {a.args["prompt_tokens"]: a.args
+              for a in engine._span.named("engine.admit")}
+    assert admits[19]["chunks"] == 3
+    assert admits[19]["prefill_key_positions"] == 44 + 4 * 34
+    assert admits[19]["prefill_cache_positions"] == 3 * (64 + 4 * 16)
+    assert admits[5]["prefill_key_positions"] == 8 + 4 * 8
+    assert admits[5]["prefill_cache_positions"] == 64 + 4 * 16
+    for name in ("prefill_key_positions", "prefill_cache_positions"):
+        assert engine.stats[name] == admits[19][name] + admits[5][name]
 
 
 def test_a_prefix_of_a_model_with_window_layers_is_a_whole_prompt():

@@ -6,8 +6,9 @@ float32 throughout, so "equals" is 1e-4 and a write that lost precision
 would show; the untouched rows are compared bit for bit.
 
 On the CPU a decode step takes the XLA path; the last tests hold the TPU's
-kernel (``ops/decode_attention.py``, ``interpret=True``) to it, alone and
-through ``forward_cached``.
+kernels (``ops/decode_attention.py`` for a decode step,
+``ops/block_attention.py`` for a block of tokens, ``interpret=True``) to it,
+alone and through ``forward_cached``.
 """
 import jax
 import jax.numpy as jnp
@@ -18,6 +19,7 @@ from ray_tpu.llm.engine import engine_programs
 from ray_tpu.models import kv_cache, module_for
 from ray_tpu.models.gpt2 import GPT2Config
 from ray_tpu.models.llama import LlamaConfig
+from ray_tpu.ops import block_attention as block_kernel
 from ray_tpu.ops import decode_attention as kernel
 
 S, SLOTS, BUCKET, VOCAB = 32, 3, 8, 128
@@ -354,6 +356,112 @@ def test_a_cache_the_lanes_do_not_divide_keeps_the_xla_path(small_blocks):
     with pytest.raises(ValueError, match="whole tiles"):
         kernel.decode_attention(new[:, 0, :, None], new[:, 0], new[:, 0],
                                 cache["k"], cache["v"], 0, jnp.zeros(B))
+
+
+# ------------------------------------------------ a block of tokens' kernel
+
+# where a block of 64 tokens starts in a cache (or ring) of 512 positions
+STARTS = {"empty": 0, "a_whole_chunk_in": 64, "an_unaligned_prefix": 37,
+          "across_the_end": 480, "past_the_end": 1000}
+
+
+@pytest.mark.parametrize("start", list(STARTS.values()), ids=list(STARTS))
+@pytest.mark.parametrize("D", [64, 128])
+@pytest.mark.parametrize("G", [1, 8])
+@pytest.mark.parametrize("window", [None, 300], ids=["full", "ring"])
+def test_block_kernel_equals_the_xla_path(monkeypatch, window, G, D, start):
+    """A prefill chunk of 64 tokens (its last 5 a bucket's padding, which
+    attention takes as tokens on either path) against a cache of 512
+    positions that holds other columns everywhere: the kernel's tiles are
+    16 or 32 tokens and its blocks 128 positions, so a tile visits some
+    blocks and skips the rest. The same attention within bfloat16's
+    rounding, the cache EQUAL bit for bit, and changed only on the block's
+    own positions of the layer asked: in a full layer the tokens past the
+    end are dropped (a block across the end keeps its first 32, one past
+    it none), in a ring they land round its end. No mask of [T, S] is built
+    for the kernel."""
+    L, KV, T, S, dtype = 2, 2, 64, 512, jnp.bfloat16
+    monkeypatch.setattr(block_kernel, "BLOCK_BYTES", D * 128 * 2)
+    monkeypatch.setattr(block_kernel, "ROWS", 32)
+    assert block_kernel.tiles(T, G, D, S, 2) == (32 if G == 1 else 16, 128)
+    traced = []
+    monkeypatch.setattr(
+        kv_cache, "block_attention",
+        lambda *a, **kw: traced.append(kw)
+        or block_kernel.block_attention(*a, **kw))
+    names = kv_cache.WINDOW if window else kv_cache.FULL
+    ks = jax.random.split(jax.random.PRNGKey(D + G + start), 5)
+    cache = {name: jax.random.normal(key, (L, 1, KV, D, S), dtype)
+             for name, key in zip(names, ks)}
+    q = jax.random.normal(
+        ks[2], (1, T, KV, G, D) if G > 1 else (1, T, KV, D), dtype)
+    k_new = jax.random.normal(ks[3], (1, T, KV, D), dtype)
+    v_new = jax.random.normal(ks[4], (1, T, KV, D), dtype)
+
+    def attend(impl):
+        monkeypatch.setattr(kv_cache, "_decode_impl", lambda: impl)
+        at = kv_cache.step(jnp.array([start], jnp.int32), T, cache, window,
+                           real=jnp.array([T - 5], jnp.int32))
+        masks = (at.mask, at.hit, at.ring_mask, at.ring_hit)
+        assert all(m is None for m in masks) == (impl != "xla")
+        return jax.jit(lambda cache: kv_cache.attend(
+            cache, jnp.int32(1), q, k_new, v_new, at,
+            windowed=window is not None))(cache)
+
+    got_cache, got = attend("pallas_interpret")
+    assert traced == [{"window": window, "interpret": True}]
+    want_cache, want = attend("xla")
+    assert len(traced) == 1
+    assert got.shape == want.shape == q.shape and got.dtype == want.dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=2e-2, atol=2e-2)
+    positions = np.arange(start, start + T)
+    landed = positions % S if window else positions[positions < S]
+    for name, new in zip(names, (k_new, v_new)):
+        assert (np.asarray(got_cache[name]) == np.asarray(want_cache[name])
+                ).all(), name
+        now = np.asarray(got_cache[name], np.float32)
+        old = np.asarray(cache[name], np.float32)
+        assert (now[0] == old[0]).all(), name
+        untouched = np.setdiff1d(np.arange(S), landed)
+        assert (now[1][..., untouched] == old[1][..., untouched]).all(), name
+        # [T, KV, D] -> [KV, D, the tokens that landed]
+        cols = np.asarray(new[0], np.float32).transpose(1, 2, 0)
+        assert (now[1, 0][..., landed] == cols[..., :len(landed)]).all(), name
+
+
+def test_a_block_the_kernel_does_not_take_keeps_the_xla_path(monkeypatch):
+    """By static shapes alone, before anything is lowered: a few tokens at
+    every slot (speculation's verify), a block that is no whole tile of
+    tokens, a ring too short to hold the block beside the window; and the
+    kernel itself refuses what ``attend`` would not hand it."""
+    monkeypatch.setattr(kv_cache, "_decode_impl", lambda: "pallas_interpret")
+    assert kv_cache._impl(1, 64, 512) == "pallas_interpret"
+    assert kv_cache._impl(1, 64, 512, 200) == "pallas_interpret"
+    assert kv_cache._impl(3, 64, 512) == "xla"      # several slots' blocks
+    assert kv_cache._impl(1, 4, 512) == "xla"       # 1 + k tokens
+    assert kv_cache._impl(1, 64, 96) == "xla"       # no whole lane tiles
+    assert kv_cache._impl(1, 64, 256, 200) == "xla"  # 200 + 64 > 256
+    assert kv_cache._impl(3, 1, 512) == "pallas_interpret"  # a decode step
+    cache = jnp.zeros((1, 1, 2, 64, 256), jnp.float32)
+    q = jnp.zeros((1, 64, 2, 64), jnp.float32)
+    with pytest.raises(ValueError, match="holds no window"):
+        block_kernel.block_attention(q, cache, cache, 0, jnp.zeros(1),
+                                     window=200)
+    with pytest.raises(ValueError, match="XLA path"):
+        block_kernel.block_attention(q[:, :4], cache, cache, 0, jnp.zeros(1))
+
+
+def test_positions_seen_by_a_block():
+    """What ``engine.admit``'s ``prefill_key_positions`` sums a layer."""
+    seen = kv_cache.positions_seen
+    assert seen(0, 1024, 8704) == 1024 and seen(2048, 1024, 8704) == 3072
+    assert seen(8192, 1024, 8704) == 8704            # no more than it holds
+    # a window of 2048: the 2047 before the first token and the block
+    assert seen(0, 2048, 4096, 2048) == 2048
+    assert seen(100, 128, 4096, 2048) == 228
+    assert seen(6144, 1024, 4096, 2048) == 2047 + 1024
 
 
 BIG = {

@@ -23,6 +23,7 @@ from ray_tpu.llm import DecodeEngine, LLMConfig, SamplingParams
 from ray_tpu.llm.engine import engine_programs
 from ray_tpu.models import decoder, kv_cache, olmo_hybrid
 from ray_tpu.ops import delta_rule
+from ray_tpu.ops.block_attention import block_attention
 from tests.test_granite_hybrid import _Spans, _prefill_then_decode
 
 CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -226,14 +227,22 @@ def test_three_padded_chunks_then_cached_steps_match_the_reference(
     to 8 (each starts from the state the one before left, the last one's
     three padded steps must leave it alone), then 16 decode steps, beside
     two idle slots; with ``pallas_interpret`` every decode step's state
-    update is the ``delta_update`` kernel."""
+    update is the ``delta_update`` kernel, and the two chunks of 16 attend
+    through the ``block_attention`` kernel (the third is no whole tile of
+    tokens: XLA's)."""
     monkeypatch.setattr(kv_cache, "_decode_impl", lambda: impl)
+    blocks = []
+    monkeypatch.setattr(
+        kv_cache, "block_attention",
+        lambda q, *a, **kw: blocks.append(q.shape[1])
+        or block_attention(q, *a, **kw))
     cfg = LLMConfig(**TINY).model_config()
     params = _tiny_params(cfg)
     sequence = _tokens((53,), seed=2)
     want = _reference_logits(reference, params, sequence[None])[0]
     rows, _ = _prefill_then_decode(
         cfg, params, sequence, [(16, 16), (16, 16), (5, 8)])
+    assert set(blocks) == (set() if impl == "xla" else {16})
     at = [15, 31, 36] + list(range(37, 53))
     assert len(rows) == len(at) == 19
     # float32 against float32, logits and not tokens: 2.0e-5 measured, the

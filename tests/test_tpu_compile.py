@@ -226,6 +226,26 @@ def _cache_sized(hlo_text, cache, ops="copy|transpose"):
     return found
 
 
+def _block_attention(text, cache):
+    """The ``block_attention`` custom calls of a compiled ``jit_prefill``,
+    after holding the program to what a chunk's attention may leave in it:
+    an array with a dimension of a cache's length and more elements than a
+    layer's view of that cache is the cache leaf: no ``[heads, T, S]``
+    scores, no one-hot placement."""
+    import math
+    import re
+
+    leaves = [leaf.shape for name, leaf in cache.items()
+              if name in ("k", "v", "k_window", "v_window")]
+    held = {tuple(int(d) for d in dims.split(","))
+            for dims in re.findall(r"= \w+\[([\d,]+)\]", text)}
+    for leaf in leaves:
+        assert {shape for shape in held if leaf[-1] in shape
+                and math.prod(shape) > math.prod(leaf[1:])} <= set(leaves)
+    return [line for line in text.splitlines() if "custom-call(" in line
+            and re.match(r"\s*%?block_attention", line)]
+
+
 @pytest.mark.parametrize("cell", ["gpt2-xl.serve-chat",
                                   "olmoe-1b-7b.serve-assist"])
 def test_decode_program_updates_the_cache_in_place(one_chip, cell,
@@ -286,13 +306,17 @@ def test_decode_program_updates_the_cache_in_place(one_chip, cell,
     assert _cache_sized(text, cache, "fusion") == []
 
 
-def test_prefill_program_copies_no_layer_of_the_cache(one_chip):
+def test_prefill_program_copies_no_layer_of_the_cache(one_chip, monkeypatch):
     """``jit_prefill`` at bucket 256, B = 1. Its cache is not donated (a
     prefix-cache entry is shared), so the entry computation copies it once;
-    inside the layer loop nothing cache-sized is copied or transposed. It
-    converts no weight either: an admission rounded the whole model too."""
+    inside the layer loop nothing cache-sized is copied or transposed: the
+    chunk attends through the ``block_attention`` kernel (D = 64, 25 heads,
+    one call in the layer scan) over the whole cache. It converts no weight
+    either: an admission rounded the whole model too."""
     from ray_tpu.llm.engine import engine_programs
+    from ray_tpu.models import kv_cache
 
+    monkeypatch.setattr(kv_cache, "_decode_impl", lambda: "pallas")
     cfg, args = _engine_program_args(one_chip, slots=1, width=256)
     prefill = engine_programs(cfg)[0]
     compiled = prefill.lower(*args).compile()
@@ -302,6 +326,7 @@ def test_prefill_program_copies_no_layer_of_the_cache(one_chip):
     copies = _cache_sized(text, args[2])
     assert [c for c in copies if c[0] != "ENTRY"] == []
     assert len(copies) <= 2
+    assert len(_block_attention(text, args[2])) == 1
 
 
 def _grouped_products(text):
@@ -416,8 +441,8 @@ def test_trinity_programs_fit_the_chip_and_keep_both_caches_in_place(
     layer, once a layer (nothing is scanned: five kinds, one of each); the
     grouped products (``grouped_matmul`` kernels under ``moe.experts``) run
     over the four routed layers' experts as one operand ([4 x 128, K, N]); a program's arguments and temporaries fit the chip;
-    a prefill chunk's float32 scores are taken in blocks of queries, so its
-    temporaries stay under 3 GB; the prefill's logits are the one row the
+    a prefill chunk attends through the ``block_attention`` kernel, so no
+    scores are among its temporaries; the prefill's logits are the one row the
     host reads, not 2048 rows of 200192."""
     import re
 
@@ -470,7 +495,14 @@ def test_trinity_programs_fit_the_chip_and_keep_both_caches_in_place(
         assert all(f"s32[{slots + 1}]" in c for c in calls)  # the live slots
         assert _weight_converts(text, args[0]) == []
     else:
-        assert mem.temp_size_in_bytes < 3.0e9
+        # a chunk attends through the block kernel, once a layer, over the
+        # slot's own cache in place: 0.37 GB of temporaries (the routed
+        # layers' rows), where the float32 scores, a query block at a time,
+        # made them 0.41 GB and the entry copied the full layer's cache
+        assert len(_block_attention(text, cache)) == 5
+        assert _cache_sized(text, cache) == []
+        assert mem.alias_size_in_bytes == cache_bytes
+        assert mem.temp_size_in_bytes < 0.39e9
         assert "f32[1,1,200192]" in text.split("\n", 1)[0]
 
 
@@ -615,7 +647,10 @@ def test_olmo_hybrid_programs_fit_the_chip_and_step_the_state_in_place(
         assert _weight_converts(text, args[0]) == []
     else:
         assert mem.alias_size_in_bytes >= cache_bytes
+        # the four attention layers are one kind, scanned: one call
+        assert len(_block_attention(text, cache)) == 1
         assert _cache_sized(text, cache) == []
+        assert mem.temp_size_in_bytes < 0.39e9
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 10.5e9
         assert "f32[1,1,100352]" in text.split("\n", 1)[0]
 
