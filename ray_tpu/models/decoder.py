@@ -171,6 +171,7 @@ def _remat_policy(config):
     named residuals (out + logsumexp, so the backward never re-runs the
     attention kernel) and the routed experts' two up-projections (grouped
     products, which are no ``dot_general``: ``parallel/moe.py:_experts``)
+    with their rows' gates [rows] (a gather of scalars)
     and recomputes elementwise ops; "dots_all"
     additionally keeps batched dots — least recompute short of remat=False,
     for chips with HBM headroom."""
@@ -184,7 +185,7 @@ def _remat_policy(config):
     return jax.checkpoint_policies.save_from_both_policies(
         base,
         jax.checkpoint_policies.save_only_these_names(
-            "flash_out", "flash_lse", "moe_fc", "moe_gate"
+            "flash_out", "flash_lse", "moe_fc", "moe_gate", "moe_row_gates"
         ),
     )
 

@@ -441,16 +441,21 @@ def _kernels_fwd(lhs, rhs, group_sizes, out_dtype, live_groups, scope,
 
 
 def _kernels_bwd(out_dtype, live_groups, scope, interpret, kept, ct):
+    lhs, rhs, group_sizes = kept
+    return *_transposes(lhs, rhs, group_sizes, ct, live_groups, scope,
+                        interpret), None
+
+
+def _transposes(lhs, rhs, group_sizes, ct, live_groups, scope, interpret):
     """The two gradients, each in its primal's dtype straight from the
     float32 sums (``ragged_dot``'s transposes make them in the product's
     result dtype and round to the primal's after: the same one rounding)."""
-    lhs, rhs, group_sizes = kept
     # a backward function does not inherit its call site's scope
     with jax.named_scope(scope) if scope else contextlib.nullcontext():
         d_lhs = _gmm(ct, rhs, group_sizes, lhs.dtype, transpose_rhs=True,
                      live_groups=live_groups, interpret=interpret)
         d_rhs = _tgmm(lhs, ct, group_sizes, rhs.dtype, interpret=interpret)
-    return d_lhs, d_rhs, None
+    return d_lhs, d_rhs
 
 
 _kernels.defvjp(_kernels_fwd, _kernels_bwd)
@@ -475,3 +480,30 @@ def grouped_dot(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
                           or jnp.result_type(lhs.dtype, rhs.dtype))
     return _kernels(lhs, rhs, group_sizes.astype(jnp.int32), out_dtype,
                     live_groups, scope, impl == "pallas_interpret")
+
+
+def grouped_dot_grads(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
+                      ct: jax.Array, *, live_groups: Optional[int] = None,
+                      scope: Optional[str] = None
+                      ) -> Tuple[jax.Array, jax.Array]:
+    """What ``grouped_dot(lhs, rhs, group_sizes)``'s backward pass makes of
+    the cotangent ``ct`` [M, N] of its result: (d_lhs [M, K], d_rhs [G, K,
+    N]), each in its primal's dtype, by the platform's implementation. For a
+    caller whose own ``custom_vjp`` holds the product and hands it a
+    cotangent in another dtype than autodiff would (``parallel/moe.py:
+    _down_add``: bfloat16 for a float32 result). The rows of ``ct`` past the
+    last group are read by neither; those of d_lhs are UNDEFINED."""
+    impl = _impl()
+    if impl != "xla":
+        return _transposes(lhs, rhs, group_sizes.astype(jnp.int32), ct,
+                           live_groups, scope, impl == "pallas_interpret")
+    with jax.named_scope(scope) if scope else contextlib.nullcontext():
+        d_lhs = jax.lax.ragged_dot(
+            ct, rhs.swapaxes(1, 2), group_sizes,
+            preferred_element_type=jnp.float32)
+        d_rhs = jax.lax.ragged_dot_general(
+            lhs, ct, group_sizes, jax.lax.RaggedDotDimensionNumbers(
+                dot_dimension_numbers=(((0,), (0,)), ((), ())),
+                lhs_ragged_dimensions=[0], rhs_group_dimensions=[]),
+            preferred_element_type=jnp.float32)
+    return d_lhs.astype(lhs.dtype), d_rhs.astype(rhs.dtype)

@@ -16,11 +16,14 @@ that Trinity's ``afmoe`` takes). What follows is one of two dispatches:
   are dropped.
 - dropless (inference; ``MoEConfig.dropless``): one sorted, grouped dispatch.
   The T x k (token, expert) pairs are sorted by expert, the rows gathered in
-  that order, and the expert products run as grouped matrix products over
-  the row groups (``ops/grouped_matmul.py:grouped_dot``: on a TPU a Pallas
-  kernel a product, its tiles chosen from the call's shape, which visits
-  only the experts that received a row; ``jax.lax.ragged_dot`` elsewhere,
-  the platform's choice alone). Work is
+  that order with their gates, and the expert products run as grouped matrix
+  products over the row groups (``ops/grouped_matmul.py:grouped_dot``: on a
+  TPU a Pallas kernel a product, its tiles chosen from the call's shape,
+  which visits only the experts that received a row;
+  ``jax.lax.ragged_dot`` elsewhere, the platform's choice alone), the gate
+  multiplied into the hidden row between the activation and the down
+  product (``_experts``), so that what follows the products only moves
+  rows. Work is
   proportional to the routed rows, the weights read are those of the experts
   touched, and no [tokens, experts, width] array exists. One function for a
   2048-token prefill and a 16-row decode tick. It takes the weights in one
@@ -49,6 +52,7 @@ under ``moe.shared`` (``shared_expert``).
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
@@ -56,7 +60,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.ops.grouped_matmul import grouped_dot
+from ray_tpu.ops.grouped_matmul import grouped_dot, grouped_dot_grads
 
 
 @dataclass(frozen=True)
@@ -265,11 +269,30 @@ def _count(expert: jax.Array, n: int) -> jax.Array:
         :, None], axis=1, dtype=jnp.int32)
 
 
-def _experts(params, rows, counts, config: MoEConfig, layer, named=True):
-    """rows [R, D] sorted by expert, ``counts`` [E] rows an expert -> [R, D]
-    float32. Rows past ``counts.sum()`` belong to no expert; what comes back
-    for them is undefined. ``named``: the two products into the experts carry
-    the names ``moe_fc`` and ``moe_gate``.
+def _experts(params, rows, gates, counts, config: MoEConfig, layer,
+             named=True, add_at=None):
+    """rows [R, D] sorted by expert, their gates [R] float32 and ``counts``
+    [E] rows an expert -> each row's gated result, gate x its expert's
+    output: [R, D] float32, or with ``add_at`` = (index [R], T) those rows
+    added up, row r into row index[r] of [T, D] float32 (an index of T: into
+    none; ``_down_add``). Rows past ``counts.sum()`` belong to no expert;
+    what comes back for them is undefined. ``named``: the two products into
+    the experts and the rows' gates carry the names ``moe_fc``, ``moe_gate``
+    and ``moe_row_gates``.
+
+    The gate meets the row BETWEEN the activation and the down product, the
+    hidden rows [R, M]: the down projection is linear, so gate x (h W) =
+    (gate x h) W, and the hidden row is a third as wide and half as deep as
+    the float32 output. ``act(g) * h * gate`` is one elementwise expression,
+    float32 inside and rounded to the rows' dtype once, as ``act(g) * h``
+    alone was. What follows the down product then moves rows and multiplies
+    nothing, and the gates' gradient is <hidden, d hidden>: nothing in the
+    backward pass needs the expert's output, so no checkpoint runs the down
+    product again for it (2.8 + 4.6 + 7.1 ms of a 387 ms step went for that
+    in the routed training cell; PERF.md section 6, PR 49). A row of no
+    expert takes the gate 0, by a select over [R]: the products leave
+    anything in such a row, d hidden too, and the select's transpose keeps
+    <hidden, d hidden> of it out of the gates' gradient.
 
     With ``layer`` the weights are every layer's, [L, E, ..]: the products
     then run over L x E groups of which only this layer's hold rows. A
@@ -297,29 +320,91 @@ def _experts(params, rows, counts, config: MoEConfig, layer, named=True):
                 f"``stacked_for`` casts the stack once, outside the loop")
         return w.reshape((-1,) + w.shape[2:])
 
+    filled = counts.sum()
     if layer is not None:
         L = params["expert_fc"].shape[0]
         counts = jax.lax.dynamic_update_slice(
             jnp.zeros((L * E,), counts.dtype), counts, (layer * E,))
     with jax.named_scope("moe.experts"):
         # a checkpoint whose policy keeps the names
-        # (``decoder._remat_policy``) hands the two products to the backward
-        # pass, where a grouped product is no ``dot_general`` and would run
-        # again; anywhere else a name is the identity
+        # (``decoder._remat_policy``) hands the two products into the
+        # experts to the backward pass, where a grouped product is no
+        # ``dot_general`` and would run again, and the rows' gates, whose
+        # gather is 8.5 ns a row on the chip; anywhere else a name is the
+        # identity
         name = checkpoint_name if named else lambda x, _: x
+        gates = name(jnp.where(jnp.arange(rows.shape[0]) < filled, gates,
+                               0.0), "moe_row_gates")
 
         def product(x, w, out=None):
             return grouped_dot(x, weights(w), counts, out, live_groups=E,
                                scope="moe.experts")
 
         h = name(product(rows, "expert_fc"), "moe_fc")
-        if _gated(config):
-            g = name(product(rows, "expert_gate"), "moe_gate")
-            act = jax.nn.silu if config.activation == "swiglu" else jax.nn.relu
-            h = act(g) * h
-        else:
-            h = jax.nn.gelu(h)
-        return product(h, "expert_out", jnp.float32)
+        g = name(product(rows, "expert_gate"), "moe_gate") if _gated(
+            config) else None
+        h = _gated_hidden(config.activation, h, g, gates)
+        if add_at is None:
+            return product(h, "expert_out", jnp.float32)
+        w_out = weights("expert_out")
+    return _down_add(h, w_out, counts, *add_at, E)
+
+
+@functools.partial(jax.checkpoint, static_argnums=0)
+def _gated_hidden(activation, h, g, gates):
+    """``act(g) * h * gate`` (``gelu(h) * gate`` of experts that are not
+    gated, g None): h, g [R, M] with the rows' gates [R] -> [R, M] in h's
+    dtype, float32 inside and rounded once. Under a checkpoint of its own:
+    what its backward pass needs beyond h, g and the gates it makes again
+    inside that pass's one fusion. Without it the float32 values between
+    the factors are residuals, and a checkpoint around the layer writes
+    them out, three [R, M] float32 arrays a layer (3.5 ms of the routed
+    training cell's step; PERF.md section 6, PR 49)."""
+    f32 = jnp.float32
+    if g is None:
+        x = jax.nn.gelu(h.astype(f32))
+    else:
+        act = jax.nn.silu if activation == "swiglu" else jax.nn.relu
+        x = act(g.astype(f32)) * h.astype(f32)
+    return (x * gates[:, None].astype(f32)).astype(h.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _down_add(h, w, sizes, index, T, live_groups):
+    """The down product and the combine of a share as one function: hidden
+    rows h [R, M] (gated) x w [G, M, D] by ``sizes``, row r of the float32
+    result added into row ``index[r]`` of [T, D] float32 (an index of T:
+    dropped, which every row past the last group needs). One function
+    because of its backward pass: the cotangent [T, D] holds what the
+    caller's cast to the rows' dtype made of it, its rows are gathered in
+    THAT dtype and reach the two backward kernels so. Autodiff gives the
+    float32 product a float32 cotangent: a [R, D] gather at twice the bytes
+    and two kernels that read it to round it again (7.2 ms of gathers where
+    the dispatch's bf16 gathers of as many rows take 2.3; PR 44's trace)."""
+    with jax.named_scope("moe.experts"):
+        y = grouped_dot(h, w, sizes, jnp.float32, live_groups=live_groups,
+                        scope="moe.experts")
+    with jax.named_scope("moe.combine"):
+        return jnp.zeros((T, y.shape[1]), jnp.float32).at[index].add(
+            y, mode="drop")
+
+
+def _down_add_fwd(h, w, sizes, index, T, live_groups):
+    return _down_add(h, w, sizes, index, T, live_groups), (
+        h, w, sizes, index)
+
+
+def _down_add_bwd(T, live_groups, kept, ct):
+    h, w, sizes, index = kept
+    with jax.named_scope("moe.combine"):
+        # a row past the last group reads the last token's: finite, and
+        # neither product reads it (``grouped_dot_grads``)
+        dy = ct.astype(h.dtype).at[index].get(mode="clip")
+    return *grouped_dot_grads(h, w, sizes, dy, live_groups=live_groups,
+                              scope="moe.experts"), None, None
+
+
+_down_add.defvjp(_down_add_fwd, _down_add_bwd)
 
 
 def _grouped(params, tokens, gates, chosen, row_mask, config: MoEConfig,
@@ -327,7 +412,14 @@ def _grouped(params, tokens, gates, chosen, row_mask, config: MoEConfig,
     """The sorted, grouped dispatch: tokens [T, D] with their k gates and
     experts -> (out [T, D], rows an expert [E]). A token that ``row_mask``
     [T] leaves out reaches no expert: its pairs sort behind the last group
-    and cost their place in the sort."""
+    and cost their place in the sort. A pair's gate rides its row through
+    ``_experts``; what comes back is put in the tokens' order and a token's
+    k rows added in float32. The rows past the last group are the masked
+    tokens' and come back undefined: such a token's SUM is made zeros, by
+    one select over [T, D] (over the [T * k, D] rows before the gather it
+    was a pass over k times as much, 0.41 ms a layer of a 2,048-token
+    prefill; PERF.md section 6, PR 49). A masked token's own gradient is
+    whatever its rows' was: nothing reads it."""
     T, D = tokens.shape
     E, k = config.num_experts, config.top_k
     with jax.named_scope("moe.dispatch"):
@@ -337,12 +429,13 @@ def _grouped(params, tokens, gates, chosen, row_mask, config: MoEConfig,
         order = jnp.argsort(expert, stable=True)       # pair ids by expert
         counts = _count(expert, E)
         rows = tokens[order // k]                      # [T*k, D]
-    y = _experts(params, rows, counts, config, layer)
+        row_gates = gates.reshape(T * k)[order]
+    y = _experts(params, rows, row_gates, counts, config, layer)
     with jax.named_scope("moe.combine"):
-        real = (jnp.arange(T * k) < counts.sum())[:, None]
-        y = jnp.where(real, y, 0.0) * gates.reshape(T * k)[order][:, None]
         back = jnp.zeros((T * k,), jnp.int32).at[order].set(jnp.arange(T * k))
         out = y[back].reshape(T, k, D).sum(axis=1)
+        if row_mask is not None:
+            out = jnp.where(row_mask[:, None], out, 0.0)
     return out.astype(tokens.dtype), counts
 
 
@@ -378,7 +471,17 @@ def _grouped_share(params, tokens, gates, chosen, row_mask, counts,
     row. Where the routing sends the share more rows than one pass holds,
     further passes take the next ``held_rows_bound`` pairs each until none
     is left (each recomputed in the backward pass, so their residuals do not
-    add up): whatever the routing, every pair of a held expert is computed."""
+    add up): whatever the routing, every pair of a held expert is computed.
+
+    A pair's gate is gathered beside its row and meets it inside
+    ``_experts``, on the hidden row; the combine is the scatter-add of the
+    experts' rows into their tokens', in float32, and no product or select
+    over [R, D] stands before it: a buffer row past the last pair is added
+    to no token (``mode="drop"``). What the grouped products leave in such a
+    row is undefined, and two selects keep it out of everything: the one on
+    the gathered ``rows`` here (zeros in; its transpose keeps an undefined
+    gradient out of the tokens'), and the one on the gates in ``_experts``
+    (its transpose keeps it out of the gates')."""
     T, D = tokens.shape
     k, n_held = config.top_k, config.num_held
     R = held_rows_bound(T, config)
@@ -413,12 +516,11 @@ def _grouped_share(params, tokens, gates, chosen, row_mask, counts,
             # backward pass too, and the select's transpose keeps that out
             # of the tokens' gradient
             rows = jnp.where(real[:, None], tokens[token], 0)   # [R, D]
-        y = _experts(params, rows, sizes, config, layer, named)
-        with jax.named_scope("moe.combine"):
             gate = flat_gates[jnp.where(real, ids, 0)]
-            y = jnp.where(real[:, None], y, 0.0) * gate[:, None]
-            return jnp.zeros((T, D), jnp.float32).at[
-                jnp.where(real, token, T)].add(y, mode="drop")
+        # the combine: a row added to its token's and nothing else; a row
+        # past the last pair to none (``mode="drop"`` of token T)
+        return _experts(params, rows, gate, sizes, config, layer, named,
+                        add_at=(jnp.where(real, token, T), T))
 
     if passes == 1:
         out = one(tokens, params, 0, named=True)

@@ -211,17 +211,21 @@ def test_a_share_drops_no_row_whatever_the_routing(reference, sent):
 
 @pytest.mark.parametrize("products_are", ["ragged_dot_general", "pallas_call"])
 @pytest.mark.parametrize("held, passes, kept, again", [
-    (8, 1, 10, 12), (2, 3, 25, 27)], ids=["one-pass", "under-the-cond"])
+    (8, 1, 10, 12), (2, 3, 24, 26)], ids=["one-pass", "under-the-cond"])
 def test_the_up_projections_are_kept_for_the_backward_pass(
         held, passes, kept, again, products_are, monkeypatch):
     """Under the block's checkpoint policy the backward pass of a routed
-    layer runs the down product again and not the two up-projections (named
-    ``moe_fc`` / ``moe_gate``; a grouped product is no ``dot_general``): two
-    grouped products fewer than under ``remat_policy="full"``, through the
-    ``lax.cond`` of a share that may need further passes too (whose own 15,
-    forward, remat twice over and transposes, carry no name: an outer policy
-    reaches through the checkpoint around a pass, and three passes' worth of
-    residuals would be kept). The gradients are the same to the bit.
+    layer does not run the two up-projections again (named ``moe_fc`` /
+    ``moe_gate``; a grouped product is no ``dot_general``): two grouped
+    products fewer than under ``remat_policy="full"``, through the
+    ``lax.cond`` of a share that may need further passes too (whose own 14,
+    forward, remat and transposes, carry no name: an outer policy reaches
+    through the checkpoint around a pass, and three passes' worth of
+    residuals would be kept; 15 before PR 49, when the gates' gradient
+    needed the experts' output). The down product does run again HERE, for
+    this test's own loss, whose square needs the layer's result; behind a
+    residual stream nothing does (``tests/test_moe_gated_rows.py``). The
+    gradients are the same to the bit.
     ``pallas_call``: the same counts with the TPU's kernels forced (in
     interpret mode), whose ``custom_vjp`` keeps the operands it was given
     and nothing the policy would have to run a product again for."""
@@ -301,8 +305,17 @@ def test_the_step_reports_cross_entropy_and_what_rides_beside_it():
 # PR 43: the loss makes its two gradients in its forward scan
 # (``ops/xent.py``): no remat'd body and no transposed scan in the train
 # step, 1,902 -> 1,843; the decode programs run no loss and stand.
-STANDING = {"gpt2-tiny step": 1843, "gpt2 decode": 347, "llama decode": 536,
-            "afmoe decode": 1086}
+# PR 49: a pair's gate meets its hidden row before the down product
+# (``moe._experts``): the select that gives a row of no expert the gate 0
+# (an iota, the counts' sum, a compare, a select over [rows]) and the
+# expression's converts to float32 and back where the product over [rows, D]
+# was, and ``_grouped`` zeroes a masked token's SUM where it zeroed its k
+# rows before their gather (its own iota, sum and compare gone): as lowered,
+# 7 operations more in llama's one scanned layer (536 -> 543) and 11 in
+# afmoe's two (1,086 -> 1,097); compiled, they are elementwise over [rows]
+# or inside the activation's fusion.
+STANDING = {"gpt2-tiny step": 1843, "gpt2 decode": 347, "llama decode": 543,
+            "afmoe decode": 1097}
 DECODE = {
     "gpt2": {},
     "llama": {"moe_num_experts": 4, "num_kv_heads": 2},

@@ -21,6 +21,7 @@ from jax.sharding import (
 
 from ray_tpu.ops import grouped_matmul
 from ray_tpu.ops.attention import attention, flash_attention
+from ray_tpu.parallel import moe
 
 
 @pytest.fixture(scope="module")
@@ -766,9 +767,11 @@ def test_smallthinker_step_fits_the_chip_with_its_window_in_the_kernels(
     directions (``grouped_matmul``, and ``grouped_matmul_dw`` for the
     weights' gradients: three of a layer's six transposes; every call under
     the ``moe.experts`` scope, the ``custom_vjp``'s backward too), the
-    two up-projections kept for the backward pass and not run again, no
-    count made by a scatter-add of ones, no [B, H, T, T] array anywhere,
-    and a loss head that projects a chunk's logits once (``_loss_head``)."""
+    two up-projections kept for the backward pass and not run again, the
+    down product not run again either and its cotangent's rows gathered in
+    bfloat16 (PR 49: the gate rides the hidden row), no count made by a
+    scatter-add of ones, no [B, H, T, T] array anywhere, and a loss head
+    that projects a chunk's logits once (``_loss_head``)."""
     import re
 
     cfg, compiled = _compiled_train_step(
@@ -778,8 +781,10 @@ def test_smallthinker_step_fits_the_chip_with_its_window_in_the_kernels(
     assert 7.8e9 < mem.argument_size_in_bytes < 7.95e9  # 656.6M x 12 bytes
     # 10,170,040,832 (sandbox compile, PR 41); 9,356,529,152 before the
     # up-projections were residuals: the compiler's sum moves by 0.81 GB,
-    # the chip's peak by 0.13 (14.880 -> 15.007 GB, ``memory_peak_bytes``)
-    assert mem.temp_size_in_bytes < 10.3e9
+    # the chip's peak by 0.13 (14.880 -> 15.007 GB, ``memory_peak_bytes``);
+    # 9,126,115,328 -> 8,358,041,600 when the backward pass stopped making
+    # float32 [36864, 2560] rows (sandbox compile, PR 49)
+    assert mem.temp_size_in_bytes < 8.5e9
     text = compiled.as_text()
     # 18,048,474,112 with the loss body under remat (sandbox compile, PR 42's
     # tree); 18,048,409,600 with the gradients made in the loss's forward
@@ -790,20 +795,35 @@ def test_smallthinker_step_fits_the_chip_with_its_window_in_the_kernels(
     assert sum("window_fwd" in n for n in flash) == 3
     assert sum("window_bwd" in n for n in flash) == 3
     assert len(flash) == 8
-    # a layer: 10 in the branch every balanced routing takes (3 forward, the
-    # down product again under remat, 6 transposes; 12 with the two
-    # up-projections run again, as before PR 41) and 12 in the branch of
-    # further passes, which keeps nothing
+    # a layer: 9 in the branch every balanced routing takes (3 forward, 6
+    # transposes; 10 with the down product again under remat, for the gates'
+    # gradient, as before PR 49; 12 with the two up-projections run again,
+    # as before PR 41) and 11 in the branch of further passes, which keeps
+    # nothing and runs the two up-projections again
     products = [line for line in text.splitlines()
                 if "tpu_custom_call" in line and " = " in line
                 and "grouped_matmul" in line.split(" = ")[0]]
-    assert len(products) == (10 + 12) * 4 and "ragged-dot" not in text
+    assert len(products) == (9 + 11) * 4 and "ragged-dot" not in text
     # of a branch's six transposes three are the weights' gradients
     assert sum("grouped_matmul_dw" in line.split(" = ")[0]
                for line in products) == (3 + 3) * 4
     for line in products:
         assert "moe.experts" in re.search(
             r'op_name="([^"]*)"', line).group(1), line[-300:]
+    # the down product -> f32[rows, D]: forward, once a branch, and in
+    # nothing a checkpoint runs again
+    R = moe.held_rows_bound(16384, cfg.moe)
+    down = [line for line in products if f" = f32[{R},2560]" in line]
+    assert R == 36864 and len(down) == 2 * 4
+    assert not [line for line in down if "rematted_computation" in line]
+    # the rows of the combine's cotangent are gathered as they arrive, in
+    # bfloat16: 4 a branch under ``moe.combine``, where autodiff's were
+    # float32 (the float32 gather that stays is the forward scatter-add's
+    # own permutation of its updates, ``op_name=".../scatter-add"``)
+    gathers = re.findall(
+        rf"= (\w+)\[{R},2560\]\S* gather\([^\n]*"
+        r'op_name="[^"]*transpose[^"]*moe\.combine/[^"]*"', text)
+    assert gathers == ["bf16"] * 8
     # the counts of rows an expert are compares and column sums
     assert not re.search(r"= s32\[(16|64)\]\S* scatter\(", text)
     assert not re.search(r"\[\d+,28,8192,8192\]", text)
