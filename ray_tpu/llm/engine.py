@@ -107,7 +107,17 @@ back, beside the logits and in the same read, how many distinct experts
 received a row in each layer: ``experts_touched`` and ``moe_rows`` of
 ``engine.tick`` (with ``moe_layers``, to divide by) and ``engine.admit``,
 ``moe_experts_touched`` and ``moe_rows`` of ``stats``. A dense model's
-programs are as they were.
+programs are as they were. Where the layers hold a SHARE of the experts
+(``MoEConfig.num_held``: one chip of an expert-parallel group) the programs
+count, beside the experts touched, the pairs the held experts computed:
+``moe_rows_held`` of ``engine.tick`` (of the programs read since the span
+before, as ``experts_touched``) and of ``stats`` (the admissions' too).
+
+A model with latent-attention layers (``models/kv_cache.py:attend_latent``)
+keeps one row a position and layer in the same pytree; ``latent_positions``
+of ``engine.tick`` is what the tick needed of it (``cache_positions`` a
+latent layer), ``layers_full`` counts no such layer, and a chunk's
+``prefill_key_positions`` count them as layers that attend everything.
 """
 from __future__ import annotations
 
@@ -143,6 +153,9 @@ class SamplingParams:
     seed: Optional[int] = None  # per-request determinism
     stop_token_ids: Sequence[int] = field(default_factory=tuple)
     stop: Sequence[str] = field(default_factory=tuple)  # string stops
+    # EOS is a token like any other and the answer runs to its
+    # ``max_new_tokens`` (vLLM's ``ignore_eos``): load of stated lengths
+    ignore_eos: bool = False
 
 
 class GenerationResult(list):
@@ -360,6 +373,12 @@ class DecodeEngine:
         # state layers: what a slot carries through them is a state, whatever
         # its length (``models/kv_cache.py``)
         self._layers_state = sum(k.state is not None for k in kinds)
+        # latent layers: one row a position that every head shares
+        self._layers_latent = sum(k.latent is not None for k in kinds)
+        # the layers hold a share of the experts: the programs count the
+        # rows the held experts computed beside the experts touched
+        self._moe_share = bool(self._moe_top_k) and (
+            cfg.moe.num_held is not None)
         # the model programs take ``real``: a router's rows, a state's steps
         self._takes_real = bool(self._moe_layers or self._layers_state)
         self.tokenizer = load_tokenizer(config)
@@ -405,13 +424,15 @@ class DecodeEngine:
         block = max(*config.prefill_buckets, 1 + self._spec_k)
         self._cache = decoder.init_kv_cache(cfg, B, S, block=block)
         self._layers_full = (
-            len(kinds) - self._layers_window - self._layers_state)
+            len(kinds) - self._layers_window - self._layers_state
+            - self._layers_latent)
         # (layers, positions held, window) of each kind of attention layer
         self._attended = [
-            (layers, self._cache[names[0]].shape[-1], window)
-            for layers, names, window in (
-                (self._layers_full, kv_cache.FULL, None),
-                (self._layers_window, kv_cache.WINDOW, self._window))
+            (layers, self._cache[name].shape[-1], window)
+            for layers, name, window in (
+                (self._layers_full, kv_cache.FULL[0], None),
+                (self._layers_window, kv_cache.WINDOW[0], self._window),
+                (self._layers_latent, kv_cache.LATENT, None))
             if layers]
         # the lengths short of a whole prompt that the prefix store keeps
         self._boundaries = tuple(config.prefill_buckets) if (
@@ -432,8 +453,9 @@ class DecodeEngine:
         self._ids = jnp.zeros((B,), jnp.int32)
         self._flying: Optional[_Tick] = None
         # experts touched that a read has counted and no ``engine.tick``
-        # span carries yet: the next one does
+        # span carries yet: the next one does; the held experts' rows too
         self._touched_unspanned = 0
+        self._held_unspanned = 0
 
         self._slots = [_Slot() for _ in range(B)]
         self._pending: "queue.Queue" = queue.Queue()
@@ -477,6 +499,12 @@ class DecodeEngine:
             # distinct experts that received one, summed over layers and
             # programs; both 0 for a dense model
             "moe_rows": 0, "moe_experts_touched": 0,
+            # of those rows, the ones the experts held here computed (a
+            # model whose layers hold a share of the experts; else 0)
+            "moe_rows_held": 0,
+            # positions of the latent layers' cache the ticks needed, summed
+            # over those layers (0 for a model with none)
+            "latent_positions": 0,
             # state layers: slots that decode x state layers over ticks (the
             # states a tick reads and writes), and prompt tokens that went
             # through a prefill's scan; both 0 for a model with none
@@ -664,7 +692,14 @@ class DecodeEngine:
         """A span's ``experts_touched`` of a program's count a layer, on the
         host (a list of one, or of none from a dense model), summed into
         ``stats``."""
-        experts = int(touched[0].sum()) if touched else 0
+        if not touched:
+            return 0
+        counts = np.asarray(touched[0])
+        if counts.ndim == 2:    # a share: [layers, (touched, rows held)]
+            held = int(counts[:, 1].sum())
+            self.stats["moe_rows_held"] += held
+            counts = counts[:, 0]
+        experts = int(counts.sum())
         self.stats["moe_experts_touched"] += experts
         return experts
 
@@ -879,14 +914,19 @@ class DecodeEngine:
         )
         return how
 
+    def _stop_tokens(self, params: SamplingParams) -> set:
+        """The tokens that end a request's answer and are no part of it."""
+        return set(params.stop_token_ids) | (
+            set() if params.ignore_eos else {self.tokenizer.eos_id})
+
     def _finish_if_done_locked(self, b: int):
         slot = self._slots[b]
-        eos = self.tokenizer.eos_id
-        stop = set(slot.params.stop_token_ids) | {eos}
+        stop = self._stop_tokens(slot.params)
         out = None
         # the first that holds names how the answer ended
         reason = (
-            "eos" if slot.last_token == eos
+            "eos" if slot.last_token == self.tokenizer.eos_id
+            and not slot.params.ignore_eos
             else "stop" if slot.last_token in stop
             else "length" if slot.produced >= slot.params.max_new_tokens
             else "context" if slot.length + 1 >= self.config.max_seq_len
@@ -1065,6 +1105,9 @@ class DecodeEngine:
                         cache_positions if self._layers_full else 0,
                     "cache_positions_window": int(np.minimum(
                         needed, self._window).sum())}
+                if self._layers_latent:
+                    positions["latent_positions"] = (
+                        cache_positions * self._layers_latent)
                 if drafts:
                     sent = (jnp.asarray(toks), jnp.asarray(lens),
                             *self._real(real))
@@ -1110,8 +1153,10 @@ class DecodeEngine:
                 cache_positions=cache_positions, overrun=overrun,
                 moe_rows=moe_rows, experts_touched=self._touched_unspanned,
                 layers_full=self._layers_full,
-                layers_window=self._layers_window, **positions, **state)
-            self._touched_unspanned = 0
+                layers_window=self._layers_window, **positions, **state,
+                **({"moe_rows_held": self._held_unspanned}
+                   if self._moe_share else {}))
+            self._touched_unspanned = self._held_unspanned = 0
 
     def _read_locked(self, tick: Optional[_Tick]) -> None:
         """Bring ``tick``'s results to the host and do its rows' bookkeeping
@@ -1130,8 +1175,10 @@ class DecodeEngine:
             ids, logits, touched = jax.device_get((
                 tick.ids, tick.logits if tick.host_rows else None,
                 tick.touched))
+            held = self.stats["moe_rows_held"]
             experts = self._experts_touched(touched)
             self._touched_unspanned += experts
+            self._held_unspanned += self.stats["moe_rows_held"] - held
             read.set_metadata(experts_touched=experts)
         with self._span("engine.tick.sample"):
             for i in tick.rows:
@@ -1263,9 +1310,7 @@ class DecodeEngine:
                 # a stop TOKEN ends the request without being part of the
                 # output; the done marker's kept-length already excludes
                 # it, so check before yielding
-                stop = set((params.stop_token_ids if params else ())
-                           ) | {self.tokenizer.eos_id}
-                if item in stop:
+                if item in self._stop_tokens(params or SamplingParams()):
                     continue  # await the done marker
                 yield item
 

@@ -38,6 +38,7 @@ def extract_sampling(payload: dict, config: LLMConfig) -> SamplingParams:
         seed=(int(payload["seed"]) if payload.get("seed") is not None
               else None),
         stop=tuple(stop),
+        ignore_eos=bool(payload.get("ignore_eos", False)),
     )
 
 

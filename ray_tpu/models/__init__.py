@@ -44,6 +44,7 @@ FAMILIES = {
     "smallthinker": "ray_tpu.models.smallthinker",
     "granite_hybrid": "ray_tpu.models.granite_hybrid",
     "olmo_hybrid": "ray_tpu.models.olmo_hybrid",
+    "bailing_hybrid": "ray_tpu.models.bailing_hybrid",
 }
 
 
@@ -78,6 +79,7 @@ MOE_KEYS = {
     "moe_expert_bias_init_std": "expert_bias_init_std",
     "moe_num_held": "num_held", "moe_first_held": "first_held",
     "moe_dropless": "dropless",
+    "moe_n_group": "n_group", "moe_topk_group": "topk_group",
 }
 
 

@@ -102,8 +102,14 @@ class Layer(NamedTuple):
     # of tokens in; None: attention
     state: Optional[int] = None
     # which recurrence (an ``ops/ssm.py:Recurrence``: ``ssm.MAMBA2``,
-    # ``delta_rule.GATED_DELTA``); None with ``state``
+    # ``delta_rule.GATED_DELTA``, ``kda.KDA``); None with ``state``
     recurrence: Optional[Any] = None
+    # its attention is over a LATENT row a position that every head shares
+    # (``kv_cache.attend_latent``): how many channels the row has (the
+    # latent's rank and the shared rotated key); None: keys and values a
+    # head. The family's ``qkv`` then gives (q [B, T, H, Dn + Dr], the new
+    # rows [B, T, latent], the up-projection [R, H, Dn + Dv])
+    latent: Optional[int] = None
 
 
 class Segment(NamedTuple):
@@ -255,6 +261,10 @@ def _body(config, mesh: Optional[Mesh], pos, kind: Layer = Layer(),
         from_input = family.at_input(config, kind.name, layer, x, stacked)
         if kind.state is not None:
             x = _recur(config, kind, layer, x, None)[0]
+        elif kind.latent is not None:
+            q, rows, up = family.qkv(config, kind.name, layer, x, pos)
+            x = family.attn_out(config, layer, x, kv_cache.latent_attention(
+                q, rows, up.astype(q.dtype), q.shape[-1] ** -0.5))
         else:
             q, k, v = family.qkv(config, kind.name, layer, x, pos)
             if q.ndim == 5:  # [B, T, KV, G, D]: G query heads a kv head
@@ -407,17 +417,22 @@ def init_kv_cache(config, batch: int, max_len: int, dtype=None,
     ``llm/_internal/serve/engines/vllm``; here the cache is a jax pytree so
     the whole decode step stays one XLA program.)"""
     kinds = layer_kinds(config)
-    windows = [k.window for k in kinds if k.state is None]
+    latent = [k.latent for k in kinds if k.latent is not None]
+    if len(set(latent)) > 1:
+        raise ValueError(f"latent layers of several widths: {set(latent)}")
+    windows = [k.window for k in kinds
+               if k.state is None and k.latent is None]
     lengths = {w for w in windows if w is not None}
     if len(lengths) > 1:
         raise ValueError(f"window layers of several lengths: {lengths}")
     ring = kv_cache.ring_length(lengths.pop(), block, max_len) if lengths else 0
-    states = len(kinds) - len(windows)
+    states = len(kinds) - len(windows) - len(latent)
     return kv_cache.init_kv_cache(
         windows.count(None), batch, config.num_kv_heads, config.head_dim,
         max_len, dtype or config.dtype, len(windows) - windows.count(None),
         ring, states,
         module_for(config).state_leaves(config) if states else None,
+        len(latent), latent[0] if latent else 0,
     )
 
 
@@ -454,7 +469,9 @@ def forward_cached(
     says how many of a row's T tokens are tokens: 0 for an idle decode slot,
     the prompt's length in a prefill bucket. The rest is routed to no
     expert. Given ``real``, a third result counts the distinct experts that
-    received a row in each layer, [L] int32."""
+    received a row in each layer, [L] int32; of a model whose layers hold a
+    SHARE of the experts (``MoEConfig.num_held``), beside it the rows the
+    held experts computed: [L, 2] int32."""
     family = module_for(config)
     B, T = tokens.shape
     pos = start[:, None] + jnp.arange(T)[None, :]          # [B, T] absolute
@@ -473,6 +490,11 @@ def forward_cached(
         from_input = family.at_input(config, kind.name, layer, x, stacked)
         if kind.state is not None:
             x, cache = _recur(config, kind, layer, x, (cache, index, at))
+        elif kind.latent is not None:
+            q, rows, up = family.qkv(config, kind.name, layer, x, pos)
+            cache, attn = kv_cache.attend_latent(
+                cache, index, q, rows, up, at, q.shape[-1] ** -0.5)
+            x = family.attn_out(config, layer, x, attn)
         else:
             q, k_new, v_new = family.qkv(config, kind.name, layer, x, pos)
             # the cache is attended as the family groups its heads (GQA:
@@ -482,13 +504,18 @@ def forward_cached(
                 cache, index, q, k_new, v_new, at, kind.window is not None)
             x = family.attn_out(
                 config, layer, x, attn.reshape(B, T, -1, attn.shape[-1]))
-        x, _, touched = family.ffn(
+        x, aux, touched = family.ffn(
             config, kind.name, layer, x, rng=None, row_mask=mask,
             stacked=stacked, from_input=from_input)
+        if share:   # beside it, the rows the held experts computed
+            touched = jnp.stack(
+                [touched, aux["moe_rows_held"]]).astype(jnp.int32)
         return x, cache, touched
 
+    share = config.moe is not None and config.moe.num_held is not None
     cache_at = _places(
         segments, lambda kind: "state" if kind.state is not None
+        else "latent" if kind.latent is not None
         else "ring" if kind.window is not None else "full")
     routed_at = _places(
         segments, lambda kind: "experts" if kind.routed and experts is not None
@@ -520,9 +547,10 @@ def forward_cached(
     logits = family.head(config, params, family.final_norm(config, params, x))
     if real is None:
         return logits, cache
+    width = (2,) if share else ()
     touched = (every_touched[0] if len(every_touched) == 1
-               and every_touched[0].ndim == 1 else jnp.concatenate(
-                   [t.reshape(-1) for t in every_touched]))
+               and every_touched[0].ndim == 1 + share else jnp.concatenate(
+                   [t.reshape(-1, *width) for t in every_touched]))
     return logits, cache, touched
 
 

@@ -54,6 +54,23 @@ recurrences share what they share: a block of tokens takes the layer's
 state out, scans from it and puts back the state after the block's last REAL
 token (``Step.real``); a decode step on a TPU is one kernel over the whole
 state that reads and writes the slots that decode and no other.
+
+A model with latent-attention layers (DeepSeek-V2's multi-head latent
+attention) holds ONE leaf more, ``"latent"`` ``[Ll, B, 1, R + Dr, S]``: a
+position's row is the normed latent ``c`` [R] and the rotated key ``kr``
+[Dr] that every head shares, and nothing a head: K and V are both
+up-projections of ``c``, so the rows are the keys' and the values' at once
+(576 values a position where 32 heads of 128 would hold 8,192). Laid out as
+one kv head of ``R + Dr`` channels, position minor, so that ``_place``,
+``_write`` and the decode kernel's tiles are the other leaves' own.
+``attend_latent`` is such a layer's whole access, by two routes that must
+agree: a decode step ABSORBS the up-projection (``q_nope Wuk^T`` [H, R]
+against the rows, the probabilities' sum over ``c`` [H, R] through ``Wuv``),
+on a TPU through ``ops/decode_attention.py:latent_decode_attention``, which
+reads each filled chunk once for both products; a block of tokens
+UP-PROJECTS the rows it sees, its own and the earlier chunks', a block of
+positions at a time, and attends as H heads of ``Dn + Dr`` / ``Dv``
+(``_latent_blocks``: XLA, a running softmax over the filled blocks alone).
 """
 from __future__ import annotations
 
@@ -64,11 +81,17 @@ import jax.numpy as jnp
 
 from ray_tpu.ops import ssm
 from ray_tpu.ops.block_attention import TOKENS, block_attention
-from ray_tpu.ops.decode_attention import TILE, decode_attention, live_slots
+from ray_tpu.ops.decode_attention import (
+    TILE,
+    decode_attention,
+    latent_decode_attention,
+    live_slots,
+)
 
 
 FULL, WINDOW = ("k", "v"), ("k_window", "v_window")
 STATE = ("ssm", "conv")
+LATENT = "latent"
 
 
 def ring_length(window: int, block: int, max_len: int) -> int:
@@ -93,16 +116,22 @@ def positions_seen(start: int, T: int, length: int,
 def init_kv_cache(num_layers: int, batch: int, kv_heads: int, head_dim: int,
                   max_len: int, dtype, window_layers: int = 0,
                   ring: int = 0, state_layers: int = 0,
-                  state=None) -> Dict[str, jax.Array]:
+                  state=None, latent_layers: int = 0,
+                  latent_dim: int = 0) -> Dict[str, jax.Array]:
     """``num_layers`` full layers of ``max_len`` positions,
-    ``window_layers`` rings of ``ring`` and ``state_layers`` states:
-    ``state`` names each of their leaves' (shape a slot, dtype)."""
+    ``window_layers`` rings of ``ring``, ``state_layers`` states (``state``
+    names each of their leaves' (shape a slot, dtype)) and ``latent_layers``
+    layers of one row of ``latent_dim`` channels a position."""
     cache = {name: jnp.zeros((state_layers, batch, *shape), kind)
              for name, (shape, kind) in (state or {}).items()
              if state_layers}
+    if latent_layers:
+        cache[LATENT] = jnp.zeros(
+            (latent_layers, batch, 1, latent_dim, max_len), dtype)
     for names, layers, length in ((FULL, num_layers, max_len),
                                   (WINDOW, window_layers, ring)):
-        if layers or (names is FULL and not window_layers):
+        if layers or (names is FULL and not window_layers
+                      and not latent_layers):
             shape = (layers, batch, kv_heads, head_dim, length)
             cache.update({n: jnp.zeros(shape, dtype) for n in names})
     return cache
@@ -146,8 +175,10 @@ def step(start: jax.Array, T: int, cache: Dict[str, jax.Array],
     B = start.shape[0]
     pos = (start[:, None] + jnp.arange(T)[None, :])[:, :, None]
     mask = hit = ring_mask = ring_hit = None
-    if FULL[0] in cache and _impl(B, T, cache[FULL[0]].shape[-1]) == "xla":
-        key_pos = jnp.arange(cache[FULL[0]].shape[-1])[None, None, :]
+    # a latent layer's rows lie as a full layer's columns do
+    whole = next((cache[n] for n in (FULL[0], LATENT) if n in cache), None)
+    if whole is not None and _impl(B, T, whole.shape[-1]) == "xla":
+        key_pos = jnp.arange(whole.shape[-1])[None, None, :]
         mask, hit = key_pos <= pos, pos == key_pos
     if WINDOW[0] in cache and _impl(
             B, T, cache[WINDOW[0]].shape[-1], window) == "xla":
@@ -305,13 +336,135 @@ def _write(rows: jax.Array, new: jax.Array, hit: jax.Array) -> jax.Array:
     return jnp.where(hit.any(1)[:, None, None, :], placed, rows)
 
 
+# positions of a latent cache up-projected and scored at once by a block of
+# tokens (``_latent_blocks``): the scores are [H, T, this] float32
+LATENT_POSITIONS = 1024
+
+
+def latent_attention(q, rows, up, scale: float):
+    """The full forward's latent attention, from nothing before it: q
+    [B, T, H, Dn + Dr], ``rows`` [B, T, R + Dr] (the normed latent and the
+    rotated shared key), ``up`` [R, H, Dn + Dv] -> [B, T, H, Dv]; causal,
+    from the up-projected keys and values, plain XLA."""
+    R = up.shape[0]
+    Dn = q.shape[-1] - (rows.shape[-1] - R)
+    with jax.named_scope("mla.up"):
+        kv = jnp.einsum("bsr,rhd->bshd", rows[..., :R],
+                        up.astype(rows.dtype))
+    scores = (jnp.einsum("bthd,bshd->bhts", q[..., :Dn], kv[..., :Dn])
+              + jnp.einsum("bthd,bsd->bhts", q[..., Dn:], rows[..., R:])
+              ).astype(jnp.float32) * scale
+    T = q.shape[1]
+    seen = jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]
+    probs = jax.nn.softmax(jnp.where(seen, scores, -1e30), axis=-1)
+    return jnp.einsum("bhts,bshd->bthd", probs.astype(q.dtype), kv[..., Dn:])
+
+
+def attend_latent(cache: Dict[str, jax.Array], layer: jax.Array,
+                  q: jax.Array, rows_new: jax.Array, up: jax.Array,
+                  at: Step, scale: float):
+    """Latent layer ``layer`` of ``cache`` with ``rows_new`` [B, T, R + Dr]
+    in place, and q [B, T, H, Dn + Dr] attended over it through ``up``
+    [R, H, Dn + Dv] (a head's ``[Wuk | Wuv]``) -> (cache, [B, T, H, Dv]).
+    A decode step absorbs ``up`` into q and into the result and never
+    up-projects a row; a block of one slot's tokens (where the decode
+    kernel's platform is) up-projects the filled blocks of positions, one
+    after another; anything else (the tests' oracle) absorbs over the whole
+    cache in XLA."""
+    B, T, H, Dq = q.shape
+    R = up.shape[0]
+    Dn = Dq - (rows_new.shape[-1] - R)
+    leaf = cache[LATENT]
+    impl = _impl(B, T, leaf.shape[-1])
+    up = up.astype(q.dtype)
+    if impl != "xla" and T > 1:
+        leaf = _place(leaf, layer, rows_new[:, :, None, :], at.start[0],
+                      False)
+        out = _latent_blocks(leaf, layer, q[0], up, at.start[0], Dn, scale)
+        return {**cache, LATENT: leaf}, out[None]
+    with jax.named_scope("mla.up"):
+        # q_nope Wuk^T beside the rotated part: what a row is scored against
+        qa = jnp.concatenate([
+            jnp.einsum("bthd,rhd->bthr", q[..., :Dn], up[..., :Dn]),
+            q[..., Dn:]], axis=-1)
+    with jax.named_scope("mla.attend"):
+        if impl != "xla":
+            summed, leaf = latent_decode_attention(
+                qa[:, 0], rows_new[:, 0], leaf, layer, at.start, values=R,
+                scale=scale, live=at.live,
+                interpret=impl == "pallas_interpret")
+            summed = summed[:, None]
+        else:
+            held = _write(
+                jax.lax.dynamic_index_in_dim(leaf, layer, 0, False),
+                rows_new[:, :, None, :], at.hit)[:, 0]         # [B, D, S]
+            scores = jnp.einsum("bthd,bds->bhts", qa, held).astype(
+                jnp.float32) * scale
+            probs = jax.nn.softmax(
+                jnp.where(at.mask[:, None], scores, -1e30), axis=-1)
+            summed = jnp.einsum("bhts,brs->bthr", probs.astype(q.dtype),
+                                held[:, :R])
+            # as ``_attend_xla``: the rows go back once attention has read
+            summed, held, leaf = jax.lax.optimization_barrier(
+                (summed, held, leaf))
+            leaf = jax.lax.dynamic_update_index_in_dim(
+                leaf, held[:, None], layer, 0)
+    with jax.named_scope("mla.up"):
+        out = jnp.einsum("bthr,rhd->bthd", summed.astype(q.dtype),
+                         up[..., Dn:])
+    return {**cache, LATENT: leaf}, out
+
+
+def _latent_blocks(leaf, layer, q, up, start, Dn: int, scale: float):
+    """q [T, H, Dn + Dr] at positions ``start ..`` over layer ``layer`` of
+    ``leaf`` [L, 1, 1, R + Dr, S], the block's own rows in place already ->
+    [T, H, Dv]. The positions come ``LATENT_POSITIONS`` at a time, up to
+    the block that holds the last token's and no further: each block's rows
+    are up-projected to H heads' keys and values, scored under the causal
+    mask and folded into a running softmax (float32). No [T, S] array."""
+    T, H, _ = q.shape
+    R, S = up.shape[0], leaf.shape[-1]
+    D = leaf.shape[-2]
+    n = next(n for n in (LATENT_POSITIONS, 512, 256, TILE, S) if S % n == 0)
+    f32 = jnp.float32
+    pos = start + jnp.arange(T)
+    q_nope, q_rope = q[..., :Dn], q[..., Dn:]
+
+    def block(i, carry):
+        m, l, acc = carry
+        rows = jax.lax.dynamic_slice(
+            leaf, (layer, 0, 0, 0, i * n), (1, 1, 1, D, n))[0, 0, 0]
+        with jax.named_scope("mla.up"):
+            kv = jnp.einsum("rs,rhd->hds", rows[:R], up)      # [H, .., n]
+        sc = (jnp.einsum("thd,hds->hts", q_nope, kv[:, :Dn])
+              + jnp.einsum("thd,ds->hts", q_rope, rows[R:])
+              ).astype(f32) * scale
+        seen = (i * n + jnp.arange(n))[None, :] <= pos[:, None]
+        sc = jnp.where(seen, sc, -1e30)
+        m_next = jnp.maximum(m, sc.max(-1, keepdims=True))
+        alpha = jnp.exp(m - m_next)
+        p = jnp.where(seen, jnp.exp(sc - m_next), 0.0)
+        acc = alpha * acc + jnp.einsum(
+            "hts,hds->htd", p.astype(q.dtype), kv[:, Dn:],
+            preferred_element_type=f32)
+        return m_next, alpha * l + p.sum(-1, keepdims=True), acc
+
+    with jax.named_scope("mla.attend"):
+        blocks = jnp.minimum((start + T - 1) // n + 1, S // n)
+        _, l, acc = jax.lax.fori_loop(0, blocks, block, (
+            jnp.full((H, T, 1), -1e30, f32), jnp.zeros((H, T, 1), f32),
+            jnp.zeros((H, T, up.shape[-1] - Dn), f32)))
+    return jnp.swapaxes(acc / l, 0, 1).astype(q.dtype)
+
+
 def recur(carried, entering, gates, layer, recurrence, shape, chunk: int):
     """A state layer's mixer between its two projections, whatever its
     recurrence: ``entering`` [B, T, C] (what enters the convolution) through
     the layer's ``conv_w`` [C, K] (and ``conv_b`` [C], where it has one),
     then ``recurrence`` (an ``ops/ssm.py:Recurrence``: Mamba-2's, the gated
     delta rule's) over what left it and ``gates``, the family's per-step
-    numbers (a pytree of [B, T, H] float32) -> (cache, y [B, T, H, P]
+    numbers (a pytree of [B, T, H] or [B, T, H, ..] float32: a decay a head
+    or a channel) -> (cache, y [B, T, H, P]
     float32). ``shape`` is a slot's state (H, ..), ``chunk`` the scan's.
     Shared, and so written here once: the convolution's tail, the steps
     that are no token (their gates are zeroed, which every recurrence takes
@@ -338,8 +491,9 @@ def recur(carried, entering, gates, layer, recurrence, shape, chunk: int):
         mixed, tail = ssm.conv(entering, tail, layer["conv_w"],
                                layer.get("conv_b"), real)
     if real is not None:    # a step that is no token leaves the state
-        token = jnp.arange(T)[None, :, None] < real[:, None, None]
-        gates = jax.tree.map(lambda g: jnp.where(token, g, 0.0), gates)
+        token = jnp.arange(T)[None, :] < real[:, None]
+        gates = jax.tree.map(lambda g: jnp.where(
+            token.reshape(B, T, *(1,) * (g.ndim - 2)), g, 0.0), gates)
     kernel = cache is not None and T == 1 and _decode_impl() != "xla"
     if T == 1:
         mixed, gates = mixed[:, 0], jax.tree.map(lambda g: g[:, 0], gates)

@@ -35,6 +35,13 @@ position ``lens[b] - window + 1`` round the ring to the one that holds
 ``lens[b] mod S``, the tile it writes. Where those are one and the same
 chunk of the ring (the window's two ends in it), it is read twice, each
 time masked to its own end.
+
+``latent_decode_attention`` is the same visit for a LATENT cache
+``[L, B, 1, D, S]`` (multi-head latent attention with the up-projection
+absorbed; ``models/kv_cache.py:attend_latent``): one row a position that
+every query head scores, whose first ``values`` channels are the values too,
+so a chunk is read once and enters both products. No ring and no group of
+heads; its own chunk length (``LATENT_BLOCK_BYTES``).
 """
 from __future__ import annotations
 
@@ -298,3 +305,179 @@ def decode_attention(q, k_new, v_new, k_cache, v_cache, layer, lens, *,
       live.astype(jnp.int32), q, k_new[:, :, None, :], v_new[:, :, None, :],
       columns(k_new), columns(v_new), k_cache, v_cache)
     return o, k_cache, v_cache
+
+
+# of one chunk of a latent cache in VMEM (two are held): 576 rows of 512
+# positions in bfloat16
+LATENT_BLOCK_BYTES = 5 << 17
+
+
+def _latent_kernel(layer_ref, lens_ref, live_ref, q_ref, new_row_ref,
+                   new_col_ref, c_hbm, o_ref, co_hbm, cbuf, ctile, read_sem,
+                   write_sem, m_sc, l_sc, acc_sc, *, bs: int, scale: float,
+                   values: int):
+    """``_kernel`` for a cache of ONE row a position and no head axis,
+    whose first ``values`` channels are also the values: a chunk is read
+    once and enters both products. No ring, no group of heads; the visits,
+    the two buffers and the tile written are ``_kernel``'s."""
+    B = q_ref.shape[0]
+    S = c_hbm.shape[-1]
+    visits = live_ref[B]
+    layer = layer_ref[0]
+
+    def span(i, size):
+        return pl.ds(pl.multiple_of(i * size, size), size)
+
+    def place_of(b):    # a column past the end lands nowhere
+        return jnp.minimum(lens_ref[b], S - 1)
+
+    def read(v, c, buf):
+        return pltpu.make_async_copy(
+            c_hbm.at[layer, live_ref[v], 0, :, span(c, bs)], cbuf.at[buf],
+            read_sem.at[buf])
+
+    def write(v, buf):
+        return pltpu.make_async_copy(
+            ctile.at[buf],
+            co_hbm.at[layer, live_ref[v], 0, :,
+                      span(place_of(live_ref[v]) // TILE, TILE)],
+            write_sem.at[buf])
+
+    def visit(v, buf):
+        b = live_ref[v]
+        n = lens_ref[b]                   # the new column's position
+        place = place_of(b)
+        kept = n < S
+        count = place // bs + 1
+        q = q_ref[b]                      # [H, D]
+        new = new_row_ref[b]              # [1, D]
+        s_new = jnp.sum(q.astype(jnp.float32) * new.astype(jnp.float32),
+                        axis=-1, keepdims=True) * scale          # [H, 1]
+        m_sc[...] = jnp.where(kept, s_new, NEG_INF)
+        l_sc[...] = jnp.where(kept, jnp.ones_like(s_new), 0.0)
+        acc_sc[...] = jnp.where(kept, jnp.broadcast_to(
+            new[:, :values].astype(jnp.float32), acc_sc.shape), 0.0)
+
+        def chunk(c, buf):
+            @pl.when(c + 1 < count)
+            def _():
+                read(v, c + 1, 1 - buf).start()
+
+            @pl.when((c + 1 == count) & (v + 1 < visits))
+            def _():
+                read(v + 1, 0, 1 - buf).start()
+
+            read(v, c, buf).wait()
+
+            @pl.when(c * bs < n)          # a filled position among them
+            def _():
+                rows = cbuf[buf]                                 # [D, bs]
+                kt = jnp.promote_types(q.dtype, rows.dtype)
+                sc = jnp.einsum("hd,ds->hs", q.astype(kt), rows.astype(kt),
+                                preferred_element_type=jnp.float32) * scale
+                pos = c * bs + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+                old = pos < n
+                sc = jnp.where(old, sc, NEG_INF)
+                m_prev = m_sc[...]
+                m_next = jnp.maximum(
+                    m_prev, jnp.max(sc, axis=-1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_next)
+                p = jnp.where(old, jnp.exp(sc - m_next), 0.0)
+                l_sc[...] = alpha * l_sc[...] + jnp.sum(
+                    p, axis=-1, keepdims=True)
+                # the values are the chunk's first rows: nothing else is read
+                acc_sc[...] = alpha * acc_sc[...] + jnp.einsum(
+                    "hs,rs->hr", p.astype(q.dtype).astype(kt),
+                    rows[:values].astype(kt),
+                    preferred_element_type=jnp.float32)
+                m_sc[...] = m_next
+            return 1 - buf
+
+        after = jax.lax.fori_loop(0, count, chunk, buf)
+        o_ref[b] = (acc_sc[...] / l_sc[...]).astype(o_ref.dtype)
+
+        held, out = 1 - after, v % 2
+
+        @pl.when(v >= 2)
+        def _():                          # the tile sent two visits ago
+            write(v - 2, out).wait()
+
+        within = span((place % bs) // TILE, TILE)
+        lane = (place // TILE) * TILE + jax.lax.broadcasted_iota(
+            jnp.int32, (1, TILE), 1)
+        # n == S matches no lane: the tile as it was
+        ctile[out] = jnp.where(lane == n, new_col_ref[b],
+                               cbuf[held, :, within])
+        write(v, out).start()
+        return after
+
+    o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    @pl.when(visits > 0)
+    def _():
+        read(0, 0, 0).start()
+
+    jax.lax.fori_loop(0, visits, visit, 0)
+    for back in (2, 1):
+
+        @pl.when(visits >= back)
+        def _():
+            write(visits - back, (visits - back) % 2).wait()
+
+
+def latent_decode_attention(q, new, cache, layer, lens, *, values: int,
+                            scale: float, live=None,
+                            interpret: bool = False):
+    """A decode step over a LATENT cache ``[L, B, 1, D, S]``: one row of D
+    channels a position, which every query head shares, and whose first
+    ``values`` channels are the values too (multi-head latent attention with
+    the up-projection absorbed into the queries). q [B, H, D] attends layer
+    ``layer``'s filled positions (``< lens[b]``) and the new row ``new``
+    [B, D], which is written to position ``lens[b]`` (dropped where that is
+    S) -> (out [B, H, values], cache: the operand's own buffer). Scores are
+    ``q . row x scale``. ``live`` as ``decode_attention``'s. Each chunk of
+    the cache is read once, for both products."""
+    B, H, D = q.shape
+    S = cache.shape[-1]
+    cdt = cache.dtype
+    if S % TILE:
+        raise ValueError(
+            f"the kernel moves whole tiles of {TILE} positions: a cache of "
+            f"{S} is the XLA path's (models/kv_cache.py:attend_latent)")
+    bs = TILE
+    while S % (2 * bs) == 0 and D * 2 * bs * cdt.itemsize <= LATENT_BLOCK_BYTES:
+        bs *= 2
+    if live is None:
+        live = jnp.arange(B + 1, dtype=jnp.int32)
+    new = new.astype(cdt)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    o, cache = pl.pallas_call(
+        functools.partial(_latent_kernel, bs=bs, scale=scale, values=values),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(1,),
+            in_specs=[vmem, vmem, vmem, hbm],
+            out_specs=[vmem, hbm],
+            scratch_shapes=[
+                pltpu.VMEM((2, D, bs), cdt),
+                pltpu.VMEM((2, D, TILE), cdt),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, values), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, H, values),
+                                 jnp.promote_types(q.dtype, cdt)),
+            jax.ShapeDtypeStruct(cache.shape, cdt)],
+        input_output_aliases={6: 1},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=8 * D * bs * cdt.itemsize + (48 << 20)),
+        name="latent_decode_attention",
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), lens.astype(jnp.int32),
+      live.astype(jnp.int32), q, new[:, None, :], new[:, :, None], cache)
+    return o, cache

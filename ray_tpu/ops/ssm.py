@@ -34,7 +34,8 @@ oracle:
   the call as it entered and its row of the result is zeros.
   ``ssm_update_xla`` is the same step over one layer's slice. The kernel's
   way through the slots (``visit_live``) is any one-token step's; Mamba-2's
-  own is ``_step``, and ``ops/delta_rule.py`` gives it another.
+  own is ``_step``, and ``ops/delta_rule.py`` gives it another (which
+  ``ops/kda.py`` runs with a decay a channel).
 
 ``MAMBA2`` is the three as ``models/kv_cache.py:recur`` takes a kind of state
 layer's recurrence (``Recurrence``): from what left the convolution, the
@@ -312,9 +313,11 @@ def ssm_update(states, layer, x, dt, A, Bm, Cm, *, live=None,
 class Recurrence(NamedTuple):
     """A kind of state layer's recurrence, as ``kv_cache.recur`` runs it
     between the convolution and the cache: ``mixed`` is what left the
-    convolution, ``gates`` the family's per-step numbers [.., H] float32
-    (zeros at a step that is no token, which must then leave the state as it
-    is), ``layer`` the layer's weights; y comes back float32 [.., H, P]."""
+    convolution, ``gates`` the family's per-step numbers, a pytree of
+    float32 arrays [.., H] or [.., H, ..] (a decay a head, or a key channel:
+    ``ops/kda.py``; zeros at a step that is no token, which must then leave
+    the state as it is), ``layer`` the layer's weights; y comes back float32
+    [.., H, P]."""
     scope: str          # its operations run under ``<scope>.conv|scan|update``
     # (layer, mixed [B, T, C], gates, state [B, H, ..], chunk) -> (y, state)
     scan: Callable

@@ -6,7 +6,9 @@ softmax (Switch/GShard, Mixtral, OLMoE: ``norm_topk_prob`` says whether the
 k gates are renormalised to sum to 1), or a sigmoid an expert, the k chosen
 under a learned bias that the gates do not carry, renormalised and scaled
 (``score_func``, ``expert_bias``, ``route_scale``: the DeepSeek-V3 router
-that Trinity's ``afmoe`` takes). What follows is one of two dispatches:
+that Trinity's ``afmoe`` takes), the k chosen among the experts of the best
+groups alone where the configuration groups them (``n_group``,
+``topk_group``: ``_in_best_groups``). What follows is one of two dispatches:
 
 - capacity (training default): the sharded-einsum formulation of GShard/Switch.
   Routing builds a dispatch one-hot [tokens, experts, capacity]; einsums
@@ -103,6 +105,12 @@ class MoEConfig:
     # ``first_held`` on (None: all). The router still scores ``num_experts``.
     num_held: Optional[int] = None
     first_held: int = 0
+    # Group-limited choice (DeepSeek-V3's ``noaux_tc``): the experts lie in
+    # ``n_group`` groups side by side, a group's score is the sum of its two
+    # largest scores (with the bias), the ``topk_group`` best groups stay and
+    # the k are chosen among their experts alone. None: among all experts.
+    n_group: Optional[int] = None
+    topk_group: Optional[int] = None
 
     def __post_init__(self):
         if self.activation not in ("gelu", "swiglu", "reglu"):
@@ -122,6 +130,15 @@ class MoEConfig:
                     f"MoEConfig: experts {self.first_held} to "
                     f"{self.first_held + self.num_held - 1} of "
                     f"{self.num_experts}")
+        if self.n_group is not None and not (
+                self.topk_group and 0 < self.topk_group <= self.n_group
+                and self.num_experts % self.n_group == 0
+                and self.num_experts // self.n_group >= 2
+                and self.top_k <= self.topk_group
+                * (self.num_experts // self.n_group)):
+            raise ValueError(
+                f"MoEConfig: {self.topk_group} of {self.n_group} groups of "
+                f"{self.num_experts} experts for a choice of {self.top_k}")
         if self.score_func not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"MoEConfig.score_func must be 'softmax' or 'sigmoid', got "
@@ -159,8 +176,9 @@ def init_moe_params(
         params["expert_gate"] = normal(k4, lead + (E, embed_dim, mlp_dim))
     if config.expert_bias:
         # float32 whatever the weights': it decides a choice among scores
+        # (over every expert the router scores, held here or not)
         params["expert_bias"] = config.expert_bias_init_std * (
-            jax.random.normal(k5, lead + (E,), jnp.float32))
+            jax.random.normal(k5, lead + (X,), jnp.float32))
     return params
 
 
@@ -224,15 +242,33 @@ def _route(params, tokens, config: MoEConfig, rng, layer, logits=None):
             probs = jax.nn.sigmoid(router_logits)
         else:
             probs = jax.nn.softmax(router_logits, axis=-1)
+        choice = probs
         if config.expert_bias:
             # the bias moves the choice and not the gates
-            _, chosen = jax.lax.top_k(
-                probs + _own(params, "expert_bias", layer).astype(
-                    jnp.float32), config.top_k)
-            gates = jnp.take_along_axis(probs, chosen, axis=-1)
-        else:
+            choice = probs + _own(params, "expert_bias", layer).astype(
+                jnp.float32)
+        if config.n_group is not None:
+            choice = _in_best_groups(choice, config)
+        if choice is probs:     # the scores decide alone: their top-k as it was
             gates, chosen = jax.lax.top_k(probs, config.top_k)
+        else:
+            _, chosen = jax.lax.top_k(choice, config.top_k)
+            gates = jnp.take_along_axis(probs, chosen, axis=-1)
     return probs, gates, chosen
+
+
+def _in_best_groups(choice: jax.Array, config: MoEConfig) -> jax.Array:
+    """``choice`` [T, E], the scores the k are chosen by, with every expert
+    outside the ``topk_group`` best of the ``n_group`` groups at -inf: a
+    group's score is the sum of its two largest. A high score in a losing
+    group is not chosen."""
+    T, E = choice.shape
+    grouped = choice.reshape(T, config.n_group, E // config.n_group)
+    score = jax.lax.top_k(grouped, 2)[0].sum(-1)             # [T, groups]
+    _, best = jax.lax.top_k(score, config.topk_group)
+    # a compare and a reduction, as ``_count``: no scatter
+    stays = (best[:, :, None] == jnp.arange(config.n_group)).any(axis=1)
+    return jnp.where(stays[:, :, None], grouped, -jnp.inf).reshape(T, E)
 
 
 def _normalised(gates: jax.Array, config: MoEConfig) -> jax.Array:

@@ -477,10 +477,10 @@ def test_a_reader_returns_the_hand_sum_over_the_spans(
     listed = {m["name"]: m for m in bench["per_layer"]}
     assert listed[metric]["moves"] == "per_token_p50_ms"
     # every serving cell, none dropped: the cells that report the metric
-    # these move (the three of PR 36, PR 42's and PR 47's)
+    # these move (the three of PR 36, PR 42's, PR 47's and PR 51's)
     serving = next(m["workloads"] for m in bench["end_to_end"]
                    if m["name"] == "per_token_p50_ms")
-    assert len(serving) == 5
+    assert len(serving) == 6
     assert listed[metric]["workloads"] == serving
     monkeypatch.setattr(host_spans, "TRACE_ROOT", recording["logdir"])
     got = run.read_layer_metric(
@@ -749,3 +749,70 @@ def test_unattributed_idle_arithmetic():
     assert H.overlap_ns(gaps, H.leaves(line)) == 30
     assert [s.name for s in H.children(line, line[0])] == [
         "engine.tick.pack", "engine.tick.fetch"]
+
+
+# A third recording, after the two before it were closed: a model whose
+# layers hold a SHARE of group-routed experts, keep states and attend a
+# latent cache (``models/bailing_hybrid.py``), for the two counters only
+# such a model's ``engine.tick`` carries.
+
+
+@pytest.fixture(scope="module")
+def share_recording(greedy_recording, tmp_path_factory):
+    import jax
+
+    from benchmarks.lib import host_spans
+    from tests.test_bailing_hybrid import TINY
+
+    engine = DecodeEngine(LLMConfig(**TINY))
+    prompts = [[3 + i] * n for i, n in enumerate((37, 6, 20))]
+    logdir = str(tmp_path_factory.mktemp("spans_share"))
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    before = dict(engine.stats)
+    jax.profiler.start_trace(logdir, profiler_options=options)
+    try:
+        futures = [engine.submit(p, SamplingParams(max_new_tokens=9))
+                   for p in prompts]
+        answers = [list(f.result(300)) for f in futures]
+    finally:
+        jax.profiler.stop_trace()
+    stats = {k: engine.stats[k] - before[k] for k in before}
+    engine.shutdown()
+    return {"spans": host_spans.load(logdir), "stats": stats,
+            "answers": answers}
+
+
+def test_a_share_models_ticks_carry_held_rows_and_latent_positions(
+        share_recording):
+    """``moe_rows_held`` (of the programs read since the span before, as
+    ``experts_touched``: the program's own count of the pairs the held
+    experts computed) and ``latent_positions`` (what the tick needed of the
+    latent layers' cache: known at the dispatch) are on every ``engine.tick``
+    of the capture, and ``stats`` sums the same quantities."""
+    spans, stats = share_recording["spans"], share_recording["stats"]
+    ticks = spans.named("engine.tick")
+    assert stats["ticks"] == len(ticks) > 0
+    assert all(len(a) == 9 for a in share_recording["answers"])
+    assert stats["latent_positions"] == sum(
+        t.args["latent_positions"] for t in ticks) > 0
+    # two latent layers of the toy's twelve
+    assert all(t.args["latent_positions"] == 2 * t.args["cache_positions"]
+               and t.args["layers_full"] == 0 for t in ticks)
+    # every decode program's held rows are on some tick's span; the
+    # admissions' (three prompts' chunks) are in ``stats`` beside them
+    held = sum(t.args["moe_rows_held"] for t in ticks)
+    assert 0 < held <= stats["moe_rows_held"]
+    assert sum(t.args["moe_rows"] for t in ticks) == (
+        stats["slot_ticks"] * 4 * 11)
+    assert held < sum(t.args["moe_rows"] for t in ticks)
+    assert stats["moe_rows_held"] < stats["moe_rows"]
+
+
+def test_a_model_that_holds_every_expert_carries_neither(greedy_recording):
+    ticks = greedy_recording["spans"].named("engine.tick")
+    assert ticks and not any(
+        "moe_rows_held" in t.args or "latent_positions" in t.args
+        for t in ticks)
+    assert greedy_recording["stats"]["moe_rows_held"] == 0
+    assert greedy_recording["stats"]["latent_positions"] == 0

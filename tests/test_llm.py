@@ -491,12 +491,13 @@ def test_serving_returns_logprobs(rt_serve_cluster=None):
     assert all(len(d) == 2 for d in lp["top_logprobs"])
 
 
-@pytest.mark.parametrize("ended_on", ["eos", "length"])
+@pytest.mark.parametrize("ended_on", ["eos", "length", "eos_ignored"])
 @pytest.mark.parametrize("stream", [False, True])
 def test_serving_finish_reason(ended_on, stream):
     """An answer says how it ended: ``length`` where ``max_tokens`` cut it,
     ``stop`` where the model made EOS — unary and in the last streamed
-    chunk."""
+    chunk. Under ``ignore_eos`` EOS is a token like any other: the answer
+    keeps it and runs to its ``max_tokens``."""
     import json
 
     from ray_tpu.llm.serving import LLMServer
@@ -506,19 +507,26 @@ def test_serving_finish_reason(ended_on, stream):
     srv.engine = _engine()
     greedy = list(srv.engine.generate(
         srv.engine.tokenizer.encode("hi"), SamplingParams(max_new_tokens=6)))
-    if ended_on == "eos":
+    kept = 6
+    if ended_on != "length":
         # force it: the third token the model makes greedily is now EOS
         srv.engine.tokenizer.eos_id = greedy[2]
-        kept = greedy.index(greedy[2])
-    else:
-        kept = 6
-    payload = {"prompt": "hi", "max_tokens": 6, "logprobs": 1}
+        if ended_on == "eos":
+            kept = greedy.index(greedy[2])
+    payload = {"prompt": "hi", "max_tokens": 6, "logprobs": 1,
+               "ignore_eos": ended_on == "eos_ignored"}
     want = "stop" if ended_on == "eos" else "length"
     if not stream:
         resp = srv.completions(payload)
         assert resp["usage"]["completion_tokens"] == kept
         assert resp["choices"][0]["finish_reason"] == want
+        if ended_on == "eos_ignored":   # the EOS it ignores among them
+            assert resp["choices"][0]["logprobs"]["tokens"] == greedy
         return
+    if ended_on == "eos_ignored":   # the stream yields the EOS it ignores
+        assert list(srv.engine.submit_stream(
+            srv.engine.tokenizer.encode("hi"), SamplingParams(
+                max_new_tokens=6, ignore_eos=True))) == greedy
     lines = list(srv.completions_stream(payload))
     assert lines[-1] == "data: [DONE]\n\n"
     chunks = [json.loads(l[len("data: "):]) for l in lines[:-1]]

@@ -656,6 +656,129 @@ def test_olmo_hybrid_programs_fit_the_chip_and_step_the_state_in_place(
         assert "f32[1,1,100352]" in text.split("\n", 1)[0]
 
 
+# ------------------------------------------- Ling-3.0-flash's engine programs
+# The ninth cell's size: one period of six layers at the published widths,
+# one chip's share of a four-way expert-parallel group, 64 slots of 19,456
+# positions (``benchmarks/configs/ling-3.0-flash.json``).
+
+
+@pytest.mark.parametrize("program, slots, width", [
+    ("decode", 64, 1), ("prefill", 1, 2048)])
+def test_ling_programs_fit_the_chip_and_keep_states_and_latents_in_place(
+        one_chip, program, slots, width, monkeypatch):
+    """``jit_decode`` at 64 slots and ``jit_prefill`` at the largest bucket:
+    the v5e compiler takes the ``kda_update`` kernel over the whole state
+    (two calls: the dense layer's, and one for the four routed KDA layers,
+    which are one scan) and the ``latent_decode_attention`` kernel over one
+    row of 576 values a position; the share's grouped products are the
+    Pallas kernel over the stacked ``[5 x 128, ..]`` experts; the cache of two kinds is the program's argument and its result
+    in one buffer; the prefill's chunked KDA scan and the latent blocks'
+    running softmax compile with no array of the cache's length times the
+    chunk's; the table and the head are read where they lie."""
+    import re
+
+    from benchmarks import run
+    from benchmarks.lib import program as harness
+    from ray_tpu.llm.engine import engine_programs
+    from ray_tpu.models import kv_cache
+
+    monkeypatch.setattr(kv_cache, "_decode_impl", lambda: "pallas")
+    monkeypatch.setattr(grouped_matmul, "_impl", lambda: "pallas")
+    config = run.load_cell("ling-3.0-flash.serve-longgen")[2]
+    assert config["serve"]["max_batch_slots"] == 64
+    cfg, args = _engine_program_args(
+        one_chip, slots, width, harness.model_config(config), block=2048)
+    cache = args[2]
+    assert {k: (v.shape, v.dtype) for k, v in cache.items()} == {
+        "ssm": ((5, slots, 32, 128, 128), jnp.float32),
+        "conv": ((5, slots, 3 * 12288), jnp.bfloat16),
+        "latent": ((1, slots, 1, 576, 19456), jnp.bfloat16)}
+    param_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(args[0]))
+    assert 8.70e9 < param_bytes < 8.72e9    # 4.35 B parameters in bf16
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in cache.values())
+    if program == "decode":
+        compiled = engine_programs(cfg)[2].lower(
+            *_decode_args(one_chip, args)).compile()
+    else:
+        rows = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+        compiled = engine_programs(cfg, own_cache=True)[0].lower(
+            *args, rows=rows).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    print(program, "arguments", mem.argument_size_in_bytes, "temporaries",
+          mem.temp_size_in_bytes, "aliased", mem.alias_size_in_bytes,
+          "cache", cache_bytes)
+    assert mem.alias_size_in_bytes >= cache_bytes
+    # no copy or conversion of the table or of the head
+    assert not re.search(r"= \w+\[39296,2560\]\S* (copy|transpose)\(", text)
+    calls = [line for line in text.splitlines() if "custom-call(" in line]
+    # three products a routed kind (the scanned KDA layers, the latent
+    # layer), in the pass that holds a uniform router's rows and in the
+    # overflow passes behind it (``moe._grouped_share``)
+    assert len([c for c in calls
+                if re.match(r"\s*%?grouped_matmul", c)]) == 12
+    if program == "decode":
+        # 0.67 GB of states, 24 MB of rows, 1.43 GB of latents
+        assert 2.12e9 < cache_bytes < 2.14e9
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 10.9e9
+        assert mem.temp_size_in_bytes < 0.05e9
+        updates = [c for c in calls if re.match(r"\s*%?kda_update", c)]
+        assert len(updates) == 2
+        assert all(f"s32[{slots + 1}]" in c
+                   and f"f32[5,{slots},32,128,128]" in c for c in updates)
+        latent = [c for c in calls
+                  if re.match(r"\s*%?latent_decode_attention", c)]
+        assert len(latent) == 1
+        assert f"bf16[1,{slots},1,576,19456]" in latent[0]
+        assert _weight_converts(text, args[0]) == []
+        # a share's counts: experts touched and rows held, a layer
+        assert "s32[6,2]" in text.split("\n", 1)[0]
+    else:
+        assert mem.temp_size_in_bytes < 0.40e9
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 9.2e9
+        # no [heads, T, S] scores, no one-hot placement: an array with a
+        # dimension of the cache's length holds no more than the leaf
+        along = [dims.split(",") for dims in re.findall(
+            r"\w+\[([\d,]+)\]", text) if "19456" in dims.split(",")]
+        assert along and max(
+            eval("*".join(dims)) for dims in along) == 576 * 19456
+        assert "f32[1,1,39296]" in text.split("\n", 1)[0]
+
+
+def test_ling_reference_reads_a_whole_context_beside_nothing_else(one_chip):
+    """The comparison that decides ``correct`` reads a sequence that ended
+    on EOS padded to the cell's ``context_limit``, one forward of the plain
+    float32 reference over 19,455 tokens on the chip the engine has left
+    (``runners/serve_open_loop_median.py``): weights as the program holds
+    them and the temporaries fit the chip's 16 GB with room, which they did
+    not while a layer's experts were a slice (a copy) of the stacked ones."""
+    import functools
+
+    from benchmarks import run
+    from benchmarks.lib import program as harness, reference
+    from benchmarks.runners import serve_open_loop_median as runner
+    from ray_tpu.models import module_for
+
+    _, workload, config = run.load_cell("ling-3.0-flash.serve-longgen")[:3]
+    limit = int(workload["traffic"]["context_limit"])
+    assert limit == 19456
+    cfg = harness.model_config(config)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: module_for(cfg).init_params(
+            cfg, jax.random.PRNGKey(0))))
+    tokens = jax.ShapeDtypeStruct((1, limit), jnp.int32, sharding=one_chip)
+    with jax.default_matmul_precision("highest"):
+        mem = jax.jit(functools.partial(
+            runner.greedy_gaps, reference.logits_of(config))).lower(
+                params, tokens).compile().memory_analysis()
+    print("arguments", mem.argument_size_in_bytes, "temporaries",
+          mem.temp_size_in_bytes)
+    assert 8.70e9 < mem.argument_size_in_bytes < 8.72e9
+    # 3.27 GB: the logits [19455, 39296] float32 are 3.06 of them
+    assert mem.temp_size_in_bytes < 4.0e9
+
+
 # -------------------------------------------- SmallThinker's training step
 # The sixth cell's size: one chip's share of a four-way expert-parallel
 # layer, batch 2 x 8192 (``benchmarks/configs/smallthinker-21b-a3b.json``).
