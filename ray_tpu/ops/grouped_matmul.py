@@ -85,7 +85,7 @@ def _impl() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
-def _sublanes(dtype) -> int:
+def sublanes(dtype) -> int:
     """Rows of one tile of ``dtype`` in VMEM: 8 of 32 bits, 16 of 16."""
     return 8 * 4 // jnp.dtype(dtype).itemsize
 
@@ -120,7 +120,7 @@ def tiles(rows: int, K: int, N: int, groups: int, dtype
       choice, makes them a third). Between (a prefill chunk, 8 to 256 rows
       a group): 128.
     """
-    sub = _sublanes(dtype)
+    sub = sublanes(dtype)
     size = jnp.dtype(dtype).itemsize
     if rows <= FEW_ROWS:
         tm = 32

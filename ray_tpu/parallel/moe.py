@@ -63,6 +63,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.grouped_matmul import grouped_dot, grouped_dot_grads
+from ray_tpu.ops.rows_to_tokens import rows_to_tokens
 
 
 @dataclass(frozen=True)
@@ -306,15 +307,18 @@ def _count(expert: jax.Array, n: int) -> jax.Array:
 
 
 def _experts(params, rows, gates, counts, config: MoEConfig, layer,
-             named=True, add_at=None):
+             named=True, add_at=None, gate_rows=None):
     """rows [R, D] sorted by expert, their gates [R] float32 and ``counts``
     [E] rows an expert -> each row's gated result, gate x its expert's
-    output: [R, D] float32, or with ``add_at`` = (index [R], T) those rows
-    added up, row r into row index[r] of [T, D] float32 (an index of T: into
-    none; ``_down_add``). Rows past ``counts.sum()`` belong to no expert;
-    what comes back for them is undefined. ``named``: the two products into
+    output: [R, D] float32, or with ``add_at`` = (token [R], T) those rows
+    added up, row r into row token[r] of [T, D] float32 (``_down_add``; a
+    group's rows in the order of their tokens). Rows past ``counts.sum()``
+    belong to no expert; what comes back for them is undefined, and they are
+    added to no token. ``named``: the two products into
     the experts and the rows' gates carry the names ``moe_fc``, ``moe_gate``
-    and ``moe_row_gates``.
+    and ``moe_row_gates``. ``gate_rows``: the same rows under another name,
+    read by the gate's product, for a caller that wants the two products'
+    cotangents apart (``_rows_of``); None: ``rows``.
 
     The gate meets the row BETWEEN the activation and the down product, the
     hidden rows [R, M]: the down projection is linear, so gate x (h W) =
@@ -356,7 +360,7 @@ def _experts(params, rows, gates, counts, config: MoEConfig, layer,
                 f"``stacked_for`` casts the stack once, outside the loop")
         return w.reshape((-1,) + w.shape[2:])
 
-    filled = counts.sum()
+    filled, own = counts.sum(), counts
     if layer is not None:
         L = params["expert_fc"].shape[0]
         counts = jax.lax.dynamic_update_slice(
@@ -377,13 +381,14 @@ def _experts(params, rows, gates, counts, config: MoEConfig, layer,
                                scope="moe.experts")
 
         h = name(product(rows, "expert_fc"), "moe_fc")
-        g = name(product(rows, "expert_gate"), "moe_gate") if _gated(
+        g = name(product(rows if gate_rows is None else gate_rows,
+                         "expert_gate"), "moe_gate") if _gated(
             config) else None
         h = _gated_hidden(config.activation, h, g, gates)
         if add_at is None:
             return product(h, "expert_out", jnp.float32)
         w_out = weights("expert_out")
-    return _down_add(h, w_out, counts, *add_at, E)
+    return _down_add(h, w_out, counts, own, *add_at, E)
 
 
 @functools.partial(jax.checkpoint, static_argnums=0)
@@ -405,42 +410,76 @@ def _gated_hidden(activation, h, g, gates):
     return (x * gates[:, None].astype(f32)).astype(h.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _down_add(h, w, sizes, index, T, live_groups):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _down_add(h, w, sizes, own, token, T, live_groups):
     """The down product and the combine of a share as one function: hidden
     rows h [R, M] (gated) x w [G, M, D] by ``sizes``, row r of the float32
-    result added into row ``index[r]`` of [T, D] float32 (an index of T:
-    dropped, which every row past the last group needs). One function
-    because of its backward pass: the cotangent [T, D] holds what the
-    caller's cast to the rows' dtype made of it, its rows are gathered in
-    THAT dtype and reach the two backward kernels so. Autodiff gives the
-    float32 product a float32 cotangent: a [R, D] gather at twice the bytes
-    and two kernels that read it to round it again (7.2 ms of gathers where
-    the dispatch's bf16 gathers of as many rows take 2.3; PR 44's trace)."""
+    result added into row ``token[r]`` of [T, D] float32
+    (``ops/rows_to_tokens.py``: on a TPU a kernel that walks the tokens in
+    tiles; ``own`` [E] are the sizes of the groups that hold rows, ``sizes``
+    without a stack's other layers; a row past the last group is never
+    read). One function because of its backward pass: the cotangent [T, D]
+    holds what the caller's cast to the rows' dtype made of it, its rows
+    are gathered in THAT dtype and reach the two backward kernels so.
+    Autodiff gives the float32 product a float32 cotangent: a [R, D] gather
+    at twice the bytes and two kernels that read it to round it again (7.2
+    ms of gathers where the dispatch's bf16 gathers of as many rows take
+    2.3; PR 44's trace)."""
     with jax.named_scope("moe.experts"):
         y = grouped_dot(h, w, sizes, jnp.float32, live_groups=live_groups,
                         scope="moe.experts")
     with jax.named_scope("moe.combine"):
-        return jnp.zeros((T, y.shape[1]), jnp.float32).at[index].add(
-            y, mode="drop")
+        return rows_to_tokens(y, token, own, T)
 
 
-def _down_add_fwd(h, w, sizes, index, T, live_groups):
-    return _down_add(h, w, sizes, index, T, live_groups), (
-        h, w, sizes, index)
+def _down_add_fwd(h, w, sizes, own, token, T, live_groups):
+    return _down_add(h, w, sizes, own, token, T, live_groups), (
+        h, w, sizes, token)
 
 
 def _down_add_bwd(T, live_groups, kept, ct):
-    h, w, sizes, index = kept
+    h, w, sizes, token = kept
     with jax.named_scope("moe.combine"):
-        # a row past the last group reads the last token's: finite, and
-        # neither product reads it (``grouped_dot_grads``)
-        dy = ct.astype(h.dtype).at[index].get(mode="clip")
+        # a row past the last group reads some token's: finite, and neither
+        # product reads it (``grouped_dot_grads``)
+        dy = ct.astype(h.dtype)[token]
     return *grouped_dot_grads(h, w, sizes, dy, live_groups=live_groups,
-                              scope="moe.experts"), None, None
+                              scope="moe.experts"), None, None, None
 
 
 _down_add.defvjp(_down_add_fwd, _down_add_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _rows_of(tokens, token, sizes, T, readers):
+    """The dispatch's gather, ``tokens[token]``: tokens [T, D] -> rows [R,
+    D], in groups of ``sizes`` from row 0 and inside a group in the order of
+    their tokens; as a tuple that names the same rows once for each of
+    their ``readers`` (the products into the experts). A function of its
+    own for its backward pass: the readers' cotangents are added into the
+    tokens' by ``rows_to_tokens``, float32 inside and rounded once to the
+    tokens' dtype, where the gather's transpose is XLA's scatter-add
+    (serial on the chip; it adds in the rows' dtype, rounding at every
+    row) of the cotangents' sum (one more pass over [R, D], which the
+    kernel adds in VMEM: hence a cotangent a reader). A row past the last
+    group holds a real token's row, finite, which no product reads; what
+    the products' backward pass leaves in such a row of a cotangent is
+    never read either, so no select stands on either side."""
+    return (tokens[token],) * readers
+
+
+def _rows_of_fwd(tokens, token, sizes, T, readers):
+    return _rows_of(tokens, token, sizes, T, readers), (token, sizes)
+
+
+def _rows_of_bwd(T, readers, kept, cts):
+    token, sizes = kept
+    # a backward function does not inherit its call site's scope
+    with jax.named_scope("moe.dispatch"):
+        return rows_to_tokens(cts, token, sizes, T), None, None
+
+
+_rows_of.defvjp(_rows_of_fwd, _rows_of_bwd)
 
 
 def _grouped(params, tokens, gates, chosen, row_mask, config: MoEConfig,
@@ -510,14 +549,19 @@ def _grouped_share(params, tokens, gates, chosen, row_mask, counts,
     add up): whatever the routing, every pair of a held expert is computed.
 
     A pair's gate is gathered beside its row and meets it inside
-    ``_experts``, on the hidden row; the combine is the scatter-add of the
-    experts' rows into their tokens', in float32, and no product or select
-    over [R, D] stands before it: a buffer row past the last pair is added
-    to no token (``mode="drop"``). What the grouped products leave in such a
-    row is undefined, and two selects keep it out of everything: the one on
-    the gathered ``rows`` here (zeros in; its transpose keeps an undefined
-    gradient out of the tokens'), and the one on the gates in ``_experts``
-    (its transpose keeps it out of the gates')."""
+    ``_experts``, on the hidden row. The sort is stable, so inside a group
+    the pairs, and with them the tokens, ascend: the buffer is ``num_held``
+    runs each sorted by destination, which is what lets a kernel add rows
+    to tokens without sorting or permuting anything
+    (``ops/rows_to_tokens.py``). Both places where rows are added to tokens
+    call it: the combine (``_down_add``: the experts' float32 rows into
+    their tokens') and the dispatch's backward pass (``_rows_of``: the rows'
+    cotangent into the tokens'). It reads no buffer row past the last pair,
+    and no product does: what lies there, forward (a real token's row, then
+    whatever the products leave) and backward (whatever their transposes
+    leave), reaches nothing, with no select over [R, D] on the way. The one
+    select left is over [R]: the gates' in ``_experts``, whose transpose
+    keeps an undefined <hidden, d hidden> out of the gates' gradient."""
     T, D = tokens.shape
     k, n_held = config.top_k, config.num_held
     R = held_rows_bound(T, config)
@@ -546,17 +590,15 @@ def _grouped_share(params, tokens, gates, chosen, row_mask, counts,
             token = jnp.where(real, ids // k, 0)
             sizes = (jnp.clip(ends - start, 0, R)
                      - jnp.clip(ends - counts - start, 0, R))
-            # a buffer row past the last pair is zeros, by a select: what
-            # the grouped products leave in the rows of no group is
-            # undefined (on the chip: whatever the memory held), in the
-            # backward pass too, and the select's transpose keeps that out
-            # of the tokens' gradient
-            rows = jnp.where(real[:, None], tokens[token], 0)   # [R, D]
+            # a buffer row past the last pair holds token 0's row: no
+            # product reads it, forward or backward
+            rows = _rows_of(tokens, token, sizes, T,        # each [R, D]
+                            2 if _gated(config) else 1)
             gate = flat_gates[jnp.where(real, ids, 0)]
         # the combine: a row added to its token's and nothing else; a row
-        # past the last pair to none (``mode="drop"`` of token T)
-        return _experts(params, rows, gate, sizes, config, layer, named,
-                        add_at=(jnp.where(real, token, T), T))
+        # past the last pair to none
+        return _experts(params, rows[0], gate, sizes, config, layer, named,
+                        add_at=(token, T), gate_rows=rows[-1])
 
     if passes == 1:
         out = one(tokens, params, 0, named=True)

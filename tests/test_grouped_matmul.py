@@ -174,7 +174,7 @@ def test_tiles_follow_the_shape(shape):
     tm, tk, tn = gm.tiles(*args)
     assert (tm, tk, tn) == want
     rows, K, N, _, dtype = args
-    assert K % tk == 0 and tm % gm._sublanes(dtype) == 0
+    assert K % tk == 0 and tm % gm.sublanes(dtype) == 0
     size = jnp.dtype(dtype).itemsize
     assert 2 * size * (tm * tk + tk * tn) + 12 * tm * tn <= gm.VMEM_BLOCKS
 

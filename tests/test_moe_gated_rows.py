@@ -1,15 +1,19 @@
 """A routed row meets its gate before the down product (``parallel/moe.py``,
 PR 49): ``_experts`` multiplies a pair's gate into its hidden row, the
-combine of ``_grouped_share`` is the scatter-add of the rows and nothing
+combine of ``_grouped_share`` adds the rows to their tokens and does nothing
 else (``_down_add``, whose backward pass gathers the cotangent in the rows'
-dtype), and nothing in the backward pass needs an expert's output.
+dtype), and nothing in the backward pass needs an expert's output. Since PR
+52 a share's rows reach their tokens through ``ops/rows_to_tokens.py`` in
+the combine and in the dispatch's backward pass (``_rows_of``), with no
+select over the row buffer beside either.
 
 Both dispatches are held to a dense float32 sum over experts that gates the
 expert's OUTPUT, value and the gradients with respect to the tokens, the
 three expert weights and the gates: one pass, under the ``lax.cond`` of a
 share that may need further passes, with those passes forced, with a row
-mask, with ``ragged_dot`` and with the TPU's kernels in interpret mode; then
-with every row the grouped products owe nobody made NaN; then the lowered
+mask, with ``ragged_dot``, with the TPU's grouped products in interpret mode
+and with the kernel that adds rows to tokens in interpret mode; then with
+every row the grouped products owe nobody made NaN; then the lowered
 backward pass is counted. CPU, tiny widths."""
 import dataclasses
 
@@ -19,7 +23,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import decoder, get_preset
-from ray_tpu.ops import grouped_matmul
+from ray_tpu.ops import grouped_matmul, rows_to_tokens
 from ray_tpu.parallel import moe
 
 T, D, M, E, K = 96, 64, 32, 8, 2
@@ -31,6 +35,7 @@ LAYERS = {
     "share-one-pass": (8, 0, "reglu", False),
     "share-under-the-cond": (2, 2, "reglu", False),
     "share-further-passes": (2, 2, "reglu", True),
+    "share-gelu": (8, 0, "gelu", False),
     "grouped": (None, 0, "swiglu", False),
     "grouped-gelu": (None, 0, "gelu", False),
 }
@@ -132,11 +137,18 @@ def _close(got, want, tol, what):
         got, want, rtol=tol, atol=tol * np.abs(want).max(), err_msg=what)
 
 
-@pytest.fixture(params=["ragged_dot", "kernels"])
+@pytest.fixture(params=["ragged_dot", "kernels", "rows-kernel"])
 def impl(request, monkeypatch):
+    """What runs the grouped products and adds rows to tokens: XLA both, the
+    products' kernels interpreted, or the ``rows_to_tokens`` kernel
+    interpreted (at these widths by a rule that takes every shape)."""
     if request.param == "kernels":
         monkeypatch.setattr(grouped_matmul, "_impl",
                             lambda: "pallas_interpret")
+    if request.param == "rows-kernel":
+        monkeypatch.setattr(rows_to_tokens, "_impl",
+                            lambda: "pallas_interpret")
+        monkeypatch.setattr(rows_to_tokens, "ROWS_A_VISIT", 0)
     return request.param
 
 
@@ -160,7 +172,7 @@ def test_both_dispatches_are_the_dense_sum_and_its_gradients(
     assert grads["tokens"].dtype == dtype
     _close(out, want, tol, "result")
     assert set(grads) == set(want_grads) and len(grads) == (
-        4 if layer == "grouped-gelu" else 5)
+        4 if layer.endswith("gelu") else 5)
     off = ~np.asarray(mask) if masked else np.zeros(T, bool)
     if masked and layer.startswith("grouped"):
         # a masked token's own gradient is what the products left in its
@@ -215,7 +227,7 @@ def _poisoned(monkeypatch):
 
 @pytest.mark.parametrize("layer", [
     "share-one-pass", "share-under-the-cond", "share-further-passes",
-    "grouped"])
+    "share-gelu", "grouped"])
 def test_what_the_products_leave_in_rows_of_no_expert_reaches_nothing(
         layer, impl, monkeypatch):
     """With NaN wherever ``grouped_dot`` owes nothing (a share's buffer past
@@ -274,8 +286,10 @@ def _equations(jaxpr, path=()):
 # stream: 3 forward and 6 transposes in the branch one pass takes (10 before
 # PR 49: the down product again, for the gates' gradient), and under the
 # cond the further passes' own 11 (3 forward, the two up-projections again
-# under their own checkpoint, 6 transposes; 12 before)
-PRODUCTS = {"share-one-pass": 9, "share-under-the-cond": 9 + 11}
+# under their own checkpoint, 6 transposes; 12 before); without a gate's
+# product 2 forward and 4 transposes
+PRODUCTS = {"share-one-pass": 9, "share-under-the-cond": 9 + 11,
+            "share-gelu": 6}
 
 
 @pytest.mark.parametrize("layer", list(PRODUCTS))
@@ -298,14 +312,14 @@ def test_the_backward_pass_makes_no_expert_output_again(layer, impl):
 
     jaxpr = jax.make_jaxpr(jax.grad(jax.checkpoint(
         loss, policy=policy), argnums=(0, 1, 2)))(params, tokens, gates)
-    product = "ragged_dot_general" if impl == "ragged_dot" else "pallas_call"
+    product = "pallas_call" if impl == "kernels" else "ragged_dot_general"
     products = [(path, e) for path, e in _equations(jaxpr.jaxpr)
                 if e.primitive.name == product]
     assert len(products) == PRODUCTS[layer]
     down = [path for path, e in products
             if e.outvars[0].aval.shape == (R, D)
             and e.outvars[0].aval.dtype == F32]
-    branches = 1 if layer == "share-one-pass" else 2
+    branches = 2 if layer == "share-under-the-cond" else 1
     # once a branch, forward: never in what a checkpoint runs again
     assert len(down) == branches
     assert not [path for path in down if path and path[0] == "remat2"]
@@ -318,3 +332,63 @@ def test_the_backward_pass_makes_no_expert_output_again(layer, impl):
     # the rows, the rows again under the checkpoint, the cotangent's rows
     assert len(gathers) >= 3 * branches
     assert {a.dtype for a in gathers} == {jnp.dtype(BF16)}
+
+
+# ---------------------------------------- rows reach their tokens (PR 52)
+
+
+def _share_jaxpr(dtype):
+    """The jaxpr of a differentiated ``_grouped_share`` at the routed
+    training cell's shape (2 x 8,192 tokens of 2,560, 6 of 64 experts a
+    token, 16 held of 768): traced over shapes, nothing runs."""
+    config = moe.MoEConfig(num_experts=64, top_k=6, activation="reglu",
+                           dropless=True, num_held=16, first_held=0)
+    n_tok, width, hidden = 16384, 2560, 768
+
+    def shape(*dims, dtype=dtype):
+        return jax.ShapeDtypeStruct(dims, dtype)
+
+    params = {"expert_fc": shape(16, width, hidden),
+              "expert_gate": shape(16, width, hidden),
+              "expert_out": shape(16, hidden, width)}
+
+    def loss(params, tokens, gates, chosen, counts):
+        out = moe._grouped_share(params, tokens, gates, chosen, None, counts,
+                                 config, None)
+        return out.astype(F32).sum()
+
+    return moe.held_rows_bound(n_tok, config), jax.make_jaxpr(
+        jax.grad(loss, argnums=(0, 1, 2)))(
+            params, shape(n_tok, width), shape(n_tok, 6, dtype=F32),
+            shape(n_tok, 6, dtype=jnp.int32), shape(16, dtype=jnp.int32))
+
+
+@pytest.mark.parametrize("chosen", ["kernel", "scatter-add"])
+def test_no_row_scatter_and_no_select_over_the_buffer_where_the_kernel_is(
+        chosen, monkeypatch):
+    """At the cell's shape the rule takes the kernel on a TPU: the
+    differentiated share then holds no ``scatter-add`` whose updates are
+    rows of ``D`` and no ``select_n`` over ``[R, D]`` in either branch, and
+    ``rows_to_tokens`` four times (the combine and the dispatch's backward
+    pass, in the branch one pass takes and in the further passes'). Off the
+    TPU the two scatter-adds are there as they were, a branch, and still no
+    select over the buffer."""
+    if chosen == "kernel":
+        monkeypatch.setattr(rows_to_tokens, "_impl", lambda: "pallas")
+    R, jaxpr = _share_jaxpr(BF16)
+    D = 2560
+    assert R == 36864
+    equations = [e for _, e in _equations(jaxpr.jaxpr)]
+    kernels = [e for e in equations if e.primitive.name == "pallas_call"]
+    scatters = [e for e in equations if e.primitive.name == "scatter-add"
+                and e.invars[2].aval.shape[-1:] == (D,)]
+    selects = [e for e in equations if e.primitive.name == "select_n"
+               and e.outvars[0].aval.shape == (R, D)]
+    assert not selects
+    if chosen == "kernel":
+        assert not scatters
+        assert sorted(str(e.outvars[0].aval.dtype) for e in kernels) == [
+            "bfloat16", "bfloat16", "float32", "float32"]
+        assert {e.outvars[0].aval.shape for e in kernels} == {(16384, D)}
+    else:
+        assert not kernels and len(scatters) == 4

@@ -19,7 +19,7 @@ from jax.sharding import (
     Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding,
 )
 
-from ray_tpu.ops import grouped_matmul
+from ray_tpu.ops import grouped_matmul, rows_to_tokens
 from ray_tpu.ops.attention import attention, flash_attention
 from ray_tpu.parallel import moe
 
@@ -671,8 +671,11 @@ def test_ling_programs_fit_the_chip_and_keep_states_and_latents_in_place(
     (two calls: the dense layer's, and one for the four routed KDA layers,
     which are one scan) and the ``latent_decode_attention`` kernel over one
     row of 576 values a position; the share's grouped products are the
-    Pallas kernel over the stacked ``[5 x 128, ..]`` experts; the cache of two kinds is the program's argument and its result
-    in one buffer; the prefill's chunked KDA scan and the latent blocks'
+    Pallas kernel over the stacked ``[5 x 128, ..]`` experts, and a chunk's
+    rows reach their tokens through ``rows_to_tokens`` (12 rows a visit;
+    a tick's 192 rows, 1.5 a visit, stay XLA's scatter-add: PR 52); the
+    cache of two kinds is the program's argument and its result in one
+    buffer; the prefill's chunked KDA scan and the latent blocks'
     running softmax compile with no array of the cache's length times the
     chunk's; the table and the head are read where they lie."""
     import re
@@ -684,6 +687,7 @@ def test_ling_programs_fit_the_chip_and_keep_states_and_latents_in_place(
 
     monkeypatch.setattr(kv_cache, "_decode_impl", lambda: "pallas")
     monkeypatch.setattr(grouped_matmul, "_impl", lambda: "pallas")
+    monkeypatch.setattr(rows_to_tokens, "_impl", lambda: "pallas")
     config = run.load_cell("ling-3.0-flash.serve-longgen")[2]
     assert config["serve"]["max_batch_slots"] == 64
     cfg, args = _engine_program_args(
@@ -717,6 +721,9 @@ def test_ling_programs_fit_the_chip_and_keep_states_and_latents_in_place(
     # overflow passes behind it (``moe._grouped_share``)
     assert len([c for c in calls
                 if re.match(r"\s*%?grouped_matmul", c)]) == 12
+    # the combine of each routed kind's two branches, where the rule takes it
+    assert len([c for c in calls if re.match(r"\s*%?rows_to_tokens", c)]) == (
+        0 if program == "decode" else 4)
     if program == "decode":
         # 0.67 GB of states, 24 MB of rows, 1.43 GB of latents
         assert 2.12e9 < cache_bytes < 2.14e9
@@ -815,6 +822,41 @@ def test_flash_with_a_window_and_grouped_heads_compiles_for_v5e(
     assert "bf16[2,8192,28,128]" in text and "bf16[8,8192,128]" in text
 
 
+# (R, G, T, dtype, buffers) at the edges of ``rows_to_tokens.engages`` for
+# rows of 2,560: the most tokens SMEM is asked to hold; with them the most
+# visits the rule admits (R / ROWS_A_VISIT: 8,320 bounds beside the tokens);
+# the most VMEM, one float32 buffer (77.9 MB) and two bfloat16 ones (80.6
+# of the 83.9 allowed); the most groups VMEM admits beside one tile
+ROWS_TO_TOKENS_EDGES = {
+    "most-rows": (65536, 16, 32768, jnp.float32, 1),
+    "most-visits": (65536, 128, 32768, jnp.float32, 1),
+    "most-vmem": (65536, 16, 12288, jnp.float32, 1),
+    "most-vmem-two-bfloat16": (65536, 16, 12288, jnp.bfloat16, 2),
+    "most-groups": (1600, 200, 512, jnp.float32, 1),
+}
+
+
+@pytest.mark.parametrize("edge", list(ROWS_TO_TOKENS_EDGES))
+def test_rows_to_tokens_compiles_at_the_edges_of_its_rule(
+        one_chip, edge, monkeypatch):
+    """What ``engages`` admits compiles: the rule's limits (tokens and
+    bounds in SMEM, buffers in VMEM) are ones the chip's compiler took, so
+    a call the rule hands the kernel is never a compile error (a shape past
+    them is XLA's scatter-add, as off the chip)."""
+    R, G, T, dtype, buffers = ROWS_TO_TOKENS_EDGES[edge]
+    monkeypatch.setattr(rows_to_tokens, "_impl", lambda: "pallas")
+    assert rows_to_tokens.engages(R, G, T, 2560, dtype, buffers)
+    rows = jax.ShapeDtypeStruct((R, 2560), dtype, sharding=one_chip)
+    token = jax.ShapeDtypeStruct((R,), jnp.int32, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((G,), jnp.int32, sharding=one_chip)
+    text = jax.jit(lambda rows, token, sizes: rows_to_tokens.rows_to_tokens(
+        (rows,) * buffers, token, sizes, T)).lower(
+            rows, token, sizes).compile().as_text()
+    calls = [line.split(" = ")[0] for line in text.splitlines()
+             if "tpu_custom_call" in line and " = " in line]
+    assert len(calls) == 1 and "rows_to_tokens" in calls[0]
+
+
 def _loss_head(text, mem, vocab, embed, was):
     """The compiled step's loss head (``ops/xent.py``, PR 43): the chunk's
     logits are projected once, in the forward scan, where dW is made too;
@@ -842,11 +884,13 @@ def _loss_head(text, mem, vocab, embed, was):
 
 def _compiled_train_step(one_chip, cell_name, monkeypatch):
     """(model configuration, compiled step) of a training cell for the
-    described chip: the cell's own widths and batch, the flash kernels and
-    the grouped products' (the platform's choices, made here for it)."""
+    described chip: the cell's own widths and batch, the flash kernels, the
+    grouped products' and the one that adds rows to tokens (the platform's
+    choices, made here for it)."""
     import dataclasses
 
     monkeypatch.setattr(grouped_matmul, "_impl", lambda: "pallas")
+    monkeypatch.setattr(rows_to_tokens, "_impl", lambda: "pallas")
 
     from benchmarks import run
     from benchmarks.lib import program
@@ -892,9 +936,12 @@ def test_smallthinker_step_fits_the_chip_with_its_window_in_the_kernels(
     the ``moe.experts`` scope, the ``custom_vjp``'s backward too), the
     two up-projections kept for the backward pass and not run again, the
     down product not run again either and its cotangent's rows gathered in
-    bfloat16 (PR 49: the gate rides the hidden row), no count made by a
-    scatter-add of ones, no [B, H, T, T] array anywhere, and a loss head
-    that projects a chunk's logits once (``_loss_head``)."""
+    bfloat16 (PR 49: the gate rides the hidden row), the rows added to
+    their tokens by the ``rows_to_tokens`` kernel in the combine and in the
+    dispatch's backward pass, with no scatter-add of [.., 2560] rows and no
+    select over the row buffer left in a routed layer (PR 52), no count
+    made by a scatter-add of ones, no [B, H, T, T] array anywhere, and a
+    loss head that projects a chunk's logits once (``_loss_head``)."""
     import re
 
     cfg, compiled = _compiled_train_step(
@@ -906,8 +953,10 @@ def test_smallthinker_step_fits_the_chip_with_its_window_in_the_kernels(
     # up-projections were residuals: the compiler's sum moves by 0.81 GB,
     # the chip's peak by 0.13 (14.880 -> 15.007 GB, ``memory_peak_bytes``);
     # 9,126,115,328 -> 8,358,041,600 when the backward pass stopped making
-    # float32 [36864, 2560] rows (sandbox compile, PR 49)
-    assert mem.temp_size_in_bytes < 8.5e9
+    # float32 [36864, 2560] rows (sandbox compile, PR 49); 8,183,665,152 when the
+    # selects over the row buffer left with the scatter-adds (sandbox
+    # compile, PR 52)
+    assert mem.temp_size_in_bytes < 8.25e9
     text = compiled.as_text()
     # 18,048,474,112 with the loss body under remat (sandbox compile, PR 42's
     # tree); 18,048,409,600 with the gradients made in the loss's forward
@@ -939,10 +988,32 @@ def test_smallthinker_step_fits_the_chip_with_its_window_in_the_kernels(
     down = [line for line in products if f" = f32[{R},2560]" in line]
     assert R == 36864 and len(down) == 2 * 4
     assert not [line for line in down if "rematted_computation" in line]
+    # rows reach their tokens through the kernel: a layer's combine
+    # (float32 rows) and its dispatch's backward pass (the cotangent's, in
+    # bfloat16), once in each of the layer's two branches: 8 of the 16 run
+    # in a step whose routing one pass holds
+    added = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and " = " in line
+             and "rows_to_tokens" in line.split(" = ")[0]]
+    scopes = [re.search(r'op_name="([^"]*)"', line).group(1)
+              for line in added]
+    assert len(added) == (1 + 1) * 2 * 4
+    assert sum(" = f32[16384,2560]" in line and "moe.combine" in scope
+               and "transpose" not in scope
+               for line, scope in zip(added, scopes)) == 2 * 4
+    assert sum(" = bf16[16384,2560]" in line and "moe.dispatch" in scope
+               and "transpose" in scope
+               for line, scope in zip(added, scopes)) == 2 * 4
+    assert not [scope for scope in scopes if "moe.experts" in scope]
+    # the one scatter of [.., 2560] rows left is the embedding's gradient,
+    # and no select over the [36864, 2560] buffer stands in a routed layer
+    scatters = re.findall(r"= \w+\[\d+,2560\]\S* scatter\([^\n]*", text)
+    assert len(scatters) == 1 and "moe." not in scatters[0]
+    assert not re.search(
+        rf"= \w+\[{R},2560\]\S* select\([^\n]*moe\.", text)
     # the rows of the combine's cotangent are gathered as they arrive, in
     # bfloat16: 4 a branch under ``moe.combine``, where autodiff's were
-    # float32 (the float32 gather that stays is the forward scatter-add's
-    # own permutation of its updates, ``op_name=".../scatter-add"``)
+    # float32
     gathers = re.findall(
         rf"= (\w+)\[{R},2560\]\S* gather\([^\n]*"
         r'op_name="[^"]*transpose[^"]*moe\.combine/[^"]*"', text)
