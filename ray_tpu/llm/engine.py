@@ -180,8 +180,10 @@ class TokenStream:
     finish_reason = ""
     finished_at = 0.0
 
-    def __init__(self, tokens):
+    def __init__(self, tokens, lock_wait_s: float = 0.0):
         self._tokens = tokens(self)
+        # what ``submit_stream`` waited for the engine's lock
+        self.lock_wait_s = lock_wait_s
 
     def __iter__(self):
         return self
@@ -1223,14 +1225,26 @@ class DecodeEngine:
 
     # ------------------------------------------------------------- public
 
-    def _enqueue(self, kind: str, payload, params, fut: Future, rid) -> None:
+    def _enqueue(self, kind: str, payload, params, fut: Future,
+                 rid) -> float:
+        """Queue a request and see that the loop runs. Returns the seconds
+        the caller then waited for the engine's lock, which the loop holds
+        for a whole turn (``lock_wait_ms`` of ``llm.request``). The request
+        is stamped ``submitted`` and queued first, so the wait is the
+        CALLER's and runs beside the request's ``engine.finish``
+        ``total_ms``, not on top of it (a running loop takes the request up
+        at its next turn, whoever holds the lock). Where it outlasts the
+        engine's work the answer waits for it: the caller reads its first
+        token only after this returns, and the excess shows as ``llm.done``'s
+        ``after_finish_ms``. A sum over a request's way counts ``total_ms``
+        and ``after_finish_ms``, and not this."""
         if rid is None:
             # submitted to the engine directly: an engine-local number
             rid = f"engine-{next(self._rid_seq)}"
         self._pending.put(_Pending(
             kind, payload, params or SamplingParams(), fut, rid,
             time.monotonic()))
-        self._ensure_loop()
+        return self._ensure_loop()
 
     def submit(self, prompt_ids: List[int],
                params: Optional[SamplingParams] = None,
@@ -1241,7 +1255,8 @@ class DecodeEngine:
         if not prompt_ids:
             raise ValueError("prompt must be non-empty")
         fut: Future = Future()
-        self._enqueue("prompt", list(prompt_ids), params, fut, rid)
+        fut.lock_wait_s = self._enqueue(
+            "prompt", list(prompt_ids), params, fut, rid)
         return fut
 
     def prefill_only(self, prompt_ids: List[int],
@@ -1294,7 +1309,8 @@ class DecodeEngine:
         fut: Future = Future()
         q: "_q.Queue" = _q.Queue()
         fut._rt_stream_q = q
-        self._enqueue("prompt", list(prompt_ids), params, fut, rid)
+        lock_wait_s = self._enqueue(
+            "prompt", list(prompt_ids), params, fut, rid)
 
         def gen(stream: TokenStream):
             while True:
@@ -1314,7 +1330,7 @@ class DecodeEngine:
                     continue  # await the done marker
                 yield item
 
-        return TokenStream(gen)
+        return TokenStream(gen, lock_wait_s)
 
     def generate(self, prompt_ids: List[int],
                  params: Optional[SamplingParams] = None) -> List[int]:
@@ -1327,15 +1343,19 @@ class DecodeEngine:
         out = self.generate(ids, params)
         return self.tokenizer.decode(out)
 
-    def _ensure_loop(self):
+    def _ensure_loop(self) -> float:
+        """Start the loop's thread where none runs; the seconds the caller
+        waited for the lock."""
+        asked = time.monotonic()
         with self._lock:
-            if self._loop_thread is not None and self._loop_thread.is_alive():
-                return
-            self._stopped = False
-            self._loop_thread = threading.Thread(
-                target=self._loop, daemon=True, name="rt-llm-engine"
-            )
-            self._loop_thread.start()
+            waited = time.monotonic() - asked
+            if self._loop_thread is None or not self._loop_thread.is_alive():
+                self._stopped = False
+                self._loop_thread = threading.Thread(
+                    target=self._loop, daemon=True, name="rt-llm-engine"
+                )
+                self._loop_thread.start()
+        return waited
 
     def _loop(self):
         idle_since = None

@@ -16,6 +16,7 @@ from typing import Any, Dict, List, Optional
 
 from ray_tpu.llm.config import LLMConfig
 from ray_tpu.llm.engine import DecodeEngine, SamplingParams
+from ray_tpu.serve.replica import request_origin
 
 
 def extract_sampling(payload: dict, config: LLMConfig) -> SamplingParams:
@@ -96,11 +97,13 @@ def _done(rid: str, stream: bool, tokens: int, finished_at: float):
     (``finished_at``: ``time.monotonic()`` of its ``engine.finish``), around
     the making of its last piece, which the caller counts into the span's
     ``chunks``. ``after_finish_ms``: from the finish to that piece being
-    ready to leave the replica."""
+    ready to leave the replica. ``req`` is the ingress's id for the request
+    ("" where it came through none): it ties ``rid``, the engine's and the
+    answer's, to the ``serve.*`` spans."""
     from jax.profiler import TraceAnnotation
 
-    with TraceAnnotation("llm.done", rid=rid, stream=int(stream),
-                         tokens=tokens) as done:
+    with TraceAnnotation("llm.done", rid=rid, req=request_origin()[0],
+                         stream=int(stream), tokens=tokens) as done:
         yield done
         done.set_metadata(after_finish_ms=round(
             (time.monotonic() - finished_at) * 1e3, 3))
@@ -159,21 +162,33 @@ class LLMServer:
         the call of the endpoint; ``since_call_ms`` of the span is from
         there to the hand-over: for a stream, whose body runs at the
         proxy's first pull, the reply to the proxy and that pull's way
-        back."""
+        back. ``since_received_ms`` is to the same moment from the
+        ingress's stamp (``serve/replica.py:request_origin``; with ``req``,
+        only where the request came through one); ``lock_wait_ms`` is what
+        the hand-over then waited for the engine's lock
+        (``DecodeEngine._enqueue``)."""
         from jax.profiler import TraceAnnotation
 
         params = self._sampling(payload)
+        req, received = request_origin()
         with TraceAnnotation(
-            "llm.request", rid=rid, max_tokens=params.max_new_tokens,
-            stream=int(stream),
+            "llm.request", rid=rid, req=req,
+            max_tokens=params.max_new_tokens, stream=int(stream),
         ) as request:
             ids = self.engine.tokenizer.encode(prompt)
+            now = time.monotonic()
             request.set_metadata(
                 prompt_tokens=len(ids),
-                since_call_ms=round((time.monotonic() - called) * 1e3, 3))
+                since_call_ms=round((now - called) * 1e3, 3))
+            if received:
+                request.set_metadata(
+                    since_received_ms=round((now - received) * 1e3, 3))
             send = (self.engine.submit_stream if stream
                     else self.engine.submit)
-            return ids, send(ids, params, rid=rid)
+            out = send(ids, params, rid=rid)
+            request.set_metadata(
+                lock_wait_ms=round(out.lock_wait_s * 1e3, 3))
+            return ids, out
 
     def _answer(self, prompt: str, payload: dict, rid: str, called: float):
         """(prompt ids, the engine's answer, its text) of a unary request."""

@@ -88,14 +88,22 @@ class GRPCProxy(ProxyBase):
 
     # ------------------------------------------------------------- routing
 
-    async def _handle_for(self, req: dict, context):
+    def _origin_of(self, context) -> tuple:
+        """(``req``, ``received``) of a call that has just arrived: the
+        ``x-request-id`` of its metadata or an id minted here, and the
+        stamp (``ProxyBase._open``). They ride with the call to the
+        replica's spans; this proxy leaves none of its own yet."""
+        metadata = dict(context.invocation_metadata() or ())
+        return self._open(metadata.get("x-request-id")).origin
+
+    async def _handle_for(self, req: dict, context, origin: tuple):
         """Resolve the deployment handle + per-request options, or abort."""
         import grpc
 
         route = req.get("route") or "/"
         try:
             # The controller RPC blocks; it must not stall the grpc.aio loop.
-            deployment = await asyncio.get_running_loop().run_in_executor(
+            deployment, _ = await asyncio.get_running_loop().run_in_executor(
                 None, self._route_for, route
             )
         except Exception as e:
@@ -116,8 +124,8 @@ class GRPCProxy(ProxyBase):
         for key, value in context.invocation_metadata() or ():
             if key == "serve-multiplexed-model-id" and value:
                 model_id = value
-        if model_id:
-            handle = handle.options(multiplexed_model_id=model_id)
+        handle = handle.options(
+            multiplexed_model_id=model_id or None, origin=origin)
         return handle, req.get("method") or "__call__"
 
     def _admit(self, context) -> bool:
@@ -156,6 +164,7 @@ class GRPCProxy(ProxyBase):
 
         from ray_tpu._private.config import rt_config
 
+        origin = self._origin_of(context)
         try:
             req = _unpack(request)
         except Exception as e:
@@ -166,7 +175,7 @@ class GRPCProxy(ProxyBase):
             return b""
         self._inflight += 1
         try:
-            handle, method = await self._handle_for(req, context)
+            handle, method = await self._handle_for(req, context, origin)
             if handle is None:
                 return b""
             loop = asyncio.get_running_loop()
@@ -199,6 +208,7 @@ class GRPCProxy(ProxyBase):
 
         from ray_tpu._private.config import rt_config
 
+        origin = self._origin_of(context)
         try:
             req = _unpack(request)
         except Exception as e:
@@ -210,7 +220,7 @@ class GRPCProxy(ProxyBase):
         self._inflight += 1
         it = None
         try:
-            handle, method = await self._handle_for(req, context)
+            handle, method = await self._handle_for(req, context, origin)
             if handle is None:
                 return
             handle = handle.options(stream=True)

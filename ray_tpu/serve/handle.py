@@ -71,6 +71,11 @@ class _StreamIterator:
         self._key = replica_key
         self._buf: list = []
         self._done = False
+        # for the caller's ledger (``serve.proxy.request``): the
+        # ``next_chunks`` calls made, and time.monotonic() of the return of
+        # the one that said done (0.0 until then)
+        self.pulls = 0
+        self.drained_at = 0.0
 
     def __iter__(self):
         return self
@@ -100,6 +105,7 @@ class _StreamIterator:
     def _ingest(self, chunks, done: bool):
         self._buf.extend(chunks)
         if done:
+            self.drained_at = time.monotonic()
             self._done = True
             self._settle()
 
@@ -116,6 +122,7 @@ class _StreamIterator:
                     faultpoints.fire(
                         "serve.replica.stream", err=ConnectionError
                     )
+                self.pulls += 1
                 chunks, done = ray_tpu.get(
                     self._replica.next_chunks.remote(self._stream_id),
                     timeout=float(rt_config.serve_stream_chunk_timeout_s),
@@ -150,6 +157,7 @@ class _StreamIterator:
                         "serve.replica.stream", err=ConnectionError
                     )
                 w = get_global_worker()
+                self.pulls += 1
                 chunks, done = await asyncio.wait_for(
                     w.as_asyncio_future(
                         self._replica.next_chunks.remote(self._stream_id)
@@ -506,10 +514,12 @@ class _Router:
         except Exception:
             pass
 
-    def _refresh(self, force: bool = False):
+    def _refresh(self, force: bool = False) -> bool:
+        """Fetch the replica set from the controller where the one held is
+        stale (or ``force``); whether it went to the controller."""
         now = time.monotonic()
         if not force and now - self._fetched_at < self._refresh_s:
-            return
+            return False
         import ray_tpu
 
         self._subscribe_push()
@@ -554,11 +564,16 @@ class _Router:
             # keep the invalidated timestamp so the next pick re-fetches.
             if self._invalidation_gen == gen:
                 self._fetched_at = now
+        return True
 
     def pick(self, model_id: Optional[str] = None):
         """Power-of-two-choices on locally tracked in-flight counts; with a
         model_id, replicas already holding that model are preferred
-        (reference: model-multiplex-aware routing)."""
+        (reference: model-multiplex-aware routing). Returns (replica, its
+        routing key, what ``serve.route`` says of the pick: ``replicas``
+        in the set, the chosen one's ``inflight`` with this request, and
+        ``refreshed``, 1 where a controller refresh was on this call's
+        path)."""
         from ray_tpu._private.backoff import Backoff
 
         if model_id and not self._multiplex:
@@ -578,9 +593,10 @@ class _Router:
         empty_deadline = time.monotonic() + 30
         poll = Backoff(base=0.05, cap=1.0)
         force = False
+        refreshed = False
         while True:
             try:
-                self._refresh(force=force)
+                refreshed |= self._refresh(force=force)
             except Exception as e:
                 if time.monotonic() > fail_deadline:
                     raise ServeRetryableError(
@@ -633,7 +649,11 @@ class _Router:
                         self._inflight[key] = (
                             self._inflight.get(key, 0) + 1
                         )
-                        return chosen, key
+                        return chosen, key, {
+                            "replicas": len(self._replicas),
+                            "inflight": self._inflight[key],
+                            "refreshed": int(refreshed),
+                        }
             if time.monotonic() > empty_deadline:
                 raise ServeRetryableError(
                     f"no replicas for deployment '{self._deployment}'"
@@ -691,23 +711,29 @@ class _MethodCaller:
 
 class DeploymentHandle:
     def __init__(self, deployment: str, _router: Optional[_Router] = None,
-                 _multiplexed_model_id: str = "", _stream: bool = False):
+                 _multiplexed_model_id: str = "", _stream: bool = False,
+                 _origin: Optional[tuple] = None):
         self._deployment = deployment
         self._router = _router or _Router(deployment)
         self._multiplexed_model_id = _multiplexed_model_id
         self._stream = _stream
+        self._origin = _origin
 
     @property
     def deployment_name(self) -> str:
         return self._deployment
 
     def options(self, *, multiplexed_model_id: Optional[str] = None,
-                stream: Optional[bool] = None) -> "DeploymentHandle":
+                stream: Optional[bool] = None,
+                origin: Optional[tuple] = None) -> "DeploymentHandle":
         """Per-call options (reference: ``handle.options(...)``):
         ``multiplexed_model_id`` routes to replicas holding that model and
         is readable in the request via ``serve.get_multiplexed_model_id()``;
         ``stream=True`` returns an iterator over a generator deployment's
-        chunks. The returned handle shares this handle's router state."""
+        chunks; ``origin`` is an ingress's (``req``, ``received``) for one
+        request (``serve/replica.py:request_origin``), which the spans of
+        its way carry. The returned handle shares this handle's router
+        state."""
         return DeploymentHandle(
             self._deployment,
             _router=self._router,
@@ -716,14 +742,17 @@ class DeploymentHandle:
                 if multiplexed_model_id is None else multiplexed_model_id
             ),
             _stream=self._stream if stream is None else stream,
+            _origin=self._origin if origin is None else origin,
         )
 
     def _call(self, method: str, args, kwargs) -> DeploymentResponse:
         from ray_tpu._private import faultpoints
         from ray_tpu._private.backoff import Backoff
         from ray_tpu._private.config import rt_config
+        from ray_tpu.util.debug import span
 
         model_id = self._multiplexed_model_id
+        origin = self._origin
         # Transparent failover is safe ONLY here: a submission that fails
         # in this frame never reached user code, so replaying it on
         # another replica cannot double-execute anything. Bounded and
@@ -734,16 +763,23 @@ class DeploymentHandle:
         retry = Backoff(base=0.05, cap=0.5)
         attempt = 0
         while True:
-            replica, key = self._router.pick(model_id or None)
+            # the router's pick, under a span of its own: all a caller
+            # without an ingress has, and beside an ingress's ``submit_ms``
+            # what tells the pick from the wait for an executor thread
+            with span("serve.route", req=origin[0] if origin else "",
+                      deployment=self._deployment) as route:
+                replica, key, picked = self._router.pick(model_id or None)
+                route.set_metadata(**picked)
             try:
                 if faultpoints.ACTIVE:
                     faultpoints.fire(
                         "serve.replica.call", err=ConnectionError
                     )
-                if model_id or self._stream:
+                if model_id or self._stream or origin:
                     ref = replica.handle_request.remote(
                         method, args, kwargs,
                         model_id=model_id or None, stream=self._stream,
+                        origin=origin,
                     )
                 else:
                     ref = replica.handle_request.remote(method, args, kwargs)

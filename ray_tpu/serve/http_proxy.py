@@ -21,14 +21,27 @@ Production semantics (reference: the proxy's request lifecycle):
 - **Streams fail loudly**: a mid-stream failure emits a terminal
   ``event: error`` SSE frame instead of silently truncating, and a client
   disconnect cancels the replica-side generator so its slot frees now.
+
+A request's way through here is on record. The handler mints ``req`` (the
+client's ``X-Request-Id`` where it sent one) and stamps ``received`` at its
+first line; both ride with the call (``handle.options(origin=)``) to the
+replica's ``serve.replica.call`` / ``serve.replica.pull`` and the
+deployment's ``llm.request`` / ``llm.done``. At its end, whatever the
+outcome, the handler leaves a ``serve.proxy.request`` span
+(``jax.profiler.TraceAnnotation``, inert unless a capture runs in this
+process; no span stays open across an ``await``), whose arguments are the
+request's ledger (``Ledger``), and ``ProxyBase.stats()`` sums the same parts
+with no capture.
 """
 from __future__ import annotations
 
 import asyncio
 import json
 import logging
+import os
 import time
-from typing import Dict, Optional
+import uuid
+from typing import Dict, Optional, Tuple
 
 logger = logging.getLogger(__name__)
 
@@ -59,24 +72,78 @@ def _error_status(e: BaseException):
     }[_classify_error(e)]
 
 
+class Ledger:
+    """What one request spent where in the proxy: seconds, each part taken
+    with ``time.monotonic()`` around the await it names, for the arguments
+    of ``serve.proxy.request`` and the sums of ``ProxyBase.stats()``."""
+
+    __slots__ = (
+        "req", "received", "inflight", "status", "stream", "route_fetched",
+        "route_s", "read_s", "submit_s", "register_s", "pulls",
+        "pull_wait_s", "write_s", "after_last_pull_s", "bytes")
+
+    def __init__(self, req: str, received: float, inflight: int):
+        self.req, self.received, self.inflight = req, received, inflight
+        # the status the client got; for a stream that failed after its
+        # 200 went out, the one its error maps to (the client got that as
+        # a terminal frame); 0 where the handler was cancelled
+        self.status = 0
+        self.stream = self.route_fetched = self.pulls = self.bytes = 0
+        self.route_s = self.read_s = self.submit_s = self.register_s = 0.0
+        self.pull_wait_s = self.write_s = self.after_last_pull_s = 0.0
+
+    @property
+    def origin(self) -> Tuple[str, float]:
+        """What rides with the call to the replica."""
+        return self.req, self.received
+
+    async def timed(self, part: str, awaitable):
+        """Await, and add the wait to ``part``."""
+        began = time.monotonic()
+        try:
+            return await awaitable
+        finally:
+            setattr(self, part,
+                    getattr(self, part) + time.monotonic() - began)
+
+
+def _ms(seconds: float) -> float:
+    return round(seconds * 1e3, 3)
+
+
 class ProxyBase:
     """Ingress-agnostic half of a serve proxy: route resolution with a
-    short cache, admission counters, and stream teardown. Both the HTTP
-    and gRPC proxies inherit it — the pieces live ONCE, with real `self`
-    ownership of the state they touch (each proxy renders rejections in
-    its own protocol)."""
+    short cache, admission counters, the request's id and ledger, and
+    stream teardown. Both the HTTP and gRPC proxies inherit it — the pieces
+    live ONCE, with real `self` ownership of the state they touch (each
+    proxy renders rejections in its own protocol)."""
 
     def __init__(self):
         # Admission control + observability counters (single event loop:
         # plain ints are race-free).
         self._inflight = 0
-        self._shed = 0
         self._handles: Dict[str, object] = {}
         self._routes_cache = (-10.0, {})
+        self._stats = dict.fromkeys(
+            ("requests", "streams", "shed", "errors", "route_fetches",
+             "pulls", "bytes"), 0)
+        self._stats.update(dict.fromkeys(
+            ("route_s", "submit_s", "register_s", "pull_wait_s", "write_s",
+             "total_s"), 0.0))
 
     def stats(self) -> dict:
-        """Live admission-control counters (bench/tests)."""
-        return {"inflight": self._inflight, "shed": self._shed}
+        """The proxy's ``engine.stats``: ``inflight`` now, and since the
+        start the sums of what each closed request's ``serve.proxy.request``
+        says of it (``_close``): ``requests``, of them ``streams``, ``shed``
+        (the in-flight cap's 503s) and ``errors`` (every other answer of 500
+        and above), ``route_fetches`` (requests that went to the controller
+        for the route table) and the seconds and counts of the ledger's
+        parts. Its readers: an operator (PERF.md section 3), and tier-1,
+        which holds each sum against the spans' arguments
+        (``tests/test_serve_path_spans.py``). The gRPC proxy closes no
+        ledger yet: of these it counts ``shed`` alone."""
+        return {"inflight": self._inflight, "pid": os.getpid(),
+                **self._stats}
 
     def _over_cap(self) -> bool:
         """Admission check: True when the request must be shed (counts
@@ -85,11 +152,50 @@ class ProxyBase:
 
         cap = int(rt_config.serve_max_inflight)
         if cap > 0 and self._inflight >= cap:
-            self._shed += 1
+            self._stats["shed"] += 1
             return True
         return False
 
-    def _route_for(self, path: str) -> Optional[str]:
+    def _open(self, client_id: Optional[str] = None) -> Ledger:
+        """A request enters: its id (the client's, or a short hex one
+        minted here) and the stamp of its arrival."""
+        return Ledger(client_id or uuid.uuid4().hex[:16], time.monotonic(),
+                      self._inflight)
+
+    def _close(self, led: Ledger, shed: bool = False) -> None:
+        """A request leaves, whatever its outcome: its ledger goes into the
+        sums and onto a ``serve.proxy.request`` span."""
+        from ray_tpu.util.debug import span
+
+        total = time.monotonic() - led.received
+        sums = self._stats
+        sums["requests"] += 1
+        sums["streams"] += led.stream
+        sums["errors"] += led.status >= 500 and not shed
+        sums["route_fetches"] += led.route_fetched
+        sums["pulls"] += led.pulls
+        sums["bytes"] += led.bytes
+        sums["total_s"] += total
+        for part in ("route_s", "submit_s", "register_s", "pull_wait_s",
+                     "write_s"):
+            sums[part] += getattr(led, part)
+
+        with span(
+            "serve.proxy.request", req=led.req, status=led.status,
+            stream=led.stream, inflight=led.inflight,
+            route_ms=_ms(led.route_s), route_fetched=led.route_fetched,
+            read_ms=_ms(led.read_s), submit_ms=_ms(led.submit_s),
+            register_ms=_ms(led.register_s), pulls=led.pulls,
+            pull_wait_ms=_ms(led.pull_wait_s), write_ms=_ms(led.write_s),
+            after_last_pull_ms=_ms(led.after_last_pull_s), bytes=led.bytes,
+            total_ms=_ms(total),
+        ):
+            pass
+
+    def _route_for(self, path: str) -> Tuple[Optional[str], int]:
+        """(the deployment behind ``path`` or None, 1 where this call went
+        to the controller for the route table and 0 where the cached one
+        answered)."""
         import ray_tpu
         from ray_tpu._private import faultpoints
         from ray_tpu.serve.controller import CONTROLLER_NAME
@@ -97,7 +203,11 @@ class ProxyBase:
         if faultpoints.ACTIVE:
             faultpoints.fire("serve.proxy.route", err=ConnectionError)
 
+        fetched = 0
+
         def fetch():
+            nonlocal fetched
+            fetched = 1
             routes = ray_tpu.get(
                 ray_tpu.get_actor(CONTROLLER_NAME).get_routes.remote(),
                 timeout=10,
@@ -123,7 +233,7 @@ class ProxyBase:
             # Miss on a warm cache: a route registered moments ago must
             # not 404 for the cache TTL — refetch once before giving up.
             found = match(fetch())
-        return found
+        return found, fetched
 
     def _close_stream(self, it):
         """Release the handle-side stream iterator (settles the router
@@ -165,9 +275,12 @@ class HTTPProxy(ProxyBase):
         from aiohttp import web
         from ray_tpu._private.config import rt_config
 
+        led = self._open(request.headers.get("X-Request-Id"))
         # Admission control: shed BEFORE any routing work. Saturation must
         # degrade to fast typed rejections, not queue collapse.
         if self._over_cap():
+            led.status = 503
+            self._close(led, shed=True)
             return web.Response(
                 status=503,
                 text=f"proxy saturated: {self._inflight} requests in "
@@ -177,19 +290,25 @@ class HTTPProxy(ProxyBase):
             )
         self._inflight += 1
         try:
-            return await self._handle_admitted(request)
+            resp = await self._handle_admitted(request, led)
+            led.status = led.status or resp.status
+            return resp
+        except Exception:
+            led.status = 500  # what aiohttp answers where the handler raises
+            raise
         finally:
             self._inflight -= 1
+            self._close(led)
 
-    async def _handle_admitted(self, request):
+    async def _handle_admitted(self, request, led: Ledger):
         from aiohttp import web
 
         loop = asyncio.get_running_loop()
         try:
             # The controller RPC blocks; keep it off the proxy event loop.
-            deployment = await loop.run_in_executor(
-                None, self._route_for, request.path
-            )
+            deployment, led.route_fetched = await led.timed(
+                "route_s", loop.run_in_executor(
+                    None, self._route_for, request.path))
         except Exception as e:
             # Route resolution is infra, not the app: a controller blip or
             # injected fault is a retryable 503, never a bare 500.
@@ -204,6 +323,7 @@ class HTTPProxy(ProxyBase):
         handle = self._handles.get(deployment)
         if handle is None:
             handle = self._handles[deployment] = DeploymentHandle(deployment)
+        began = time.monotonic()
         body = await request.read()
         payload = {
             "body": body,
@@ -224,37 +344,57 @@ class HTTPProxy(ProxyBase):
             )
         except json.JSONDecodeError:
             pass
+        led.read_s = time.monotonic() - began
+        led.stream = int(wants_stream)
         if wants_stream:
             return await self._handle_stream(
-                request, handle.options(stream=True), payload, loop
+                request, handle.options(stream=True, origin=led.origin),
+                payload, loop, led,
             )
         from ray_tpu._private.config import rt_config
 
         timeout = float(rt_config.serve_request_timeout_s)
+        handle = handle.options(origin=led.origin)
         try:
             # Submission may briefly block (router pick / controller
             # refresh): keep it off the loop. The WAIT is fully async —
             # parking a blocked executor thread per in-flight request
             # starves co-located replicas (all actors in a worker process
             # share one default executor) and deadlocks under bursts.
-            resp = await loop.run_in_executor(
+            resp = await led.timed("submit_s", loop.run_in_executor(
                 None, lambda: handle.remote(payload)
-            )
-            out = await resp.result_async(timeout)
+            ))
+            out = await led.timed("register_s", resp.result_async(timeout))
         except Exception as e:
-            status, retry_after = _error_status(e)
-            headers = {"Retry-After": retry_after} if retry_after else None
-            return web.Response(
-                status=status, text=f"{type(e).__name__}: {e}",
-                headers=headers,
-            )
-        if isinstance(out, (bytes, bytearray)):
-            return web.Response(body=bytes(out))
-        if isinstance(out, str):
-            return web.Response(text=out)
-        return web.json_response(out)
+            return self._error_response(e)
+        return self._plain_response(out, led)
 
-    async def _handle_stream(self, request, handle, payload, loop):
+    def _error_response(self, e: BaseException):
+        from aiohttp import web
+
+        status, retry_after = _error_status(e)
+        headers = {"Retry-After": retry_after} if retry_after else None
+        return web.Response(
+            status=status, text=f"{type(e).__name__}: {e}",
+            headers=headers,
+        )
+
+    def _plain_response(self, out, led: Ledger):
+        """A whole answer as one response, which aiohttp sends after the
+        handler returned: its bytes are on the ledger, its write is not."""
+        from aiohttp import web
+
+        if isinstance(out, (bytes, bytearray)):
+            resp = web.Response(body=bytes(out))
+        elif isinstance(out, str):
+            resp = web.Response(text=out)
+        else:
+            resp = web.json_response(out)
+        led.bytes = len(resp.body or b"")
+        return resp
+
+    async def _handle_stream(self, request, handle, payload, loop,
+                             led: Ledger):
         from aiohttp import web
         from ray_tpu._private.config import rt_config
 
@@ -269,10 +409,14 @@ class HTTPProxy(ProxyBase):
             # __anext__ applies the handle-side per-chunk deadline and
             # maps replica death to the typed retryable class; the outer
             # wait_for is the backstop if the pull itself wedges.
+            began = time.monotonic()
             try:
                 return await asyncio.wait_for(it.__anext__(), chunk_timeout)
             except StopAsyncIteration:
                 return done
+            finally:
+                led.pull_wait_s += time.monotonic() - began
+                led.pulls = it.pulls
 
         it = None
         try:
@@ -280,40 +424,31 @@ class HTTPProxy(ProxyBase):
             # stream registration wait and every chunk pull are async —
             # an open stream costs a coroutine, not a blocked executor
             # thread (co-located replicas share the executor).
-            gen = await loop.run_in_executor(
+            gen = await led.timed("submit_s", loop.run_in_executor(
                 None, lambda: handle.remote(payload)
-            )
+            ))
             # Registration (time-to-first-response) is bounded by the
             # REQUEST deadline like the unary path; only chunk pulls get
             # the longer streaming horizon.
-            out = await gen.result_async(
+            out = await led.timed("register_s", gen.result_async(
                 float(rt_config.serve_request_timeout_s)
-            )
+            ))
             if not isinstance(out, _StreamIterator):
                 # The deployment chose not to stream (e.g. stream=true
                 # with options the endpoint serves non-incrementally): a
                 # plain response comes back shaped like the unary path,
                 # not a broken SSE body.
-                if isinstance(out, (bytes, bytearray)):
-                    return web.Response(body=bytes(out))
-                if isinstance(out, str):
-                    return web.Response(text=out)
-                return web.json_response(out)
+                return self._plain_response(out, led)
             it = out
             first = await _next()
         except Exception as e:
             self._close_stream(it)
-            status, retry_after = _error_status(e)
-            headers = {"Retry-After": retry_after} if retry_after else None
-            return web.Response(
-                status=status, text=f"{type(e).__name__}: {e}",
-                headers=headers,
-            )
+            return self._error_response(e)
         resp = web.StreamResponse(headers={
             "Content-Type": "text/event-stream",
             "Cache-Control": "no-cache",
         })
-        await resp.prepare(request)
+        await led.timed("write_s", resp.prepare(request))
         chunk = first
         try:
             while chunk is not done:
@@ -323,7 +458,10 @@ class HTTPProxy(ProxyBase):
                     # generic generator deployments may yield objects:
                     # frame them as JSON lines rather than dropping them
                     chunk = (json.dumps(chunk) + "\n").encode()
+                began = time.monotonic()
                 await resp.write(chunk)
+                led.write_s += time.monotonic() - began
+                led.bytes += len(chunk)
                 chunk = await _next()
         except (ConnectionResetError, ConnectionError) as e:
             # CLIENT went away mid-stream: cancel the replica-side
@@ -338,6 +476,7 @@ class HTTPProxy(ProxyBase):
                            request.path, type(e).__name__, e)
             from ray_tpu.serve.handle import ServeRetryableError
 
+            led.status = _error_status(e)[0]
             frame = {
                 "error": type(e).__name__,
                 "message": str(e),
@@ -356,9 +495,13 @@ class HTTPProxy(ProxyBase):
         finally:
             self._close_stream(it)
         try:
-            await resp.write_eof()
+            await led.timed("write_s", resp.write_eof())
         except Exception as e:
             logger.debug("eof after disconnect: %s", e)
+        if it.drained_at:
+            # the return of the pull that said done -> the last byte
+            # handed to the socket
+            led.after_last_pull_s = time.monotonic() - it.drained_at
         return resp
 
     async def stop(self) -> bool:

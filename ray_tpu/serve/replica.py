@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import asyncio
 import inspect
+import os
 import time
 from typing import Any, Callable, Dict, List, Optional
 
@@ -46,6 +47,26 @@ def get_gang_context() -> Optional[GangContext]:
     return _gang_ctx_var.get()
 
 
+# What the ingress knows of a request, for the spans of its way
+# (``serve.replica.call``, ``serve.replica.pull``, ``llm.request``): the id
+# it minted (``req``) and ``time.monotonic()`` of the request's arrival
+# there (``received``; one clock for every process of a machine, and no
+# time at all across machines: the spans' ``since_received_ms`` is then
+# not to be read). It rides
+# with the call as ``model_id`` does: handle option ->
+# ``handle_request(origin=)`` -> this variable, restored in ``next_chunks``.
+NO_ORIGIN = ("", 0.0)  # a call that came through no ingress
+_origin_var: "_contextvars.ContextVar[tuple]" = _contextvars.ContextVar(
+    "rt_serve_request_origin", default=NO_ORIGIN
+)
+
+
+def request_origin() -> tuple:
+    """Inside a request: (``req``, ``received``) as the ingress stamped
+    them, ``NO_ORIGIN`` for a call that came through none."""
+    return _origin_var.get()
+
+
 class Replica:
     """Created via ray_tpu.remote with max_concurrency > 1 so requests
     overlap; ``_ongoing`` is the live load metric."""
@@ -67,7 +88,15 @@ class Replica:
                 _gang_ctx_var.reset(token)
         self._ongoing = 0
         self._total = 0
-        # live generator streams: stream_id -> [iter, last_access, model_id]
+        # what ``serve.replica.pull`` says of each pull, summed
+        self._pulls = 0
+        self._pull_turn_s = 0.0
+        self._pool_wait_s = 0.0
+        # live generator streams: stream_id -> [iter, last_active,
+        # model_id, origin, pulls]. ``last_active`` is time.monotonic() of
+        # the stream's registration, of its newest pull's arrival or of
+        # that pull's reply, whichever came last: the idle sweep and a
+        # pull's ``turn_ms`` read the one stamp.
         self._streams: Dict[str, list] = {}
         self._stream_seq = 0
         # A sync generator's body runs in a thread for as long as a pull
@@ -108,9 +137,14 @@ class Replica:
 
     @_actor_method(concurrency_group="control")
     def stats(self) -> dict:
+        """Live load, and the cumulative sums of ``serve.replica.pull``'s
+        arguments with no capture running (an operator's; tier-1 holds
+        each against the spans: ``tests/test_serve_path_spans.py``)."""
         return {"ongoing": self._ongoing, "total": self._total,
                 "streams": len(self._streams),
-                "draining": self._draining}
+                "draining": self._draining,
+                "pulls": self._pulls, "pull_turn_s": self._pull_turn_s,
+                "pool_wait_s": self._pool_wait_s, "pid": os.getpid()}
 
     @_actor_method(concurrency_group="control")
     def drain(self) -> dict:
@@ -131,10 +165,11 @@ class Replica:
 
     # ------------------------------------------------------------ streaming
 
-    def _register_stream(self, gen, model_id: Optional[str]) -> dict:
+    def _register_stream(self, gen, model_id: Optional[str],
+                         origin: tuple) -> dict:
         self._stream_seq += 1
         sid = f"s{self._stream_seq}"
-        self._streams[sid] = [gen, time.monotonic(), model_id]
+        self._streams[sid] = [gen, time.monotonic(), model_id, origin, 0]
         return {"__rt_stream__": sid}
 
     @_actor_method(concurrency_group="control")
@@ -186,7 +221,15 @@ class Replica:
     async def next_chunks(self, stream_id: str, max_n: int = 16):
         """Pull up to max_n chunks; returns (chunks, done). Abandoned
         streams are swept after 10 minutes idle; pulling a swept (or
-        unknown) stream raises instead of faking a clean end."""
+        unknown) stream raises instead of faking a clean end.
+
+        A pull that returns leaves a ``serve.replica.pull`` span, whose
+        arguments are its ledger: ``turn_ms`` from the stream's registration
+        or its previous reply to this pull's first line (two call legs and
+        the caller's turn between), ``pool_wait_ms`` the wait for a
+        ``rt-stream-pull`` thread, ``wait_ms`` inside the generator."""
+        from ray_tpu.util.debug import span
+
         now = time.monotonic()
         for sid in [
             s for s, rec in self._streams.items() if now - rec[1] > 600
@@ -198,8 +241,9 @@ class Replica:
                 f"stream {stream_id} unknown or expired (streams idle "
                 f">600s are swept); chunks may have been lost"
             )
-        gen, _, model_id = rec
+        gen, last_active, model_id, origin, _ = rec
         rec[1] = now
+        rec[4] += 1
         # The generator body runs in THIS task (async gen) or an executor
         # thread (sync gen), not the handle_request task that created the
         # stream — restore its request context here.
@@ -209,52 +253,77 @@ class Replica:
             from ray_tpu.serve.multiplex import _set_request_model_id
 
             _set_request_model_id(model_id)
+        _origin_var.set(origin)
         chunks: List[Any] = []
+        done = False
+        scheduled = began = now
         try:
             if inspect.isasyncgen(gen):
                 while len(chunks) < max_n:
                     try:
                         chunks.append(await gen.__anext__())
                     except StopAsyncIteration:
-                        self._streams.pop(stream_id, None)
-                        return chunks, True
+                        done = True
+                        break
             else:
                 import contextvars
 
                 loop = asyncio.get_running_loop()
 
                 def pull():
+                    began = time.monotonic()
                     out = []
                     try:
                         while len(out) < max_n:
                             out.append(next(gen))
                     except StopIteration:
-                        return out, True
-                    return out, False
+                        return out, True, began
+                    return out, False, began
 
                 call_ctx = contextvars.copy_context()
-                chunks, done = await loop.run_in_executor(
+                scheduled = time.monotonic()
+                chunks, done, began = await loop.run_in_executor(
                     self._stream_pulls, lambda: call_ctx.run(pull)
                 )
-                if done:
-                    self._streams.pop(stream_id, None)
-                return chunks, done
         except Exception:
             self._streams.pop(stream_id, None)
             raise
-        return chunks, False
+        if done:
+            self._streams.pop(stream_id, None)
+        # the reply leaves here: the next pull's turn starts
+        replied = rec[1] = time.monotonic()
+        turn, pool_wait = now - last_active, began - scheduled
+        self._pulls += 1
+        self._pull_turn_s += turn
+        self._pool_wait_s += pool_wait
+        with span(
+            "serve.replica.pull", req=origin[0], n=rec[4],
+            chunks=len(chunks), done=int(done),
+            turn_ms=round(turn * 1e3, 3),
+            pool_wait_ms=round(pool_wait * 1e3, 3),
+            wait_ms=round((replied - began) * 1e3, 3),
+        ):
+            return chunks, done
 
     async def handle_request(self, method: str, args, kwargs,
                              model_id: Optional[str] = None,
-                             stream: bool = False):
+                             stream: bool = False,
+                             origin: Optional[tuple] = None):
+        """One call of the deployment. ``origin`` is the ingress's (``req``,
+        ``received``) for the request (``request_origin``); the call leaves
+        a ``serve.replica.call`` span at its end, whatever its outcome."""
+        called = time.monotonic()
+        origin = origin or NO_ORIGIN
         if self._gang_ctx is not None:
             _gang_ctx_var.set(self._gang_ctx)
         if model_id is not None:
             from ray_tpu.serve.multiplex import _set_request_model_id
 
             _set_request_model_id(model_id)
+        _origin_var.set(origin)
         self._ongoing += 1
         self._total += 1
+        ongoing = self._ongoing
         try:
             if self._is_function:
                 fn = self._instance
@@ -263,13 +332,14 @@ class Replica:
             if inspect.isasyncgenfunction(fn) or (
                 stream and inspect.isgeneratorfunction(fn)
             ):
-                return self._register_stream(fn(*args, **kwargs), model_id)
+                return self._register_stream(
+                    fn(*args, **kwargs), model_id, origin)
             if inspect.iscoroutinefunction(fn) or (
                 hasattr(fn, "_is_serve_batch")
             ):
                 out = await fn(*args, **kwargs)
                 if stream and inspect.isgenerator(out):
-                    return self._register_stream(out, model_id)
+                    return self._register_stream(out, model_id, origin)
                 return out
             # Sync callables run on an executor thread: they may block (e.g.
             # a composition handle's .result()) and must not stall this
@@ -285,10 +355,28 @@ class Replica:
             if inspect.isawaitable(out):
                 out = await out
             if stream and inspect.isgenerator(out):
-                return self._register_stream(out, model_id)
+                return self._register_stream(out, model_id, origin)
             return out
         finally:
             self._ongoing -= 1
+            self._called(method, stream, origin, ongoing, called)
+
+    def _called(self, method: str, stream: bool, origin: tuple,
+                ongoing: int, called: float) -> None:
+        """The ``serve.replica.call`` span of a call that began at
+        ``called``: ``since_received_ms`` from the ingress's stamp to the
+        handler's first line (``handle.remote`` and the call's way here),
+        where an ingress stamped one; ``call_ms`` from there to now."""
+        from ray_tpu.util.debug import span
+
+        req, received = origin
+        ledger = {"call_ms": round((time.monotonic() - called) * 1e3, 3)}
+        if received:
+            ledger["since_received_ms"] = round(
+                (called - received) * 1e3, 3)
+        with span("serve.replica.call", req=req, method=method,
+                  stream=int(stream), ongoing=ongoing, **ledger):
+            pass
 
 
 class _BatchQueue:

@@ -135,6 +135,34 @@ def xla_profile_capture(duration_s: float = 3.0,
             "hint": "xprof / tensorboard --logdir <logdir>, or Perfetto"}
 
 
+class _NoSpan:
+    """What ``span`` hands out where no capture can run."""
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **args) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+def span(name: str, **args):
+    """A ``jax.profiler.TraceAnnotation`` for the serve path
+    (``serve/http_proxy.py``, ``handle.py``, ``replica.py``), inert unless a
+    capture runs. A process that has not loaded JAX runs no capture, and a
+    handle in a driver, or a deployment of plain Python, does not import it
+    (seconds) for a span nobody can record: there this is a context that
+    does nothing."""
+    annotation = getattr(
+        sys.modules.get("jax.profiler"), "TraceAnnotation", None)
+    return _NO_SPAN if annotation is None else annotation(name, **args)
+
+
 _compiles = 0
 _compiles_listening = False
 _compiles_lock = threading.Lock()
