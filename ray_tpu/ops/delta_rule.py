@@ -34,9 +34,10 @@ recurrence):
   that is <= 0, so a decay near 0 divides nothing. XLA on every platform.
 - ``delta_update`` (one token a slot: decode): the Pallas TPU kernel of
   ``ssm.visit_live`` (the whole state ``[L, B, H, Dk, Dv]`` in HBM, aliased
-  to its result, the live slots' states through VMEM one after another and
-  no other slot's) with this recurrence's step; ``delta_update_xla`` is the
-  same step over one layer's slice.
+  to its result, the live slots' states through VMEM in PIECES of a few
+  whole heads, a ring of ``ssm.DEPTH`` pieces each way, and no other
+  slot's) with this recurrence's step; ``delta_update_xla`` is the same
+  step over one layer's slice.
 
 ``GATED_DELTA`` is the three as ``models/kv_cache.py:recur`` takes a kind
 of state layer's recurrence: from what left the convolution (q, k and v
@@ -200,17 +201,17 @@ def held_shape(heads: int, Dk: int, Dv: int) -> tuple:
     return heads, Dk, -(-Dv // TILE) * TILE
 
 
-def _step(b, sbuf, obuf, buf, decay_ref, kab_ref, k_ref, q_ref, bv_ref,
+def _step(b, h0, sbuf, obuf, entry, decay_ref, kab_ref, k_ref, q_ref, bv_ref,
           y_ref):
-    for h in range(bv_ref.shape[1]):
+    for i in range(sbuf.shape[1]):
         # a head's decay, key and query lie down the sublanes of its
         # [Dk, Dv] tile, [Dk, 1]; its value along the lanes, [1, Dv]
-        head = slice(h, h + 1)
-        S = sbuf[buf, h]
+        head = slice(h0 + i, h0 + i + 1)
+        S = sbuf[entry, i]
         held = jnp.sum(kab_ref[b, :, head] * S, axis=0, keepdims=True)
         new = (decay_ref[b, :, head] * S
                + k_ref[b, :, head] * (bv_ref[b, head, :] - held))
-        obuf[buf, h] = new
+        obuf[entry, i] = new
         # the row is Dv wide: what the cache pads a head's values with
         # stays out of it
         y_ref[b, head, :] = jnp.sum(

@@ -34,10 +34,11 @@ The pieces, in the manner of ``ops/delta_rule.py``:
   (A whole chunk of 64 as one reference would need ``exp(315)``.) The
   product of the two factors is the true ratio, at most 1, whatever each is.
 - ``kda_update`` (one token a slot: decode): the kernel of
-  ``ssm.visit_live`` with the delta rule's own step (``delta_rule._step``
-  reads a head's decay down the sublanes of its tile: there the same number
-  ``Dk`` times, here a channel's own); ``kda_update_xla`` the same step over
-  one layer's slice.
+  ``ssm.visit_live`` (the live slots' states through VMEM in PIECES of a few
+  whole heads, a ring of ``ssm.DEPTH`` pieces each way) with the delta
+  rule's own step (``delta_rule._step`` reads a head's decay down the
+  sublanes of its tile: there the same number ``Dk`` times, here a
+  channel's own); ``kda_update_xla`` the same step over one layer's slice.
 
 ``KDA`` is the three as ``models/kv_cache.py:recur`` takes a kind of state
 layer's recurrence: from what left the convolution (q, k and v side by side)

@@ -28,12 +28,15 @@ oracle:
   custom call is a whole array: a layer's slice cut by the scan would be
   copied for it). The state stays in HBM; the layer index and the live slots
   ride as scalar-prefetch arguments and steer the kernel's own DMAs. It
-  visits the slots it is told decode (``decode_attention.live_slots``), one
-  slot's state in flight while the one before is computed on, and no other:
-  a slot that is not live starts no DMA in either direction, its state leaves
-  the call as it entered and its row of the result is zeros.
+  walks the slots it is told decode (``decode_attention.live_slots``) in
+  PIECES, a run of whole heads of one slot's state each (``heads_a_piece``:
+  from the state's shape alone), slot by slot and within a slot piece by
+  piece, through a ring of ``DEPTH`` pieces each way: the next pieces on
+  their way in and the last ones on their way back while one is computed
+  on. A slot that is not live starts no DMA in either direction, its state
+  leaves the call as it entered and its row of the result is zeros.
   ``ssm_update_xla`` is the same step over one layer's slice. The kernel's
-  way through the slots (``visit_live``) is any one-token step's; Mamba-2's
+  way through the pieces (``visit_live``) is any one-token step's; Mamba-2's
   own is ``_step``, and ``ops/delta_rule.py`` gives it another (which
   ``ops/kda.py`` runs with a decay a channel).
 
@@ -51,8 +54,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# of one slot's state in VMEM; two in flight and two on their way back
-STATE_BYTES = 4 << 20
+# of a piece, a run of whole heads of one live slot's state: what one DMA
+# moves and one turn of the kernel's walk computes on (``heads_a_piece``).
+# On the v5e (PR 54, ``scripts/time_state_update.py``) a slot's reads alone
+# run at 737 GB/s, its writes alone at 646, both together at the sum of the
+# two times and 3-6% over it: reads and writes gain nothing from running
+# together, only the body hides under them, and the fewer and longer the
+# DMAs the nearer to the sum. So a piece is a slot wherever a slot is within
+# this (2 MB in Granite and Ling, 2.95 in Olmo-Hybrid); half a slot a piece
+# costs a walk of two and more slots 1-2.5% and saves a lone slot's call,
+# whose body then hides too, a quarter.
+PIECE_BYTES = 3 << 20
+# pieces in the ring each way: reads set out DEPTH - 1 pieces ahead of the
+# one computed on, a write is waited for DEPTH pieces after it set out (one
+# read ahead leaves HBM waiting for a body: 2 loses 2-6% to 3; 4 and 6 read
+# as 3)
+DEPTH = 3
+# of the two rings together: half of what VMEM a v5e core has
+RING_BYTES = 64 << 20
 
 
 def conv(xbc, tail, weight, bias, real=None):
@@ -169,124 +188,160 @@ def ssm_update_xla(state, x, dt, A, Bm, Cm, live=None):
     return jnp.where(keep, y, 0.0), jnp.where(keep[..., None], new, state)
 
 
-def _visiting(body, operands: int):
-    """The kernel of a one-token step over a whole state ``[L, B, ...]``
+def heads_a_piece(heads: int, head_bytes: int) -> int:
+    """Whole heads of a slot's state that make one piece of the kernel's
+    walk: the largest divisor of ``heads`` whose piece stays within
+    ``PIECE_BYTES`` (one head where none does)."""
+    return max(hp for hp in range(1, heads + 1) if heads % hp == 0
+               and (hp == 1 or hp * head_bytes <= PIECE_BYTES))
+
+
+def _visiting(body, operands: int, hp: int, depth: int):
+    """The kernel of a one-token step over a whole state ``[L, B, H, ...]``
     that stays in HBM, whatever the step: the live slots' states come
-    through VMEM one after another, the next on its way in and the last on
-    its way back while ``body(b, sbuf, obuf, buf, *operands, y_ref)`` makes
-    slot ``b``'s new state ``obuf[buf]`` and its row of ``y_ref`` from
-    ``sbuf[buf]`` and the ``operands`` small arrays in VMEM. A slot that is
+    through VMEM in pieces of ``hp`` heads, slot by slot and within a slot
+    piece by piece, through rings of ``depth`` pieces. The reads of the
+    next ``depth - 1`` pieces are on their way in and the writes of the
+    last ``depth`` on their way back while ``body(b, h0, sbuf, obuf, entry,
+    *operands, y_ref)`` makes heads ``h0 .. h0 + hp - 1`` of slot ``b``'s
+    new state, ``obuf[entry]``, and their part of ``y_ref[b]`` from
+    ``sbuf[entry]`` and the ``operands`` small arrays in VMEM. A piece's
+    place in its slot is static (a head's operands are cut by a constant);
+    only the slot and the ring's entry are read at run time. A slot that is
     not live starts no DMA in either direction."""
 
     def kernel(layer_ref, live_ref, *refs):
         small = refs[:operands]
         s_hbm, y_ref, so_hbm, sbuf, obuf, read_sem, write_sem = refs[operands:]
         B = y_ref.shape[0]
+        pieces = s_hbm.shape[2] // hp       # of one slot
         visits = live_ref[B]    # the live slots' indices, then their count
         layer = layer_ref[0]
 
-        def read(v, buf):
-            return pltpu.make_async_copy(
-                s_hbm.at[layer, live_ref[v]], sbuf.at[buf], read_sem.at[buf])
+        def piece(v, p):
+            """Piece ``p`` of visit ``v``, ``p`` counted on through the
+            visits after (or back through those before) -> (its visit, its
+            first head, its ring entry)."""
+            dv, at = divmod(p, pieces)
+            return v + dv, at * hp, (v * pieces + p) % depth
 
-        def write(v, buf):
+        def read(v, p):
+            v, h0, entry = piece(v, p)
             return pltpu.make_async_copy(
-                obuf.at[buf], so_hbm.at[layer, live_ref[v]],
-                write_sem.at[buf])
+                s_hbm.at[layer, live_ref[v], h0:h0 + hp], sbuf.at[entry],
+                read_sem.at[entry])
+
+        def write(v, p):
+            v, h0, entry = piece(v, p)
+            return pltpu.make_async_copy(
+                obuf.at[entry], so_hbm.at[layer, live_ref[v], h0:h0 + hp],
+                write_sem.at[entry])
+
+        def when(cond, then):   # ``cond`` a Python bool where it is static
+            if cond is True:
+                then()
+            elif cond is not False:
+                pl.when(cond)(then)
 
         def visit(v, _):
-            buf = v % 2
-
-            @pl.when(v + 1 < visits)
-            def _():            # the next slot's state sets out
-                read(v + 1, 1 - buf).start()
-
-            read(v, buf).wait()
-
-            @pl.when(v >= 2)
-            def _():            # the state sent back two visits ago
-                write(v - 2, buf).wait()
-
-            body(live_ref[v], sbuf, obuf, buf, *small, y_ref)
-            write(v, buf).start()
+            b = live_ref[v]
+            for p in range(pieces):
+                ahead = p + depth - 1
+                # the piece ``depth - 1`` on sets out, into the entry the
+                # piece before this one was read from
+                when(ahead < pieces or v + ahead // pieces < visits,
+                     lambda: read(v, ahead).start())
+                read(v, p).wait()
+                # the entry this piece is written into: sent back ``depth``
+                # pieces ago
+                when(p >= depth or v * pieces + p >= depth,
+                     lambda: write(v, p - depth).wait())
+                body(b, p * hp, sbuf, obuf, piece(v, p)[2], *small, y_ref)
+                write(v, p).start()
             return 0
 
         # what no visit writes, a slot that is not live, leaves as zeros
         y_ref[...] = jnp.zeros(y_ref.shape, y_ref.dtype)
-
-        @pl.when(visits > 0)
-        def _():
-            read(0, 0).start()
-
+        for p in range(depth - 1):      # every first read before any wait
+            when(p // pieces < visits, lambda: read(0, p).start())
         jax.lax.fori_loop(0, visits, visit, 0)
-        for back in (2, 1):     # the last two visits' states
-
-            @pl.when(visits >= back)
-            def _():
-                write(visits - back, (visits - back) % 2).wait()
+        for back in range(depth, 0, -1):    # the last pieces' writes
+            when(visits * pieces >= back,
+                 lambda: write(visits, -back).wait())
 
     return kernel
 
 
 def visit_live(body, name: str, states, layer, live, operands, row,
-               interpret: bool = False):
+               interpret=False):
     """``body`` (``_visiting``) over layer ``layer`` of ``states``
-    [L, B, ...] float32, in place -> (y [B, *row] float32, states: the
+    [L, B, H, ...] float32, in place -> (y [B, *row] float32, states: the
     operand's own buffer). ``live`` (``decode_attention.live_slots``'
     [B + 1]; None: every slot) names the slots it visits; ``operands`` are
     the step's small arrays, each whole in VMEM. ``name`` is the custom
-    call's, which the benchmark's readers know it by."""
-    L, B, *slot = states.shape
+    call's, which the benchmark's readers know it by. ``interpret``: True,
+    or a ``pltpu.InterpretParams``, runs the kernel on the CPU."""
+    L, B, H, *head = states.shape
     f32 = jnp.float32
-    held = 4 * math.prod(slot)     # bytes of one slot's state
     if states.dtype != f32:
         raise ValueError(f"the kernel steps a float32 state, not "
                          f"{states.dtype}: the XLA path's ({name}_xla)")
-    if held > STATE_BYTES:
+    head_bytes = 4 * math.prod(head)
+    hp = heads_a_piece(H, head_bytes)
+    rings = 2 * DEPTH * hp * head_bytes
+    if rings > RING_BYTES:
         raise ValueError(
-            f"a slot's state of {' x '.join(map(str, slot))} is held in "
-            f"VMEM whole, four at a time: more than {STATE_BYTES} bytes is "
-            "not")
+            f"a head's state of {' x '.join(map(str, head))} goes through "
+            f"VMEM whole, {2 * DEPTH} at a time: more than {RING_BYTES} "
+            "bytes in all is not")
     if live is None:        # slots 0 .. B - 1, and B of them
         live = jnp.arange(B + 1, dtype=jnp.int32)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
-        _visiting(body, len(operands)),
+        _visiting(body, len(operands), hp, DEPTH),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(1,),
             in_specs=[vmem] * len(operands) + [hbm],
             out_specs=[vmem, hbm],
             scratch_shapes=[
-                pltpu.VMEM((2, *slot), f32),
-                pltpu.VMEM((2, *slot), f32),
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((DEPTH, hp, *head), f32),
+                pltpu.VMEM((DEPTH, hp, *head), f32),
+                pltpu.SemaphoreType.DMA((DEPTH,)),
+                pltpu.SemaphoreType.DMA((DEPTH,)),
             ],
         ),
         out_shape=[jax.ShapeDtypeStruct((B, *row), f32),
                    jax.ShapeDtypeStruct(states.shape, f32)],
         # operands count from the scalar-prefetch arguments on
         input_output_aliases={2 + len(operands): 1},
-        # four states held, and the small operands, whose rows pad to
-        # whole tiles
+        # the two rings, and the small operands, whose rows pad to whole
+        # tiles
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=4 * held + (32 << 20)),
+            vmem_limit_bytes=rings + (32 << 20)),
         name=name,
         interpret=interpret,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), live.astype(jnp.int32),
       *operands, states)
 
 
-def _step(b, sbuf, obuf, buf, decay_ref, xdt_ref, b_ref, c_ref, y_ref):
+def _step(b, h0, sbuf, obuf, entry, decay_ref, xdt_ref, b_ref, c_ref,
+          y_ref):
     heard, said = b_ref[b], c_ref[b]                      # [1, N]
-    for h in range(decay_ref.shape[-1]):
-        # a head's decay and its dt * x lie down the sublanes: [P, 1]
-        new = (decay_ref[b, :, h:h + 1] * sbuf[buf, h]
-               + xdt_ref[b, :, h:h + 1] * heard)
-        obuf[buf, h] = new
-        y_ref[b, :, h:h + 1] = jnp.sum(new * said, axis=-1, keepdims=True)
+    heads = [(i, slice(h0 + i, h0 + i + 1)) for i in range(sbuf.shape[1])]
+    # Two passes over the piece. A head's decay and its dt * x lie down the
+    # sublanes, [P, 1], and are spread along the lanes; its rows are summed
+    # across the lanes: two trips through the same unit, and in ONE pass a
+    # head's sum waits for its spread and the next head's spread for that
+    # sum (7.6 us a slot's 64 heads on the v5e, where the passes apart take
+    # 2.1 + 1.0).
+    for i, head in heads:
+        obuf[entry, i] = (decay_ref[b, :, head] * sbuf[entry, i]
+                          + xdt_ref[b, :, head] * heard)
+    for i, head in heads:
+        y_ref[b, :, head] = jnp.sum(obuf[entry, i] * said, axis=-1,
+                                    keepdims=True)
 
 
 def ssm_update(states, layer, x, dt, A, Bm, Cm, *, live=None,

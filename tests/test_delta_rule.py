@@ -11,11 +11,13 @@ import pytest
 
 from ray_tpu.ops import delta_rule, ssm
 from ray_tpu.ops.decode_attention import live_slots
+from tests.test_ssm import LATE, _scattered
 
 H, DK, DV = 3, 8, 16
 
 
-def _inputs(B, T, seed=0, dtype=jnp.float32, decay=(1e-3, 3.0), beta=2.0):
+def _inputs(B, T, seed=0, dtype=jnp.float32, decay=(1e-3, 3.0), beta=2.0,
+            H=H):
     """Keys and queries of unit length, ``beta`` up to its bound of 2 (past
     1 an eigenvalue of a step turns negative), log decays from -0.001 (a
     step that keeps nearly everything) to -3 (one that keeps a twentieth),
@@ -143,8 +145,8 @@ def test_scan_in_bfloat16_stays_near_the_float32_recurrence():
         jnp.abs(want_s).max())
 
 
-def _step_inputs(B, seed=5):
-    q, k, v, g, b, _ = _inputs(B, 1, seed=seed)
+def _step_inputs(B, seed=5, H=H):
+    q, k, v, g, b, _ = _inputs(B, 1, seed=seed, H=H)
     return tuple(a[:, 0] for a in (q, k, v, g, b))
 
 
@@ -180,6 +182,62 @@ def test_update_kernel_equals_the_xla_step_and_skips_idle_slots(live):
         if not alive:
             assert bool((out[1, b] == states[1, b]).all())
             assert bool((o[b] == 0).all())
+
+
+@pytest.mark.parametrize("heads, a_piece", [(30, 5), (7, 1), (3, 3)])
+@pytest.mark.parametrize("count", [
+    0, 1, 2, ssm.DEPTH, ssm.DEPTH + 1, None])
+def test_update_kernel_walks_the_live_slots_in_pieces(
+        count, heads, a_piece, monkeypatch):
+    """Live sets of 0, 1, 2, ``DEPTH``, ``DEPTH + 1`` and all slots, in
+    scattered order, with Olmo-Hybrid's 30 heads (a count with no power of
+    two in it: six pieces of five), a prime count (one head a piece) and a
+    slot that is one piece: the states and rows are the XLA step's, a slot
+    that is not live is the bits it was and its row zeros, and so is every
+    other layer."""
+    B = ssm.DEPTH + 2
+    monkeypatch.setattr(ssm, "PIECE_BYTES", 5 * DK * DV * 4)
+    assert ssm.heads_a_piece(heads, DK * DV * 4) == a_piece
+    states = jnp.asarray(np.random.default_rng(9).normal(
+        size=(2, B, heads, DK, DV)), jnp.float32)
+    step = _step_inputs(B, seed=10, H=heads)
+    live, mask = _scattered(count, B, seed=heads)
+    want_o, want_s = delta_rule.delta_update_xla(states[1], *step, mask)
+    o, out = delta_rule.delta_update(states, jnp.int32(1), *step, live=live,
+                                     interpret=LATE)
+    # alpha and beta folded into the key and the value first: 5e-7
+    np.testing.assert_allclose(o, want_o, atol=1e-5)
+    np.testing.assert_allclose(out[1], want_s, atol=1e-5)
+    assert np.array_equal(out[0], states[0])
+    idle = ~np.asarray(mask)
+    assert np.array_equal(out[1][idle], states[1][idle])
+    assert not np.asarray(o)[idle].any()
+
+
+@pytest.mark.parametrize("piece, depth", [(1, 2), (1, 4), (3, 3), (5, 8),
+                                          (15, 4)])
+def test_the_walks_grain_and_depth_change_no_bit(piece, depth, monkeypatch):
+    """Whatever the piece and the ring, 30 heads' states and rows are bit
+    for bit those of the walk that moves a slot's state whole, one read
+    ahead and two writes behind (the kernel before it walked in pieces)."""
+    B, heads = 5, 30
+    states = jnp.asarray(np.random.default_rng(11).normal(
+        size=(2, B, heads, DK, DV)), jnp.float32)
+    step = _step_inputs(B, seed=12, H=heads)
+    live, _ = _scattered(3, B, seed=13)
+
+    def run(a_piece, ring):
+        monkeypatch.setattr(ssm, "PIECE_BYTES", a_piece * DK * DV * 4)
+        monkeypatch.setattr(ssm, "DEPTH", ring)
+        return delta_rule.delta_update(states, jnp.int32(1), *step,
+                                       live=live, interpret=LATE)
+
+    want_o, want_s = run(heads, 2)
+    o, out = run(piece, depth)
+    assert np.array_equal(np.asarray(o).view(np.uint32),
+                          np.asarray(want_o).view(np.uint32))
+    assert np.array_equal(np.asarray(out).view(np.uint32),
+                          np.asarray(want_s).view(np.uint32))
 
 
 def test_the_cache_holds_values_up_to_a_lane_tile_and_the_rest_stays_zero():

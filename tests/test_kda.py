@@ -10,6 +10,7 @@ import pytest
 
 from ray_tpu.ops import delta_rule, kda, ssm
 from ray_tpu.ops.decode_attention import live_slots
+from tests.test_ssm import LATE, _scattered
 
 H, DK, DV = 3, 8, 16
 LOWER = -5.0    # the published lower bound of a step's log decay
@@ -160,6 +161,49 @@ def test_update_kernel_equals_the_xla_step_and_skips_idle_slots(live):
         if not on:
             assert np.array_equal(new[1, slot], states[1, slot])
             assert not np.asarray(o[slot]).any()
+
+
+@pytest.mark.parametrize("a_piece, depth", [(1, ssm.DEPTH), (3, ssm.DEPTH),
+                                            (1, 2)])
+@pytest.mark.parametrize("count", [
+    0, 1, 2, ssm.DEPTH, ssm.DEPTH + 1, None])
+def test_update_kernel_walks_the_live_slots_in_pieces(
+        count, a_piece, depth, monkeypatch):
+    """Live sets of 0, 1, 2, ``DEPTH``, ``DEPTH + 1`` and all slots, in
+    scattered order, a slot in three pieces of a head and in one, through
+    the ring as it is and through one of two: the states and rows are the
+    XLA step's AND bit for bit those of the walk that moves a slot's state
+    whole, one read ahead and two writes behind (the kernel before it walked
+    in pieces); a slot that is not live is the bits it was and its row
+    zeros, and so is the other layer. The TPU interpreter runs a DMA when
+    it is WAITED for: a wait that is missing shows as wrong numbers."""
+    B = ssm.DEPTH + 2
+    q, k, v, g, b, _ = _inputs(B, 1, seed=10)
+    step = tuple(a[:, 0] for a in (q, k, v, g, b))
+    states = jnp.asarray(np.random.default_rng(11).normal(
+        size=(2, B, H, DK, DV)), jnp.float32)
+    live, mask = _scattered(count, B, seed=12)
+    idle = ~np.asarray(mask)
+
+    def run(heads, ring):
+        monkeypatch.setattr(ssm, "PIECE_BYTES", heads * DK * DV * 4)
+        monkeypatch.setattr(ssm, "DEPTH", ring)
+        assert ssm.heads_a_piece(H, DK * DV * 4) == heads
+        return kda.kda_update(states, jnp.int32(0), *step,
+                              live=live, interpret=LATE)
+
+    want_o, want_s = kda.kda_update_xla(states[0], *step, mask)
+    whole_o, whole_s = run(H, 2)
+    o, new = run(a_piece, depth)
+    np.testing.assert_allclose(o, want_o, atol=1e-6)
+    np.testing.assert_allclose(new[0], want_s, atol=1e-6)
+    assert np.array_equal(np.asarray(o).view(np.uint32),
+                          np.asarray(whole_o).view(np.uint32))
+    assert np.array_equal(np.asarray(new).view(np.uint32),
+                          np.asarray(whole_s).view(np.uint32))
+    assert np.array_equal(new[1], states[1])
+    assert np.array_equal(new[0][idle], states[0][idle])
+    assert not np.asarray(o)[idle].any()
 
 
 def test_the_kernel_steps_a_float32_state_and_refuses_any_other():

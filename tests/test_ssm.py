@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops import ssm
 from ray_tpu.ops.decode_attention import live_slots
@@ -136,6 +137,125 @@ def test_update_kernel_equals_the_xla_step_and_skips_idle_slots(live):
         if not alive:
             assert bool((out[1, b] == states[1, b]).all())
             assert bool((y[b] == 0).all())
+
+
+def _scattered(count, B, seed):
+    """``live_slots``' [B + 1] for ``count`` slots (None: all) in no order:
+    the walk takes them as they are named."""
+    slots = np.random.default_rng(seed).permutation(B)[
+        :B if count is None else count]
+    live = np.zeros(B + 1, np.int32)
+    live[:len(slots)], live[B] = slots, len(slots)
+    mask = np.zeros(B, bool)
+    mask[slots] = True
+    return jnp.asarray(live), jnp.asarray(mask)
+
+
+# the TPU interpreter runs a DMA when it is WAITED for: a piece computed on
+# before its read's wait, or a ring entry written over before its write's,
+# shows as wrong numbers
+LATE = pltpu.InterpretParams()
+
+
+@pytest.mark.parametrize("heads", [1, 2, 4])
+@pytest.mark.parametrize("count", [
+    0, 1, 2, ssm.DEPTH, ssm.DEPTH + 1, None])
+def test_update_kernel_walks_the_live_slots_in_pieces(
+        count, heads, monkeypatch):
+    """Live sets of 0, 1, 2, ``DEPTH``, ``DEPTH + 1`` and all slots, in
+    scattered order, a slot in pieces of one, two and all four of its heads
+    (4, 2 and 1 pieces: a ring reaches into the next slot, or the one after
+    it): the states and rows are the XLA step's, a slot that is not live is
+    the bits it was and its row zeros, and so is every other layer."""
+    B = ssm.DEPTH + 3
+    monkeypatch.setattr(ssm, "PIECE_BYTES", heads * P * N * 4)
+    assert ssm.heads_a_piece(H, P * N * 4) == heads
+    states = jnp.asarray(np.random.default_rng(6).normal(
+        size=(3, B, H, P, N)), jnp.float32)
+    x, dt, A, Bm, Cm = _step_inputs(B)
+    live, mask = _scattered(count, B, seed=10 + heads)
+    want_y, want_s = ssm.ssm_update_xla(states[1], x, dt, A, Bm, Cm, mask)
+    y, out = ssm.ssm_update(states, jnp.int32(1), x, dt, A, Bm, Cm,
+                            live=live, interpret=LATE)
+    np.testing.assert_allclose(y, want_y, atol=1e-5)
+    np.testing.assert_allclose(out[1], want_s, atol=1e-6)
+    assert np.array_equal(out[0], states[0])
+    assert np.array_equal(out[2], states[2])
+    idle = ~np.asarray(mask)
+    assert np.array_equal(out[1][idle], states[1][idle])
+    assert not np.asarray(y)[idle].any()
+
+
+@pytest.mark.parametrize("piece, depth", [(1, 2), (1, 4), (2, 3), (2, 8),
+                                          (4, 4)])
+def test_the_walks_grain_and_depth_change_no_bit(piece, depth, monkeypatch):
+    """Same bytes moved, same sums, another schedule: whatever the piece
+    and the ring, the kernel's states and rows are bit for bit those of the
+    walk that moves a slot's state whole, one read ahead and two writes
+    behind (what the kernel did before it walked in pieces)."""
+    B = 6
+    states = jnp.asarray(np.random.default_rng(11).normal(
+        size=(2, B, H, P, N)), jnp.float32)
+    step = _step_inputs(B, seed=12)
+    live, _ = _scattered(4, B, seed=13)
+
+    def run(heads, ring):
+        monkeypatch.setattr(ssm, "PIECE_BYTES", heads * P * N * 4)
+        monkeypatch.setattr(ssm, "DEPTH", ring)
+        return ssm.ssm_update(states, jnp.int32(0), *step, live=live,
+                              interpret=LATE)
+
+    want_y, want_s = run(H, 2)
+    y, out = run(piece, depth)
+    assert np.array_equal(np.asarray(y).view(np.uint32),
+                          np.asarray(want_y).view(np.uint32))
+    assert np.array_equal(np.asarray(out).view(np.uint32),
+                          np.asarray(want_s).view(np.uint32))
+
+
+def test_heads_a_piece_follows_from_the_states_shape_alone():
+    """The largest divisor of the heads whose piece stays within
+    ``PIECE_BYTES``: the three cells' states (a slot is one piece in each:
+    the longest DMAs measured best), a state of twice Granite's heads, a
+    head count that is prime, and a head that is over the bound by itself."""
+    assert ssm.PIECE_BYTES == 3 << 20
+    assert ssm.heads_a_piece(64, 64 * 128 * 4) == 64     # Granite
+    assert ssm.heads_a_piece(32, 128 * 128 * 4) == 32    # Ling
+    assert ssm.heads_a_piece(30, 96 * 256 * 4) == 30     # Olmo-Hybrid
+    assert ssm.heads_a_piece(128, 64 * 128 * 4) == 64
+    assert ssm.heads_a_piece(37, 96 * 256 * 4) == 1
+    assert ssm.heads_a_piece(7, 96 * 256 * 4) == 7
+    assert ssm.heads_a_piece(8, 2 << 20) == 1
+    assert ssm.heads_a_piece(3, 4 << 20) == 1
+
+
+def test_a_slot_need_not_fit_vmem_whole_but_a_ring_of_heads_must():
+    """A slot's state of 4.7 MB (more than the 4 MB the kernel once held
+    whole, four times over) goes through in three pieces of three 512 KB
+    heads; a head that ``2 x DEPTH`` of do not fit beside each other is
+    refused."""
+    heads, rows, lanes = 9, 512, 256
+    states = jnp.asarray(np.random.default_rng(14).normal(
+        size=(1, 2, heads, rows, lanes)), jnp.float32)
+    assert states[0, 0].nbytes > 4 << 20
+    rng = np.random.default_rng(15)
+    x = jnp.asarray(rng.normal(size=(2, heads, rows)), jnp.float32)
+    dt = jnp.asarray(rng.uniform(0.01, 0.1, (2, heads)), jnp.float32)
+    A = -jnp.asarray(rng.uniform(1, 16, (heads,)), jnp.float32)
+    Bm, Cm = (jnp.asarray(rng.normal(size=(2, lanes)), jnp.float32)
+              for _ in range(2))
+    live, mask = _scattered(1, 2, seed=16)
+    want_y, want_s = ssm.ssm_update_xla(states[0], x, dt, A, Bm, Cm, mask)
+    y, out = ssm.ssm_update(states, jnp.int32(0), x, dt, A, Bm, Cm,
+                            live=live, interpret=LATE)
+    np.testing.assert_allclose(y, want_y, atol=1e-4)    # sums of 256
+    np.testing.assert_allclose(out[0], want_s, atol=1e-6)
+    wide = ssm.RING_BYTES // (2 * ssm.DEPTH * 4 * 128) + 8
+    with pytest.raises(ValueError, match="goes through VMEM whole"):
+        ssm.ssm_update(jnp.zeros((1, 1, 1, wide, 128), jnp.float32),
+                       jnp.int32(0), x[:1, :1, :1].repeat(wide, 2),
+                       dt[:1, :1], A[:1], Bm[:1, :128], Cm[:1, :128],
+                       interpret=True)
 
 
 def test_one_step_is_the_recurrence_of_one_token():

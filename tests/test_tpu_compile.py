@@ -507,6 +507,25 @@ def test_trinity_programs_fit_the_chip_and_keep_both_caches_in_place(
         assert "f32[1,1,200192]" in text.split("\n", 1)[0]
 
 
+def _walks_in_pieces(updates, heads, head_bytes):
+    """Each of a one-token state kernel's calls declares, as its
+    ``vmem_limit_bytes``, the two rings of ``DEPTH`` pieces it asks for as
+    scratch (``ops/ssm.py:visit_live``; a piece is at most ``PIECE_BYTES``,
+    a slot whole in the three cells) and 32 MB for its small operands, and
+    the compiler fitted the kernel in."""
+    import re
+
+    from ray_tpu.ops import ssm
+
+    piece = head_bytes * ssm.heads_a_piece(heads, head_bytes)
+    rings = 2 * ssm.DEPTH * piece
+    assert updates and piece <= ssm.PIECE_BYTES
+    for call in updates:
+        declared = int(re.search(
+            r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', call).group(1))
+        assert rings < declared == rings + (32 << 20)
+
+
 # ---------------------------------- Granite 4.0-H Micro's engine programs
 # The seventh cell's size: the whole model, 48 slots
 # (``benchmarks/configs/granite-4.0-h-micro.json``).
@@ -566,6 +585,7 @@ def test_granite_programs_fit_the_chip_and_step_the_state_in_place(
         assert len(updates) == 9
         assert all(f"s32[{slots + 1}]" in c
                    and f"f32[36,{slots},64,64,128]" in c for c in updates)
+        _walks_in_pieces(updates, 64, 64 * 128 * 4)
         assert len([c for c in calls
                     if re.match(r"\s*%?decode_attention", c)]) == 1
         # the rows are not repacked around a layer's write
@@ -643,6 +663,7 @@ def test_olmo_hybrid_programs_fit_the_chip_and_step_the_state_in_place(
         assert len(updates) == 3
         assert all(f"s32[{slots + 1}]" in c
                    and f"f32[12,{slots},30,96,256]" in c for c in updates)
+        _walks_in_pieces(updates, 30, 96 * 256 * 4)
         assert len([c for c in calls
                     if re.match(r"\s*%?decode_attention", c)]) == 1
         assert _weight_converts(text, args[0]) == []
@@ -733,6 +754,7 @@ def test_ling_programs_fit_the_chip_and_keep_states_and_latents_in_place(
         assert len(updates) == 2
         assert all(f"s32[{slots + 1}]" in c
                    and f"f32[5,{slots},32,128,128]" in c for c in updates)
+        _walks_in_pieces(updates, 32, 128 * 128 * 4)
         latent = [c for c in calls
                   if re.match(r"\s*%?latent_decode_attention", c)]
         assert len(latent) == 1
