@@ -45,6 +45,7 @@ FAMILIES = {
     "granite_hybrid": "ray_tpu.models.granite_hybrid",
     "olmo_hybrid": "ray_tpu.models.olmo_hybrid",
     "bailing_hybrid": "ray_tpu.models.bailing_hybrid",
+    "joyai_llm_flash": "ray_tpu.models.joyai_llm_flash",
 }
 
 
@@ -80,6 +81,8 @@ MOE_KEYS = {
     "moe_num_held": "num_held", "moe_first_held": "first_held",
     "moe_dropless": "dropless",
     "moe_n_group": "n_group", "moe_topk_group": "topk_group",
+    "moe_bias_update_rate": "bias_update_rate",
+    "moe_aux_loss_weight": "aux_loss_weight",
 }
 
 

@@ -42,6 +42,10 @@ one signature across families:
   the gate, the output projection and the residual;
   ``state_leaves(config)`` names such a cache's leaves: name -> (shape a
   slot, dtype);
+- ``second_loss(config, params, x, targets, mask, aux, mesh)`` and
+  ``step_rule(config, params, updates, counted)``: a prediction layer's loss
+  behind the trunk, and leaves that a rule moves in the optimizer's place
+  (the defaults below: neither);
 - ``final_norm(config, params, x)``, ``head(config, params, x)`` -> float32
   logits, ``head_weight(params)`` -> the [V, E] matrix the chunked
   cross-entropy multiplies by;
@@ -84,7 +88,12 @@ from jax.sharding import Mesh
 
 from ray_tpu.models import kv_cache, module_for
 from ray_tpu.ops.attention import attention
-from ray_tpu.parallel.moe import aux_loss_of, aux_zero, stacked_for
+from ray_tpu.parallel.moe import (
+    aux_loss_of,
+    aux_zero,
+    counts_apart,
+    stacked_for,
+)
 
 
 
@@ -164,11 +173,30 @@ def at_input(config, kind, layer, x, stacked):
     return None
 
 
+def second_loss(config, params, x, targets, mask, aux, mesh):
+    """The piece's default: no loss but the next token's. A family with a
+    prediction layer behind the trunk gives its own: x [B, T, E] the last
+    layer's output before the final norm, ``targets`` [B, T] each position's
+    next token, ``mask`` the batch's or None, ``aux`` what the trunk's layers
+    added up -> ``aux`` with its layers' in it and, weighted, its loss as
+    ``second_loss`` (``moe.aux_loss_of`` adds it to what is minimised)."""
+    return aux
+
+
+def step_rule(config, params, updates, counted):
+    """The piece's default: every leaf moves by the optimizer's update. A
+    family whose leaves a rule moves (a router's bias under a balancing
+    rule) gives its own: the optimizer's ``updates`` and what the step
+    counted beside its loss -> (the updates with the rule's in their place,
+    what is reported of the counts)."""
+    return updates, counted
+
+
 # what a family module hands on under its own name (``gpt2.forward``,
 # ``module_for(cfg).loss_fn``): each the one definition here
 __all__ = ["forward_features", "forward", "init_kv_cache", "forward_cached",
            "forward_pipelined", "loss_fn", "count_params", "Layer", "Segment",
-           "single_kind", "periods", "at_input"]
+           "single_kind", "periods", "at_input", "second_loss", "step_rule"]
 
 
 def _remat_policy(config):
@@ -262,9 +290,13 @@ def _body(config, mesh: Optional[Mesh], pos, kind: Layer = Layer(),
         if kind.state is not None:
             x = _recur(config, kind, layer, x, None)[0]
         elif kind.latent is not None:
+            # the full forward attends up-projected keys and values, a head
+            # its own, through the dispatcher (the flash kernels on the
+            # chip: no [T, T] scores): the keys as wide as q, the values not
             q, rows, up = family.qkv(config, kind.name, layer, x, pos)
-            x = family.attn_out(config, layer, x, kv_cache.latent_attention(
-                q, rows, up.astype(q.dtype), q.shape[-1] ** -0.5))
+            k, v = kv_cache.latent_kv(rows, up.astype(q.dtype), q.shape[-1])
+            x = family.attn_out(config, layer, x, _attention_dispatch(
+                config, q, k, v, mesh))
         else:
             q, k, v = family.qkv(config, kind.name, layer, x, pos)
             if q.ndim == 5:  # [B, T, KV, G, D]: G query heads a kv head
@@ -338,19 +370,13 @@ def _scanned(period, carry, xs, repeats: int):
     return jax.lax.scan(period, carry, xs)
 
 
-def forward_features(
-    params: Dict[str, Any],
-    tokens: jax.Array,
-    config,
-    mesh: Optional[Mesh] = None,
-    rng: Optional[jax.Array] = None,
-) -> tuple:
-    """tokens [B, T] int32 → (final-trunk features [B, T, E], aux loss: the
-    layers' summed, in the form their ``ffn`` gives it).
-    The loss path consumes features directly (vocab-chunked cross entropy,
-    ``ops/xent.py``) so the [B, T, V] logits tensor never materializes.
-    ``rng``: optional key enabling stochastic layers (MoE router jitter),
-    one key a layer."""
+def _trunk(params, tokens, config, mesh, rng) -> tuple:
+    """tokens [B, T] int32 -> (the last layer's output [B, T, E], not yet
+    normed; aux loss: the layers' summed, in the form their ``ffn`` gives
+    it). Where a router keeps its counts over all experts for a rule
+    (``moe_counts`` in a layer's aux: ``moe.MoEConfig.bias_update_rate``)
+    they are not summed: ``aux["moe_counts"]`` is [routed layers, experts],
+    in the order of the experts' stack."""
     x, pos = _embed(params, tokens, config)
     segments, experts = module_for(config).layers(
         config, params["blocks"], cached=False)
@@ -364,6 +390,7 @@ def forward_features(
         # a scan reads the stack by a traced index: cast once, not a layer
         scanned = stacked_for(experts, config.dtype)
     layers_before = 0
+    every_count = []
     for (kinds, layers, repeats), routed in zip(segments, routed_at):
         # one repeat is no scan: every layer's place in the stack is known
         bodies = [
@@ -381,14 +408,40 @@ def forward_features(
         def period(carry, xs, bodies=bodies, routed=routed, stack=stack):
             x, aux, repeat = carry
             layers, *rngs = xs
+            counts = []
             for j, body in enumerate(bodies):
                 stacked = routed[j] and (stack, _nth(repeat, *routed[j]))
                 x, layer_aux = body(
                     x, layers[j], rngs[0][j] if rngs else None, stacked)
+                layer_aux, layer_counts = counts_apart(layer_aux)
+                if layer_counts is not None:
+                    counts.append(layer_counts)
                 aux = jax.tree.map(jnp.add, aux, layer_aux)
-            return (x, aux, repeat + 1), None
+            return (x, aux, repeat + 1), (
+                jnp.stack(counts) if counts else None)
 
-        (x, aux, _), _ = _scanned(period, (x, aux, 0), xs, repeats)
+        (x, aux, _), counts = _scanned(period, (x, aux, 0), xs, repeats)
+        if counts is not None:
+            every_count.append(counts.reshape(-1, counts.shape[-1]))
+    if every_count:
+        aux = {**aux, "moe_counts": jnp.concatenate(every_count)}
+    return x, aux
+
+
+def forward_features(
+    params: Dict[str, Any],
+    tokens: jax.Array,
+    config,
+    mesh: Optional[Mesh] = None,
+    rng: Optional[jax.Array] = None,
+) -> tuple:
+    """tokens [B, T] int32 → (final-trunk features [B, T, E], aux loss: the
+    layers' summed, in the form their ``ffn`` gives it).
+    The loss path consumes features directly (vocab-chunked cross entropy,
+    ``ops/xent.py``) so the [B, T, V] logits tensor never materializes.
+    ``rng``: optional key enabling stochastic layers (MoE router jitter),
+    one key a layer."""
+    x, aux = _trunk(params, tokens, config, mesh, rng)
     return module_for(config).final_norm(config, params, x), aux
 
 
@@ -567,7 +620,10 @@ def loss_fn(
     {"tokens": [B, T+1]} or {"inputs": [B,T], "targets": [B,T]}. ``rng``
     feeds MoE router jitter (unpipelined path only). ``parts``: the cross
     entropy and what the layers added up beside it (``forward_features``),
-    apart, for a caller that reports them apart."""
+    apart, for a caller that reports them apart (``moe.aux_loss_of`` is what
+    the second adds to the first). A family's ``second_loss`` (a prediction
+    layer behind the trunk) gets the trunk's output before the final norm
+    and adds its own to the aux."""
     if "tokens" in batch:
         inputs = batch["tokens"][:, :-1]
         targets = batch["tokens"][:, 1:]
@@ -585,10 +641,13 @@ def loss_fn(
         return -(ll * mask).sum() / jnp.maximum(mask.sum(), 1) + aux
     from ray_tpu.ops.xent import chunked_softmax_xent
 
-    x, aux = forward_features(params, inputs, config, mesh, rng=rng)
+    family = module_for(config)
+    x, aux = _trunk(params, inputs, config, mesh, rng)
     xent = chunked_softmax_xent(
-        x, module_for(config).head_weight(params), targets, batch.get("mask")
-    )
+        family.final_norm(config, params, x), family.head_weight(params),
+        targets, batch.get("mask"))
+    aux = family.second_loss(
+        config, params, x, targets, batch.get("mask"), aux, mesh)
     return (xent, aux) if parts else xent + aux_loss_of(aux)
 
 

@@ -341,23 +341,22 @@ def _write(rows: jax.Array, new: jax.Array, hit: jax.Array) -> jax.Array:
 LATENT_POSITIONS = 1024
 
 
-def latent_attention(q, rows, up, scale: float):
-    """The full forward's latent attention, from nothing before it: q
-    [B, T, H, Dn + Dr], ``rows`` [B, T, R + Dr] (the normed latent and the
-    rotated shared key), ``up`` [R, H, Dn + Dv] -> [B, T, H, Dv]; causal,
-    from the up-projected keys and values, plain XLA."""
-    R = up.shape[0]
-    Dn = q.shape[-1] - (rows.shape[-1] - R)
+def latent_kv(rows, up, q_width: int):
+    """The full forward's keys and values of a latent layer, a head its
+    own: ``rows`` [B, T, R + Dr] (the normed latent and the rotated shared
+    key), ``up`` [R, H, Dn + Dv], queries ``q_width`` = Dn + Dr wide -> (k
+    [B, T, H, Dn + Dr]: the up-projected part and the shared key, the same
+    in every head; v [B, T, H, Dv]). What the attention dispatcher takes in
+    place of [T, T] scores in XLA: the flash kernels on the chip."""
+    R, H = up.shape[:2]
+    Dn = q_width - (rows.shape[-1] - R)
     with jax.named_scope("mla.up"):
         kv = jnp.einsum("bsr,rhd->bshd", rows[..., :R],
                         up.astype(rows.dtype))
-    scores = (jnp.einsum("bthd,bshd->bhts", q[..., :Dn], kv[..., :Dn])
-              + jnp.einsum("bthd,bsd->bhts", q[..., Dn:], rows[..., R:])
-              ).astype(jnp.float32) * scale
-    T = q.shape[1]
-    seen = jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]
-    probs = jax.nn.softmax(jnp.where(seen, scores, -1e30), axis=-1)
-    return jnp.einsum("bhts,bshd->bthd", probs.astype(q.dtype), kv[..., Dn:])
+        shared = jnp.broadcast_to(
+            rows[:, :, None, R:], rows.shape[:2] + (H, rows.shape[-1] - R))
+        return (jnp.concatenate([kv[..., :Dn], shared], axis=-1),
+                kv[..., Dn:])
 
 
 def attend_latent(cache: Dict[str, jax.Array], layer: jax.Array,

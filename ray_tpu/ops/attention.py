@@ -52,7 +52,8 @@ def attention_xla(
     kv_offset: int | jax.Array = 0,
     window: Optional[int] = None,
 ) -> jax.Array:
-    """Dense attention. q: [B, Tq, H, D]; k/v: [B, Tk, Hkv, D].
+    """Dense attention. q: [B, Tq, H, D]; k: [B, Tk, Hkv, D]; v:
+    [B, Tk, Hkv, Dv] -> [B, Tq, H, Dv].
     ``window`` (causal only): a query sees itself and the ``window - 1``
     positions before it, a band mask.
 
@@ -234,9 +235,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_k: int,
                   strip: int, causal: bool, scale: float, fold: bool,
                   seq_k: int, square: bool, window: Optional[int] = None):
     """One (batch*head, q_block) program: stream K/V blocks with online
-    softmax. Block shapes: q/o [1, Bq, D], k/v [1, Tk, D], lse [1, 8, Bq]
+    softmax. Block shapes: q [1, Bq, D], k [1, Tk, D], v [1, Tk, Dv], o
+    [1, Bq, Dv] (Dv = D but for latent attention), lse [1, 8, Bq]
     (written only when the training path asks for it: it feeds the backward);
-    scratch m/l [1, Bq], acc [D, Bq]. Scores are held keys x queries
+    scratch m/l [1, Bq], acc [Dv, Bq]. Scores are held keys x queries
     ([n, strip]): a query's max and sum reduce along sublanes and broadcast
     back along them, and q is the product's stationary operand.
 
@@ -384,11 +386,21 @@ def _kv_index(rep: int):
     return lambda b: b // rep
 
 
+def _call_name(direction: str, window, D: int, Dv: int) -> str:
+    """The instruction's name, by which a trace's reader knows a call's
+    kind: ``flash_fwd`` / ``flash_bwd``, ``flash_window_*`` with a window,
+    ``flash_mla_*`` where the values are not as wide as the keys (latent
+    attention's up-projected heads)."""
+    kind = "mla_" if Dv != D else "" if window is None else "window_"
+    return f"flash_{kind}{direction}"
+
+
 def _flash_fwd_impl(q, k, v, *, causal: bool, block_q: Optional[int],
                     block_k: Optional[int], interpret: bool,
                     with_lse: bool = False, window: Optional[int] = None):
     B, Tq, H, D = q.shape
     _, Tk, Hkv, _ = k.shape
+    Dv = v.shape[-1]    # the values' width, and the result's: D where equal
     rep = H // Hkv
     window = _window_of(window, causal, Tk)
     if window is not None and Tq != Tk:
@@ -406,15 +418,15 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, block_q: Optional[int],
         seq_k=Tk, square=_square(Tq, Tk, block_q, block_k, causal, window),
         window=window,
     )
-    out_shape = [jax.ShapeDtypeStruct((B * H, Tq_p, D), q.dtype)]
-    out_specs = [pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0))]
+    out_shape = [jax.ShapeDtypeStruct((B * H, Tq_p, Dv), q.dtype)]
+    out_specs = [pl.BlockSpec((1, block_q, Dv), lambda b, i: (b, i, 0))]
     if with_lse:
         out_shape.append(jax.ShapeDtypeStruct((B * H, 8, Tq_p), jnp.float32))
         out_specs.append(pl.BlockSpec((1, 8, block_q), lambda b, i: (b, 0, i)))
     kv = _kv_index(rep)
     size = q.dtype.itemsize
-    limit = _vmem_limit(2 * Tk_p * D * size + 2 * block_q * D * size
-                        + block_q * D * 4)
+    limit = _vmem_limit(Tk_p * (D + Dv) * size + block_q * (D + Dv) * size
+                        + block_q * Dv * 4)
     # Without ``with_lse`` (inference, no grad) the LSE output does not
     # exist: it would be wasted write bandwidth on every forward.
     out, *lse = pl.pallas_call(
@@ -424,20 +436,20 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, block_q: Optional[int],
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, Tk_p, D), lambda b, i: (kv(b), 0, 0)),
-            pl.BlockSpec((1, Tk_p, D), lambda b, i: (kv(b), 0, 0)),
+            pl.BlockSpec((1, Tk_p, Dv), lambda b, i: (kv(b), 0, 0)),
         ],
         out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((1, block_q), jnp.float32),  # running max
             pltpu.VMEM((1, block_q), jnp.float32),  # running sum
-            pltpu.VMEM((D, block_q), jnp.float32),  # o accumulator
+            pltpu.VMEM((Dv, block_q), jnp.float32),  # o accumulator
         ],
         **({"compiler_params": pltpu.CompilerParams(**limit)}
            if limit else {}),
         interpret=interpret,
-        name="flash_fwd" if window is None else "flash_window_fwd",
+        name=_call_name("fwd", window, D, Dv),
     )(qf, kf, vf)
-    out = out.reshape(B, H, Tq_p, D).transpose(0, 2, 1, 3)
+    out = out.reshape(B, H, Tq_p, Dv).transpose(0, 2, 1, 3)
     if Tq_p != Tq:
         out = out[:, :Tq]
     if with_lse:
@@ -455,7 +467,8 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     once a block pair (five products). Scores are held keys x queries
     ([n, width]), so the saved row statistics broadcast along sublanes as
     they are stored and only dQ's product contracts over rows. Shapes:
-    k/v/dk/dv [1, Bk, D]; q/do/dq [1, Tq, D]; lse/delta [1, 8, Tq] (row 0 is
+    k/dk [1, Bk, D], v/dv [1, Bk, Dv]; q/dq [1, Tq, D], do [1, Tq, Dv];
+    lse/delta [1, 8, Tq] (row 0 is
     the data; the 8 rows are sublane replication for mosaic's block-shape
     rules); scratch dq_acc [Tq, D] f32, alive across the key-block axis and
     written at its last step, dk_acc/dv_acc [Bk, D] f32.
@@ -577,6 +590,7 @@ def _flash_bwd_impl(q, k, v, out, lse, g, *, causal: bool,
     own, which are summed over the group afterwards."""
     B, Tq, H, D = q.shape
     _, Tk, Hkv, _ = k.shape
+    Dv = v.shape[-1]    # of v, out, g and dv; q, k, dq and dk are D wide
     rep = H // Hkv
     window = _window_of(window, causal, Tk)
     block_q, block_k = _blocks(Tq, Tk, block_q, block_k)
@@ -591,11 +605,19 @@ def _flash_bwd_impl(q, k, v, out, lse, g, *, causal: bool,
 
     kv = _kv_index(rep)
     size = q.dtype.itemsize
-    limit = _vmem_limit(3 * Tq_p * D * size + Tq_p * D * 4
-                        + 2 * 8 * Tq_p * 4 + 4 * block_k * D * (size + 2))
-    whole_q = pl.BlockSpec((1, Tq_p, D), lambda b, j: (b, 0, 0))
-    k_block = pl.BlockSpec((1, block_k, D), lambda b, j: (kv(b), j, 0))
-    dk_block = pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0))
+    limit = _vmem_limit(Tq_p * (2 * D + Dv) * size + Tq_p * D * 4
+                        + 2 * 8 * Tq_p * 4
+                        + 2 * block_k * (D + Dv) * (size + 2))
+
+    def whole_q(width):
+        return pl.BlockSpec((1, Tq_p, width), lambda b, j: (b, 0, 0))
+
+    def k_block(width):
+        return pl.BlockSpec((1, block_k, width), lambda b, j: (kv(b), j, 0))
+
+    def dk_block(width):
+        return pl.BlockSpec((1, block_k, width), lambda b, j: (b, j, 0))
+
     stats = pl.BlockSpec((1, 8, Tq_p), lambda b, j: (b, 0, 0))
     dq, dk, dv = pl.pallas_call(
         functools.partial(
@@ -609,28 +631,29 @@ def _flash_bwd_impl(q, k, v, out, lse, g, *, causal: bool,
         out_shape=(
             jax.ShapeDtypeStruct((B * H, Tq_p, D), q.dtype),
             jax.ShapeDtypeStruct((B * H, Tk_p, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, Tk_p, D), q.dtype),
+            jax.ShapeDtypeStruct((B * H, Tk_p, Dv), q.dtype),
         ),
         grid=(B * H, Tk_p // block_k),
-        in_specs=[whole_q, k_block, k_block, whole_q, stats, stats],
-        out_specs=(whole_q, dk_block, dk_block),
+        in_specs=[whole_q(D), k_block(D), k_block(Dv), whole_q(Dv), stats,
+                  stats],
+        out_specs=(whole_q(D), dk_block(D), dk_block(Dv)),
         scratch_shapes=[
-            pltpu.VMEM((Tq_p, D), jnp.float32),     # dq, across key blocks
-            pltpu.VMEM((block_k, D), jnp.float32),  # dk
-            pltpu.VMEM((block_k, D), jnp.float32),  # dv
+            pltpu.VMEM((Tq_p, D), jnp.float32),      # dq, across key blocks
+            pltpu.VMEM((block_k, D), jnp.float32),   # dk
+            pltpu.VMEM((block_k, Dv), jnp.float32),  # dv
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"), **limit),
         interpret=interpret,
-        name="flash_bwd" if window is None else "flash_window_bwd",
+        name=_call_name("bwd", window, D, Dv),
     )(qf, kf, vf, dof, lse, delta)
 
     dq = dq.reshape(B, H, Tq_p, D).transpose(0, 2, 1, 3)[:, :Tq]
     dk = dk.reshape(B, H, Tk_p, D).transpose(0, 2, 1, 3)[:, :Tk]
-    dv = dv.reshape(B, H, Tk_p, D).transpose(0, 2, 1, 3)[:, :Tk]
+    dv = dv.reshape(B, H, Tk_p, Dv).transpose(0, 2, 1, 3)[:, :Tk]
     if rep != 1:
         dk = dk.reshape(B, Tk, Hkv, rep, D).sum(axis=3)
-        dv = dv.reshape(B, Tk, Hkv, rep, D).sum(axis=3)
+        dv = dv.reshape(B, Tk, Hkv, rep, Dv).sum(axis=3)
     return dq, dk, dv
 
 
@@ -644,8 +667,10 @@ def flash_attention(q, k, v, causal: bool = True,
     the forward; backward never materializes the [Tq, Tk] score matrix —
     round 2 recomputed attention in XLA for grads, which put three dense
     [B, H, Tq, Tk] tensors back into every train step). q [B, T, H, D], k
-    and v [B, T, Hkv, D] with H a multiple of Hkv; ``window`` as
-    ``attention_xla``'s."""
+    [B, T, Hkv, D] and v [B, T, Hkv, Dv] with H a multiple of Hkv ->
+    [B, T, H, Dv]; the values may be narrower or wider than the keys (latent
+    attention: 192 and 128), and where they are not the calls are the ones
+    they were; ``window`` as ``attention_xla``'s."""
     return _flash_fwd_impl(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
         interpret=interpret, window=window,

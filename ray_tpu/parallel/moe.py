@@ -112,6 +112,12 @@ class MoEConfig:
     # the k are chosen among their experts alone. None: among all experts.
     n_group: Optional[int] = None
     topk_group: Optional[int] = None
+    # The bias is moved after each step by a rule and not by the optimizer
+    # (``bias_rule_update``; a trainer's business: ``train/step.py``), by
+    # this much: a layer that holds a share then gives its count of pairs
+    # over ALL experts beside its other counts (``moe_counts``). 0: the bias
+    # is a parameter like any other.
+    bias_update_rate: float = 0.0
 
     def __post_init__(self):
         if self.activation not in ("gelu", "swiglu", "reglu"):
@@ -604,12 +610,22 @@ def _grouped_share(params, tokens, gates, chosen, row_mask, counts,
         out = one(tokens, params, 0, named=True)
     else:
         def overflow(tokens, params):
-            def step(acc, start):
-                part = jax.lax.cond(
-                    start < total, jax.checkpoint(one),
+            # the checkpoint AROUND the cond: what a pass keeps for the
+            # backward pass is then the checkpoint's own inputs, which but
+            # for ``start`` are the scan's constants and are kept once; a
+            # cond's residuals are values made in the loop, and the scan
+            # stacked them, ``passes`` copies of the tokens and of the
+            # share's three matrices (2.3 GB at 16 of 256 experts held,
+            # filled with zeros every layer whichever branch ran)
+            @jax.checkpoint
+            def a_pass(tokens, params, start):
+                return jax.lax.cond(
+                    start < total, one,
                     lambda *_: jnp.zeros((T, D), jnp.float32),
                     tokens, params, start)
-                return acc + part, None
+
+            def step(acc, start):
+                return acc + a_pass(tokens, params, start), None
 
             return jax.lax.scan(step, jnp.zeros((T, D), jnp.float32),
                                 jnp.arange(passes) * R)[0]
@@ -630,8 +646,32 @@ def aux_zero(config: Optional[MoEConfig]):
 
 
 def aux_loss_of(aux) -> jax.Array:
-    """The auxiliary loss of what ``aux_zero`` and the layers added up."""
-    return aux["aux_loss"] if isinstance(aux, dict) else aux
+    """What ``aux_zero`` and the layers added up adds to the cross entropy
+    a step minimises: the auxiliary loss and, where a family put one beside
+    it, its weighted ``second_loss`` (``decoder.second_loss``)."""
+    if not isinstance(aux, dict):
+        return aux
+    return aux["aux_loss"] + aux.get("second_loss", 0.0)
+
+
+def counts_apart(aux):
+    """A layer's aux -> (the aux a stack adds up, the layer's ``moe_counts``
+    or None): the counts a rule moves the bias by are kept a layer apart."""
+    if not isinstance(aux, dict) or "moe_counts" not in aux:
+        return aux, None
+    aux = dict(aux)
+    return aux, aux.pop("moe_counts")
+
+
+def bias_rule_update(counts: jax.Array, rate: float) -> jax.Array:
+    """DeepSeek-V3's balancing rule (``noaux_tc``), the move of one step:
+    ``counts`` [.., E] the pairs each expert was chosen for in the step's
+    batch -> ``rate x sign(mean(counts) - counts)`` [.., E] float32: an
+    expert chosen less than the mean is chosen more readily from the next
+    step on. The caller adds it to ``expert_bias`` in the optimizer's
+    place (the bias moves a choice of indices and has no gradient)."""
+    counts = counts.astype(jnp.float32)
+    return rate * jnp.sign(counts.mean(axis=-1, keepdims=True) - counts)
 
 
 def moe_layer_counted(
@@ -654,7 +694,10 @@ def moe_layer_counted(
     computed them (``router_logits``). A layer that holds a share of the
     experts (``num_held``) gives, in place of the scalar loss, the loss (over
     ALL experts) beside the rows it computed and the rows of its fullest
-    expert: ``{"aux_loss", "moe_rows_held", "moe_rows_max_expert"}``."""
+    expert: ``{"aux_loss", "moe_rows_held", "moe_rows_max_expert"}``, and
+    where a rule moves its bias (``bias_update_rate``) the pairs each of ALL
+    experts was chosen for, ``moe_counts`` [E] int32, which a stack of
+    layers keeps a layer apart (``decoder._trunk``)."""
     B, T, D = x.shape
     E, k = config.num_experts, config.top_k
     tokens = x.reshape(B * T, D)
@@ -677,9 +720,11 @@ def moe_layer_counted(
         out = _grouped_share(
             params, tokens, _normalised(gates, config), chosen, mask, counts,
             config, layer)
-        return out.reshape(B, T, D), {
-            "aux_loss": aux, "moe_rows_held": counts.sum(),
-            "moe_rows_max_expert": counts.max()}, (counts > 0).sum()
+        counted = {"aux_loss": aux, "moe_rows_held": counts.sum(),
+                   "moe_rows_max_expert": counts.max()}
+        if config.bias_update_rate:
+            counted["moe_counts"] = every
+        return out.reshape(B, T, D), counted, (counts > 0).sum()
 
     if config.dropless:
         out, counts = _grouped(

@@ -19,6 +19,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.models import decoder, module_for
+from ray_tpu.parallel.moe import aux_loss_of
 from ray_tpu.parallel.sharding import (
     DEFAULT_RULES,
     named_sharding,
@@ -127,10 +128,15 @@ def make_train_step(
     ``metrics["loss"]`` is the next-token cross entropy. A model with routed
     experts is trained on that plus its auxiliary loss, which rides beside
     it as ``metrics["aux_loss"]``, with whatever else its layers counted (a
-    share of the experts: ``moe_rows_held``, ``moe_rows_max_expert``).
+    share of the experts: ``moe_rows_held``, ``moe_rows_max_expert``), a
+    family's second loss (``mtp_loss``, weighted into what is minimised:
+    ``moe.aux_loss_of``) and its ``step_rule``'s own counters.
     """
     moe = getattr(config, "moe", None)
     needs_rng = moe is not None and moe.router_jitter > 0
+    # a family's leaves that a rule moves and the optimizer does not (a
+    # router's bias under a balancing rule: ``models/joyai_llm_flash.py``)
+    rule = module_for(config).step_rule
     p_shard = (
         param_shardings(mesh, config, rules) if mesh is not None else None
     )
@@ -143,9 +149,11 @@ def make_train_step(
             ), {}
         xent, aux = decoder.loss_fn(
             params, batch, config, mesh, rng=rng, parts=True)
+        total = xent + aux_loss_of(aux)
         if not isinstance(aux, dict):
             aux = {"aux_loss": aux}
-        return xent + aux["aux_loss"], {"loss": xent, **aux}
+        aux = {k: v for k, v in aux.items() if k != "second_loss"}
+        return total, {"loss": xent, **aux}
 
     def step_fn(state, batch):
         params = state["params"]
@@ -161,6 +169,7 @@ def make_train_step(
         updates, new_opt = opt.update(
             grads, state["opt_state"], state["params"]
         )
+        updates, beside = rule(config, state["params"], updates, beside)
         new_params = optax.apply_updates(state["params"], updates)
         if p_shard is not None:
             # Pin the new state to the layout create_train_state gave it:

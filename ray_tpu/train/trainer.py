@@ -121,7 +121,8 @@ def default_jax_train_loop(config: Dict[str, Any]):
     from ray_tpu.util.debug import compile_count
 
     # what is fetched from a step's metrics, in one transfer
-    FETCHED = ("loss", "aux_loss", "moe_rows_held", "moe_rows_max_expert")
+    FETCHED = ("loss", "aux_loss", "moe_rows_held", "moe_rows_max_expert",
+               "mtp_loss", "moe_rows_max_all", "moe_bias_abs_mean")
     ctx = get_context()
     device_info = local_device_info()
     model = config.get("model", {})

@@ -121,19 +121,32 @@ def test_flash_ragged_seq_len(causal, T, block):
     (1536, 256, 64, 2, 1),
     (1000, 256, 64, 2, 1),     # ragged: the padded keys in the last block
     (1000, None, 128, 1, 1),
+    # keys and values of two widths (latent attention's up-projected heads:
+    # 192 / 128): a length no block divides, one block, and a tiny pair
+    (300, 128, (192, 128), 2, 2),
+    (256, None, (192, 128), 1, 1),
+    (100, 64, (24, 16), 2, 1),
+    (200, None, (16, 24), 1, 1),   # the values the wider
 ], ids=str)
 def test_flash_bwd_kernel_gqa_and_ragged(causal, T, block, D, H, Hkv):
     """The ONE Pallas backward kernel (dq, dk, dv from one pass): GQA
     head-group reduction, pad-row masking (q rows past seq end must
     contribute nothing to dk/dv), several blocks with a crossed diagonal,
-    the blocks the kernel chooses itself."""
+    the blocks the kernel chooses itself; ``D`` a pair: keys ``D[0]`` and
+    values ``D[1]`` wide in one call (scores from the keys' width, the
+    result, its cotangent and dv from the values')."""
     key = jax.random.PRNGKey(3)
     ks = jax.random.split(key, 4)
     B = 1
+    D, Dv = D if isinstance(D, tuple) else (D, D)
     q = jax.random.normal(ks[0], (B, T, H, D), jnp.float32)
     k = jax.random.normal(ks[1], (B, T, Hkv, D), jnp.float32)
-    v = jax.random.normal(ks[2], (B, T, Hkv, D), jnp.float32)
-    g = jax.random.normal(ks[3], (B, T, H, D), jnp.float32)
+    v = jax.random.normal(ks[2], (B, T, Hkv, Dv), jnp.float32)
+    g = jax.random.normal(ks[3], (B, T, H, Dv), jnp.float32)
+    np.testing.assert_allclose(
+        np.asarray(flash_attention(q, k, v, causal, block, block, True)),
+        np.asarray(attention_xla(q, k, v, causal=causal)),
+        atol=2e-5, rtol=2e-5)
 
     def loss_flash(q, k, v):
         return jnp.vdot(flash_attention(q, k, v, causal, block, block, True),
@@ -163,7 +176,9 @@ def test_flash_bwd_kernel_gqa_and_ragged(causal, T, block, D, H, Hkv):
 def test_flash_block_counts(T, block, causal, visited, masked, fwd, bwd):
     """The work the kernels do, from the shapes alone: block pairs visited,
     those on the masked path (the diagonal's, or the padded tail's), and the
-    score elements computed, as shares of 1024^2."""
+    score elements computed, as shares of 1024^2. No width is asked for: the
+    counts are those of a call whose values are narrower than its keys
+    too."""
     got = flash_block_counts(T, T, block, block, causal)
     assert got == {"visited": visited, "masked": masked,
                    "elements_fwd": int(fwd * 1024 ** 2),

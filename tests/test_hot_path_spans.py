@@ -95,7 +95,19 @@ def _tokens(answers):
     }
 
 
-def _train(run_dir, num_steps=4):
+# a model with a prediction layer and a router's bias under its rule
+# (``models/joyai_llm_flash.py``), as a configuration file states one
+_MTP_MODEL = dict(
+    family="joyai_llm_flash", vocab_size=128, max_seq_len=16, num_layers=2,
+    num_heads=2, embed_dim=32, mlp_dim=64, moe_mlp_dim=16, q_lora_rank=24,
+    kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+    attention_impl="xla", moe_num_experts=8, moe_top_k=2,
+    moe_score_func="sigmoid", moe_route_scale=2.5,
+    moe_expert_bias_init_std=0.02, moe_bias_update_rate=0.001,
+    moe_aux_loss_weight=0.0, moe_num_held=2, moe_dropless=True)
+
+
+def _train(run_dir, num_steps=4, model=None):
     from ray_tpu.train.context import TrainContext, _set_context
     from ray_tpu.train.trainer import default_jax_train_loop
 
@@ -106,7 +118,7 @@ def _train(run_dir, num_steps=4):
         _set_context(ctx)
         try:
             result["out"] = default_jax_train_loop({
-                "model": dict(
+                "model": model or dict(
                     vocab_size=128, max_seq_len=16, num_layers=1,
                     num_heads=2, embed_dim=32, attention_impl="xla"),
                 "mesh": {"data": -1}, "num_steps": num_steps,
@@ -170,6 +182,7 @@ def recording(tmp_path_factory):
         bf16 = DecodeEngine(LLMConfig(**{**_MODEL, "dtype": "bfloat16"}))
         traced = _scenario(srv, stop_token)
         reports = _train(os.path.join(root, "run_traced"))
+        reports_mtp = _train(os.path.join(root, "run_mtp"), 3, _MTP_MODEL)
         time.sleep(0.06)
     finally:
         jax.profiler.stop_trace()
@@ -184,7 +197,7 @@ def recording(tmp_path_factory):
     return {
         "spans": spans, "engine": engine_line, "train": train_line,
         "stats": stats, "plain_stats": plain_stats, "plain": plain,
-        "traced": traced, "reports": reports,
+        "traced": traced, "reports": reports, "reports_mtp": reports_mtp,
         "files_without_capture": files_without_capture,
         "float32_engine": srv.engine, "bf16_engine": bf16, "logdir": logdir,
     }
@@ -260,7 +273,9 @@ def test_train_children_lie_inside_their_step_in_order(recording):
     from benchmarks.lib import host_spans
 
     line = recording["train"]
-    steps = [s for s in line if s.name == "train.step"]
+    # the dense run's four steps; the run with a prediction layer that
+    # follows it may come to lie on the same line (a thread's id used again)
+    steps = [s for s in line if s.name == "train.step"][:4]
     assert [s.args["step_num"] for s in steps] == [0, 1, 2, 3]
     for i, step in enumerate(steps):
         kids = [s.name for s in host_spans.children(line, step)
@@ -272,6 +287,57 @@ def test_train_children_lie_inside_their_step_in_order(recording):
     # the second run of the same loop builds its programs anew (a new jit
     # of a new closure), but none between two steps once it is warm
     assert reports[-1]["compiles"] == reports[1]["compiles"]
+
+
+def test_a_prediction_layers_loss_and_the_rules_counters_ride_the_fetch(
+        recording):
+    """A model with a prediction layer behind the trunk and a router's bias
+    under its rule: what its step counts beside the main loss is on each
+    ``train.loss_fetch`` span and in each report, and nowhere in the dense
+    model's."""
+    spans = recording["spans"]
+    keys = {"aux_loss", "mtp_loss", "moe_rows_held", "moe_rows_max_expert",
+            "moe_rows_max_all", "moe_bias_abs_mean"}
+    fetches = spans.named("train.loss_fetch")
+    routed = [s for s in fetches if "mtp_loss" in s.args]
+    assert len(routed) == 3 and len(fetches) == 4 + 3
+    reports = recording["reports_mtp"]
+    assert [m["step"] for m in reports] == [1, 2, 3]
+    for span, report in zip(routed, reports):
+        assert keys <= set(span.args) and keys <= set(report)
+        assert float(span.args["mtp_loss"]) == pytest.approx(
+            report["mtp_loss"])
+        # 2 routed layers (one the prediction layer's) x 8 x 16 tokens x 2
+        assert 2 * 256 / 8 <= report["moe_rows_max_all"] <= 2 * 256
+        assert 0 < report["moe_bias_abs_mean"] < 0.1
+        assert report["aux_loss"] == 0.0
+    assert not keys & set().union(*(
+        set(s.args) for s in fetches if s not in routed))
+    assert not keys & set(recording["reports"][0])
+
+
+def test_the_train_steps_operations_carry_the_new_scopes():
+    """What a trace's reader finds latent attention's projections and the
+    prediction layer by (``benchmarks/lib/train_mla.py``): every scope is on
+    some operation of the compiled train step, the prediction layer's in
+    the backward pass too."""
+    import jax
+
+    from ray_tpu.models import config_for
+    from ray_tpu.train.step import (
+        OptimizerConfig, create_train_state, make_train_step)
+
+    model = dict(_MTP_MODEL)
+    cfg = config_for(model.pop("family"), **model)
+    opt = OptimizerConfig().build()
+    state = jax.eval_shape(
+        lambda: create_train_state(cfg, opt, jax.random.PRNGKey(0)))
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 17), "int32")}
+    text = make_train_step(cfg, opt).lower(state, batch).compile().as_text()
+    for scope in ("mla.q", "mla.down", "mla.up", "mla.out", "mtp.in",
+                  "mtp.block", "mtp.head", "moe.route", "moe.shared"):
+        assert scope in text, scope
+    assert "transpose(jvp(mtp.block))" in text
 
 
 def test_one_request_one_rid(recording):

@@ -26,9 +26,10 @@ from ray_tpu.parallel.moe import MoEConfig
 # what the decoder calls on a family, what a server calls once as it takes
 # its weights, and what callers outside ask of one
 PIECES = ("layers", "embed", "at_input", "qkv", "attn_out", "ffn",
-          "final_norm", "head", "head_weight", "serving_params")
+          "final_norm", "head", "head_weight", "serving_params",
+          "second_loss", "step_rule")
 # the pieces the decoder gives a default: a family has one, its own or that
-DEFAULTED = ("at_input",)
+DEFAULTED = ("at_input", "second_loss", "step_rule")
 # what a family with state layers has beside them (``decoder.Layer.state``)
 STATE_PIECES = ("state_in", "state_out", "state_leaves")
 OWN = ("Config", "PRESETS", "EXPERT_ACTIVATION", "init_params", "param_axes")
@@ -181,6 +182,11 @@ def test_llm_config_builds_every_family(family, experts):
             # state, none keys and values a head
             assert cache["k"].shape[0] == 0
             assert cache["ssm"].shape[:3] == (4, 3, 4)
+        elif "latent" in cache:
+            # every layer attends a latent row a position, none keys and
+            # values a head
+            assert set(cache) == {"latent"}
+            assert cache["latent"].shape == (4, 3, 1, cfg.latent_dim, 16)
         else:
             assert cache["k"].shape == (4, 3, 4, 16, 16)
 
@@ -193,7 +199,8 @@ TINY = {"gpt2": "gpt2-tiny", "llama": "llama-tiny", "afmoe": "afmoe-tiny",
         "smallthinker": "smallthinker-tiny",
         "granite_hybrid": "granite-hybrid-tiny",
         "olmo_hybrid": "olmo-hybrid-tiny",
-        "bailing_hybrid": "bailing-hybrid-tiny"}
+        "bailing_hybrid": "bailing-hybrid-tiny",
+        "joyai_llm_flash": "joyai-flash-tiny"}
 
 
 def _flat(family) -> dict:

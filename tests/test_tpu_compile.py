@@ -1043,3 +1043,75 @@ def test_smallthinker_step_fits_the_chip_with_its_window_in_the_kernels(
     # the counts of rows an expert are compares and column sums
     assert not re.search(r"= s32\[(16|64)\]\S* scatter\(", text)
     assert not re.search(r"\[\d+,28,8192,8192\]", text)
+
+
+# -------------------------------------------- JoyAI-LLM-Flash's training step
+# The tenth cell's size: one chip's share of a sixteen-way expert-parallel
+# layer, batch 2 x 8192 (``benchmarks/configs/joyai-llm-flash.json``).
+
+
+def test_flash_with_keys_wider_than_values_compiles_for_v5e(one_chip):
+    """32 heads at 8192 tokens, keys 192 and values 128 wide in one call,
+    forward and backward: 192 is one and a half lane tiles, which the
+    compiler takes as a block's whole last dimension; nothing is padded to
+    256 in HBM (q, k, dq and dk are [.., 192], v, o and dv [.., 128]); and
+    the calls carry the name a trace's reader knows them by
+    (``benchmarks/lib/train_mla.py``)."""
+    q = jax.ShapeDtypeStruct((2, 8192, 32, 192), jnp.bfloat16,
+                             sharding=one_chip)
+    v = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    text = jax.jit(_flash_grads).lower(q, q, v).compile().as_text()
+    calls = [line.split(" = ") for line in text.splitlines()
+             if "tpu_custom_call" in line and " = " in line]
+    assert len(calls) == 2
+    (fwd,), (bwd,) = ([result for name, result in calls if kind in name]
+                      for kind in ("flash_mla_fwd", "flash_mla_bwd"))
+    assert fwd.startswith("(bf16[64,8192,128]")
+    assert bwd.startswith(
+        "(bf16[64,8192,192]{2,1,0:T(8,128)(2,1)}, bf16[64,8192,192]"
+        "{2,1,0:T(8,128)(2,1)}, bf16[64,8192,128]")
+    assert ",256]" not in text
+
+
+def test_joyai_step_fits_the_chip_with_latent_attention_in_the_kernels(
+        one_chip, monkeypatch):
+    """The cell's train step for the described chip: accepted at batch 2 x
+    8192 with remat ``dots`` beside 680.4M parameters' state (8,165,367,296
+    bytes of arguments + 10,339,678,208 of temporaries; sandbox compile, PR
+    55: before a share's overflow passes kept their residuals once, the
+    scan stacked eleven copies of the tokens and of the share's matrices
+    and the compiler refused it by 1.42 GB), six flash calls at two widths
+    forward and six backward (five trunk mixers and the prediction layer's)
+    and no [T, T] array anywhere, the prediction layer's operations under
+    its scopes in the backward pass too, and two loss heads over the one
+    table (the main one and the prediction layer's), each projecting a
+    chunk's logits once."""
+    import re
+
+    cfg, compiled = _compiled_train_step(
+        one_chip, "joyai-llm-flash.train-seq8k", monkeypatch)
+    assert cfg.moe.num_held == 16 and cfg.moe.num_experts == 256
+    assert cfg.moe.aux_loss_weight == 0.0 and cfg.moe.bias_update_rate == 0.001
+    mem = compiled.memory_analysis()
+    assert 8.1e9 < mem.argument_size_in_bytes < 8.2e9   # 680.4M x 12 bytes
+    assert mem.temp_size_in_bytes < 10.45e9
+    text = compiled.as_text()
+    names = [line.split(" = ")[0] for line in text.splitlines()
+             if "tpu_custom_call" in line and " = " in line]
+    flash = [n for n in names if "flash_" in n]
+    assert sum("flash_mla_fwd" in n for n in flash) == 6
+    assert sum("flash_mla_bwd" in n for n in flash) == 6
+    assert len(flash) == 12
+    assert not re.search(r"\[[\d,]*8192,8192[\d,]*\]", text)
+    assert "transpose(jvp(mtp.block))/jvp(mtp.block)/checkpoint/flash_mla_bwd" \
+        in text
+    for scope in ("mla.q", "mla.down", "mla.up", "mla.out", "mtp.in",
+                  "mtp.head"):
+        assert scope in text, scope
+    logits = [line for line in text.splitlines()
+              if " convolution(" in line and "closed_call/bce,ve->bcv/" in line]
+    assert len(logits) == 2 and all(
+        f",{cfg.vocab_size}]" in line.split(" = ")[1] for line in logits)
+    assert "rematted_computation/bce,ve->bcv" not in text
+    assert "ragged-dot" not in text
