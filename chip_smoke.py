@@ -54,7 +54,7 @@ TRAIN_STEPS = 6
 # bf16 has 8 mantissa bits: one ulp at the outputs' magnitude (|x| < 8) is
 # 2**-5. Flash and XLA attention accumulate in f32 in different orders, so
 # elements may differ by about one rounding of the result.
-PARITY_SHAPE = (4, 1024, 12, 64)
+PARITY_SHAPE = (4, 12, 1024, 64)   # [B, H, T, D], heads-major
 PARITY_MAX_ABS = 2.0 ** -4
 PARITY_REL_FRO = 1e-2
 # Sharded vs one-device losses differ only by the order of f32 sums and
@@ -105,7 +105,7 @@ def _attention_parity(shape, impl: str, mesh_axes: Optional[dict] = None
     if mesh_axes:
         mesh = MeshConfig(**mesh_axes).build()
         q, k, v, g = jax.device_put((q, k, v, g), NamedSharding(
-            mesh, P(("data", "fsdp"), None, "tensor", None)))
+            mesh, P(("data", "fsdp"), "tensor", None, None)))
 
     def fwd_bwd(fn):
         def run(q, k, v, g):

@@ -279,29 +279,32 @@ def embed(config: AfmoeConfig, params, tokens, pos, cached: bool):
     return x * (config.embed_dim ** 0.5) if config.mup_enabled else x
 
 
-def qkv(config: AfmoeConfig, kind, layer, x, pos):
-    """x [B, T, E] normed -> q [B, T, KV, G, D], k and v [B, T, KV, D]; q
-    and k normed head by head, and rotated in a sliding layer only."""
+def qkv(config: AfmoeConfig, kind, layer, x, pos, heads_major: bool = False):
+    """x [B, T, E] normed -> q [B, T, KV, G, D], k and v [B, T, KV, D] (q
+    [B, H, T, D], k and v [B, KV, T, D] where ``heads_major``); q and k
+    normed head by head, and rotated in a sliding layer only."""
     B, T = x.shape[:2]
     h = _rms_norm(x, layer["attn_norm"], config.rms_eps, config.dtype)
-    q = jnp.einsum("bte,ehd->bthd", h, layer["wq"].astype(h.dtype))
-    k = jnp.einsum("bte,ehd->bthd", h, layer["wk"].astype(h.dtype))
-    v = jnp.einsum("bte,ehd->bthd", h, layer["wv"].astype(h.dtype))
+    q, k, v = (heads_in(h, layer[w].astype(h.dtype), heads_major)
+               for w in ("wq", "wk", "wv"))
     q = _rms_norm(q, layer["q_norm"], config.rms_eps)
     k = _rms_norm(k, layer["k_norm"], config.rms_eps)
     if kind.startswith("sliding"):
-        q, k = (_rope(a, pos, config.rope_theta) for a in (q, k))
+        q, k = (_rope(a, pos, config.rope_theta, heads_major)
+                for a in (q, k))
+    if heads_major:
+        return q, k, v
     return q.reshape(B, T, config.num_kv_heads, -1, config.head_dim), k, v
 
 
-def attn_out(config: AfmoeConfig, layer, x, attn):
+def attn_out(config: AfmoeConfig, layer, x, attn, heads_major: bool = False):
     """The gate (from the normed stream ``qkv`` projected), the output
     projection, the norm of the branch's output, the residual."""
     h = _rms_norm(x, layer["attn_norm"], config.rms_eps, config.dtype)
     gate = jax.nn.sigmoid(
-        jnp.einsum("bte,ehd->bthd", h, layer["wg"].astype(h.dtype)))
-    out = jnp.einsum("bthd,hde->bte", attn * gate.astype(attn.dtype),
-                     layer["wo"].astype(attn.dtype))
+        heads_in(h, layer["wg"].astype(h.dtype), heads_major))
+    out = heads_out(attn * gate.astype(attn.dtype),
+                    layer["wo"].astype(attn.dtype), heads_major)
     return x + _rms_norm(out, layer["post_attn_norm"], config.rms_eps)
 
 

@@ -53,11 +53,12 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu.models import narrowed
 from ray_tpu.models.decoder import *  # noqa: F401,F403 — what families share
 from ray_tpu.models.decoder import Layer, Segment, periods
-from ray_tpu.models.llama import _rms_norm
+from ray_tpu.models.llama import _rms_norm, _turned_on_lanes
 from ray_tpu.ops import kda
 from ray_tpu.parallel.moe import (
     MoEConfig,
@@ -371,37 +372,64 @@ def _rope_interleaved(x, pos, theta: float):
                      axis=-1).reshape(x.shape).astype(x.dtype)
 
 
-def qkv(config: Config, kind, layer, x, pos):
-    """A latent layer's pieces of x [B, T, E]: (q [B, T, H, Dn + Dr], its
-    last Dr rotated; the new rows [B, T, R + Dr] = [RMSNorm(c) | rope(kr)]
-    as the cache holds them; the up-projection [R, H, Dn + Dv])."""
-    R, Dn = config.kv_lora_rank, config.qk_nope_head_dim
-    h = _rms_norm(x, layer["mix_norm"], config.rms_eps, config.dtype)
-    with jax.named_scope("mla.q"):
-        q = jnp.einsum("bte,ehd->bthd", h, layer["wq"].astype(h.dtype))
-        q = jnp.concatenate([q[..., :Dn], _rope_interleaved(
-            q[..., Dn:], pos, config.rope_theta)], axis=-1)
+def _rope_lanes(x, pos, theta: float):
+    """``_rope_interleaved`` for the full forward, on x as it lies ([B, T,
+    D] or, heads-major, [B, H, T, D]): channel 2i meets -x[2i + 1] and
+    channel 2i + 1 meets x[2i] (``llama._turned_on_lanes``)."""
+    D = x.shape[-1]
+    inv_freq = 1.0 / theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+    angles = pos.astype(jnp.float32)[..., None] * jnp.repeat(inv_freq, 2)
+    j = np.arange(D)
+    return _turned_on_lanes(x, angles, j ^ 1, np.where(j % 2, 1.0, -1.0))
+
+
+def _latent_q(q, pos, Dn: int, theta: float, heads_major: bool):
+    """A latent layer's queries with their last channels rotated: [B, T, H,
+    Dn + Dr], or [B, H, T, Dn + Dr] where ``heads_major``."""
+    rope = _rope_lanes if heads_major else _rope_interleaved
+    return jnp.concatenate([
+        q[..., :Dn], rope(q[..., Dn:], pos, theta)], axis=-1)
+
+
+def _latent_rows(config, layer, h, pos, heads_major: bool):
+    """A position's row of a latent layer from the normed stream h [B, T,
+    E]: [RMSNorm(c) | rope(kr)], [B, T, R + Dr], as the cache holds it."""
+    rope = _rope_lanes if heads_major else _rope_interleaved
     with jax.named_scope("mla.down"):
         c, kr = jnp.split(
             jnp.einsum("bte,ef->btf", h, layer["w_dkv"].astype(h.dtype)),
-            [R], axis=-1)
-        rows = jnp.concatenate([
+            [config.kv_lora_rank], axis=-1)
+        return jnp.concatenate([
             _rms_norm(c, layer["kv_norm"], config.rms_eps, h.dtype),
-            _rope_interleaved(kr, pos, config.rope_theta)], axis=-1)
-    return q, rows, layer["w_ukv"]
+            rope(kr, pos, config.rope_theta)], axis=-1)
 
 
-def attn_out(config: Config, layer, x, attn):
-    """A latent layer's heads [B, T, H, Dv] times their gates (from the
-    normed stream ``qkv`` projected), the output projection, the residual."""
+def qkv(config: Config, kind, layer, x, pos, heads_major: bool = False):
+    """A latent layer's pieces of x [B, T, E]: (q [B, T, H, Dn + Dr], its
+    last Dr rotated, [B, H, T, Dn + Dr] where ``heads_major``; the new rows
+    [B, T, R + Dr] = [RMSNorm(c) | rope(kr)] as the cache holds them; the
+    up-projection [R, H, Dn + Dv])."""
+    h = _rms_norm(x, layer["mix_norm"], config.rms_eps, config.dtype)
+    with jax.named_scope("mla.q"):
+        q = heads_in(h, layer["wq"].astype(h.dtype), heads_major)
+        q = _latent_q(q, pos, config.qk_nope_head_dim, config.rope_theta,
+                     heads_major)
+    return q, _latent_rows(config, layer, h, pos, heads_major), layer["w_ukv"]
+
+
+def attn_out(config: Config, layer, x, attn, heads_major: bool = False):
+    """A latent layer's heads [B, T, H, Dv] ([B, H, T, Dv] where
+    ``heads_major``) times their gates (from the normed stream ``qkv``
+    projected), the output projection, the residual."""
     h = _rms_norm(x, layer["mix_norm"], config.rms_eps, config.dtype)
     with jax.named_scope("mla.out"):
         gate = jax.nn.sigmoid(jnp.einsum(
             "bte,eh->bth", h, layer["wz"].astype(h.dtype),
             preferred_element_type=jnp.float32))
-        out = jnp.einsum(
-            "bthd,hde->bte", (attn * gate[..., None]).astype(attn.dtype),
-            layer["wo"].astype(attn.dtype))
+        if heads_major:
+            gate = gate.swapaxes(1, 2)      # [B, H, T]: a scalar a head
+        out = heads_out((attn * gate[..., None]).astype(attn.dtype),
+                        layer["wo"].astype(attn.dtype), heads_major)
     return x + out
 
 

@@ -12,12 +12,20 @@ one signature across families:
   experts' weights of every routed layer or None): which kinds of layer
   follow one another, and where each one's weights are in ``blocks``
   (``Layer``, ``Segment`` below). ``blocks`` None asks for the kinds alone;
-- ``qkv(config, kind, layer, x, pos)`` -> q, and k and v [B, T, KV, D], from
-  the stream (the family's norm, RoPE and q/k norm inside; ``kind`` is the
-  ``Layer.name`` the family gave this layer). q is [B, T, H, D], or
-  [B, T, KV, G, D] where G query heads share a kv head;
-- ``attn_out(config, layer, x, attn)`` -> the stream after the output
-  projection and the residual;
+- ``qkv(config, kind, layer, x, pos, heads_major=False)`` -> q, and k and v
+  [B, T, KV, D], from the stream (the family's norm, RoPE and q/k norm
+  inside; ``kind`` is the ``Layer.name`` the family gave this layer). q is
+  [B, T, H, D], or [B, T, KV, G, D] where G query heads share a kv head.
+  That is the cached forward's order (its rows go into the cache a
+  position at a time, and its call is the one it always was). The full
+  forward asks for q [B, H, T, D] and k and v [B, KV, T, D]
+  (``heads_major=True``, a constant there): the order the attention kernels
+  fold by a reshape, which the projection's product writes itself
+  (``ops.attention.heads_in``; a transpose after the rotation is a copy of
+  the whole array, the same order asked of the product is not);
+- ``attn_out(config, layer, x, attn, heads_major=False)`` -> the stream
+  after the output projection and the residual; ``attn`` [B, H, T, D] where
+  ``heads_major``, else [B, T, H, D];
 - ``at_input(config, kind, layer, x, stacked)`` -> whatever the family's
   ``ffn`` wants of the block's INPUT, the stream before the attention's norm
   (a router that reads it gives its logits), or None: the default below,
@@ -61,10 +69,10 @@ one signature across families:
   was initialised;
 - ``config.num_kv_heads``.
 
-What follows from shapes alone is decided here: a grouped q is flattened for
-the full forward (the attention takes k and v with the kv heads they have),
-the cache is attended with q as it came (``kv_cache.attend`` takes either),
-and the output projection gets [B, T, H, D] from both.
+What follows from shapes alone is decided here: the cache is attended with q
+as it came (``kv_cache.attend`` takes it grouped or flat) and the output
+projection gets [B, T, H, D] from it; the full forward's attention takes k
+and v with the kv heads they have.
 
 Layers are stacked into scanned super-layers (``lax.scan`` over depth:
 O(1) compile time in depth, and the layout the "stage" mesh axis splits),
@@ -87,7 +95,9 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from ray_tpu.models import kv_cache, module_for
-from ray_tpu.ops.attention import attention
+from ray_tpu.ops.attention import (
+    attention, heads_in, heads_out, swapped, with_shared,
+)
 from ray_tpu.parallel.moe import (
     aux_loss_of,
     aux_zero,
@@ -196,7 +206,10 @@ def step_rule(config, params, updates, counted):
 # ``module_for(cfg).loss_fn``): each the one definition here
 __all__ = ["forward_features", "forward", "init_kv_cache", "forward_cached",
            "forward_pipelined", "loss_fn", "count_params", "Layer", "Segment",
-           "single_kind", "periods", "at_input", "second_loss", "step_rule"]
+           "single_kind", "periods", "at_input", "second_loss", "step_rule",
+           "heads_in", "heads_out", "swapped"]
+
+
 
 
 def _remat_policy(config):
@@ -225,46 +238,42 @@ def _remat_policy(config):
 
 
 def _attention_dispatch(config, q, k, v, mesh: Optional[Mesh],
-                        window: Optional[int] = None):
+                        window: Optional[int] = None, shared=None):
     """Adds the mesh-aware ring/ulysses branches on top of the shared
-    single-device dispatcher (``ops.attention.attention``). q [B, T, H, D],
-    k and v [B, T, KV, D]; ``window``: of a layer whose attention has one."""
+    single-device dispatcher (``ops.attention.attention``). q [B, H, T, D],
+    k and v [B, KV, T, D]; ``window``: of a layer whose attention has one;
+    ``shared`` [B, T, Dr]: a latent layer's rotated key, which every head
+    reads behind its own channels of k."""
     impl = config.attention_impl
     if impl in ("ring", "ulysses"):
         from ray_tpu.parallel import ring_attention
 
         if window is not None:
             raise ValueError(f"attention_impl {impl!r} has no window")
-        rep = q.shape[2] // k.shape[2]
-        return {"ring": ring_attention.ring_attention,
-                "ulysses": ring_attention.ulysses_attention}[impl](
-            q, _repeat_kv(k, rep), _repeat_kv(v, rep), mesh=mesh,
-            axis=config.seq_axis, causal=True)
+        if shared is not None:
+            k = with_shared(k, shared)
+        rep = q.shape[1] // k.shape[1]
+        # the sequence's shards are [B, T / n, H, D] there: its own order
+        (out,) = swapped({"ring": ring_attention.ring_attention,
+                          "ulysses": ring_attention.ulysses_attention}[impl](
+            *swapped(q, jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)),
+            mesh=mesh, axis=config.seq_axis, causal=True))
+        return out
     return attention(q, k, v, causal=True, impl=impl, mesh=mesh,
-                     window=window)
-
-
-def _repeat_kv(x: jax.Array, n: int) -> jax.Array:
-    """[B, T, KV, D] -> [B, T, KV*n, D] (GQA head expansion)."""
-    if n == 1:
-        return x
-    B, T, KV, D = x.shape
-    return jnp.broadcast_to(
-        x[:, :, :, None, :], (B, T, KV, n, D)
-    ).reshape(B, T, KV * n, D)
+                     window=window, shared=shared)
 
 
 def _window_attention(q, k, v, window: int):
     """Causal attention over the token itself and the ``window - 1`` before
-    it, [B, T, H, D] each: a band mask over whole [B, H, T, T] scores in
+    it, [B, H, T, D] each: a band mask over whole [B, H, T, T] scores in
     plain XLA. What the kernels' window is held to (``tests/``); the layer
     stack goes through ``_attention_dispatch``."""
-    i = jnp.arange(q.shape[1])
+    i = jnp.arange(q.shape[2])
     band = (i[None, :] <= i[:, None]) & (i[None, :] > i[:, None] - window)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32)
     scores = scores / jnp.sqrt(jnp.float32(q.shape[-1]))
     probs = jax.nn.softmax(jnp.where(band, scores, -1e30), axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), v)
+    return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(q.dtype), v)
 
 
 def _body(config, mesh: Optional[Mesh], pos, kind: Layer = Layer(),
@@ -289,20 +298,8 @@ def _body(config, mesh: Optional[Mesh], pos, kind: Layer = Layer(),
         from_input = family.at_input(config, kind.name, layer, x, stacked)
         if kind.state is not None:
             x = _recur(config, kind, layer, x, None)[0]
-        elif kind.latent is not None:
-            # the full forward attends up-projected keys and values, a head
-            # its own, through the dispatcher (the flash kernels on the
-            # chip: no [T, T] scores): the keys as wide as q, the values not
-            q, rows, up = family.qkv(config, kind.name, layer, x, pos)
-            k, v = kv_cache.latent_kv(rows, up.astype(q.dtype), q.shape[-1])
-            x = family.attn_out(config, layer, x, _attention_dispatch(
-                config, q, k, v, mesh))
         else:
-            q, k, v = family.qkv(config, kind.name, layer, x, pos)
-            if q.ndim == 5:  # [B, T, KV, G, D]: G query heads a kv head
-                q = q.reshape(*q.shape[:2], -1, q.shape[-1])
-            attn = _attention_dispatch(config, q, k, v, mesh, kind.window)
-            x = family.attn_out(config, layer, x, attn)
+            x = _mixer(config, mesh, pos, kind, layer, x)
         x, aux, _ = family.ffn(
             config, kind.name, layer, x, rng=rng, row_mask=None,
             stacked=stacked, from_input=from_input)
@@ -311,6 +308,28 @@ def _body(config, mesh: Optional[Mesh], pos, kind: Layer = Layer(),
     if config.remat:
         return jax.checkpoint(block, policy=_remat_policy(config))
     return block
+
+
+def _mixer(config, mesh: Optional[Mesh], pos, kind: Layer, layer, x):
+    """The full forward's attention of one layer, between the family's two
+    pieces: heads-major from the projections to the kernels and back, so
+    that nothing is transposed on the way."""
+    family = module_for(config)
+    if kind.latent is not None:
+        # the full forward attends up-projected keys and values, a head
+        # its own, through the dispatcher (the flash kernels on the chip:
+        # no [T, T] scores): the keys' own channels and the values a head,
+        # the rotated key once for all of them
+        q, rows, up = family.qkv(
+            config, kind.name, layer, x, pos, heads_major=True)
+        k, v, shared = kv_cache.latent_kv(
+            rows, up.astype(q.dtype), q.shape[-1])
+        attn = _attention_dispatch(config, q, k, v, mesh, shared=shared)
+    else:
+        q, k, v = family.qkv(
+            config, kind.name, layer, x, pos, heads_major=True)
+        attn = _attention_dispatch(config, q, k, v, mesh, kind.window)
+    return family.attn_out(config, layer, x, attn, heads_major=True)
 
 
 def _recur(config, kind: Layer, layer, x, carried):

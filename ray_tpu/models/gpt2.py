@@ -197,16 +197,25 @@ def embed(config: GPT2Config, params, tokens, pos, cached: bool):
     return x + wpe.astype(config.dtype)
 
 
-def qkv(config: GPT2Config, kind, layer, x, pos):
-    """ln1 + the fused projection: [B, T, E] → (q, k, v) each [B, T, H, D]."""
+def qkv(config: GPT2Config, kind, layer, x, pos, heads_major: bool = False):
+    """ln1 + the fused projection: [B, T, E] → (q, k, v) each [B, T, H, D],
+    or [B, H, T, D] where ``heads_major``: transposed behind the one
+    product, as ``attn_out`` transposes back before its own. At 64 channels
+    a head ``ops.attention.flash_attention`` undoes both (its docstring says
+    why), the compiler drops each pair, and the products are the ones the
+    cached forward has."""
     h = _layer_norm(x, layer["ln1_g"], layer["ln1_b"])
     qkv = jnp.einsum("bte,eshd->btshd", h, layer["qkv_w"].astype(h.dtype))
     qkv = qkv + layer["qkv_b"].astype(h.dtype)
-    return qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+    return swapped(q, k, v) if heads_major else (q, k, v)
 
 
-def attn_out(config: GPT2Config, layer, x, attn):
-    """Output projection + residual add."""
+def attn_out(config: GPT2Config, layer, x, attn, heads_major: bool = False):
+    """Output projection + residual add; ``attn`` [B, H, T, D] where
+    ``heads_major``, transposed before the product (``qkv``)."""
+    if heads_major:
+        (attn,) = swapped(attn)
     attn = jnp.einsum("bthd,hde->bte", attn, layer["proj_w"].astype(x.dtype))
     return x + attn + layer["proj_b"].astype(x.dtype)
 

@@ -307,22 +307,24 @@ def embed(config: Config, params, tokens, pos, cached: bool):
     return x * config.embedding_multiplier
 
 
-def qkv(config: Config, kind, layer, x, pos):
-    """x [B, T, E] normed -> q [B, T, KV, G, D], k and v [B, T, KV, D]: no
-    norm, no rotation. q carries the stated scale over the ``sqrt(D)`` the
-    attention divides by."""
+def qkv(config: Config, kind, layer, x, pos, heads_major: bool = False):
+    """x [B, T, E] normed -> q [B, T, KV, G, D], k and v [B, T, KV, D] (q
+    [B, H, T, D], k and v [B, KV, T, D] where ``heads_major``): no norm, no
+    rotation. q carries the stated scale over the ``sqrt(D)`` the attention
+    divides by."""
     B, T = x.shape[:2]
     h = _rms_norm(x, layer["input_norm"], config.rms_eps, config.dtype)
-    q = jnp.einsum("bte,ehd->bthd", h, layer["wq"].astype(h.dtype))
-    k = jnp.einsum("bte,ehd->bthd", h, layer["wk"].astype(h.dtype))
-    v = jnp.einsum("bte,ehd->bthd", h, layer["wv"].astype(h.dtype))
+    q, k, v = (heads_in(h, layer[w].astype(h.dtype), heads_major)
+               for w in ("wq", "wk", "wv"))
     q = q * jnp.asarray(
         config.attention_multiplier * config.head_dim ** 0.5, q.dtype)
+    if heads_major:
+        return q, k, v
     return q.reshape(B, T, config.num_kv_heads, -1, config.head_dim), k, v
 
 
-def attn_out(config: Config, layer, x, attn):
-    out = jnp.einsum("bthd,hde->bte", attn, layer["wo"].astype(attn.dtype))
+def attn_out(config: Config, layer, x, attn, heads_major: bool = False):
+    out = heads_out(attn, layer["wo"].astype(attn.dtype), heads_major)
     return x + config.residual_multiplier * out
 
 

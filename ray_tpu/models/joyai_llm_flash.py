@@ -50,7 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import narrowed
-from ray_tpu.models.bailing_hybrid import _of_moe, _rope_interleaved
+from ray_tpu.models.bailing_hybrid import _of_moe, _latent_q, _latent_rows
 from ray_tpu.models.decoder import *  # noqa: F401,F403 — what families share
 from ray_tpu.models.decoder import Layer, Segment, _body
 from ray_tpu.models.llama import _rms_norm
@@ -321,36 +321,28 @@ def embed(config: Config, params, tokens, pos, cached: bool):
         jnp.float32 if cached else config.dtype)
 
 
-def qkv(config: Config, kind, layer, x, pos):
+def qkv(config: Config, kind, layer, x, pos, heads_major: bool = False):
     """A layer's latent pieces of x [B, T, E]: (q [B, T, H, Dn + Dr] from
-    the normed query rank, its last Dr rotated; the new rows [B, T, R + Dr]
-    = [RMSNorm(c) | rope(kr)] as the cache holds them; the up-projection
-    [R, H, Dn + Dv])."""
-    R, Dn = config.kv_lora_rank, config.qk_nope_head_dim
+    the normed query rank, its last Dr rotated, [B, H, T, Dn + Dr] where
+    ``heads_major``; the new rows [B, T, R + Dr] = [RMSNorm(c) | rope(kr)]
+    as the cache holds them; the up-projection [R, H, Dn + Dv])."""
     h = _rms_norm(x, layer["mix_norm"], config.rms_eps, config.dtype)
     with jax.named_scope("mla.q"):
         cq = _rms_norm(
             jnp.einsum("bte,er->btr", h, layer["w_dq"].astype(h.dtype)),
             layer["q_norm"], config.rms_eps, h.dtype)
-        q = jnp.einsum("btr,rhd->bthd", cq, layer["w_uq"].astype(h.dtype))
-        q = jnp.concatenate([q[..., :Dn], _rope_interleaved(
-            q[..., Dn:], pos, config.rope_theta)], axis=-1)
-    with jax.named_scope("mla.down"):
-        c, kr = jnp.split(
-            jnp.einsum("bte,ef->btf", h, layer["w_dkv"].astype(h.dtype)),
-            [R], axis=-1)
-        rows = jnp.concatenate([
-            _rms_norm(c, layer["kv_norm"], config.rms_eps, h.dtype),
-            _rope_interleaved(kr, pos, config.rope_theta)], axis=-1)
-    return q, rows, layer["w_ukv"]
+        q = heads_in(cq, layer["w_uq"].astype(h.dtype), heads_major)
+        q = _latent_q(q, pos, config.qk_nope_head_dim, config.rope_theta,
+                     heads_major)
+    return q, _latent_rows(config, layer, h, pos, heads_major), layer["w_ukv"]
 
 
-def attn_out(config: Config, layer, x, attn):
-    """The heads [B, T, H, Dv] through the output projection, the
-    residual."""
+def attn_out(config: Config, layer, x, attn, heads_major: bool = False):
+    """The heads [B, T, H, Dv] ([B, H, T, Dv] where ``heads_major``)
+    through the output projection, the residual."""
     with jax.named_scope("mla.out"):
-        return x + jnp.einsum("bthd,hde->bte", attn,
-                              layer["wo"].astype(attn.dtype))
+        return x + heads_out(attn, layer["wo"].astype(attn.dtype),
+                             heads_major)
 
 
 def ffn(config: Config, kind, layer, x, rng, row_mask, stacked,

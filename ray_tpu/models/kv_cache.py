@@ -80,6 +80,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops import ssm
+from ray_tpu.ops.attention import heads_in
 from ray_tpu.ops.block_attention import TOKENS, block_attention
 from ray_tpu.ops.decode_attention import (
     TILE,
@@ -344,19 +345,21 @@ LATENT_POSITIONS = 1024
 def latent_kv(rows, up, q_width: int):
     """The full forward's keys and values of a latent layer, a head its
     own: ``rows`` [B, T, R + Dr] (the normed latent and the rotated shared
-    key), ``up`` [R, H, Dn + Dv], queries ``q_width`` = Dn + Dr wide -> (k
-    [B, T, H, Dn + Dr]: the up-projected part and the shared key, the same
-    in every head; v [B, T, H, Dv]). What the attention dispatcher takes in
-    place of [T, T] scores in XLA: the flash kernels on the chip."""
-    R, H = up.shape[:2]
+    key), ``up`` [R, H, Dn + Dv], queries ``q_width`` = Dn + Dr wide -> (the
+    keys' up-projected channels [B, H, T, Dn], the values [B, H, T, Dv], the
+    shared key [B, T, Dr] as the rows hold it). What the attention
+    dispatcher takes in place of [T, T] scores in XLA: the flash kernels on
+    the chip, which read the shared key where it lies, one block a batch
+    row for every head of it, so it is repeated into no head. Two products
+    over the two halves of ``up``, heads-major each: the kernels fold them
+    by a reshape, and a slice of one product's result would be a copy."""
+    R = up.shape[0]
     Dn = q_width - (rows.shape[-1] - R)
     with jax.named_scope("mla.up"):
-        kv = jnp.einsum("bsr,rhd->bshd", rows[..., :R],
-                        up.astype(rows.dtype))
-        shared = jnp.broadcast_to(
-            rows[:, :, None, R:], rows.shape[:2] + (H, rows.shape[-1] - R))
-        return (jnp.concatenate([kv[..., :Dn], shared], axis=-1),
-                kv[..., Dn:])
+        up = up.astype(rows.dtype)
+        k, v = (heads_in(rows[..., :R], w, True)
+                for w in (up[..., :Dn], up[..., Dn:]))
+    return k, v, rows[..., R:]
 
 
 def attend_latent(cache: Dict[str, jax.Array], layer: jax.Array,

@@ -30,6 +30,7 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu.models import narrowed
 from ray_tpu.models.decoder import *  # noqa: F401,F403 — what families share
@@ -213,11 +214,46 @@ def _rms_norm(x, g, eps, dtype=None):
     return (x32 * scale * g).astype(dtype or x.dtype)
 
 
-def _rope(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding. x: [B, T, H, D], pos: [B, T] absolute positions."""
+def _turned_on_lanes(x, angles, partner, sign):
+    """A rotary embedding of the full forward, on x as it lies: x [B, T, D]
+    or, heads-major, [B, H, T, D]; ``angles`` [B, T, D] float32, a channel's
+    own; channel j meets ``sign[j] * x[partner[j]]`` (numpy [D] each) -> x
+    cos + that sin, in float32, as x's dtype. The partner comes through a
+    product with a constant [D, D] matrix of 0 and +-1, which is exact, and
+    the whole is one pass over whole rows: slices of the channels and their
+    concatenation (half a row each, or a [.., D / 2, 2] view, or a roll)
+    make the compiler lay arrays of half a row's lanes out positions-minor
+    or keep them as arrays of their own, padded to whole lane tiles, and
+    copy the result into the kernels' order (sandbox compiles and the
+    traced steps, PR 56)."""
+    D = x.shape[-1]
+    swap = np.zeros((D, D), np.float32)
+    swap[partner, np.arange(D)] = sign
+    if x.ndim == 4:
+        angles = angles[:, None]                             # [B, 1, T, D]
+    # the batch rows as the product's batch dimension: a remat policy that
+    # keeps the products without one (``dots``) then keeps not this one,
+    # which is as cheap to make again as to read
+    other = jnp.einsum("b...d,bde->b...e", x, jnp.broadcast_to(
+        jnp.asarray(swap, x.dtype), (x.shape[0], D, D)),
+        precision=jax.lax.Precision.HIGHEST)
+    return (x.astype(jnp.float32) * jnp.cos(angles)
+            + other.astype(jnp.float32) * jnp.sin(angles)).astype(x.dtype)
+
+
+def _rope(x: jax.Array, pos: jax.Array, theta: float,
+          heads_major: bool = False) -> jax.Array:
+    """Rotary embedding, channel i with channel i + D / 2. x: [B, T, H, D],
+    or [B, H, T, D] where ``heads_major`` (the full forward's:
+    ``_turned_on_lanes``); pos: [B, T] absolute positions."""
     D = x.shape[-1]
     freqs = theta ** (-jnp.arange(0, D // 2, dtype=jnp.float32) / (D // 2))
     angles = pos[..., None].astype(jnp.float32) * freqs      # [B, T, D/2]
+    if heads_major:
+        j = np.arange(D)
+        return _turned_on_lanes(
+            x, jnp.tile(angles, 2), (j + D // 2) % D,
+            np.where(j < D // 2, -1.0, 1.0))
     cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)     # [B, T, 1, D/2]
     sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
     x1, x2 = x[..., : D // 2], x[..., D // 2:]
@@ -248,29 +284,36 @@ def embed(config: LlamaConfig, params, tokens, pos, cached: bool):
         jnp.float32 if cached else config.dtype)
 
 
-def qkv(config: LlamaConfig, kind, layer, x, pos):
+def qkv(config: LlamaConfig, kind, layer, x, pos, heads_major: bool = False):
     """The attention preamble: x [B, T, E] normed, pos [B, T] absolute -> q
     [B, T, KV, G, D] (the G query heads of a kv head together, at G = 1
-    too) and k [B, T, KV, D], both rotated, and v [B, T, KV, D]."""
+    too) and k [B, T, KV, D], both rotated, and v [B, T, KV, D]; where
+    ``heads_major``, q [B, H, T, D], k and v [B, KV, T, D]. A norm over all
+    of a position's channels together is written positions-major only: its
+    q and k are transposed behind it."""
     B, T = x.shape[:2]
+    full_norm = config.qk_norm == "full"
     h = _rms_norm(x, layer["attn_norm"], config.rms_eps, config.dtype)
-    q = jnp.einsum("bte,ehd->bthd", h, layer["wq"].astype(h.dtype))
-    k = jnp.einsum("bte,ehd->bthd", h, layer["wk"].astype(h.dtype))
-    v = jnp.einsum("bte,ehd->bthd", h, layer["wv"].astype(h.dtype))
-    if config.qk_norm == "full":
+    q, k = (heads_in(h, layer[w].astype(h.dtype),
+                     heads_major and not full_norm) for w in ("wq", "wk"))
+    v = heads_in(h, layer["wv"].astype(h.dtype), heads_major)
+    if full_norm:
         q = _rms_norm(q.reshape(B, T, -1), layer["q_norm"],
                       config.rms_eps).reshape(q.shape)
         k = _rms_norm(k.reshape(B, T, -1), layer["k_norm"],
                       config.rms_eps).reshape(k.shape)
-    q = _rope(q, pos, config.rope_theta)
-    return (q.reshape(B, T, config.num_kv_heads, -1, config.head_dim),
-            _rope(k, pos, config.rope_theta), v)
+        if heads_major:
+            q, k = swapped(q, k)
+    q = _rope(q, pos, config.rope_theta, heads_major)
+    k = _rope(k, pos, config.rope_theta, heads_major)
+    if heads_major:
+        return q, k, v
+    return q.reshape(B, T, config.num_kv_heads, -1, config.head_dim), k, v
 
 
-def attn_out(config: LlamaConfig, layer, x, attn):
+def attn_out(config: LlamaConfig, layer, x, attn, heads_major: bool = False):
     """Output projection + residual add."""
-    return x + jnp.einsum("bthd,hde->bte", attn,
-                          layer["wo"].astype(attn.dtype))
+    return x + heads_out(attn, layer["wo"].astype(attn.dtype), heads_major)
 
 
 def ffn(config: LlamaConfig, kind, layer, x, rng, row_mask, stacked,

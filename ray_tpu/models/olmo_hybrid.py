@@ -303,23 +303,26 @@ def _branch(config: Config, x, out, gain):
     return x + _rms_norm(out, gain, config.rms_eps, x.dtype)
 
 
-def qkv(config: Config, kind, layer, x, pos):
+def qkv(config: Config, kind, layer, x, pos, heads_major: bool = False):
     """x [B, T, E] as the stream has it (no norm before a branch) -> q, k
     and v [B, T, H, D]: q and k normed over all their channels together,
-    then split into heads; nothing is rotated."""
+    then split into heads; nothing is rotated. That norm is written
+    positions-major only: where ``heads_major`` q and k are transposed
+    behind it, [B, H, T, D], and v's product writes that order itself."""
     B, T = x.shape[:2]
     h = x.astype(config.dtype)
     q = jnp.einsum("bte,ehd->bthd", h, layer["wq"].astype(h.dtype))
     k = jnp.einsum("bte,ehd->bthd", h, layer["wk"].astype(h.dtype))
-    v = jnp.einsum("bte,ehd->bthd", h, layer["wv"].astype(h.dtype))
-    q = _rms_norm(q.reshape(B, T, -1), layer["q_norm"], config.rms_eps)
-    k = _rms_norm(k.reshape(B, T, -1), layer["k_norm"], config.rms_eps)
-    return q.reshape(v.shape[:2] + (-1, config.head_dim)), k.reshape(
-        v.shape), v
+    v = heads_in(h, layer["wv"].astype(h.dtype), heads_major)
+    q = _rms_norm(q.reshape(B, T, -1), layer["q_norm"],
+                  config.rms_eps).reshape(B, T, -1, config.head_dim)
+    k = _rms_norm(k.reshape(B, T, -1), layer["k_norm"],
+                  config.rms_eps).reshape(k.shape)
+    return (*swapped(q, k), v) if heads_major else (q, k, v)
 
 
-def attn_out(config: Config, layer, x, attn):
-    out = jnp.einsum("bthd,hde->bte", attn, layer["wo"].astype(attn.dtype))
+def attn_out(config: Config, layer, x, attn, heads_major: bool = False):
+    out = heads_out(attn, layer["wo"].astype(attn.dtype), heads_major)
     return _branch(config, x, out, layer["mix_norm"])
 
 

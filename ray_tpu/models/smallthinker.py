@@ -256,23 +256,25 @@ def at_input(config: SmallThinkerConfig, kind, layer, x, stacked):
     return router_logits(moe, x, index)
 
 
-def qkv(config: SmallThinkerConfig, kind, layer, x, pos):
-    """x [B, T, E] normed -> q [B, T, KV, G, D], k and v [B, T, KV, D]; q
-    and k rotated in a window layer only."""
+def qkv(config: SmallThinkerConfig, kind, layer, x, pos, heads_major: bool = False):
+    """x [B, T, E] normed -> q [B, T, KV, G, D], k and v [B, T, KV, D] (q
+    [B, H, T, D], k and v [B, KV, T, D] where ``heads_major``); q and k
+    rotated in a window layer only."""
     B, T = x.shape[:2]
     h = _rms_norm(x, layer["attn_norm"], config.rms_eps, config.dtype)
-    q = jnp.einsum("bte,ehd->bthd", h, layer["wq"].astype(h.dtype))
-    k = jnp.einsum("bte,ehd->bthd", h, layer["wk"].astype(h.dtype))
-    v = jnp.einsum("bte,ehd->bthd", h, layer["wv"].astype(h.dtype))
+    q, k, v = (heads_in(h, layer[w].astype(h.dtype), heads_major)
+               for w in ("wq", "wk", "wv"))
     if kind == WINDOW:
-        q, k = (_rope(a, pos, config.rope_theta) for a in (q, k))
+        q, k = (_rope(a, pos, config.rope_theta, heads_major)
+                for a in (q, k))
+    if heads_major:
+        return q, k, v
     return q.reshape(B, T, config.num_kv_heads, -1, config.head_dim), k, v
 
 
-def attn_out(config: SmallThinkerConfig, layer, x, attn):
+def attn_out(config: SmallThinkerConfig, layer, x, attn, heads_major: bool = False):
     """Output projection + residual add."""
-    return x + jnp.einsum("bthd,hde->bte", attn,
-                          layer["wo"].astype(attn.dtype))
+    return x + heads_out(attn, layer["wo"].astype(attn.dtype), heads_major)
 
 
 def ffn(config: SmallThinkerConfig, kind, layer, x, rng, row_mask, stacked,

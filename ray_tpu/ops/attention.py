@@ -25,7 +25,12 @@ ours. Design:
 - ``attention``: dispatcher — pallas on TPU, XLA elsewhere; tests run the
   same kernel code on the CPU mesh through ``impl="flash_interpret"``.
 
-Shapes follow [batch, seq, heads, head_dim] throughout.
+Shapes are heads-major throughout, [batch, heads, seq, head_dim]: what a
+kernel folds into its [batch x heads, seq, head_dim] is then a reshape, and
+the projections on either side write and read that order themselves
+(``heads_in`` / ``heads_out`` below). Whatever a call still moves (the
+padding of a sequence that is no multiple of its block, the slice that takes
+it off again) lies under ``jax.named_scope("attn.fold")``.
 """
 from __future__ import annotations
 
@@ -36,6 +41,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
@@ -52,8 +58,8 @@ def attention_xla(
     kv_offset: int | jax.Array = 0,
     window: Optional[int] = None,
 ) -> jax.Array:
-    """Dense attention. q: [B, Tq, H, D]; k: [B, Tk, Hkv, D]; v:
-    [B, Tk, Hkv, Dv] -> [B, Tq, H, Dv].
+    """Dense attention. q: [B, H, Tq, D]; k: [B, Hkv, Tk, D]; v:
+    [B, Hkv, Tk, Dv] -> [B, H, Tq, Dv].
     ``window`` (causal only): a query sees itself and the ``window - 1``
     positions before it, a band mask.
 
@@ -61,14 +67,14 @@ def attention_xla(
     position offsets so callers holding only a chunk of the sequence (ring /
     blockwise) mask correctly.
     """
-    B, Tq, H, D = q.shape
-    _, Tk, Hkv, _ = k.shape
+    B, H, Tq, D = q.shape
+    _, Hkv, Tk, _ = k.shape
     if Hkv != H:
         rep = H // Hkv
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
+        k = jnp.repeat(k, rep, axis=1)
+        v = jnp.repeat(v, rep, axis=1)
     scale = D ** -0.5
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     logits = logits.astype(jnp.float32)
     if bias is not None:
         logits = logits + bias
@@ -80,7 +86,7 @@ def attention_xla(
             mask &= k_pos > q_pos - window
         logits = jnp.where(mask[None, None], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
 # --------------------------------------------------------------------- pallas
@@ -231,14 +237,18 @@ def _keep(shape, *, lead, causal: bool, k_left, window=None):
     return keep
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_k: int,
+def _flash_kernel(q_ref, k_ref, v_ref, *rest, block_k: int,
                   strip: int, causal: bool, scale: float, fold: bool,
-                  seq_k: int, square: bool, window: Optional[int] = None):
+                  seq_k: int, square: bool, window: Optional[int] = None,
+                  shared: bool = False):
     """One (batch*head, q_block) program: stream K/V blocks with online
     softmax. Block shapes: q [1, Bq, D], k [1, Tk, D], v [1, Tk, Dv], o
     [1, Bq, Dv] (Dv = D but for latent attention), lse [1, 8, Bq]
     (written only when the training path asks for it: it feeds the backward);
-    scratch m/l [1, Bq], acc [Dv, Bq]. Scores are held keys x queries
+    scratch m/l [1, Bq], acc [Dv, Bq]. With a ``shared`` key (a fourth
+    operand [1, Tk, Dr], one block a batch row that every head of it reads)
+    k is [1, Tk, D - Dr] and a score is two products, q's first channels
+    with k and its last Dr with the shared key. Scores are held keys x queries
     ([n, strip]): a query's max and sum reduce along sublanes and broadcast
     back along them, and q is the product's stationary operand.
 
@@ -252,7 +262,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_k: int,
     at the block that holds the first query's oldest visible key, and the
     blocks before the first one that every query of the block sees whole
     are masked too."""
-    *lse_ref, m_ref, l_ref, acc_ref = rest
+    s_ref, rest = (rest[0], rest[1:]) if shared else (None, rest)
+    o_ref, *lse_ref, m_ref, l_ref, acc_ref = rest
+    Dn = k_ref.shape[2]
     qi = pl.program_id(1)
     block_q = q_ref.shape[1]
     q_off = qi * block_q
@@ -269,8 +281,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_k: int,
     def scores(k_off, n: int, c: int, lead):
         """Keys ``k_off:+n`` against queries ``c:+strip`` of the block;
         ``lead`` as in ``_keep``, None for the plain step."""
-        s = jax.lax.dot_general(k_ref[0, pl.ds(k_off, n), :], q[c:c + strip],
+        s = jax.lax.dot_general(k_ref[0, pl.ds(k_off, n), :],
+                                q[c:c + strip, :Dn],
                                 _NT, preferred_element_type=jnp.float32)
+        if shared:
+            s += jax.lax.dot_general(
+                s_ref[0, pl.ds(k_off, n), :], q[c:c + strip, Dn:], _NT,
+                preferred_element_type=jnp.float32)
         if not fold:
             s = s * scale
         if lead is not None:
@@ -369,11 +386,33 @@ def _vmem_limit(resident_bytes: int):
 
 
 def _folded(x, seq_p: int):
-    """[B, T, H, D] -> [B*H, T padded to ``seq_p``, D]."""
-    B, T, H, D = x.shape
+    """[B, H.., T, D] -> [B x H.., T padded to ``seq_p``, D]: a reshape of
+    the array in row-major order, and a copy only where the sequence is no
+    multiple of its block."""
+    *lead, T, D = x.shape
     if seq_p != T:
-        x = jnp.pad(x, ((0, 0), (0, seq_p - T), (0, 0), (0, 0)))
-    return x.transpose(0, 2, 1, 3).reshape(B * H, seq_p, D)
+        with jax.named_scope("attn.fold"):
+            x = jnp.pad(x, ((0, 0),) * len(lead) + ((0, seq_p - T), (0, 0)))
+    if D >= LANES:
+        # said as a constraint, the kernels' order reaches the product that
+        # makes x; left to the call's own operand layout it is met by a
+        # copy. Not under 128 channels: such a row fills part of its lanes,
+        # so an array kept in this order (a residual of every layer) is
+        # padded to twice its size, and the compiler's own order, positions
+        # minor, with a copy into the call is the cheaper (GPT-2's 64)
+        x = with_layout_constraint(
+            x, Layout(major_to_minor=tuple(range(x.ndim))))
+    return x.reshape(math.prod(lead), seq_p, D)
+
+
+def _unfolded(x, like, seq: int):
+    """A call's [B x H, T padded, D] result as ``like``'s [B, H, ``seq``,
+    D]."""
+    x = x.reshape(*like.shape[:2], *x.shape[1:])
+    if x.shape[2] != seq:
+        with jax.named_scope("attn.fold"):
+            x = x[:, :, :seq]
+    return x
 
 
 def _kv_index(rep: int):
@@ -395,11 +434,12 @@ def _call_name(direction: str, window, D: int, Dv: int) -> str:
     return f"flash_{kind}{direction}"
 
 
-def _flash_fwd_impl(q, k, v, *, causal: bool, block_q: Optional[int],
-                    block_k: Optional[int], interpret: bool,
-                    with_lse: bool = False, window: Optional[int] = None):
-    B, Tq, H, D = q.shape
-    _, Tk, Hkv, _ = k.shape
+def _flash_fwd_impl(q, k, v, shared=None, *, causal: bool,
+                    block_q: Optional[int], block_k: Optional[int],
+                    interpret: bool, with_lse: bool = False,
+                    window: Optional[int] = None):
+    B, H, Tq, D = q.shape
+    _, Hkv, Tk, _ = k.shape
     Dv = v.shape[-1]    # the values' width, and the result's: D where equal
     rep = H // Hkv
     window = _window_of(window, causal, Tk)
@@ -411,19 +451,30 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, block_q: Optional[int],
     # Pad keys are masked in-kernel via seq_k; pad q rows are sliced off.
     Tq_p, Tk_p = _pad_to(Tq, block_q), _pad_to(Tk, block_k)
     scale = D ** -0.5
-    qf, kf, vf = _folded(q, Tq_p), _folded(k, Tk_p), _folded(v, Tk_p)
+    operands = [_folded(q, Tq_p), _folded(k, Tk_p), _folded(v, Tk_p)]
+    kv = _kv_index(rep)
+    in_specs = [
+        pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
+        pl.BlockSpec((1, Tk_p, k.shape[-1]), lambda b, i: (kv(b), 0, 0)),
+        pl.BlockSpec((1, Tk_p, Dv), lambda b, i: (kv(b), 0, 0)),
+    ]
+    if shared is not None:
+        # one block a batch row, the same for every head of it: fetched
+        # once a row, as a grouped kv head is once a group
+        operands.append(_folded(shared, Tk_p))
+        in_specs.append(pl.BlockSpec((1, Tk_p, shared.shape[-1]),
+                                     lambda b, i: (b // H, 0, 0)))
     kernel = functools.partial(
         _flash_kernel, block_k=block_k, strip=_strip(block_q, _FWD_STRIP),
         causal=causal, scale=scale, fold=_fold_scale(q.dtype, scale),
         seq_k=Tk, square=_square(Tq, Tk, block_q, block_k, causal, window),
-        window=window,
+        window=window, shared=shared is not None,
     )
     out_shape = [jax.ShapeDtypeStruct((B * H, Tq_p, Dv), q.dtype)]
     out_specs = [pl.BlockSpec((1, block_q, Dv), lambda b, i: (b, i, 0))]
     if with_lse:
         out_shape.append(jax.ShapeDtypeStruct((B * H, 8, Tq_p), jnp.float32))
         out_specs.append(pl.BlockSpec((1, 8, block_q), lambda b, i: (b, 0, i)))
-    kv = _kv_index(rep)
     size = q.dtype.itemsize
     limit = _vmem_limit(Tk_p * (D + Dv) * size + block_q * (D + Dv) * size
                         + block_q * Dv * 4)
@@ -433,11 +484,7 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, block_q: Optional[int],
         kernel,
         out_shape=out_shape,
         grid=(B * H, Tq_p // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, Tk_p, D), lambda b, i: (kv(b), 0, 0)),
-            pl.BlockSpec((1, Tk_p, Dv), lambda b, i: (kv(b), 0, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((1, block_q), jnp.float32),  # running max
@@ -448,20 +495,17 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, block_q: Optional[int],
            if limit else {}),
         interpret=interpret,
         name=_call_name("fwd", window, D, Dv),
-    )(qf, kf, vf)
-    out = out.reshape(B, H, Tq_p, Dv).transpose(0, 2, 1, 3)
-    if Tq_p != Tq:
-        out = out[:, :Tq]
+    )(*operands)
+    out = _unfolded(out, q, Tq)
     if with_lse:
         return out, lse[0]  # [B*H, 8, Tq_p], the backward's layout
     return out
 
 
-def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
-                      block_q: int, strip: int, chunk: int, causal: bool,
-                      scale: float, fold: bool, seq_k: int, square: bool,
-                      window: Optional[int] = None):
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, *rest, block_q: int, strip: int,
+                      chunk: int, causal: bool, scale: float, fold: bool,
+                      seq_k: int, square: bool,
+                      window: Optional[int] = None, heads: int = 0):
     """One (batch*head, k_block) program of the ONE backward pass: dK/dV of
     this key block and this key block's part of dQ, from S, P and dP computed
     once a block pair (five products). Scores are held keys x queries
@@ -471,7 +515,12 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     lse/delta [1, 8, Tq] (row 0 is
     the data; the 8 rows are sublane replication for mosaic's block-shape
     rules); scratch dq_acc [Tq, D] f32, alive across the key-block axis and
-    written at its last step, dk_acc/dv_acc [Bk, D] f32.
+    written at its last step, dk_acc/dv_acc [Bk, D] f32. With a shared key
+    (``heads``: how many heads read each of its blocks; a fourth operand
+    [1, Bk, Dr] as the forward's) k and dk are D - Dr wide, dq's last Dr
+    channels come from the shared key, and its gradient is a fourth result
+    [1, Tk, Dr], one block a batch row that stays in VMEM while the row's
+    heads add to its float32 scratch [Tk, Dr] and the last one writes it.
 
     Padded queries need no mask (their dO and delta are zero and their lse
     is finite); padded keys and the diagonal do, on the blocks that hold
@@ -481,16 +530,33 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     over the query blocks ends at the last one that sees a key of this
     block, and the blocks after the last one whose every query sees the
     block whole are masked too."""
+    if heads:
+        (s_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dsh_ref,
+         dq_acc, dk_acc, dv_acc, dsh_acc) = rest
+    else:
+        (do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+         dq_acc, dk_acc, dv_acc) = rest
     kj = pl.program_id(1)
     n_kb = pl.num_programs(1)
-    block_k = k_ref.shape[1]
+    block_k, Dn = k_ref.shape[1:]
     k_off = kj * block_k
+    tail = seq_k % block_k != 0
+
+    def scaled(x):
+        return (x.astype(jnp.float32) * scale).astype(x.dtype) if fold else x
+
     k = k_ref[0]  # [Bk, D]
     v = v_ref[0]
-    ks = k
-    if fold:
-        ks = (k.astype(jnp.float32) * scale).astype(k.dtype)
-    tail = seq_k % block_k != 0
+    ks = scaled(k)
+    if heads:
+        head = pl.program_id(0) % heads
+        rows = pl.ds(pl.multiple_of(k_off, block_k), block_k)
+        sh = s_ref[0]  # [Bk, Dr]
+        shs = scaled(sh)
+
+        @pl.when(head == 0)
+        def _():
+            dsh_acc[rows, :] = jnp.zeros((block_k, sh.shape[1]), jnp.float32)
 
     @pl.when(kj == 0)
     def _():
@@ -505,8 +571,11 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         cols = pl.ds(q_off, width)
         q_c = q_ref[0, cols, :]
         do_c = do_ref[0, cols, :]
-        s = jax.lax.dot_general(ks[:n], q_c, _NT,
+        s = jax.lax.dot_general(ks[:n], q_c[:, :Dn], _NT,
                                 preferred_element_type=jnp.float32)
+        if heads:
+            s += jax.lax.dot_general(shs[:n], q_c[:, Dn:], _NT,
+                                     preferred_element_type=jnp.float32)
         if not fold:
             s = s * scale
         p = jnp.exp(s - lse_ref[0, 0:1, cols])
@@ -522,9 +591,15 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dp = jax.lax.dot_general(v[:n], do_c, _NT,
                                  preferred_element_type=jnp.float32)
         ds = (p * (dp - delta_ref[0, 0:1, cols])).astype(q_c.dtype)
-        dk_acc[:n, :] += jnp.dot(ds, q_c, preferred_element_type=jnp.float32)
-        dq_acc[cols, :] += jax.lax.dot_general(
+        dk_acc[:n, :] += jnp.dot(ds, q_c[:, :Dn],
+                                 preferred_element_type=jnp.float32)
+        dq_acc[cols, :Dn] += jax.lax.dot_general(
             ds, k[:n], _TN, preferred_element_type=jnp.float32)
+        if heads:
+            dsh_acc[pl.ds(pl.multiple_of(k_off, block_k), n), :] += jnp.dot(
+                ds, q_c[:, Dn:], preferred_element_type=jnp.float32)
+            dq_acc[cols, Dn:] += jax.lax.dot_general(
+                ds, sh[:n], _TN, preferred_element_type=jnp.float32)
 
     def block(masked: bool):
         def body(i, _):
@@ -575,22 +650,29 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     # dS was left unscaled: the scale goes on the [*, D] results.
     dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+    if heads:
+        @pl.when(head == heads - 1)
+        def _():
+            dsh_ref[0, rows, :] = (dsh_acc[rows, :] * scale).astype(
+                dsh_ref.dtype)
 
     @pl.when(kj == n_kb - 1)
     def _():
         dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
-def _flash_bwd_impl(q, k, v, out, lse, g, *, causal: bool,
+def _flash_bwd_impl(q, k, v, shared, out, lse, g, *, causal: bool,
                     block_q: Optional[int], block_k: Optional[int],
                     interpret: bool, window: Optional[int] = None):
     """Pallas flash backward, one call: no [Tq, Tk] materialization. Returns
-    (dq, dk, dv) with GQA head-group reduction applied: every query head
-    reads its kv head's k and v where they lie and writes a dk and dv of its
-    own, which are summed over the group afterwards."""
-    B, Tq, H, D = q.shape
-    _, Tk, Hkv, _ = k.shape
-    Dv = v.shape[-1]    # of v, out, g and dv; q, k, dq and dk are D wide
+    (dq, dk, dv, the shared key's gradient or None) with GQA head-group
+    reduction applied: every query head reads its kv head's k and v where
+    they lie and writes a dk and dv of its own, which are summed over the
+    group afterwards. A shared key's gradient is summed over its heads
+    inside the call."""
+    B, H, Tq, D = q.shape
+    _, Hkv, Tk, Dn = k.shape    # Dn: D, less a shared key's width
+    Dv = v.shape[-1]    # of v, out, g and dv; q and dq are D wide
     rep = H // Hkv
     window = _window_of(window, causal, Tk)
     block_q, block_k = _blocks(Tq, Tk, block_q, block_k)
@@ -607,7 +689,8 @@ def _flash_bwd_impl(q, k, v, out, lse, g, *, causal: bool,
     size = q.dtype.itemsize
     limit = _vmem_limit(Tq_p * (2 * D + Dv) * size + Tq_p * D * 4
                         + 2 * 8 * Tq_p * 4
-                        + 2 * block_k * (D + Dv) * (size + 2))
+                        + 2 * block_k * (D + Dv) * (size + 2)
+                        + (0 if shared is None else Tk_p * LANES * (4 + size)))
 
     def whole_q(width):
         return pl.BlockSpec((1, Tq_p, width), lambda b, j: (b, 0, 0))
@@ -619,99 +702,184 @@ def _flash_bwd_impl(q, k, v, out, lse, g, *, causal: bool,
         return pl.BlockSpec((1, block_k, width), lambda b, j: (b, j, 0))
 
     stats = pl.BlockSpec((1, 8, Tq_p), lambda b, j: (b, 0, 0))
-    dq, dk, dv = pl.pallas_call(
+    operands = [qf, kf, vf]
+    in_specs = [whole_q(D), k_block(Dn), k_block(Dv)]
+    out_shape = [jax.ShapeDtypeStruct((B * H, Tq_p, D), q.dtype),
+                 jax.ShapeDtypeStruct((B * H, Tk_p, Dn), q.dtype),
+                 jax.ShapeDtypeStruct((B * H, Tk_p, Dv), q.dtype)]
+    out_specs = [whole_q(D), dk_block(Dn), dk_block(Dv)]
+    scratch = [pltpu.VMEM((Tq_p, D), jnp.float32),      # dq, across key blocks
+               pltpu.VMEM((block_k, Dn), jnp.float32),  # dk
+               pltpu.VMEM((block_k, Dv), jnp.float32)]  # dv
+    semantics = ("parallel", "arbitrary")
+    if shared is not None:
+        Dr = shared.shape[-1]
+        operands.append(_folded(shared, Tk_p))
+        in_specs.append(pl.BlockSpec((1, block_k, Dr),
+                                     lambda b, j: (b // H, j, 0)))
+        # a batch row's block is revisited by each of its heads in turn, so
+        # the heads run one after another and none on another core
+        out_shape.append(jax.ShapeDtypeStruct((B, Tk_p, Dr), q.dtype))
+        out_specs.append(pl.BlockSpec((1, Tk_p, Dr),
+                                      lambda b, j: (b // H, 0, 0)))
+        scratch.append(pltpu.VMEM((Tk_p, Dr), jnp.float32))
+        semantics = ("arbitrary", "arbitrary")
+    dq, dk, dv, *dshared = pl.pallas_call(
         functools.partial(
             _flash_bwd_kernel, block_q=block_q,
             strip=_strip(block_q, _BWD_STRIP),
             chunk=_strip(block_q, _BWD_CHUNK), causal=causal, scale=scale,
             fold=_fold_scale(q.dtype, scale), seq_k=Tk,
             square=_square(Tq, Tk, block_q, block_k, causal, window),
-            window=window,
+            window=window, heads=0 if shared is None else H,
         ),
-        out_shape=(
-            jax.ShapeDtypeStruct((B * H, Tq_p, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, Tk_p, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, Tk_p, Dv), q.dtype),
-        ),
+        out_shape=out_shape,
         grid=(B * H, Tk_p // block_k),
-        in_specs=[whole_q(D), k_block(D), k_block(Dv), whole_q(Dv), stats,
-                  stats],
-        out_specs=(whole_q(D), dk_block(D), dk_block(Dv)),
-        scratch_shapes=[
-            pltpu.VMEM((Tq_p, D), jnp.float32),      # dq, across key blocks
-            pltpu.VMEM((block_k, D), jnp.float32),   # dk
-            pltpu.VMEM((block_k, Dv), jnp.float32),  # dv
-        ],
+        in_specs=in_specs + [whole_q(Dv), stats, stats],
+        out_specs=out_specs,
+        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"), **limit),
+            dimension_semantics=semantics, **limit),
         interpret=interpret,
         name=_call_name("bwd", window, D, Dv),
-    )(qf, kf, vf, dof, lse, delta)
+    )(*operands, dof, lse, delta)
 
-    dq = dq.reshape(B, H, Tq_p, D).transpose(0, 2, 1, 3)[:, :Tq]
-    dk = dk.reshape(B, H, Tk_p, D).transpose(0, 2, 1, 3)[:, :Tk]
-    dv = dv.reshape(B, H, Tk_p, Dv).transpose(0, 2, 1, 3)[:, :Tk]
+    dq, dk, dv = _unfolded(dq, q, Tq), _unfolded(dk, q, Tk), \
+        _unfolded(dv, q, Tk)
     if rep != 1:
-        dk = dk.reshape(B, Tk, Hkv, rep, D).sum(axis=3)
-        dv = dv.reshape(B, Tk, Hkv, rep, Dv).sum(axis=3)
-    return dq, dk, dv
+        with jax.named_scope("attn.fold"):
+            dk = dk.reshape(B, Hkv, rep, Tk, Dn).sum(axis=2)
+            dv = dv.reshape(B, Hkv, rep, Tk, Dv).sum(axis=2)
+    if shared is None:
+        return dq, dk, dv, None
+    with jax.named_scope("attn.fold"):
+        return dq, dk, dv, dshared[0][:, :Tk]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def swapped(*arrays):
+    """[B, T, H, D] <-> [B, H, T, D], each of ``arrays``: a copy of each,
+    under the scope that says so in a trace. For code whose arrays are
+    written in one order only, at its own door."""
+    with jax.named_scope("attn.fold"):
+        return tuple(a.swapaxes(1, 2) for a in arrays)
+
+
 def flash_attention(q, k, v, causal: bool = True,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: bool = False,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None, shared=None):
     """Flash attention: pallas forward AND pallas backward (LSE saved by
     the forward; backward never materializes the [Tq, Tk] score matrix —
     round 2 recomputed attention in XLA for grads, which put three dense
-    [B, H, Tq, Tk] tensors back into every train step). q [B, T, H, D], k
-    [B, T, Hkv, D] and v [B, T, Hkv, Dv] with H a multiple of Hkv ->
-    [B, T, H, Dv]; the values may be narrower or wider than the keys (latent
+    [B, H, Tq, Tk] tensors back into every train step). q [B, H, T, D], k
+    [B, Hkv, T, D] and v [B, Hkv, T, Dv] with H a multiple of Hkv ->
+    [B, H, T, Dv]; the values may be narrower or wider than the keys (latent
     attention: 192 and 128), and where they are not the calls are the ones
-    they were; ``window`` as ``attention_xla``'s."""
+    they were; ``window`` as ``attention_xla``'s. ``shared`` [B, T, Dr]: a
+    key every head shares (latent attention's rotated one), read where it
+    lies: k is then [B, H, T, D - Dr], each head's own channels, and the
+    scores are those of ``concatenate([k, shared a head], -1)``.
+
+    Heads under 128 channels wide (GPT-2's 64) cross the calls' boundary
+    POSITIONS-major, [B, T, H, D], as their projection's product writes
+    them: a row of the kernels' order fills part of its lanes, so nothing
+    is kept in that order and the arrays are copied into and out of it
+    around each call, inside the ``custom_vjp`` (``_flash_narrow``). A
+    caller that transposed such arrays to get here (``gpt2.qkv``) has its
+    transposes undone by the ones here, and the compiler drops both: every
+    heads-major product tried at that width lost on one cell or another
+    (my chip runs, PR 56: three products of the fused matrix's slices +2.9%
+    on one chip and -3.2% on four, three rings of weight shards under
+    ``fsdp`` where one was; the fused product transposed outside the
+    ``custom_vjp`` -1.8 to -2.6% on one chip)."""
+    if q.shape[-1] < LANES and shared is None:
+        (out,) = swapped(_flash_narrow(
+            *swapped(q, k, v), causal, block_q, block_k, interpret, window))
+        return out
+    return _flash_wide(q, k, v, causal, block_q, block_k, interpret, window,
+                       shared)
+
+
+def _named(out, lse):
+    """The forward's residuals under the names a remat policy keeps them
+    by: without them a jax.checkpoint around the transformer block re-runs
+    the flash forward a second time in the backward pass just to rebuild
+    them."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    return checkpoint_name(out, "flash_out"), checkpoint_name(lse, "flash_lse")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_wide(q, k, v, causal, block_q, block_k, interpret, window, shared):
     return _flash_fwd_impl(
-        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
+        q, k, v, shared, causal=causal, block_q=block_q, block_k=block_k,
         interpret=interpret, window=window,
     )
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, window):
-    out, lse = _flash_fwd_impl(
-        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
+def _flash_wide_fwd(q, k, v, causal, block_q, block_k, interpret, window,
+                    shared):
+    out, lse = _named(*_flash_fwd_impl(
+        q, k, v, shared, causal=causal, block_q=block_q, block_k=block_k,
         interpret=interpret, with_lse=True, window=window,
-    )
-    # Named so remat policies can keep them: without this, a jax.checkpoint
-    # around the transformer block re-runs the flash forward a second time
-    # in the backward pass just to rebuild these residuals.
-    from jax.ad_checkpoint import checkpoint_name
-
-    out = checkpoint_name(out, "flash_out")
-    lse = checkpoint_name(lse, "flash_lse")
-    return out, (q, k, v, out, lse)
+    ))
+    return out, (q, k, v, shared, out, lse)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, window, res, g):
-    q, k, v, out, lse = res
+def _flash_wide_bwd(causal, block_q, block_k, interpret, window, res, g):
+    q, k, v, shared, out, lse = res
     return _flash_bwd_impl(
-        q, k, v, out, lse, g, causal=causal, block_q=block_q,
+        q, k, v, shared, out, lse, g, causal=causal, block_q=block_q,
         block_k=block_k, interpret=interpret, window=window,
     )
 
 
-flash_attention.defvjp(_flash_fwd, _flash_bwd)
+_flash_wide.defvjp(_flash_wide_fwd, _flash_wide_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_narrow(q, k, v, causal, block_q, block_k, interpret, window):
+    """``flash_attention`` on [B, T, H, D] arrays, in and out: the
+    transposes into and out of the kernels' order inside the rule, forward
+    and backward, so that what is kept between them (q, k, v, the result)
+    lies as the products around it wrote and read it."""
+    (out,) = swapped(_flash_fwd_impl(
+        *swapped(q, k, v), causal=causal, block_q=block_q, block_k=block_k,
+        interpret=interpret, window=window))
+    return out
+
+
+def _flash_narrow_fwd(q, k, v, causal, block_q, block_k, interpret, window):
+    out, lse = _flash_fwd_impl(
+        *swapped(q, k, v), causal=causal, block_q=block_q, block_k=block_k,
+        interpret=interpret, with_lse=True, window=window)
+    out, lse = _named(*swapped(out), lse)
+    return out, (q, k, v, out, lse)
+
+
+def _flash_narrow_bwd(causal, block_q, block_k, interpret, window, res, g):
+    q, k, v, out, lse = res
+    q, k, v, out, g = swapped(q, k, v, out, g)
+    return swapped(*_flash_bwd_impl(
+        q, k, v, None, out, lse, g, causal=causal, block_q=block_q,
+        block_k=block_k, interpret=interpret, window=window)[:3])
+
+
+_flash_narrow.defvjp(_flash_narrow_fwd, _flash_narrow_bwd)
 
 
 def attention(
     q, k, v, *, causal: bool = True, impl: str = "auto",
     block_q: Optional[int] = None, block_k: Optional[int] = None,
-    mesh=None, window: Optional[int] = None,
+    mesh=None, window: Optional[int] = None, shared=None,
 ):
     """Dispatcher. impl: auto | xla | flash | flash_interpret. ``auto`` is
     decided by platform alone: the pallas kernel on TPU (a kernel that fails
     to lower or compile there raises — it never gives way to XLA), XLA
-    attention elsewhere.
+    attention elsewhere. q [B, H, T, D], k and v [B, Hkv, T, ..]; ``shared``
+    as ``flash_attention``'s (XLA attends the key it stands for).
 
     ``mesh``: the mesh the surrounding jit is sharded over. GSPMD cannot
     partition a Mosaic custom call, so on a mesh of several devices the
@@ -720,16 +888,19 @@ def attention(
     if impl == "auto":
         impl = "flash" if jax.default_backend() == "tpu" else "xla"
     if impl == "xla":
+        if shared is not None:
+            k = with_shared(k, shared)
         return attention_xla(q, k, v, causal=causal, window=window)
     if impl not in ("flash", "flash_interpret"):
         raise ValueError(f"unknown attention impl {impl}")
 
-    def kernel(q, k, v):
+    def kernel(q, k, v, *shared):
         return flash_attention(
             q, k, v, causal, block_q, block_k, impl == "flash_interpret",
-            window,
+            window, *shared,
         )
 
+    shared = () if shared is None else (shared,)
     if mesh is not None and mesh.size > 1:
         from jax.sharding import PartitionSpec as P
 
@@ -742,15 +913,58 @@ def attention(
         free = set(mesh.axis_names) - held
         batch = tuple(a for a in ("data", "fsdp") if a in free)
         heads = "tensor" if "tensor" in free else None
-        spec = P(batch or None, None, heads, None)
-        if heads and k.shape[2] % mesh.shape["tensor"]:
+        spec = P(batch or None, heads, None, None)
+        if heads and k.shape[1] % mesh.shape["tensor"]:
             # fewer kv heads than the axis divides: every shard needs whole
             # groups, so the kv heads are spread to the query heads first
-            rep = q.shape[2] // k.shape[2]
-            k, v = (jnp.repeat(a, rep, axis=2) for a in (k, v))
+            rep = q.shape[1] // k.shape[1]
+            with jax.named_scope("attn.fold"):
+                k, v = (jnp.repeat(a, rep, axis=1) for a in (k, v))
         if free:
             kernel = jax.shard_map(
                 kernel, mesh=None if held else mesh, axis_names=free,
-                in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
+                in_specs=(spec,) * 3
+                + (P(batch or None, None, None),) * len(shared),
+                out_specs=spec, check_vma=False,
             )
-    return kernel(q, k, v)
+    return kernel(q, k, v, *shared)
+
+
+def with_shared(k, shared):
+    """k [B, H, T, Dn] with ``shared`` [B, T, Dr] behind each head's
+    channels: the keys [B, H, T, Dn + Dr] that a call with a shared key
+    attends without making them (XLA's path and the tests' oracles do)."""
+    return jnp.concatenate([k, jnp.broadcast_to(
+        shared[:, None], k.shape[:3] + shared.shape[-1:])], axis=-1)
+
+
+# ------------------------------------------- the projections on either side
+# What stands between a projection's product and a kernel is a reshape only if
+# the product writes [B, H, T, D] with D minor, and between a kernel and the
+# weights' gradient only if that product reads it so. The chip's compiler lays
+# a product's result out by its own rule (positions minor wherever a width is
+# under the 128 lanes) and meets a Mosaic call's operand layout with a copy of
+# the whole array. Two things make it write and read the kernels' order
+# instead (sandbox compiles for a described v5e, PR 56;
+# ``tests/test_tpu_compile.py`` counts the copies that are left): ``_folded``
+# states the order as a layout constraint, which the compiler carries back
+# through a rotation and a concatenation to the product itself; and the
+# heads-major projection names its WEIGHTS first, so that its transpose, the
+# weights' gradient, takes dq, dk and dv as they lie (with the activations
+# first it asks for them positions-minor: three copies a layer).
+
+
+def heads_in(x, w, heads_major: bool):
+    """x [B, T, E] through w [E, H, D] -> [B, H, T, D] where ``heads_major``
+    (the full forward: what a kernel folds by a reshape), else [B, T, H, D]
+    (the cached forward's rows)."""
+    if heads_major:
+        return jnp.einsum("ehd,bte->bhtd", w, x)
+    return jnp.einsum("bte,ehd->bthd", x, w)
+
+
+def heads_out(attn, w, heads_major: bool):
+    """The heads, [B, H, T, D] where ``heads_major`` else [B, T, H, D],
+    through w [H, D, E] -> [B, T, E]."""
+    return jnp.einsum("bhtd,hde->bte" if heads_major else "bthd,hde->bte",
+                      attn, w)

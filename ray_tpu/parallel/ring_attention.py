@@ -23,6 +23,18 @@ from jax import shard_map
 from ray_tpu.ops.attention import NEG_INF
 
 
+def _whole_sequence(q, k, v, causal: bool, impl: str = "xla"):
+    """Attention over a sequence that is whole on this device, [B, T, H, D]
+    as this module keeps its arrays (its shards are of the sequence):
+    through ``ops.attention``, which is heads-major."""
+    from ray_tpu.ops.attention import attention_xla, flash_attention
+
+    q, k, v = (a.swapaxes(1, 2) for a in (q, k, v))
+    out = (flash_attention(q, k, v, causal) if impl == "flash"
+           else attention_xla(q, k, v, causal=causal))
+    return out.swapaxes(1, 2)
+
+
 def _blockwise_piece(q, k, v, scale, q_chunk, kv_chunk, t_local, causal):
     """Attention logits piece between the local Q chunk and one K/V chunk,
     returning (unnormalized o, running max m, running denom l) inputs for
@@ -68,9 +80,7 @@ def ring_attention(
         qkv_spec = P(("data", "fsdp"), axis, "tensor", None)
     n = mesh.shape[axis]
     if n == 1:
-        from ray_tpu.ops.attention import attention_xla
-
-        return attention_xla(q, k, v, causal=causal)
+        return _whole_sequence(q, k, v, causal)
 
     scale = q.shape[-1] ** -0.5
 
@@ -132,10 +142,8 @@ def ulysses_attention(
     if qkv_spec is None:
         qkv_spec = P(("data", "fsdp"), axis, "tensor", None)
     n = mesh.shape[axis]
-    from ray_tpu.ops.attention import attention_xla, flash_attention
-
     if n == 1:
-        return attention_xla(q, k, v, causal=causal)
+        return _whole_sequence(q, k, v, causal)
     if q.shape[2] % n != 0:
         raise ValueError(f"heads {q.shape[2]} not divisible by {axis}={n}")
 
@@ -149,12 +157,8 @@ def ulysses_attention(
             return jax.lax.all_to_all(x, axis, split_axis=1, concat_axis=2,
                                       tiled=True)
 
-        qg, kg, vg = swap_in(q), swap_in(k), swap_in(v)
-        if impl == "flash":
-            o = flash_attention(qg, kg, vg, causal)
-        else:
-            o = attention_xla(qg, kg, vg, causal=causal)
-        return swap_out(o)
+        return swap_out(_whole_sequence(
+            swap_in(q), swap_in(k), swap_in(v), causal, impl))
 
     return shard_map(
         local_fn,

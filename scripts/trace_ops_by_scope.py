@@ -20,6 +20,15 @@ is a whole path element) does not find; this file finds it, and prints
 beside its own sum the one the benchmark's readers make. No cell runs this
 file. ISSUE 41's table came from PR 40's trace by hand; this prints it from
 any.
+
+Since PR 56 also the attention's rows of a training step, named here (the
+benchmark's list is ``benchmarks/lib/moe_ops.py:SCOPES``): the ``mla.*`` and
+``mtp.*`` scopes (an operation counts under its innermost one), the flash
+kernels by their instruction's name, ``attn.fold`` (every pad, slice, repeat
+or transpose that still stands between a projection and a flash call:
+``ops/attention.py``, its ``swapped`` among them), and, so that a trace
+from before that scope reads beside one with it, the copies and transposes
+of whole arrays that lie under no scope at all.
 """
 from __future__ import annotations
 
@@ -37,18 +46,29 @@ from benchmarks.lib import moe_ops, op_scopes  # noqa: E402
 from benchmarks.lib import trace as T  # noqa: E402
 
 RESULT = re.compile(r" = \(?(\w+\[[\d,]*\])")
-SCOPE = re.compile(r"(?:^|[/(])(%s)(?:[/):]|$)" % "|".join(
-    re.escape(s) for s in moe_ops.SCOPES))
+FLASH, UNSCOPED_COPIES = "flash kernels", "copies under no scope"
+SCOPES = moe_ops.SCOPES + (
+    "mla.q", "mla.down", "mla.up", "mla.out", "mtp.in", "mtp.block",
+    "mtp.head", "attn.fold")
+SCOPE = re.compile(r"(?:^|[/(])(%s)(?=[/):]|$)" % "|".join(
+    re.escape(s) for s in SCOPES))
 
 
 def scope_of(meta: op_scopes.OpMeta):
-    """(the operation's ``moe.*`` scope or None, whether the benchmark's
-    readers find it)."""
+    """(the operation's row: a flash kernel by its name, else its innermost
+    scope of ``SCOPES``, else a copy's own row or None; whether the
+    benchmark's ``moe.*`` readers find it)."""
+    name = T.instruction_name(meta.text)
+    if T.is_kernel(meta.text) and "flash_" in name:
+        return FLASH, False
     theirs = moe_ops.scope_of(meta)
     if theirs is not None:
         return theirs, True
-    m = SCOPE.search(meta.op_name)
-    return (m.group(1) if m else None), False
+    found = SCOPE.findall(meta.op_name)
+    if found:
+        return found[-1], False
+    moved = "copy" in name or "transpose" in name
+    return (UNSCOPED_COPIES if moved else None), False
 
 
 # the grouped products' kernels by instruction name: this repo's (PR 44 on)
@@ -128,10 +148,11 @@ def main(argv=None) -> int:
             cell[0] += own
             cell[1] += 1
     layer = 0
-    for scope in moe_ops.SCOPES:
+    for scope in SCOPES + (FLASH, UNSCOPED_COPIES):
         under = sum(own for (s, _), c in by.items() if s == scope
                     for own, _ in c.values())
-        layer += under
+        if scope in moe_ops.SCOPES:
+            layer += under
         print(f"{scope}: {under * ms:.2f} ms a step")
         for (s, direction), cells in sorted(by.items()):
             if s != scope:

@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.attention import (
-    attention_xla, flash_attention, flash_block_counts,
+    attention_xla, flash_attention, flash_block_counts, with_shared,
 )
 from ray_tpu.parallel.mesh import MeshConfig
 from ray_tpu.parallel.ring_attention import ring_attention, ulysses_attention
@@ -14,11 +14,18 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 
 def _make_qkv(B=2, T=128, H=4, D=32, dtype=jnp.float32, seed=0):
+    """Heads-major, [B, H, T, D], as the attention ops take them."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    q = jax.random.normal(ks[0], (B, T, H, D), dtype)
-    k = jax.random.normal(ks[1], (B, T, H, D), dtype)
-    v = jax.random.normal(ks[2], (B, T, H, D), dtype)
+    q = jax.random.normal(ks[0], (B, H, T, D), dtype)
+    k = jax.random.normal(ks[1], (B, H, T, D), dtype)
+    v = jax.random.normal(ks[2], (B, H, T, D), dtype)
     return q, k, v
+
+
+def _swapped(*arrays):
+    """[B, H, T, D] <-> [B, T, H, D]: the order the sequence-parallel
+    attentions keep, whose shards are of the sequence."""
+    return tuple(a.swapaxes(1, 2) for a in arrays)
 
 
 def test_flash_matches_xla_causal():
@@ -56,16 +63,16 @@ def test_ring_attention_matches_dense(causal):
     q, k, v = _make_qkv(B=2, T=128, H=4, D=16)
     spec = P(None, "seq", None, None)
     sharding = NamedSharding(mesh, spec)
-    qs, ks, vs = (jax.device_put(x, sharding) for x in (q, k, v))
+    qs, ks, vs = (jax.device_put(x, sharding) for x in _swapped(q, k, v))
     out = ring_attention(qs, ks, vs, mesh=mesh, axis="seq", causal=causal,
                          qkv_spec=spec)
-    ref = attention_xla(q, k, v, causal=causal)
+    (ref,) = _swapped(attention_xla(q, k, v, causal=causal))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
 
 def test_ring_attention_grads():
     mesh = MeshConfig(data=1, seq=4).build(jax.devices()[:4])
-    q, k, v = _make_qkv(B=1, T=64, H=2, D=8)
+    q, k, v = _swapped(*_make_qkv(B=1, T=64, H=2, D=8))
     spec = P(None, "seq", None, None)
 
     def loss_ring(q, k, v):
@@ -73,7 +80,7 @@ def test_ring_attention_grads():
                               qkv_spec=spec).sum()
 
     def loss_ref(q, k, v):
-        return attention_xla(q, k, v, causal=True).sum()
+        return attention_xla(*_swapped(q, k, v), causal=True).sum()
 
     g1 = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
     g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
@@ -85,9 +92,9 @@ def test_ulysses_matches_dense():
     mesh = MeshConfig(data=1, seq=4).build(jax.devices()[:4])
     q, k, v = _make_qkv(B=2, T=128, H=4, D=16)
     spec = P(None, "seq", None, None)
-    out = ulysses_attention(q, k, v, mesh=mesh, axis="seq", causal=True,
-                            qkv_spec=spec)
-    ref = attention_xla(q, k, v, causal=True)
+    out = ulysses_attention(*_swapped(q, k, v), mesh=mesh, axis="seq",
+                            causal=True, qkv_spec=spec)
+    (ref,) = _swapped(attention_xla(q, k, v, causal=True))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
 
@@ -139,10 +146,10 @@ def test_flash_bwd_kernel_gqa_and_ragged(causal, T, block, D, H, Hkv):
     ks = jax.random.split(key, 4)
     B = 1
     D, Dv = D if isinstance(D, tuple) else (D, D)
-    q = jax.random.normal(ks[0], (B, T, H, D), jnp.float32)
-    k = jax.random.normal(ks[1], (B, T, Hkv, D), jnp.float32)
-    v = jax.random.normal(ks[2], (B, T, Hkv, Dv), jnp.float32)
-    g = jax.random.normal(ks[3], (B, T, H, Dv), jnp.float32)
+    q = jax.random.normal(ks[0], (B, H, T, D), jnp.float32)
+    k = jax.random.normal(ks[1], (B, Hkv, T, D), jnp.float32)
+    v = jax.random.normal(ks[2], (B, Hkv, T, Dv), jnp.float32)
+    g = jax.random.normal(ks[3], (B, H, T, Dv), jnp.float32)
     np.testing.assert_allclose(
         np.asarray(flash_attention(q, k, v, causal, block, block, True)),
         np.asarray(attention_xla(q, k, v, causal=causal)),
@@ -214,7 +221,7 @@ def test_flash_under_mesh_runs_per_shard(axes):
 
     mesh = MeshConfig(**axes).build()
     q, k, v = _make_qkv(B=8, T=64, H=4, D=32)
-    spec = NamedSharding(mesh, P(("data", "fsdp"), None, "tensor", None))
+    spec = NamedSharding(mesh, P(("data", "fsdp"), "tensor", None, None))
     q, k, v = (jax.device_put(x, spec) for x in (q, k, v))
 
     def loss(impl, q, k, v):
@@ -239,10 +246,11 @@ def test_flash_under_mesh_runs_per_shard(axes):
 def _window_oracle(q, k, v, window):
     """``decoder._window_attention`` over k and v spread to the query heads:
     the band mask in plain XLA that the kernels replace on the chip."""
-    from ray_tpu.models.decoder import _repeat_kv, _window_attention
+    from ray_tpu.models.decoder import _window_attention
 
-    rep = q.shape[2] // k.shape[2]
-    return _window_attention(q, _repeat_kv(k, rep), _repeat_kv(v, rep), window)
+    rep = q.shape[1] // k.shape[1]
+    return _window_attention(
+        q, jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1), window)
 
 
 @pytest.mark.parametrize("T,block,window,H,Hkv,D", [
@@ -263,10 +271,10 @@ def test_flash_window_matches_the_band_mask(T, block, window, H, Hkv, D):
     order of their sums (measured 3e-6 forward, 2e-5 on the gradients; a
     window off by one position reads 1e-2 and more)."""
     ks = jax.random.split(jax.random.PRNGKey(5), 4)
-    q = jax.random.normal(ks[0], (2, T, H, D), jnp.float32)
-    k = jax.random.normal(ks[1], (2, T, Hkv, D), jnp.float32)
-    v = jax.random.normal(ks[2], (2, T, Hkv, D), jnp.float32)
-    g = jax.random.normal(ks[3], (2, T, H, D), jnp.float32)
+    q = jax.random.normal(ks[0], (2, H, T, D), jnp.float32)
+    k = jax.random.normal(ks[1], (2, Hkv, T, D), jnp.float32)
+    v = jax.random.normal(ks[2], (2, Hkv, T, D), jnp.float32)
+    g = jax.random.normal(ks[3], (2, H, T, D), jnp.float32)
 
     def flash(q, k, v):
         return flash_attention(q, k, v, True, block, block, True, window)
@@ -310,4 +318,97 @@ def test_a_window_is_causal_self_attention():
     with pytest.raises(ValueError, match="causal"):
         flash_attention(q, k, v, False, None, None, True, 64)
     with pytest.raises(ValueError, match="self-attention"):
-        flash_attention(q[:, :64], k, v, True, None, None, True, 32)
+        flash_attention(q[:, :, :64], k, v, True, None, None, True, 32)
+
+
+@pytest.mark.parametrize("T,block,widths,H,window", [
+    (300, 128, (128, 64, 128), 2, None),   # the published widths, ragged
+    (200, 64, (16, 8, 16), 3, None),       # toy widths, three heads a row
+    (200, 64, (16, 8, 24), 3, 50),         # a window, the values the wider
+    (256, None, (16, 8, 16), 2, None),     # one block, the diagonal's strips
+    (200, (64, 128), (16, 8, 16), 3, 120),  # blocks of two sizes, a window
+], ids=str)
+def test_flash_with_a_shared_key_as_an_operand(T, block, widths, H, window):
+    """Latent attention's calls with the rotated key as an operand of its
+    own, [B, T, Dr], which every head of a batch row reads where it lies,
+    against ``attention_xla`` on the keys with it repeated into every head
+    and concatenated: the result and all four gradients, the shared key's
+    summed over the heads inside the backward call, in float32 through the
+    interpreter at lengths no block divides (measured 3e-6 on the result and
+    on each gradient)."""
+    Dn, Dr, Dv = widths
+    bq, bk = block if isinstance(block, tuple) else (block, block)
+    ks = jax.random.split(jax.random.PRNGKey(7), 5)
+    q = jax.random.normal(ks[0], (2, H, T, Dn + Dr), jnp.float32)
+    k = jax.random.normal(ks[1], (2, H, T, Dn), jnp.float32)
+    v = jax.random.normal(ks[2], (2, H, T, Dv), jnp.float32)
+    shared = jax.random.normal(ks[3], (2, T, Dr), jnp.float32)
+    g = jax.random.normal(ks[4], (2, H, T, Dv), jnp.float32)
+
+    def flash(q, k, v, shared):
+        return flash_attention(q, k, v, True, bq, bk, True, window, shared)
+
+    def plain(q, k, v, shared):
+        return attention_xla(q, with_shared(k, shared), v, window=window)
+
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v, shared)), np.asarray(plain(q, k, v, shared)),
+        atol=2e-5, rtol=2e-5)
+    got, want = (
+        jax.grad(lambda *a: jnp.vdot(f(*a), g), argnums=(0, 1, 2, 3))(
+            q, k, v, shared) for f in (flash, plain))
+    assert got[3].shape == shared.shape
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-4, rtol=2e-4)
+    # and the shared key is something: without it the scores are others
+    assert float(jnp.abs(
+        flash(q, k, v, shared) - flash(q, k, v, 0 * shared)).max()) > 1e-2
+
+
+@pytest.mark.parametrize("shape", [(2, 40, 64), (2, 3, 40, 64), (1, 7, 8)],
+                         ids=str)
+def test_rope_on_the_lanes_is_the_interleaved_rotation(shape):
+    """The full forward's rotation (a roll each way and a select by parity,
+    on [B, T, D] or heads-major [B, H, T, D]) against the cached forward's
+    (pairs as a [.., D / 2, 2] view, on [B, T, .., D]) to float32 rounding,
+    and its gradient with it."""
+    from ray_tpu.models.bailing_hybrid import _rope_interleaved, _rope_lanes
+
+    B, T = shape[0], shape[-2]
+    x = jax.random.normal(jax.random.PRNGKey(9), shape, jnp.float32)
+    pos = jnp.arange(T)[None] + jnp.asarray([[5], [300]])[:B]
+    swap = (lambda a: a.swapaxes(1, 2)) if x.ndim == 4 else (lambda a: a)
+
+    def lanes(x):
+        return _rope_lanes(x, pos, 32e6)
+
+    def pairs(x):
+        return swap(_rope_interleaved(swap(x), pos, 32e6))
+
+    np.testing.assert_allclose(np.asarray(lanes(x)), np.asarray(pairs(x)),
+                               atol=1e-6, rtol=1e-6)
+    g = jax.random.normal(jax.random.PRNGKey(10), shape, jnp.float32)
+    np.testing.assert_allclose(
+        *(np.asarray(jax.grad(lambda x: jnp.vdot(f(x), g))(x))
+          for f in (lanes, pairs)), atol=1e-6, rtol=1e-6)
+    assert lanes(x.astype(jnp.bfloat16)).dtype == jnp.bfloat16
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=str)
+def test_rope_by_halves_on_the_lanes_is_the_cached_forwards(dtype):
+    """llama's rotation (channel i with i + D / 2) as the full forward
+    writes it, heads-major and whole rows at a time, against the cached
+    forward's slices and concatenation: float32 rounding in float32, one
+    rounding of the result in bfloat16 (the cached form rounds cos, sin and
+    each product)."""
+    from ray_tpu.models.llama import _rope
+
+    x = jax.random.normal(jax.random.PRNGKey(11), (2, 40, 3, 64), dtype)
+    pos = jnp.arange(40)[None] + jnp.asarray([[0], [1000]])
+    got = _rope(x.swapaxes(1, 2), pos, 1e4, heads_major=True).swapaxes(1, 2)
+    want = _rope(x, pos, 1e4)
+    assert got.dtype == dtype
+    tol = 2e-6 if dtype == jnp.float32 else 4e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=tol, rtol=0)

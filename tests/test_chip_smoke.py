@@ -28,7 +28,7 @@ def _train(records, attention_impl):
     # node processes inherit conftest's 8 virtual CPU devices
     return chip_smoke.train_phase(
         TOY, expected_platform="cpu", attention_impl=attention_impl,
-        batch_size=8, num_steps=5, parity_shape=(1, 256, 2, 64), seed=0,
+        batch_size=8, num_steps=5, parity_shape=(1, 2, 256, 64), seed=0,
         emit=records.append,
     )
 
@@ -71,7 +71,7 @@ def test_sharded_phase_at_toy_size(records):
     device = chip_smoke.sharded_phase(
         TOY, expected_platform="cpu", attention_impl="flash_interpret",
         mesh={"data": 2, "fsdp": 2, "tensor": 2}, batch_size=8, num_steps=4,
-        parity_shape=(4, 256, 2, 64), seed=0, emit=records.append,
+        parity_shape=(4, 2, 256, 64), seed=0, emit=records.append,
     )
     assert device["count"] == 8
     run = next(r for r in records if "sharded_losses" in r)

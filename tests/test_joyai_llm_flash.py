@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import decoder, joyai_llm_flash as family
+from ray_tpu.models import bailing_hybrid, decoder, joyai_llm_flash as family
 from ray_tpu.ops import xent
 from ray_tpu.parallel import moe
 
@@ -197,11 +197,13 @@ def _last_position_unmasked(monkeypatch, cfg):
 
 def _rope_by_halves(x, pos, theta):
     """Channel i turned with channel i + D / 2 (llama's layout) where the
-    configuration pairs (2i, 2i + 1)."""
+    configuration pairs (2i, 2i + 1); x [B, T, D] or, as the full forward
+    has the queries, [B, H, T, D]."""
     D = x.shape[-1]
     angle = pos.astype(jnp.float32)[..., None] / theta ** (
         jnp.arange(0, D, 2, dtype=jnp.float32) / D)
-    angle = angle.reshape(pos.shape + (1,) * (x.ndim - 3) + (D // 2,))
+    if x.ndim == 4:
+        angle = angle[:, None]
     a, b = x[..., :D // 2], x[..., D // 2:]
     return jnp.concatenate([a * jnp.cos(angle) - b * jnp.sin(angle),
                             a * jnp.sin(angle) + b * jnp.cos(angle)], -1)
@@ -213,14 +215,15 @@ def _wrong_scale(monkeypatch, cfg):
     real = decoder._attention_dispatch
     wrong = (cfg.head_dim / cfg.qk_nope_head_dim) ** 0.5
     monkeypatch.setattr(
-        decoder, "_attention_dispatch", lambda config, q, k, v, mesh,
-        window=None: real(config, q * wrong, k, v, mesh, window))
+        decoder, "_attention_dispatch", lambda config, q, *rest, **kw:
+        real(config, q * wrong, *rest, **kw))
 
 
 FAULTS = {
     "no norm on cq": _no_query_norm,
+    # the full forward's rotation, which the families' latent layers share
     "rotation by halves": lambda mp, cfg: mp.setattr(
-        family, "_rope_interleaved", _rope_by_halves),
+        bailing_hybrid, "_rope_lanes", _rope_by_halves),
     "scale 1 / sqrt(nope width)": _wrong_scale,
     "the bias added to the gates": _bias_in_the_gates,
     "routed_scaling_factor left out": None,     # a configuration's

@@ -666,3 +666,48 @@ def test_a_prompt_longer_than_the_largest_bucket_is_admitted_in_chunks(
         DecodeEngine(LLMConfig(
             model_family=family, max_seq_len=16, prefill_buckets=(8,),
             **extra)).generate(list(range(2, 18)))
+
+
+# --------------------------------------- the two orders of a family's heads
+# The cached forward calls ``qkv`` / ``attn_out`` as it always did, [B, T, H,
+# D]; the full forward asks for [B, H, T, D] (``heads_major=True``), which a
+# family's products write themselves. One projection, two orders: the same
+# numbers.
+
+
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_heads_major_pieces_are_the_cached_ones_transposed(family):
+    module = family_module(family)
+    cfg = dataclasses.replace(get_preset(TINY[family]), dtype=jnp.float32)
+    params = module.init_params(cfg, jax.random.PRNGKey(0))
+    segments, _ = module.layers(cfg, params["blocks"], cached=False)
+    B, T = 2, 12
+    x = jax.random.normal(jax.random.PRNGKey(1), (B, T, cfg.embed_dim))
+    pos = jnp.arange(T)[None] + jnp.asarray([[3], [40]])
+    seen = 0
+    for segment in segments:
+        for kind, stacked in zip(segment.kinds, segment.params):
+            if kind.state is not None:
+                continue
+            seen += 1
+            layer = jax.tree.map(lambda a: a[0], stacked)
+            got = module.qkv(cfg, kind.name, layer, x, pos, heads_major=True)
+            want = list(module.qkv(cfg, kind.name, layer, x, pos))
+            # grouped query heads [B, T, KV, G, D] are flat, kv-major, there
+            want[0] = want[0].reshape(B, T, -1, want[0].shape[-1])
+            if kind.latent is not None:   # the rows and the up-projection
+                want[1:] = [a.swapaxes(1, 2) for a in want[1:]]    # as given
+            for a, b in zip(got, want):
+                np.testing.assert_allclose(
+                    np.asarray(a), np.asarray(b.swapaxes(1, 2)),
+                    atol=2e-6, rtol=2e-6)
+            attn = jax.random.normal(jax.random.PRNGKey(2), want[0].shape[:3]
+                                     + got[-1].shape[-1:])
+            if kind.latent is not None:
+                attn = attn[..., :cfg.v_head_dim]
+            np.testing.assert_allclose(
+                np.asarray(module.attn_out(cfg, layer, x, attn.swapaxes(1, 2),
+                                           heads_major=True)),
+                np.asarray(module.attn_out(cfg, layer, x, attn)),
+                atol=2e-6, rtol=2e-6)
+    assert seen

@@ -64,8 +64,9 @@ def _flash_grads(q, k, v):
     )(q, k, v)
 
 
-# GPT-2-small at the smoke/bench batch, GPT-2-medium, and head-dim 128.
-WIDTHS = [(32, 1024, 12, 64), (16, 1024, 16, 64), (4, 2048, 16, 128)]
+# GPT-2-small at the smoke/bench batch, GPT-2-medium, and head-dim 128:
+# [B, H, T, D], heads-major as the attention ops take them.
+WIDTHS = [(32, 12, 1024, 64), (16, 16, 1024, 64), (4, 16, 2048, 128)]
 
 
 @pytest.mark.parametrize("shape", WIDTHS, ids=str)
@@ -87,8 +88,8 @@ def test_flash_calls_are_what_the_roofline_reader_looks_for(one_chip, blocks):
     chip."""
     from benchmarks.lib import kernels, trace
 
-    B, T, H, D = WIDTHS[1]
-    x = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16, sharding=one_chip)
+    B, H, T, D = WIDTHS[1]
+    x = jax.ShapeDtypeStruct((B, H, T, D), jnp.bfloat16, sharding=one_chip)
 
     def grads(q, k, v):
         return jax.grad(
@@ -109,8 +110,8 @@ def test_flash_attention_compiles_inside_a_sharded_jit(topo):
     import numpy as np
 
     mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("fsdp", "tensor"))
-    spec = NamedSharding(mesh, P("fsdp", None, "tensor", None))
-    x = jax.ShapeDtypeStruct((32, 1024, 12, 64), jnp.bfloat16, sharding=spec)
+    spec = NamedSharding(mesh, P("fsdp", "tensor", None, None))
+    x = jax.ShapeDtypeStruct((32, 12, 1024, 64), jnp.bfloat16, sharding=spec)
     fn = functools.partial(attention, causal=True, impl="flash", mesh=mesh)
     compiled = jax.jit(fn).lower(x, x, x).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -127,7 +128,7 @@ def test_flash_attention_compiles_inside_a_pipeline_stage(topo):
 
     mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("stage", "data"))
     spec = NamedSharding(mesh, P("data", None, None, None))
-    x = jax.ShapeDtypeStruct((32, 1024, 12, 64), jnp.bfloat16, sharding=spec)
+    x = jax.ShapeDtypeStruct((32, 12, 1024, 64), jnp.bfloat16, sharding=spec)
     stage = jax.shard_map(
         functools.partial(attention, causal=True, impl="flash", mesh=mesh),
         mesh=mesh, axis_names={"stage"}, in_specs=(P(), P(), P()),
@@ -823,9 +824,9 @@ def test_flash_with_a_window_and_grouped_heads_compiles_for_v5e(
     head are 2 MB each in VMEM, past the compiler's own 16 MiB limit, which
     the calls raise for themselves; and each kind of call carries its name
     (``benchmarks/lib/train_moe.py`` costs a call by it)."""
-    q = jax.ShapeDtypeStruct((2, 8192, 28, 128), jnp.bfloat16,
+    q = jax.ShapeDtypeStruct((2, 28, 8192, 128), jnp.bfloat16,
                              sharding=one_chip)
-    k = jax.ShapeDtypeStruct((2, 8192, 4, 128), jnp.bfloat16,
+    k = jax.ShapeDtypeStruct((2, 4, 8192, 128), jnp.bfloat16,
                              sharding=one_chip)
 
     def grads(q, k, v):
@@ -841,7 +842,7 @@ def test_flash_with_a_window_and_grouped_heads_compiles_for_v5e(
     assert sum(fwd in c for c in calls) == sum(bwd in c for c in calls) == 1
     # k and v go in with the kv heads they have: nothing 28 heads wide but
     # q, o, their gradients and each query head's own dk and dv
-    assert "bf16[2,8192,28,128]" in text and "bf16[8,8192,128]" in text
+    assert "bf16[2,28,8192,128]" in text and "bf16[8,8192,128]" in text
 
 
 # (R, G, T, dtype, buffers) at the edges of ``rows_to_tokens.engages`` for
@@ -1050,28 +1051,153 @@ def test_smallthinker_step_fits_the_chip_with_its_window_in_the_kernels(
 # layer, batch 2 x 8192 (``benchmarks/configs/joyai-llm-flash.json``).
 
 
-def test_flash_with_keys_wider_than_values_compiles_for_v5e(one_chip):
+def _pallas_calls(text):
+    """The instruction of each Pallas call in a compiled program, as a
+    trace's reader sees it (``name = (results) custom-call(operands)``)."""
+    return [line for line in text.splitlines()
+            if "tpu_custom_call" in line and " = " in line]
+
+
+@pytest.mark.parametrize("shared", [False, True],
+                         ids=["keys-whole", "shared-key-an-operand"])
+def test_flash_with_keys_wider_than_values_compiles_for_v5e(one_chip, shared):
     """32 heads at 8192 tokens, keys 192 and values 128 wide in one call,
     forward and backward: 192 is one and a half lane tiles, which the
     compiler takes as a block's whole last dimension; nothing is padded to
-    256 in HBM (q, k, dq and dk are [.., 192], v, o and dv [.., 128]); and
-    the calls carry the name a trace's reader knows them by
-    (``benchmarks/lib/train_mla.py``)."""
-    q = jax.ShapeDtypeStruct((2, 8192, 32, 192), jnp.bfloat16,
-                             sharding=one_chip)
-    v = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16,
-                             sharding=one_chip)
-    text = jax.jit(_flash_grads).lower(q, q, v).compile().as_text()
-    calls = [line.split(" = ") for line in text.splitlines()
-             if "tpu_custom_call" in line and " = " in line]
+    256 in HBM; and the calls carry the name a trace's reader knows them by
+    (``benchmarks/lib/train_mla.py``), whose first results it checks: o
+    [64, 8192, 128] forward, dq [64, 8192, 192] backward, by its own
+    ``FIRST_RESULT``. With the rotated key as an operand of its own
+    ([2, 8192, 64], a fourth operand that every head of a row reads), k and
+    dk are the 128 channels a head has of its own and the shared key's
+    gradient is a fourth result, summed over the heads inside the call."""
+    from benchmarks.lib import train_mla
+
+    def S(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    q, v = S(2, 32, 8192, 192), S(2, 32, 8192, 128)
+    if shared:
+        def grads(q, k, v, shared):
+            return jax.grad(
+                lambda *a: flash_attention(
+                    *a[:3], True, None, None, False, None, a[3])
+                .astype(jnp.float32).sum(), argnums=(0, 1, 2, 3))(
+                    q, k, v, shared)
+        lowered = jax.jit(grads).lower(q, v, v, S(2, 8192, 64))
+    else:
+        lowered = jax.jit(_flash_grads).lower(q, q, v)
+    text = lowered.compile().as_text()
+    calls = _pallas_calls(text)
     assert len(calls) == 2
-    (fwd,), (bwd,) = ([result for name, result in calls if kind in name]
+    (fwd,), (bwd,) = ([call for call in calls if kind in call.split(" = ")[0]]
                       for kind in ("flash_mla_fwd", "flash_mla_bwd"))
-    assert fwd.startswith("(bf16[64,8192,128]")
-    assert bwd.startswith(
-        "(bf16[64,8192,192]{2,1,0:T(8,128)(2,1)}, bf16[64,8192,192]"
-        "{2,1,0:T(8,128)(2,1)}, bf16[64,8192,128]")
+    assert train_mla.FIRST_RESULT.search(fwd).group(1) == "64,8192,128"
+    assert train_mla.FIRST_RESULT.search(bwd).group(1) == "64,8192,192"
+    results, operands = bwd.split(" = ")[1].split(" custom-call(")
+    dk = "128" if shared else "192"
+    assert results.startswith(
+        "(bf16[64,8192,192]{2,1,0:T(8,128)(2,1)}, bf16[64,8192," + dk
+        + "]{2,1,0:T(8,128)(2,1)}, bf16[64,8192,128]")
+    if shared:   # its gradient a result, itself an operand
+        assert "bf16[2,8192,64]" in results
+        assert "bf16[2,8192,64]{2,1,0}" in operands
     assert ",256]" not in text
+
+
+# --------------------------------- what crosses the flash kernels' boundary
+# A projection's product writes, and the weights' gradient reads, the
+# kernels' own [B x H, T, D]: no whole-array copy stands between them (PR
+# 56). XLA lays a product's result out by its own rule, so this holds only
+# as long as ``ops/attention.py`` says the order as a layout constraint and
+# names a heads-major projection's weights first: a later change that
+# brings a copy back fails here, on the CPU.
+
+
+def _mixer_gradient(one_chip, cell_name):
+    """The compiled gradient of ONE mixer of a training cell's model (the
+    last layer's attention between the family's two pieces, under the
+    step's remat) with respect to its input and its weights, at the cell's
+    batch: text of the program for the described chip."""
+    import dataclasses
+
+    from benchmarks import run
+    from benchmarks.lib import program
+    from ray_tpu.models import config_for, decoder, module_for
+
+    _, cell, config, _, _ = run.load_cell(cell_name)
+    model = program.trainer_model(config)
+    cfg = dataclasses.replace(
+        config_for(model.pop("family"), **model), attention_impl="flash")
+    family = module_for(cfg)
+    params = jax.eval_shape(
+        lambda: family.init_params(cfg, jax.random.PRNGKey(0)))
+    segment = family.layers(cfg, params["blocks"], cached=False)[0][-1]
+    kind = segment.kinds[-1]
+    layer = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype,
+                                       sharding=one_chip),
+        segment.params[-1])
+    B, T = cell["job"]["batch_size"], cell["job"]["seq_len"]
+    x = jax.ShapeDtypeStruct((B, T, cfg.embed_dim), cfg.dtype,
+                             sharding=one_chip)
+
+    def loss(x, layer):
+        pos = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
+        mixer = jax.checkpoint(
+            functools.partial(decoder._mixer, cfg, None, pos, kind),
+            policy=decoder._remat_policy(cfg))
+        return mixer(layer, x).astype(jnp.float32).sum()
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        x, layer).compile().as_text()
+
+
+def _whole_array_copies(text, floor=30e6):
+    """The ``copy`` / ``transpose`` instructions of ``floor`` bytes or more
+    in a compiled program's entry computation, fusions of that name too:
+    [(name, shape with its layout)]."""
+    import re
+
+    sizes = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
+    lines = text.splitlines()
+    entry = next(i for i, line in enumerate(lines) if line.startswith("ENTRY"))
+    found = []
+    for line in lines[entry:]:
+        m = re.match(r"\s*(?:ROOT )?(\S+) = (\w+)\[([\d,]*)\](\{[^}]*\}) "
+                     r"([\w-]+)\(", line)
+        if m is None:
+            continue
+        name, dtype, dims, layout, op = m.groups()
+        moved = op in ("copy", "transpose") or (
+            op == "fusion" and ("copy" in name or "transpose" in name))
+        size = sizes.get(dtype, 4)
+        for d in filter(None, dims.split(",")):
+            size *= int(d)
+        if moved and size >= floor:
+            found.append((name, f"{dtype}[{dims}]{layout}"))
+    return found
+
+
+@pytest.mark.parametrize("cell, left", [
+    # 15 before (2.2 GB a mixer: q, k, v and o into the kernels' order,
+    # forward and again under remat, the keys' concatenation, dO in and dq,
+    # dk, dv out, the rotation's pair views); sandbox compile, PR 56
+    ("joyai-llm-flash.train-seq8k", 0),
+    # 5 before: q, o, dO and dq at 28 heads (k and v at 4 are under 30 MB)
+    ("smallthinker-21b-a3b.train-seq8k", 0),
+    # 11 before and 11 now: at 64 channels a head the kernels' order fills
+    # half of each row's lanes, so ``_folded`` asks for it under 128 channels
+    # of no product (a residual kept in that order is padded to twice its
+    # size: the whole step was refused by 1.64 GB with it), the compiler
+    # lays its products out positions-minor and copies each array into and
+    # out of the calls, as it did. Held so that it does not grow.
+    ("gpt2-medium.train-steady", 11),
+])
+def test_no_whole_array_copy_stands_around_the_flash_calls(one_chip, cell,
+                                                           left):
+    copies = _whole_array_copies(_mixer_gradient(one_chip, cell))
+    assert len(copies) <= left, copies
 
 
 def test_joyai_step_fits_the_chip_with_latent_attention_in_the_kernels(
