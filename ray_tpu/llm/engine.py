@@ -118,6 +118,14 @@ keeps one row a position and layer in the same pytree; ``latent_positions``
 of ``engine.tick`` is what the tick needed of it (``cache_positions`` a
 latent layer), ``layers_full`` counts no such layer, and a chunk's
 ``prefill_key_positions`` count them as layers that attend everything.
+Where those layers choose what they attend (a lightning indexer,
+``decoder.Layer.index``), ``index_positions`` of ``engine.tick`` and
+``engine.admit`` is what the indexer scored (each live slot's, or each of a
+chunk's queries', visible positions, a latent layer) and
+``selected_positions`` what attention was then asked to read (the kept
+positions or the visible ones, whichever is fewer, a query and layer);
+``stats`` sums both, and ``engine.admit.cache`` carries the ``bytes`` of the
+slot cache an admission fills.
 """
 from __future__ import annotations
 
@@ -377,6 +385,10 @@ class DecodeEngine:
         self._layers_state = sum(k.state is not None for k in kinds)
         # latent layers: one row a position that every head shares
         self._layers_latent = sum(k.latent is not None for k in kinds)
+        # and the positions a query of theirs keeps, where an indexer
+        # chooses them (0: every earlier one)
+        self._index_kept = max(
+            (k.index.kept for k in kinds if k.index is not None), default=0)
         # the layers hold a share of the experts: the programs count the
         # rows the held experts computed beside the experts touched
         self._moe_share = bool(self._moe_top_k) and (
@@ -425,6 +437,9 @@ class DecodeEngine:
         # ring has room for it beside the window (``models/kv_cache.py``)
         block = max(*config.prefill_buckets, 1 + self._spec_k)
         self._cache = decoder.init_kv_cache(cfg, B, S, block=block)
+        # what one slot holds of it: the cache an admission fills
+        self._slot_cache_bytes = sum(
+            leaf.nbytes // B for leaf in jax.tree.leaves(self._cache))
         self._layers_full = (
             len(kinds) - self._layers_window - self._layers_state
             - self._layers_latent)
@@ -507,6 +522,10 @@ class DecodeEngine:
             # positions of the latent layers' cache the ticks needed, summed
             # over those layers (0 for a model with none)
             "latent_positions": 0,
+            # positions an indexer scored and positions attention was then
+            # asked to read, ticks and admissions, summed over the latent
+            # layers (0 for a model whose layers attend everything)
+            "index_positions": 0, "selected_positions": 0,
             # state layers: slots that decode x state layers over ticks (the
             # states a tick reads and writes), and prompt tokens that went
             # through a prefill's scan; both 0 for a model with none
@@ -686,9 +705,22 @@ class DecodeEngine:
                 for layers, length, window in self._attended),
             "prefill_cache_positions": len(chunks) * sum(
                 layers * length for layers, length, _ in self._attended)}
+        if self._index_kept:
+            seen = np.concatenate(
+                [np.arange(start + 1, start + T + 1) for start, T in chunks]
+                or [np.zeros(0, np.int64)])
+            counts.update(self._chosen_positions(seen))
         for name, count in counts.items():
             self.stats[name] += count
         return counts
+
+    def _chosen_positions(self, seen) -> dict:
+        """``index_positions`` and ``selected_positions`` of queries that
+        see ``seen`` positions each, over the latent layers."""
+        return {
+            "index_positions": int(seen.sum()) * self._layers_latent,
+            "selected_positions": int(np.minimum(
+                seen, self._index_kept).sum()) * self._layers_latent}
 
     def _experts_touched(self, touched) -> int:
         """A span's ``experts_touched`` of a program's count a layer, on the
@@ -758,7 +790,7 @@ class DecodeEngine:
         if entry is not None:
             cache1 = entry["cache"]
         else:
-            with span("engine.admit.cache"):
+            with span("engine.admit.cache", bytes=self._slot_cache_bytes):
                 cache1 = self._empty_slot_cache()
         programs = []  # (lengths read of it, its logits, its touched)
         chunks = []    # (where it starts, its tokens with the padding)
@@ -1110,6 +1142,9 @@ class DecodeEngine:
                 if self._layers_latent:
                     positions["latent_positions"] = (
                         cache_positions * self._layers_latent)
+                if self._index_kept:
+                    positions.update(
+                        self._chosen_positions(needed[list(rows)]))
                 if drafts:
                     sent = (jnp.asarray(toks), jnp.asarray(lens),
                             *self._real(real))

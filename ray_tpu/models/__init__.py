@@ -10,8 +10,12 @@ calls (``layers``, ``embed``, ``qkv``, ``attn_out``, ``ffn``, ``final_norm``,
 ``head``, ``head_weight``, ``at_input`` where its feed-forward reads the
 block's input (the decoder's default is None), and ``state_in`` /
 ``state_out`` / ``state_leaves`` where a layer keeps a state (Mamba-2's in
-``granite_hybrid``, the gated delta rule's in ``olmo_hybrid``: the kind's
-``Layer.recurrence`` says which); ``decoder.py`` gives each one's signature) and the one a server calls once
+``granite_hybrid``, the gated delta rule's in ``olmo_hybrid``, Kimi Delta
+Attention's in ``bailing_hybrid``: the kind's ``Layer.recurrence`` says
+which; a latent layer's ``qkv`` gives a row a position and the
+up-projection, ``bailing_hybrid``'s, ``joyai_llm_flash``'s and, the ninth
+family, ``deepseek_v32``'s, whose fourth piece is its lightning indexer at
+the call's tokens, ``Layer.index``); ``decoder.py`` gives each one's signature) and the one a server calls once
 (``serving_params``), and it hands the decoder's functions on under its own
 name, so ``module_for(cfg).loss_fn`` is the one definition. A new
 architecture is a family module, or a piece of one, and one line of
@@ -24,7 +28,8 @@ trainer from its ``model`` dictionary, ``LLMConfig`` from its sizes and its
 ``model`` (checked against :func:`config_keys`). ``MOE_KEYS`` is the one
 table of a router's flat names, read here alone.
 The KV cache is ``{"k", "v"}``, each ``[L, B, KV, D, S]``, and for a model with
-window layers their rings, with state layers their states, beside it
+window layers their rings, with state layers their states, with latent
+layers their rows (and an indexer's keys), beside it
 (``kv_cache.py``): callers outside this
 package rely on the slot being axis 1 of every leaf and on nothing else.
 """
@@ -46,6 +51,7 @@ FAMILIES = {
     "olmo_hybrid": "ray_tpu.models.olmo_hybrid",
     "bailing_hybrid": "ray_tpu.models.bailing_hybrid",
     "joyai_llm_flash": "ray_tpu.models.joyai_llm_flash",
+    "deepseek_v32": "ray_tpu.models.deepseek_v32",
 }
 
 

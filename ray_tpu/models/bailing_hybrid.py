@@ -48,8 +48,9 @@ one scan, the latent layer).
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -358,12 +359,50 @@ def embed(config: Config, params, tokens, pos, cached: bool):
         jnp.float32 if cached else config.dtype)
 
 
-def _rope_interleaved(x, pos, theta: float):
+class Yarn(NamedTuple):
+    """YaRN's change to a rotation's frequencies (arXiv:2309.00071, as
+    DeepSeek-V3's ``rope_scaling`` states it): a context ``factor`` times
+    the ``original`` one; a frequency that turns more than ``beta_fast``
+    times over the original context stays, one that turns fewer than
+    ``beta_slow`` times is divided by the factor, a linear ramp between."""
+    factor: float
+    original: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+
+    def softmax_scale(self, width: int, mscale_all_dim: float) -> float:
+        """``width ^ -0.5 x m^2``, ``m = 0.1 x mscale_all_dim x ln factor +
+        1``: what the longer context does to the attention's temperature
+        (the cosines and sines are not scaled)."""
+        m = 0.1 * mscale_all_dim * math.log(self.factor) + 1.0
+        return width ** -0.5 * m * m
+
+
+def _inv_freq(D: int, theta: float, yarn: Optional[Yarn] = None):
+    """The D / 2 frequencies of a rotation over D channels at ``theta``
+    [D / 2] float32; under ``yarn`` the ramp's blend of each with itself
+    divided by the factor."""
+    freq = 1.0 / theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+    if yarn is None:
+        return freq
+
+    def turns(n):   # the channel pair that turns n times in the original
+        return D * math.log(yarn.original / (n * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(turns(yarn.beta_fast)), 0)
+    high = min(math.ceil(turns(yarn.beta_slow)), D - 1)
+    ramp = jnp.clip((jnp.arange(D // 2, dtype=jnp.float32) - low)
+                    / ((high if high != low else high + 0.001) - low), 0, 1)
+    return freq / yarn.factor * ramp + freq * (1 - ramp)
+
+
+def _rope_interleaved(x, pos, theta: float, yarn: Optional[Yarn] = None):
     """x [B, T, .., D] rotated by its position: pair (2i, 2i + 1) by
     frequency i (``rope_interleave``), in float32."""
     D = x.shape[-1]
-    inv_freq = 1.0 / theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D)
-    angles = pos.astype(jnp.float32)[..., None] * inv_freq   # [B, T, D / 2]
+    freq = _inv_freq(D, theta, yarn)
+    angles = pos.astype(jnp.float32)[..., None] * freq       # [B, T, D / 2]
     angles = angles.reshape(pos.shape + (1,) * (x.ndim - 3) + (D // 2,))
     pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], D // 2, 2)
     even, odd = pairs[..., 0], pairs[..., 1]
@@ -372,26 +411,28 @@ def _rope_interleaved(x, pos, theta: float):
                      axis=-1).reshape(x.shape).astype(x.dtype)
 
 
-def _rope_lanes(x, pos, theta: float):
+def _rope_lanes(x, pos, theta: float, yarn: Optional[Yarn] = None):
     """``_rope_interleaved`` for the full forward, on x as it lies ([B, T,
     D] or, heads-major, [B, H, T, D]): channel 2i meets -x[2i + 1] and
     channel 2i + 1 meets x[2i] (``llama._turned_on_lanes``)."""
     D = x.shape[-1]
-    inv_freq = 1.0 / theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D)
-    angles = pos.astype(jnp.float32)[..., None] * jnp.repeat(inv_freq, 2)
+    angles = pos.astype(jnp.float32)[..., None] * jnp.repeat(
+        _inv_freq(D, theta, yarn), 2)
     j = np.arange(D)
     return _turned_on_lanes(x, angles, j ^ 1, np.where(j % 2, 1.0, -1.0))
 
 
-def _latent_q(q, pos, Dn: int, theta: float, heads_major: bool):
+def _latent_q(q, pos, Dn: int, theta: float, heads_major: bool,
+              yarn: Optional[Yarn] = None):
     """A latent layer's queries with their last channels rotated: [B, T, H,
     Dn + Dr], or [B, H, T, Dn + Dr] where ``heads_major``."""
     rope = _rope_lanes if heads_major else _rope_interleaved
     return jnp.concatenate([
-        q[..., :Dn], rope(q[..., Dn:], pos, theta)], axis=-1)
+        q[..., :Dn], rope(q[..., Dn:], pos, theta, yarn)], axis=-1)
 
 
-def _latent_rows(config, layer, h, pos, heads_major: bool):
+def _latent_rows(config, layer, h, pos, heads_major: bool,
+                 yarn: Optional[Yarn] = None):
     """A position's row of a latent layer from the normed stream h [B, T,
     E]: [RMSNorm(c) | rope(kr)], [B, T, R + Dr], as the cache holds it."""
     rope = _rope_lanes if heads_major else _rope_interleaved
@@ -401,7 +442,7 @@ def _latent_rows(config, layer, h, pos, heads_major: bool):
             [config.kv_lora_rank], axis=-1)
         return jnp.concatenate([
             _rms_norm(c, layer["kv_norm"], config.rms_eps, h.dtype),
-            rope(kr, pos, config.rope_theta)], axis=-1)
+            rope(kr, pos, config.rope_theta, yarn)], axis=-1)
 
 
 def qkv(config: Config, kind, layer, x, pos, heads_major: bool = False):

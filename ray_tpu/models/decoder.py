@@ -107,6 +107,13 @@ from ray_tpu.parallel.moe import (
 
 
 
+class Index(NamedTuple):
+    """A lightning indexer, as far as the skeleton has to know it."""
+    heads: int
+    dim: int
+    kept: int
+
+
 class Layer(NamedTuple):
     """One kind of layer, as far as the skeleton has to know it."""
     name: Optional[str] = None    # the family's word for it: ``kind`` of
@@ -129,6 +136,14 @@ class Layer(NamedTuple):
     # head. The family's ``qkv`` then gives (q [B, T, H, Dn + Dr], the new
     # rows [B, T, latent], the up-projection [R, H, Dn + Dv])
     latent: Optional[int] = None
+    # a latent layer's lightning indexer (``kv_cache.Indexed``): its heads,
+    # the channels of its one key a position (a second row of the layer's
+    # cache) and how many positions a query's attention keeps; None: every
+    # earlier position. The family's ``qkv`` then gives a fourth piece, the
+    # ``kv_cache.Indexed`` of the call's tokens
+    index: Optional[Index] = None
+    # its softmax's scale where that is not the queries' width ^ -0.5
+    scale: Optional[float] = None
 
 
 class Segment(NamedTuple):
@@ -206,6 +221,7 @@ def step_rule(config, params, updates, counted):
 # ``module_for(cfg).loss_fn``): each the one definition here
 __all__ = ["forward_features", "forward", "init_kv_cache", "forward_cached",
            "forward_pipelined", "loss_fn", "count_params", "Layer", "Segment",
+           "Index",
            "single_kind", "periods", "at_input", "second_loss", "step_rule",
            "heads_in", "heads_out", "swapped"]
 
@@ -315,7 +331,15 @@ def _mixer(config, mesh: Optional[Mesh], pos, kind: Layer, layer, x):
     pieces: heads-major from the projections to the kernels and back, so
     that nothing is transposed on the way."""
     family = module_for(config)
-    if kind.latent is not None:
+    if kind.index is not None:
+        # a choice of positions a query: [T, T] scores in XLA, whatever the
+        # backend (the served path is the cached forward's)
+        q, rows, up, index = family.qkv(
+            config, kind.name, layer, x, pos, heads_major=True)
+        k, v, shared = kv_cache.latent_kv(
+            rows, up.astype(q.dtype), q.shape[-1])
+        attn = kv_cache.selected_attention(q, k, v, shared, index, kind.scale)
+    elif kind.latent is not None:
         # the full forward attends up-projected keys and values, a head
         # its own, through the dispatcher (the flash kernels on the chip:
         # no [T, T] scores): the keys' own channels and the values a head,
@@ -492,6 +516,11 @@ def init_kv_cache(config, batch: int, max_len: int, dtype=None,
     latent = [k.latent for k in kinds if k.latent is not None]
     if len(set(latent)) > 1:
         raise ValueError(f"latent layers of several widths: {set(latent)}")
+    indexed = [k.index.dim for k in kinds if k.index is not None]
+    if indexed and (len(set(indexed)) > 1 or len(indexed) != len(latent)):
+        raise ValueError(
+            "latent layers with and without an indexer, or indexers of "
+            f"several widths: {indexed} of {len(latent)} layers")
     windows = [k.window for k in kinds
                if k.state is None and k.latent is None]
     lengths = {w for w in windows if w is not None}
@@ -505,6 +534,7 @@ def init_kv_cache(config, batch: int, max_len: int, dtype=None,
         ring, states,
         module_for(config).state_leaves(config) if states else None,
         len(latent), latent[0] if latent else 0,
+        indexed[0] if indexed else 0,
     )
 
 
@@ -563,9 +593,12 @@ def forward_cached(
         if kind.state is not None:
             x, cache = _recur(config, kind, layer, x, (cache, index, at))
         elif kind.latent is not None:
-            q, rows, up = family.qkv(config, kind.name, layer, x, pos)
+            # with an indexer a fourth piece, its ``kv_cache.Indexed``
+            q, rows, up, *chooser = family.qkv(
+                config, kind.name, layer, x, pos)
             cache, attn = kv_cache.attend_latent(
-                cache, index, q, rows, up, at, q.shape[-1] ** -0.5)
+                cache, index, q, rows, up, at,
+                kind.scale or q.shape[-1] ** -0.5, *chooser)
             x = family.attn_out(config, layer, x, attn)
         else:
             q, k_new, v_new = family.qkv(config, kind.name, layer, x, pos)

@@ -71,6 +71,25 @@ reads each filled chunk once for both products; a block of tokens
 UP-PROJECTS the rows it sees, its own and the earlier chunks', a block of
 positions at a time, and attends as H heads of ``Dn + Dr`` / ``Dv``
 (``_latent_blocks``: XLA, a running softmax over the filled blocks alone).
+
+A latent layer with a lightning indexer (DeepSeek-V3.2-Exp's sparse
+attention; ``decoder.Layer.index``) keeps TWO rows a position: beside the
+latent row the indexer's rotated key, leaf ``"index"`` ``[Ll, B, S, Di]``,
+position MAJOR: a key is the 128 lanes of one row, so the leaf is tiled as
+it lies, a step writes its key as one row, and the one product that reads
+all of a slot's keys (``q . K``) contracts their minor axis. (Held position
+minor like the rest, the compiler turned the whole leaf into this order at
+a decode program's start and back at its end, 2 x 0.34 GB a tick at 8
+slots of 33k.) The latent rows keep their order because what reads them is
+still the routes above. A chunk and a decode step write both rows of their
+positions; the queries are then scored against every cached key
+(``ops/index_select.py``), EXACTLY the ``kept`` largest positions of each
+query are chosen, and the softmax runs over those alone. The choice enters
+every route as a mask, so every visible row is read and an unchosen one adds
+nothing: the decode kernel takes it beside the cache, a chunk attends
+through ``ops/block_attention.py:selected_block_attention`` (a head's
+queries against blocks of rows up-projected in VMEM), the XLA route masks
+its whole scores.
 """
 from __future__ import annotations
 
@@ -79,9 +98,11 @@ from typing import Dict, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops import ssm
+from ray_tpu.ops import index_select, ssm
 from ray_tpu.ops.attention import heads_in
-from ray_tpu.ops.block_attention import TOKENS, block_attention
+from ray_tpu.ops.block_attention import (
+    TOKENS, block_attention, selected_block_attention,
+)
 from ray_tpu.ops.decode_attention import (
     TILE,
     decode_attention,
@@ -93,6 +114,10 @@ from ray_tpu.ops.decode_attention import (
 FULL, WINDOW = ("k", "v"), ("k_window", "v_window")
 STATE = ("ssm", "conv")
 LATENT = "latent"
+INDEX = "index"
+
+
+Indexed = index_select.Indexed
 
 
 def ring_length(window: int, block: int, max_len: int) -> int:
@@ -118,17 +143,22 @@ def init_kv_cache(num_layers: int, batch: int, kv_heads: int, head_dim: int,
                   max_len: int, dtype, window_layers: int = 0,
                   ring: int = 0, state_layers: int = 0,
                   state=None, latent_layers: int = 0,
-                  latent_dim: int = 0) -> Dict[str, jax.Array]:
+                  latent_dim: int = 0,
+                  index_dim: int = 0) -> Dict[str, jax.Array]:
     """``num_layers`` full layers of ``max_len`` positions,
     ``window_layers`` rings of ``ring``, ``state_layers`` states (``state``
     names each of their leaves' (shape a slot, dtype)) and ``latent_layers``
-    layers of one row of ``latent_dim`` channels a position."""
+    layers of one row of ``latent_dim`` channels a position, beside it an
+    indexer's key of ``index_dim`` channels where they have one."""
     cache = {name: jnp.zeros((state_layers, batch, *shape), kind)
              for name, (shape, kind) in (state or {}).items()
              if state_layers}
     if latent_layers:
         cache[LATENT] = jnp.zeros(
             (latent_layers, batch, 1, latent_dim, max_len), dtype)
+        if index_dim:
+            cache[INDEX] = jnp.zeros(
+                (latent_layers, batch, max_len, index_dim), dtype)
     for names, layers, length in ((FULL, num_layers, max_len),
                                   (WINDOW, window_layers, ring)):
         if layers or (names is FULL and not window_layers
@@ -362,9 +392,32 @@ def latent_kv(rows, up, q_width: int):
     return k, v, rows[..., R:]
 
 
+def selected_attention(q, k, v, shared, index: Indexed,
+                       scale: Optional[float] = None):
+    """The full forward's attention of a latent layer with an indexer, over
+    ``latent_kv``'s pieces: q [B, H, T, Dn + Dr], k [B, H, T, Dn], v
+    [B, H, T, Dv], ``shared`` [B, T, Dr] -> [B, H, T, Dv]. Each query scores
+    every position up to its own, keeps the ``index.kept`` largest and
+    attends those alone: whole [T, T] arrays in XLA, what a test or a short
+    batch can hold (the served path is ``attend_latent``)."""
+    T, Dn = q.shape[2], k.shape[-1]
+    seen = jnp.tril(jnp.ones((T, T), bool))[None]
+    picked = index_select.chosen(
+        index_select.scores(index.q, index.weights, index.key), seen,
+        index.kept)
+    with jax.named_scope("mla.sparse"):
+        sc = (jnp.einsum("bhtd,bhsd->bhts", q[..., :Dn], k)
+              + jnp.einsum("bhtd,bsd->bhts", q[..., Dn:], shared)
+              ).astype(jnp.float32) * (scale or q.shape[-1] ** -0.5)
+        probs = jax.nn.softmax(
+            jnp.where(picked[:, None], sc, -1e30), axis=-1)
+        return jnp.einsum("bhts,bhsd->bhtd", probs.astype(q.dtype), v)
+
+
 def attend_latent(cache: Dict[str, jax.Array], layer: jax.Array,
                   q: jax.Array, rows_new: jax.Array, up: jax.Array,
-                  at: Step, scale: float):
+                  at: Step, scale: float,
+                  index: Optional[Indexed] = None):
     """Latent layer ``layer`` of ``cache`` with ``rows_new`` [B, T, R + Dr]
     in place, and q [B, T, H, Dn + Dr] attended over it through ``up``
     [R, H, Dn + Dv] (a head's ``[Wuk | Wuv]``) -> (cache, [B, T, H, Dv]).
@@ -372,7 +425,10 @@ def attend_latent(cache: Dict[str, jax.Array], layer: jax.Array,
     up-projects a row; a block of one slot's tokens (where the decode
     kernel's platform is) up-projects the filled blocks of positions, one
     after another; anything else (the tests' oracle) absorbs over the whole
-    cache in XLA."""
+    cache in XLA. ``index``: the layer's indexer at these tokens. Its keys
+    go into the ``"index"`` leaf as the rows go into theirs, and each query
+    attends the ``index.kept`` positions it scores highest and no other, by
+    whichever of the three routes."""
     B, T, H, Dq = q.shape
     R = up.shape[0]
     Dn = Dq - (rows_new.shape[-1] - R)
@@ -380,30 +436,51 @@ def attend_latent(cache: Dict[str, jax.Array], layer: jax.Array,
     impl = _impl(B, T, leaf.shape[-1])
     up = up.astype(q.dtype)
     if impl != "xla" and T > 1:
-        leaf = _place(leaf, layer, rows_new[:, :, None, :], at.start[0],
-                      False)
-        out = _latent_blocks(leaf, layer, q[0], up, at.start[0], Dn, scale)
-        return {**cache, LATENT: leaf}, out[None]
+        start = at.start[0]
+        leaf = _place(leaf, layer, rows_new[:, :, None, :], start, False)
+        if index is None:
+            out = _latent_blocks(leaf, layer, q[0], up, start, Dn, scale)
+            return {**cache, LATENT: leaf}, out[None]
+        keys = _place_rows(cache[INDEX], layer, index.key[0], start)
+        out = _attend_chosen(leaf, keys, layer, q[0], up, index, start,
+                             scale, impl == "pallas_interpret")
+        return {**cache, LATENT: leaf, INDEX: keys}, out[None]
     with jax.named_scope("mla.up"):
         # q_nope Wuk^T beside the rotated part: what a row is scored against
         qa = jnp.concatenate([
             jnp.einsum("bthd,rhd->bthr", q[..., :Dn], up[..., :Dn]),
             q[..., Dn:]], axis=-1)
-    with jax.named_scope("mla.attend"):
-        if impl != "xla":
+    if impl != "xla":
+        picked = None
+        if index is not None:
+            cache, picked = _chosen_of_step(cache, layer, index, at)
+        with jax.named_scope("mla.attend" if index is None else "mla.sparse"):
             summed, leaf = latent_decode_attention(
                 qa[:, 0], rows_new[:, 0], leaf, layer, at.start, values=R,
-                scale=scale, live=at.live,
+                scale=scale, live=at.live, chosen=picked,
                 interpret=impl == "pallas_interpret")
             summed = summed[:, None]
-        else:
+    else:
+        mask = at.mask
+        if index is not None:
+            keys = jax.lax.dynamic_index_in_dim(
+                cache[INDEX], layer, 0, False)                  # [B, S, Di]
+            keys = jnp.where(
+                at.hit.any(1)[:, :, None], jnp.einsum(
+                    "btd,bts->bsd", index.key.astype(keys.dtype),
+                    at.hit.astype(keys.dtype),
+                    precision=jax.lax.Precision.HIGHEST), keys)
+            mask = index_select.chosen(
+                index_select.scores(index.q, index.weights, keys),
+                mask, index.kept)
+        with jax.named_scope("mla.attend" if index is None else "mla.sparse"):
             held = _write(
                 jax.lax.dynamic_index_in_dim(leaf, layer, 0, False),
                 rows_new[:, :, None, :], at.hit)[:, 0]         # [B, D, S]
             scores = jnp.einsum("bthd,bds->bhts", qa, held).astype(
                 jnp.float32) * scale
             probs = jax.nn.softmax(
-                jnp.where(at.mask[:, None], scores, -1e30), axis=-1)
+                jnp.where(mask[:, None], scores, -1e30), axis=-1)
             summed = jnp.einsum("bhts,brs->bthr", probs.astype(q.dtype),
                                 held[:, :R])
             # as ``_attend_xla``: the rows go back once attention has read
@@ -411,10 +488,102 @@ def attend_latent(cache: Dict[str, jax.Array], layer: jax.Array,
                 (summed, held, leaf))
             leaf = jax.lax.dynamic_update_index_in_dim(
                 leaf, held[:, None], layer, 0)
+        if index is not None:
+            summed, keys, cache = jax.lax.optimization_barrier(
+                (summed, keys, cache))
+            cache = {**cache, INDEX: jax.lax.dynamic_update_index_in_dim(
+                cache[INDEX], keys, layer, 0)}
     with jax.named_scope("mla.up"):
         out = jnp.einsum("bthr,rhd->bthd", summed.astype(q.dtype),
                          up[..., Dn:])
     return {**cache, LATENT: leaf}, out
+
+
+# widths of the cache a chunk chooses and attends over: the narrowest that
+# holds the chunk's last position (the choice is passes over [T, width]
+# arrays and the kernel's grid steps over the width's blocks, and a 12k
+# prompt's chunks fill a third of a 33k cache)
+CHOICE_WIDTHS = (4096, 8192, 16384)
+
+
+def _attend_chosen(leaf, keys, layer, q, up, index: Indexed, start,
+                   scale: float, interpret: bool):
+    """One slot's block of T tokens at ``start ..``, both their rows in
+    place already (``leaf`` [L, 1, 1, R + Dr, S], ``keys`` [L, 1, S, Di]):
+    every token's index scores against the filled keys, its choice, and its
+    attention over the chosen rows (``ops/block_attention.py:
+    selected_block_attention``) -> [T, H, Dv]."""
+    T, S = q.shape[0], leaf.shape[-1]
+    found = index_select.scores_of_block(
+        index.q[0], index.weights[0], keys, layer, start + T)
+    pos = start + jnp.arange(T)
+
+    def over(width):
+        def attended(found):
+            picked = index_select.chosen(
+                found[:, :width],
+                jnp.arange(width)[None, :] <= pos[:, None], index.kept)
+            with jax.named_scope("mla.sparse"):
+                return selected_block_attention(
+                    q, up, leaf, jnp.pad(picked, ((0, 0), (0, S - width))),
+                    layer, start, scale=scale, width=width,
+                    interpret=interpret)
+        return attended
+
+    widths = [w for w in CHOICE_WIDTHS if w < S] + [S]
+    return jax.lax.switch(
+        sum((start + T > w).astype(jnp.int32) for w in widths[:-1]),
+        [over(w) for w in widths], found)
+
+
+def _place_rows(leaf: jax.Array, layer, new: jax.Array, start):
+    """``_place`` for a position-major leaf [L, 1, S, D]: ``new`` [T, D] on
+    positions ``start .. start + T - 1`` of layer ``layer``; a block that
+    reaches past the end is written over the last T positions with what
+    was there kept before it, its tokens past the end dropped."""
+    S, T = leaf.shape[-2], new.shape[0]
+    at = jnp.clip(start, 0, S - T)
+    over = start - at                 # rows that do not fit before the end
+    where = (layer, 0, at, 0)
+    old = jax.lax.dynamic_slice(leaf, where, (1, 1, T, new.shape[1]))
+    rows = jnp.roll(new.astype(leaf.dtype), over, axis=0)[None, None]
+    return jax.lax.dynamic_update_slice(leaf, jnp.where(
+        (jnp.arange(T) >= over)[None, None, :, None], rows, old), where)
+
+
+def _chosen_of_step(cache, layer, index: Indexed, at: Step):
+    """A decode step's choice: each slot's one query scored against all of
+    its keys, its own new one among them, and the new key of every slot
+    that decodes put into its place of the ``"index"`` leaf (any other slot
+    keeps its own, as under the decode kernel) -> (cache, [B, S] bool: the
+    positions it reads, its own new one among them or not). The keys are
+    scored as they were and the new one beside them, and a slot's row is one
+    update in place: a scatter is laid out anew around the whole leaf, twice
+    a layer."""
+    keys = cache[INDEX]
+    B, S, Di = keys.shape[1:]
+    lens = at.start
+    new = index.key[:, 0].astype(keys.dtype)                     # [B, Di]
+    found = index_select.scores(
+        index.q, index.weights,
+        jax.lax.dynamic_index_in_dim(keys, layer, 0, False))[:, 0]
+    own = index_select.scores(index.q, index.weights, new[:, None])[:, 0]
+    found = jnp.where(jnp.arange(S)[None, :] == lens[:, None], own, found)
+    picked = index_select.chosen(
+        found, jnp.arange(S)[None, :] <= lens[:, None], index.kept)
+    slots, count = jnp.arange(B), B
+    if at.live is not None:     # ``live_slots``: the first ``live[B]`` name
+        slots, count = at.live[:B], at.live[B]
+    with jax.named_scope("mla.index"):
+        for v in range(B):
+            b = slots[v]
+            where = (layer, b, jnp.minimum(lens[b], S - 1), 0)
+            old = jax.lax.dynamic_slice(keys, where, (1, 1, 1, Di))
+            # a position past the end lands nowhere
+            mine = (v < count) & (lens[b] < S)
+            keys = jax.lax.dynamic_update_slice(keys, jnp.where(
+                mine, new[b].reshape(old.shape), old), where)
+    return {**cache, INDEX: keys}, picked
 
 
 def _latent_blocks(leaf, layer, q, up, start, Dn: int, scale: float):

@@ -206,3 +206,134 @@ def block_attention(q, k_cache, v_cache, layer, start, *, window=None,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), start.astype(jnp.int32),
       folded(q), k_cache, v_cache)
     return unfolded(o)
+
+
+# ------------------------------------------------ a latent layer's chosen rows
+# positions of a latent cache a step of ``selected_block_attention`` holds
+SELECTED_POSITIONS = 512
+
+
+def _selected_kernel(layer_ref, start_ref, qn_ref, qr_ref, wk_ref, wv_ref,
+                     c_ref, mask_ref, o_ref, m_sc, l_sc, acc_sc, *, bs: int,
+                     S: int, scale: float, rank: int):
+    """One head's T queries against one block of ``bs`` positions of a
+    latent cache: the block's rows ``[rank + Dr, bs]`` are up-projected to
+    the head's keys and values here (``wk [Dn, rank]``, ``wv [Dv, rank]``),
+    scored (``q_nope . k_nope + q_rope . kr``), masked by the choice and
+    folded into the head's running softmax. A step past the block that
+    holds the last token's position computes nothing."""
+    j = pl.program_id(1)
+    T = qn_ref.shape[0]
+    shift = bs.bit_length() - 1
+    count = (jnp.minimum(start_ref[0] + T - 1, S - 1) >> shift) + 1
+
+    @pl.when(j == 0)
+    def _():
+        m_sc[...] = jnp.full(m_sc.shape, NEG_INF, jnp.float32)
+        l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    @pl.when(j < count)
+    def _():
+        qn, qr = qn_ref[...], qr_ref[...]
+        rows = c_ref[...].astype(qn.dtype)                # [rank + Dr, bs]
+        c, kr = rows[:rank], rows[rank:]
+        # the head's keys and values of the block, rounded as a product's
+        # result in the activations' dtype is
+        kn = jnp.dot(wk_ref[...], c,
+                     preferred_element_type=jnp.float32).astype(qn.dtype)
+        v = jnp.dot(wv_ref[...], c,
+                    preferred_element_type=jnp.float32).astype(qn.dtype)
+        sc = (jnp.dot(qn, kn, preferred_element_type=jnp.float32)
+              + jnp.dot(qr, kr, preferred_element_type=jnp.float32)
+              ) * scale                                          # [T, bs]
+        seen = mask_ref[...] != 0
+        sc = jnp.where(seen, sc, NEG_INF)
+        m_prev = m_sc[...]
+        m_next = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_next)
+        p = jnp.where(seen, jnp.exp(sc - m_next), 0.0)
+        l_sc[...] = alpha * l_sc[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_sc[...] = alpha * acc_sc[...] + jax.lax.dot_general(
+            p.astype(qn.dtype), v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_sc[...] = m_next
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        o_ref[...] = (acc_sc[...] / l_sc[...]).astype(o_ref.dtype)
+
+
+def selected_block_attention(q, up, cache, picked, layer, start, *,
+                             scale: float, width=None,
+                             interpret: bool = False):
+    """A block of one slot's tokens over the rows of a LATENT cache that
+    each token CHOSE: q [T, H, Dn + Dr] at positions ``start [1] + t``, ``up``
+    [R, H, Dn + Dv] (a head's ``[Wuk | Wuv]``), ``cache`` [L, 1, 1, R + Dr,
+    S] with the tokens' own rows in place, ``picked`` [T, S] bool (causal
+    already: an indexer's choice) -> [T, H, Dv]. One head at a time holds
+    all T queries and walks the blocks of ``SELECTED_POSITIONS`` positions
+    up to the one with the last token's: each block's rows are read where
+    they lie and up-projected in VMEM, so no up-projected key, no score and
+    no probability is ever in HBM. Every visible row is read; an unchosen
+    one enters no softmax. ``width`` (a multiple of the block; None: S):
+    positions the grid covers, for a caller that knows ``start + T`` lies
+    within it."""
+    T, H, Dq = q.shape
+    R = up.shape[0]
+    D, S = cache.shape[-2:]
+    Dn = Dq - (D - R)
+    Dv = up.shape[-1] - Dn
+    if S % TILE or T % TOKENS:
+        raise ValueError(
+            f"a block of tokens (a multiple of {TOKENS}) against whole tiles "
+            f"of {TILE} positions: {T} against {S} is the XLA path's "
+            f"(models/kv_cache.py:attend_latent)")
+    bs = next(n for n in (SELECTED_POSITIONS, 256, TILE) if S % n == 0)
+    nk = -(-(width or S) // bs)
+    shift = bs.bit_length() - 1
+
+    def block(j, start_ref):
+        count = (jnp.minimum(start_ref[0] + T - 1, S - 1) >> shift) + 1
+        return jnp.minimum(j, count - 1)       # past the last: the last
+
+    up = up.astype(q.dtype)
+    head = lambda width: pl.BlockSpec(                       # noqa: E731
+        (None, T, width), lambda h, j, *_: (h, 0, 0))
+    weight = lambda width: pl.BlockSpec(                     # noqa: E731
+        (None, width, R), lambda h, j, *_: (h, 0, 0))
+    o = pl.pallas_call(
+        functools.partial(_selected_kernel, bs=bs, S=S, scale=scale, rank=R),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(H, nk),
+            in_specs=[
+                head(Dn), head(Dq - Dn), weight(Dn), weight(Dv),
+                pl.BlockSpec(
+                    (None, None, None, D, bs),
+                    lambda h, j, layer_ref, start_ref: (
+                        layer_ref[0], 0, 0, 0, block(j, start_ref))),
+                pl.BlockSpec(
+                    (T, bs), lambda h, j, layer_ref, start_ref: (
+                        0, block(j, start_ref)))],
+            out_specs=head(Dv),
+            scratch_shapes=[
+                pltpu.VMEM((T, 1), jnp.float32),
+                pltpu.VMEM((T, 1), jnp.float32),
+                pltpu.VMEM((T, Dv), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((H, T, Dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=4 * D * bs * cache.dtype.itemsize
+            + 10 * T * bs * 4 + 8 * T * max(Dq, TILE) * 4 + (16 << 20)),
+        name="selected_block_attention",
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+      jnp.reshape(start, (1,)).astype(jnp.int32),
+      jnp.moveaxis(q[..., :Dn], 1, 0), jnp.moveaxis(q[..., Dn:], 1, 0),
+      jnp.transpose(up[..., :Dn], (1, 2, 0)),
+      jnp.transpose(up[..., Dn:], (1, 2, 0)), cache,
+      picked.astype(jnp.int8))
+    return jnp.moveaxis(o, 0, 1)

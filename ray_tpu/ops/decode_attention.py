@@ -313,13 +313,19 @@ LATENT_BLOCK_BYTES = 5 << 17
 
 
 def _latent_kernel(layer_ref, lens_ref, live_ref, q_ref, new_row_ref,
-                   new_col_ref, c_hbm, o_ref, co_hbm, cbuf, ctile, read_sem,
-                   write_sem, m_sc, l_sc, acc_sc, *, bs: int, scale: float,
-                   values: int):
+                   new_col_ref, *rest, bs: int, scale: float, values: int,
+                   selected: bool):
     """``_kernel`` for a cache of ONE row a position and no head axis,
     whose first ``values`` channels are also the values: a chunk is read
     once and enters both products. No ring, no group of heads; the visits,
-    the two buffers and the tile written are ``_kernel``'s."""
+    the two buffers and the tile written are ``_kernel``'s. ``selected``:
+    one operand more, ``chosen_ref`` [B, 1, S] float32 in VMEM, above
+    0 at the positions a slot's query reads (its own new row's among them,
+    at ``lens[b]``): every other position is read and left out of the
+    softmax."""
+    chosen_ref = rest[0] if selected else None
+    (c_hbm, o_ref, co_hbm, cbuf, ctile, read_sem, write_sem, m_sc, l_sc,
+     acc_sc) = rest[selected:]
     B = q_ref.shape[0]
     S = c_hbm.shape[-1]
     visits = live_ref[B]
@@ -348,6 +354,11 @@ def _latent_kernel(layer_ref, lens_ref, live_ref, q_ref, new_row_ref,
         n = lens_ref[b]                   # the new column's position
         place = place_of(b)
         kept = n < S
+        if selected:    # the new row's own place among the chosen
+            at = (place // TILE) * TILE
+            lane = at + jax.lax.broadcasted_iota(jnp.int32, (1, TILE), 1)
+            own = chosen_ref[b, :, span(place // TILE, TILE)]
+            kept &= jnp.sum(jnp.where(lane == n, own, 0.0)) > 0
         count = place // bs + 1
         q = q_ref[b]                      # [H, D]
         new = new_row_ref[b]              # [1, D]
@@ -377,6 +388,8 @@ def _latent_kernel(layer_ref, lens_ref, live_ref, q_ref, new_row_ref,
                                 preferred_element_type=jnp.float32) * scale
                 pos = c * bs + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
                 old = pos < n
+                if selected:
+                    old &= chosen_ref[b, :, span(c, bs)] > 0
                 sc = jnp.where(old, sc, NEG_INF)
                 m_prev = m_sc[...]
                 m_next = jnp.maximum(
@@ -426,7 +439,7 @@ def _latent_kernel(layer_ref, lens_ref, live_ref, q_ref, new_row_ref,
 
 
 def latent_decode_attention(q, new, cache, layer, lens, *, values: int,
-                            scale: float, live=None,
+                            scale: float, live=None, chosen=None,
                             interpret: bool = False):
     """A decode step over a LATENT cache ``[L, B, 1, D, S]``: one row of D
     channels a position, which every query head shares, and whose first
@@ -436,7 +449,10 @@ def latent_decode_attention(q, new, cache, layer, lens, *, values: int,
     [B, D], which is written to position ``lens[b]`` (dropped where that is
     S) -> (out [B, H, values], cache: the operand's own buffer). Scores are
     ``q . row x scale``. ``live`` as ``decode_attention``'s. Each chunk of
-    the cache is read once, for both products."""
+    the cache is read once, for both products. ``chosen`` [B, S] bool (None:
+    every filled position): the positions a slot's softmax runs over, its
+    new row's own place among them; the others are read and masked, so a
+    step costs what it cost and the result is the selected attention's."""
     B, H, D = q.shape
     S = cache.shape[-1]
     cdt = cache.dtype
@@ -452,12 +468,15 @@ def latent_decode_attention(q, new, cache, layer, lens, *, values: int,
     new = new.astype(cdt)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
+    selected = chosen is not None
+    marks = (chosen[:, None, :].astype(jnp.float32),) if selected else ()
     o, cache = pl.pallas_call(
-        functools.partial(_latent_kernel, bs=bs, scale=scale, values=values),
+        functools.partial(_latent_kernel, bs=bs, scale=scale, values=values,
+                          selected=selected),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(1,),
-            in_specs=[vmem, vmem, vmem, hbm],
+            in_specs=[vmem, vmem, vmem, *(vmem,) * selected, hbm],
             out_specs=[vmem, hbm],
             scratch_shapes=[
                 pltpu.VMEM((2, D, bs), cdt),
@@ -473,11 +492,13 @@ def latent_decode_attention(q, new, cache, layer, lens, *, values: int,
             jax.ShapeDtypeStruct((B, H, values),
                                  jnp.promote_types(q.dtype, cdt)),
             jax.ShapeDtypeStruct(cache.shape, cdt)],
-        input_output_aliases={6: 1},
+        input_output_aliases={6 + selected: 1},
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=8 * D * bs * cdt.itemsize + (48 << 20)),
+            vmem_limit_bytes=8 * D * bs * cdt.itemsize + (48 << 20)
+            + selected * 8 * B * S * 4),
         name="latent_decode_attention",
         interpret=interpret,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), lens.astype(jnp.int32),
-      live.astype(jnp.int32), q, new[:, None, :], new[:, :, None], cache)
+      live.astype(jnp.int32), q, new[:, None, :], new[:, :, None], *marks,
+      cache)
     return o, cache

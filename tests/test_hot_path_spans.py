@@ -543,10 +543,10 @@ def test_a_reader_returns_the_hand_sum_over_the_spans(
     listed = {m["name"]: m for m in bench["per_layer"]}
     assert listed[metric]["moves"] == "per_token_p50_ms"
     # every serving cell, none dropped: the cells that report the metric
-    # these move (the three of PR 36, PR 42's, PR 47's and PR 51's)
+    # these move (the three of PR 36, PR 42's, PR 47's, PR 51's and PR 57's)
     serving = next(m["workloads"] for m in bench["end_to_end"]
                    if m["name"] == "per_token_p50_ms")
-    assert len(serving) == 6
+    assert len(serving) == 7
     assert listed[metric]["workloads"] == serving
     monkeypatch.setattr(host_spans, "TRACE_ROOT", recording["logdir"])
     got = run.read_layer_metric(
@@ -882,3 +882,97 @@ def test_a_model_that_holds_every_expert_carries_neither(greedy_recording):
         for t in ticks)
     assert greedy_recording["stats"]["moe_rows_held"] == 0
     assert greedy_recording["stats"]["latent_positions"] == 0
+
+
+# A fourth recording, after the three before it were closed: a model whose
+# latent layers choose what they attend (``models/deepseek_v32.py``: 16
+# positions kept), for the two counters only such a model's spans carry.
+
+
+@pytest.fixture(scope="module")
+def indexed_recording(share_recording, tmp_path_factory):
+    import jax
+
+    from benchmarks.lib import host_spans
+    from tests.test_deepseek_v32 import TINY
+
+    engine = DecodeEngine(LLMConfig(**TINY))
+    prompts = [[3 + i] * n for i, n in enumerate((37, 6, 20))]
+    logdir = str(tmp_path_factory.mktemp("spans_indexed"))
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    before = dict(engine.stats)
+    jax.profiler.start_trace(logdir, profiler_options=options)
+    try:
+        futures = [engine.submit(p, SamplingParams(max_new_tokens=9))
+                   for p in prompts]
+        answers = [list(f.result(300)) for f in futures]
+    finally:
+        jax.profiler.stop_trace()
+    stats = {k: engine.stats[k] - before[k] for k in before}
+    engine.shutdown()
+    return {"spans": host_spans.load(logdir), "stats": stats,
+            "answers": answers}
+
+
+def test_an_indexed_models_ticks_carry_what_was_scored_and_what_was_read(
+        indexed_recording):
+    """``index_positions`` (every live slot's visible positions, a latent
+    layer: what the tick needed of the latent cache, scored) and
+    ``selected_positions`` (16 of them a slot, or all where a slot holds
+    fewer) are on every ``engine.tick`` of the capture, known at the
+    dispatch."""
+    spans, stats = indexed_recording["spans"], indexed_recording["stats"]
+    ticks = spans.named("engine.tick")
+    assert stats["ticks"] == len(ticks) > 0
+    assert all(len(a) == 9 for a in indexed_recording["answers"])
+    # three latent layers, each with its indexer
+    assert all(t.args["index_positions"] == t.args["latent_positions"]
+               == 3 * t.args["cache_positions"] for t in ticks)
+    assert all(3 * t.args["active"] <= t.args["selected_positions"]
+               <= min(t.args["index_positions"], 3 * 16 * t.args["active"])
+               for t in ticks)
+    # the slot that holds 6 prompt tokens reads all it sees, the others 16
+    assert any(t.args["selected_positions"] < 3 * 16 * t.args["active"]
+               for t in ticks)
+    assert any(t.args["selected_positions"] < t.args["index_positions"]
+               for t in ticks)
+
+
+def test_an_indexed_models_admissions_carry_them_and_stats_sums_both(
+        indexed_recording):
+    """An admission's chunks score every query's visible positions and read
+    16 of them or all; ``stats`` sums the ticks' and the admissions'."""
+    spans, stats = indexed_recording["spans"], indexed_recording["stats"]
+    admits = spans.named("engine.admit")
+    assert len(admits) == 3
+    # prompts of 37, 6 and 20 tokens in chunks of 16 with their padding:
+    # every padded query counted as the program computes it
+    seen = {37: [(0, 16), (16, 16), (32, 8)], 6: [(0, 8)],
+            20: [(0, 16), (16, 8)]}
+    want = sorted(3 * sum(t + 1 for start, n in chunks
+                          for t in range(start, start + n))
+                  for chunks in seen.values())
+    assert sorted(a.args["index_positions"] for a in admits) == want
+    assert all(0 < a.args["selected_positions"] <= a.args["index_positions"]
+               for a in admits)
+    # the prompt of 6 in a bucket of 8: nothing is left out
+    assert min(a.args["index_positions"] - a.args["selected_positions"]
+               for a in admits) == 0
+    both = admits + spans.named("engine.tick")
+    for name in ("index_positions", "selected_positions"):
+        assert stats[name] == sum(s.args[name] for s in both) > 0
+    cache = spans.named("engine.admit.cache")
+    assert len(cache) == 3 and {s.args["bytes"] for s in cache} == {
+        3 * 128 * (40 + 16) * 4}
+
+
+def test_a_model_without_an_indexer_carries_neither_counter(
+        greedy_recording, share_recording):
+    for recording in (greedy_recording, share_recording):
+        spans = recording["spans"]
+        assert not any(
+            "index_positions" in s.args or "selected_positions" in s.args
+            for s in spans.named("engine.tick") + spans.named("engine.admit"))
+        assert recording["stats"]["index_positions"] == 0
+        assert recording["stats"]["selected_positions"] == 0

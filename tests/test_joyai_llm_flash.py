@@ -195,10 +195,11 @@ def _last_position_unmasked(monkeypatch, cfg):
         real(x, w, targets, None, **kw))
 
 
-def _rope_by_halves(x, pos, theta):
+def _rope_by_halves(x, pos, theta, yarn=None):
     """Channel i turned with channel i + D / 2 (llama's layout) where the
     configuration pairs (2i, 2i + 1); x [B, T, D] or, as the full forward
-    has the queries, [B, H, T, D]."""
+    has the queries, [B, H, T, D]. (``yarn``: the rotations' fourth
+    argument since PR 57, None in this family.)"""
     D = x.shape[-1]
     angle = pos.astype(jnp.float32)[..., None] / theta ** (
         jnp.arange(0, D, 2, dtype=jnp.float32) / D)

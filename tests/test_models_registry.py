@@ -185,8 +185,11 @@ def test_llm_config_builds_every_family(family, experts):
         elif "latent" in cache:
             # every layer attends a latent row a position, none keys and
             # values a head
-            assert set(cache) == {"latent"}
+            # and beside it, where the layers choose what they attend,
+            # their indexer's key
+            assert set(cache) - {"index"} == {"latent"}
             assert cache["latent"].shape == (4, 3, 1, cfg.latent_dim, 16)
+            assert ("index" in cache) == (family == "deepseek_v32")
         else:
             assert cache["k"].shape == (4, 3, 4, 16, 16)
 
@@ -200,7 +203,8 @@ TINY = {"gpt2": "gpt2-tiny", "llama": "llama-tiny", "afmoe": "afmoe-tiny",
         "granite_hybrid": "granite-hybrid-tiny",
         "olmo_hybrid": "olmo-hybrid-tiny",
         "bailing_hybrid": "bailing-hybrid-tiny",
-        "joyai_llm_flash": "joyai-flash-tiny"}
+        "joyai_llm_flash": "joyai-flash-tiny",
+        "deepseek_v32": "deepseek-v32-tiny"}
 
 
 def _flat(family) -> dict:
@@ -695,6 +699,13 @@ def test_heads_major_pieces_are_the_cached_ones_transposed(family):
             want = list(module.qkv(cfg, kind.name, layer, x, pos))
             # grouped query heads [B, T, KV, G, D] are flat, kv-major, there
             want[0] = want[0].reshape(B, T, -1, want[0].shape[-1])
+            if kind.index is not None:
+                # an indexer's pieces are the same in either order
+                for a, b in zip(jax.tree.leaves(got[3]),
+                                jax.tree.leaves(want[3])):
+                    np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                               atol=2e-6, rtol=2e-6)
+                got, want = got[:3], want[:3]
             if kind.latent is not None:   # the rows and the up-projection
                 want[1:] = [a.swapaxes(1, 2) for a in want[1:]]    # as given
             for a, b in zip(got, want):

@@ -809,6 +809,130 @@ def test_ling_reference_reads_a_whole_context_beside_nothing_else(one_chip):
     assert mem.temp_size_in_bytes < 4.0e9
 
 
+# ------------------------------------------ DeepSeek-V3.2-Exp's engine programs
+# The eleventh cell's size: a dense and four routed layers at the published
+# widths, one chip's share of a sixteen-way expert-parallel group, 8 slots of
+# 33,280 positions (``benchmarks/configs/deepseek-v3.2-exp.json``).
+
+
+@pytest.mark.parametrize("program, slots, width", [
+    ("decode", 8, 1), ("prefill", 1, 2048)])
+def test_deepseek_v32_programs_fit_the_chip_and_keep_both_rows_in_place(
+        one_chip, program, slots, width, monkeypatch):
+    """``jit_decode`` at 8 slots and ``jit_prefill`` at the largest bucket
+    (the longest chunk: 2,048 tokens against a cache of 33,280): the v5e
+    compiler takes the ``latent_decode_attention`` kernel with the choice's
+    mask beside the cache (two calls: the dense layer's, and one for the
+    four routed layers, which are one scan) and the
+    ``selected_block_attention`` kernel at 128 heads of 2,048 queries (a
+    call a kind of layer and width of the cache it may choose over); the
+    cache of two leaves, the latent rows position-minor and the indexer's
+    keys position-major, is the program's argument and its result in one
+    buffer; no array a head wide has the cache's length (the index scores
+    and their choice are [2048, 33280]); bytes of arguments and temporaries
+    are printed."""
+    import re
+
+    from benchmarks import run
+    from benchmarks.lib import program as harness
+    from ray_tpu.llm.engine import engine_programs
+    from ray_tpu.models import kv_cache
+
+    monkeypatch.setattr(kv_cache, "_decode_impl", lambda: "pallas")
+    monkeypatch.setattr(grouped_matmul, "_impl", lambda: "pallas")
+    monkeypatch.setattr(rows_to_tokens, "_impl", lambda: "pallas")
+    config = run.load_cell("deepseek-v3.2-exp.serve-longdoc")[2]
+    assert config["serve"]["max_batch_slots"] == 8
+    cfg, args = _engine_program_args(
+        one_chip, slots, width, harness.model_config(config), block=2048)
+    S = cfg.max_seq_len
+    cache = args[2]
+    assert {k: (v.shape, v.dtype) for k, v in cache.items()} == {
+        "latent": ((5, slots, 1, 576, S), jnp.bfloat16),
+        "index": ((5, slots, S, 128), jnp.bfloat16)}
+    params = sum(a.size for a in jax.tree.leaves(args[0]))
+    assert params == 4_635_518_208
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in cache.values())
+    assert cache_bytes == slots * S * 5 * (576 + 128) * 2
+    if program == "decode":
+        compiled = engine_programs(cfg)[2].lower(
+            *_decode_args(one_chip, args)).compile()
+    else:
+        rows = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+        compiled = engine_programs(cfg, own_cache=True)[0].lower(
+            *args, rows=rows).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    print(program, "arguments", mem.argument_size_in_bytes, "temporaries",
+          mem.temp_size_in_bytes, "aliased", mem.alias_size_in_bytes,
+          "cache", cache_bytes)
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert not re.search(r"= \w+\[16160,7168\]\S* (copy|transpose)\(", text)
+    calls = [line for line in text.splitlines() if "custom-call(" in line]
+    if program == "decode":
+        # 9.27 GB of weights and 1.87 GB of cache: 70% of the chip
+        assert 11.1e9 < mem.argument_size_in_bytes < 11.2e9
+        assert mem.temp_size_in_bytes < 0.05e9
+        latent = [c for c in calls
+                  if re.match(r"\s*%?latent_decode_attention", c)]
+        assert len(latent) == 2
+        # the choice rides beside the cache: [slots, 1, S] float32
+        assert all(f"bf16[5,{slots},1,576,{S}]" in c
+                   and f"f32[{slots},1,{S}]" in c for c in latent)
+        # no copy of either leaf, whole or a layer's
+        assert not re.search(
+            rf"= bf16\[(5,)?{slots},(1,576,{S}|{S},128)\]\S* (copy|transpose)\(",
+            text)
+        assert _weight_converts(text, args[0]) == []
+        assert "s32[5,2]" in text.split("\n", 1)[0]
+    else:
+        assert mem.temp_size_in_bytes < 2.0e9
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 11.5e9
+        chosen = [c for c in calls
+                  if re.match(r"\s*%?selected_block_attention", c)]
+        # two kinds of layer x the widths a chunk may choose over
+        assert len(chosen) == 2 * (len(kv_cache.CHOICE_WIDTHS) + 1)
+        assert all("bf16[128,2048,128]" in c and f"s8[2048,{S}]" in c
+                   for c in chosen)
+        # nothing a head wide along the cache: no [heads, tokens, S] score
+        assert not re.search(rf"\[(128|64),2048,{S}\]|\[2048,(128|64),{S}\]",
+                             text)
+
+
+def test_deepseek_v32_reference_reads_a_whole_context_beside_nothing_else(
+        one_chip):
+    """The comparison that decides ``correct`` reads the check requests
+    padded to 24,576 positions, and a sequence that ended on EOS padded to
+    the cell's ``context_limit``, in one forward of the plain float32
+    reference on the chip the engine has left: weights as the program holds
+    them and the temporaries fit the chip's 16 GB with room."""
+    import functools
+
+    from benchmarks import run
+    from benchmarks.lib import program as harness, reference
+    from benchmarks.runners import serve_open_loop_median as runner
+    from ray_tpu.models import module_for
+
+    _, workload, config = run.load_cell("deepseek-v3.2-exp.serve-longdoc")[:3]
+    limit = int(workload["traffic"]["context_limit"])
+    cfg = harness.model_config(config)
+    assert limit == cfg.max_seq_len
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: module_for(cfg).init_params(
+            cfg, jax.random.PRNGKey(0))))
+    tokens = jax.ShapeDtypeStruct((1, limit), jnp.int32, sharding=one_chip)
+    with jax.default_matmul_precision("highest"):
+        mem = jax.jit(functools.partial(
+            runner.greedy_gaps, reference.logits_of(config))).lower(
+                params, tokens).compile().memory_analysis()
+    print("arguments", mem.argument_size_in_bytes, "temporaries",
+          mem.temp_size_in_bytes)
+    assert 9.27e9 < mem.argument_size_in_bytes < 9.28e9
+    # 4.34 GB at 33,280 positions: the logits [33279, 16160] float32 are
+    # 2.15 of them, the chosen set [33280, 33280] booleans 1.1
+    assert mem.temp_size_in_bytes < 5.0e9
+
+
 # -------------------------------------------- SmallThinker's training step
 # The sixth cell's size: one chip's share of a four-way expert-parallel
 # layer, batch 2 x 8192 (``benchmarks/configs/smallthinker-21b-a3b.json``).
