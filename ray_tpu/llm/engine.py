@@ -123,8 +123,14 @@ Where those layers choose what they attend (a lightning indexer,
 ``engine.admit`` is what the indexer scored (each live slot's, or each of a
 chunk's queries', visible positions, a latent layer) and
 ``selected_positions`` what attention was then asked to read (the kept
-positions or the visible ones, whichever is fewer, a query and layer);
-``stats`` sums both, and ``engine.admit.cache`` carries the ``bytes`` of the
+positions or the visible ones, whichever is fewer, a query and layer), and
+``index_positions_read`` the positions of keys that the scores' kernel
+visits for them where it runs (``ops/index_select.py:positions_read``, on
+the host from the same lengths: whole blocks of a live slot's filled
+positions in a tick, of what a tile of queries sees in a chunk; any other
+backend's XLA scores every slot's whole leaf), so that ``index_positions /
+index_positions_read`` is near 1 where only keys a query may see are read;
+``stats`` sums the three, and ``engine.admit.cache`` carries the ``bytes`` of the
 slot cache an admission fills.
 """
 from __future__ import annotations
@@ -526,6 +532,9 @@ class DecodeEngine:
             # asked to read, ticks and admissions, summed over the latent
             # layers (0 for a model whose layers attend everything)
             "index_positions": 0, "selected_positions": 0,
+            # positions of the indexer's keys that the scores' kernel visits
+            # for them: whole blocks, of live slots and visible ones alone
+            "index_positions_read": 0,
             # state layers: slots that decode x state layers over ticks (the
             # states a tick reads and writes), and prompt tokens that went
             # through a prefill's scan; both 0 for a model with none
@@ -709,16 +718,24 @@ class DecodeEngine:
             seen = np.concatenate(
                 [np.arange(start + 1, start + T + 1) for start, T in chunks]
                 or [np.zeros(0, np.int64)])
-            counts.update(self._chosen_positions(seen))
+            counts.update(self._chosen_positions(seen, chunks))
         for name, count in counts.items():
             self.stats[name] += count
         return counts
 
-    def _chosen_positions(self, seen) -> dict:
+    def _chosen_positions(self, seen, blocks) -> dict:
         """``index_positions`` and ``selected_positions`` of queries that
-        see ``seen`` positions each, over the latent layers."""
+        see ``seen`` positions each, and ``index_positions_read`` of the
+        ``blocks`` [(start, tokens)] of one slot each that they came in,
+        over the latent layers."""
+        from ray_tpu.ops import index_select
+
         return {
             "index_positions": int(seen.sum()) * self._layers_latent,
+            "index_positions_read": sum(
+                index_select.positions_read(
+                    int(start), T, self.config.max_seq_len)
+                for start, T in blocks) * self._layers_latent,
             "selected_positions": int(np.minimum(
                 seen, self._index_kept).sum()) * self._layers_latent}
 
@@ -1143,8 +1160,8 @@ class DecodeEngine:
                     positions["latent_positions"] = (
                         cache_positions * self._layers_latent)
                 if self._index_kept:
-                    positions.update(
-                        self._chosen_positions(needed[list(rows)]))
+                    positions.update(self._chosen_positions(
+                        needed[list(rows)], [(lens[i], 1) for i in rows]))
                 if drafts:
                     sent = (jnp.asarray(toks), jnp.asarray(lens),
                             *self._real(real))

@@ -82,9 +82,16 @@ minor like the rest, the compiler turned the whole leaf into this order at
 a decode program's start and back at its end, 2 x 0.34 GB a tick at 8
 slots of 33k.) The latent rows keep their order because what reads them is
 still the routes above. A chunk and a decode step write both rows of their
-positions; the queries are then scored against every cached key
-(``ops/index_select.py``), EXACTLY the ``kept`` largest positions of each
-query are chosen, and the softmax runs over those alone. The choice enters
+positions; the queries are then scored against every cached key they may
+see (``ops/index_select.py``), EXACTLY the ``kept`` largest positions of each
+query are chosen, and the softmax runs over those alone. Where a kernel
+attends, two kernels score and search: ``index_scores`` reads the leaf where
+it lies, a live slot's filled blocks in a decode step and a tile of queries'
+visible blocks in a chunk, and keeps what is a head wide in VMEM;
+``index_kth_largest`` finds each row's threshold with the row's keys held
+in VMEM, and ``index_select.chosen`` makes the set from it. XLA's ``scores``
+and ``chosen`` over the whole leaf are every other route's (and the full
+forward's), and the kernels' oracle. The choice enters
 every route as a mask, so every visible row is read and an unchosen one adds
 nothing: the decode kernel takes it beside the cache, a chunk attends
 through ``ops/block_attention.py:selected_block_attention`` (a head's
@@ -510,19 +517,20 @@ def _attend_chosen(leaf, keys, layer, q, up, index: Indexed, start,
                    scale: float, interpret: bool):
     """One slot's block of T tokens at ``start ..``, both their rows in
     place already (``leaf`` [L, 1, 1, R + Dr, S], ``keys`` [L, 1, S, Di]):
-    every token's index scores against the filled keys, its choice, and its
-    attention over the chosen rows (``ops/block_attention.py:
-    selected_block_attention``) -> [T, H, Dv]."""
+    every token's index scores against the keys its tile of queries sees
+    (``index_select.scores_of_block``), its choice (``chosen_up_to``), and
+    its attention over the chosen rows
+    (``ops/block_attention.py:selected_block_attention``) -> [T, H, Dv], all
+    three over the narrowest of ``CHOICE_WIDTHS`` that holds the chunk."""
     T, S = q.shape[0], leaf.shape[-1]
-    found = index_select.scores_of_block(
-        index.q[0], index.weights[0], keys, layer, start + T)
-    pos = start + jnp.arange(T)
 
     def over(width):
-        def attended(found):
-            picked = index_select.chosen(
-                found[:, :width],
-                jnp.arange(width)[None, :] <= pos[:, None], index.kept)
+        def attended(_):
+            found = index_select.scores_of_block(
+                index.q[0], index.weights[0], keys, layer, start,
+                width=width, interpret=interpret)
+            picked = index_select.chosen_up_to(
+                found, start + jnp.arange(T), index.kept, interpret=interpret)
             with jax.named_scope("mla.sparse"):
                 return selected_block_attention(
                     q, up, leaf, jnp.pad(picked, ((0, 0), (0, S - width))),
@@ -533,7 +541,7 @@ def _attend_chosen(leaf, keys, layer, q, up, index: Indexed, start,
     widths = [w for w in CHOICE_WIDTHS if w < S] + [S]
     return jax.lax.switch(
         sum((start + T > w).astype(jnp.int32) for w in widths[:-1]),
-        [over(w) for w in widths], found)
+        [over(w) for w in widths], None)
 
 
 def _place_rows(leaf: jax.Array, layer, new: jax.Array, start):
@@ -552,28 +560,30 @@ def _place_rows(leaf: jax.Array, layer, new: jax.Array, start):
 
 
 def _chosen_of_step(cache, layer, index: Indexed, at: Step):
-    """A decode step's choice: each slot's one query scored against all of
-    its keys, its own new one among them, and the new key of every slot
-    that decodes put into its place of the ``"index"`` leaf (any other slot
-    keeps its own, as under the decode kernel) -> (cache, [B, S] bool: the
-    positions it reads, its own new one among them or not). The keys are
-    scored as they were and the new one beside them, and a slot's row is one
-    update in place: a scatter is laid out anew around the whole leaf, twice
-    a layer."""
+    """A decode step's choice: each live slot's one query scored against
+    the keys it has filled (``index_select.scores_of_step``: an idle slot
+    and a block past a slot's length are not read, and their scores hold
+    nothing) and its own new one, and the new key of every slot that decodes
+    put into its place of the ``"index"`` leaf (any other slot keeps its
+    own, as under the decode kernel) -> (cache, [B, S] bool: the positions
+    it reads, its own new one among them or not; an idle slot's row means
+    nothing). The keys are scored as they were and the new one beside them,
+    and a slot's row is one update in place: a scatter is laid out anew
+    around the whole leaf, twice a layer."""
     keys = cache[INDEX]
     B, S, Di = keys.shape[1:]
     lens = at.start
     new = index.key[:, 0].astype(keys.dtype)                     # [B, Di]
-    found = index_select.scores(
-        index.q, index.weights,
-        jax.lax.dynamic_index_in_dim(keys, layer, 0, False))[:, 0]
+    live = jnp.arange(B + 1, dtype=jnp.int32) if at.live is None else at.live
+    slots, count = live[:B], live[B]    # ``live_slots``: the first name them
+    interpret = _decode_impl() == "pallas_interpret"
+    found = index_select.scores_of_step(
+        index.q[:, 0], index.weights[:, 0], keys, layer, lens, live,
+        interpret=interpret)
     own = index_select.scores(index.q, index.weights, new[:, None])[:, 0]
     found = jnp.where(jnp.arange(S)[None, :] == lens[:, None], own, found)
-    picked = index_select.chosen(
-        found, jnp.arange(S)[None, :] <= lens[:, None], index.kept)
-    slots, count = jnp.arange(B), B
-    if at.live is not None:     # ``live_slots``: the first ``live[B]`` name
-        slots, count = at.live[:B], at.live[B]
+    picked = index_select.chosen_up_to(
+        found, lens, index.kept, interpret=interpret)
     with jax.named_scope("mla.index"):
         for v in range(B):
             b = slots[v]

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from benchmarks.tests import faults_deepseek_v32 as faults
 from ray_tpu.llm import DecodeEngine, LLMConfig, SamplingParams
@@ -266,13 +267,20 @@ def _by_a_sort(scores, visible, kept):
     return out
 
 
+@pytest.mark.parametrize("search", ["digits", "kernel"])
 @pytest.mark.parametrize("case", [
-    "random", "ties", "all_equal", "signed_zeros", "padded_bucket"])
-def test_the_chosen_set_is_the_sorts_exactly(case):
+    "random", "ties", "all_equal", "signed_zeros", "padded_bucket",
+    "ties_at_the_threshold", "fewer_than_kept"])
+def test_the_chosen_set_is_the_sorts_exactly(monkeypatch, case, search):
     """16 kept of up to 96: rows that see fewer than 16 positions keep them
     all; equal scores straddling the 16th place go to the lower positions;
     -0.0 ties with 0.0; the rows of a padded bucket (queries past the
-    prompt's end) are rows like any other."""
+    prompt's end) are rows like any other; a row whose 16th and 17th
+    largest are one value met many times; rows that all see fewer than 16.
+    ``kernel``: the threshold from ``kth_largest`` (two tiles of 16 rows,
+    counted 32 positions at a time up to a tile's last visible block) in
+    place of ``chosen``'s own digits, over scores that hold a NaN wherever a
+    row does not see: the same set, bit for bit."""
     rng = np.random.default_rng(5)
     T, S, kept, start = 32, 96, 16, 40
     scores = rng.normal(size=(T, S)).astype(np.float32)
@@ -285,16 +293,145 @@ def test_the_chosen_set_is_the_sorts_exactly(case):
         scores = np.where(rng.random((T, S)) < 0.5, -0.0, 0.0).astype(
             np.float32)
         scores[:, ::7] = -1.0
+    elif case == "ties_at_the_threshold":
+        # ten above, then one value thirty times: six of the thirty are in
+        scores = -np.abs(scores) - 1.0
+        scores[:, 3:33] = 0.5
+        scores[:, 33:43] = 2.0 + rng.random((T, 10)).astype(np.float32)
     if case == "padded_bucket":
         start = 0       # the first rows see 1, 2, .. positions
+    elif case == "fewer_than_kept":
+        start, T = 0, 8
+        scores = scores[:T]
     pos = start + np.arange(T)
     visible = np.arange(S)[None, :] <= pos[:, None]
-    got = np.asarray(jax.jit(index_select.chosen, static_argnums=2)(
-        jnp.asarray(scores), jnp.asarray(visible), kept))
+
+    def choose(scores, visible):
+        if search == "digits":
+            return index_select.chosen(scores, visible, kept)
+        kth = index_select.kth_largest(
+            scores, jnp.asarray(pos), kept, interpret=True)
+        return index_select.chosen(scores, visible, kept, kth)
+
+    given = scores
+    if search == "kernel":
+        monkeypatch.setattr(index_select, "COUNTED", 32)
+        given = np.where(visible, scores, np.nan)
+    got = np.asarray(jax.jit(choose)(jnp.asarray(given), jnp.asarray(visible)))
     want = _by_a_sort(scores, visible, kept)
     assert np.array_equal(got, want)
     assert np.array_equal(got.sum(-1), np.minimum(kept, pos + 1))
     assert not (got & ~visible).any()
+
+
+@pytest.mark.parametrize("lens", [[40, 17, 90], [0, 128, 200]])
+def test_the_kernels_threshold_is_the_digit_searchs_for_slots_of_a_tick(
+        monkeypatch, lens):
+    """A decode step's rows: each sees the positions up to its length (its
+    own new key's place among them; a length past the cache's end sees all
+    of it), so one tile's rows end in different blocks. The threshold a row
+    is ``chosen``'s own ``prefix``: the largest key that
+    ``min(kept, visible)`` of the row's keys reach."""
+    monkeypatch.setattr(index_select, "COUNTED", 32)
+    rng = np.random.default_rng(7)
+    S, kept = 128, 16
+    scores = rng.integers(-3, 4, (3, S)).astype(np.float32) * 0.5
+    lens = np.asarray(lens)
+    visible = np.arange(S)[None, :] <= lens[:, None]
+    kth = np.asarray(index_select.kth_largest(
+        jnp.asarray(np.where(visible, scores, np.nan)), jnp.asarray(lens),
+        kept, interpret=True))
+    u = np.where(visible, np.asarray(index_select._ordered(
+        jnp.asarray(scores))), 0).astype(np.uint64)
+    k = np.minimum(visible.sum(-1), kept)
+    want = np.stack([np.sort(row)[::-1][n - 1] for row, n in zip(u, k)])
+    assert np.array_equal(kth[:, 0], want)
+    got = index_select.chosen(
+        jnp.asarray(scores), jnp.asarray(visible), kept, jnp.asarray(kth))
+    assert np.array_equal(np.asarray(got), _by_a_sort(scores, visible, kept))
+
+
+# --------------------------------------------------- the scores, inside VMEM
+
+
+def _indexer(rng, B, T, S, heads=4, width=16):
+    """(leaf [2, B, S, Di], q [B, T, Hi, Di], weights [B, T, Hi])."""
+    return (jnp.asarray(rng.normal(size=(2, B, S, width)), jnp.float32),
+            jnp.asarray(rng.normal(size=(B, T, heads, width)), jnp.float32),
+            jnp.asarray(np.abs(rng.normal(size=(B, T, heads))) + 0.1,
+                        jnp.float32))
+
+
+# every place the kernel did not write reads NaN
+UNWRITTEN = pltpu.InterpretParams(uninitialized_memory="nan")
+
+
+@pytest.mark.parametrize("live", [
+    [True, False, True], [True, True, True], [False, False, False]],
+    ids=["one_idle", "all", "none"])
+def test_a_ticks_scores_read_live_slots_filled_blocks_and_nothing_else(
+        monkeypatch, live):
+    """Three slots at lengths 40 / 17 / 90, blocks of 32 positions: a live
+    slot's scores over the positions it has filled are
+    ``index_select.scores``'s; its blocks past its length are never written,
+    nor is any place of an idle slot's row, and what lies in the leaf there
+    (a NaN in every key past a slot's length and in all of an idle slot's)
+    changes nothing a query sees."""
+    monkeypatch.setattr(index_select, "STEP_POSITIONS", 32)
+    rng = np.random.default_rng(11)
+    B, S = 3, 128
+    leaf, q, w = _indexer(rng, B, 1, S)
+    lens = np.asarray([40, 17, 90])
+    alive = np.asarray(live)
+    filled = (np.arange(S)[None, :] < lens[:, None]) & alive[:, None]
+    poisoned = jnp.where(filled[None, :, :, None], leaf, jnp.nan)
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(index_select.scores(q, w, leaf[1])[:, 0])
+        got = np.asarray(index_select.scores_of_step(
+            q[:, 0], w[:, 0], poisoned, 1, jnp.asarray(lens, jnp.int32),
+            kv_cache.live_slots(jnp.asarray(alive)), interpret=UNWRITTEN))
+    np.testing.assert_allclose(got[filled], want[filled], rtol=1e-5,
+                               atol=1e-5)
+    visited = (np.arange(S)[None, :] < -(-lens[:, None] // 32) * 32) & (
+        alive[:, None])
+    assert np.isnan(got[~visited]).all()
+    assert not np.isnan(got[filled]).any()
+
+
+@pytest.mark.parametrize("start, width", [
+    (24, 64), (24, None), (112, None), (120, None)],
+    ids=["boundary_inside", "whole_cache", "to_the_end", "past_the_end"])
+def test_a_chunks_scores_read_what_each_tile_of_queries_sees(
+        monkeypatch, start, width):
+    """16 queries at ``start ..`` as two tiles of 8 against blocks of 32
+    positions: tile 0 of the chunk at 24 ends in block 0 and tile 1 in
+    block 1 (a block boundary inside the chunk), the chunk at 112 reaches
+    the cache's last position, the one at 120 past it (its tokens past the
+    end see all of the cache). Where a query sees, ``index_select.scores``;
+    past a tile's last visible block nothing is written, and a NaN in every
+    key past the chunk's last position changes nothing."""
+    monkeypatch.setattr(index_select, "POSITIONS", 32)
+    monkeypatch.setattr(index_select, "QUERIES", 8)
+    rng = np.random.default_rng(13)
+    T, S = 16, 128
+    leaf, q, w = _indexer(rng, 1, T, S)
+    pos = start + np.arange(T)
+    poisoned = jnp.where(
+        (np.arange(S) <= pos[-1])[None, None, :, None], leaf, jnp.nan)
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(index_select.scores(q, w, leaf[1])[0])
+        got = np.asarray(index_select.scores_of_block(
+            q[0], w[0], poisoned, 1, jnp.int32(start), width=width,
+            interpret=UNWRITTEN))
+    assert got.shape == (T, width or S)
+    sees = np.arange(width or S)[None, :] <= pos[:, None]
+    np.testing.assert_allclose(got[sees], want[:, :width or S][sees],
+                               rtol=1e-5, atol=1e-5)
+    last = np.repeat(pos.reshape(-1, 8)[:, -1], 8)     # of a row's tile
+    visited = np.arange(width or S)[None, :] < (
+        np.minimum(last, S - 1)[:, None] // 32 + 1) * 32
+    assert np.isnan(got[~visited]).all()
+    assert not np.isnan(got[sees]).any()
 
 
 def test_the_programs_choice_is_the_references(reference):

@@ -967,12 +967,45 @@ def test_an_indexed_models_admissions_carry_them_and_stats_sums_both(
         3 * 128 * (40 + 16) * 4}
 
 
+def test_an_indexed_models_spans_carry_the_positions_the_kernels_visit(
+        indexed_recording):
+    """``index_positions_read``: whole blocks of the keys of the slots that
+    decode, and of what a chunk's tiles of queries see, and of nothing else.
+    At 128 positions a slot one block is a slot's whole leaf: a tick reads it
+    and the token's own key once a live slot and layer, whatever the three
+    slots hold; a chunk reads it once a padded query. What was NEEDED
+    (``index_positions``) is not what was read, and is as it was."""
+    from ray_tpu.ops import index_select
+
+    spans, stats = indexed_recording["spans"], indexed_recording["stats"]
+    ticks, admits = spans.named("engine.tick"), spans.named("engine.admit")
+    assert all(t.args["index_positions_read"] == 3 * (128 + 1)
+               * t.args["active"] for t in ticks)
+    assert all(t.args["index_positions"] == 3 * t.args["cache_positions"]
+               < t.args["index_positions_read"] for t in ticks)
+    padded = {37: 40, 6: 8, 20: 24}
+    assert sorted(a.args["index_positions_read"] for a in admits) == sorted(
+        3 * 128 * n for n in padded.values())
+    assert stats["index_positions_read"] == sum(
+        s.args["index_positions_read"] for s in ticks + admits)
+    # whole blocks: a cache of 33,280 at the cell's sizes, on the host
+    assert index_select.positions_read(12000, 1, 33280) == 2 * 6656 + 1
+    assert index_select.positions_read(0, 1, 33280) == 1
+    tile = index_select.QUERIES       # of queries; blocks of 512 positions
+    assert index_select.positions_read(10240, 2048, 33280) == sum(
+        tile * 512 * ((10240 + first + tile - 1) // 512 + 1)
+        for first in range(0, 2048, tile))
+    assert index_select.positions_read(32768, 2048, 33280) == 2048 * 33280
+
+
 def test_a_model_without_an_indexer_carries_neither_counter(
         greedy_recording, share_recording):
     for recording in (greedy_recording, share_recording):
         spans = recording["spans"]
         assert not any(
             "index_positions" in s.args or "selected_positions" in s.args
+            or "index_positions_read" in s.args
             for s in spans.named("engine.tick") + spans.named("engine.admit"))
         assert recording["stats"]["index_positions"] == 0
+        assert recording["stats"]["index_positions_read"] == 0
         assert recording["stats"]["selected_positions"] == 0

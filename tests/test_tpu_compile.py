@@ -830,13 +830,20 @@ def test_deepseek_v32_programs_fit_the_chip_and_keep_both_rows_in_place(
     keys position-major, is the program's argument and its result in one
     buffer; no array a head wide has the cache's length (the index scores
     and their choice are [2048, 33280]); bytes of arguments and temporaries
-    are printed."""
+    are printed. The indexer's two steps are the ``index_scores`` and
+    ``index_kth_largest`` kernels (PR 58), a pair a kind of layer in a tick
+    and a pair a kind and width in a chunk, each reading the ``index`` leaf
+    where it lies: nothing a head wide leaves VMEM (no [8, 64, 33280]
+    products and no [8, 15, 33280] compares in ``jit_decode``, no
+    [2048, 64, n] float32 in ``jit_prefill``)."""
     import re
 
     from benchmarks import run
     from benchmarks.lib import program as harness
     from ray_tpu.llm.engine import engine_programs
     from ray_tpu.models import kv_cache
+
+    from ray_tpu.ops import index_select
 
     monkeypatch.setattr(kv_cache, "_decode_impl", lambda: "pallas")
     monkeypatch.setattr(grouped_matmul, "_impl", lambda: "pallas")
@@ -884,6 +891,17 @@ def test_deepseek_v32_programs_fit_the_chip_and_keep_both_rows_in_place(
             text)
         assert _weight_converts(text, args[0]) == []
         assert "s32[5,2]" in text.split("\n", 1)[0]
+        # the indexer's kernels, once a kind of layer: the scores read the
+        # leaf itself, the search the [slots, S] scores
+        scored = [c for c in calls if re.match(r"\s*%?index_scores", c)]
+        searched = [c for c in calls
+                    if re.match(r"\s*%?index_kth_largest", c)]
+        assert len(scored) == len(searched) == 2
+        assert all(f"bf16[5,{slots},{S},128]" in c
+                   and f"f32[{slots},1,{S}]" in c for c in scored)
+        assert all(f"f32[{slots},{S}]" in c for c in searched)
+        # nothing a head wide along the cache, product or compare
+        assert not re.search(rf"\[{slots},(64|15),{S}\]", text)
     else:
         assert mem.temp_size_in_bytes < 2.0e9
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 11.5e9
@@ -896,6 +914,25 @@ def test_deepseek_v32_programs_fit_the_chip_and_keep_both_rows_in_place(
         # nothing a head wide along the cache: no [heads, tokens, S] score
         assert not re.search(rf"\[(128|64),2048,{S}\]|\[2048,(128|64),{S}\]",
                              text)
+        # the indexer's kernels, a pair a kind of layer and width: a tile of
+        # queries against the leaf where it lies, [2048, width] scores out
+        widths = (*kv_cache.CHOICE_WIDTHS, S)
+        scored = [c for c in calls if re.match(r"\s*%?index_scores", c)]
+        searched = [c for c in calls
+                    if re.match(r"\s*%?index_kth_largest", c)]
+        assert len(scored) == len(searched) == 2 * len(widths)
+        assert all(f"bf16[5,1,{S},128]" in c for c in scored)
+        tile = index_select.QUERIES
+        for calls_of, shape in ((scored, f"f32[{2048 // tile},{tile},{{}}]"),
+                                (searched, "f32[2048,{}]")):
+            assert sorted(w for c in calls_of for w in widths
+                          if shape.format(w) in c) == sorted(2 * widths)
+        # and the products of a block before the sum over heads stay in VMEM
+        assert not re.search(r"f32\[2048,64,\d+\]", text)
+        # neither leaf copied, whole or a layer's
+        assert not re.search(
+            rf"= bf16\[(5,)?1,(1,576,{S}|{S},128)\]\S* (copy|transpose)\(",
+            text)
 
 
 def test_deepseek_v32_reference_reads_a_whole_context_beside_nothing_else(
