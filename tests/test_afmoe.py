@@ -1,127 +1,60 @@
-"""Arcee's ``afmoe`` (Trinity-Mini) through the program: the family's pieces
-against the benchmark's plain reference (``benchmarks/references/afmoe.py``),
-the router's sigmoid scores, bias and scale, the two kinds of KV cache
-through ``DecodeEngine`` across the ring's wrap, and the decode kernel with a
-window at G = 8.
+"""Arcee's ``afmoe`` (Trinity-Mini): what is peculiar to it. The cases every
+family shares (the reference and each fault, bfloat16, the plan of a lead
+and whole periods, padded chunks, idle and reused slots, two slots,
+speculation verified in a ring with room for the draft) run over its row of
+``tests/families.py``; here, a full layer that knows no position, the
+router's sigmoid scores, bias and scale, the two kinds of KV cache through
+``DecodeEngine`` across the ring's wrap, and the decode kernel with a window
+at G = 8.
 
 CPU, float32 where gates and logits are compared, seeded weights, tiny
-widths; each tolerance is written where it is used, with its reason. Nothing
-timed here is a device number.
+widths; each tolerance is written where it is used. No device number.
 """
 import dataclasses
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.llm import DecodeEngine, LLMConfig, SamplingParams
+from ray_tpu.llm import SamplingParams
 from ray_tpu.models import afmoe, decoder, kv_cache
 from ray_tpu.ops import decode_attention as kernel
 from ray_tpu.ops.block_attention import block_attention
 from ray_tpu.parallel import moe
 from ray_tpu.parallel.moe import MoEConfig, init_moe_params
+from tests import families
+from tests.families import _tokens
 
-CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+FAMILY = "afmoe"
 S, F = afmoe.SLIDING, afmoe.FULL
 
-# a dense sliding lead, then one period of three sliding layers and a full
-# one, all routed: the benchmark's cut at toy widths, window 8
-TINY = dict(
-    model_family="afmoe", vocab_size=300, max_seq_len=64, num_layers=5,
-    num_heads=8, num_kv_heads=2, embed_dim=64, head_dim=16, mlp_dim=96,
-    moe_mlp_dim=32, rope_theta=10000, rms_eps=1e-5, num_dense_layers=1,
-    num_shared_experts=1, sliding_window=8, layer_types=(S, S, S, S, F),
-    mup_enabled=True, moe_num_experts=16, moe_top_k=4,
-    moe_norm_topk_prob=True, moe_score_func="sigmoid", moe_route_scale=2.826,
-    moe_router_init_std=0.3, moe_expert_bias_init_std=0.05, dtype="float32",
-    max_batch_slots=3, prefill_buckets=(4, 8),
-)
 
-
-@pytest.fixture
-def reference(monkeypatch):
-    from benchmarks.lib import named
-
-    module = named.load(os.path.join(
-        CHECKOUT, "benchmarks", "references", "afmoe.py"))
-    # what the weights do not carry, at the toy's values
-    monkeypatch.setattr(module, "SLIDING_WINDOW", TINY["sliding_window"])
-    monkeypatch.setattr(module, "TOP_K", TINY["moe_top_k"])
-    return module
-
-
-def _tiny_params(cfg, seed=0):
-    """The family's own init with what would hide a fault moved: norm gains
-    of all ones (a norm on the wrong vector), matrices of 0.02 (attention
-    nearly flat, experts of 1e-4)."""
-    params = afmoe.init_params(cfg, jax.random.PRNGKey(seed))
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 256))
-
-    def moved(path, a):
-        name = path[-1].key
-        if name.endswith("norm") or name == "norm_f":
-            return a * jax.random.uniform(next(keys), a.shape, a.dtype, 0.5, 1.5)
-        if name in ("router_w", "expert_bias", "wte", "lm_head"):
-            return a
-        return a * 6.0
-
-    return jax.tree_util.tree_map_with_path(moved, params)
-
-
-def _reference_logits(reference, params, tokens):
-    with jax.default_matmul_precision("highest"):
-        return np.asarray(reference.logits(params, jnp.asarray(tokens)))
-
-
-def _tokens(shape, seed=0):
-    return np.random.default_rng(seed).integers(2, 300, shape).astype(np.int32)
-
-
-# ------------------------------------------- the family against the reference
-
-
-def test_the_family_matches_the_reference_and_each_fault_does_not(
-        reference, monkeypatch):
-    cfg = LLMConfig(**TINY).model_config()
+def test_the_rows_router_is_dropless_sigmoid_with_a_bias_and_a_scale():
+    cfg = families.model_config(FAMILY)
     assert cfg.moe.dropless and cfg.moe.score_func == "sigmoid"
     assert cfg.moe.expert_bias and cfg.moe.route_scale == 2.826
-    params = _tiny_params(cfg)
-    tokens = _tokens((2, 24))          # three windows long
-    got = np.asarray(afmoe.forward(params, jnp.asarray(tokens), cfg)[0])
-    want = _reference_logits(reference, params, tokens)
-    # float32 against float32: the order of the sums, 1e-6 measured on
-    # logits of 0.5 to 3
-    assert np.abs(want).max() > 0.5
-    assert np.abs(got - want).max() < 2e-5
 
-    def off(**changes):
-        other = dataclasses.replace(cfg, **changes)
-        return np.abs(np.asarray(afmoe.forward(
-            params, jnp.asarray(tokens), other)[0]) - want).max()
 
-    # the faintest faults read far above that: the window ignored, RoPE on
-    # the full layer too (every layer sliding, the window too long to cut),
-    # no RoPE anywhere, the embedding's multiplier, the bias in the gates'
-    # place (none at all), the scale, the shared expert left out
-    assert off(sliding_window=64) > 1e-2
-    assert off(layer_types=(S,) * 5, sliding_window=64) > 1e-2
-    assert off(layer_types=(F,) * 5) > 1e-2
-    assert off(mup_enabled=False) > 1e-2
-    for change in (dict(expert_bias=False), dict(route_scale=1.0),
-                   dict(norm_topk_prob=False), dict(score_func="softmax")):
-        assert off(moe=dataclasses.replace(cfg.moe, **change)) > 1e-3, change
-    # the shared expert is counted once: the reference adds it once, and a
-    # program that added it twice (its down projection doubled) is far off
-    twice = jax.tree_util.tree_map_with_path(
-        lambda path, a: a * 2 if path[-1].key == "shared_down" else a, params)
-    assert np.abs(np.asarray(afmoe.forward(
-        twice, jnp.asarray(tokens), cfg)[0]) - want).max() > 1e-2
-    # and the reference sees its own window
-    monkeypatch.setattr(reference, "SLIDING_WINDOW", 64)
-    assert np.abs(got - _reference_logits(reference, params, tokens)
-                  ).max() > 1e-2
+def test_the_published_stack_is_two_dense_of_32_and_the_cells_cut_four_rings():
+    """At the published sizes (the segments' names: the row's ``stacks``):
+    30 layers of 32 routed, three windows of 2,048 and a full layer a period;
+    the cell's cut holds one full layer's 8,192 columns and four rings."""
+    published = afmoe.Config(
+        num_layers=32, num_dense_layers=2, sliding_window=2048,
+        layer_types=(S, S, S, F) * 8, moe=MoEConfig(num_experts=128, top_k=8))
+    kinds = decoder.layer_kinds(published)
+    assert len(kinds) == 32 and sum(k.routed for k in kinds) == 30
+    assert [k.window for k in kinds[:4]] == [2048, 2048, 2048, None]
+    cut = dataclasses.replace(published, num_layers=5, num_dense_layers=1,
+                              layer_types=(S, S, S, S, F))
+    (kinds, _, repeats), = afmoe.layers(cut, None, cached=True)[0]
+    assert len(kinds) == 5 and repeats == 1
+    cache = jax.eval_shape(
+        lambda: decoder.init_kv_cache(cut, 32, 8192, block=2048))
+    assert {k: v.shape for k, v in cache.items()} == {
+        "k": (1, 32, 32, 64, 8192), "v": (1, 32, 32, 64, 8192),
+        "k_window": (4, 32, 32, 64, 4096), "v_window": (4, 32, 32, 64, 4096)}
 
 
 @pytest.mark.parametrize("kinds, moves", [((F,), False), ((S,), True)],
@@ -130,16 +63,15 @@ def test_a_full_layer_knows_no_position_and_a_sliding_one_does(kinds, moves):
     """One layer: with no position signal the last token's logits are those
     of the SET of tokens before it, whatever their order; RoPE tells the
     orders apart."""
-    cfg = dataclasses.replace(
-        LLMConfig(**{**TINY, "num_layers": 1, "num_dense_layers": 1,
-                     "layer_types": kinds, "sliding_window": 64}
-                  ).model_config())
-    params = _tiny_params(cfg)
+    cfg = families.model_config(
+        FAMILY, num_layers=1, num_dense_layers=1, layer_types=kinds,
+        sliding_window=64)
+    params = families._moved(FAMILY, cfg)
     tokens = _tokens((1, 12), seed=3)
     shuffled = np.concatenate(
         [tokens[:, :-1][:, np.random.default_rng(1).permutation(11)],
          tokens[:, -1:]], axis=1)
-    a, b = (np.asarray(afmoe.forward(params, jnp.asarray(t), cfg)[0])[0, -1]
+    a, b = (families.forward_logits(FAMILY, cfg, params, t)[0, -1]
             for t in (tokens, shuffled))
     if moves:
         assert np.abs(a - b).max() > 1e-2
@@ -216,47 +148,13 @@ def test_sigmoid_routed_layer_matches_a_per_token_loop(dropless):
 # ------------------------------------- two kinds of cache, through the engine
 
 
-class _Spans:
-    """Stands in for ``jax.profiler.TraceAnnotation``: every span's name and
-    arguments, with no capture."""
-
-    def __init__(self):
-        self.seen = []
-
-    def __call__(self, name, **args):
-        span = _Span(name, args)
-        self.seen.append(span)
-        return span
-
-    def named(self, name):
-        return [s for s in self.seen if s.name == name]
-
-
-class _Span:
-    def __init__(self, name, args):
-        self.name, self.args = name, dict(args)
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def set_metadata(self, **args):
-        self.args.update(args)
-
-
-def _engine(**changes):
-    engine = DecodeEngine(LLMConfig(**{**TINY, **changes}))
-    engine.params = afmoe.serving_params(
-        engine.model_config, _tiny_params(engine.model_config))
-    engine._span = _Spans()
-    return engine
-
-
-def _full_logprobs(engine, sequence):
-    logits = afmoe.forward(engine.params, jnp.asarray([sequence], jnp.int32),
-                           engine.model_config)[0][0]
+def _full_logprobs(engine, sequence, length):
+    """One program at one ``length`` (causal and dropless: the padding
+    reaches nothing), where a forward a length compiled every operation."""
+    padded = np.zeros((1, length), np.int32)
+    padded[0, :len(sequence)] = sequence
+    logits = families.forward_logits(
+        FAMILY, engine.model_config, engine.params, padded)[0]
     return np.asarray(jax.nn.log_softmax(logits, axis=-1))
 
 
@@ -282,7 +180,7 @@ def test_chunked_prefill_and_cached_decode_across_the_rings_wrap(
         kv_cache, "block_attention",
         lambda q, *a, **kw: blocks.append((q.shape[1], kw["window"]))
         or block_attention(q, *a, **kw))
-    engine = _engine(**sizes)
+    engine = families._engine(FAMILY, **sizes)
     window = engine._window
     n_long, n_new = (int(2.5 * window), 30) if impl == "xla" else (200, 70)
     assert {k: v.shape[0::4] for k, v in engine._cache.items()} == {
@@ -295,7 +193,7 @@ def test_chunked_prefill_and_cached_decode_across_the_rings_wrap(
     for prompt, future in zip(prompts, futures):
         out = future.result(timeout=600)
         assert len(out) == n_new
-        want = _full_logprobs(engine, prompt + list(out))
+        want = _full_logprobs(engine, prompt + list(out), n_long + n_new)
         got = np.array([lp["logprob"] for lp in out.logprobs])
         at = np.arange(len(prompt) - 1, len(prompt) - 1 + n_new)
         # float32 all through: the order of the sums (2e-6 measured)
@@ -333,7 +231,7 @@ def test_an_admission_counts_the_key_positions_its_chunks_see():
     rings (16 positions, window 8) the 7 before its first token, where there
     are that many, and its own: 8 + 15 + 11. The caches hold 64 + 4 x 16, once
     a chunk. A prompt of one chunk beside it; ``stats`` sums both."""
-    engine = _engine()
+    engine = families._engine(FAMILY)
     for n in (19, 5):
         engine.generate([int(t) for t in _tokens((n,), seed=n)],
                         SamplingParams(max_new_tokens=2))
@@ -347,41 +245,6 @@ def test_an_admission_counts_the_key_positions_its_chunks_see():
     assert admits[5]["prefill_cache_positions"] == 64 + 4 * 16
     for name in ("prefill_key_positions", "prefill_cache_positions"):
         assert engine.stats[name] == admits[19][name] + admits[5][name]
-
-
-def test_a_prefix_of_a_model_with_window_layers_is_a_whole_prompt():
-    """A ring that went on past a bucket boundary is not that prefix's
-    cache: the store keeps whole prompts only, and a continuation from one
-    (its ring as the prompt left it) decodes what a fresh prefill does."""
-    prompt = [int(t) for t in _tokens((19,), seed=9)]
-    params = SamplingParams(max_new_tokens=6)
-    fresh = _engine()
-    want = [list(fresh.generate(p, params)) for p in (prompt[:12], prompt)]
-    fresh.shutdown()
-    engine = _engine(prefix_cache_size=4)
-    got = [list(engine.generate(p, params)) for p in (prompt[:12], prompt)]
-    assert got == want
-    assert [len(k) for k in engine._prefix_cache] == [12, 19]
-    assert engine.stats["prefix_partial_hits"] == 1
-    assert list(engine.generate(prompt, params)) == want[1]
-    assert engine.stats["prefix_hits"] == 1
-    engine.shutdown()
-
-
-def test_speculation_verifies_in_a_ring_that_has_room_for_the_draft():
-    """The ring is the window and the longest block one program writes, a
-    verify step's 1 + k among them: a rejected draft never lands on a
-    position the token it is rolled back to still sees."""
-    prompt = [7, 8, 9, 10] * 5
-    params = SamplingParams(max_new_tokens=24)
-    plain = _engine()
-    want = list(plain.generate(prompt, params))
-    plain.shutdown()
-    engine = _engine(speculative_ngram_k=3, prefill_buckets=(2, 4))
-    assert engine._cache["k_window"].shape[-1] == 8 + 4
-    assert list(engine.generate(prompt, params)) == want
-    assert engine.stats["spec_proposed"] > 0
-    engine.shutdown()
 
 
 # ------------------------------------------ the decode kernel with a window

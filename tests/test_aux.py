@@ -4,6 +4,9 @@ Reference analogs: ``python/ray/tests/test_metrics_agent.py``,
 ``test_tracing.py``, ``test_runtime_env*``, chaos suites under
 ``release/nightly_tests``.
 """
+import os
+import subprocess
+import sys
 import time
 
 import pytest
@@ -420,3 +423,27 @@ def test_system_config_propagates_to_workers(tmp_path):
         assert resolved == 123456789
     finally:
         ray_tpu.shutdown()
+
+
+def test_a_files_system_config_ends_with_the_file(tmp_path):
+    """``shutdown`` keeps ``_system_config``'s overrides in the process
+    (the program's debt: ``ROADMAP.md`` Queue 3, item 13), and an xdist
+    worker runs file after file: ``tests/conftest.py`` puts ``rt_config``
+    back as a file found it. Two files under that conftest in one process:
+    the second finds nothing of what the first set."""
+    head = "import ray_tpu\nfrom ray_tpu._private.config import rt_config\n\n"
+    (tmp_path / "test_sets.py").write_text(
+        head + "def test_sets():\n"
+        "    ray_tpu.init(num_cpus=1, _system_config={'rpc_deadline_s': 2.0})\n"
+        "    ray_tpu.shutdown()\n"
+        "    assert rt_config.system_config() == {'rpc_deadline_s': 2.0}\n")
+    (tmp_path / "test_finds.py").write_text(
+        head + "def test_finds():\n"
+        "    assert rt_config.system_config() == {}\n")
+    done = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "tests.conftest",
+         "-p", "no:cacheprovider", "-p", "no:xdist", "-p", "no:randomly",
+         str(tmp_path / "test_sets.py"), str(tmp_path / "test_finds.py")],
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        capture_output=True, text=True, timeout=300)
+    assert "2 passed" in done.stdout, done.stdout + done.stderr

@@ -15,6 +15,7 @@ import pytest
 
 import ray_tpu
 from ray_tpu.util import state
+from tests.conftest import start_head
 
 
 # ------------------------------------------------------------- state API
@@ -68,22 +69,12 @@ def test_task_summary(ops_cluster):
 
 @pytest.fixture(scope="module")
 def standalone_head(tmp_path_factory):
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
     # Self-sufficient auth: clients in this module authenticate with the
     # same token as the head regardless of test-file ordering (stdout info
     # is redacted, so the env is the distribution channel here).
-    tok = env.get("RT_AUTH_TOKEN") or "standalone-head-test-token"
-    env["RT_AUTH_TOKEN"] = tok
     prev = os.environ.get("RT_AUTH_TOKEN")
-    os.environ["RT_AUTH_TOKEN"] = tok
-    proc = subprocess.Popen(
-        [sys.executable, "-m", "ray_tpu._private.head_main",
-         "--num-cpus", "2", "--dashboard-port", "0"],
-        stdout=subprocess.PIPE, text=True, env=env, cwd="/root/repo",
-    )
-    line = proc.stdout.readline().strip()
-    info = json.loads(line)
+    os.environ["RT_AUTH_TOKEN"] = prev or "standalone-head-test-token"
+    proc, info = start_head("--num-cpus", "2", "--dashboard-port", "0")
     yield info
     if prev is None:
         os.environ.pop("RT_AUTH_TOKEN", None)
@@ -319,19 +310,9 @@ def test_head_state_survives_restart(tmp_path, monkeypatch):
     # fixed token: standalone runs have no ambient cluster token, and the
     # redacted stdout info cannot carry one to this client
     monkeypatch.setenv("RT_AUTH_TOKEN", "statetest" * 3)
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-
-    def start_head():
-        proc = subprocess.Popen(
-            [sys.executable, "-m", "ray_tpu._private.head_main",
-             "--num-cpus", "1", "--state-file", state_file,
-             "--state-save-interval", "0.5"],
-            stdout=subprocess.PIPE, text=True, env=env, cwd="/root/repo",
-        )
-        return proc, json.loads(proc.stdout.readline().strip())
-
-    proc, info = start_head()
+    head = ("--num-cpus", "1", "--state-file", state_file,
+            "--state-save-interval", "0.5")
+    proc, info = start_head(*head)
     try:
         from ray_tpu._private.sync_client import SyncHeadClient
 
@@ -351,7 +332,7 @@ def test_head_state_survives_restart(tmp_path, monkeypatch):
         proc.terminate()
         proc.wait(timeout=10)
 
-    proc, info = start_head()
+    proc, info = start_head(*head)
     try:
         from ray_tpu.job_submission import JobSubmissionClient
 
@@ -450,19 +431,9 @@ def test_head_restart_live_rejoin(tmp_path):
     # cluster token)
     prev_tok = os.environ.get("RT_AUTH_TOKEN")
     os.environ["RT_AUTH_TOKEN"] = "rejoin-test-token"
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-
-    def start_head():
-        proc = subprocess.Popen(
-            [sys.executable, "-m", "ray_tpu._private.head_main",
-             "--num-cpus", "2", "--state-file", state_file,
-             "--state-save-interval", "0.5", "--no-address-file"],
-            stdout=subprocess.PIPE, text=True, env=env, cwd="/root/repo",
-        )
-        return proc, json.loads(proc.stdout.readline().strip())
-
-    proc, info = start_head()
+    head = ("--num-cpus", "2", "--state-file", state_file,
+            "--state-save-interval", "0.5", "--no-address-file")
+    proc, info = start_head(*head)
     import ray_tpu
 
     try:
@@ -488,7 +459,7 @@ def test_head_restart_live_rejoin(tmp_path):
         assert ray_tpu.get(c.incr.remote(), timeout=30) == 2
 
         # restart the head on the SAME port from its snapshot
-        proc, info2 = start_head()
+        proc, info2 = start_head(*head)
         assert info2["address"] == info["address"], "head must rebind port"
 
         # the node reconnects and re-reports the actor; state survived
@@ -544,6 +515,13 @@ def test_head_restart_live_rejoin(tmp_path):
                 proc.wait(timeout=10)
             except subprocess.TimeoutExpired:
                 proc.kill()
+            # the first head's node outlived it, as it must: no head is
+            # left that would stop it, and it outlived pytest too
+            for pid in info["node_pids"]:
+                try:
+                    os.kill(pid, _signal.SIGKILL)
+                except ProcessLookupError:
+                    pass
 
 
 def test_failed_init_cleans_up_and_next_init_works(monkeypatch):

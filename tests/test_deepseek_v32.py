@@ -1,21 +1,19 @@
-"""DeepSeek-V3.2-Exp (``deepseek_v32``) through the program: the family's
-pieces against the benchmark's plain reference
-(``benchmarks/references/deepseek_v32.py``: the indexer's scores, the choice
-by a sort as a [T, T] mask, latent attention from up-projected keys and
-values a head at a time, the held experts by a loop), the exact choice
-against a sort, YaRN's numbers, and a slot's two rows a position in the
-engine's cache through ``DecodeEngine``: prefill in padded chunks, cached
-decoding through the masked kernel, the engine's two counters.
+"""DeepSeek-V3.2-Exp (``deepseek_v32``): what is peculiar to it. The cases
+every family shares (the reference and each of the serving cell's faults,
+bfloat16, the refusals, the plan of two rows a position, the four shares,
+padded chunks through the masked kernels, idle and reused slots, two slots,
+speculation) run over its row of ``tests/families.py``; here, YaRN's
+numbers, the exact choice against a sort, the indexer's scores inside VMEM,
+the masked decode kernel against the XLA step, and the engine's two
+counters.
 
-CPU, float32 where logits are compared, seeded weights, the toy's widths (a
-dense layer and two routed ones, 2 heads of 16 | 8, an indexer of 4 heads of
-16 that keeps 16 positions, YaRN from a context of 32, 16 experts in 4
-groups of which experts 4-7 are held, chunks of 16); each tolerance is
-written where it is used. Nothing timed here is a device number.
+CPU, float32, seeded weights, the toy's widths (a dense layer and two routed
+ones, 2 heads of 16 | 8, an indexer of 4 heads of 16 that keeps 16
+positions, YaRN from a context of 32); each tolerance is written where it is
+used. No device number.
 """
 import dataclasses
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -23,121 +21,23 @@ import numpy as np
 import pytest
 from jax.experimental.pallas import tpu as pltpu
 
-from benchmarks.tests import faults_deepseek_v32 as faults
-from ray_tpu.llm import DecodeEngine, LLMConfig, SamplingParams
-from ray_tpu.llm.engine import engine_programs
+from ray_tpu.llm import SamplingParams
 from ray_tpu.models import bailing_hybrid, decoder, deepseek_v32, kv_cache
-from ray_tpu.ops import block_attention, index_select
-from tests.test_granite_hybrid import _Spans, _prefill_then_decode
+from ray_tpu.ops import index_select
+from tests import families
+from tests.families import _tokens
 
-CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-TINY = dict(
-    model_family="deepseek_v32", vocab_size=300, max_seq_len=128,
-    num_layers=3, num_heads=2, embed_dim=64, mlp_dim=96, moe_mlp_dim=32,
-    rms_eps=1e-6, first_k_dense=1, num_shared_experts=1, q_lora_rank=48,
-    kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
-    rope_theta=10000.0, rope_factor=40.0, rope_original_max_position=32,
-    rope_beta_fast=32.0, rope_beta_slow=1.0, rope_mscale_all_dim=1.0,
-    index_n_heads=4, index_head_dim=16, index_topk=16, moe_num_experts=16,
-    moe_top_k=4, moe_norm_topk_prob=True, moe_score_func="sigmoid",
-    moe_route_scale=2.5, moe_n_group=4, moe_topk_group=2, moe_num_held=4,
-    moe_first_held=4, moe_expert_bias_init_std=0.02, dtype="float32",
-    max_batch_slots=3, prefill_buckets=(8, 16),
-)
+FAMILY = "deepseek_v32"
 
 
-@pytest.fixture(scope="module")
-def reference():
-    """The plain reference with its constants at the toy's: 16 positions
-    kept, YaRN from 32, 4 of 16 experts in 2 of 4 groups, experts 4-7 held,
-    16 rows at a time."""
-    from benchmarks.lib import named
-
-    ref = named.load(os.path.join(
-        CHECKOUT, "benchmarks", "references", "deepseek_v32.py"))
-    ref.TOP_K, ref.N_GROUP, ref.TOPK_GROUP, ref.FIRST_HELD = 4, 4, 2, 4
-    ref.INDEX_TOPK, ref.ROPE_ORIGINAL, ref.BLOCK = 16, 32, 16
-    return ref
-
-
-def _config(**changes):
-    return LLMConfig(**{**TINY, **changes}).model_config()
-
-
-def _tiny_params(cfg, seed=0):
-    """The family's own init with what would hide a fault moved: norm gains
-    off 1 and the indexer's LayerNorm bias off 0 (a norm on the wrong
-    vector), the matrices times 4 (at 0.02 and 64 channels a router's
-    scores all sit at 0.5 and a softmax over a few dozen positions is flat)
-    and the router's bias at 0.1 a sigmoid's spread."""
-    params = deepseek_v32.init_params(cfg, jax.random.PRNGKey(seed))
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 128))
-
-    def moved(path, a):
-        name = path[-1].key
-        if name.endswith("norm") or name == "norm_f":
-            return a * jax.random.uniform(next(keys), a.shape, a.dtype, 0.5, 1.5)
-        if name == "ik_bias":
-            return a + 0.3 * jax.random.normal(next(keys), a.shape, a.dtype)
-        if name == "expert_bias":
-            return a * 5.0
-        if name in ("wte", "lm_head"):
-            return a
-        return a * 4.0
-
-    return jax.tree_util.tree_map_with_path(moved, params)
-
-
-def _reference_logits(reference, params, tokens):
-    with jax.default_matmul_precision("highest"):
-        return np.asarray(jax.jit(reference.logits)(params, jnp.asarray(tokens)))
-
-
-def _tokens(shape, seed=0):
-    return np.random.default_rng(seed).integers(2, 300, shape).astype(np.int32)
-
-
-# ------------------------------------------- the family against the reference
-
-
-@pytest.mark.parametrize("variant", ("sound",) + faults.VARIANTS)
-def test_the_family_matches_the_reference_and_each_fault_does_not(
-        reference, monkeypatch, variant):
-    """The full forward over 64 tokens (16 of up to 64 positions chosen)
-    against the reference in float32: 5e-5, where its own rounding is 4e-6;
-    and with each of the cell's faults planted on the program's side
-    (``benchmarks/tests/faults_deepseek_v32.py``) it is far from it."""
-    config = {"model": dict(TINY)}
-    faults.plant(variant, config, monkeypatch.setattr)
-    cfg = LLMConfig(**config["model"]).model_config()
-    params = _tiny_params(cfg)
-    tokens = _tokens((2, 64))
-    want = _reference_logits(reference, params, tokens)
-    with jax.default_matmul_precision("highest"):
-        got, _ = jax.jit(lambda p, t: deepseek_v32.forward(p, t, cfg))(
-            params, jnp.asarray(tokens))
-    gap = np.abs(np.asarray(got) - want)
-    print(variant, gap.max())
-    if variant == "sound":
-        assert gap.max() < 5e-5
-        return
-    assert gap.max() > 1e-2
-    # the first 16 tokens see at most 16 positions: every one is chosen,
-    # whatever the indexer does
-    if variant in ("recent", "ik_unrotated", "no_relu"):
-        assert gap[:, :16].max() < 5e-5
-
-
-def test_bfloat16_activations_stay_near_the_float32_reference(reference):
-    cfg = _config(dtype="bfloat16")
-    params = _tiny_params(cfg)
-    tokens = _tokens((2, 64))
-    want = _reference_logits(reference, params, tokens)
-    got, _ = jax.jit(lambda p, t: deepseek_v32.forward(p, t, cfg))(
-        params, jnp.asarray(tokens))
-    # bf16 at 64 channels: most tokens within 0.05, a flipped choice more
-    assert np.median(np.abs(np.asarray(got) - want)) < 0.05
+def test_the_rows_layers_are_a_dense_lead_and_routed_ones_an_indexer_each():
+    """A share of 4 of 16 experts in 4 groups; every layer a latent row of
+    32 + 8 values and an indexer that keeps 16 positions."""
+    cfg = families.model_config(FAMILY)
+    assert cfg.moe.num_held == 4 and cfg.moe.n_group == 4
+    kinds = decoder.layer_kinds(cfg)
+    assert [k.routed for k in kinds] == [False, True, True]
+    assert all(k.latent == 40 and k.index.kept == 16 for k in kinds)
 
 
 def test_yarn_frequencies_and_scale_by_numbers_worked_out_here():
@@ -170,87 +70,6 @@ def test_yarn_frequencies_and_scale_by_numbers_worked_out_here():
     kind, = decoder.layer_kinds(cfg)
     assert kind.scale == cfg.softmax_scale
     assert kind.index == decoder.Index(64, 128, 2048)
-
-
-def test_two_rows_a_position_in_the_cache_plan():
-    """A dense lead and the routed layers, each one scan; the cache: a
-    latent row of rank + rope values AND the indexer's key a position and
-    layer, no keys or values a head; the costs' count is the leaves'."""
-    cfg = _config()
-    whole = dataclasses.replace(cfg, num_layers=61, first_k_dense=3)
-    segments, _ = deepseek_v32.layers(whole, None, cached=True)
-    assert [([k.name for k in s.kinds], s.repeats) for s in segments] == [
-        (["dense"], 3), (["routed"], 58)]
-    cache = jax.eval_shape(
-        lambda: decoder.init_kv_cache(cfg, 3, 128, block=16))
-    assert {k: (v.shape, v.dtype) for k, v in cache.items()} == {
-        "latent": ((3, 3, 1, 40, 128), jnp.float32),
-        "index": ((3, 3, 128, 16), jnp.float32)}
-    from benchmarks.lib import named
-
-    costs = named.load(os.path.join(
-        CHECKOUT, "benchmarks", "costs", "deepseek_v32.py"))
-    for sized in (cfg, dataclasses.replace(cfg, num_layers=5),
-                  dataclasses.replace(cfg, first_k_dense=3)):
-        params = jax.eval_shape(
-            lambda: deepseek_v32.init_params(sized, jax.random.PRNGKey(0)))
-        model = {f.name: getattr(sized, f.name)
-                 for f in dataclasses.fields(sized)}
-        model.update(moe_num_experts=16, moe_num_held=4, moe_top_k=4)
-        assert costs.param_count(model)["total"] == sum(
-            p.size for p in jax.tree.leaves(params))
-
-
-@pytest.mark.parametrize("bad, match", [
-    (dict(first_k_dense=-1), "first_k_dense -1"),
-    (dict(qk_rope_head_dim=32), "rotates its first 32 channels of 16"),
-    (dict(moe_dropless=False), "dropless")])
-def test_a_configuration_it_cannot_run_is_refused_by_name(bad, match):
-    from ray_tpu.models import config_for
-
-    sizes = {k: v for k, v in TINY.items() if k not in (
-        "model_family", "max_batch_slots", "prefill_buckets")}
-    with pytest.raises(ValueError, match=match):
-        config_for("deepseek_v32", **{"moe_dropless": True, **sizes, **bad})
-
-
-def test_the_four_shares_and_the_shared_expert_once_are_the_whole_layer(
-        reference, monkeypatch):
-    """The four chips' routed parts, each through the family's own ``ffn``
-    with its share of the weights, plus the shared expert ONCE, add up to
-    what the uncut reference gives for the layer: the router scores all 16
-    experts on every chip, and a pair is computed on exactly one."""
-    cfg = _config()
-    whole = dataclasses.replace(cfg, moe=dataclasses.replace(
-        cfg.moe, num_held=None, first_held=0))
-    params = _tiny_params(whole)            # all 16 experts' weights
-    layer = jax.tree.map(lambda a: a[0], params["blocks"]["segments"][1][0])
-    stacked = jax.tree.map(lambda a: a[:1], params["blocks"]["experts"])
-    experts = jax.tree.map(lambda a: a[0], stacked)
-    x = jnp.asarray(np.random.default_rng(0).normal(size=(2, 24, 64)),
-                    jnp.float32)
-    h = reference._rms_norm(x, layer["mlp_norm"]).reshape(-1, 64)
-    with jax.default_matmul_precision("highest"):
-        monkeypatch.setattr(reference, "FIRST_HELD", 0)
-        gates = reference.route(h, experts["router_w"], experts["expert_bias"])
-        shared = reference._swiglu(h, layer["shared_gate"],
-                                   layer["shared_up"], layer["shared_down"])
-        uncut = reference._experts(h, gates, stacked, 0) + shared
-        parts, rows = jnp.zeros_like(uncut), 0
-        for first in (0, 4, 8, 12):
-            share = dataclasses.replace(cfg, moe=dataclasses.replace(
-                cfg.moe, first_held=first))
-            held = {k: w if k in ("router_w", "expert_bias")
-                    else w[first:first + 4] for k, w in experts.items()}
-            out, aux, _ = deepseek_v32.ffn(
-                share, "routed", layer, x, None, None, (held, None))
-            parts += (out - x).reshape(-1, 64) - shared
-            rows += int(aux["moe_rows_held"])
-    # every (token, expert) pair on exactly one chip
-    assert rows == 2 * 24 * 4
-    assert float(jnp.abs(uncut - shared).max()) > 0.05
-    # float32 sums in another order: 2e-7 measured
-    np.testing.assert_allclose(parts + shared, uncut, atol=2e-6)
 
 
 # ------------------------------------------------------------ the exact choice
@@ -434,84 +253,33 @@ def test_a_chunks_scores_read_what_each_tile_of_queries_sees(
     assert not np.isnan(got[sees]).any()
 
 
-def test_the_programs_choice_is_the_references(reference):
+def test_the_programs_choice_is_the_references():
     """A layer's indexer through the family's own pieces, its scores and its
     choice (``index_select``), against the reference's [T, T] mask from a
     sort: the same set for every query of 64."""
-    cfg = _config()
-    params = _tiny_params(cfg)
+    cfg, params = families.tiny_params(FAMILY)
+    reference = families.reference(FAMILY)
     layer = jax.tree.map(lambda a: a[0], params["blocks"]["segments"][1][0])
     x = jnp.asarray(np.random.default_rng(1).normal(size=(1, 64, 64)),
                     jnp.float32)
     pos = jnp.arange(64)[None]
-    with jax.default_matmul_precision("highest"):
+    def both(x, layer):
         h = reference._rms_norm(x, layer["mix_norm"])
         cq = reference._rms_norm(h @ layer["w_dq"], layer["q_norm"])
         ix = deepseek_v32._indexed(cfg, layer, h, cq, pos)
         found = index_select.scores(ix.q, ix.weights, ix.key)
         seen = jnp.tril(jnp.ones((64, 64), bool))[None]
-        got = index_select.chosen(found, seen, ix.kept)[0]
-        want = reference.chosen_mask(cq[0], h[0], layer, 8)
+        return (index_select.chosen(found, seen, ix.kept)[0],
+                reference.chosen_mask(cq[0], h[0], layer, 8))
+
+    # one program: op by op it is a minute
+    with jax.default_matmul_precision("highest"):
+        got, want = jax.jit(both)(x, layer)
     assert np.array_equal(np.asarray(got), np.asarray(want))
     assert np.array_equal(np.asarray(got).sum(-1),
                           np.minimum(16, np.arange(64) + 1))
     # and it is no sliding window
     assert not np.array_equal(np.asarray(got)[40], np.arange(64) > 24)
-
-
-# ---------------------------------------------------- the cache and the engine
-
-
-@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
-@pytest.mark.parametrize("chunks", [
-    [(16, 16)], [(16, 16), (16, 16), (5, 8)]], ids=["one_bucket", "chunks"])
-def test_padded_chunks_then_cached_steps_match_the_reference(
-        reference, monkeypatch, impl, chunks):
-    """A prompt in one bucket (every position chosen), and one of 37 tokens
-    as two full chunks of 16 and 5 tokens padded to 8 (each writes both rows
-    of its positions and chooses among everything cached), then 16 decode
-    steps beside two idle slots, 16 of up to 53 positions chosen. With
-    ``pallas_interpret`` every decode step is the
-    ``latent_decode_attention`` kernel under the choice's mask, and a chunk
-    of 16 scores the filled blocks of its keys and attends the filled blocks
-    of its rows through ``selected_block_attention``, 32 positions at a
-    time."""
-    monkeypatch.setattr(kv_cache, "_decode_impl", lambda: impl)
-    monkeypatch.setattr(kv_cache, "CHOICE_WIDTHS", (32, 64))
-    monkeypatch.setattr(index_select, "POSITIONS", 32)
-    monkeypatch.setattr(block_attention, "SELECTED_POSITIONS", 32)
-    blocks = []
-    selected = kv_cache.selected_block_attention
-    monkeypatch.setattr(
-        kv_cache, "selected_block_attention",
-        lambda q, up, leaf, picked, *a, **k: blocks.append(
-            (q.shape[0], picked.shape, k["width"]))
-        or selected(q, up, leaf, picked, *a, **k))
-    cfg = _config()
-    params = _tiny_params(cfg)
-    prompt = sum(n for n, _ in chunks)
-    sequence = _tokens((prompt + 16,), seed=2)
-    want = _reference_logits(reference, params, sequence[None])[0]
-    with jax.default_matmul_precision("highest"):
-        rows, cache = _prefill_then_decode(cfg, params, sequence, chunks)
-    # a chunk's choice and attention over the narrowest width that holds it
-    assert set(blocks) == (set() if impl == "xla" else {
-        (16, (16, 128), w) for w in (32, 64, 128)})
-    at = list(np.cumsum([n for n, _ in chunks]) - 1) + list(
-        range(prompt, prompt + 16))
-    assert len(rows) == len(at)
-    # float32 against float32, logits and not tokens: the full forward's
-    # own distance from the reference (4e-6)
-    assert np.abs(np.stack(rows) - want[at]).max() < 5e-5
-    # both rows of every position of slot 1, and nothing of the idle slots
-    for name, leaf in cache.items():
-        # [layer, slot, position, channel] of either leaf
-        leaf = np.asarray(leaf) if name == "index" else np.swapaxes(
-            np.asarray(leaf)[:, :, 0], 2, 3)
-        assert np.abs(leaf[:, 1, :prompt + 16]).max(axis=-1).min() > 0
-        if impl != "xla":   # the XLA step computes every slot
-            assert not leaf[:, [0, 2]].any()
-        assert not leaf[:, 1, prompt + 16:].any()
 
 
 @pytest.mark.parametrize("live", [
@@ -543,10 +311,11 @@ def test_masked_decode_kernel_equals_the_xla_step_and_skips_idle_slots(
     outs = {}
     for impl in ("xla", "pallas_interpret"):
         monkeypatch.setattr(kv_cache, "_decode_impl", lambda: impl)
-        at = kv_cache.step(lens, 1, cache, live=alive)
+        # one program a route: op by op it is many times as long
         with jax.default_matmul_precision("highest"):
-            outs[impl] = kv_cache.attend_latent(
-                cache, 1, q, rows, up, at, 0.2, ix)
+            outs[impl] = jax.jit(lambda cache: kv_cache.attend_latent(
+                cache, 1, q, rows, up,
+                kv_cache.step(lens, 1, cache, live=alive), 0.2, ix))(cache)
     (got_cache, got), (want_cache, want) = (
         outs["pallas_interpret"], outs["xla"])
     keep = np.asarray(live)
@@ -571,21 +340,14 @@ def test_masked_decode_kernel_equals_the_xla_step_and_skips_idle_slots(
         assert not bool(picked[0, 40]) and int(picked[0].sum()) == 16
 
 
-def _engine(**changes):
-    engine = DecodeEngine(LLMConfig(**{**TINY, **changes}))
-    engine.params = deepseek_v32.serving_params(
-        engine.model_config, _tiny_params(engine.model_config))
-    return engine
-
-
 def test_two_slots_of_different_lengths_answer_as_the_full_forward_does():
     """Two requests at once through ``DecodeEngine`` (40 and 9 prompt
     tokens, 12 answer tokens each): the greedy answers are the full
     forward's, and the engine's two counters are the sums worked out here:
     every query's visible positions a latent layer, and 16 of them or
     all."""
-    engine = _engine()
-    spans = engine._span = _Spans()
+    engine = families._engine(FAMILY)
+    spans = engine._span
     cfg = engine.model_config
     prompts = [list(_tokens((n,), seed=n)) for n in (40, 9)]
     try:
@@ -599,11 +361,13 @@ def test_two_slots_of_different_lengths_answer_as_the_full_forward_does():
     params = engine.params
     forward = jax.jit(lambda p, t: deepseek_v32.forward(p, t, cfg)[0])
     for prompt, answer in zip(prompts, answers):
-        # teacher-forced: one forward over the prompt and the answer
-        tokens = [int(t) for t in prompt] + answer
+        # teacher-forced: one forward over the prompt and the answer, at
+        # one length for both (causal: the padding after reaches nothing)
+        tokens = np.zeros((1, 52), np.int32)
+        tokens[0, :len(prompt) + 12] = [int(t) for t in prompt] + answer
         greedy = np.asarray(jnp.argmax(forward(
-            params, jnp.asarray([tokens]))[0], axis=-1))
-        assert list(greedy[len(prompt) - 1:-1]) == answer
+            params, jnp.asarray(tokens))[0], axis=-1))
+        assert list(greedy[len(prompt) - 1:len(prompt) + 11]) == answer
     # admissions: chunks of 16 with their padding (40 -> 16 + 16 + 8, 9 ->
     # 16), every padded query counted as the program computes it
     seen = [t + 1 for start, n in ((0, 16), (16, 16), (32, 8), (0, 16))
@@ -627,38 +391,15 @@ def test_two_slots_of_different_lengths_answer_as_the_full_forward_does():
     assert cache_span == 3 * (40 + 16) * 128 * 4
 
 
-def test_speculation_has_no_quarrel_with_an_indexer_but_the_model_must_take_real():
-    """The engine's programs are told which rows are tokens (a router's),
-    and count a share's rows: three results."""
-    cfg = _config()
-    assert cfg.moe.num_held == 4 and cfg.moe.n_group == 4
-    kinds = decoder.layer_kinds(cfg)
-    assert [k.routed for k in kinds] == [False, True, True]
-    assert all(k.latent == 40 and k.index.kept == 16 for k in kinds)
-
-
 @pytest.mark.parametrize("scope", ["mla.index", "mla.select", "mla.sparse"])
-def test_the_programs_operations_carry_the_three_scopes(monkeypatch, scope):
+def test_the_programs_operations_carry_the_three_scopes(scope):
     """What a trace's reader finds the sparse attention's operations by
     (``benchmarks/lib/dsa_ops.py``): each scope is on some operation of the
     compiled decode and prefill programs, beside the latent layer's own."""
-    cfg = _config()
-    params = jax.eval_shape(lambda: deepseek_v32.serving_params(
-        cfg, deepseek_v32.init_params(cfg, jax.random.PRNGKey(0))))
-    prefill, _, decode, _ = engine_programs(cfg)
-    cache = jax.eval_shape(lambda: decoder.init_kv_cache(cfg, 3, 128, block=16))
-    slots = jax.ShapeDtypeStruct((3,), jnp.int32)
-    text = decode.lower(params, slots, cache, jax.ShapeDtypeStruct(
-        (3, 3), jnp.int32)).compile().as_text()
+    decoded, prefilled = families.program_texts(FAMILY)
     for name in (scope, "mla.q", "mla.down", "mla.up", "mla.out"):
-        assert name in text, name
-    cache1 = jax.eval_shape(lambda: decoder.init_kv_cache(cfg, 1, 128, block=16))
-    one = jax.ShapeDtypeStruct((1,), jnp.int32)
-    monkeypatch.setattr(kv_cache, "_decode_impl", lambda: "pallas_interpret")
-    text = prefill.lower(
-        params, jax.ShapeDtypeStruct((1, 16), jnp.int32), cache1, one, one,
-        rows=one).compile().as_text()
+        assert name in decoded, name
     # a chunk's rows are up-projected inside the kernel, under mla.sparse
     for name in (scope, "mla.q", "mla.down", "mla.out"):
-        assert name in text, name
-    assert "mla.attend" not in text and "mla.up" not in text
+        assert name in prefilled, name
+    assert "mla.attend" not in prefilled and "mla.up" not in prefilled

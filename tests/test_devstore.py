@@ -41,11 +41,7 @@ from ray_tpu._private import worker as worker_mod
 from ray_tpu._private.test_utils import wait_for_condition
 
 
-@pytest.fixture(autouse=True)
-def _fp_clean():
-    fp.clear()
-    yield
-    fp.clear()
+pytestmark = pytest.mark.usefixtures("faults_cleared")
 
 
 @pytest.fixture

@@ -33,11 +33,7 @@ from ray_tpu._private import specframe
 from ray_tpu._private import worker as worker_mod
 
 
-@pytest.fixture(autouse=True)
-def _fp_clean():
-    fp.clear()
-    yield
-    fp.clear()
+pytestmark = pytest.mark.usefixtures("faults_cleared")
 
 
 # ------------------------------------------------------ plane queue units

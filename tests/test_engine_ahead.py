@@ -41,7 +41,10 @@ def lively_params(config: LLMConfig):
     return jax.tree.map(lambda a: a * 8 if a.ndim >= 2 else a, params)
 
 
-def _engine(family, **more):
+pytestmark = pytest.mark.usefixtures("one_compile_a_file")
+
+
+def _lively_engine(family, **more):
     config = LLMConfig(**{**CONFIGS[family], **more})
     return DecodeEngine(config, params=lively_params(config))
 
@@ -49,7 +52,7 @@ def _engine(family, **more):
 def _hand_driven(family, **more):
     """An engine whose loop thread never starts: ``submit`` queues, and the
     test takes the loop's turns itself."""
-    engine = _engine(family, **more)
+    engine = _lively_engine(family, **more)
     engine._ensure_loop = lambda: None
     return engine
 
@@ -83,7 +86,7 @@ def family(request):
 def alone(family):
     """What each test prompt answers when it is the only request, with a
     host row's loop (``logprobs=1``): today's order of events."""
-    engine = _engine(family)
+    engine = _lively_engine(family)
     out = {i: list(engine.generate(
         _prompt(i), SamplingParams(max_new_tokens=14, logprobs=1)))
         for i in range(6)}
@@ -115,7 +118,7 @@ def test_depth_one_answers_as_depth_zero(family, alone, streamed):
     a stop token that comes fourth, and their ``logprobs=1`` twins: the
     same ids and the same endings, though only the twins' ticks waited for
     the host."""
-    engine = _engine(family)
+    engine = _lively_engine(family)
     stop = alone[1][3]
     assert stop not in alone[1][:3]
     asks = [(0, 9, None), (1, 12, stop), (2, 1, None), (3, 14, None),
@@ -297,11 +300,11 @@ def test_speculation_keeps_the_host_in_every_tick(family):
     """Drafts come from the host's history: with ``speculative_ngram_k``
     every row is a host row, and the answers are the plain engine's."""
     prompt = [5, 6, 7, 8, 5, 6, 7, 8, 5, 6]
-    plain = _engine(family)
+    plain = _lively_engine(family)
     want = list(plain.generate(prompt, SamplingParams(max_new_tokens=10)))
     assert plain.stats["ticks_ahead"] > 0
     plain.shutdown()
-    spec = _engine(family, speculative_ngram_k=3)
+    spec = _lively_engine(family, speculative_ngram_k=3)
     got = list(spec.generate(prompt, SamplingParams(max_new_tokens=10)))
     assert got == want
     assert spec.stats["ticks_ahead"] == spec.stats["overrun_rows"] == 0
@@ -311,7 +314,7 @@ def test_speculation_keeps_the_host_in_every_tick(family):
 def test_a_failed_tick_leaves_nothing_in_flight(family):
     """The loop's last resort (fail every request, clear the slots) also
     forgets the tick in flight: the next request starts from rest."""
-    engine = _engine(family)
+    engine = _lively_engine(family)
     honest = engine._decode
     calls = []
 
@@ -333,7 +336,7 @@ def test_a_failed_tick_leaves_nothing_in_flight(family):
         assert engine._flying is None
         engine._cache = decoder.init_kv_cache(
             engine.model_config, 2, engine.config.max_seq_len)
-    want = _engine(family)
+    want = _lively_engine(family)
     assert list(engine.generate(
         _prompt(1), SamplingParams(max_new_tokens=6))) == list(
         want.generate(_prompt(1), SamplingParams(max_new_tokens=6)))

@@ -26,13 +26,10 @@ import pytest
 import ray_tpu
 from ray_tpu._private import faultpoints as fp
 from ray_tpu._private.test_utils import NodeKiller, wait_for_condition
+from tests.conftest import _leases_settled, _no_leaked_objects
 
 
-@pytest.fixture(autouse=True)
-def _clean_faults():
-    fp.clear()
-    yield
-    fp.clear()
+pytestmark = pytest.mark.usefixtures("faults_cleared")
 
 
 # chaos_flight_trace moved to conftest.py (shared with the serve chaos
@@ -236,26 +233,6 @@ def test_wait_for_condition_polls_and_times_out():
 
 
 # --------------------------------------------------- cluster: retry/dedup
-def _leases_settled():
-    """All leases returned: every alive node's availability is back to its
-    full capacity at the head."""
-    cluster = ray_tpu._internal_cluster()
-    return all(
-        all(n.available.get(k, 0.0) >= v - 1e-9
-            for k, v in n.resources.items())
-        for n in cluster.head.nodes.values() if n.alive
-    )
-
-
-def _no_leaked_objects():
-    """Zero leaked objects (the memtrack plane's chaos SLO, joined to the
-    zero-leaked-leases one): no directory entry past the grace window
-    that no live process owns, stores, or borrows."""
-    from ray_tpu.util import state
-
-    return state.memory_summary(grace_s=1.0)["leaks"] == []
-
-
 def test_lease_reply_drop_is_retried_and_deduped(rt_start, fast_rpc):
     # The FIRST lease reply is swallowed after the head applied the grant;
     # the client's deadline fires, the retry carries the same correlation

@@ -145,6 +145,13 @@ def recording(tmp_path_factory):
     from benchmarks.lib import host_spans
 
     root = str(tmp_path_factory.mktemp("spans"))
+    # an engine that an earlier file of this worker left running idles for
+    # 30 s before its loop parks, and its ``engine.idle`` spans would lie in
+    # this capture on a line of their own (``test_llm.py`` leaves nineteen)
+    parked = time.monotonic() + 45
+    while time.monotonic() < parked and any(
+            t.name == "rt-llm-engine" for t in threading.enumerate()):
+        time.sleep(0.1)
     srv = _server()
     tok = srv.engine.tokenizer
     # the tokens the two requests that are to end early would make if
@@ -823,16 +830,16 @@ def test_unattributed_idle_arithmetic():
 # such a model's ``engine.tick`` carries.
 
 
-@pytest.fixture(scope="module")
-def share_recording(greedy_recording, tmp_path_factory):
+def _recorded(family, logdir):
+    """Three requests (37, 6 and 20 prompt tokens, 9 answer tokens each) on
+    the family's row of ``tests/families.py`` under one capture."""
     import jax
 
     from benchmarks.lib import host_spans
-    from tests.test_bailing_hybrid import TINY
+    from tests.families import TINY
 
-    engine = DecodeEngine(LLMConfig(**TINY))
+    engine = DecodeEngine(LLMConfig(**TINY[family]))
     prompts = [[3 + i] * n for i, n in enumerate((37, 6, 20))]
-    logdir = str(tmp_path_factory.mktemp("spans_share"))
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     before = dict(engine.stats)
@@ -849,6 +856,12 @@ def share_recording(greedy_recording, tmp_path_factory):
             "answers": answers}
 
 
+@pytest.fixture(scope="module")
+def share_recording(greedy_recording, tmp_path_factory):
+    return _recorded("bailing_hybrid",
+                     str(tmp_path_factory.mktemp("spans_share")))
+
+
 def test_a_share_models_ticks_carry_held_rows_and_latent_positions(
         share_recording):
     """``moe_rows_held`` (of the programs read since the span before, as
@@ -862,15 +875,19 @@ def test_a_share_models_ticks_carry_held_rows_and_latent_positions(
     assert all(len(a) == 9 for a in share_recording["answers"])
     assert stats["latent_positions"] == sum(
         t.args["latent_positions"] for t in ticks) > 0
-    # two latent layers of the toy's twelve
-    assert all(t.args["latent_positions"] == 2 * t.args["cache_positions"]
+    # the toy's latent layers, and none that holds keys and values a head
+    from tests import families
+
+    kinds = families.kinds("bailing_hybrid")
+    latent = sum(k.latent is not None for k in kinds)
+    assert all(t.args["latent_positions"] == latent * t.args["cache_positions"]
                and t.args["layers_full"] == 0 for t in ticks)
     # every decode program's held rows are on some tick's span; the
     # admissions' (three prompts' chunks) are in ``stats`` beside them
     held = sum(t.args["moe_rows_held"] for t in ticks)
     assert 0 < held <= stats["moe_rows_held"]
     assert sum(t.args["moe_rows"] for t in ticks) == (
-        stats["slot_ticks"] * 4 * 11)
+        stats["slot_ticks"] * 4 * sum(k.routed for k in kinds))
     assert held < sum(t.args["moe_rows"] for t in ticks)
     assert stats["moe_rows_held"] < stats["moe_rows"]
 
@@ -891,28 +908,8 @@ def test_a_model_that_holds_every_expert_carries_neither(greedy_recording):
 
 @pytest.fixture(scope="module")
 def indexed_recording(share_recording, tmp_path_factory):
-    import jax
-
-    from benchmarks.lib import host_spans
-    from tests.test_deepseek_v32 import TINY
-
-    engine = DecodeEngine(LLMConfig(**TINY))
-    prompts = [[3 + i] * n for i, n in enumerate((37, 6, 20))]
-    logdir = str(tmp_path_factory.mktemp("spans_indexed"))
-    options = jax.profiler.ProfileOptions()
-    options.python_tracer_level = 0
-    before = dict(engine.stats)
-    jax.profiler.start_trace(logdir, profiler_options=options)
-    try:
-        futures = [engine.submit(p, SamplingParams(max_new_tokens=9))
-                   for p in prompts]
-        answers = [list(f.result(300)) for f in futures]
-    finally:
-        jax.profiler.stop_trace()
-    stats = {k: engine.stats[k] - before[k] for k in before}
-    engine.shutdown()
-    return {"spans": host_spans.load(logdir), "stats": stats,
-            "answers": answers}
+    return _recorded("deepseek_v32",
+                     str(tmp_path_factory.mktemp("spans_indexed")))
 
 
 def test_an_indexed_models_ticks_carry_what_was_scored_and_what_was_read(

@@ -1,10 +1,12 @@
 """The ``joyai_llm_flash`` family (JoyAI-LLM-Flash: DeepSeek-V3's block)
-through the program: its pieces against the benchmark's plain reference
-(``benchmarks/references/joyai_llm_flash.py``: latent attention from
-up-projected keys and values one head after another, the held experts by a
-loop, the prediction layer written out), the faults that comparison must
-catch, a layer's sixteen experts in four shares, the balancing rule under
-the trainer's step, and the cached forward through the latent cache.
+: what is peculiar to it. The cases every family shares (the logits
+against the reference, bfloat16, the refusals, the plan, padded chunks
+through the latent cache, idle and reused slots, two slots, speculation) run
+over its row of ``tests/families.py``; here, what a step minimises against
+the benchmark's plain reference (``benchmarks/references/joyai_llm_flash.py``:
+two losses and every leaf's gradient), the faults that comparison must
+catch, a block's sixteen experts in four shares, and the balancing rule
+under the trainer's step.
 
 CPU, float32, seeded weights, tiny widths that keep every ratio (heads of 24
 / 16, a query rank, 1 dense + 2 routed layers + the prediction layer, 16
@@ -12,7 +14,7 @@ experts of which 4 are held, 2 a token); each tolerance is written where it
 is used. Nothing timed here is a device number.
 """
 import dataclasses
-import os
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -22,8 +24,9 @@ import pytest
 from ray_tpu.models import bailing_hybrid, decoder, joyai_llm_flash as family
 from ray_tpu.ops import xent
 from ray_tpu.parallel import moe
+from tests import families
 
-CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+FAMILY = "joyai_llm_flash"
 T = 37
 
 
@@ -33,42 +36,7 @@ def _config(**changes):
     return dataclasses.replace(family.JOYAI_FLASH_TINY, **changes)
 
 
-@pytest.fixture(scope="module")
-def reference():
-    """The plain reference with its constants at the toy's: 2 of 16 experts
-    a token, experts 0-3 held."""
-    from benchmarks.lib import named
-
-    ref = named.load(os.path.join(
-        CHECKOUT, "benchmarks", "references", "joyai_llm_flash.py"))
-    ref.TOP_K, ref.FIRST_HELD = 2, 0
-    return ref
-
-
-def _params(cfg, seed=0):
-    """The family's own init with what would hide a fault moved: norm gains
-    off 1 (a norm left out or put on the wrong vector), the matrices times 4
-    (at 0.02 and 64 channels a router's scores all sit at 0.5 and a softmax
-    over 37 positions is flat: nothing a token says would move them), the
-    embedding at 0.3 (at 1.0 no layer shows in a logit) and the router's
-    bias at 0.1, a sigmoid's spread (a choice the bias decides)."""
-    params = family.init_params(cfg, jax.random.PRNGKey(seed))
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 128))
-
-    def moved(path, a):
-        name = path[-1].key
-        if "norm" in name:
-            return a * jax.random.uniform(next(keys), a.shape, a.dtype, 0.5, 1.5)
-        if name == "expert_bias":
-            return a * 5.0
-        if name == "wte":
-            return a * 0.3
-        return a if name == "lm_head" else a * 4.0
-
-    return jax.tree_util.tree_map_with_path(moved, params)
-
-
-def _tokens(seed=0):
+def _batch(seed=0):
     return jnp.asarray(np.random.default_rng(seed).integers(
         0, 512, (2, T + 1)).astype(np.int32))
 
@@ -93,29 +61,27 @@ def leaf_gaps(got, want):
     return out
 
 
-_PLAIN = {}
-
-
-def _plain_side(reference):
-    """The reference's logits, two losses and gradients of ``_params`` on
-    ``_tokens``: no fault and no change of configuration below moves a
+@functools.lru_cache(maxsize=None)
+def _plain_side():
+    """The reference's logits, two losses and gradients of the moved weights
+    on ``_batch``: no fault and no change of configuration below moves a
     weight's shape or the reference, so they are made once."""
+    reference = families.reference(FAMILY)
+
     def plain(params, tokens):
         return (reference.logits(params, tokens[:, :-1]),
                 *reference.loss_parts(params, tokens),
                 jax.grad(lambda p: reference.loss(p, tokens))(params))
 
-    if id(reference) not in _PLAIN:
-        with jax.default_matmul_precision("highest"):
-            _PLAIN[id(reference)] = jax.jit(plain)(
-                _params(_config()), _tokens())
-    return _PLAIN[id(reference)]
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(plain)(families._moved(FAMILY, _config()), _batch())
 
 
-def _readings(reference, cfg, params, tokens):
+def _readings(cfg, params, tokens):
     """How far the program lies from the reference: the main head's logits
     (max), the two losses, and over the leaves the worst relative error of a
-    gradient's norm and the worst 1 - cosine, of what a step minimises."""
+    gradient's norm and the worst 1 - cosine, of what a step minimises; and
+    the program's gradients."""
     def program(params, tokens):
         xent_, aux = family.loss_fn(params, {"tokens": tokens}, cfg,
                                     parts=True)
@@ -126,13 +92,13 @@ def _readings(reference, cfg, params, tokens):
     # each side one program: op by op the same arithmetic takes a minute
     with jax.default_matmul_precision("highest"):
         got, xent_, mtp_, grads = jax.jit(program)(params, tokens)
-    want, main, mtp, grads_ref = _plain_side(reference)
+    want, main, mtp, grads_ref = _plain_side()
     gaps = leaf_gaps(grads, grads_ref).values()
     return {"logits": float(jnp.abs(got - want).max()),
             "main": abs(float(xent_) - float(main)),
             "mtp": abs(float(mtp_) - float(mtp)),
             "grad_norm": max(g[0] for g in gaps),
-            "grad_turn": max(g[1] for g in gaps)}
+            "grad_turn": max(g[1] for g in gaps)}, grads
 
 
 # float32 against float32, the order of the sums alone: logits 6e-7 on 1.4,
@@ -143,20 +109,17 @@ LIMITS = {"logits": 2e-5, "main": 1e-5, "mtp": 1e-5, "grad_norm": 1e-4,
 
 
 @pytest.mark.parametrize("impl", ["xla", "flash_interpret"])
-def test_logits_losses_and_every_leafs_gradient_match_the_reference(
-        reference, impl):
+def test_logits_losses_and_every_leafs_gradient_match_the_reference(impl):
     cfg = _config(attention_impl=impl)
     kinds = decoder.layer_kinds(cfg)
     assert [(k.name, k.routed, k.latent) for k in kinds] == [
         ("dense", False, 24), ("routed", True, 24), ("routed", True, 24)]
-    params = _params(cfg)
+    params = families._moved(FAMILY, cfg)
     assert set(params["mtp"]) == {"norm_h", "norm_e", "eh_proj", "layer",
                                   "experts", "norm_f"}
-    got = _readings(reference, cfg, params, _tokens())
+    got, grads = _readings(cfg, params, _batch())
     assert all(got[k] <= LIMITS[k] for k in LIMITS), got
     # the router's bias has no gradient: it moves a choice of indices
-    grads = jax.jit(jax.grad(
-        lambda p: family.loss_fn(p, {"tokens": _tokens()}, cfg)))(params)
     assert not np.asarray(
         grads["blocks"]["experts"]["expert_bias"]).any()
     assert not np.asarray(grads["mtp"]["experts"]["expert_bias"]).any()
@@ -237,7 +200,7 @@ FAULTS = {
 
 
 @pytest.mark.parametrize("fault", list(FAULTS))
-def test_each_fault_is_caught_by_the_comparison(reference, monkeypatch, fault):
+def test_each_fault_is_caught_by_the_comparison(monkeypatch, fault):
     """The program with one fault planted reads past a limit that the sound
     program stays inside (the test above): which reading, and by how much,
     is asserted a fault. A loss weight of 0 moves no loss and no logit: only
@@ -249,7 +212,7 @@ def test_each_fault_is_caught_by_the_comparison(reference, monkeypatch, fault):
     cfg = _config(**changes)
     if FAULTS[fault] is not None:
         FAULTS[fault](monkeypatch, cfg)
-    got = _readings(reference, cfg, _params(cfg), _tokens())
+    got, _ = _readings(cfg, families._moved(FAMILY, cfg), _batch())
     over = {k: got[k] / LIMITS[k] for k in LIMITS if got[k] > LIMITS[k]}
     assert over and max(over.values()) > 30, (fault, got)
     if fault == "loss weight 0":
@@ -259,7 +222,7 @@ def test_each_fault_is_caught_by_the_comparison(reference, monkeypatch, fault):
         assert "mtp" in over and "logits" not in over and "main" not in over
 
 
-def test_four_shares_and_the_shared_expert_once_are_the_whole_layer(reference):
+def test_four_shares_and_the_shared_expert_once_are_the_whole_layer():
     """The guide's section 4 for a layer shared four ways (the cell's
     sixteen, at the toy's 16 experts): each share's block gives ``base + its
     experts' part`` (``base``: the stream after the mixer and the shared
@@ -267,7 +230,7 @@ def test_four_shares_and_the_shared_expert_once_are_the_whole_layer(reference):
     are the uncut reference's layer, the shared expert counted once."""
     cfg = _config()
     whole = _config(moe=dataclasses.replace(cfg.moe, num_held=16))
-    params = _params(whole)
+    params = families._moved(FAMILY, whole)
     x = 0.5 * jax.random.normal(jax.random.PRNGKey(1), (2, T, 64))
     pos = jnp.broadcast_to(jnp.arange(T)[None], (2, T))
     layer = jax.tree.map(lambda a: a[0], params["blocks"]["segments"][1][0])
@@ -275,8 +238,9 @@ def test_four_shares_and_the_shared_expert_once_are_the_whole_layer(reference):
     kind = decoder.layer_kinds(cfg)[1]
 
     def block(cfg, experts):
-        return decoder._body(cfg, None, pos, kind)(
-            x, layer, None, (experts, None))[0]
+        # one program a share: op by op a block is five seconds
+        return jax.jit(lambda experts: decoder._body(cfg, None, pos, kind)(
+            x, layer, None, (experts, None))[0])(experts)
 
     base = block(whole, {**experts, "expert_out": jnp.zeros_like(
         experts["expert_out"])})
@@ -287,7 +251,7 @@ def test_four_shares_and_the_shared_expert_once_are_the_whole_layer(reference):
                 else w[first:first + 4] for name, w in experts.items()}
         parts.append(block(share, held) - base)
     with jax.default_matmul_precision("highest"):
-        want = reference._layer(x, layer, experts)
+        want = families.reference(FAMILY)._layer(x, layer, experts)
     np.testing.assert_allclose(np.asarray(base + sum(parts)),
                                np.asarray(want), atol=2e-5, rtol=0)
     # and each part is something: no share is the whole
@@ -307,8 +271,8 @@ def test_a_step_moves_each_bias_by_the_rule_and_the_optimizer_does_not():
     cfg = _config()
     opt = OptimizerConfig(learning_rate=0.1, warmup_steps=0).build()
     state = create_train_state(cfg, opt, jax.random.PRNGKey(0))
-    state["params"] = _params(cfg)
-    batch = {"tokens": _tokens()}
+    state["params"] = families._moved(FAMILY, cfg)
+    batch = {"tokens": _batch()}
     xent_, aux = jax.jit(lambda p, b: family.loss_fn(p, b, cfg, parts=True))(
         state["params"], batch)
     counts = np.asarray(aux["moe_counts"])
@@ -340,39 +304,3 @@ def test_a_step_moves_each_bias_by_the_rule_and_the_optimizer_does_not():
         "moe_bias_abs_mean"}
 
 
-def test_prefill_then_decode_through_the_latent_cache_is_the_reference(
-        reference):
-    """The family's cached forward (a prefill of 24 tokens, then 13 decode
-    steps of one, a position's 24-value row in the latent cache) against the
-    reference's FULL forward at the same size, float32: within 2e-5 on
-    logits of 1.4. The prediction layer is held and not run."""
-    cfg = _config()
-    params = _params(cfg)
-    tokens = _tokens()[:, :T]
-    with jax.default_matmul_precision("highest"):
-        want = reference.logits(params, tokens)
-        served = family.serving_params(cfg, params)
-        cache = decoder.init_kv_cache(cfg, 2, 64, block=24)
-        assert set(cache) == {"latent"} and cache["latent"].shape == (
-            3, 2, 1, 24, 64)
-        start = jnp.zeros((2,), jnp.int32)
-        cached = jax.jit(lambda tokens, cache, start: decoder.forward_cached(
-            served, tokens, cache, start, cfg))
-        got, cache = cached(tokens[:, :24], cache, start)
-        parts = [got]
-        for t in range(24, T):
-            step, cache = cached(tokens[:, t:t + 1], cache, start + t)
-            parts.append(step)
-    assert float(jnp.abs(want).max()) > 0.5
-    np.testing.assert_allclose(
-        np.concatenate(parts, axis=1), np.asarray(want), atol=2e-5, rtol=0)
-
-
-@pytest.mark.parametrize("bad, match", [
-    ({"moe": moe.MoEConfig(num_experts=16, activation="swiglu")}, "dropless"),
-    ({"num_mtp_layers": 2}, "num_mtp_layers"),
-    ({"first_k_dense": 4}, "first_k_dense"),
-])
-def test_a_configuration_it_cannot_run_is_refused_by_name(bad, match):
-    with pytest.raises(ValueError, match=match):
-        _config(**bad)

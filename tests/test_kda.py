@@ -10,7 +10,7 @@ import pytest
 
 from ray_tpu.ops import delta_rule, kda, ssm
 from ray_tpu.ops.decode_attention import live_slots
-from tests.test_ssm import LATE, _scattered
+from tests.families import LATE, _scattered
 
 H, DK, DV = 3, 8, 16
 LOWER = -5.0    # the published lower bound of a step's log decay

@@ -22,36 +22,46 @@ _SMALL = dict(
 )
 
 
-def _engine(**over):
+pytestmark = pytest.mark.usefixtures("one_compile_a_file")
+
+
+def _small_engine(**over):
     return DecodeEngine(LLMConfig(**{**_SMALL, **over}), seed=0)
+
+
+def _greedy_by_the_full_forward(module, eng, prompt, n_new):
+    """The argmax over the full forward, run again for every token up to an
+    eos: one program over a fixed length (causal, and served dropless: what
+    lies after a position does not reach it), where a forward a length
+    compiled every operation again."""
+    import jax
+    import jax.numpy as jnp
+
+    cfg = eng.model_config
+    forward = jax.jit(lambda p, toks: module.forward(p, toks, cfg)[0])
+    seq, expect = list(prompt), []
+    for _ in range(n_new):
+        toks = np.zeros((1, len(prompt) + n_new), np.int32)
+        toks[0, :len(seq)] = seq
+        logits = forward(eng.params, jnp.asarray(toks))
+        expect.append(int(jnp.argmax(logits[0, len(seq) - 1])))
+        if expect[-1] == eng.tokenizer.eos_id:
+            break
+        seq.append(expect[-1])
+    return expect
 
 
 def test_cached_decode_matches_full_forward():
     """Incremental KV-cache decoding must produce exactly the greedy tokens
     the full-context forward produces."""
-    import jax
-    import jax.numpy as jnp
-
     from ray_tpu.models import gpt2
 
-    eng = _engine()
+    eng = _small_engine()
     prompt = [5, 9, 17, 33, 2, 7]
     n_new = 12
     got = eng.generate(prompt, SamplingParams(max_new_tokens=n_new))
 
-    # reference: argmax over full forward, re-run per step
-    cfg = eng.model_config
-    seq = list(prompt)
-    expect = []
-    for _ in range(n_new):
-        logits, _ = gpt2.forward(
-            eng.params, jnp.asarray([seq], jnp.int32), cfg
-        )
-        nxt = int(jnp.argmax(logits[0, -1]))
-        expect.append(nxt)
-        if nxt == eng.tokenizer.eos_id:
-            break
-        seq.append(nxt)
+    expect = _greedy_by_the_full_forward(gpt2, eng, prompt, n_new)
     # engine strips a trailing eos; align lengths
     assert got == [t for t in expect if t != eng.tokenizer.eos_id][: len(got)]
     assert len(got) >= 1
@@ -60,19 +70,19 @@ def test_cached_decode_matches_full_forward():
 def test_continuous_batching_matches_sequential():
     """Interleaved requests (shared slots) must decode the same greedy
     outputs as one-at-a-time generation."""
-    eng = _engine()
+    eng = _small_engine()
     prompts = [[3, 1, 4], [1, 5, 9, 2], [6, 5], [3, 5, 8, 9, 7]]
     p = SamplingParams(max_new_tokens=8)
     futs = [eng.submit(pr, p) for pr in prompts]  # all in flight together
     batched = [f.result(120) for f in futs]
 
-    eng2 = _engine()
+    eng2 = _small_engine()
     sequential = [eng2.generate(pr, p) for pr in prompts]
     assert batched == sequential
 
 
 def test_more_requests_than_slots():
-    eng = _engine(max_batch_slots=2)
+    eng = _small_engine(max_batch_slots=2)
     p = SamplingParams(max_new_tokens=4)
     futs = [eng.submit([i + 2, i + 3], p) for i in range(7)]
     outs = [f.result(120) for f in futs]
@@ -81,7 +91,7 @@ def test_more_requests_than_slots():
 
 
 def test_temperature_sampling_runs():
-    eng = _engine()
+    eng = _small_engine()
     out = eng.generate(
         [4, 8, 15], SamplingParams(max_new_tokens=6, temperature=0.8, top_k=8)
     )
@@ -92,7 +102,7 @@ def test_prompt_too_long_rejected():
     """Too long is longer than the context leaves room for an answer; a
     prompt longer than the largest prefill bucket is admitted in chunks
     (PR 34)."""
-    eng = _engine()
+    eng = _small_engine()
     assert 58 > max(eng.config.prefill_buckets)
     out = eng.generate(list(range(2, 60)), SamplingParams(max_new_tokens=2))
     assert len(out) == 2
@@ -181,13 +191,13 @@ def test_batch_processor(llm_cluster):
 def test_prefill_decode_disaggregation_matches_monolithic():
     """PD split: prefill_only state transferred into a separate engine must
     produce exactly the monolithic engine's greedy output."""
-    eng_mono = _engine()
+    eng_mono = _small_engine()
     prompt = [7, 3, 11, 19]
     p = SamplingParams(max_new_tokens=8)
     expect = eng_mono.generate(prompt, p)
 
-    eng_prefill = _engine()
-    eng_decode = _engine()
+    eng_prefill = _small_engine()
+    eng_decode = _small_engine()
     prefilled = eng_prefill.prefill_only(prompt, p)
     # simulate the wire: numpy arrays survive a serialize round-trip
     import pickle
@@ -211,7 +221,7 @@ def test_pd_serving_app(llm_cluster):
         assert out["disaggregated"] is True
         assert out["usage"]["completion_tokens"] >= 1
         # equals the monolithic engine's greedy result on the same weights
-        eng = _engine(vocab_size=512)
+        eng = _small_engine(vocab_size=512)
         expect = eng.tokenizer.decode(
             eng.generate(eng.tokenizer.encode("hello"),
                          SamplingParams(max_new_tokens=4))
@@ -227,7 +237,7 @@ def test_pd_serving_app(llm_cluster):
 def test_prefix_cache_exact_hit_same_output():
     """Identical prompts: the second request skips prefill entirely and
     greedy output is unchanged."""
-    eng = _engine(prefix_cache_size=4)
+    eng = _small_engine(prefix_cache_size=4)
     try:
         prompt = list(range(2, 14))
         p = SamplingParams(max_new_tokens=6)
@@ -247,14 +257,14 @@ def test_prefix_cache_partial_hit_matches_uncached():
     longer = base + [30, 31, 32, 33]
     p = SamplingParams(max_new_tokens=6)
 
-    ref_eng = _engine(prefix_cache_size=0)
+    ref_eng = _small_engine(prefix_cache_size=0)
     try:
         expected = ref_eng.generate(longer, p)
         assert ref_eng.stats["prefix_hits"] == 0
     finally:
         ref_eng.shutdown()
 
-    eng = _engine(prefix_cache_size=4)
+    eng = _small_engine(prefix_cache_size=4)
     try:
         eng.generate(base, p)           # seeds the prefix cache
         out = eng.generate(longer, p)
@@ -265,7 +275,7 @@ def test_prefix_cache_partial_hit_matches_uncached():
 
 
 def test_prefix_cache_lru_bound():
-    eng = _engine(prefix_cache_size=2)
+    eng = _small_engine(prefix_cache_size=2)
     try:
         p = SamplingParams(max_new_tokens=2)
         for start in (2, 20, 40):
@@ -314,11 +324,9 @@ def test_moe_cached_decode_matches_full_forward():
     """MoE (Mixtral-style) decode through the KV cache must reproduce the
     full-forward greedy tokens — the expert routing is per-token and must
     be identical in both paths."""
-    import jax.numpy as jnp
-
     from ray_tpu.models import llama
 
-    eng = _engine(
+    eng = _small_engine(
         model_family="llama", moe_num_experts=4, moe_top_k=2, num_layers=2,
     )
     assert eng.model_config.moe is not None
@@ -326,18 +334,7 @@ def test_moe_cached_decode_matches_full_forward():
     n_new = 8
     got = eng.generate(prompt, SamplingParams(max_new_tokens=n_new))
 
-    cfg = eng.model_config
-    seq = list(prompt)
-    expect = []
-    for _ in range(n_new):
-        logits, _ = llama.forward(
-            eng.params, jnp.asarray([seq], jnp.int32), cfg
-        )
-        nxt = int(jnp.argmax(logits[0, -1]))
-        expect.append(nxt)
-        if nxt == eng.tokenizer.eos_id:
-            break
-        seq.append(nxt)
+    expect = _greedy_by_the_full_forward(llama, eng, prompt, n_new)
     assert got == [t for t in expect if t != eng.tokenizer.eos_id][: len(got)]
     assert len(got) >= 1
 
@@ -369,7 +366,7 @@ def test_moe_openai_app(llm_cluster):
 def test_sampling_seed_reproducible_and_varied():
     """Per-request seed: same seed -> identical stochastic output; the
     engine-global rng stays untouched for other requests."""
-    eng = _engine()
+    eng = _small_engine()
     prompt = [5, 9, 17]
     p = SamplingParams(max_new_tokens=8, temperature=1.0, seed=7)
     out1 = eng.generate(prompt, p)
@@ -390,7 +387,7 @@ def test_sampling_top_p_restricts_support():
     the model's actual next-token distribution)."""
     import jax.numpy as jnp
 
-    eng = _engine()
+    eng = _small_engine()
     prompt = [5, 9, 17, 33]
     # collect the model's next-token distribution via logprobs
     probe = eng.generate(prompt, SamplingParams(
@@ -417,7 +414,7 @@ def test_sampling_top_p_restricts_support():
 def test_sampling_penalties_suppress_repeats():
     """A strong frequency penalty forbids re-drawing generated tokens
     (greedy without it repeats on a tiny random model)."""
-    eng = _engine()
+    eng = _small_engine()
     prompt = [3, 3, 3, 3]
     base = eng.generate(prompt, SamplingParams(max_new_tokens=12))
     pen = eng.generate(prompt, SamplingParams(
@@ -429,7 +426,7 @@ def test_sampling_penalties_suppress_repeats():
 
 
 def test_sampling_logprobs_shape_and_consistency():
-    eng = _engine()
+    eng = _small_engine()
     out = eng.generate([5, 9, 17], SamplingParams(
         max_new_tokens=5, logprobs=3,
     ))
@@ -443,7 +440,7 @@ def test_sampling_logprobs_shape_and_consistency():
 
 
 def test_stop_strings_trim_output():
-    eng = _engine()
+    eng = _small_engine()
     prompt = [5, 9, 17, 33, 2, 7]
     full = eng.generate(prompt, SamplingParams(max_new_tokens=10))
     full_text = eng.tokenizer.decode(list(full))
@@ -461,9 +458,9 @@ def test_pd_disaggregation_logprobs_and_seed_alignment():
     """PD split preserves the sampling contract: logprob entries align
     1:1 with tokens (incl. the prefill server's first token), and a
     seeded stochastic request matches the monolithic engine exactly."""
-    eng_prefill = _engine()
-    eng_decode = _engine()
-    eng_mono = _engine()
+    eng_prefill = _small_engine()
+    eng_decode = _small_engine()
+    eng_mono = _small_engine()
     prompt = [5, 9, 17, 33]
     p = SamplingParams(max_new_tokens=6, temperature=0.7, seed=11,
                        logprobs=2)
@@ -483,7 +480,7 @@ def test_serving_returns_logprobs(rt_serve_cluster=None):
 
     srv = LLMServer.__new__(LLMServer)
     srv.config = LLMConfig(**_SMALL)
-    srv.engine = _engine()
+    srv.engine = _small_engine()
     resp = srv.completions({"prompt": "hi", "max_tokens": 4, "logprobs": 2})
     lp = resp["choices"][0]["logprobs"]
     assert len(lp["tokens"]) == resp["usage"]["completion_tokens"]
@@ -504,7 +501,7 @@ def test_serving_finish_reason(ended_on, stream):
 
     srv = LLMServer.__new__(LLMServer)
     srv.config = LLMConfig(**_SMALL)
-    srv.engine = _engine()
+    srv.engine = _small_engine()
     greedy = list(srv.engine.generate(
         srv.engine.tokenizer.encode("hi"), SamplingParams(max_new_tokens=6)))
     kept = 6
@@ -543,7 +540,7 @@ def test_serving_finish_reason(ended_on, stream):
 def test_engine_stream_matches_generate():
     """submit_stream yields exactly the tokens generate() returns (greedy),
     and rejects string stops (their trim point needs the full output)."""
-    eng = _engine()
+    eng = _small_engine()
     prompt = [5, 9, 17, 33]
     p = SamplingParams(max_new_tokens=8)
     expect = list(eng.generate(prompt, p))
@@ -620,7 +617,7 @@ def test_speculative_ngram_matches_plain_greedy():
     output (acceptance only keeps tokens the full model agrees with) while
     accepting drafts on repetitive text — and streams/continuous-batches
     identically."""
-    plain = _engine()
+    plain = _small_engine()
     spec = DecodeEngine(
         LLMConfig(**{**_SMALL, "speculative_ngram_k": 4}), seed=0
     )
@@ -670,7 +667,7 @@ def test_prefill_donates_an_admissions_own_slot_cache_and_no_entrys(shared):
     it."""
     import jax
 
-    eng = _engine(prefix_cache_size=4)
+    eng = _small_engine(prefix_cache_size=4)
     given = {"own": [], "entry": []}
     own, entry = eng._prefill_own, eng._prefill
     eng._prefill_own = lambda params, toks, cache, *a, **k: (

@@ -346,15 +346,16 @@ def test_tied_head_gradient_is_the_sum_of_both_uses():
         logits, _ = gpt2.forward(p, inputs, cfg)
         return _naive_xent(logits, jnp.eye(512), targets)
 
-    tied = jax.grad(lambda p: gpt2.loss_fn(p, batch, cfg))(params)
-    as_embedding, as_head = jax.grad(apart, argnums=(0, 1))(
+    # each a program: op by op a backward pass takes many times as long
+    tied = jax.jit(jax.grad(lambda p: gpt2.loss_fn(p, batch, cfg)))(params)
+    as_embedding, as_head = jax.jit(jax.grad(apart, argnums=(0, 1)))(
         params["wte"], params["wte"])
     assert np.abs(np.asarray(as_embedding)).max() > 1e-4
     assert np.abs(np.asarray(as_head)).max() > 1e-4
     np.testing.assert_allclose(
         np.asarray(tied["wte"]), np.asarray(as_embedding + as_head),
         atol=1e-7)
-    want = jax.grad(naive)(params)
+    want = jax.jit(jax.grad(naive))(params)
     for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(tied)[0],
                             jax.tree.leaves(want)):
         np.testing.assert_allclose(

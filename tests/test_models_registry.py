@@ -4,7 +4,10 @@
 and the loss; a family module owns what differs and is listed once, in
 ``models.FAMILIES``. These tests hold the seam where it is: a family that
 grows its own scan, or a decoder that asks which family it serves, fails
-here.
+here. What a family's forward computes is held over the table of
+``tests/families.py`` (``test_family_reference.py``, ``test_family_cached.py``,
+``test_family_engine.py``), its ``serving_params`` and heads-major pieces by
+``test_family_weights.py``.
 """
 import ast
 import dataclasses
@@ -12,7 +15,6 @@ import inspect
 import json
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -22,6 +24,7 @@ from ray_tpu.models import (
     FAMILIES, config_for, decoder, family_module, get_preset, module_for,
 )
 from ray_tpu.parallel.moe import MoEConfig
+from tests import families
 
 # what the decoder calls on a family, what a server calls once as it takes
 # its weights, and what callers outside ask of one
@@ -35,10 +38,6 @@ STATE_PIECES = ("state_in", "state_out", "state_leaves")
 OWN = ("Config", "PRESETS", "EXPERT_ACTIVATION", "init_params", "param_axes")
 SHARED = ("forward_features", "forward", "init_kv_cache", "forward_cached",
           "forward_pipelined", "loss_fn", "count_params")
-# families with no dense form: every layer routed, whatever is stated
-ALWAYS_ROUTED = ("smallthinker",)
-# families with no routed form: experts are refused by the key's name
-NEVER_ROUTED = ("granite_hybrid", "olmo_hybrid")
 GATED = ("swiglu", "reglu")   # activations with a gate matrix
 
 
@@ -142,7 +141,8 @@ def test_llm_config_builds_every_family(family, experts):
     heads not stated are as many as the query heads, and routed experts get
     the family's activation."""
     module = family_module(family)
-    if experts and family in NEVER_ROUTED:
+    if experts and families.variants(family)["routed"] is None:
+        # no routed form: experts are refused by the key's name
         with pytest.raises(ValueError, match="moe"):
             LLMConfig(model_family=family, num_heads=4, embed_dim=64,
                       moe_num_experts=experts).model_config()
@@ -159,10 +159,9 @@ def test_llm_config_builds_every_family(family, experts):
         # several in a stack of their own
         gated = "expert_gate" in (blocks.get("moe") or blocks["experts"])
         assert gated == (module.EXPERT_ACTIVATION in GATED)
-    elif family in ALWAYS_ROUTED:
-        assert cfg.moe == module.Config().moe   # the family's own
     else:
-        assert cfg.moe is None
+        # dense, or, for a family with no dense form, its own experts
+        assert cfg.moe == module.Config().moe
     # stated: the family's config takes it under its own name, or refuses
     # it by that name
     stated = dict(model_family=family, num_heads=4, embed_dim=64,
@@ -189,7 +188,8 @@ def test_llm_config_builds_every_family(family, experts):
             # their indexer's key
             assert set(cache) - {"index"} == {"latent"}
             assert cache["latent"].shape == (4, 3, 1, cfg.latent_dim, 16)
-            assert ("index" in cache) == (family == "deepseek_v32")
+            assert ("index" in cache) == any(
+                k.index is not None for k in decoder.layer_kinds(cfg))
         else:
             assert cache["k"].shape == (4, 3, 4, 16, 16)
 
@@ -198,40 +198,15 @@ def test_llm_config_builds_every_family(family, experts):
 # ``LLMConfig`` names no family's field: what it does not read goes to
 # ``models.config_for`` as it was stated, the function the trainer calls too.
 
-TINY = {"gpt2": "gpt2-tiny", "llama": "llama-tiny", "afmoe": "afmoe-tiny",
-        "smallthinker": "smallthinker-tiny",
-        "granite_hybrid": "granite-hybrid-tiny",
-        "olmo_hybrid": "olmo-hybrid-tiny",
-        "bailing_hybrid": "bailing-hybrid-tiny",
-        "joyai_llm_flash": "joyai-flash-tiny",
-        "deepseek_v32": "deepseek-v32-tiny"}
-
-
-def _flat(family) -> dict:
-    """The family's tiny preset as a configuration file states one: dtypes
-    by name, how many experts and the router's numbers that are not
-    ``MoEConfig``'s own under their flat names, no ``attention_impl``."""
-    cfg = get_preset(TINY[family])
-    flat = {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)
-            if f.name not in ("moe", "attention_impl")
-            and getattr(cfg, f.name) is not None}
-    for key in ("dtype", "param_dtype"):
-        flat[key] = jnp.dtype(flat[key]).name
-    if cfg.moe is not None:
-        assert cfg.moe.activation == family_module(family).EXPERT_ACTIVATION
-        flat.update({key: getattr(cfg.moe, name)
-                     for key, name in models.MOE_KEYS.items()
-                     if name == "num_experts"
-                     or getattr(cfg.moe, name) != getattr(MoEConfig, name)})
-    return flat
-
-
 @pytest.mark.parametrize("family", list(FAMILIES))
 def test_the_engine_and_the_trainer_build_one_config_from_one_set_of_keys(
         family):
-    flat = _flat(family)
+    flat = families.flat_keys(families.preset(family))
     routed = bool(flat.get("moe_num_experts"))
-    assert routed == (get_preset(TINY[family]).moe is not None)
+    assert routed == (families.preset(family).moe is not None)
+    if routed:
+        assert families.preset(family).moe.activation == family_module(
+            family).EXPERT_ACTIVATION
     served = LLMConfig(model_family=family, **flat).model_config()
     # the trainer's ``config_for(model.pop("family"), **model)``
     trained = config_for(family, **{
@@ -239,7 +214,7 @@ def test_the_engine_and_the_trainer_build_one_config_from_one_set_of_keys(
         **({"moe_dropless": True} if routed else {})})
     assert served == trained and type(served) is type(trained)
     # and the keys said what the preset is
-    preset = dataclasses.replace(get_preset(TINY[family]),
+    preset = dataclasses.replace(families.preset(family),
                                  attention_impl="xla")
     if routed:
         preset = dataclasses.replace(
@@ -260,20 +235,6 @@ def test_the_engine_and_the_trainer_build_one_config_from_one_set_of_keys(
         config.model)
 
 
-TOY_FAMILY = """
-from dataclasses import dataclass
-from typing import Sequence
-
-from ray_tpu.models.llama import *  # noqa: F401,F403 — llama's pieces
-
-
-@dataclass(frozen=True)
-class Config(LlamaConfig):
-    toy_gain: float = 1.0
-    toy_layout: Sequence[int] = ()
-"""
-
-
 @pytest.mark.parametrize("loaded", [True, False], ids=["loaded", "on_disk"])
 def test_a_new_family_reaches_the_engine_config_with_no_edit_outside_it(
         monkeypatch, tmp_path, loaded):
@@ -285,7 +246,7 @@ def test_a_new_family_reaches_the_engine_config_with_no_edit_outside_it(
     import sys
 
     name = f"toy_family_{'loaded' if loaded else 'on_disk'}"
-    (tmp_path / f"{name}.py").write_text(TOY_FAMILY)
+    (tmp_path / f"{name}.py").write_text(families.TOY_FAMILY)
     monkeypatch.syspath_prepend(str(tmp_path))
     monkeypatch.setitem(FAMILIES, "toy", name)
     # the test's end takes out of ``sys.modules`` whatever is there by then
@@ -474,109 +435,6 @@ def test_the_engine_serves_a_trained_bundle_dropless(tmp_path):
         direct.shutdown()
 
 
-# ------------------------------------------------- the weights a server holds
-# ``serving_params``: what a family's cached forward rounds on every use,
-# rounded once. The same values by the same operation, so not a bit moves.
-
-def _tiny(family, experts, **dtypes):
-    if experts and family in NEVER_ROUTED:
-        pytest.skip(f"{family} has no routed form")
-    if family in ALWAYS_ROUTED:
-        # "dense": the preset's own experts; "routed": the count asked for
-        cfg = dataclasses.replace(get_preset(TINY[family]), **dtypes)
-    else:
-        cfg = dataclasses.replace(
-            get_preset(TINY[family]), moe=None, **dtypes)
-    if experts:
-        cfg = dataclasses.replace(cfg, moe=MoEConfig(
-            num_experts=experts, top_k=2, dropless=True,
-            activation=family_module(family).EXPERT_ACTIVATION))
-    return cfg
-
-
-def _perturbed(params):
-    """Every leaf of ones or zeros (norm gains, biases) and the router moved:
-    at init ``bf16(1.0) == 1.0`` hides a gain that was rounded."""
-    keys = iter(jax.random.split(jax.random.PRNGKey(1), 64))
-
-    def moved(path, a):
-        constant = bool((a == a.reshape(-1)[0]).all())  # ones or zeros
-        if not constant and path[-1].key != "router_w":
-            return a
-        return a + 0.37 * jax.random.normal(next(keys), a.shape, a.dtype)
-
-    return jax.tree_util.tree_map_with_path(moved, params)
-
-
-def _prefill_and_three_steps(cfg, params):
-    """Every logit and the cache of a prefill of two prompts and three
-    greedy decode steps of ``forward_cached``."""
-    step = jax.jit(
-        lambda p, t, c, s: decoder.forward_cached(p, t, c, s, cfg))
-    tokens = jnp.asarray(
-        np.random.default_rng(0).integers(2, cfg.vocab_size, (2, 16)),
-        jnp.int32)
-    start = jnp.zeros((2,), jnp.int32)
-    logits, cache = step(
-        params, tokens, decoder.init_kv_cache(cfg, 2, 64, block=16), start)
-    out = [logits]
-    for i in range(3):
-        nxt = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
-        logits, cache = step(params, nxt, cache, start + 16 + i)
-        out.append(logits)
-    return [np.asarray(a) for a in out + list(cache.values())]
-
-
-@pytest.mark.parametrize("weights", ["init", "perturbed"])
-@pytest.mark.parametrize("experts", [0, 4], ids=["dense", "routed"])
-@pytest.mark.parametrize("family", list(FAMILIES))
-def test_serving_params_move_no_bit_of_the_cached_forward(
-        family, experts, weights):
-    module = family_module(family)
-    cfg = _tiny(family, experts)
-    assert cfg.param_dtype == jnp.float32 and cfg.dtype == jnp.bfloat16
-    given = module.init_params(cfg, jax.random.PRNGKey(0))
-    if weights == "perturbed":
-        given = _perturbed(given)
-        assert not any(((a == 1) | (a == 0)).any()
-                       for a in jax.tree.leaves(given))
-    held = module.serving_params(cfg, given)
-    rounded = [h is not g for g, h in zip(
-        jax.tree.leaves(given), jax.tree.leaves(held))]
-    assert any(rounded) and not all(rounded)
-    assert {a.dtype for a in jax.tree.leaves(held)} == {
-        jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)}
-    want = _prefill_and_three_steps(cfg, given)
-    for a, b in zip(want, _prefill_and_three_steps(cfg, held)):
-        np.testing.assert_array_equal(a, b)
-    if weights == "perturbed":
-        # and the comparison sees a leaf rounded that the forward reads as
-        # it is (a norm's gain, llama's ``wte``, the router)
-        everything = jax.tree.map(lambda a: a.astype(cfg.dtype), given)
-        assert any((a != b).any() for a, b in zip(
-            want, _prefill_and_three_steps(cfg, everything)))
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("experts", [0, 4], ids=["dense", "routed"])
-@pytest.mark.parametrize("family", list(FAMILIES))
-def test_serving_params_are_the_arrays_given_where_none_is_wider(
-        family, experts, dtype):
-    """``param_dtype == dtype``, and bf16 weights under float32
-    activations: nothing to round, so no operation runs and no byte is
-    copied (7.1 GB of bf16 experts stay where they lie)."""
-    module = family_module(family)
-    for cfg in (_tiny(family, experts, dtype=dtype, param_dtype=dtype),
-                _tiny(family, experts, dtype=jnp.float32,
-                      param_dtype=jnp.bfloat16)):
-        given = module.init_params(cfg, jax.random.PRNGKey(0))
-        held = module.serving_params(cfg, given)
-        assert jax.tree.structure(held) == jax.tree.structure(given)
-        for g, h in zip(jax.tree.leaves(given), jax.tree.leaves(held)):
-            assert h is g
-
-
 # ------------------------------------------------------- kinds of layer
 
 
@@ -586,7 +444,7 @@ def test_a_family_of_one_kind_of_layer_is_one_scan(family, experts):
     """``gpt2`` and ``llama`` state one kind of layer: one segment over the
     blocks as they are stacked, no window, one cache."""
     module = family_module(family)
-    cfg = _tiny(family, experts)
+    cfg = families.variants(family)["routed" if experts else "dense"]
     params = module.init_params(cfg, jax.random.PRNGKey(0))
     for cached in (False, True):
         segments, stack = module.layers(cfg, params["blocks"], cached)
@@ -599,126 +457,3 @@ def test_a_family_of_one_kind_of_layer_is_one_scan(family, experts):
     assert set(decoder.layer_kinds(cfg)) == {
         decoder.Layer(routed=bool(experts))}
     assert sorted(decoder.init_kv_cache(cfg, 2, 32)) == ["k", "v"]
-
-
-def test_a_stack_of_several_kinds_is_a_lead_and_whole_periods():
-    from ray_tpu.models import afmoe
-
-    S, F = afmoe.SLIDING, afmoe.FULL
-    published = afmoe.Config(
-        num_layers=32, num_dense_layers=2, sliding_window=2048,
-        layer_types=(S, S, S, F) * 8, moe=MoEConfig(num_experts=128, top_k=8))
-    segments, _ = afmoe.layers(published, None, cached=True)
-    assert [(len(s.kinds), s.repeats) for s in segments] == [(4, 1), (4, 7)]
-    assert [k.name for k in segments[0].kinds] == [
-        "sliding/dense", "sliding/dense", "sliding/routed", "full/routed"]
-    assert [k.name for k in segments[1].kinds] == [
-        "sliding/routed"] * 3 + ["full/routed"]
-    kinds = decoder.layer_kinds(published)
-    assert len(kinds) == 32 and sum(k.routed for k in kinds) == 30
-    assert [k.window for k in kinds[:4]] == [2048, 2048, 2048, None]
-    # the cell's cut: five kinds, nothing repeats, so nothing is scanned
-    cut = dataclasses.replace(published, num_layers=5, num_dense_layers=1,
-                              layer_types=(S, S, S, S, F))
-    (kinds, _, repeats), = afmoe.layers(cut, None, cached=True)[0]
-    assert len(kinds) == 5 and repeats == 1
-    cache = jax.eval_shape(
-        lambda: decoder.init_kv_cache(cut, 32, 8192, block=2048))
-    assert {k: v.shape for k, v in cache.items()} == {
-        "k": (1, 32, 32, 64, 8192), "v": (1, 32, 32, 64, 8192),
-        "k_window": (4, 32, 32, 64, 4096), "v_window": (4, 32, 32, 64, 4096)}
-    with pytest.raises(ValueError, match="layer_types"):
-        dataclasses.replace(cut, layer_types=(S, S, S, S, "S"))
-    with pytest.raises(ValueError, match="pipeline"):
-        decoder.forward_pipelined(
-            afmoe.init_params(afmoe.AFMOE_TINY, jax.random.PRNGKey(0)),
-            jnp.zeros((2, 8), jnp.int32), afmoe.AFMOE_TINY, None)
-
-
-@pytest.mark.parametrize("family", list(FAMILIES))
-def test_a_prompt_longer_than_the_largest_bucket_is_admitted_in_chunks(
-        family):
-    """17 tokens through buckets of 8 and 16 (one chunk of 16 and one of 1,
-    the second continuing the first's cache) give the tokens of one
-    unchunked prefill in a bucket of 32."""
-    from ray_tpu.llm import DecodeEngine, SamplingParams
-
-    extra = dict(layer_types=("sliding_attention",) * 3 + ("full_attention",),
-                 sliding_window=8, num_dense_layers=1,
-                 moe_num_experts=4, moe_score_func="sigmoid"
-                 ) if family == "afmoe" else dict(
-        layer_types=("mamba", "mamba", "attention", "mamba"),
-        mamba_d_state=16, mamba_d_head=16, mamba_chunk_size=8
-        ) if family == "granite_hybrid" else dict(
-        layer_types=("linear_attention",) * 3 + ("full_attention",),
-        linear_num_key_heads=4, linear_num_value_heads=4,
-        linear_key_head_dim=8, linear_value_head_dim=16, linear_chunk_size=4
-        ) if family == "olmo_hybrid" else {}
-    prompt = [int(t) for t in np.random.default_rng(5).integers(2, 300, 17)]
-    answers = []
-    for buckets in ((8, 16), (32,)):
-        engine = DecodeEngine(LLMConfig(
-            model_family=family, vocab_size=300, max_seq_len=64,
-            num_layers=4, num_heads=4, embed_dim=64, dtype="float32",
-            max_batch_slots=2, prefill_buckets=buckets, **extra))
-        out = engine.generate(prompt, SamplingParams(max_new_tokens=6))
-        answers.append(list(out))
-        engine.shutdown()
-        assert len(out) == 6
-    assert answers[0] == answers[1]
-    with pytest.raises(ValueError, match="no room for an answer"):
-        DecodeEngine(LLMConfig(
-            model_family=family, max_seq_len=16, prefill_buckets=(8,),
-            **extra)).generate(list(range(2, 18)))
-
-
-# --------------------------------------- the two orders of a family's heads
-# The cached forward calls ``qkv`` / ``attn_out`` as it always did, [B, T, H,
-# D]; the full forward asks for [B, H, T, D] (``heads_major=True``), which a
-# family's products write themselves. One projection, two orders: the same
-# numbers.
-
-
-@pytest.mark.parametrize("family", list(FAMILIES))
-def test_heads_major_pieces_are_the_cached_ones_transposed(family):
-    module = family_module(family)
-    cfg = dataclasses.replace(get_preset(TINY[family]), dtype=jnp.float32)
-    params = module.init_params(cfg, jax.random.PRNGKey(0))
-    segments, _ = module.layers(cfg, params["blocks"], cached=False)
-    B, T = 2, 12
-    x = jax.random.normal(jax.random.PRNGKey(1), (B, T, cfg.embed_dim))
-    pos = jnp.arange(T)[None] + jnp.asarray([[3], [40]])
-    seen = 0
-    for segment in segments:
-        for kind, stacked in zip(segment.kinds, segment.params):
-            if kind.state is not None:
-                continue
-            seen += 1
-            layer = jax.tree.map(lambda a: a[0], stacked)
-            got = module.qkv(cfg, kind.name, layer, x, pos, heads_major=True)
-            want = list(module.qkv(cfg, kind.name, layer, x, pos))
-            # grouped query heads [B, T, KV, G, D] are flat, kv-major, there
-            want[0] = want[0].reshape(B, T, -1, want[0].shape[-1])
-            if kind.index is not None:
-                # an indexer's pieces are the same in either order
-                for a, b in zip(jax.tree.leaves(got[3]),
-                                jax.tree.leaves(want[3])):
-                    np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                               atol=2e-6, rtol=2e-6)
-                got, want = got[:3], want[:3]
-            if kind.latent is not None:   # the rows and the up-projection
-                want[1:] = [a.swapaxes(1, 2) for a in want[1:]]    # as given
-            for a, b in zip(got, want):
-                np.testing.assert_allclose(
-                    np.asarray(a), np.asarray(b.swapaxes(1, 2)),
-                    atol=2e-6, rtol=2e-6)
-            attn = jax.random.normal(jax.random.PRNGKey(2), want[0].shape[:3]
-                                     + got[-1].shape[-1:])
-            if kind.latent is not None:
-                attn = attn[..., :cfg.v_head_dim]
-            np.testing.assert_allclose(
-                np.asarray(module.attn_out(cfg, layer, x, attn.swapaxes(1, 2),
-                                           heads_major=True)),
-                np.asarray(module.attn_out(cfg, layer, x, attn)),
-                atol=2e-6, rtol=2e-6)
-    assert seen

@@ -15,6 +15,7 @@ import pytest
 
 from ray_tpu.models import get_preset, module_for
 from ray_tpu.parallel import moe
+from tests.families import _equations
 
 E = 64           # experts the router scores
 PAIRS = 96
@@ -149,30 +150,18 @@ def test_the_models_counts_are_the_numbers_they_were():
     cfg = dataclasses.replace(cfg, dtype=jnp.float32, moe=dataclasses.replace(
         cfg.moe, num_held=2, first_held=2))
     model = module_for(cfg)
-    params = model.init_params(cfg, jax.random.PRNGKey(0))
     tokens = jnp.asarray(np.random.default_rng(0).integers(
         0, 512, (2, 33)), jnp.int32)
-    _, aux = model.loss_fn(params, {"tokens": tokens}, cfg, parts=True)
+    # one program: op by op the same arithmetic takes ten times as long
+    _, aux = jax.jit(lambda key, tokens: model.loss_fn(
+        model.init_params(cfg, key), {"tokens": tokens}, cfg, parts=True))(
+        jax.random.PRNGKey(0), tokens)
     assert (int(aux["moe_rows_held"]), int(aux["moe_rows_max_expert"])) == (
         MODEL_AS_IT_WAS[1:])
     assert abs(float(aux["aux_loss"]) - MODEL_AS_IT_WAS[0]) < 5e-7
 
 
 # --------------------------------------------------------- no scatter of ones
-
-
-def _equations(jaxpr):
-    """Every equation of a jaxpr and of the jaxprs inside its equations
-    (branches, bodies, checkpoints); ``tests/test_smallthinker.py`` counts
-    grouped products with it."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for value in eqn.params.values():
-            for sub in (value if isinstance(value, (tuple, list))
-                        else (value,)):
-                inner = getattr(sub, "jaxpr", sub)
-                if hasattr(inner, "eqns"):
-                    yield from _equations(inner)
 
 
 @pytest.mark.parametrize("case", ["share2-3", "share6-7-masked", "olmoe"])

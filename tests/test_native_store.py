@@ -212,13 +212,18 @@ def test_cross_process_write_then_parent_read():
         store.close_all()
 
 
-def test_hybrid_falls_back_when_arena_full():
+def test_hybrid_falls_back_when_arena_full(monkeypatch):
+    # the smallest arena there is (``_shm_budget``'s floor of 16 MiB): any
+    # object larger than the arena proves the fallback, and twice the
+    # default 4 GiB was 8 GiB moved several times
+    monkeypatch.setenv("RT_ARENA_BYTES", str(1 << 20))
     name = f"/rt_test_hy_{os.getpid()}_{secrets.token_hex(4)}"
     store = HybridShmStore(name)
     try:
         if store.arena is None:
             pytest.skip("no native arena")
         cap = store.arena.stats()["capacity"]
+        assert cap <= 1 << 24
         oid = _hex()
         meta = store.put_frames(oid, [b"W" * (cap * 2)])
         assert "seg" in meta  # portable fallback segment

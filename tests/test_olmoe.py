@@ -23,6 +23,7 @@ from ray_tpu.models import llama
 from ray_tpu.parallel.moe import (
     MoEConfig, init_moe_params, moe_layer, moe_layer_counted,
 )
+from tests import families
 
 CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -31,11 +32,6 @@ def _benchmark_file(kind, name):
     from benchmarks.lib import named
 
     return named.load(os.path.join(CHECKOUT, "benchmarks", kind, name))
-
-
-@pytest.fixture(scope="module")
-def reference():
-    return _benchmark_file("references", "olmoe.py")
 
 
 # ------------------------------------------------ (a) - (c): the dispatch
@@ -177,60 +173,21 @@ def test_no_array_has_a_token_and_an_expert_by_width_extent():
         CHECKOUT, "ray_tpu", "parallel", "moe.py")).read()
 
 
-# --------------------------------- (d): the family against the reference
+# ------------------ (d): the family against the reference: the ``llama`` row
+# of ``tests/families.py`` (sound, a key left un-normed, gates renormalised)
 
-TINY = dict(
-    model_family="llama", vocab_size=300, max_seq_len=64, num_layers=2,
-    num_heads=4, num_kv_heads=4, embed_dim=64, mlp_dim=32, rope_theta=10000,
-    rms_eps=1e-5, qk_norm="full", moe_num_experts=16, moe_top_k=8,
-    moe_norm_topk_prob=False, dtype="float32", max_batch_slots=4,
-    prefill_buckets=(8, 16, 32),
-)
+# the row's keys with this file's engine: four slots, a bucket of 32
+OLMOE = {**families.TINY["llama"], "max_seq_len": 64, "max_batch_slots": 4,
+         "prefill_buckets": (8, 16, 32)}
 
 
-def _tiny_params(cfg, seed=0):
-    params = llama.init_params(cfg, jax.random.PRNGKey(seed))
-    blocks = dict(params["blocks"])
-    # norms of all ones would hide a norm applied to the wrong vector, and
-    # experts of 0.02 a wrong gate
-    rng = np.random.default_rng(seed)
-    for name in ("q_norm", "k_norm", "attn_norm", "mlp_norm"):
-        blocks[name] = (blocks[name] * jnp.asarray(
-            rng.uniform(0.5, 1.5, blocks[name].shape), blocks[name].dtype))
-    blocks["moe"] = jax.tree.map(lambda a: a * 8.0, blocks["moe"])
-    return {**params, "blocks": blocks}
+@pytest.fixture(scope="module")
+def reference():
+    return families.reference("llama")
 
 
-def _reference_logits(reference, params, tokens):
-    with jax.default_matmul_precision("highest"):
-        return np.asarray(reference.logits(params, jnp.asarray(tokens)))
-
-
-def test_llama_with_olmoe_flags_matches_the_reference(reference):
-    cfg = LLMConfig(**TINY).model_config()
-    assert cfg.qk_norm == "full" and cfg.moe.dropless
-    assert not cfg.moe.norm_topk_prob and cfg.moe.activation == "swiglu"
-    params = _tiny_params(cfg)
-    tokens = np.random.default_rng(0).integers(0, 300, (2, 24)).astype(
-        np.int32)
-    got, _ = llama.forward(params, jnp.asarray(tokens), cfg)
-    want = _reference_logits(reference, params, tokens)
-    # float32 against float32: the order of the sums, 2e-7 to 2e-6 measured
-    # on logits of 0.5 to 2
-    assert np.abs(want).max() > 0.5
-    assert np.abs(np.asarray(got) - want).max() < 1e-5
-    # the faintest faults must read above that: a key left un-normed, and
-    # gates renormalised
-    for fault in (dataclasses.replace(cfg, qk_norm="none"),
-                  dataclasses.replace(cfg, moe=dataclasses.replace(
-                      cfg.moe, norm_topk_prob=True))):
-        off, _ = llama.forward(params, jnp.asarray(tokens), fault)
-        assert np.abs(np.asarray(off) - want).max() > 1e-3
-    unnormed = {**params, "blocks": {
-        **params["blocks"],
-        "k_norm": jnp.ones_like(params["blocks"]["k_norm"])}}
-    off, _ = llama.forward(unnormed, jnp.asarray(tokens), cfg)
-    assert np.abs(np.asarray(off) - want).max() > 1e-3
+def _weights(config):
+    return families._moved("llama", config.model_config())
 
 
 # ------------------------------------- (e) - (g): through the engine
@@ -283,8 +240,8 @@ def _decode(engine, sequences, active, garbage=0):
 ], ids=["float32", "bf16_weights"])
 def test_engine_prefill_and_cached_decode_match_the_reference(
         reference, dtype, tol):
-    config = LLMConfig(**{**TINY, "dtype": dtype, "param_dtype": dtype})
-    engine = DecodeEngine(config, params=_tiny_params(config.model_config()))
+    config = LLMConfig(**{**OLMOE, "dtype": dtype, "param_dtype": dtype})
+    engine = DecodeEngine(config, params=_weights(config))
     assert {a.dtype for a in jax.tree.leaves(engine.params)} == {
         jnp.dtype(dtype)}
     assert engine._cache["k"].dtype == jnp.dtype(dtype)
@@ -294,8 +251,12 @@ def test_engine_prefill_and_cached_decode_match_the_reference(
     got, want = [], []
 
     def full(b):
-        return _reference_logits(
-            reference, engine.params, np.asarray([sequences[b]], np.int32))[0]
+        # one length, so one program (causal, a token at a time: what lies
+        # after a position does not reach it)
+        padded = np.zeros((1, 40), np.int32)
+        padded[0, :len(sequences[b])] = sequences[b]
+        return families._reference_logits(
+            reference, engine.params, padded)[0, :len(sequences[b])]
 
     for b, seq in sequences.items():
         logits, touched = _prefill_into(engine, b, seq)
@@ -336,16 +297,16 @@ def served(tmp_path_factory):
     """Four requests on the tiny OLMoE engine's loop, under one capture."""
     from benchmarks.lib import host_spans
 
-    config = LLMConfig(**TINY)
-    engine = DecodeEngine(config, params=_tiny_params(config.model_config()))
+    config = LLMConfig(**OLMOE)
+    engine = DecodeEngine(config, params=_weights(config))
     logdir = str(tmp_path_factory.mktemp("olmoe_spans"))
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     rng = np.random.default_rng(9)
     prompts = [list(rng.integers(2, 258, n)) for n in (3, 11, 20, 7, 30)]
-    bf16 = LLMConfig(**{**TINY, "dtype": "bfloat16",
+    bf16 = LLMConfig(**{**OLMOE, "dtype": "bfloat16",
                         "param_dtype": "bfloat16"})
-    bf16_given = _tiny_params(bf16.model_config())
+    bf16_given = _weights(bf16)
     jax.profiler.start_trace(logdir, profiler_options=options)
     try:
         # a replica that takes bf16 weights while the capture runs
@@ -421,8 +382,9 @@ def test_experts_touched_is_what_the_reference_routes(served, reference):
 
         reference.route, honest = spy, reference.route
         try:
-            _reference_logits(reference, params,
-                              np.asarray([prompt], np.int32))
+            # op by op: a program traced before the spy would not call it
+            with jax.default_matmul_precision("highest"):
+                reference.logits(params, jnp.asarray([prompt], jnp.int32))
         finally:
             reference.route = honest
         jax.effects_barrier()
@@ -447,20 +409,20 @@ def test_a_dense_model_routes_nothing():
 
 
 def test_llm_config_fields_reach_the_family_by_name():
-    cfg = LLMConfig(**TINY).model_config()
+    cfg = LLMConfig(**OLMOE).model_config()
     assert (cfg.hidden_dim, cfg.rope_theta, cfg.rms_eps) == (32, 10000, 1e-5)
     assert cfg.param_dtype == jnp.float32  # not stated: the family's own
-    assert LLMConfig(**{**TINY, "param_dtype": "bfloat16"}
+    assert LLMConfig(**{**OLMOE, "param_dtype": "bfloat16"}
                      ).model_config().param_dtype == jnp.bfloat16
     # a field the gpt2 family does not take is an error by its name
     for field in ("rope_theta", "rms_eps", "qk_norm", "mlp_dim"):
         with pytest.raises(TypeError, match=field):
-            LLMConfig(model_family="gpt2", **{field: TINY[field]}
+            LLMConfig(model_family="gpt2", **{field: OLMOE[field]}
                       ).model_config()
     with pytest.raises(TypeError, match="num_kv_heads"):
         LLMConfig(model_family="gpt2", num_kv_heads=2).model_config()
     with pytest.raises(ValueError, match="qk_norm"):
-        LLMConfig(**{**TINY, "qk_norm": "head"}).model_config()
+        LLMConfig(**{**OLMOE, "qk_norm": "head"}).model_config()
     with pytest.raises(ValueError, match="unknown model_family 'mamba'"):
         LLMConfig(model_family="mamba").model_config()
 
@@ -468,9 +430,9 @@ def test_llm_config_fields_reach_the_family_by_name():
 def test_router_init_std_reaches_the_routers_weights_and_nothing_else():
     """0.02 unless stated; stated, the router's weights have that scale and
     every other leaf is what it was (same key)."""
-    base = LLMConfig(**TINY).model_config()
+    base = LLMConfig(**OLMOE).model_config()
     assert base.moe.router_init_std == 0.02
-    peaked = LLMConfig(**TINY, moe_router_init_std=0.1).model_config()
+    peaked = LLMConfig(**OLMOE, moe_router_init_std=0.1).model_config()
     assert peaked.moe.router_init_std == 0.1
     a, b = (llama.init_params(c, jax.random.PRNGKey(0))
             for c in (base, peaked))
@@ -489,9 +451,9 @@ def test_stacked_experts_are_cast_once_outside_the_layer_loop():
     straight from the head's float32 sums."""
     from ray_tpu.parallel.moe import stacked_for
 
-    cfg = LLMConfig(**{**TINY, "dtype": "bfloat16"}).model_config()
+    cfg = LLMConfig(**{**OLMOE, "dtype": "bfloat16"}).model_config()
     assert (cfg.dtype, cfg.param_dtype) == (jnp.bfloat16, jnp.float32)
-    params = _tiny_params(cfg)
+    params = families._moved("llama", cfg)
     tokens = jnp.asarray(np.random.default_rng(1).integers(2, 258, (2, 8)),
                          jnp.int32)
     cache = llama.init_kv_cache(cfg, 2, 16)
@@ -563,11 +525,11 @@ def test_the_configuration_file_holds_the_published_numbers():
 
 def test_param_count_is_the_leaves_of_init_params():
     costs = _benchmark_file("costs", "olmoe.py")
-    model = {k: v for k, v in TINY.items() if k in (
+    model = {k: v for k, v in OLMOE.items() if k in (
         "vocab_size", "max_seq_len", "num_layers", "num_heads",
         "num_kv_heads", "embed_dim", "mlp_dim", "moe_num_experts",
         "moe_top_k")}
-    cfg = LLMConfig(**TINY).model_config()
+    cfg = LLMConfig(**OLMOE).model_config()
     leaves = jax.tree.leaves(llama.init_params(cfg, jax.random.PRNGKey(0)))
     count = costs.param_count(model)
     assert count["total"] == sum(a.size for a in leaves)

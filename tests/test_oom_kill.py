@@ -13,7 +13,9 @@ from ray_tpu._private.memory_monitor import get_memory_usage
 from ray_tpu._private.test_utils import wait_for_condition
 
 
-def test_leaky_task_killed_and_retried_elsewhere(tmp_path):
+def _stage(marker):
+    """(the task's result, its attempts, whether the memory reading was seen
+    over the leaky node's threshold while the task ran)."""
     used, total = get_memory_usage()
     frac = used / max(total, 1)
     leak_bytes = 3 * 1024**3
@@ -23,7 +25,6 @@ def test_leaky_task_killed_and_retried_elsewhere(tmp_path):
     # the rescue node's threshold sits far above so it never presses.
     thr_leaky = frac + 0.5 * leak_bytes / total
     thr_rescue = min(frac + 10 * leak_bytes / total, 0.98)
-    marker = str(tmp_path / "attempts")
 
     # Only the leaky node exists at submit time, so attempt 1 must land
     # there; the rescue node joins while the leak is in flight.
@@ -62,10 +63,29 @@ def test_leaky_task_killed_and_retried_elsewhere(tmp_path):
             env={"RT_MEMORY_THRESHOLD": f"{thr_rescue:.5f}"},
         )
 
-        out = ray_tpu.get(ref, timeout=120)
-        assert out == "ok", f"expected the retry to succeed, got {out!r}"
+        pressed, deadline = False, time.monotonic() + 120
+        while True:
+            used, total = get_memory_usage()
+            pressed = pressed or used / max(total, 1) > thr_leaky
+            done, _ = ray_tpu.wait([ref], timeout=0.2)
+            if done or time.monotonic() > deadline:
+                break
+        out = ray_tpu.get(ref, timeout=5)
         with open(marker) as f:
-            attempts = len(f.readlines())
-        assert attempts >= 2, "task was never killed + retried"
+            return out, len(f.readlines()), pressed
     finally:
         ray_tpu.shutdown()
+
+
+def test_leaky_task_killed_and_retried_elsewhere(tmp_path):
+    """The threshold is the shared host's reading at the start plus half the
+    leak: where other tenants give back more than the leak adds, the node
+    never presses. A reading that never passed the threshold is the
+    staging's failure and it is staged again; a node that pressed and did
+    not kill is the watchdog's, at once."""
+    for attempt in range(3):
+        out, attempts, pressed = _stage(str(tmp_path / f"attempts{attempt}"))
+        if out == "ok" or pressed:
+            break
+    assert out == "ok", f"expected the retry to succeed, got {out!r}"
+    assert attempts >= 2, "task was never killed + retried"

@@ -24,16 +24,11 @@ import time
 import pytest
 
 import ray_tpu
-from ray_tpu._private import faultpoints as fp
 from ray_tpu._private import protocol, specframe
 from ray_tpu._private import worker as worker_mod
 
 
-@pytest.fixture(autouse=True)
-def _fp_clean():
-    fp.clear()
-    yield
-    fp.clear()
+pytestmark = pytest.mark.usefixtures("faults_cleared")
 
 
 # ------------------------------------------------------ window mechanics

@@ -5,7 +5,6 @@ Reference analog: per-algorithm learning tests under
 tolerance tests in ``rllib/env/``.
 """
 import numpy as np
-import pytest
 
 import ray_tpu
 from ray_tpu.rllib import IMPALAConfig, PPOConfig, make_trainable
@@ -102,13 +101,6 @@ def test_vtrace_on_policy_equals_nstep():
 
 
 # ------------------------------------------------------------- learning
-
-
-@pytest.fixture
-def rl_cluster():
-    ray_tpu.init(num_cpus=6)
-    yield
-    ray_tpu.shutdown()
 
 
 def _ppo_config(**training):

@@ -8,7 +8,6 @@ integration, and a short TQC learning run.
 import numpy as np
 import pytest
 
-import ray_tpu
 from ray_tpu.rllib import TQCConfig
 from ray_tpu.rllib.connectors import (
     ClipObs,
@@ -116,13 +115,6 @@ class ShiftedObsEnv:
 
     def close(self):
         pass
-
-
-@pytest.fixture
-def rl_cluster():
-    ray_tpu.init(num_cpus=6)
-    yield
-    ray_tpu.shutdown()
 
 
 def test_runner_applies_and_syncs_connector_state(rl_cluster):

@@ -9,9 +9,7 @@ pair, replay windowing, and checkpoint roundtrip.
 """
 import gymnasium as gym
 import numpy as np
-import pytest
 
-import ray_tpu
 from ray_tpu.rllib import DreamerV3Config
 
 
@@ -40,13 +38,6 @@ class ParityEnv:
 
     def close(self):
         pass
-
-
-@pytest.fixture
-def rl_cluster():
-    ray_tpu.init(num_cpus=6)
-    yield
-    ray_tpu.shutdown()
 
 
 def _config():

@@ -1,17 +1,8 @@
 """Multi-agent episodes: per-policy learners over one env (reference:
 ``rllib/env/multi_agent_env_runner.py`` + multi_agent config)."""
 import numpy as np
-import pytest
 
-import ray_tpu
 from ray_tpu.rllib.multi_agent import MultiAgentPPOConfig
-
-
-@pytest.fixture
-def rl_cluster():
-    ray_tpu.init(num_cpus=4)
-    yield
-    ray_tpu.shutdown()
 
 
 class TwoGuessersEnv:

@@ -7,7 +7,6 @@ toy problems plus checkpoint roundtrips.
 import numpy as np
 import pytest
 
-import ray_tpu
 from ray_tpu.rllib import BCConfig, MARWILConfig, SACConfig
 
 
@@ -38,13 +37,6 @@ class TargetReachEnv:
 
     def close(self):
         pass
-
-
-@pytest.fixture
-def rl_cluster():
-    ray_tpu.init(num_cpus=6)
-    yield
-    ray_tpu.shutdown()
 
 
 def _sac_config():

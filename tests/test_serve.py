@@ -7,12 +7,6 @@ import ray_tpu
 from ray_tpu import serve
 
 
-@pytest.fixture
-def srv(rt_start):
-    yield rt_start
-    serve.shutdown()
-
-
 @pytest.mark.parametrize("rt_start", [{"num_cpus": 8}], indirect=True)
 def test_deploy_and_call(srv):
     @serve.deployment(num_replicas=2)

@@ -25,19 +25,10 @@ import ray_tpu
 from ray_tpu import serve
 from ray_tpu._private import faultpoints as fp
 from ray_tpu._private.test_utils import wait_for_condition
+from tests.conftest import _leases_settled, _no_leaked_objects
 
 
-@pytest.fixture(autouse=True)
-def _clean_faults():
-    fp.clear()
-    yield
-    fp.clear()
-
-
-@pytest.fixture
-def srv(rt_start):
-    yield rt_start
-    serve.shutdown()
+pytestmark = pytest.mark.usefixtures("faults_cleared")
 
 
 def _replica_handles(name):
@@ -47,26 +38,9 @@ def _replica_handles(name):
     return ray_tpu.get(controller.get_handles.remote(name), timeout=30)
 
 
-def _leases_settled():
-    cluster = ray_tpu._internal_cluster()
-    return all(
-        all(n.available.get(k, 0.0) >= v - 1e-9
-            for k, v in n.resources.items())
-        for n in cluster.head.nodes.values() if n.alive
-    )
-
-
 def _zero_stranded(router):
     snap = router.inflight_snapshot()
     return sum(snap.values()) == 0, snap
-
-
-def _no_leaked_objects():
-    """Zero leaked objects (memtrack plane SLO, same contract as the
-    core chaos matrix): no orphaned directory entries past grace."""
-    from ray_tpu.util import state
-
-    return state.memory_summary(grace_s=1.0)["leaks"] == []
 
 
 # ------------------------------------------------- pre-dispatch failover

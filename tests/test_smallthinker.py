@@ -1,12 +1,16 @@
-"""SmallThinker over the one decoder, on a share of a layer's experts: held
-to its plain reference (``benchmarks/references/smallthinker.py``) on the
-CPU at a toy size with seeded weights. The toy keeps what is odd about the
-published shapes: 7 query heads a kv head, a head width that is not the
-model's, a window shorter than the sequence, a period of one global layer
-and three window layers."""
-import collections
+"""SmallThinker over the one decoder, on a share of a layer's experts: what
+is peculiar to it. The cases every family shares (the logits against the
+reference, bfloat16, the plan, padded chunks and cached steps, idle and
+reused slots, two slots, speculation) run over its row of
+``tests/families.py``; here, the loss and every leaf's gradient on a share
+against its plain reference (``benchmarks/references/smallthinker.py``), a
+layer in four shares, a share that drops no row, the up-projections held for
+the backward pass, and the programs that stand. The toy keeps what is odd
+about the published shapes: 7 query heads a kv head, a head width that is
+not the model's, a window shorter than the sequence, a period of one global
+layer and three window layers."""
 import dataclasses
-import os
+import functools
 import re
 
 import jax
@@ -14,23 +18,19 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.lib import named
-from benchmarks.lib.cluster import BENCH_DIR
 from ray_tpu.models import decoder, get_preset, module_for
 from ray_tpu.parallel import moe
-from tests.test_moe_counts import _equations
+from tests import families
+from tests.families import _equations
 
 T = 32
 
 
 @pytest.fixture
-def reference(monkeypatch):
-    """The reference with the toy's numbers in place of the published."""
-    ref = named.load(os.path.join(BENCH_DIR, "references", "smallthinker.py"))
-    monkeypatch.setattr(ref, "SLIDING_WINDOW", 8)
-    monkeypatch.setattr(ref, "TOP_K", 2)
-    monkeypatch.setattr(ref, "Q_BLOCK", 16)   # T = 32: two blocks of queries
-    return ref
+def reference():
+    """The reference with the toy's numbers in place of the published (the
+    row's constants), a module of this test's own: a case sets its share."""
+    return families.load_reference("smallthinker")
 
 
 def _config(first=None, held=None, **kw):
@@ -41,7 +41,7 @@ def _config(first=None, held=None, **kw):
         **kw)
 
 
-def _tokens(seed=0, batch=2, length=T + 1):
+def _batch(seed=0, batch=2, length=T + 1):
     return jnp.asarray(np.random.default_rng(seed).integers(
         0, 512, (batch, length)), jnp.int32)
 
@@ -53,10 +53,24 @@ def _reference_loss(ref, params, tokens):
     return -ll.mean() + aux
 
 
+@functools.lru_cache(maxsize=None)
+def _reference_side(first, held):
+    """The reference's loss and gradients on a share's initial weights, one
+    program, once a share: the attention's implementation is the program's
+    side alone."""
+    reference = families.load_reference("smallthinker", FIRST_HELD=first or 0)
+    cfg = _config(first, held)
+    params = module_for(cfg).init_params(cfg, jax.random.PRNGKey(0))
+    tokens = _batch()
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(jax.value_and_grad(
+            lambda p: _reference_loss(reference, p, tokens)))(params)
+
+
 @pytest.mark.parametrize("impl", ["xla", "flash_interpret"])
 @pytest.mark.parametrize("first, held", [(None, None), (2, 2), (6, 2)],
                          ids=["all", "experts2-3", "experts6-7"])
-def test_loss_and_gradients_match_the_reference(reference, impl, first, held):
+def test_loss_and_gradients_match_the_reference(impl, first, held):
     """The family's loss (cross entropy + auxiliary loss) and its gradient
     by every leaf, float32 on both sides with the kernels' window and
     grouped heads (``flash_interpret``) or XLA's. The two differ in the
@@ -64,17 +78,16 @@ def test_loss_and_gradients_match_the_reference(reference, impl, first, held):
     gradient within 3e-7 where its largest entry is 1e-3 to 1e-2 (measured;
     the limits leave a factor of five). SiLU for ReLU reads 2e-4 on the
     experts' gradients, a router fed the normed input 1e-3 on the
-    router's, a window ignored 2e-3 on wq."""
+    router's, a window ignored 2e-3 on wq. Each side is one program: op by
+    op the same arithmetic takes a minute."""
     cfg = _config(first, held, attention_impl=impl)
-    reference.FIRST_HELD = first or 0
     model = module_for(cfg)
     params = model.init_params(cfg, jax.random.PRNGKey(0))
-    tokens = _tokens()
+    tokens = _batch()
     with jax.default_matmul_precision("highest"):
-        got, got_grads = jax.value_and_grad(
-            lambda p: model.loss_fn(p, {"tokens": tokens}, cfg))(params)
-        want, want_grads = jax.value_and_grad(
-            lambda p: _reference_loss(reference, p, tokens))(params)
+        got, got_grads = jax.jit(jax.value_and_grad(
+            lambda p: model.loss_fn(p, {"tokens": tokens}, cfg)))(params)
+    want, want_grads = _reference_side(first, held)
     assert abs(float(got) - float(want)) < 1e-5
     gaps = jax.tree.map(lambda a, b: float(jnp.abs(a - b).max()),
                         got_grads, want_grads)
@@ -93,41 +106,19 @@ def test_the_aux_loss_and_the_counts_ride_beside_the_loss(reference):
     reference.FIRST_HELD = 2
     model = module_for(cfg)
     params = model.init_params(cfg, jax.random.PRNGKey(0))
-    tokens = _tokens()
-    xent, aux = model.loss_fn(params, {"tokens": tokens}, cfg, parts=True)
+    tokens = _batch()
+    xent, aux = jax.jit(lambda p: model.loss_fn(
+        p, {"tokens": tokens}, cfg, parts=True))(params)
     assert set(aux) == {"aux_loss", "moe_rows_held", "moe_rows_max_expert"}
-    _, want_aux = reference.logits_and_aux(params, tokens[:, :-1])
+    _, want_aux = jax.jit(reference.logits_and_aux)(params, tokens[:, :-1])
     assert abs(float(aux["aux_loss"]) - float(want_aux)) < 1e-6
     pairs = 2 * T * 2 * 4          # tokens x top_k x layers
     assert 0.1 * pairs < int(aux["moe_rows_held"]) < 0.5 * pairs
     assert (int(aux["moe_rows_held"]) / 2 <= int(aux["moe_rows_max_expert"])
             <= int(aux["moe_rows_held"]))
-    total = model.loss_fn(params, {"tokens": tokens}, cfg)
+    total = jax.jit(lambda p: model.loss_fn(p, {"tokens": tokens}, cfg))(
+        params)
     assert abs(float(total) - float(xent + aux["aux_loss"])) < 1e-6
-
-
-def test_prefill_then_cached_decode_is_the_full_forward():
-    """A prefill of 24 tokens and 8 decode steps of one against the full
-    forward's logits, float32: the window's ring, RoPE by layer kind and the
-    router at the block's input are the same program both ways (within 2e-5
-    on logits of 0.3; a decode step that rotated a global layer reads
-    1e-2)."""
-    cfg = _config(2, 2)
-    model = module_for(cfg)
-    params = model.init_params(cfg, jax.random.PRNGKey(0))
-    tokens = _tokens(length=T)
-    want, _ = model.forward(params, tokens, cfg)
-    cache = decoder.init_kv_cache(cfg, 2, 64, block=24)
-    start = jnp.zeros((2,), jnp.int32)
-    got, cache = decoder.forward_cached(
-        params, tokens[:, :24], cache, start, cfg)
-    parts = [got]
-    for t in range(24, T):
-        step, cache = decoder.forward_cached(
-            params, tokens[:, t:t + 1], cache, start + t, cfg)
-        parts.append(step)
-    np.testing.assert_allclose(
-        np.concatenate(parts, axis=1), np.asarray(want), atol=2e-5, rtol=0)
 
 
 def _one_layer(first, held):
@@ -149,8 +140,10 @@ def test_four_shares_of_a_layer_add_up_to_the_uncut_layer(reference):
 
     def block(cfg, experts):
         kind = decoder.layer_kinds(cfg)[0]
-        stacked = (moe.stacked_for(experts, cfg.dtype), 0)
-        return decoder._body(cfg, None, pos, kind)(x, layer, None, stacked)[0]
+        # one program a share: op by op a block is seconds
+        return jax.jit(lambda experts: decoder._body(cfg, None, pos, kind)(
+            x, layer, None, (moe.stacked_for(experts, cfg.dtype), 0))[0])(
+            experts)
 
     base = block(cfg, {**experts,
                        "expert_out": jnp.zeros_like(experts["expert_out"])})
@@ -277,12 +270,12 @@ def test_the_step_reports_cross_entropy_and_what_rides_beside_it():
     cfg = _config(2, 2)
     opt = OptimizerConfig().build()
     state = create_train_state(cfg, opt, jax.random.PRNGKey(0))
-    batch = {"tokens": _tokens()}
-    xent, aux = module_for(cfg).loss_fn(
-        state["params"], batch, cfg, parts=True)
+    batch = {"tokens": _batch()}
+    xent, aux = jax.jit(lambda p: module_for(cfg).loss_fn(
+        p, batch, cfg, parts=True))(state["params"])
     _, metrics = make_train_step(cfg, opt, donate=False)(state, batch)
-    # the step is one jit and the parts were computed eagerly: the order of
-    # float32 sums, 1.4e-6 on a loss of 6.23
+    # the step is one program and the parts another: the order of float32
+    # sums, 1.4e-6 on a loss of 6.23 (measured with the parts op by op)
     assert abs(float(metrics["loss"]) - float(xent)) < 1e-5
     for key, value in aux.items():
         assert abs(float(metrics[key]) - float(value)) < 1e-5, key

@@ -8,10 +8,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops import ssm
 from ray_tpu.ops.decode_attention import live_slots
+from tests.families import LATE, _scattered
 
 H, P, N = 4, 8, 16
 
@@ -137,24 +137,6 @@ def test_update_kernel_equals_the_xla_step_and_skips_idle_slots(live):
         if not alive:
             assert bool((out[1, b] == states[1, b]).all())
             assert bool((y[b] == 0).all())
-
-
-def _scattered(count, B, seed):
-    """``live_slots``' [B + 1] for ``count`` slots (None: all) in no order:
-    the walk takes them as they are named."""
-    slots = np.random.default_rng(seed).permutation(B)[
-        :B if count is None else count]
-    live = np.zeros(B + 1, np.int32)
-    live[:len(slots)], live[B] = slots, len(slots)
-    mask = np.zeros(B, bool)
-    mask[slots] = True
-    return jnp.asarray(live), jnp.asarray(mask)
-
-
-# the TPU interpreter runs a DMA when it is WAITED for: a piece computed on
-# before its read's wait, or a ring entry written over before its write's,
-# shows as wrong numbers
-LATE = pltpu.InterpretParams()
 
 
 @pytest.mark.parametrize("heads", [1, 2, 4])

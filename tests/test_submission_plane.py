@@ -32,11 +32,7 @@ from ray_tpu._private.test_utils import wait_for_condition
 from ray_tpu._private.worker import FN_NS
 
 
-@pytest.fixture(autouse=True)
-def _fp_clean():
-    fp.clear()
-    yield
-    fp.clear()
+pytestmark = pytest.mark.usefixtures("faults_cleared")
 
 
 class _HeadVerbCounter:

@@ -294,6 +294,10 @@ def test_elastic_grows_back_when_node_returns(rt_cluster, tmp_path):
     import time as _t
 
     ray_tpu_mod, cluster = rt_cluster
+    # rank 0 marks the world it runs in, and the chaos goes by the marks:
+    # by the clock, a loaded box was back at two before a step at one
+    marks = str(tmp_path / "marks")
+    os.makedirs(marks)
 
     def train_fn(config):
         import tempfile
@@ -307,6 +311,8 @@ def test_elastic_grows_back_when_node_returns(rt_cluster, tmp_path):
                 start = int(f.read()) + 1
         for step in range(start, 44):
             if ctx.get_world_rank() == 0:
+                open(os.path.join(
+                    marks, f"world_{ctx.get_world_size()}"), "w").close()
                 with tempfile.TemporaryDirectory() as d:
                     with open(os.path.join(d, "step.txt"), "w") as f:
                         f.write(str(step))
@@ -318,10 +324,17 @@ def test_elastic_grows_back_when_node_returns(rt_cluster, tmp_path):
                 train.report({"step": step, "world": ctx.get_world_size()})
             time.sleep(0.25)
 
+    def seen(mark, deadline=120.0):
+        end = _t.monotonic() + deadline
+        while not os.path.exists(os.path.join(marks, mark)):
+            if _t.monotonic() > end:
+                return
+            _t.sleep(0.05)
+
     def chaos():
-        _t.sleep(2.0)
+        seen("world_2")  # a step was taken on both nodes
         cluster.kill_node(cluster.nodes[1])  # shrink to 1
-        _t.sleep(3.0)
+        seen("world_1")  # and one on the node that is left
         cluster.add_node({"CPU": 2})  # capacity returns: grow back
 
     t = threading.Thread(target=chaos, daemon=True)

@@ -29,11 +29,7 @@ from ray_tpu._private import protocol, specframe, taskpath
 from ray_tpu._private import worker as worker_mod
 
 
-@pytest.fixture(autouse=True)
-def _fp_clean():
-    fp.clear()
-    yield
-    fp.clear()
+pytestmark = pytest.mark.usefixtures("faults_cleared")
 
 
 # ------------------------------------------------------ window mechanics

@@ -6,14 +6,13 @@ durability, not snapshot-timer durability. The head appends durable-table
 mutations (KV, jobs) to a generational WAL (``_private/wal.py``); restart
 replays snapshot + WAL.
 """
-import json
 import os
 import signal
-import subprocess
-import sys
 import time
 
 import pytest
+
+from tests.conftest import start_head
 
 
 def test_wal_record_roundtrip_and_torn_tail(tmp_path):
@@ -60,22 +59,11 @@ def test_head_kv_survives_hard_kill_via_wal(tmp_path, clean, monkeypatch):
     # fixed token shared by both head incarnations and this client (the
     # test skips the 0600 address file that normally distributes it)
     monkeypatch.setenv("RT_AUTH_TOKEN", "waltest" * 4)
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["RT_AUTH_TOKEN"] = "waltest" * 4
-
-    def start_head():
-        proc = subprocess.Popen(
-            [sys.executable, "-m", "ray_tpu._private.head_main",
-             "--state-file", state_file,
-             "--state-save-interval", "3600", "--no-address-file"],
-            stdout=subprocess.PIPE, text=True, env=env, cwd="/root/repo",
-        )
-        return proc, json.loads(proc.stdout.readline().strip())
-
+    head = ("--state-file", state_file, "--state-save-interval", "3600",
+            "--no-address-file")
     from ray_tpu._private.sync_client import SyncHeadClient
 
-    proc, info = start_head()
+    proc, info = start_head(*head)
     try:
         client = SyncHeadClient(info["address"])
         client.call("kv_put", {"ns": "user", "key": "alpha"},
@@ -91,7 +79,7 @@ def test_head_kv_survives_hard_kill_via_wal(tmp_path, clean, monkeypatch):
         proc.wait(timeout=10)
 
     assert not os.path.exists(state_file)  # no snapshot ever written
-    proc, info = start_head()
+    proc, info = start_head(*head)
     try:
         client = SyncHeadClient(info["address"])
         h, frames = client.call("kv_get", {"ns": "user", "key": "beta"})
