@@ -130,8 +130,14 @@ the host from the same lengths: whole blocks of a live slot's filled
 positions in a tick, of what a tile of queries sees in a chunk; any other
 backend's XLA scores every slot's whole leaf), so that ``index_positions /
 index_positions_read`` is near 1 where only keys a query may see are read;
-``stats`` sums the three, and ``engine.admit.cache`` carries the ``bytes`` of the
-slot cache an admission fills.
+``sparse_tiles`` of ``engine.admit`` is the (sub-tile of queries, block of
+positions) pairs the chunks' attention over the choice would visit with
+every query reading every block up to the chunk's last token's, and
+``sparse_tiles_computed`` the pairs it computes
+(``ops/block_attention.py:selected_tiles``, on the host from the chunks'
+starts and lengths: a sub-tile above the diagonal is left out), a head and
+latent layer; ``stats`` sums the five, and ``engine.admit.cache`` carries
+the ``bytes`` of the slot cache an admission fills.
 """
 from __future__ import annotations
 
@@ -535,6 +541,10 @@ class DecodeEngine:
             # positions of the indexer's keys that the scores' kernel visits
             # for them: whole blocks, of live slots and visible ones alone
             "index_positions_read": 0,
+            # sub-tiles of queries x blocks of positions the chunks' sparse
+            # attention would visit with every query reading every visible
+            # block, and the ones it computes: none above the diagonal
+            "sparse_tiles": 0, "sparse_tiles_computed": 0,
             # state layers: slots that decode x state layers over ticks (the
             # states a tick reads and writes), and prompt tokens that went
             # through a prefill's scan; both 0 for a model with none
@@ -706,6 +716,7 @@ class DecodeEngine:
         attention layers, beside ``prefill_cache_positions``, all that those
         layers' caches hold, once a chunk; summed into ``stats``."""
         from ray_tpu.models import kv_cache
+        from ray_tpu.ops import block_attention
 
         counts = {
             "prefill_key_positions": sum(
@@ -719,6 +730,12 @@ class DecodeEngine:
                 [np.arange(start + 1, start + T + 1) for start, T in chunks]
                 or [np.zeros(0, np.int64)])
             counts.update(self._chosen_positions(seen, chunks))
+            tiles = [block_attention.selected_tiles(
+                start, T, self.config.max_seq_len) for start, T in chunks]
+            counts["sparse_tiles"] = self._layers_latent * sum(
+                every for every, _ in tiles)
+            counts["sparse_tiles_computed"] = self._layers_latent * sum(
+                computed for _, computed in tiles)
         for name, count in counts.items():
             self.stats[name] += count
         return counts

@@ -95,8 +95,9 @@ forward's), and the kernels' oracle. The choice enters
 every route as a mask, so every visible row is read and an unchosen one adds
 nothing: the decode kernel takes it beside the cache, a chunk attends
 through ``ops/block_attention.py:selected_block_attention`` (a head's
-queries against blocks of rows up-projected in VMEM), the XLA route masks
-its whole scores.
+queries against blocks of rows up-projected in VMEM; what lies above the
+chunk's own diagonal is left out by sub-tiles of queries), the XLA route
+masks its whole scores.
 """
 from __future__ import annotations
 
@@ -533,8 +534,7 @@ def _attend_chosen(leaf, keys, layer, q, up, index: Indexed, start,
                 found, start + jnp.arange(T), index.kept, interpret=interpret)
             with jax.named_scope("mla.sparse"):
                 return selected_block_attention(
-                    q, up, leaf, jnp.pad(picked, ((0, 0), (0, S - width))),
-                    layer, start, scale=scale, width=width,
+                    q, up, leaf, picked, layer, start, scale=scale,
                     interpret=interpret)
         return attended
 

@@ -26,8 +26,17 @@ two ends in it) is masked each time to its own end, which is sound where the
 ring holds the window and the block, ``S >= window + T``: a slot ``p mod S``
 then holds position p for every p a token of the block may see.
 
-Tile sizes follow the shapes given (``tiles``), nothing else; S is a
-multiple of the 128 lanes and T of ``TOKENS``.
+``selected_block_attention`` is the chunk of a LATENT layer whose tokens
+each chose what they attend (``models/kv_cache.py:_attend_chosen``): one head
+a grid row, all T queries against one block of the cache's rows a step, the
+block up-projected to the head's keys and values in VMEM, the choice an int8
+tile beside it. Every row of a visible block is read and up-projected; a
+position a query did not choose is masked out of its softmax; and of a
+block in the chunk's own span only the sub-tiles of queries that can see it
+are computed (``selected_tiles`` counts both on the host).
+
+Tile sizes follow the shapes given (``tiles``, ``_selected_tiling``), nothing
+else; S is a multiple of the 128 lanes and T of ``TOKENS``.
 """
 from __future__ import annotations
 
@@ -209,23 +218,55 @@ def block_attention(q, k_cache, v_cache, layer, start, *, window=None,
 
 
 # ------------------------------------------------ a latent layer's chosen rows
-# positions of a latent cache a step of ``selected_block_attention`` holds
+# positions of a latent cache a step of ``selected_block_attention`` holds,
+# and the queries of one of its sub-tiles where they divide a chunk
 SELECTED_POSITIONS = 512
 
 
-def _selected_kernel(layer_ref, start_ref, qn_ref, qr_ref, wk_ref, wv_ref,
-                     c_ref, mask_ref, o_ref, m_sc, l_sc, acc_sc, *, bs: int,
-                     S: int, scale: float, rank: int):
+def _selected_tiling(T: int, S: int):
+    """(queries a sub-tile, positions a block) of a chunk of T tokens
+    against a cache of S positions: blocks of ``SELECTED_POSITIONS`` or the
+    longest shorter power of two that divides S, sub-tiles of a block's
+    length (a tile above the diagonal is then a whole one) or, where that
+    does not divide T, all T queries."""
+    bs = next(n for n in (SELECTED_POSITIONS, 256, TILE) if S % n == 0)
+    return (bs if T % bs == 0 else T), bs
+
+
+def selected_tiles(start: int, T: int, S: int):
+    """(sub-tile x block visits a chunk of T tokens at ``start ..`` would
+    make with every query attending every block up to its last token's, the
+    visits ``selected_block_attention`` computes: a sub-tile whose last
+    query lies before a block's first position sees nothing of it), on the
+    host, one head and layer."""
+    tr, bs = _selected_tiling(T, S)
+    blocks = min(start + T - 1, S - 1) // bs + 1
+    return blocks * (T // tr), sum(
+        T // tr - max(j * bs - start, 0) // tr for j in range(blocks))
+
+
+def _selected_kernel(layer_ref, start_ref, q_ref, up_ref, c_ref, mask_ref,
+                     o_ref, m_sc, l_sc, acc_sc, *, tr: int, bs: int, S: int,
+                     scale: float, rank: int, Dn: int):
     """One head's T queries against one block of ``bs`` positions of a
     latent cache: the block's rows ``[rank + Dr, bs]`` are up-projected to
-    the head's keys and values here (``wk [Dn, rank]``, ``wv [Dv, rank]``),
-    scored (``q_nope . k_nope + q_rope . kr``), masked by the choice and
-    folded into the head's running softmax. A step past the block that
-    holds the last token's position computes nothing."""
+    the head's keys and values here (``up [Dn + Dv, rank]``), the keys
+    stacked over the block's rotated rows, so that a score is one product
+    ``q [., Dn + Dr] @ k [Dn + Dr, bs]``; then, ``tr`` queries at a time,
+    scored, masked by the choice and folded into the queries' running
+    softmax: the scale inside the exponential's argument, a query's sum
+    kept a lane apart (``l [T, lanes]``: the lanes are added once, at the
+    end, where a sum across them in every step held the step up). A step
+    past the block that holds the last token's position computes nothing,
+    nor does a sub-tile whose last query lies before the block's first
+    position."""
     j = pl.program_id(1)
-    T = qn_ref.shape[0]
+    T = q_ref.shape[0]
+    n, lanes = T // tr, l_sc.shape[1]
     shift = bs.bit_length() - 1
-    count = (jnp.minimum(start_ref[0] + T - 1, S - 1) >> shift) + 1
+    start = start_ref[0]
+    count = (jnp.minimum(start + T - 1, S - 1) >> shift) + 1
+    dtype = q_ref.dtype
 
     @pl.when(j == 0)
     def _():
@@ -233,82 +274,108 @@ def _selected_kernel(layer_ref, start_ref, qn_ref, qr_ref, wk_ref, wv_ref,
         l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
         acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
 
-    @pl.when(j < count)
-    def _():
-        qn, qr = qn_ref[...], qr_ref[...]
-        rows = c_ref[...].astype(qn.dtype)                # [rank + Dr, bs]
-        c, kr = rows[:rank], rows[rank:]
-        # the head's keys and values of the block, rounded as a product's
-        # result in the activations' dtype is
-        kn = jnp.dot(wk_ref[...], c,
-                     preferred_element_type=jnp.float32).astype(qn.dtype)
-        v = jnp.dot(wv_ref[...], c,
-                    preferred_element_type=jnp.float32).astype(qn.dtype)
-        sc = (jnp.dot(qn, kn, preferred_element_type=jnp.float32)
-              + jnp.dot(qr, kr, preferred_element_type=jnp.float32)
-              ) * scale                                          # [T, bs]
-        seen = mask_ref[...] != 0
-        sc = jnp.where(seen, sc, NEG_INF)
-        m_prev = m_sc[...]
+    def up_projected(rows, c):
+        """The head's keys or values of the block, rounded as a product's
+        result in the activations' dtype is."""
+        return jnp.dot(up_ref[rows, :], c,
+                       preferred_element_type=jnp.float32).astype(dtype)
+
+    def scores(i, k):
+        own = slice(i * tr, (i + 1) * tr)
+        sc = jnp.dot(q_ref[own, :], k, preferred_element_type=jnp.float32)
+        return jnp.where(mask_ref[own, :] != 0, sc, NEG_INF)     # [tr, bs]
+
+    def fold(i, sc, v):
+        own = slice(i * tr, (i + 1) * tr)
+        m_prev = m_sc[own, :]
         m_next = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_next)
-        p = jnp.where(seen, jnp.exp(sc - m_next), 0.0)
-        l_sc[...] = alpha * l_sc[...] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_sc[...] = alpha * acc_sc[...] + jax.lax.dot_general(
-            p.astype(qn.dtype), v, (((1,), (1,)), ((), ())),
+        alpha = jnp.exp((m_prev - m_next) * scale)
+        # a query that has met no chosen position yet adds 1s here (NEG_INF
+        # - NEG_INF); the first block that holds one wipes them out (alpha
+        # = 0), and a masked score's exponential is 0 from then on
+        p = jnp.exp((sc - m_next) * scale)
+        l_sc[own, :] = alpha * l_sc[own, :] + sum(
+            p[:, at:at + lanes] for at in range(0, bs, lanes))
+        acc_sc[own, :] = alpha * acc_sc[own, :] + jax.lax.dot_general(
+            p.astype(dtype), v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_sc[...] = m_next
+        m_sc[own, :] = m_next
+
+    def walk(first: int):
+        """The sub-tiles from ``first`` on in ONE straight line, in the
+        order the units should meet them: a sub-tile's scores are asked for
+        before the one before it is folded (and the first one's before the
+        values are up-projected), so that the products run beside the
+        softmax's passes: the compiler keeps to the order it is given."""
+        block = c_ref[...].astype(dtype)                  # [rank + Dr, bs]
+        c = block[:rank]
+        k = jnp.concatenate(
+            [up_projected(slice(0, Dn), c), block[rank:]], axis=0)
+        sc = scores(first, k)
+        v = up_projected(slice(Dn, None), c)
+        for i in range(first, n):
+            ahead = scores(i + 1, k) if i + 1 < n else None
+            fold(i, sc, v)
+            sc = ahead
+
+    # a variant a first sub-tile whose last query sees the block's first
+    # position: 0 for every block before the chunk's own span
+    first = jnp.maximum((j << shift) - start, 0) >> (
+        tr.bit_length() - 1) if n > 1 else 0
+    for f in range(n):
+        pl.when((j < count) & (first == f))(functools.partial(walk, f))
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _():
-        o_ref[...] = (acc_sc[...] / l_sc[...]).astype(o_ref.dtype)
+        o_ref[...] = (acc_sc[...] / jnp.sum(
+            l_sc[...], axis=-1, keepdims=True)).astype(o_ref.dtype)
 
 
 def selected_block_attention(q, up, cache, picked, layer, start, *,
-                             scale: float, width=None,
-                             interpret: bool = False):
+                             scale: float, interpret: bool = False):
     """A block of one slot's tokens over the rows of a LATENT cache that
     each token CHOSE: q [T, H, Dn + Dr] at positions ``start [1] + t``, ``up``
     [R, H, Dn + Dv] (a head's ``[Wuk | Wuv]``), ``cache`` [L, 1, 1, R + Dr,
-    S] with the tokens' own rows in place, ``picked`` [T, S] bool (causal
-    already: an indexer's choice) -> [T, H, Dv]. One head at a time holds
-    all T queries and walks the blocks of ``SELECTED_POSITIONS`` positions
-    up to the one with the last token's: each block's rows are read where
-    they lie and up-projected in VMEM, so no up-projected key, no score and
-    no probability is ever in HBM. Every visible row is read; an unchosen
-    one enters no softmax. ``width`` (a multiple of the block; None: S):
-    positions the grid covers, for a caller that knows ``start + T`` lies
-    within it."""
+    S] with the tokens' own rows in place, ``picked`` [T, width] bool (causal
+    already: an indexer's choice; ``width`` whole blocks, up to S, with
+    ``start + T`` inside them) -> [T, H, Dv]. One head at a time holds all T
+    queries and walks the blocks of ``SELECTED_POSITIONS`` positions up to
+    the one with the last token's: each block's rows are read where they
+    lie and up-projected in VMEM, so no up-projected key, no score and no
+    probability is ever in HBM. Every row of a visible block is read and an
+    unchosen one enters no softmax; a sub-tile of queries above the diagonal
+    (its last query before the block's first position) is not computed."""
     T, H, Dq = q.shape
     R = up.shape[0]
     D, S = cache.shape[-2:]
     Dn = Dq - (D - R)
     Dv = up.shape[-1] - Dn
+    width = picked.shape[1]
     if S % TILE or T % TOKENS:
         raise ValueError(
             f"a block of tokens (a multiple of {TOKENS}) against whole tiles "
             f"of {TILE} positions: {T} against {S} is the XLA path's "
             f"(models/kv_cache.py:attend_latent)")
-    bs = next(n for n in (SELECTED_POSITIONS, 256, TILE) if S % n == 0)
-    nk = -(-(width or S) // bs)
+    tr, bs = _selected_tiling(T, S)
+    if width % bs or width > S:
+        raise ValueError(
+            f"a choice over {width} of {S} positions: no whole blocks of {bs}")
     shift = bs.bit_length() - 1
 
     def block(j, start_ref):
         count = (jnp.minimum(start_ref[0] + T - 1, S - 1) >> shift) + 1
         return jnp.minimum(j, count - 1)       # past the last: the last
 
-    up = up.astype(q.dtype)
-    head = lambda width: pl.BlockSpec(                       # noqa: E731
-        (None, T, width), lambda h, j, *_: (h, 0, 0))
-    weight = lambda width: pl.BlockSpec(                     # noqa: E731
-        (None, width, R), lambda h, j, *_: (h, 0, 0))
+    head = lambda *shape: pl.BlockSpec(                      # noqa: E731
+        (None, *shape), lambda h, j, *_: (h, 0, 0))
     o = pl.pallas_call(
-        functools.partial(_selected_kernel, bs=bs, S=S, scale=scale, rank=R),
+        functools.partial(_selected_kernel, tr=tr, bs=bs, S=S, scale=scale,
+                          rank=R, Dn=Dn),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(H, nk),
+            grid=(H, width // bs),
             in_specs=[
-                head(Dn), head(Dq - Dn), weight(Dn), weight(Dv),
+                head(T, Dq), head(Dn + Dv, R),
                 pl.BlockSpec(
                     (None, None, None, D, bs),
                     lambda h, j, layer_ref, start_ref: (
@@ -316,10 +383,10 @@ def selected_block_attention(q, up, cache, picked, layer, start, *,
                 pl.BlockSpec(
                     (T, bs), lambda h, j, layer_ref, start_ref: (
                         0, block(j, start_ref)))],
-            out_specs=head(Dv),
+            out_specs=head(T, Dv),
             scratch_shapes=[
                 pltpu.VMEM((T, 1), jnp.float32),
-                pltpu.VMEM((T, 1), jnp.float32),
+                pltpu.VMEM((T, min(TILE, bs)), jnp.float32),
                 pltpu.VMEM((T, Dv), jnp.float32),
             ],
         ),
@@ -332,8 +399,6 @@ def selected_block_attention(q, up, cache, picked, layer, start, *,
         interpret=interpret,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32),
       jnp.reshape(start, (1,)).astype(jnp.int32),
-      jnp.moveaxis(q[..., :Dn], 1, 0), jnp.moveaxis(q[..., Dn:], 1, 0),
-      jnp.transpose(up[..., :Dn], (1, 2, 0)),
-      jnp.transpose(up[..., Dn:], (1, 2, 0)), cache,
-      picked.astype(jnp.int8))
+      jnp.moveaxis(q, 1, 0), jnp.transpose(up.astype(q.dtype), (1, 2, 0)),
+      cache, picked.astype(jnp.int8))
     return jnp.moveaxis(o, 0, 1)

@@ -268,13 +268,12 @@ def _watch_selected_blocks(monkeypatch, impl, seen):
     monkeypatch.setattr(index_select, "POSITIONS", 32)
     monkeypatch.setattr(block_attention, "SELECTED_POSITIONS", 32)
     _spy(monkeypatch, "selected_block_attention", seen,
-         lambda q, up, leaf, picked, *a, **k: (
-             q.shape[0], picked.shape, k["width"]))
+         lambda q, up, leaf, picked, *a, **k: (q.shape[0], picked.shape))
 
     def check(chunks, cache):
         # one trace holds every width's branch
         blocks_are(seen, set() if impl == "xla" else {
-            (16, (16, 128), w) for w in (32, 64, 128)})
+            (16, (16, w)) for w in (32, 64, 128)})
         filled = sum(n for n, _ in chunks) + 16
         for name, leaf in cache.items():
             # [layer, slot, position, channel] of either leaf

@@ -908,8 +908,14 @@ def test_a_model_that_holds_every_expert_carries_neither(greedy_recording):
 
 @pytest.fixture(scope="module")
 def indexed_recording(share_recording, tmp_path_factory):
-    return _recorded("deepseek_v32",
-                     str(tmp_path_factory.mktemp("spans_indexed")))
+    from ray_tpu.ops import block_attention
+
+    # blocks and sub-tiles of 8, so that a chunk of 16 has a diagonal (the
+    # count is the host's: off the TPU no kernel attends)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(block_attention, "SELECTED_POSITIONS", 8)
+        return _recorded("deepseek_v32",
+                         str(tmp_path_factory.mktemp("spans_indexed")))
 
 
 def test_an_indexed_models_ticks_carry_what_was_scored_and_what_was_read(
@@ -995,14 +1001,37 @@ def test_an_indexed_models_spans_carry_the_positions_the_kernels_visit(
     assert index_select.positions_read(32768, 2048, 33280) == 2048 * 33280
 
 
+def test_an_indexed_models_admissions_carry_the_tiles_attention_computes(
+        indexed_recording):
+    """``sparse_tiles``: (sub-tile of 8 queries, block of 8 positions) pairs
+    up to each chunk's last token's block, and ``sparse_tiles_computed``:
+    those whose sub-tile's last query sees the block's first position, a
+    latent layer. The prompt of 37 is three chunks, (0, 16), (16, 16) and
+    (32, 8): 4 + 8 + 5 pairs, of which the second sub-tile's alone computes
+    each chunk's last block where the chunk has two: 3 + 7 + 5."""
+    spans, stats = indexed_recording["spans"], indexed_recording["stats"]
+    admits = spans.named("engine.admit")
+    assert sorted((a.args["sparse_tiles"], a.args["sparse_tiles_computed"])
+                  for a in admits) == [(3 * 1, 3 * 1), (3 * 7, 3 * 6),
+                                       (3 * 17, 3 * 15)]
+    three_chunks = max(admits, key=lambda a: a.args["sparse_tiles"])
+    assert three_chunks.args["chunks"] == 3
+    assert three_chunks.args["sparse_tiles_computed"] / three_chunks.args[
+        "sparse_tiles"] == pytest.approx(15 / 17)
+    for name in ("sparse_tiles", "sparse_tiles_computed"):
+        assert stats[name] == sum(a.args[name] for a in admits)
+        assert not any(name in t.args for t in spans.named("engine.tick"))
+
+
 def test_a_model_without_an_indexer_carries_neither_counter(
         greedy_recording, share_recording):
     for recording in (greedy_recording, share_recording):
         spans = recording["spans"]
         assert not any(
             "index_positions" in s.args or "selected_positions" in s.args
-            or "index_positions_read" in s.args
+            or "index_positions_read" in s.args or "sparse_tiles" in s.args
             for s in spans.named("engine.tick") + spans.named("engine.admit"))
         assert recording["stats"]["index_positions"] == 0
         assert recording["stats"]["index_positions_read"] == 0
+        assert recording["stats"]["sparse_tiles"] == 0
         assert recording["stats"]["selected_positions"] == 0

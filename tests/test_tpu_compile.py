@@ -909,14 +909,19 @@ def test_deepseek_v32_programs_fit_the_chip_and_keep_both_rows_in_place(
                   if re.match(r"\s*%?selected_block_attention", c)]
         # two kinds of layer x the widths a chunk may choose over
         assert len(chosen) == 2 * (len(kv_cache.CHOICE_WIDTHS) + 1)
-        assert all("bf16[128,2048,128]" in c and f"s8[2048,{S}]" in c
-                   for c in chosen)
+        # the queries whole (128 + 64 channels: a score is one product), a
+        # head's [Wuk | Wuv], 128 values a head out; the choice comes as wide
+        # as it was made
+        assert all("bf16[128,2048,192]" in c and "bf16[128,256,512]" in c
+                   and "bf16[128,2048,128]" in c for c in chosen)
+        widths = (*kv_cache.CHOICE_WIDTHS, S)
+        assert sorted(w for c in chosen for w in widths
+                      if f"s8[2048,{w}]" in c) == sorted(2 * widths)
         # nothing a head wide along the cache: no [heads, tokens, S] score
         assert not re.search(rf"\[(128|64),2048,{S}\]|\[2048,(128|64),{S}\]",
                              text)
         # the indexer's kernels, a pair a kind of layer and width: a tile of
         # queries against the leaf where it lies, [2048, width] scores out
-        widths = (*kv_cache.CHOICE_WIDTHS, S)
         scored = [c for c in calls if re.match(r"\s*%?index_scores", c)]
         searched = [c for c in calls
                     if re.match(r"\s*%?index_kth_largest", c)]
